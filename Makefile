@@ -175,7 +175,8 @@ spec-decode-smoke:
 	JAX_PLATFORMS=cpu $(PY) run_tests.py --spec-decode-smoke
 
 # bench regression gate (ISSUE 16): bin/dstpu-benchdiff under the committed
-# benchtrack.json policy — the committed BENCH_r04->r05 pair must pass and an
-# injected 30% serving-throughput regression must exit 1
+# benchtrack.json policy — a trajectory pair whose base timed out must pass and
+# an injected 30% serving-throughput regression must exit 1 (records built from
+# literals in a temp directory)
 bench-diff:
 	$(PY) run_tests.py --bench-diff

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..compat import ensure_cpu_multiprocess_collectives
 from ..parallel.mesh import MeshTopology, get_topology
 from ..runtime.heartbeat import (COLLECTIVE_TIMEOUT_ENV, INIT_RETRIES_ENV,
                                  INIT_RETRY_BACKOFF_ENV, get_heartbeat)
@@ -153,13 +152,6 @@ def init_distributed(dist_backend: str = "xla",
     if coord:
         nproc = world_size if world_size > 0 else int(os.environ.get("WORLD_SIZE", "1"))
         pid = rank if rank >= 0 else int(os.environ.get("RANK", "0"))
-        # pre-0.5 jax defaults CPU collectives to 'none', so a multiprocess
-        # CPU job dies on its first collective; align with the new default
-        # BEFORE the client exists (no-op where the option is gone/explicit)
-        if nproc > 1 and not ensure_cpu_multiprocess_collectives():
-            warning_once("init_distributed: no cross-process CPU collectives "
-                         "implementation could be selected on this jax — "
-                         "multiprocess CPU programs will fail")
         _initialize_with_retries(coord, nproc, pid, timeout)
         if verbose:
             logger.info(f"jax.distributed initialized: process {pid}/{nproc} via {coord}")

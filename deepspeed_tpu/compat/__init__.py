@@ -1,80 +1,39 @@
-"""Versioned-import shim over the drifted jax/Pallas API surface.
+"""Single import point for the jax symbols whose spelling has moved before.
 
-The reference DeepSpeed survives CUDA/torch version skew through its
-accelerator + op_builder indirection (SURVEY §L0): kernels never import a
-vendor API directly, they ask the abstraction layer.  This package is the
-jax_graft equivalent for the *jax* API surface: every symbol whose import
-path or signature has drifted across the jax versions we support is exported
-from here, resolved against whatever the installed jax actually ships, and
-**dslint enforces** (rule ``direct-shimmed-import``) that nothing outside
-``compat/`` spells the underlying paths — so the next upstream rename lands
-as one edit to ``SHIMMED_SYMBOLS`` plus one lint report naming call sites,
-instead of 41 red tests across the kernel/onebit/TP/sequence families.
+Written for the one installation there is — jax 0.9.0 / jaxlib 0.9.0 /
+libtpu 0.0.34: every entry of ``SHIMMED_SYMBOLS`` holds exactly the spelling
+that jax ships, and nothing here probes versions, translates keyword names or
+carries a reimplementation for a jax that is not installed.  What remains is
+the alias layer itself: the ``direct-shimmed-import`` lint rule bans spelling
+these paths anywhere else, so the next upstream rename is one edit here plus a
+lint report naming the call sites.  Whether that layer earns its keep is an
+open simplicity question (ROADMAP, Design queue).
 
-Shimmed today (jax 0.4.x ←→ 0.5/0.6+):
+Exported:
 
-- ``shard_map`` — moved from ``jax.experimental.shard_map`` to top-level
-  ``jax.shard_map``; the replication-check kwarg was renamed
-  ``check_rep`` → ``check_vma``.  Exported as a signature-normalizing
-  wrapper: call it with ``check_vma=`` everywhere and the shim translates
-  for whichever implementation resolved.
-- ``CompilerParams`` — Pallas-TPU compiler params, renamed from
-  ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``.
-- ``axis_size`` — ``jax.lax.axis_size`` is new-jax-only; old jax falls back
-  to the behavior-compatible ``psum(1, axis)`` reimplementation in
-  ``compat/_fallbacks.py``.
-- ``Space`` — the ``jax.memory.Space`` memories enum; old jax falls back to
-  lazily-resolved ``TransferToMemoryKind`` placements (see
-  ``compat/_fallbacks.py``).
-
-How to add a shimmed symbol (see README "Compatibility & drift policy"):
-
-1. add a ``SHIMMED_SYMBOLS`` entry: exported name → tuple of
-   ``"module:attr"`` candidates, NEWEST spelling first (first hit wins);
-2. export it below (plain ``resolve_symbol`` binding, or a wrapper when the
-   *signature* drifted too, like ``shard_map``);
-3. port the call sites — ``dstpu-lint`` now flags every direct spelling of
-   any candidate path, inside ``deepspeed_tpu/`` and ``tests/`` alike;
-4. add resolution tests to ``tests/unit/test_compat.py`` covering both the
-   new-name and old-name branches (module monkeypatching, no jax upgrade
-   needed).
+- ``shard_map`` — ``jax.shard_map`` (``check_vma=``, ``axis_names=``).
+- ``CompilerParams`` — ``jax.experimental.pallas.tpu.CompilerParams``.
+- ``axis_size`` — ``jax.lax.axis_size``.
+- ``Space`` — the ``jax.memory.Space`` memories enum.
 
 ``SHIMMED_SYMBOLS`` doubles as the machine-readable registry dslint reads —
-by AST parse of this file, never by importing it — so the lint rule can never
-go stale relative to what the shim actually covers.
+by AST parse of this file, never by importing it.  Keep values as literal
+tuples of literal ``"module:attr"`` strings.
 """
 
 import importlib
-import inspect
 from typing import Any, Dict, Tuple
 
-# exported name -> ordered "module:attr" candidates, newest spelling FIRST.
-# dslint's direct-shimmed-import rule bans every candidate spelling outside
-# compat/ (both directions: the old name must not linger, the new name must
-# not be imported around the shim).  Keep values as literal tuples of literal
-# strings: the rule reads this assignment from the AST.
 SHIMMED_SYMBOLS: Dict[str, Tuple[str, ...]] = {
-    "shard_map": (
-        "jax:shard_map",
-        "jax.experimental.shard_map:shard_map",
-    ),
-    "CompilerParams": (
-        "jax.experimental.pallas.tpu:CompilerParams",
-        "jax.experimental.pallas.tpu:TPUCompilerParams",
-    ),
-    "axis_size": (
-        "jax.lax:axis_size",
-        "deepspeed_tpu.compat._fallbacks:axis_size",
-    ),
-    "Space": (
-        "jax.memory:Space",
-        "deepspeed_tpu.compat._fallbacks:Space",
-    ),
+    "shard_map": ("jax:shard_map", ),
+    "CompilerParams": ("jax.experimental.pallas.tpu:CompilerParams", ),
+    "axis_size": ("jax.lax:axis_size", ),
+    "Space": ("jax.memory:Space", ),
 }
 
 
 class CompatResolutionError(ImportError):
-    """No candidate spelling of a shimmed symbol exists in the installed jax."""
+    """The installed jax lacks the registered spelling of a shimmed symbol."""
 
 
 _cache: Dict[str, Tuple[Any, str]] = {}
@@ -100,121 +59,25 @@ def _resolve_uncached(name: str) -> Tuple[Any, str]:
         tried.append(f"{spec} (attribute absent)")
     raise CompatResolutionError(
         f"compat: no installed spelling of '{name}' — tried {'; '.join(tried)}. "
-        f"The installed jax has drifted past every candidate in "
-        f"SHIMMED_SYMBOLS['{name}']; add its current path as the first entry.")
+        f"The installed jax has moved it; put its current path in "
+        f"SHIMMED_SYMBOLS['{name}'].")
 
 
 def resolve_symbol(name: str, refresh: bool = False) -> Any:
-    """The object behind a shimmed name under the installed jax (cached).
-
-    ``refresh=True`` re-runs resolution — the seam the compat unit tests use
-    to exercise both the new-name and old-name branches via monkeypatched
-    modules without reinstalling jax.
-    """
+    """The object behind a shimmed name under the installed jax (cached)."""
     if refresh or name not in _cache:
         _cache[name] = _resolve_uncached(name)
     return _cache[name][0]
 
 
 def resolved_source(name: str) -> str:
-    """Which candidate spelling ``resolve_symbol`` bound (for diagnostics)."""
+    """Which spelling ``resolve_symbol`` bound (for diagnostics)."""
     resolve_symbol(name)
     return _cache[name][1]
 
 
-# --------------------------------------------------------------- shard_map
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-              axis_names=None, **kwargs):
-    """``jax.shard_map`` across the rename AND the kwarg drift.
-
-    Call with the NEW spellings everywhere; the shim translates for whichever
-    implementation resolved:
-
-    - ``check_vma=`` → ``check_rep=`` on the pre-rename
-      ``jax.experimental.shard_map.shard_map`` (a ``check_rep=`` kwarg is
-      likewise forwarded under whichever name the implementation accepts, so
-      the shim never strands a caller mid-migration);
-    - ``axis_names={...}`` (the set of mesh axes the body is MANUAL over) →
-      the old API's complementary ``auto=`` set (the mesh axes left
-      automatic), computed against ``mesh.axis_names``.
-    """
-    impl = resolve_symbol("shard_map")
-    params = inspect.signature(impl).parameters
-    flag = kwargs.pop("check_rep", check_vma)
-    if flag is not None:
-        kwargs["check_vma" if "check_vma" in params else "check_rep"] = flag
-    if axis_names is not None:
-        if "axis_names" in params:
-            kwargs["axis_names"] = set(axis_names)
-        else:
-            # the old API spells partial-manual as the complementary ``auto=``
-            # set — but its XLA lowering hard-ABORTS the process on real auto
-            # axes (spmd_partitioner IsManualSubgroup check), so refuse with a
-            # debuggable Python error instead.  Size-1 leftover axes are
-            # semantically manual==auto and simply fold into manual.
-            auto = {a for a in mesh.axis_names
-                    if a not in frozenset(axis_names) and mesh.shape[a] > 1}
-            if auto:
-                raise NotImplementedError(
-                    f"compat.shard_map: partial-manual over {sorted(axis_names)} "
-                    f"with automatic axes {sorted(auto)} is not runnable on this "
-                    f"jax ({resolved_source('shard_map')}): the old 'auto=' "
-                    f"lowering aborts in XLA's SPMD partitioner. Gate the caller "
-                    f"on compat.supports_partial_manual() and fall back to a "
-                    f"fully-manual or fully-automatic formulation.")
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def ensure_cpu_multiprocess_collectives() -> bool:
-    """Align old jax with the new default for cross-process CPU collectives.
-
-    New jax runs multiprocess CPU programs out of the box (its
-    ``jax_cpu_collectives_implementation`` defaults to ``gloo``); old jax
-    defaults the same option to ``none``, so the first cross-process
-    computation — even ``multihost_utils.sync_global_devices`` — dies with
-    "Multiprocess computations aren't implemented on the CPU backend".
-    Select gloo when the option exists and nothing was chosen explicitly.
-    Must run BEFORE the CPU client is created (comm.init_distributed calls
-    it ahead of ``jax.distributed.initialize``).  Returns False only when a
-    collectives implementation could not be arranged."""
-    import jax
-    try:
-        # the option is defined at xla_bridge import, which plain `import jax`
-        # defers — force it so the probe reads the real default
-        import jax._src.xla_bridge  # noqa: F401
-    except ImportError:
-        pass
-    try:
-        # flag-style options aren't attribute-readable on old jax — _read is
-        # the accessor that works across versions
-        current = jax.config._read("jax_cpu_collectives_implementation")
-    except (AttributeError, KeyError, ValueError):
-        return True  # option retired: this jax defaults to a working impl
-    if current in (None, "none"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            return False
-    return True
-
-
-def supports_partial_manual() -> bool:
-    """Whether ``shard_map`` can leave some mesh axes automatic
-    (``axis_names=`` subset).  Only the new top-level ``jax.shard_map``
-    supports this reliably — the experimental API's ``auto=`` crashes XLA's
-    SPMD partitioner on real (size>1) auto axes, so callers of hierarchical
-    manual/auto programs (e.g. stage-3 ZeRO++) must gate on this and degrade
-    to a formulation the installed jax can run."""
-    impl = resolve_symbol("shard_map")
-    return "axis_names" in inspect.signature(impl).parameters
-
-
-# --------------------------------------------------- plain renamed exports
-# Resolved LAZILY via module __getattr__ (PEP 562): `from compat import
-# CompilerParams` resolves at the importer's import time, but importers that
-# only need shard_map/the probes (comm, the runtime engine) never trigger a
-# Pallas-TPU import — eager resolution here would couple the whole package's
-# import surface to jax.experimental.pallas.tpu being importable.
+# Resolved LAZILY via module __getattr__ (PEP 562): importers that only need
+# shard_map (comm, the runtime engine) never trigger a Pallas-TPU import.
 def __getattr__(name: str):
     if name in SHIMMED_SYMBOLS:
         return resolve_symbol(name)
@@ -222,6 +85,4 @@ def __getattr__(name: str):
 
 
 __all__ = ["SHIMMED_SYMBOLS", "CompatResolutionError", "resolve_symbol",
-           "resolved_source", "shard_map", "supports_partial_manual",
-           "ensure_cpu_multiprocess_collectives",
-           "CompilerParams", "axis_size", "Space"]
+           "resolved_source", "shard_map", "CompilerParams", "axis_size", "Space"]

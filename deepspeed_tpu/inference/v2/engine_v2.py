@@ -1041,8 +1041,7 @@ class InferenceEngineV2:
                      eos_token_id: Optional[int] = None) -> Optional[Dict[int, List[int]]]:
         """Run ``k`` decode steps INSIDE one compiled program — one host
         round-trip per k tokens instead of per token (the latency lever the
-        reference gets from CUDA-graph decode loops; on a remote-relay
-        transport this is the difference between ~4 and ~100+ tok/s/seq).
+        reference gets from CUDA-graph decode loops).
 
         Greedy AND sampled (temperature/top-k/top-p from the engine config)
         decode both run device-side; with ``eos_token_id`` the scan carries a
@@ -1482,7 +1481,7 @@ class InferenceEngineV2:
         ``greedy=False`` samples with the engine config's temperature/top-k/
         top-p — still through the device-side burst (the scan carries the rng
         and an eos done-mask), so sampled serving runs at burst throughput
-        rather than the one-host-roundtrip-per-token relay floor."""
+        rather than one host round-trip per token."""
         uids = list(range(len(prompts)))
         results = self._serve(uids, prompts, max_new_tokens=max_new_tokens,
                               eos_token_id=eos_token_id, greedy=greedy, strict=strict,

@@ -4,7 +4,8 @@ The v2 ragged engine's serve loop used to rebuild its whole padded batch on
 the host every step (one ``np`` rebuild + four ``jnp.asarray`` uploads) and
 then block on ``np.asarray(toks)`` before it could schedule the next step —
 pure orchestration overhead that left a ~20x gap between the fused decode
-burst and the continuous-batching loop (BENCH_r05: 1907 vs 90.4 tok/s).
+burst and the continuous-batching loop (1907 vs 90.4 tok/s in the last chip
+record before this module; that record is deleted, the figures are history).
 This module holds the three host-link levers the engine composes:
 
 - :class:`DeviceBatchState` — persistent donated device buffers per

@@ -3,8 +3,9 @@
 Parity: reference inference/v2/model_implementations/mistral (the reference
 serves Mistral with windowed blocked flash).  The backbone is byte-identical to
 Llama, so everything delegates to models/llama with ``sliding_window`` threaded
-through: training masks the window inside sdpa; v2 serving passes it to the
-Pallas paged kernel (ops/attention/paged.py window arg).
+through: training runs the default (flash) attention while the sequence fits
+the window and masks the window inside sdpa past it; v2 serving passes it to
+the Pallas paged kernel (ops/attention/paged.py window arg).
 """
 
 import dataclasses
@@ -17,7 +18,7 @@ import numpy as np
 
 from . import llama
 from .llama import LlamaConfig
-from .transformer import cross_entropy_loss, sdpa
+from .transformer import cross_entropy_loss, default_attention, sdpa
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,10 +38,9 @@ class MistralConfig(LlamaConfig):
                              max_seq_len=seq, sliding_window=window)
 
 
-def windowed_attention(window: Optional[int]):
-    """attention_fn applying the sliding-window causal mask (training path)."""
-    if window is None:
-        return None
+def dense_windowed_attention(window: int):
+    """Sliding-window causal attention as one dense mask handed to ``sdpa`` —
+    right for any length, and the plain reference the kernels are held to."""
 
     def attn(q, k, v, causal=True, mask=None, softmax_scale=None):
         sq, sk = q.shape[1], k.shape[1]
@@ -52,6 +52,25 @@ def windowed_attention(window: Optional[int]):
         else:
             wmask = wmask[None, None]
         return sdpa(q, k, v, causal=False, mask=wmask, softmax_scale=softmax_scale)
+
+    return attn
+
+
+def windowed_attention(window: Optional[int]):
+    """attention_fn for the training path.  While the keys fit the window
+    (``sk <= window``) the window mask IS the causal mask, so the call goes to
+    the backend's default attention with ``causal=True`` — the Pallas flash
+    kernel on TPU — exactly as Llama does.  Only past the window is the dense
+    mask built."""
+    if window is None:
+        return None
+    dense = dense_windowed_attention(window)
+
+    def attn(q, k, v, causal=True, mask=None, softmax_scale=None):
+        if k.shape[1] <= window:
+            return default_attention()(q, k, v, causal=True, mask=mask,
+                                       softmax_scale=softmax_scale)
+        return dense(q, k, v, mask=mask, softmax_scale=softmax_scale)
 
     return attn
 

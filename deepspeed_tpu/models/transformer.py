@@ -397,5 +397,7 @@ def cross_entropy_loss(logits, labels, ignore_index=-100, z_loss=0.0):
 
 
 def init_linear(key, in_dim, out_dim, scale=None, dtype=jnp.float32):
-    scale = scale if scale is not None else 1.0 / np.sqrt(in_dim)
+    # a Python float, so the product keeps ``dtype``: a numpy scalar here
+    # promoted bf16 weights to float32 (twice the bytes at 4096 width)
+    scale = float(scale if scale is not None else 1.0 / np.sqrt(in_dim))
     return jax.random.normal(key, (in_dim, out_dim), dtype) * scale

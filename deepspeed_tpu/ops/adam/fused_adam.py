@@ -46,6 +46,7 @@ def _flat_kernel_call(kernel, scal, arrays, n_out):
         out_shape=[jax.ShapeDtypeStruct((n_pad // 128, 128), jnp.float32)] * n_out,
         input_output_aliases={i + 1: i for i in range(n_out)},
         interpret=_pallas.INTERPRET,
+        name=f"fused_{kernel.__name__.strip('_')}",
     )(scal, *[as2d(a) for a in arrays])
     return tuple(o.reshape(n_pad)[:n] for o in outs)
 

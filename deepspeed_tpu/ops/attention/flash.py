@@ -26,7 +26,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import CompilerParams
+from ...compat import CompilerParams, shard_map
 from .. import _pallas
 from .._pallas import use_pallas as _use_pallas
 
@@ -125,6 +125,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=_pallas.INTERPRET,
+        name="flash_attention_fwd",
     )(qt, kt, vt)
     return out[:, :, :sq].transpose(0, 2, 1, 3), lse[:, :, :sq, 0]
 
@@ -275,6 +276,7 @@ def _flash_bwd(scale, causal, block_q, block_k, res, g, g_lse=None):
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=_pallas.INTERPRET,
+        name="flash_attention_bwd_dkv",
     )(qt, kt, vt, dot, lse_p, delta_p)
     # fold grouped q-heads into their kv head
     dk = dk_h.reshape(b, hk, group, sk_p, d).sum(axis=2)
@@ -299,6 +301,7 @@ def _flash_bwd(scale, causal, block_q, block_k, res, g, g_lse=None):
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=_pallas.INTERPRET,
+        name="flash_attention_bwd_dq",
     )(qt, kt, vt, dot, lse_p, delta_p)
 
     dq = dq[:, :, :sq].transpose(0, 2, 1, 3)
@@ -373,6 +376,34 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     return _flash_lse(q, k, v, scale, causal, block_q, block_k)
 
 
+def _per_shard(kernel, q, k, v):
+    """Run ``kernel(q, k, v)`` on each device's shard of a multi-device mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map" — what a
+    ZeRO-3 step on more than one real chip answered), so under the process's
+    mesh the call is wrapped here: batch over the data-parallel axes, heads
+    over the tensor axis, each where it divides; a dimension that does not
+    divide is computed replicated.  Inside a caller's own shard_map (ring,
+    Ulysses, TP serving) the arrays are already per-shard, and with no
+    topology installed the call is a plain single-device one."""
+    from jax.sharding import PartitionSpec, get_abstract_mesh
+
+    from ...parallel.mesh import DATA_AXIS, FSDP_AXIS, TENSOR_AXIS, peek_topology
+    topo = peek_topology()  # the engine's; none installed means a plain call
+    if topo is None or topo.mesh.devices.size == 1 or get_abstract_mesh().manual_axes:
+        return kernel(q, k, v)
+    mesh = topo.mesh
+    batch_axes = tuple(a for a in (DATA_AXIS, FSDP_AXIS) if mesh.shape[a] > 1)
+    if q.shape[0] % int(np.prod([mesh.shape[a] for a in batch_axes])) != 0:
+        batch_axes = ()
+    tp = mesh.shape[TENSOR_AXIS]
+    head_axis = TENSOR_AXIS if tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0 else None
+    spec = PartitionSpec(batch_axes or None, None, head_axis, None)
+    return shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                     check_vma=False)(q, k, v)
+
+
 def flash_attention(q, k, v, causal: bool = True, mask=None,
                     softmax_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024):
@@ -390,4 +421,5 @@ def flash_attention(q, k, v, causal: bool = True, mask=None,
         return sdpa(q, k, v, causal=causal, mask=mask, softmax_scale=softmax_scale)
     d = q.shape[-1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(d))
-    return _flash(q, k, v, scale, causal, block_q, block_k)
+    return _per_shard(lambda q, k, v: _flash(q, k, v, scale, causal, block_q, block_k),
+                      q, k, v)

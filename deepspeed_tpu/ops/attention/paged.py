@@ -36,6 +36,36 @@ from .._pallas import use_pallas as _use_pallas
 
 NEG_INF = -1e30
 
+# Scalar memory (SMEM) of one TensorCore, and what the kernel's own scalars and
+# the compiler keep of it.  Source: the v5e compiler (libtpu 0.0.34) asked
+# through a described topology — "Ran out of memory in memory space smem. Used
+# 1.01M of 1.00M" for a [512, 512] table, and the padded sizes it names for
+# other shapes (rows to 8 — to 1, 2 or 4 under that —, columns to 128, 4 bytes
+# each; the per-sequence vectors and ~1.1 KiB of the compiler's own on top).
+SMEM_BYTES = 1 << 20
+_SMEM_RESERVE = 2048
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def check_block_table_fits(n: int, maxb: int, n_vectors: int = 3) -> None:
+    """The block table and the per-sequence vectors ride into the kernel as
+    scalar-prefetch operands, so they must fit scalar memory whole.  Raise a
+    readable error here instead of an XLA resource error mid-serve."""
+    rows = _round_up(n, 8) if n > 4 else (1, 1, 2, 4, 4)[n]
+    table = rows * _round_up(maxb, 128) * 4
+    need = table + n_vectors * _round_up(n, 128) * 4 + _SMEM_RESERVE
+    if need > SMEM_BYTES:
+        raise ValueError(
+            f"paged_attention: block table [{n}, {maxb}] int32 needs {table} bytes of "
+            f"scalar memory (padded to {rows} x {_round_up(maxb, 128)}) plus "
+            f"{need - table} for the per-sequence scalars; the chip has {SMEM_BYTES}. "
+            f"Serve fewer sequences per step, a shorter max context "
+            f"(max_blocks_per_seq), or a larger KV block size "
+            f"(n_seqs x max_blocks_per_seq must stay under ~{SMEM_BYTES // 4}).")
+
 
 def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, *rest,
                   scale, block_size, t_pad, window, alibi):
@@ -109,11 +139,12 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
         return _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
                                scale, window, alibi_slopes)
 
+    alibi = alibi_slopes is not None
+    check_block_table_fits(n, maxb, n_vectors=4 if alibi else 3)
     group = hq // kvh
     t_pad = max(8, int(np.ceil(t / 8)) * 8)
     qt = jnp.pad(q.transpose(0, 2, 1, 3), ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
 
-    alibi = alibi_slopes is not None
     kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs,
                                t_pad=t_pad, window=window, alibi=alibi)
     nsp = 5 if alibi else 4
@@ -145,6 +176,7 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_pallas.INTERPRET,
+        name="paged_attention",
     )(*scalars, qt, kpool, vpool)
     return out[:, :, :t].transpose(0, 2, 1, 3)
 

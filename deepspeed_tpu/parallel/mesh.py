@@ -158,6 +158,12 @@ def get_topology() -> MeshTopology:
     return _GLOBAL_TOPOLOGY
 
 
+def peek_topology() -> Optional[MeshTopology]:
+    """The topology an engine (or ``set_topology``) installed, or None — never
+    builds one, so asking does not claim every device of the host."""
+    return _GLOBAL_TOPOLOGY
+
+
 def reset_topology():
     global _GLOBAL_TOPOLOGY
     _GLOBAL_TOPOLOGY = None

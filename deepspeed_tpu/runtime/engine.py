@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..compat import shard_map, supports_partial_manual
+from ..compat import shard_map
 from ..monitor.monitor import MonitorMaster
 from ..monitor.telemetry import TelemetryCollector
 from ..parallel.mesh import MeshTopology, set_topology
@@ -506,22 +506,11 @@ class Engine:
         # GSPMD per-layer gather axis ('fsdp' — the hpZ secondary partition)
         # fp16 is excluded: int4 quantization would launder grad inf/nan into
         # finite values before overflow detection, defeating loss-scale skips
-        # ... and a jax whose shard_map supports partial-manual (manual 'data'
-        # hop around GSPMD 'fsdp' gathers); without it the quantized stage-3
-        # wire format degrades to the plain GSPMD stage-3 path below, loudly
-        zpp3_eligible = (self.zero_stage >= 3 and pure_dp and not fp16
-                         and self.plan.shard_axes == ("data", "fsdp")
-                         and topo.axis_size("data") > 1 and topo.axis_size("fsdp") > 1
-                         and bool(zero_cfg.zero_quantized_gradients
-                                  or zero_cfg.zero_quantized_weights))
-        zpp3 = zpp3_eligible and supports_partial_manual()
-        if zpp3_eligible and not zpp3:
-            # only when the jax capability was the DECIDING condition — an
-            # fp16/mesh exclusion must not be misattributed to the jax version
-            log_dist("stage-3 ZeRO++ quantized communication requires a jax whose "
-                     "shard_map supports partial-manual meshes (axis_names=); this "
-                     "jax does not — falling back to plain (unquantized) stage-3 "
-                     "GSPMD communication", ranks=[0])
+        zpp3 = (self.zero_stage >= 3 and pure_dp and not fp16
+                and self.plan.shard_axes == ("data", "fsdp")
+                and topo.axis_size("data") > 1 and topo.axis_size("fsdp") > 1
+                and bool(zero_cfg.zero_quantized_gradients
+                         or zero_cfg.zero_quantized_weights))
         hpz = (zero_cfg.zero_hpz_partition_size > 1 and self.zero_stage >= 3
                and topo.axis_size("fsdp") > 1)
         if zero_cfg.zero_quantized_gradients and not (qgz or zpp3):

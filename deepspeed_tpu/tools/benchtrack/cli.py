@@ -63,11 +63,11 @@ def render_text(rows: List[dict], base: dict, cand: dict) -> str:
 def main(argv: Optional[List[str]] = None) -> int:
     parser = argparse.ArgumentParser(
         prog="dstpu-benchdiff",
-        description="Diff two BENCH_*.json records (or a fresh bench run vs "
-                    "the committed trajectory) under the benchtrack.json "
+        description="Diff two bench records (a driver's command-wrapper "
+                    "record or the plain JSON of a fresh run) under the benchtrack.json "
                     "direction+tolerance policy; exit 1 on regression.")
-    parser.add_argument("base", help="baseline record (e.g. BENCH_r04.json)")
-    parser.add_argument("candidate", help="candidate record (e.g. BENCH_r05.json)")
+    parser.add_argument("base", help="baseline record (JSON file)")
+    parser.add_argument("candidate", help="candidate record (JSON file)")
     parser.add_argument("--policy", default=None,
                         help="policy file (default: benchtrack.json next to "
                              "the base record, then ./benchtrack.json)")

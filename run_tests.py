@@ -1732,39 +1732,47 @@ def spec_decode_smoke():
 
 
 def run_bench_diff_lane():
-    """bench regression gate (ISSUE 16): the committed BENCH_r04->r05 pair
-    must pass (timed-out r04 carries zero metrics -> all-missing verdicts,
-    never a failure), and an injected-regression fixture must exit 1 — both
-    via the standalone bin/dstpu-benchdiff CLI (same loading discipline as
-    the lint lane: works even when the library is broken at import time)."""
+    """bench regression gate (ISSUE 16): a trajectory pair whose base timed
+    out must pass (a timed-out record carries zero metrics -> all-missing
+    verdicts, never a failure), and an injected-regression fixture must exit 1
+    — both via the standalone bin/dstpu-benchdiff CLI (same loading discipline
+    as the lint lane: works even when the library is broken at import time).
+    The records are built here from literals, in a temp directory."""
     import os
     import tempfile
     t0 = time.time()
     root = os.path.dirname(os.path.abspath(__file__))
     cli = os.path.join(root, "bin", "dstpu-benchdiff")
-    committed = subprocess.run(
-        [sys.executable, cli, os.path.join(root, "BENCH_r04.json"),
-         os.path.join(root, "BENCH_r05.json"),
-         "--policy", os.path.join(root, "benchtrack.json")],
-        capture_output=True, text=True)
-    # injected regression: candidate = r05's metrics with the serving
-    # throughput cut 30% — must trip the gate
-    from deepspeed_tpu.tools.benchtrack.diffcore import load_bench
-    metrics = dict(load_bench(os.path.join(root, "BENCH_r05.json"))["metrics"])
-    degraded = dict(metrics)
-    degraded["serving_mixed_tok_s"] = metrics.get("serving_mixed_tok_s", 100.0) * 0.7
+    policy = os.path.join(root, "benchtrack.json")
     tmp = tempfile.mkdtemp(prefix="dstpu_benchdiff_")
-    base_p = os.path.join(tmp, "base.json")
-    cand_p = os.path.join(tmp, "degraded.json")
-    json.dump(metrics, open(base_p, "w"))
-    json.dump(degraded, open(cand_p, "w"))
+
+    def write(name, obj):
+        path = os.path.join(tmp, name)
+        with open(path, "w") as fh:
+            json.dump(obj, fh)
+        return path
+
+    metrics = {"serving_mixed_tok_s": 90.4, "serving_mixed_p50_step_ms": 113.9,
+               "decode_tok_s": 1907.0, "mfu": 0.58}
+    timed_out = write("timed_out.json", {
+        "n": 4, "cmd": "python bench.py", "rc": 124, "parsed": None,
+        "tail": "[INFO] Engine: zero_stage=3 dp_world=1 batch=6\n"})
+    completed = write("completed.json", {
+        "n": 5, "cmd": "python bench.py", "rc": 0, "parsed": None,
+        "tail": json.dumps(metrics)[1:-1]})
+    committed = subprocess.run(
+        [sys.executable, cli, timed_out, completed, "--policy", policy],
+        capture_output=True, text=True)
+    # injected regression: the same metrics with the serving throughput cut
+    # 30% — must trip the gate
     injected = subprocess.run(
-        [sys.executable, cli, base_p, cand_p,
-         "--policy", os.path.join(root, "benchtrack.json")],
+        [sys.executable, cli, write("base.json", metrics),
+         write("degraded.json", {**metrics, "serving_mixed_tok_s": 90.4 * 0.7}),
+         "--policy", policy],
         capture_output=True, text=True)
     dt = time.time() - t0
     ok = committed.returncode == 0 and injected.returncode == 1
-    tail = (f"committed pair rc={committed.returncode} (want 0), "
+    tail = (f"trajectory pair rc={committed.returncode} (want 0), "
             f"injected regression rc={injected.returncode} (want 1)")
     print(f"[bench_diff] {tail}  ({dt:.0f}s)")
     if not ok:

@@ -459,15 +459,26 @@ def test_benchdiff_cli_exit_codes(tmp_path, capsys):
                            _write(tmp_path / "pol2.json", {"metrics": {}})]) == 2
 
 
-def test_benchdiff_wrapper_shape_and_committed_pair():
-    r04 = os.path.join(REPO_ROOT, "BENCH_r04.json")
-    r05 = os.path.join(REPO_ROOT, "BENCH_r05.json")
-    if not (os.path.exists(r04) and os.path.exists(r05)):
-        pytest.skip("committed BENCH records not present")
-    rec = load_bench(r05)
+# the two shapes a driver's command-wrapper record takes: a run killed at its
+# time limit (log-only tail, nothing judgeable) and a run that printed its
+# metrics into the tail
+_WRAPPER_TIMED_OUT = {
+    "n": 4, "cmd": "python bench.py", "rc": 124, "parsed": None,
+    "tail": "[INFO] MeshTopology: {'data': 1, 'fsdp': 1} over 1 devices\n"
+            "[INFO] Engine: zero_stage=3 dp_world=1 batch=6\n"}
+_WRAPPER_COMPLETED = {
+    "n": 5, "cmd": "python bench.py", "rc": 0, "parsed": None,
+    "tail": ', "decode_tok_s": 1907.0, "decode_n_seqs": 128, '
+            '"serving_mixed_tok_s": 90.4, "serving_mixed_p50_step_ms": 113.9, "mfu": 0.58'}
+
+
+def test_benchdiff_wrapper_shape_and_trajectory_pair(tmp_path):
+    base = _write(tmp_path / "base.json", _WRAPPER_TIMED_OUT)
+    cand = _write(tmp_path / "cand.json", _WRAPPER_COMPLETED)
+    rec = load_bench(cand)
     assert rec["metrics"].get("serving_mixed_tok_s", 0) > 0
-    # r04 timed out (rc=124, log-only tail): zero metrics, all-missing
-    # verdicts, and the committed-trajectory gate stays green
-    assert load_bench(r04)["metrics"] == {}
-    assert benchdiff_main([r04, r05, "--policy",
+    # a timed-out base (rc=124, log-only tail): zero metrics, all-missing
+    # verdicts, and the trajectory gate stays green under the repo's policy
+    assert load_bench(base)["metrics"] == {}
+    assert benchdiff_main([base, cand, "--policy",
                            os.path.join(REPO_ROOT, "benchtrack.json")]) == 0

@@ -1,0 +1,166 @@
+"""The one file that asks the chip's compiler: the kernels of the main path,
+compiled for a described (not attached) v5e at the shapes ``chip_smoke.py``
+runs — Mistral-7B heads (H=32, KV=8, Dh=128), window 4096.  About 2 s a case,
+no chip time.  What interpret mode cannot show, this does: tiling, scalar and
+vector memory limits, a kernel the compiler refuses.
+
+The topology is described inside a module-scoped fixture, never at import (only
+one process may load the TPU's library, and every xdist worker imports every
+test file); compilation happens in the test's own process, with the persistent
+compilation cache off around it (a described compile is written to the cache
+but cannot be read back without a chip).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops._pallas import kernel_calls
+
+H, KV, DH, WINDOW = 32, 8, 128, 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def _cache_off():
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip(one_chip, _cache_off, monkeypatch):
+    """``shape, dtype -> ShapeDtypeStruct`` on the described chip, with the
+    kernels' dispatch steered to Pallas: ``use_pallas()`` asks
+    ``jax.default_backend()``, which still sees the CPU here."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def compile_and_count(fn, *avals):
+    compiled = jax.jit(fn).lower(*avals).compile()
+    return kernel_calls(compiled.as_text())
+
+
+def paged_avals(chip, n, t, block, maxb, num_blocks=256, dh=DH, pool_dtype=jnp.bfloat16):
+    return (chip((n, t, H, dh), jnp.bfloat16),
+            chip((num_blocks, KV, block, dh), pool_dtype),
+            chip((num_blocks, KV, block, dh), pool_dtype),
+            chip((n, maxb), jnp.int32), chip((n, ), jnp.int32),
+            chip((n, ), jnp.int32), chip((n, ), jnp.int32))
+
+
+@pytest.mark.parametrize("n,t,block,maxb,window", [
+    pytest.param(32, 1, 128, 40, WINDOW, id="decode-T1-page128"),
+    pytest.param(32, 1, 16, 320, WINDOW, id="decode-T1-page16"),
+    pytest.param(8, 256, 128, 40, WINDOW, id="chunked-prefill-T256"),
+    pytest.param(32, 9, 128, 40, WINDOW, id="spec-verify-T9"),
+    pytest.param(32, 1, 128, 40, None, id="decode-no-window"),
+])
+def test_paged_attention_compiles(chip, n, t, block, maxb, window):
+    from deepspeed_tpu.ops.attention.paged import paged_attention
+
+    def fn(q, k, v, tables, lengths, start, n_tok):
+        return paged_attention(q, k, v, tables, lengths, start, n_tok,
+                               block_size=block, window=window)
+
+    assert compile_and_count(fn, *paged_avals(chip, n, t, block, maxb)) == {"paged_attention": 1}
+
+
+def test_paged_attention_alibi_compiles(chip):
+    from deepspeed_tpu.ops.attention.paged import paged_attention
+
+    def fn(q, k, v, tables, lengths, start, n_tok, slopes):
+        return paged_attention(q, k, v, tables, lengths, start, n_tok,
+                               block_size=128, alibi_slopes=slopes)
+
+    avals = paged_avals(chip, 32, 1, 128, 40) + (chip((H, ), jnp.float32), )
+    assert compile_and_count(fn, *avals) == {"paged_attention": 1}
+
+
+def test_oversized_block_table_is_a_readable_error(chip):
+    """[512, 512] int32 is what the compiler answers with "Ran out of memory in
+    memory space smem. Used 1.01M of 1.00M": 128 sequences of 32k context at
+    the 16-token page.  The check fires before the compiler is asked."""
+    from deepspeed_tpu.ops.attention.paged import check_block_table_fits, paged_attention
+
+    def fn(q, k, v, tables, lengths, start, n_tok):
+        return paged_attention(q, k, v, tables, lengths, start, n_tok, block_size=16)
+
+    with pytest.raises(ValueError, match=r"block table \[512, 512\].*scalar memory"):
+        jax.jit(fn).lower(*paged_avals(chip, 512, 1, 16, 512))
+    # the bound is the compiler's: these two it takes, as the check says
+    check_block_table_fits(512, 384)
+    check_block_table_fits(1000, 256)
+    assert compile_and_count(fn, *paged_avals(chip, 512, 1, 16, 384)) == {"paged_attention": 1}
+
+
+def flash_avals(chip, batch, seq):
+    return (chip((batch, seq, H, DH), jnp.bfloat16), chip((batch, seq, KV, DH), jnp.bfloat16),
+            chip((batch, seq, KV, DH), jnp.bfloat16))
+
+
+def test_flash_forward_compiles_at_2k(chip):
+    from deepspeed_tpu.ops.attention.flash import flash_attention
+    calls = compile_and_count(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                              *flash_avals(chip, 2, 2048))
+    assert calls == {"flash_attention_fwd": 1}
+
+
+def test_flash_forward_backward_compiles_at_2k(chip):
+    from deepspeed_tpu.ops.attention.flash import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    calls = compile_and_count(jax.grad(loss, argnums=(0, 1, 2)), *flash_avals(chip, 2, 2048))
+    assert calls == {"flash_attention_fwd": 1, "flash_attention_bwd_dkv": 1,
+                     "flash_attention_bwd_dq": 1}
+
+
+def test_mistral_training_attention_reaches_flash(chip):
+    """seq <= window: the window mask is the causal mask, and the Mistral
+    training path must land on the flash kernel, not on a dense mask."""
+    from deepspeed_tpu.models import mistral
+    attn = mistral.windowed_attention(WINDOW)
+    assert compile_and_count(attn, *flash_avals(chip, 2, 2048)) == {"flash_attention_fwd": 1}
+    # past the window the dense mask is right, and no kernel takes it
+    assert compile_and_count(attn, *flash_avals(chip, 1, WINDOW + 128)) == {}
+
+
+def test_fused_adamw_flat_compiles(chip):
+    from deepspeed_tpu.ops.adam.fused_adam import fused_adamw_flat
+    n = 1 << 26  # one stacked 4096 x 14336 FFN leaf is 2^25.8 elements
+
+    def fn(p, m, v, g):
+        return fused_adamw_flat(p, m, v, g, lr=1e-4, step=3)
+
+    calls = compile_and_count(fn, chip((n, ), jnp.float32), chip((n, ), jnp.float32),
+                              chip((n, ), jnp.float32), chip((n, ), jnp.bfloat16))
+    assert calls == {"fused_adamw_kernel": 1}
+
+
+def test_quantize_int8_compiles(chip):
+    from deepspeed_tpu.ops.quantizer.quantize import quantize_int8
+    calls = compile_and_count(lambda x: quantize_int8(x)[:2], chip((4096, 14336), jnp.bfloat16))
+    assert sum(calls.values()) == 1

@@ -21,6 +21,19 @@ def test_leg_error_keying():
     assert bench._leg("ok", lambda: {"x": 1}) == {"x": 1}
 
 
+def test_leg_that_raises_fails_the_run(monkeypatch):
+    """The message is kept for the artifact, and main() exits non-zero on it."""
+    monkeypatch.setattr(bench, "_FAILED_LEGS", [])
+
+    def boom():
+        raise RuntimeError("kaput")
+
+    bench._leg("fine", lambda: {"x": 1})
+    assert bench._FAILED_LEGS == []
+    bench._leg("myleg", boom)
+    assert bench._FAILED_LEGS == ["myleg"]
+
+
 def test_artifact_shape_and_mfu_extraction():
     line = bench._artifact({"mfu": 0.5, "foo": 1})
     d = json.loads(line)

@@ -1,7 +1,6 @@
-"""compat shim tests: both resolution branches of every shimmed symbol
-(new-name present / old-name present) via module monkeypatching — no jax
-upgrade needed — plus the signature-normalizing wrappers and the capability
-probes."""
+"""compat tests: every registered symbol resolves to the one spelling the
+installed jax ships, the resolver's errors name their remedy, and the exports
+run for real.  There is no second candidate, fallback or probe to test."""
 # dslint: disable-file=direct-shimmed-import  # the shim's own tests reference the banned spellings by design
 
 import importlib
@@ -28,41 +27,31 @@ class TestResolution:
             assert obj is not None
             assert compat.resolved_source(name) in compat.SHIMMED_SYMBOLS[name]
 
-    def test_new_name_branch_wins_when_present(self, monkeypatch):
+    def test_every_symbol_has_exactly_one_spelling_and_it_is_jax(self):
+        # code for the one installation there is: no second candidate, and no
+        # candidate inside this package (a reimplementation for another jax)
+        for name, candidates in compat.SHIMMED_SYMBOLS.items():
+            assert len(candidates) == 1, (name, candidates)
+            assert candidates[0].startswith("jax"), (name, candidates)
+
+    def test_exports_are_the_jax_objects_themselves(self):
+        pltpu = importlib.import_module("jax.experimental.pallas.tpu")
+        assert compat.shard_map is jax.shard_map
+        assert compat.CompilerParams is pltpu.CompilerParams
+        assert compat.axis_size is jax.lax.axis_size
+        assert compat.Space is jax.memory.Space
+
+    def test_nothing_for_another_jax_is_left(self):
+        with pytest.raises(ImportError):
+            importlib.import_module("deepspeed_tpu.compat._fallbacks")
+        for gone in ("supports_partial_manual", "ensure_cpu_multiprocess_collectives"):
+            assert not hasattr(compat, gone)
+
+    def test_registered_spelling_is_what_resolves(self, monkeypatch):
         sentinel = object()
-        # this container's jax predates top-level jax.shard_map — grafting it
-        # on exercises the new-name branch without a jax upgrade
-        monkeypatch.setattr(jax, "shard_map", sentinel, raising=False)
+        monkeypatch.setattr(jax, "shard_map", sentinel)
         assert compat.resolve_symbol("shard_map", refresh=True) is sentinel
         assert compat.resolved_source("shard_map") == "jax:shard_map"
-
-    def test_old_name_branch_when_new_absent(self):
-        # stock jax 0.4.x: no jax.shard_map -> the experimental path resolves
-        if hasattr(jax, "shard_map"):
-            pytest.skip("this jax ships top-level shard_map")
-        impl = compat.resolve_symbol("shard_map", refresh=True)
-        legacy = importlib.import_module("jax.experimental.shard_map")
-        assert impl is legacy.shard_map
-        assert compat.resolved_source("shard_map") == \
-            "jax.experimental.shard_map:shard_map"
-
-    def test_compiler_params_both_branches(self, monkeypatch):
-        pltpu = importlib.import_module("jax.experimental.pallas.tpu")
-        sentinel = type("NewCompilerParams", (), {})
-        monkeypatch.setattr(pltpu, "CompilerParams", sentinel, raising=False)
-        assert compat.resolve_symbol("CompilerParams", refresh=True) is sentinel
-        monkeypatch.delattr(pltpu, "CompilerParams", raising=False)
-        old = compat.resolve_symbol("CompilerParams", refresh=True)
-        assert old is pltpu.TPUCompilerParams
-
-    def test_axis_size_prefers_native_then_falls_back(self, monkeypatch):
-        sentinel = object()
-        monkeypatch.setattr(jax.lax, "axis_size", sentinel, raising=False)
-        assert compat.resolve_symbol("axis_size", refresh=True) is sentinel
-        monkeypatch.delattr(jax.lax, "axis_size", raising=False)
-        from deepspeed_tpu.compat import _fallbacks
-        assert compat.resolve_symbol("axis_size", refresh=True) is \
-            _fallbacks.axis_size
 
     def test_unknown_symbol_raises(self):
         with pytest.raises(compat.CompatResolutionError, match="not a shimmed"):
@@ -83,82 +72,9 @@ class TestResolution:
         assert compat.resolve_symbol("shard_map", refresh=True) is not first
 
 
-# ------------------------------------------------- shard_map wrapper drift
-def _fake_new_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-                        axis_names=frozenset()):
-    return ("new", check_vma, set(axis_names))
-
-
-def _fake_old_shard_map(f, mesh, in_specs, out_specs, check_rep=True,
-                        auto=frozenset()):
-    return ("old", check_rep, set(auto))
-
-
-class _FakeMesh:
-    axis_names = ("data", "fsdp", "tensor")
-
-    def __init__(self, sizes):
-        self.shape = dict(sizes)
-
-
-class TestShardMapWrapper:
-    def _bind(self, impl, spec):
-        compat._cache["shard_map"] = (impl, spec)
-
-    def test_check_vma_passes_through_on_new_impl(self):
-        self._bind(_fake_new_shard_map, "jax:shard_map")
-        kind, flag, _ = compat.shard_map(None, mesh=None, in_specs=(),
-                                         out_specs=(), check_vma=False)
-        assert (kind, flag) == ("new", False)
-
-    def test_check_vma_translates_to_check_rep_on_old_impl(self):
-        self._bind(_fake_old_shard_map, "jax.experimental.shard_map:shard_map")
-        kind, flag, _ = compat.shard_map(None, mesh=None, in_specs=(),
-                                         out_specs=(), check_vma=False)
-        assert (kind, flag) == ("old", False)
-
-    def test_check_rep_spelling_still_accepted_both_ways(self):
-        self._bind(_fake_new_shard_map, "jax:shard_map")
-        kind, flag, _ = compat.shard_map(None, mesh=None, in_specs=(),
-                                         out_specs=(), check_rep=False)
-        assert (kind, flag) == ("new", False)
-        self._bind(_fake_old_shard_map, "jax.experimental.shard_map:shard_map")
-        kind, flag, _ = compat.shard_map(None, mesh=None, in_specs=(),
-                                         out_specs=(), check_rep=False)
-        assert (kind, flag) == ("old", False)
-
-    def test_axis_names_forwarded_on_new_impl(self):
-        self._bind(_fake_new_shard_map, "jax:shard_map")
-        kind, _, names = compat.shard_map(None, mesh=_FakeMesh({"data": 2}),
-                                          in_specs=(), out_specs=(),
-                                          axis_names={"data"})
-        assert (kind, names) == ("new", {"data"})
-
-    def test_axis_names_with_only_trivial_leftovers_runs_fully_manual(self):
-        # size-1 leftover axes are manual==auto; the old impl gets auto={} --
-        # i.e. fully manual, which is exactly equivalent
-        self._bind(_fake_old_shard_map, "jax.experimental.shard_map:shard_map")
-        mesh = _FakeMesh({"data": 4, "fsdp": 1, "tensor": 1})
-        kind, _, auto = compat.shard_map(None, mesh=mesh, in_specs=(),
-                                         out_specs=(), axis_names={"data"})
-        assert (kind, auto) == ("old", set())
-
-    def test_partial_manual_refused_on_old_impl(self):
-        # real auto axes on the old impl would hard-ABORT in XLA's SPMD
-        # partitioner; the wrapper must fail as a debuggable Python error
-        self._bind(_fake_old_shard_map, "jax.experimental.shard_map:shard_map")
-        mesh = _FakeMesh({"data": 2, "fsdp": 4, "tensor": 1})
-        with pytest.raises(NotImplementedError, match="supports_partial_manual"):
-            compat.shard_map(None, mesh=mesh, in_specs=(), out_specs=(),
-                             axis_names={"data"})
-
-    def test_supports_partial_manual_tracks_impl(self):
-        self._bind(_fake_new_shard_map, "jax:shard_map")
-        assert compat.supports_partial_manual()
-        self._bind(_fake_old_shard_map, "jax.experimental.shard_map:shard_map")
-        assert not compat.supports_partial_manual()
-
-    def test_wrapper_runs_for_real_on_this_jax(self):
+# ------------------------------------------------------------ the exports
+class TestExports:
+    def test_shard_map_runs_for_real_on_this_jax(self):
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:1]), ("data", ))
@@ -167,21 +83,6 @@ class TestShardMapWrapper:
         np.testing.assert_array_equal(np.asarray(fn(jnp.arange(4.0))),
                                       [0.0, 2.0, 4.0, 6.0])
 
-
-# ------------------------------------------------------------ other shims
-class TestOtherShims:
-    def test_axis_size_fallback_matches_axis_semantics(self):
-        import jax.numpy as jnp
-        from jax.sharding import Mesh, PartitionSpec as P
-        from deepspeed_tpu.compat import _fallbacks
-        mesh = Mesh(np.array(jax.devices()[:1]), ("data", ))
-
-        def body(x):
-            return x * _fallbacks.axis_size("data")
-
-        fn = compat.shard_map(body, mesh=mesh, in_specs=P("data"),
-                              out_specs=P("data"), check_vma=False)
-        np.testing.assert_array_equal(np.asarray(fn(jnp.ones(2))), [1.0, 1.0])
 
     def test_space_members_are_device_put_targets_inside_jit(self):
         import jax.numpy as jnp
@@ -198,36 +99,3 @@ class TestOtherShims:
         p = compat.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
         assert tuple(p.dimension_semantics) == ("parallel", "arbitrary")
-
-
-# ---------------------------------------- cpu multiprocess collectives knob
-class TestEnsureCpuMultiprocessCollectives:
-    def test_selects_gloo_when_unset(self, monkeypatch):
-        calls = []
-        monkeypatch.setattr(jax.config, "_read", lambda name: "none")
-        monkeypatch.setattr(jax.config, "update",
-                            lambda name, val: calls.append((name, val)))
-        assert compat.ensure_cpu_multiprocess_collectives()
-        assert calls == [("jax_cpu_collectives_implementation", "gloo")]
-
-    def test_respects_explicit_choice(self, monkeypatch):
-        calls = []
-        monkeypatch.setattr(jax.config, "_read", lambda name: "mpi")
-        monkeypatch.setattr(jax.config, "update",
-                            lambda name, val: calls.append((name, val)))
-        assert compat.ensure_cpu_multiprocess_collectives()
-        assert calls == []
-
-    def test_retired_option_means_new_jax_defaults_are_fine(self, monkeypatch):
-        def boom(name):
-            raise AttributeError(name)
-        monkeypatch.setattr(jax.config, "_read", boom)
-        assert compat.ensure_cpu_multiprocess_collectives()
-
-    def test_reports_failure_when_gloo_unavailable(self, monkeypatch):
-        monkeypatch.setattr(jax.config, "_read", lambda name: "none")
-
-        def refuse(name, val):
-            raise RuntimeError("no gloo in this jaxlib")
-        monkeypatch.setattr(jax.config, "update", refuse)
-        assert not compat.ensure_cpu_multiprocess_collectives()
