@@ -1,0 +1,236 @@
+"""The harness: the command end to end at its rehearsal size, the form of
+``BENCHMARK.json``, and that a cell, a configuration, a traffic mix and a
+per-layer metric added as new files are found without editing any file."""
+
+import importlib
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from chipbench.generators.waves import Traffic, quantile_lengths
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+CELLS = {w["name"]: w for w in BENCH["workloads"]}
+END_TO_END = {m["name"]: m for m in BENCH["end_to_end"]}
+
+
+def data(*parts):
+    with open(os.path.join(ROOT, "chipbench", *parts)) as f:
+        return json.load(f)
+
+
+def reports(metric, cell):
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def run_command(root, *argv, **env):
+    return subprocess.run([sys.executable, os.path.join(root, "chipbench", "run.py"), *argv],
+                          capture_output=True, text=True, timeout=900,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT, **env})
+
+
+# ---------------------------------------------------------------- the command
+@pytest.mark.parametrize("cell,trace", [("serve.decode-heavy", "0"), ("serve.long-prompt", "1"),
+                                        ("train.zero3-fsdp4", "1")])
+def test_rehearsal_walks_the_cell_and_is_never_a_result(rehearse, cell, trace):
+    got = rehearse("--workload", cell, "--seed", str(2 ** 31 + 12345), "--seconds", "0",
+                   "--trace", trace)
+    assert got.code == 3
+    assert '"correct": true' not in got.out
+    assert got.line["correct"] is False and got.line["would_be_correct"] is True
+    assert got.line["failed"] == 0 and got.line["attempted"] > 0
+    wanted = [m["name"] for m in (BENCH["per_layer"] if trace == "1" else BENCH["end_to_end"])
+              if reports(m, cell)]
+    if trace == "0":
+        assert sorted(got.line["metrics"]) == sorted(wanted)
+        assert all(v["value"] > 0 for v in got.line["metrics"].values())
+    else:  # a CPU trace has no accelerator plane: only the counters can be read
+        assert set(got.line["metrics"]) <= set(wanted)
+        assert any(m["source"] == "program_counter" for m in BENCH["per_layer"]
+                   if m["name"] in got.line["metrics"]) or cell.startswith("train")
+
+
+def test_without_a_chip_there_is_no_result():
+    done = run_command(ROOT, "--workload", "serve.chat-burst", "--seed", "1", "--seconds", "1")
+    assert done.returncode == 2
+    assert "refused" in done.stderr and '"correct"' not in done.stdout
+
+
+def test_unknown_workload_is_refused():
+    done = run_command(ROOT, "--workload", "no.such-cell")
+    assert done.returncode == 2 and '"correct"' not in done.stdout
+
+
+# ------------------------------------------------------------ BENCHMARK.json
+def test_top_level_form():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads",
+                          "end_to_end", "per_layer"}
+    assert 1 <= BENCH["run_seconds"] <= 51
+    assert "setup_s" in END_TO_END and END_TO_END["setup_s"]["bound"] <= 0.1
+    assert all(0.01 <= m["bound"] <= 0.1 for m in BENCH["end_to_end"])
+    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
+
+
+def test_names_and_units_hold_only_what_the_driver_takes():
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        names = [e["name"] for e in BENCH[group]]
+        assert len(names) == len(set(names))
+        assert all(NAME.match(n) for n in names), names
+    for w in BENCH["workloads"]:
+        assert NAME.match(w["config"]) and NAME.match(w["traffic"]) and w["chips"] in (1, 4)
+        assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"] and "\t" not in w["why"]
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
+        assert set(m) <= {"name", "unit", "better", "bound", "source", "layer", "moves", "workloads"}
+    assert all(m["source"] in ("host_clock", "device_trace") for m in BENCH["end_to_end"])
+    for c in BENCH["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"} and len(c["why"]) <= 200
+        assert all(NAME.match(k) for k in c["reduced"])
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(pairs) == len(set(pairs))
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_every_cell_resolves_to_files_that_exist(cell):
+    w = CELLS[cell]
+    config = next(c for c in BENCH["configs"] if c["name"] == w["config"])
+    assert config["file"] == f"chipbench/configs/{w['config']}.json"
+    spec = data("configs", w["config"] + ".json")
+    assert spec["source"] == config["source"]
+    assert sorted(spec["reduced"]) == sorted(config["reduced"])
+    traffic = data("traffic", w["traffic"] + ".json")
+    for kind, name in (("entries", spec["entry"]), ("references", spec["reference"]),
+                       ("generators", traffic["generator"])):
+        assert os.path.exists(os.path.join(ROOT, "chipbench", kind, name + ".py")), (kind, name)
+    mine = [m for m in BENCH["end_to_end"] if reports(m, cell)]
+    assert "setup_s" in [m["name"] for m in mine] and len(mine) >= 2
+    assert any(reports(m, cell) for m in BENCH["per_layer"])
+
+
+def test_every_configuration_is_used_and_widths_are_published():
+    used = {w["config"] for w in BENCH["workloads"]}
+    assert used == {c["name"] for c in BENCH["configs"]}
+    published = {"hidden_size": 4096, "intermediate_size": 14336, "num_attention_heads": 32,
+                 "num_key_value_heads": 8, "vocab_size": 32000, "sliding_window": 4096,
+                 "max_position_embeddings": 32768, "rope_theta": 10000.0, "rms_norm_eps": 1e-05}
+    for c in BENCH["configs"]:
+        spec = data("configs", c["name"] + ".json")
+        assert {k: spec[k] for k in published} == published
+        assert c["reduced"] == ["num_hidden_layers"] and spec["num_hidden_layers"] < 32
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]])
+def test_every_metric_has_its_file_and_its_reader(metric):
+    entry = next(m for m in BENCH["end_to_end"] + BENCH["per_layer"] if m["name"] == metric)
+    spec = data("metrics", metric + ".json")
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert spec.get(key) == entry.get(key), key
+    reader = importlib.import_module(f"chipbench.readers.{spec['reader']}")
+    assert callable(reader.read)
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
+def test_a_per_layer_metric_moves_a_metric_its_cells_report(metric):
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    moved = END_TO_END[entry["moves"]]
+    cells = entry.get("workloads") or [c for c in CELLS if reports(moved, c)]
+    assert cells and all(c in CELLS and reports(moved, c) for c in cells)
+    if "roofline" in metric or "mfu" in metric:
+        assert entry["unit"] == "%"
+    layers = {m["layer"] for m in BENCH["per_layer"]}
+    assert all("\n" not in l and len(l) <= 200 for l in layers)
+
+
+# ------------------------------------------------------------------- traffic
+def test_same_seed_same_prompts_and_every_seed_the_same_work():
+    params = data("traffic", "chat-burst.json")["params"]
+    a, b, c = (Traffic(params, s, 32000) for s in (2 ** 31 + 7, 2 ** 31 + 7, 5))
+    assert a.lengths == b.lengths and a.wave(3) == b.wave(3)
+    assert a.wave(1) != a.wave(2) and a.wave(1) != c.wave(1)
+    assert a.lengths == c.lengths  # the seed draws the tokens, never the work
+    assert len(a.lengths) == 32 and 16 <= min(a.lengths) and 1024 < max(a.lengths) <= 2048
+    assert [len(p) for p in a.wave(1)] == a.lengths
+    lengths = quantile_lengths(data("traffic", "long-prompt.json")["params"]["prompt_lengths"], 8)
+    assert sum(n > 4096 for n in lengths) == 2 and max(lengths) + 32 <= 40 * 128
+
+
+@pytest.mark.parametrize("mix", ["chat-burst", "decode-heavy", "long-prompt"])
+def test_the_order_of_a_wave_is_the_traffic_files_own(mix):
+    params = data("traffic", mix + ".json")["params"]
+    here, there = Traffic(params, 1, 32000), Traffic({**params, "order_seed": 1}, 1, 32000)
+    assert sorted(here.lengths) == sorted(there.lengths) and here.lengths != there.lengths
+    with pytest.raises(KeyError, match="order_seed"):  # a mix says which order ran
+        Traffic({k: v for k, v in params.items() if k != "order_seed"}, 1, 32000)
+
+
+def test_the_pool_holds_what_each_wave_asks_for():
+    engine = data("configs", "mistral-7b-serve-16l.json")["engine"]
+    pool = engine["num_blocks"] * engine["block_size"]
+    for name in ("decode-heavy", "long-prompt"):  # waves admitted whole
+        p = data("traffic", name + ".json")["params"]
+        lengths = quantile_lengths(p["prompt_lengths"], p["requests_per_wave"])
+        assert sum(lengths) + len(lengths) * p["max_new_tokens"] <= pool
+
+
+# ----------------------------------------------- new files, no file edited
+def test_a_new_cell_config_mix_and_metric_are_found_without_an_edit(tmp_path):
+    root = str(tmp_path / "copy")
+    shutil.copytree(os.path.join(ROOT, "chipbench"), os.path.join(root, "chipbench"),
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    before = {p: open(os.path.join(dp, p), "rb").read()
+              for dp, _, files in os.walk(os.path.join(root, "chipbench")) for p in files}
+    bench = json.loads(json.dumps(BENCH))
+
+    config = data("configs", "mistral-7b-serve-16l.json")
+    config["rehearsal"]["sizes"]["num_hidden_layers"] = 1
+    mix = {"generator": "waves", "params": {"requests_per_wave": 16, "max_new_tokens": 32,
+           "order_seed": 7, "prompt_lengths": {"dist": "uniform", "min": 64, "max": 128}}}
+    metric = {"layer": "serve loop (engine_v2._serve_loop, fastpath.py)", "unit": "count",
+              "better": "lower", "source": "program_counter", "moves": "serve_tok_s",
+              "reader": "loop_iterations"}
+    reader = 'def read(run):\n    return run.counters["loop_iterations"]\n'
+    for path, text in (("configs/new-config.json", json.dumps(config)),
+                       ("traffic/new-mix.json", json.dumps(mix)),
+                       ("metrics/serve.loop_iterations.json", json.dumps(metric)),
+                       ("readers/loop_iterations.py", reader)):
+        with open(os.path.join(root, "chipbench", path), "w") as f:
+            f.write(text)
+    bench["configs"].append({"name": "new-config", "source": config["source"], "why": "a test",
+                             "file": "chipbench/configs/new-config.json",
+                             "reduced": ["num_hidden_layers"]})
+    bench["workloads"].append({"name": "serve.new-cell", "config": "new-config",
+                               "traffic": "new-mix", "chips": 1, "why": "a test"})
+    bench["per_layer"].append({"name": "serve.loop_iterations", "unit": "count",
+                               "better": "lower", "source": "program_counter",
+                               "layer": metric["layer"], "moves": "serve_tok_s",
+                               "workloads": ["serve.new-cell"]})
+    for m in bench["end_to_end"]:
+        if m["name"] == "serve_tok_s":
+            m["workloads"].append("serve.new-cell")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    done = run_command(root, "--workload", "serve.new-cell", "--seed", "3", "--seconds", "0",
+                       "--trace", "1", "--rehearse")
+    assert done.returncode == 3, done.stderr[-2000:]
+    line = [l for l in done.stdout.splitlines() if l.startswith("[rehearsal-not-a-result] ")][-1]
+    result = json.loads(line.split(" ", 1)[1])
+    assert result["metrics"]["serve.loop_iterations"]["value"] > 0
+    assert result["would_be_correct"] is True
+    assert "requests_per_wave=4" in done.stdout and "layers=1" in done.stdout
+    after = {p: open(os.path.join(dp, p), "rb").read()
+             for dp, _, files in os.walk(os.path.join(root, "chipbench"))
+             for p in files if "__pycache__" not in dp and os.sep + "out" not in dp}
+    assert all(after[p] == before[p] for p in before if p in after)
