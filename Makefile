@@ -142,9 +142,10 @@ elastic-smoke:
 
 # serving perf observatory (ISSUE 16): 3-wave mixed-arrival serve with the
 # observatory ON — every phase family non-empty with spans summing to the
-# iteration wall, zero warm recompiles, full roofline cost coverage, the new
-# serving_phase/compiles/recompiles/roofline families strict-parsing off a
-# live /metrics scrape, and tokens + ServeCounters byte-identical vs off
+# iteration wall, zero warm recompiles, slot counters (live <= computed), the
+# serving_phase/compiles/recompiles families and the slot counters
+# strict-parsing off a live /metrics scrape, and tokens + ServeCounters
+# byte-identical vs off
 perf-smoke:
 	JAX_PLATFORMS=cpu $(PY) run_tests.py --perf-smoke
 

@@ -759,8 +759,7 @@ def measure_serving_mixed(on_tpu: bool):
                                     # tracer's streaming histograms
                                     "serving_tracing": {"enabled": True},
                                     # perf observatory (ISSUE 16): phase
-                                    # attribution + live roofline for the
-                                    # serving figure below
+                                    # attribution for the serving figure below
                                     "serving_perf": {"enabled": True}},
                             num_blocks=num_blocks, block_size=block_size,
                             max_blocks_per_seq=maxb, token_budget=budget,
@@ -773,11 +772,9 @@ def measure_serving_mixed(on_tpu: bool):
                 n_req // 4 + 12: list(range(3 * n_req // 4, n_req))}
     _run_serving_scenario(eng, prompts, arrivals, max_new)  # warm: compile buckets
     # isolate the timed pass's SLO histograms from the warm pass's
-    # compile-stall-polluted TTFT samples; same for the phase spans and the
-    # roofline's dispatch accumulators (its per-bucket cost table survives)
+    # compile-stall-polluted TTFT samples; same for the phase spans
     eng.tracer.reset_histograms()
     eng.phase_profiler.reset()
-    eng.roofline.reset()
     tokens, dt, lats, hit_stall, link = _run_serving_scenario(eng, prompts, arrivals, max_new)
     if not lats:
         return {"serving_mixed": "no tokens emitted"}
@@ -787,23 +784,9 @@ def measure_serving_mixed(on_tpu: bool):
     # same discipline for the KV-pool report: capture it before the journal
     # A/B re-runs the scenario on this engine three more times
     kv_report = _kv_report("serving_mixed", eng)
-    # perf observatory (ISSUE 16): roofline over exactly the timed pass —
-    # achieved HBM stream vs spec, live, from cost_analysis captured at the
-    # compile seams.  The denominator is the timed pass's measured elapsed
-    # (same wall serving_mixed_tok_s divides by), NOT the phase profiler's
-    # iteration wall: this scenario steers the engine step-wise through
-    # put/step/decode_burst rather than _serve_loop, so profiler iterations
-    # never begin here.  Sits alongside hbm_stream_fraction_of_spec (the
-    # synthetic-copy ceiling) to show how much of the streamable bandwidth
-    # the real serve loop touches.
-    roofline = eng.roofline.gauges(dt)
+    # perf observatory (ISSUE 16): the compile ledger's verdict on the timed
+    # pass.  (The cost_analysis roofline figures went in ISSUE 24.)
     perf_report = {
-        # 3 significant figures, not fixed decimals: the CPU tiny config
-        # achieves ~1e-7 to ~1e-5 of the TPU HBM spec and the figure must
-        # survive rounding everywhere
-        "serving_roofline_fraction": float(
-            f"{roofline['serving_roofline_fraction']:.3g}"),
-        "serving_hbm_bytes_per_token": round(roofline["serving_hbm_bytes_per_token"], 1),
         # a healthy steady-state pass recompiles nothing: warm recompiles
         # here are the runtime twin of dslint's recompile-risk rule firing
         "serving_warm_recompiles": int(eng.ledger.warm_total)}
@@ -878,8 +861,7 @@ def measure_serving_mixed(on_tpu: bool):
             # durability tax (ISSUE 8): tok/s with the request journal armed
             # vs off, same scenario (fsync_every=0; see comment above)
             "serving_mixed_journal_overhead_pct": journal_overhead_pct,
-            # perf observatory (ISSUE 16): live roofline of the timed pass
-            # (see capture comment above) + warm-recompile count
+            # perf observatory (ISSUE 16): warm-recompile count
             **perf_report,
             # KV-pool observability (ISSUE 12): fragmentation at end of the
             # timed pass, the counterfactual prefix-cache opportunity this

@@ -76,8 +76,8 @@ class InferenceConfig(ConfigModel):
     # inference/v2/ragged_manager.py PrefixCache (section defined in
     # runtime/config.py so train+serve configs share one spelling)
     serving_prefix_cache: ServingPrefixCacheConfig = Field(ServingPrefixCacheConfig)
-    # serving performance observatory: phase attribution + compile ledger +
-    # live roofline gauges — monitor/perf.py wired through the v2 serve loop
+    # serving performance observatory: phase attribution + compile ledger
+    # — monitor/perf.py wired through the v2 serve loop
     # (section defined in runtime/config.py so train+serve configs share one
     # spelling)
     serving_perf: ServingPerfConfig = Field(ServingPerfConfig)

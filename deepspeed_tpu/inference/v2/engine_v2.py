@@ -10,7 +10,6 @@ one per ragged shape — the XLA analog of the reference's CUDA-graph-free
 ragged kernels.
 """
 
-import contextlib
 import inspect
 import json
 import os
@@ -23,7 +22,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from ...compat import shard_map
-from ...monitor.perf import CompileLedger, RooflineModel, StepPhaseProfiler
+from ...monitor.perf import PHASES, CompileLedger, StepPhaseProfiler
 from ...monitor.tracing import RequestTracer
 from ...parallel.mesh import TENSOR_AXIS, MeshTopology
 from ...runtime.heartbeat import (HEARTBEAT_DIR_ENV, HEARTBEAT_INTERVAL_ENV,
@@ -261,17 +260,17 @@ class InferenceEngineV2:
         # ≤1-sync loop drives the shard_mapped forward unchanged.
         self.fastpath = self.config.serving_fastpath
         self.counters = ServeCounters()
-        # serving performance observatory (ISSUE 16): the compile ledger and
-        # roofline cost capture are always on (no clock reads, no device
-        # work) and the ledger is the single source of truth behind
-        # counters.compiles; the phase profiler reads the injectable clock at
+        # serving performance observatory (ISSUE 16): the compile ledger is
+        # always on (no clock reads, no device work) and is the single source
+        # of truth behind counters.compiles; the slot counters (ISSUE 24) are
+        # host integers bumped where a program is launched, always on too;
+        # the phase profiler reads the injectable clock at
         # phase boundaries and is gated on serving_perf.enabled so the off
         # path performs zero extra clock reads (byte-identical FakeClock runs)
         self.perf_cfg = self.config.serving_perf
         self.ledger = CompileLedger(self.counters, tracer=self.tracer)
         self.phase_profiler = StepPhaseProfiler(self.perf_cfg, clock=self._clock,
                                                 tracer=self.tracer)
-        self.roofline = RooflineModel(self.perf_cfg)
         self.batch_state = DeviceBatchState(
             self.counters, mesh=self.topology.mesh if self.tp > 1 else None,
             ledger=self.ledger)
@@ -419,7 +418,10 @@ class InferenceEngineV2:
             self._table_slack = 0
 
     # ------------------------------------------------------------------- step
-    def _build_fwd_jit(self):
+    def _build_fwd_jit(self, n: int, t: int, b: int):
+        """The ragged forward for one bucket, jitted under the bucket's name
+        (``fwd_n32_t256_b20``): the device trace's program line and the
+        compile ledger then say which bucket ran."""
         model, cfg, bs = self.model, self.model_config, self.block_size
         if self.tp > 1:
             def fwd(params, kv, tokens, n_tokens, start_pos, tables):
@@ -431,6 +433,7 @@ class InferenceEngineV2:
             def fwd(params, kv, tokens, n_tokens, start_pos, tables):
                 return model.forward_paged(cfg, params, tokens, n_tokens, start_pos,
                                            tables, kv, block_size=bs)
+        fwd.__name__ = f"fwd_n{n}_t{t}_b{b}"
         return jax.jit(fwd, donate_argnums=(1, ))  # dslint: disable=donation-after-use  # call-site contract: step() reassigns self.kv from the result in the same statement (the KV pool is donated so decode updates alias in place)
 
     def _compiled_fwd(self, n: int, t: int, b: int):
@@ -438,17 +441,15 @@ class InferenceEngineV2:
         if key not in self._fwd_cache:
             try:
                 # compile ahead-of-time even for buckets the prewarm missed:
-                # the ledger gets the real compile wall time and the roofline
-                # gets cost_analysis coverage for EVERY dispatched bucket,
-                # instead of only the prewarmed ones (ISSUE 16)
+                # the ledger gets the real compile wall time (ISSUE 16)
                 self._aot_compile_fwd(n, t, b, prewarmed=False)
             except Exception:
                 # AOT lowering can fail where plain jit works (backend
                 # quirks); serving must degrade to the lazy wrapper, not die
-                self._fwd_cache[key] = self._build_fwd_jit()
+                self._fwd_cache[key] = fwd = self._build_fwd_jit(n, t, b)
                 # lazy jit wrapper: XLA compiles at first dispatch, so the
                 # wall time shows up in the dispatch phase histogram instead
-                self.ledger.record("fwd", key)
+                self.ledger.record("fwd", key, name=fwd.__name__)
         return self._fwd_cache[key]
 
     def _aot_compile_fwd(self, n: int, t: int, b: int, *,
@@ -479,25 +480,13 @@ class InferenceEngineV2:
         # clock would shift FakeClock-driven deadline semantics with the
         # observatory on — the ledger must never perturb what it measures
         t0 = time.perf_counter()  # dslint: disable=raw-clock-in-serving  # genuinely wall-clock-only: measuring the synchronous XLA compile itself; reading the injectable clock here would shift FakeClock-driven deadline semantics with the observatory on
-        compiled = self._build_fwd_jit().lower(
+        fwd = self._build_fwd_jit(n, t, b)
+        self._fwd_cache[key] = fwd.lower(
             jax.tree_util.tree_map(abstract, self.params),
             jax.tree_util.tree_map(abstract, self.kv),
             ints((n, t)), ints((n, )), ints((n, )), ints((n, b))).compile()
-        self._fwd_cache[key] = compiled
         self.ledger.record("fwd", key, wall_s=time.perf_counter() - t0,  # dslint: disable=raw-clock-in-serving  # same stopwatch as t0 above — host compile duration, never the engine clock
-                           prewarmed=prewarmed)
-        if self.perf_cfg.capture_cost_analysis:
-            # the ONE seam holding a compiled executable: capture the
-            # compiler's own per-invocation cost numbers for the roofline
-            # (plain floats cross into monitor/perf.py — never a jax object)
-            try:
-                cost = compiled.cost_analysis()
-                if isinstance(cost, list):  # older jax returns [dict]
-                    cost = cost[0] if cost else {}
-                self.roofline.note_cost(key, float(cost.get("flops", 0.0)),
-                                        float(cost.get("bytes accessed", 0.0)))
-            except Exception:  # dslint: disable=silent-except  # cost analysis is best-effort: some backends/executables can't report costs, and the roofline must never break prewarm
-                pass
+                           prewarmed=prewarmed, name=fwd.__name__)
 
     def _cow_copy_block(self, src: int, dst: int) -> None:
         """Copy-on-write block duplication (ISSUE 13): copy one KV block's
@@ -508,7 +497,7 @@ class InferenceEngineV2:
         ([L, num_blocks, ...] — models/transformer.py), which this relies on."""
         fn = self._fwd_cache.get("cow_copy")
         if fn is None:
-            def copy(kv, pair):
+            def cow_copy(kv, pair):
                 return jax.tree_util.tree_map(
                     lambda leaf: leaf.at[:, pair[1]].set(leaf[:, pair[0]]), kv)
             if self.tp > 1:
@@ -517,9 +506,9 @@ class InferenceEngineV2:
                 # donated sharded pool aliases in place instead of degrading
                 # to a gather + single-device copy
                 kv_sh = jax.tree_util.tree_map(lambda leaf: leaf.sharding, self.kv)
-                fn = jax.jit(copy, donate_argnums=(0, ), out_shardings=kv_sh)
+                fn = jax.jit(cow_copy, donate_argnums=(0, ), out_shardings=kv_sh)
             else:
-                fn = jax.jit(copy, donate_argnums=(0, ))
+                fn = jax.jit(cow_copy, donate_argnums=(0, ))
             self._fwd_cache["cow_copy"] = fn
             self.ledger.record("cow_copy", "cow_copy")
         self.counters.dispatches += 1
@@ -583,7 +572,8 @@ class InferenceEngineV2:
         deferred = self._dispatch_step(greedy)
         if deferred is None:
             return {}
-        return deferred.patch(self.manager)
+        with self._phase_annotation("dispatch", "wait"):
+            return deferred.patch(self.manager)
 
     def _dispatch_step(self, greedy: bool) -> Optional[DeferredTokens]:
         """Fast-path step dispatch: incrementally scatter this step's deltas
@@ -611,31 +601,33 @@ class InferenceEngineV2:
         key = (n, t, b)
         rows = []
         feeds = []
-        tokens_run = 0
-        for i, c in enumerate(chunks):
-            seq = self.manager.seqs[c.uid]
-            sl = seq.tokens[seq.seen_tokens:seq.seen_tokens + c.n_tokens]
-            packed = np.zeros(3 + t + b, np.int32)
-            packed[0] = i
-            if c.n_tokens == 1 and sl[0] == PENDING_TOKEN:
-                # the input token is the previous step's sample, still on
-                # device: feed it device-side instead of waiting for it
-                if self._inflight is None or c.uid not in self._inflight.row_of:
-                    raise RuntimeError(f"uid {c.uid}: pending token scheduled with no "
-                                       f"in-flight step to feed it from")
-                feeds.append((i, self._inflight.row_of[c.uid]))
-                packed[1] = FED_SENTINEL
-            else:
-                packed[1:1 + len(sl)] = sl
-            packed[1 + t] = c.n_tokens
-            packed[2 + t] = seq.seen_tokens
-            packed[3 + t:] = self.manager.block_table_row(seq, width=b)
-            rows.append((i, packed))
-            tokens_run += c.n_tokens
-        slot = self.batch_state.update(key, rows, n_active=len(chunks),
-                                       trash_block=self.manager.trash_block)
-        if feeds:
-            self.batch_state.feed(key, self._inflight.toks_dev, feeds)
+        tokens_run = live_blocks = 0
+        with self._phase_annotation("scatter_upload"):
+            for i, c in enumerate(chunks):
+                seq = self.manager.seqs[c.uid]
+                sl = seq.tokens[seq.seen_tokens:seq.seen_tokens + c.n_tokens]
+                packed = np.zeros(3 + t + b, np.int32)
+                packed[0] = i
+                if c.n_tokens == 1 and sl[0] == PENDING_TOKEN:
+                    # the input token is the previous step's sample, still on
+                    # device: feed it device-side instead of waiting for it
+                    if self._inflight is None or c.uid not in self._inflight.row_of:
+                        raise RuntimeError(f"uid {c.uid}: pending token scheduled with no "
+                                           f"in-flight step to feed it from")
+                    feeds.append((i, self._inflight.row_of[c.uid]))
+                    packed[1] = FED_SENTINEL
+                else:
+                    packed[1:1 + len(sl)] = sl
+                packed[1 + t] = c.n_tokens
+                packed[2 + t] = seq.seen_tokens
+                packed[3 + t:] = self.manager.block_table_row(seq, width=b)
+                rows.append((i, packed))
+                tokens_run += c.n_tokens
+                live_blocks += len(seq.blocks)
+            slot = self.batch_state.update(key, rows, n_active=len(chunks),
+                                           trash_block=self.manager.trash_block)
+            if feeds:
+                self.batch_state.feed(key, self._inflight.toks_dev, feeds)
         self.phase_profiler.mark("scatter_upload")
         fwd = self._compiled_fwd(n, t, b)
         self.counters.dispatches += 1
@@ -648,7 +640,7 @@ class InferenceEngineV2:
         self.counters.dispatches += 1
         toks_dev, self._rng = pick(logits, slot.n_tokens, self._rng)
         self.phase_profiler.mark("dispatch")
-        self.roofline.note_dispatch(key, tokens_run)
+        self.counters.count_slots(n, t, b, tokens_run, live_blocks)
         emits = []
         row_of: Dict[int, int] = {}
         for i, c in enumerate(chunks):
@@ -690,6 +682,7 @@ class InferenceEngineV2:
         n_tokens = np.zeros((n, ), np.int32)
         start_pos = np.zeros((n, ), np.int32)
         tables = np.full((n, b), self.manager.trash_block, np.int32)
+        live_blocks = 0
         for i, c in enumerate(chunks):
             seq = self.manager.seqs[c.uid]
             sl = seq.tokens[seq.seen_tokens:seq.seen_tokens + c.n_tokens]
@@ -697,6 +690,7 @@ class InferenceEngineV2:
             n_tokens[i] = c.n_tokens
             start_pos[i] = seq.seen_tokens
             tables[i] = self.manager.block_table_row(seq, width=b)
+            live_blocks += len(seq.blocks)
 
         fwd = self._compiled_fwd(n, t, b)
         self.counters.dispatches += 2
@@ -709,7 +703,10 @@ class InferenceEngineV2:
                               jnp.asarray(start_pos), jnp.asarray(tables))
         pick = self._compiled_step_pick(n, greedy)
         toks_dev, self._rng = pick(logits, jnp.asarray(n_tokens), self._rng)
-        toks = materialize(toks_dev, self.counters)  # one sync: n sampled ints
+        tokens_run = int(n_tokens.sum())
+        self.counters.count_slots(n, t, b, tokens_run, live_blocks)
+        with self._phase_annotation("dispatch", "wait"):
+            toks = materialize(toks_dev, self.counters)  # one sync: n sampled ints
 
         out: Dict[int, int] = {}
         for i, c in enumerate(chunks):
@@ -728,7 +725,7 @@ class InferenceEngineV2:
             self.journal.note_token_map(out)
         self._kv_steps += 1
         self._refresh_kv()
-        self._emit_serving_gauges(tokens_run=int(n_tokens.sum()))
+        self._emit_serving_gauges(tokens_run=tokens_run)
         return out
 
     def _gauge_timestamp(self) -> Optional[float]:
@@ -848,7 +845,8 @@ class InferenceEngineV2:
     def _emit_serving_gauges(self, tokens_run: int) -> None:
         """Serving rates on top of the scheduler's per-step gauges: requests/s
         (retired-sequence rate) and tokens/s through the ragged forward."""
-        if self.telemetry is None:
+        record_gauges = getattr(self.telemetry, "record_gauges", None)
+        if record_gauges is None:  # no sink, or one that takes no gauges
             return
         c = self.counters
         gauges = {"live_seqs": float(len(self.manager.live_uids())),
@@ -895,39 +893,44 @@ class InferenceEngineV2:
         # SLO percentile gauges (ISSUE 6): ttft/tbt/e2e/queue_wait p50/p95/p99
         # from the tracer's streaming histograms ({} while tracing is off)
         gauges.update(self.tracer.gauge_fields())
-        # live roofline gauges (ISSUE 16): HBM bytes/token and achieved
-        # fractions of the HBM/FLOPs specs — meaningful rates need measured
-        # wall time, which only the enabled phase profiler accumulates
         if self.perf_cfg.enabled:
-            gauges.update(self.roofline.gauges(self.phase_profiler.wall_s))
             gauges["serving_warm_recompiles"] = float(self.ledger.warm_total)
-        rps = self.telemetry.rate("v2_completed_requests",
-                                  float(self.manager.completed_requests))
-        if rps is not None:
-            gauges["requests_per_sec"] = rps
         self._tokens_run_total = getattr(self, "_tokens_run_total", 0) + tokens_run
-        tps = self.telemetry.rate("v2_tokens_total", float(self._tokens_run_total))
-        if tps is not None:
-            gauges["tokens_per_sec"] = tps
-        self.telemetry.record_gauges(gauges, step=self.scheduler.steps,
-                                     prefix="Inference/Serving",
-                                     timestamp=self._gauge_timestamp())
+        rate = getattr(self.telemetry, "rate", None)
+        if rate is not None:
+            rps = rate("v2_completed_requests", float(self.manager.completed_requests))
+            if rps is not None:
+                gauges["requests_per_sec"] = rps
+            tps = rate("v2_tokens_total", float(self._tokens_run_total))
+            if tps is not None:
+                gauges["tokens_per_sec"] = tps
+        record_gauges(gauges, step=self.scheduler.steps, prefix="Inference/Serving",
+                      timestamp=self._gauge_timestamp())
 
-    def _phase_annotation(self, name: str):
-        """jax.profiler TraceAnnotation for one serve phase while a capture
-        window is open (ISSUE 16 satellite) — a nullcontext otherwise, so the
-        un-profiled serve loop pays one attribute check per phase."""
-        t = self.telemetry
-        if t is not None and t.tracing:
-            return t.annotation(name)
-        return contextlib.nullcontext()
+    def _tell(self, method: str, *args, **kwargs) -> None:
+        """Call ``telemetry.<method>`` where the sink has it: a ``telemetry=``
+        object implements only what it wants to hear (one that keeps request
+        records is ``record_trace`` and nothing else)."""
+        fn = getattr(self.telemetry, method, None)
+        if fn is not None:
+            fn(*args, **kwargs)
+
+    def _phase_annotation(self, phase: str, part: Optional[str] = None):
+        """``jax.profiler`` span for one serve phase (a name of
+        ``monitor.perf.PHASES``, the list ``StepPhaseProfiler.mark`` takes
+        too) or, nested inside it, for the part of it where the host blocks
+        or does bulk work (``burst.wait``).  Always opened, whoever started
+        the profiler: outside a profiler session a TraceMe is a flag check
+        (PERF.md section 6, PR 24, has the measured cost)."""
+        if phase not in PHASES:
+            raise KeyError(f"{phase!r} is not a serve phase: {PHASES}")
+        return jax.profiler.TraceAnnotation(phase if part is None else f"{phase}.{part}")
 
     def _perf_snapshot(self) -> Dict[str, Any]:
-        """Host-side perf observatory snapshot (ISSUE 16): phase attribution,
-        compile ledger, roofline — everything health()/statez surface."""
+        """Host-side perf observatory snapshot (ISSUE 16): phase attribution
+        and compile ledger — everything health()/statez surface."""
         snap = self.phase_profiler.snapshot()  # enabled/iterations/wall_s/phases
         snap["compile_ledger"] = self.ledger.snapshot()
-        snap["roofline"] = self.roofline.snapshot(self.phase_profiler.wall_s)
         return snap
 
     def _compiled_step_pick(self, n: int, greedy: bool):
@@ -947,8 +950,9 @@ class InferenceEngineV2:
                     return jnp.argmax(row, axis=-1).astype(jnp.int32), rng
                 return _sample(row, rng, temperature=temperature, top_k=top_k, top_p=top_p)
 
+            pick.__name__ = f"pick_n{n}" + ("" if greedy else "_sampled")
             self._fwd_cache[key] = jax.jit(pick)
-            self.ledger.record("pick", key)
+            self.ledger.record("pick", key, name=pick.__name__)
         return self._fwd_cache[key]
 
     # ------------------------------------------------------------ decode burst
@@ -1033,8 +1037,11 @@ class InferenceEngineV2:
             if self.tp > 1:
                 burst = self._shard_mapped(
                     burst, (self._kv_specs, PartitionSpec(), PartitionSpec()))
+            # sampled and eos-aware bursts are other programs: other names
+            burst.__name__ = (f"burst_n{n}_k{k}" + ("_sampled" if sampling else "")
+                              + (f"_eos{eos}" if eos >= 0 else ""))
             self._fwd_cache[key] = jax.jit(burst, donate_argnums=(1, ))  # dslint: disable=donation-after-use  # call-site contract: decode_burst() reassigns self.kv from the result in the same statement
-            self.ledger.record("burst", key)
+            self.ledger.record("burst", key, name=burst.__name__)
         return self._fwd_cache[key]
 
     def decode_burst(self, k: int, greedy: bool = True,
@@ -1051,6 +1058,39 @@ class InferenceEngineV2:
         more slots per sequence; returns None when not applicable (caller
         falls back to step()).
         """
+        with self._phase_annotation("burst", "prepare"):
+            prepared = self._prepare_burst(k)
+        if prepared is None:
+            return None
+        live, n, b, tok0, start0, tables = prepared
+        sample_cfg = None if greedy else (self.config.temperature, self.config.top_k,
+                                          self.config.top_p)
+        eos = -1 if eos_token_id is None else int(eos_token_id)
+        burst = self._compiled_burst(n, k, sample_cfg=sample_cfg, eos=eos)
+        done0 = jnp.zeros((n, ), jnp.bool_)
+        self.counters.dispatches += 1
+        self.counters.uploads += 3
+        self.counters.upload_ints += int(tok0.size + start0.size + tables.size)
+        # the scan carries the ENGINE rng itself (no pre-split): each fused
+        # step consumes exactly the key the stepwise pick would, so burst and
+        # per-step decode are sample-for-sample identical
+        self.kv, packed, self._rng = burst(self.params, self.kv, jnp.asarray(tok0),
+                                           jnp.asarray(start0), jnp.asarray(tables),
+                                           self._rng, done0)
+        live_blocks = sum(len(seq.blocks) for seq in live)
+        with self._phase_annotation("burst", "wait"):
+            fetched = materialize(packed, self.counters)  # ONE sync per k steps
+        with self._phase_annotation("burst", "absorb"):
+            out = self._absorb_burst(live, k, eos, fetched)
+        # k forward passes over [n, 1] token slots and [n, b] table slots each
+        self.counters.count_slots(n, 1, b, sum(len(v) for v in out.values()),
+                                  live_blocks, passes=k)
+        return out
+
+    def _prepare_burst(self, k: int):
+        """Host side of a burst before its dispatch: the applicability checks,
+        the all-or-nothing block grab for ``k`` more positions per sequence,
+        and the batch arrays.  None when the burst does not apply."""
         live, prefilling = self.scheduler.live_split(self.manager)
         if not live or prefilling:
             return None  # fuse only a pure-decode live set
@@ -1096,27 +1136,17 @@ class InferenceEngineV2:
         b = self._table_width_for(max(len(s.blocks) for s in live))
         tok0 = np.zeros((n, ), np.int32)
         start0 = np.zeros((n, ), np.int32)
+        # padded rows: decode into the trash block at position 0
         tables = np.full((n, b), self.manager.trash_block, np.int32)
         for i, seq in enumerate(live):
             tok0[i] = seq.tokens[seq.seen_tokens]
             start0[i] = seq.seen_tokens
             tables[i] = self.manager.block_table_row(seq, width=b)
-        # padded rows: decode into the trash block at position 0
-        sample_cfg = None if greedy else (self.config.temperature, self.config.top_k,
-                                          self.config.top_p)
-        eos = -1 if eos_token_id is None else int(eos_token_id)
-        burst = self._compiled_burst(n, k, sample_cfg=sample_cfg, eos=eos)
-        done0 = jnp.zeros((n, ), jnp.bool_)
-        self.counters.dispatches += 1
-        self.counters.uploads += 3
-        self.counters.upload_ints += int(tok0.size + start0.size + tables.size)
-        # the scan carries the ENGINE rng itself (no pre-split): each fused
-        # step consumes exactly the key the stepwise pick would, so burst and
-        # per-step decode are sample-for-sample identical
-        self.kv, packed, self._rng = burst(self.params, self.kv, jnp.asarray(tok0),
-                                           jnp.asarray(start0), jnp.asarray(tables),
-                                           self._rng, done0)
-        fetched = materialize(packed, self.counters)  # ONE sync per k steps
+        return live, n, b, tok0, start0, tables
+
+    def _absorb_burst(self, live, k: int, eos: int, fetched) -> Dict[int, List[int]]:
+        """Host side of a burst after its one fetch: the tokens into their
+        sequences, the records, the WAL frame, the gauges."""
         toks, dones = fetched[:k], fetched[k:]        # [K, N] each
         out: Dict[int, List[int]] = {}
         for i, seq in enumerate(live):
@@ -1178,11 +1208,12 @@ class InferenceEngineV2:
             mesh=self.topology.mesh if self.tp > 1 else None,
             ledger=self.ledger)
 
-    def _build_spec_verify_jit(self, n: int, k: int, sample_cfg=None):
+    def _build_spec_verify_jit(self, n: int, k: int, b: int, sample_cfg=None):
         """The fused verify program: ONE batched target forward over the
         paged pool scoring (input token + k draft tokens) per sequence, then
         the on-device rejection sampler — accept count and emitted run packed
-        into one [n, k+2] int32 array so the whole round rides one fetch."""
+        into one [n, k+2] int32 array so the whole round rides one fetch.
+        Jitted under its bucket's name (``spec_verify_n8_k4_b12``)."""
         model, cfg, bs = self.model, self.model_config, self.block_size
         width = jnp.full((n, ), k + 1, jnp.int32)
         if self.tp > 1:
@@ -1206,6 +1237,8 @@ class InferenceEngineV2:
                 packed, rng = rejection_select(logits, draft, rng,
                                                sample_cfg=sample_cfg)
                 return kv, packed, rng
+        verify.__name__ = (f"spec_verify_n{n}_k{k}_b{b}"
+                           + ("" if sample_cfg is None else "_sampled"))
         return jax.jit(verify, donate_argnums=(1, ))  # dslint: disable=donation-after-use  # call-site contract: decode_spec() reassigns self.kv from the result in the same statement
 
     def _compiled_spec_verify(self, n: int, k: int, b: int, sample_cfg=None):
@@ -1217,9 +1250,9 @@ class InferenceEngineV2:
             except Exception:
                 # same degrade as _compiled_fwd: lazy jit when AOT lowering
                 # fails — serving must not die on a backend quirk
-                self._fwd_cache[key] = self._build_spec_verify_jit(n, k,
-                                                                   sample_cfg)
-                self.ledger.record("spec_verify", key)
+                self._fwd_cache[key] = verify = self._build_spec_verify_jit(
+                    n, k, b, sample_cfg)
+                self.ledger.record("spec_verify", key, name=verify.__name__)
         return self._fwd_cache[key]
 
     def _aot_compile_spec_verify(self, n: int, k: int, b: int, sample_cfg=None,
@@ -1245,23 +1278,14 @@ class InferenceEngineV2:
             rng_aval = jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype)
             abstract = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
         t0 = time.perf_counter()  # dslint: disable=raw-clock-in-serving  # same contract as _aot_compile_fwd: measuring the synchronous XLA compile itself, never the engine clock
-        compiled = self._build_spec_verify_jit(n, k, sample_cfg).lower(
+        verify = self._build_spec_verify_jit(n, k, b, sample_cfg)
+        self._fwd_cache[key] = verify.lower(
             jax.tree_util.tree_map(abstract, self.params),
             jax.tree_util.tree_map(abstract, self.kv),
             ints((n, )), ints((n, k)), ints((n, )), ints((n, b)),
             rng_aval).compile()
-        self._fwd_cache[key] = compiled
         self.ledger.record("spec_verify", key, wall_s=time.perf_counter() - t0,  # dslint: disable=raw-clock-in-serving  # same stopwatch as t0 above — host compile duration, never the engine clock
-                           prewarmed=prewarmed)
-        if self.perf_cfg.capture_cost_analysis:
-            try:
-                cost = compiled.cost_analysis()
-                if isinstance(cost, list):
-                    cost = cost[0] if cost else {}
-                self.roofline.note_cost(key, float(cost.get("flops", 0.0)),
-                                        float(cost.get("bytes accessed", 0.0)))
-            except Exception:  # dslint: disable=silent-except  # cost analysis is best-effort, exactly as in _aot_compile_fwd
-                pass
+                           prewarmed=prewarmed, name=verify.__name__)
 
     def decode_spec(self, k: int, greedy: bool = True,
                     eos_token_id: Optional[int] = None
@@ -1360,9 +1384,13 @@ class InferenceEngineV2:
                                             jnp.asarray(tok0), draft_dev,
                                             jnp.asarray(start0),
                                             jnp.asarray(tables), self._rng)
+        # one forward pass over [n, k + 1] token slots; the tables are read
+        # before the accepted runs roll their draft-overshoot blocks back
+        live_blocks = sum(len(seq.blocks) for seq in live)
         handle = DeferredRuns(packed_dev=packed, uids=[s.uid for s in live],
                               counters=self.counters)
-        raw = handle.runs()  # ONE sync absorbs the whole ragged round
+        with self._phase_annotation("burst", "wait"):
+            raw = handle.runs()  # ONE sync absorbs the whole ragged round
         bs = self.manager.block_size
         out: Dict[int, List[int]] = {}
         accepted_total = 0
@@ -1392,6 +1420,8 @@ class InferenceEngineV2:
             self.counters.burst_tokens += len(run)
             max_run = max(max_run, len(run))
             out[seq.uid] = run
+        self.counters.count_slots(n, k + 1, b, sum(len(r) for r in out.values()),
+                                  live_blocks)
         self.counters.spec_rounds += 1
         self.counters.spec_proposed += len(live) * k
         self.counters.spec_accepted += accepted_total
@@ -1625,10 +1655,9 @@ class InferenceEngineV2:
                                   for uid, prompt in zip(uids, prompts)
                                   if uid not in results})
             self._prewarm(max_new_tokens, greedy=greedy)
-            if self.telemetry is not None:
-                # re-arm the serve-loop jax.profiler window for THIS
-                # generate() (ISSUE 16 satellite — one window per call)
-                self.telemetry.serve_profile_begin()
+            # re-arm the serve-loop jax.profiler window for THIS
+            # generate() (ISSUE 16 satellite — one window per call)
+            self._tell("serve_profile_begin")
             self._serve_loop(uids, my, results, produced, max_new_tokens=max_new_tokens,
                              eos_token_id=eos_token_id, greedy=greedy, strict=strict)
             # post-pass pool state: final census/forecast refresh, then the
@@ -1644,10 +1673,9 @@ class InferenceEngineV2:
             self._abandon(my, results)
             raise
         finally:
-            if self.telemetry is not None:
-                # a serve capture window must never leak across generate()
-                # calls — close it even on a strict raise
-                self.telemetry.serve_profile_end()
+            # a serve capture window must never leak across generate()
+            # calls — close it even on a strict raise
+            self._tell("serve_profile_end")
             # flush the Chrome-trace export (if configured) even on a strict
             # raise — the partial trace is exactly what the postmortem wants
             self.tracer.write_chrome_trace()
@@ -1683,11 +1711,10 @@ class InferenceEngineV2:
 
         while any(u not in results for u in uids):
             self.counters.loop_iterations += 1
-            if self.telemetry is not None:
-                # serve-loop jax.profiler capture window (ISSUE 16 satellite):
-                # [start, stop) in per-generate iterations, one window per
-                # generate() — a no-op unless the window knobs are set
-                self.telemetry.profile_serve_boundary(serve_iter)
+            # serve-loop jax.profiler capture window (ISSUE 16 satellite):
+            # [start, stop) in per-generate iterations, one window per
+            # generate() — a no-op unless the window knobs are set
+            self._tell("profile_serve_boundary", serve_iter)
             serve_iter += 1
             prof.begin_iteration()
             # serve-iteration liveness stamp (ISSUE 8): phase "serving" on
@@ -1705,7 +1732,8 @@ class InferenceEngineV2:
                 # first so PR-4 semantics match the synchronous loop exactly
                 self.counters.flushes += 1
                 self.tracer.event("flush", step=self.scheduler.steps, cause="wave")
-                absorb(self._settle_inflight())
+                with self._phase_annotation("flush"):
+                    absorb(self._settle_inflight())
                 prof.mark("flush")
             self._expire_live()
             with self._phase_annotation("admission_pump"):
@@ -1733,7 +1761,8 @@ class InferenceEngineV2:
                 # absorb the in-flight step first, then re-measure the window
                 self.counters.flushes += 1
                 self.tracer.event("flush", step=self.scheduler.steps, cause="fuse")
-                absorb(self._settle_inflight())
+                with self._phase_annotation("flush"):
+                    absorb(self._settle_inflight())
                 prof.mark("flush")
                 k = self._fusion_window(uids, results, produced, max_new_tokens)
             if fusible and k >= fusion_min:
@@ -1780,10 +1809,13 @@ class InferenceEngineV2:
                     self.counters.flushes += 1
                     self.tracer.event("flush", step=self.scheduler.steps,
                                       cause="sync")
-                    absorb(self._settle_inflight())
+                    with self._phase_annotation("flush"):
+                        absorb(self._settle_inflight())
                     prof.mark("flush")
                 with self._phase_annotation("dispatch"):
-                    absorb(self.step(greedy=greedy))
+                    stepped = self.step(greedy=greedy)
+                    with self._phase_annotation("absorb_patch"):
+                        absorb(stepped)
                 prof.mark("absorb_patch")
 
             # ---- progress watchdog: a live-but-unschedulable engine must trip,
@@ -2048,16 +2080,17 @@ class InferenceEngineV2:
         callers observe ``done`` + the finish reason."""
         now = self._clock()
         self.tracer.tick(now)  # donate the sweep's clock read to the recorder
-        for seq in list(self.manager.seqs.values()):
-            if seq.done or seq.deadline is None or now < seq.deadline:
-                continue
-            self.manager.evict(seq, DEADLINE_EXPIRED)
-            self._deadline_expired_total += 1
-            self.tracer.event("expire", step=self.scheduler.steps, uid=seq.uid,
-                              produced=seq.generated_tokens)
-            self._record_resilience("serving_deadline_expired", uid=seq.uid,
-                                    produced=seq.generated_tokens,
-                                    seen_tokens=seq.seen_tokens)
+        with self._phase_annotation("expire"):
+            for seq in list(self.manager.seqs.values()):
+                if seq.done or seq.deadline is None or now < seq.deadline:
+                    continue
+                self.manager.evict(seq, DEADLINE_EXPIRED)
+                self._deadline_expired_total += 1
+                self.tracer.event("expire", step=self.scheduler.steps, uid=seq.uid,
+                                  produced=seq.generated_tokens)
+                self._record_resilience("serving_deadline_expired", uid=seq.uid,
+                                        produced=seq.generated_tokens,
+                                        seen_tokens=seq.seen_tokens)
         # phase attribution (ISSUE 16): a no-op (and no clock read) unless
         # the profiler is enabled AND inside a serve-loop iteration
         self.phase_profiler.mark("expire")
@@ -2181,8 +2214,7 @@ class InferenceEngineV2:
                 len(self.admission), self.manager.allocator.free_blocks)
 
     def _record_resilience(self, event: str, **fields) -> None:
-        if self.telemetry is not None:
-            self.telemetry.record_resilience(event, step=self.scheduler.steps, **fields)
+        self._tell("record_resilience", event, step=self.scheduler.steps, **fields)
 
     def _journal_terminal(self, uid: int, status: str, *,
                           finish_reason: Optional[str] = None,
@@ -2320,8 +2352,8 @@ class InferenceEngineV2:
             # disk, and the drain-only degradation flag
             "fault_tolerance": self._fault_tolerance_snapshot(),
             # serving performance observatory (ISSUE 16): per-phase wall-time
-            # attribution, compile provenance, live roofline — the ledger and
-            # roofline report even with the phase profiler off
+            # attribution and compile provenance — the ledger reports even
+            # with the phase profiler off
             "perf": self._perf_snapshot(),
             # the recent engine-event history (always on, bounded ring)
             "flight_recorder": self.tracer.recorder.tail(32),

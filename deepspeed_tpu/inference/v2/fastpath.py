@@ -73,15 +73,36 @@ class ServeCounters:
     behind the adaptive-k controller and the ``serving_spec_*`` metric
     families.  All three stay zero with spec decode off (the default), so
     the pre-spec counter fields keep their exact pre-spec values.
+
+    What the device was asked to compute against what was live (ISSUE 24),
+    bumped by :meth:`count_slots` where a forward program is launched:
+    ``token_slots``  token positions the launched buckets hold (n x t a
+    forward pass: a step of 31 decode rows and one 225-token chunk is 32 x 256)
+    ``live_tokens``  of those, the tokens that advanced a sequence
+    ``table_slots``  block-table entries the paged kernel's grid walks (n x b
+    a forward pass)
+    ``live_blocks``  of those, the entries that name a sequence's own block
     """
 
     FIELDS = ("host_syncs", "dispatches", "uploads", "upload_ints", "compiles",
               "loop_iterations", "step_tokens", "burst_tokens", "flushes",
-              "spec_rounds", "spec_proposed", "spec_accepted")
+              "spec_rounds", "spec_proposed", "spec_accepted",
+              "token_slots", "live_tokens", "table_slots", "live_blocks")
 
     def __init__(self):
         for f in self.FIELDS:
             setattr(self, f, 0)
+
+    def count_slots(self, n: int, t: int, b: int, live_tokens: int,
+                    live_blocks: int, passes: int = 1) -> None:
+        """One launch of a forward program over the bucket ``[n, t]`` tokens
+        x ``[n, b]`` table slots; a burst of k steps is ``passes=k`` forward
+        passes over ``[n, 1]``, and its ``live_tokens`` are the whole
+        burst's.  Host integers only: no clock read, no device sync."""
+        self.token_slots += n * t * passes
+        self.live_tokens += live_tokens
+        self.table_slots += n * b * passes
+        self.live_blocks += live_blocks * passes
 
     def snapshot(self) -> Dict[str, int]:
         return {f: int(getattr(self, f)) for f in self.FIELDS}
@@ -358,7 +379,7 @@ class DeviceBatchState:
             if sig not in self._scatter_shapes:
                 self._scatter_shapes.add(sig)
                 if self._ledger is not None:
-                    self._ledger.record("scatter", sig)
+                    self._ledger.record("scatter", sig, name="_scatter_impl")
                 else:
                     self.counters.compiles += 1
             self.counters.uploads += 1
@@ -383,7 +404,7 @@ class DeviceBatchState:
         if sig not in self._feed_shapes:
             self._feed_shapes.add(sig)
             if self._ledger is not None:
-                self._ledger.record("feed", sig)
+                self._ledger.record("feed", sig, name="_feed_impl")
             else:
                 self.counters.compiles += 1
         self.counters.uploads += 1

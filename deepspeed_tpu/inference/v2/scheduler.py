@@ -254,8 +254,9 @@ class SplitFuseScheduler:
         return budget
 
     def _record(self, event: str, **fields) -> None:
-        if self.telemetry is not None:
-            self.telemetry.record_resilience(event, step=self.steps, **fields)
+        record = getattr(self.telemetry, "record_resilience", None)
+        if record is not None:  # a sink implements only what it wants to hear
+            record(event, step=self.steps, **fields)
 
     def _emit_gauges(self, manager: RaggedStateManager, chunks: List[ScheduledChunk],
                      n_decoding: int, n_prefilling: int) -> None:
@@ -274,8 +275,9 @@ class SplitFuseScheduler:
             "preempted_total": float(self.preempted_total),
         }
         self.steps += 1
-        if self.telemetry is not None:
-            self.telemetry.record_gauges(
+        record_gauges = getattr(self.telemetry, "record_gauges", None)
+        if record_gauges is not None:
+            record_gauges(
                 self.last_gauges, step=self.steps, prefix="Inference/Scheduler",
                 timestamp=self.gauge_timestamp() if self.gauge_timestamp else None)
 

@@ -263,6 +263,7 @@ class ModelDrafter:
                                                 length=k - 1)
                 return kv, jnp.concatenate([d0[:, None], rest.T], axis=1)
 
+            draft.__name__ = f"draft_n{n}_t{t}_b{b}_k{k}"
             if self._replicated is not None:
                 rep = self._replicated
                 self._fns[key] = jax.jit(  # dslint: disable=donation-after-use  # call-site contract: propose_batch reassigns self.kv from the result in the same statement
@@ -271,7 +272,7 @@ class ModelDrafter:
                 self._fns[key] = jax.jit(draft, donate_argnums=(1,))  # dslint: disable=donation-after-use  # call-site contract: propose_batch reassigns self.kv from the result in the same statement
             fn = self._fns[key]
             if self._ledger is not None:
-                self._ledger.record("draft", key)
+                self._ledger.record("draft", key, name=draft.__name__)
         return fn
 
     # ---------------------------------------------------------------- propose

@@ -370,6 +370,16 @@ def populate_from_engine(reg: MetricsRegistry, engine) -> None:
         "step_tokens": "tokens emitted via stepwise decode",
         "burst_tokens": "tokens emitted via fused decode bursts",
         "flushes": "pipeline flushes forced by wave boundaries",
+        # what the device was asked to compute vs what was live (ISSUE 24):
+        # live_tokens / token_slots is the padding of the launched buckets,
+        # live_blocks / table_slots the waste of the paged kernel's grid
+        "token_slots": "token positions of the launched forward buckets "
+                       "(n x t a pass; a burst of k is k passes of n x 1)",
+        "live_tokens": "tokens that advanced a sequence, of token_slots",
+        "table_slots": "block-table entries the paged kernel's grid walked "
+                       "(n x b a forward pass)",
+        "live_blocks": "block-table entries naming a live sequence's own "
+                       "block, of table_slots",
     }
     for field, help_text in counter_help.items():
         reg.set_counter(f"{reg.namespace}_fastpath_{field}_total",
@@ -573,10 +583,10 @@ def populate_from_engine(reg: MetricsRegistry, engine) -> None:
         reg.set_histogram(f"{reg.namespace}_request_{name}_seconds", hist,
                           help_text=hist_help[name])
     # serving performance observatory (ISSUE 16): per-phase wall-time
-    # histograms, compile provenance counters, warm-recompile counters, and
-    # the live roofline gauges — all host-side values the engine's perf
-    # instruments already hold (the ledger/roofline sections export even with
-    # the phase profiler off; phase + roofline-rate families need it on)
+    # histograms, compile provenance counters and warm-recompile counters —
+    # all host-side values the engine's perf instruments already hold (the
+    # ledger section exports even with the phase profiler off; the phase
+    # families need it on)
     profiler = getattr(engine, "phase_profiler", None)
     if profiler is not None:
         for phase, hist in profiler.histograms().items():
@@ -608,26 +618,6 @@ def populate_from_engine(reg: MetricsRegistry, engine) -> None:
                             help_text="warm recompiles: a bucket key rebuilt "
                                       "after being seen at its site (runtime "
                                       "twin of dslint's recompile-risk rule)")
-    roofline = getattr(engine, "roofline", None)
-    if roofline is not None and profiler is not None and profiler.enabled:
-        for name, value in roofline.gauges(profiler.wall_s).items():
-            reg.set_gauge(f"{reg.namespace}_{name}", value,
-                          help_text={
-                              "serving_hbm_bytes_per_token":
-                                  "HBM bytes accessed per served token "
-                                  "(cost_analysis over dispatched buckets)",
-                              "serving_roofline_fraction":
-                                  "achieved HBM bandwidth over the chip spec "
-                                  "(live twin of BENCH's "
-                                  "hbm_stream_fraction_of_spec)",
-                              "serving_model_flops_utilization":
-                                  "achieved FLOPs over peak (0 until "
-                                  "serving_perf.peak_flops_per_chip is set)",
-                          }[name])
-        reg.set_counter(f"{reg.namespace}_serving_uncosted_dispatches_total",
-                        roofline.uncosted_dispatches,
-                        help_text="dispatches of buckets with no captured "
-                                  "cost analysis (roofline blind spots)")
     # multi-tenant QoS (ISSUE 19): per-tenant admission, token, shed and
     # resident-KV families plus per-tenant SLO histograms — present only
     # when the policy layer is armed (serving_qos.enabled), so a QoS-off

@@ -1,9 +1,12 @@
 """Serving performance observatory (ISSUE 16).
 
-Three instruments that let the v2 serving stack attribute where a serve
-iteration's wall-clock went, where every XLA compile came from, and how close
-decode is running to the HBM roofline — the serving twin of the training-side
-``wall_clock_breakdown`` + flops-profiler story (PARITY rows 43/57):
+Two instruments that let the v2 serving stack attribute where a serve
+iteration's wall-clock went and where every XLA compile came from — the
+serving twin of the training-side ``wall_clock_breakdown`` story (PARITY row
+43).  (A third, a roofline from ``compiled.cost_analysis()`` over host wall
+time, was removed in ISSUE 24: it saw no Pallas kernel and no fused burst.
+What the device computes is counted in ``ServeCounters`` slots and read from
+the device trace by program name — ``chipbench/``.)
 
 - :class:`StepPhaseProfiler` — mark-based per-iteration phase attribution for
   the serve loop.  The engine calls ``begin_iteration()`` at the top of each
@@ -21,13 +24,10 @@ decode is running to the HBM roofline — the serving twin of the training-side
   classifies each as ``prewarmed`` / ``cold`` / first-seen vs ``warm``
   (a key recompiled after being seen — the runtime twin of dslint's
   ``recompile-risk`` rule) and bumps the counter exactly once per record, so
-  counter values are unchanged from the pre-ledger ``+= 1`` sites.
-- :class:`RooflineModel` — per-bucket FLOPs + HBM bytes captured once at AOT
-  compile time from ``compiled.cost_analysis()`` (the engine passes plain
-  floats; this module never sees a jax object), accumulated per dispatch into
-  live ``hbm_bytes_per_token`` / ``roofline_fraction`` /
-  ``model_flops_utilization`` gauges — the live counterpart of BENCH's
-  ``hbm_stream_fraction_of_spec``.
+  counter values are unchanged from the pre-ledger ``+= 1`` sites.  Each
+  record also carries the program's ``name``: the ``__name__`` the program
+  was jitted under, so the device trace's program line (``jit_<name>``), the
+  ledger event and a ``warm_recompile`` flight-recorder line agree.
 
 Zero-device-sync contract (same as heartbeat/metrics/exposition/ops_server,
 enforced by the dslint whole-file scan): nothing here imports jax or numpy,
@@ -44,7 +44,10 @@ from .tracing import StreamingHistogram
 
 # serve-loop phases, in rough per-iteration order; ``other`` absorbs the
 # residual (heartbeat stamp, ops refresh, watchdog, journal flush) so the
-# per-iteration spans always sum to the full iteration wall time
+# per-iteration spans always sum to the full iteration wall time.  The ONE
+# list of phase names: ``StepPhaseProfiler.mark`` takes these, and the serve
+# loop's ``jax.profiler`` spans (``engine_v2._phase_annotation``) are named
+# by them too (``<phase>`` or, nested inside one, ``<phase>.<part>``)
 PHASES = ("admission_pump", "scatter_upload", "dispatch", "absorb_patch",
           "burst", "flush", "expire", "other")
 
@@ -193,8 +196,11 @@ class CompileLedger:
         return key if isinstance(key, str) else repr(key)
 
     def record(self, site: str, key: Any, *, wall_s: float = 0.0,
-               prewarmed: bool = False) -> str:
-        """Record one compile at ``site`` for bucket ``key``; returns class."""
+               prewarmed: bool = False, name: Optional[str] = None) -> str:
+        """Record one compile at ``site`` for bucket ``key``; returns class.
+        ``name`` is the program's jit name (the trace module is
+        ``jit_<name>``); ``key`` alone decides ``warm``."""
+        name = site if name is None else name
         k = (site, self._key_str(key))
         seen = self._seen.get(k, 0)
         self._seen[k] = seen + 1
@@ -203,15 +209,15 @@ class CompileLedger:
             self.warm_by_site[site] = self.warm_by_site.get(site, 0) + 1
             if self._tracer is not None:
                 self._tracer.event("warm_recompile", site=site, key=k[1],
-                                   builds=seen + 1)
+                                   program=name, builds=seen + 1)
         else:
             cls = CLASS_PREWARMED if prewarmed else CLASS_COLD
         per_site = self.by_site.setdefault(site, {})
         per_site[cls] = per_site.get(cls, 0) + 1
         self.compile_wall_s += float(wall_s)
         self.total += 1
-        self.events.append({"site": site, "key": k[1], "class": cls,
-                            "wall_s": round(float(wall_s), 6)})
+        self.events.append({"site": site, "key": k[1], "name": name,
+                            "class": cls, "wall_s": round(float(wall_s), 6)})
         if self._counters is not None:
             self._counters.compiles += 1
         return cls
@@ -226,76 +232,3 @@ class CompileLedger:
                 "compile_wall_s": round(self.compile_wall_s, 6),
                 "by_site": {s: dict(c) for s, c in sorted(self.by_site.items())},
                 "recent": list(self.events)[-8:]}
-
-
-class RooflineModel:
-    """Live tokens-per-HBM-byte roofline for the serve loop.
-
-    The engine captures ``cost_analysis()`` floats once per AOT-compiled
-    bucket (:meth:`note_cost`) and charges them per dispatch
-    (:meth:`note_dispatch`); :meth:`gauges` divides the accumulated bytes and
-    FLOPs by the profiler's measured wall time against the configured HBM
-    spec and peak-FLOPs numbers.  Dispatches of buckets that were never
-    AOT-costed (lazily-compiled shapes outside the prewarm set) are counted
-    in ``uncosted_dispatches`` so a low roofline fraction is distinguishable
-    from missing cost coverage.
-    """
-
-    def __init__(self, config=None):
-        cfg = config
-        self.hbm_gbps_spec = float(getattr(cfg, "hbm_gbps_spec", 819.0))
-        self.peak_flops = getattr(cfg, "peak_flops_per_chip", None)
-        self._costs: Dict[str, Tuple[float, float]] = {}  # key -> (flops, bytes)
-        self.flops = 0.0
-        self.bytes = 0.0
-        self.tokens = 0
-        self.dispatches = 0
-        self.uncosted_dispatches = 0
-
-    def reset(self) -> None:
-        """Zero the dispatch accumulators (timed-pass isolation, e.g. bench's
-        warm-then-measure discipline).  The per-bucket cost table survives:
-        costs are a property of the compiled bucket, not of any one pass."""
-        self.flops = 0.0
-        self.bytes = 0.0
-        self.tokens = 0
-        self.dispatches = 0
-        self.uncosted_dispatches = 0
-
-    def note_cost(self, key: Any, flops: float, bytes_accessed: float) -> None:
-        self._costs[CompileLedger._key_str(key)] = (float(flops),
-                                                    float(bytes_accessed))
-
-    def note_dispatch(self, key: Any, tokens: int) -> None:
-        self.dispatches += 1
-        self.tokens += int(tokens)
-        cost = self._costs.get(CompileLedger._key_str(key))
-        if cost is None:
-            self.uncosted_dispatches += 1
-            return
-        self.flops += cost[0]
-        self.bytes += cost[1]
-
-    def gauges(self, wall_s: float) -> Dict[str, float]:
-        """Finite gauge values; zeros until there is data to divide."""
-        out = {"serving_hbm_bytes_per_token":
-               (self.bytes / self.tokens) if self.tokens else 0.0,
-               "serving_roofline_fraction": 0.0,
-               "serving_model_flops_utilization": 0.0}
-        if wall_s > 0.0:
-            out["serving_roofline_fraction"] = (
-                self.bytes / wall_s) / (self.hbm_gbps_spec * 1e9)
-            if self.peak_flops:
-                out["serving_model_flops_utilization"] = (
-                    self.flops / wall_s) / float(self.peak_flops)
-        return out
-
-    def snapshot(self, wall_s: float = 0.0) -> Dict[str, Any]:
-        return {"costed_buckets": len(self._costs),
-                "dispatches": self.dispatches,
-                "uncosted_dispatches": self.uncosted_dispatches,
-                "tokens": self.tokens,
-                "flops": self.flops,
-                "hbm_bytes": self.bytes,
-                "gauges": {k: round(v, 9)
-                           for k, v in self.gauges(wall_s).items()}}

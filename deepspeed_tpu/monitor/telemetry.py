@@ -27,7 +27,6 @@ not in the table (CPU test backend) yields ``mfu: null`` unless the config
 pins a peak.
 """
 
-import contextlib
 import json
 import os
 import time
@@ -320,16 +319,16 @@ class TelemetryCollector:
 
     def step_annotation(self, step: int):
         """StepTraceAnnotation for the train step — the marker TensorBoard's
-        profile tooling groups per-step stats by."""
-        if not self.enabled:
-            return contextlib.nullcontext()
+        profile tooling groups per-step stats by.  Opened whether telemetry
+        is enabled or not (as :meth:`annotation` is): whoever runs the
+        profiler gets the host spans, and enabling telemetry to have them
+        would also buy the per-step loss sync, changing what is measured.
+        Outside a profiler session a TraceMe is a flag check."""
         import jax
         return jax.profiler.StepTraceAnnotation("train_step", step_num=int(step))
 
     def annotation(self, name: str):
         """Named TraceAnnotation (batch-prep, checkpoint IO, eval, ...)."""
-        if not self.enabled:
-            return contextlib.nullcontext()
         import jax
         return jax.profiler.TraceAnnotation(name)
 

@@ -605,20 +605,17 @@ class ServingPerfConfig(ConfigModel):
     at phase boundaries, accumulated into deterministic-quantile streaming
     histograms, exported as ``serving_phase_*`` metric families, Chrome-trace
     phase tracks and an every-``phase_budget_every``-iterations phase-budget
-    flight-recorder line, plus the live roofline gauges
-    (``serving_hbm_bytes_per_token`` / ``serving_roofline_fraction`` /
-    ``serving_model_flops_utilization``) against ``hbm_gbps_spec`` and
-    ``peak_flops_per_chip``.  Off by default: phase marks READ the clock, and
+    flight-recorder line.  Off by default: phase marks READ the clock, and
     deadline/TTL semantics under an injected deterministic clock must not
     shift when the observatory is toggled — with it off, the engine performs
     zero additional clock reads, so tokens and ``ServeCounters`` are
     byte-identical either way (the perf-smoke lane proves it).
 
-    The CompileLedger and per-bucket ``cost_analysis()`` capture are ALWAYS
-    on regardless of ``enabled`` — they add no clock reads and no device
-    work, and the ledger is the single source of truth behind
-    ``ServeCounters.compiles`` (``capture_cost_analysis`` gates only the
-    AOT-time cost read, for backends whose executables can't report costs).
+    The CompileLedger, the slot counters of ``ServeCounters`` and the serve
+    loop's ``jax.profiler`` spans are ALWAYS on regardless of ``enabled`` —
+    they add no clock reads and no device work, and the ledger is the single
+    source of truth behind ``ServeCounters.compiles``.  (The roofline gauges
+    from ``cost_analysis()`` and their three knobs went in ISSUE 24.)
     """
     enabled: bool = False
     # emit a phase-budget flight-recorder line every N serve iterations
@@ -627,12 +624,6 @@ class ServingPerfConfig(ConfigModel):
     # histograms' 1e-5 — phase spans are sub-iteration slivers
     histogram_buckets_per_decade: int = Field(6, ge=1, le=100)
     histogram_min_s: float = Field(1e-7, gt=0.0)
-    # HBM bandwidth spec for the roofline denominator (GB/s; 819 = v5e, the
-    # same constant BENCH's hbm_stream_fraction_of_spec divides by)
-    hbm_gbps_spec: float = Field(819.0, gt=0.0)
-    # per-chip peak FLOPs for serving MFU; None leaves the MFU gauge at 0
-    peak_flops_per_chip: Optional[float] = Field(None, gt=0.0)
-    capture_cost_analysis: bool = True
 
 
 class ServingFaultToleranceConfig(ConfigModel):
@@ -1040,8 +1031,8 @@ class TrainingConfig(ConfigModel):
     # copy-on-write prefix caching over the paged KV pool — same
     # dual-spelling contract as above
     serving_prefix_cache: ServingPrefixCacheConfig = Field(ServingPrefixCacheConfig)
-    # serving performance observatory (phase attribution, compile ledger,
-    # live roofline gauges) — same dual-spelling contract as above
+    # serving performance observatory (phase attribution, compile ledger)
+    # — same dual-spelling contract as above
     serving_perf: ServingPerfConfig = Field(ServingPerfConfig)
     # fleet front-end over N supervised replicas (health-gated routing,
     # prefix affinity, journaled failover migration) — same dual-spelling

@@ -456,12 +456,15 @@ class Engine:
             clip_norm = self.config.gradient_clipping
 
             def grad_step(params16, batch, rngs):
-                grads, loss_sum = accumulate_micro_grads(loss_fn, params16, batch, rngs,
-                                                         jnp.float32(1.0))
-                grads = jax.tree_util.tree_map(lambda g: g / gas, grads)
-                norm = global_grad_norm(grads)
-                if clip_norm > 0:
-                    grads, norm = clip_by_global_norm(grads, clip_norm, precomputed_norm=norm)
+                # the scope names of train_step (the optimizer runs on the host)
+                with jax.named_scope("forward_backward"):
+                    grads, loss_sum = accumulate_micro_grads(loss_fn, params16, batch, rngs,
+                                                             jnp.float32(1.0))
+                    grads = jax.tree_util.tree_map(lambda g: g / gas, grads)
+                with jax.named_scope("grad_norm_clip"):
+                    norm = global_grad_norm(grads)
+                    if clip_norm > 0:
+                        grads, norm = clip_by_global_norm(grads, clip_norm, precomputed_norm=norm)
                 return grads, loss_sum / gas, norm
 
             self._offload_grad_fn = jax.jit(grad_step)
@@ -577,9 +580,14 @@ class Engine:
             scale = state.loss_scale.cur_scale if fp16 else jnp.float32(1.0)
             micro_rngs = jax.random.split(step_rng, gas)
 
+            # the named scopes below are metadata on the operations (a trace
+            # tells forward+backward, the norm and the update apart by them)
+            # and change nothing that is compiled.  Forward and backward are
+            # ONE scope: under value_and_grad XLA interleaves them
             if onebit_fn is not None:
                 # 1-bit optimizer: grads + compressed momentum reduction +
-                # update all inside one shard_map (comm is part of the step)
+                # update all inside one shard_map (comm is part of the step;
+                # its phases are scoped inside the body)
                 lr = lr_schedule(state.step)
                 new_params, new_opt, loss_sum, norm = onebit_fn(
                     state.params, state.opt_state, batch, micro_rngs, lr)
@@ -589,36 +597,39 @@ class Engine:
                                               skipped=jnp.zeros((), jnp.bool_),
                                               loss_scale=jnp.float32(1.0))
 
-            if zpp3_fn is not None:
-                # stage-3 ZeRO++: int8 gather + int4 hierarchical grad reduction
-                # straight from/to the fp32 master layout
-                grads, loss_sum = zpp3_fn(state.params, batch, micro_rngs, scale)
-            elif qgz_grad_fn is not None:
-                # qgZ: explicit int4-quantized dp gradient reduction (shard_map)
-                params16 = cast_for_compute(state.params)
-                grads, loss_sum = qgz_grad_fn(params16, batch, micro_rngs, scale)
-            else:
-                params16 = cast_for_compute(state.params)
-                grads, loss_sum = accumulate_micro_grads(loss_fn, params16, batch, micro_rngs, scale)
+            with jax.named_scope("forward_backward"):
+                if zpp3_fn is not None:
+                    # stage-3 ZeRO++: int8 gather + int4 hierarchical grad reduction
+                    # straight from/to the fp32 master layout
+                    grads, loss_sum = zpp3_fn(state.params, batch, micro_rngs, scale)
+                elif qgz_grad_fn is not None:
+                    # qgZ: explicit int4-quantized dp gradient reduction (shard_map)
+                    params16 = cast_for_compute(state.params)
+                    grads, loss_sum = qgz_grad_fn(params16, batch, micro_rngs, scale)
+                else:
+                    params16 = cast_for_compute(state.params)
+                    grads, loss_sum = accumulate_micro_grads(loss_fn, params16, batch, micro_rngs, scale)
 
-            # average over micro-batches and unscale; dp reduction happens via
-            # sharding propagation (data-sharded batch -> psum/reduce-scatter)
-            grads = jax.tree_util.tree_map(lambda g: g / (gas * scale), grads)
-            grads = plan.constrain_grads(grads)
+                # average over micro-batches and unscale; dp reduction happens via
+                # sharding propagation (data-sharded batch -> psum/reduce-scatter)
+                grads = jax.tree_util.tree_map(lambda g: g / (gas * scale), grads)
+                grads = plan.constrain_grads(grads)
 
-            norm = global_grad_norm(grads)
-            if clip_norm > 0:
-                grads, norm = clip_by_global_norm(grads, clip_norm, precomputed_norm=norm)
+            with jax.named_scope("grad_norm_clip"):
+                norm = global_grad_norm(grads)
+                if clip_norm > 0:
+                    grads, norm = clip_by_global_norm(grads, clip_norm, precomputed_norm=norm)
 
             lr = lr_schedule(state.step)
             overflow = jnp.logical_or(has_overflow(grads), jnp.logical_not(jnp.isfinite(norm))) if fp16 \
                 else jnp.zeros((), jnp.bool_)
 
-            if fused_step is not None:
-                new_params, new_opt = fused_step(grads, state.opt_state, state.params, lr)
-            else:
-                updates, new_opt = optimizer.update(grads, state.opt_state, state.params, lr)
-                new_params = jax.tree_util.tree_map(lambda p, u: p + u, state.params, updates)
+            with jax.named_scope("optimizer"):
+                if fused_step is not None:
+                    new_params, new_opt = fused_step(grads, state.opt_state, state.params, lr)
+                else:
+                    updates, new_opt = optimizer.update(grads, state.opt_state, state.params, lr)
+                    new_params = jax.tree_util.tree_map(lambda p, u: p + u, state.params, updates)
 
             # fp16 overflow: skip the update (reference step:1786 overflow path).
             # bf16/fp32 never overflows-skips — eliding the select keeps the old
@@ -672,21 +683,24 @@ class Engine:
         clip_norm = self.config.gradient_clipping
 
         def body(master, opt_state, batch, micro_rngs, lr):
-            params16 = jax.tree_util.tree_map(lambda x: x.astype(compute_dtype), master)
-            grads, loss_sum = accumulate_micro_grads(loss_fn, params16, batch, micro_rngs,
-                                                     jnp.float32(1.0))
-            grads = jax.tree_util.tree_map(lambda g: g / gas, grads)
+            with jax.named_scope("forward_backward"):
+                params16 = jax.tree_util.tree_map(lambda x: x.astype(compute_dtype), master)
+                grads, loss_sum = accumulate_micro_grads(loss_fn, params16, batch, micro_rngs,
+                                                         jnp.float32(1.0))
+                grads = jax.tree_util.tree_map(lambda g: g / gas, grads)
             # global norm from ONE scalar psum of squared local norms (no full
             # gradient allreduce — that would defeat the 1-bit compression):
             # normalized by world so it equals the exact global norm when rank
             # grads coincide (post-allreduce semantics); identical on every
             # rank, so the clip factor below is consistent
-            sq = global_grad_norm(grads) ** 2
-            norm = jnp.sqrt(jax.lax.psum(sq, ax) / world)
-            if clip_norm > 0:
-                # clip BEFORE the momentum update, like the fp16 optimizer path
-                grads, norm = clip_by_global_norm(grads, clip_norm, precomputed_norm=norm)
-            new_master, new_opt = spec.local_step(grads, opt_state, master, lr, ax, world)
+            with jax.named_scope("grad_norm_clip"):
+                sq = global_grad_norm(grads) ** 2
+                norm = jnp.sqrt(jax.lax.psum(sq, ax) / world)
+                if clip_norm > 0:
+                    # clip BEFORE the momentum update, like the fp16 optimizer path
+                    grads, norm = clip_by_global_norm(grads, clip_norm, precomputed_norm=norm)
+            with jax.named_scope("optimizer"):
+                new_master, new_opt = spec.local_step(grads, opt_state, master, lr, ax, world)
             return new_master, new_opt, jax.lax.pmean(loss_sum, ax), norm
 
         def step(master, opt_state, batch, micro_rngs, lr):

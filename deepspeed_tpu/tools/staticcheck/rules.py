@@ -159,8 +159,8 @@ class HostSyncInHotPath(Rule):
                    "the bench regression tooling (tools/benchtrack/) "
                    "any explicit device fetch (np.asarray/np.array/device_get/"
                    "block_until_ready/.item) anywhere in the file — liveness "
-                   "stamps, metrics scrapes, pool census hooks, phase/compile/"
-                   "roofline instruments and bench diffs are contractually "
+                   "stamps, metrics scrapes, pool census hooks, phase/compile "
+                   "instruments and bench diffs are contractually "
                    "zero-device-sync (float() on host config "
                    "values stays legal there; float-of-device-value isn't "
                    "statically separable from it)")
@@ -255,7 +255,7 @@ class HostSyncInHotPath(Rule):
             yield from self._check_zero_sync_file(
                 module, jit_roots,
                 " in monitor/perf.py — the serving perf observatory (phase "
-                "profiler / compile ledger / roofline) is contractually "
+                "profiler / compile ledger) is contractually "
                 "zero-device-sync: it consumes only the engine's injectable "
                 "clock and host floats, and its hooks run inside the serve "
                 "loop at every iteration and compile seam")

@@ -1,10 +1,12 @@
 """Serving performance observatory suite (ISSUE 16): FakeClock-exact phase
-attribution, compile-ledger classes (prewarmed/cold/warm), live roofline
-gauges, zero-perturbation byte-identity (tokens + ServeCounters with the
-observatory on vs off, fastpath AND reference paths), Chrome-trace phase
+attribution, compile-ledger classes (prewarmed/cold/warm),
+zero-perturbation byte-identity (tokens + ServeCounters with the
+observatory on vs off and with a jax.profiler trace open vs none, fastpath
+AND reference paths), Chrome-trace phase
 tracks, the serve-iteration jax.profiler window, and the benchdiff regression
 gate — all on the CPU backend with deterministic clocks."""
 
+import contextlib
 import json
 import os
 
@@ -16,8 +18,7 @@ from deepspeed_tpu.models import llama
 from deepspeed_tpu.monitor.exposition import parse_exposition, render
 from deepspeed_tpu.monitor.metrics import MetricsRegistry, populate_from_engine
 from deepspeed_tpu.monitor.perf import (CLASS_COLD, CLASS_PREWARMED, CLASS_WARM,
-                                        PHASES, CompileLedger, RooflineModel,
-                                        StepPhaseProfiler)
+                                        PHASES, CompileLedger, StepPhaseProfiler)
 from deepspeed_tpu.monitor.telemetry import TelemetryCollector
 from deepspeed_tpu.runtime.config import ServingPerfConfig, TelemetryConfig
 from deepspeed_tpu.tools.benchtrack.cli import main as benchdiff_main
@@ -28,6 +29,7 @@ from deepspeed_tpu.tools.benchtrack.diffcore import (VERDICT_IMPROVEMENT,
                                                      diff_metrics, extract_metrics,
                                                      load_bench)
 from tests.unit.fault_injection_serving import FakeClock
+from tests.unit.inference.test_serving_programs_slots_spans import _ProfilerTrace
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
@@ -162,9 +164,21 @@ def test_ledger_classes_warm_detection_and_counter_parity():
     assert led.warm_by_site == {"fwd": 1} and led.warm_total == 1
     assert counters.compiles == led.total == 4  # exactly one bump per record
     warm_events = [f for n, f in tracer.events if n == "warm_recompile"]
-    assert warm_events == [{"site": "fwd", "key": "(2, 8, 4)", "builds": 2}]
+    # the program's name rides every record; where none is given it is the site
+    assert warm_events == [{"site": "fwd", "key": "(2, 8, 4)", "program": "fwd",
+                            "builds": 2}]
     snap = led.snapshot()
     assert snap["warm_total"] == 1 and snap["recent"][-1]["class"] == CLASS_WARM
+
+
+def test_ledger_name_rides_the_event_and_never_decides_warm():
+    tracer = _TracerStub()
+    led = CompileLedger(tracer=tracer)
+    assert led.record("fwd", (2, 8, 4), name="fwd_n2_t8_b4") == CLASS_COLD
+    # the key alone decides warm: the same key under another name is a rebuild
+    assert led.record("fwd", (2, 8, 4), name="something_else") == CLASS_WARM
+    assert [e["name"] for e in led.events] == ["fwd_n2_t8_b4", "something_else"]
+    assert tracer.events[-1][1]["program"] == "something_else"
 
 
 def test_ledger_same_key_different_sites_not_warm():
@@ -179,43 +193,6 @@ def test_ledger_compile_wall_accumulates():
     led.record("fwd", (1, 1, 1), wall_s=0.25, prewarmed=True)
     led.record("fwd", (2, 1, 1), wall_s=0.5, prewarmed=True)
     assert led.compile_wall_s == pytest.approx(0.75)
-
-
-# ------------------------------------------------------------- roofline unit
-def test_roofline_gauges_finite_and_uncosted_tracking():
-    roof = RooflineModel(ServingPerfConfig(hbm_gbps_spec=100.0,
-                                           peak_flops_per_chip=1e12))
-    roof.note_cost((1, 8, 4), flops=2e9, bytes_accessed=1e9)
-    roof.note_dispatch((1, 8, 4), tokens=8)
-    roof.note_dispatch((9, 9, 9), tokens=2)  # never costed
-    assert roof.uncosted_dispatches == 1 and roof.tokens == 10
-    g = roof.gauges(wall_s=1.0)
-    assert g["serving_hbm_bytes_per_token"] == pytest.approx(1e9 / 10)
-    assert g["serving_roofline_fraction"] == pytest.approx(1e9 / (100.0 * 1e9))
-    assert g["serving_model_flops_utilization"] == pytest.approx(2e9 / 1e12)
-    # no wall time yet -> zeros, never NaN/inf
-    zeros = roof.gauges(wall_s=0.0)
-    assert zeros["serving_roofline_fraction"] == 0.0
-    assert all(v == v and abs(v) != float("inf") for v in zeros.values())
-
-
-def test_roofline_reset_zeros_accumulators_but_keeps_cost_table():
-    # bench's warm-then-measure discipline: the warm pass's dispatches must
-    # not leak into the timed pass's gauges, but the per-bucket cost table
-    # (a property of the compiled bucket, not of any one pass) survives
-    roof = RooflineModel(ServingPerfConfig(hbm_gbps_spec=100.0))
-    roof.note_cost((1, 8, 4), flops=2e9, bytes_accessed=1e9)
-    roof.note_dispatch((1, 8, 4), tokens=8)
-    roof.note_dispatch((9, 9, 9), tokens=2)
-    roof.reset()
-    assert (roof.bytes, roof.flops, roof.tokens, roof.dispatches,
-            roof.uncosted_dispatches) == (0.0, 0.0, 0, 0, 0)
-    assert roof.gauges(wall_s=1.0)["serving_roofline_fraction"] == 0.0
-    # a post-reset dispatch of the previously-costed bucket is still costed
-    roof.note_dispatch((1, 8, 4), tokens=4)
-    assert roof.uncosted_dispatches == 0 and roof.bytes == pytest.approx(1e9)
-    assert roof.gauges(wall_s=1.0)["serving_hbm_bytes_per_token"] == (
-        pytest.approx(1e9 / 4))
 
 
 # --------------------------------------------------------- engine integration
@@ -247,27 +224,37 @@ def test_engine_phase_families_fill_and_sum_to_wall():
     snap = eng.health()["perf"]
     assert snap["phases"]["dispatch"]["p50"] is not None
     assert snap["compile_ledger"]["warm_total"] == 0
-    assert snap["roofline"]["gauges"]["serving_hbm_bytes_per_token"] > 0.0
+    assert "roofline" not in snap  # removed with RooflineModel (ISSUE 24)
 
 
+@pytest.mark.parametrize("observed_by", ["phase_profiler", "profiler_trace"])
 @pytest.mark.parametrize("fastpath", [True, False])
-def test_tokens_and_counters_byte_identical_observatory_on_vs_off(fastpath):
-    """The zero-perturbation acceptance: enabling the observatory changes no
-    token and no ServeCounters value, on both the fastpath and the reference
+def test_tokens_and_counters_byte_identical_observatory_on_vs_off(fastpath, observed_by,
+                                                                  tmp_path):
+    """The zero-perturbation acceptance: neither enabling the observatory nor
+    an open jax.profiler trace (the serve loop's spans are always written)
+    changes a token or the value of any ServeCounters field, the slot
+    counters among them, on both the fastpath and the reference
     (fastpath-off) serve paths."""
-    def run(perf_on):
+    def run(observed):
         eng = _tiny_engine(
             clock=FakeClock(tick=0.001),
             config={"dtype": "float32",
                     "serving_fastpath": {"enabled": fastpath},
-                    "serving_perf": {"enabled": perf_on}})
-        toks = eng.generate(_PROMPTS, max_new_tokens=6)
+                    "serving_perf": {"enabled": observed
+                                     and observed_by == "phase_profiler"}})
+        traced = observed and observed_by == "profiler_trace"
+        with _ProfilerTrace(tmp_path) if traced else contextlib.nullcontext():
+            toks = eng.generate(_PROMPTS, max_new_tokens=6)
         return toks, eng.counters.snapshot()
 
     toks_off, counters_off = run(False)
     toks_on, counters_on = run(True)
     assert toks_on == toks_off
+    assert set(counters_on) == set(type(_tiny_engine().counters).FIELDS)
     assert counters_on == counters_off
+    assert 0 < counters_on["live_tokens"] <= counters_on["token_slots"]
+    assert 0 < counters_on["live_blocks"] <= counters_on["table_slots"]
 
 
 def test_engine_ledger_attributes_prewarm_and_traffic():
@@ -297,19 +284,6 @@ def test_engine_forced_recompile_classified_warm():
     assert tail and "fwd" in {e["site"] for e in tail}
 
 
-def test_engine_roofline_full_cost_coverage():
-    eng = _tiny_engine(config={"dtype": "float32",
-                               "serving_perf": {"enabled": True}})
-    eng.generate(_PROMPTS, max_new_tokens=4)
-    roof = eng.health()["perf"]["roofline"]
-    assert roof["costed_buckets"] > 0
-    assert roof["uncosted_dispatches"] == 0, \
-        "every dispatched fwd bucket must carry cost_analysis numbers"
-    assert roof["hbm_bytes"] > 0.0 and roof["flops"] > 0.0
-    for v in roof["gauges"].values():
-        assert v == v and abs(v) != float("inf")
-
-
 def test_metrics_families_for_observatory():
     eng = _tiny_engine(config={"dtype": "float32",
                                "serving_perf": {"enabled": True}})
@@ -326,8 +300,15 @@ def test_metrics_families_for_observatory():
     assert any(("site", "fwd") in row for row in compile_rows)
     recompiles = fams["dstpu_serving_recompiles_total"]["samples"]
     assert recompiles and all(v == 0.0 for _, _, v in recompiles)
-    assert "dstpu_serving_roofline_fraction" in fams
-    assert "dstpu_serving_hbm_bytes_per_token" in fams
+    # the cost_analysis gauges are gone; what the device was asked to compute
+    # is counted in slots, exported by the route the other counters take
+    assert not any("roofline" in f or "hbm_bytes" in f or "flops_utilization" in f
+                   for f in fams)
+    snap = eng.counters.snapshot()
+    for field in ("token_slots", "live_tokens", "table_slots", "live_blocks"):
+        (_, _, value), = fams[f"dstpu_fastpath_{field}_total"]["samples"]
+        assert value == snap[field] > 0
+        assert eng.health()["fastpath"][field] == snap[field]
 
 
 def test_chrome_trace_contains_phase_tracks(tmp_path):
@@ -390,6 +371,15 @@ def test_serve_profiler_window_closed_at_generate_end(monkeypatch):
     eng = _tiny_engine(telemetry=collector)
     eng.generate(_PROMPTS, max_new_tokens=4)
     assert calls == ["start", "stop"], calls
+
+
+@pytest.mark.parametrize("knob", ["hbm_gbps_spec", "peak_flops_per_chip",
+                                  "capture_cost_analysis"])
+def test_config_names_a_removed_roofline_knob_as_unknown(knob):
+    # the three knobs went with RooflineModel: a config that still sets one is
+    # told so by name, as for any unknown field, and not silently ignored
+    with pytest.raises(ValueError, match=f"unknown config field '{knob}'"):
+        _tiny_engine(config={"dtype": "float32", "serving_perf": {knob: 1.0}})
 
 
 def test_config_rejects_stop_before_start():

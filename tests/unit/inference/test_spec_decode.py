@@ -317,16 +317,20 @@ def test_spec_off_exposition_has_no_spec_families():
         "dstpu_fastpath_burst_tokens_total", "dstpu_fastpath_compiles_total",
         "dstpu_fastpath_dispatches_total", "dstpu_fastpath_flushes_total",
         "dstpu_fastpath_host_syncs_total",
+        "dstpu_fastpath_live_blocks_total", "dstpu_fastpath_live_tokens_total",
         "dstpu_fastpath_loop_iterations_total",
-        "dstpu_fastpath_step_tokens_total", "dstpu_fastpath_upload_ints_total",
+        "dstpu_fastpath_step_tokens_total", "dstpu_fastpath_table_slots_total",
+        "dstpu_fastpath_token_slots_total", "dstpu_fastpath_upload_ints_total",
         "dstpu_fastpath_uploads_total"]
 
 
 def test_serve_counters_fields_spec_tail():
-    """The spec counters ride at the TAIL of FIELDS so every positional
-    consumer of the pre-spec field order still reads the same values."""
-    assert ServeCounters.FIELDS[-3:] == ("spec_rounds", "spec_proposed",
-                                         "spec_accepted")
+    """The spec counters ride BEHIND the pre-spec fields, and the slot
+    counters (ISSUE 24) behind them, so every positional consumer of an older
+    field order still reads the same values."""
+    assert ServeCounters.FIELDS[-7:] == ("spec_rounds", "spec_proposed", "spec_accepted",
+                                         "token_slots", "live_tokens", "table_slots",
+                                         "live_blocks")
     c = ServeCounters()
     assert c.spec_rounds == 0 and c.spec_proposed == 0 and c.spec_accepted == 0
 
