@@ -22,6 +22,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from ...compat import shard_map
+from ...models.transformer import flat_slots
 from ...monitor.perf import PHASES, CompileLedger, StepPhaseProfiler
 from ...monitor.tracing import RequestTracer
 from ...parallel.mesh import TENSOR_AXIS, MeshTopology
@@ -259,6 +260,15 @@ class InferenceEngineV2:
         # state replicates over the engine's mesh (ISSUE 15) so the same
         # ≤1-sync loop drives the shard_mapped forward unchanged.
         self.fastpath = self.config.serving_fastpath
+        # the step's live-token bound, handed to a forward that takes it
+        # (models/llama.py forward_paged) so a bucket with more slots than the
+        # scheduler ever fills computes its live tokens, not its padding
+        # (ISSUE 25).  The reference step stays padded: it is the oracle the
+        # compacted program is compared with.
+        takes_bound = "live_token_bound" in inspect.signature(
+            model_module.forward_paged).parameters
+        self._live_token_bound: Optional[int] = (
+            token_budget if takes_bound and self.fastpath.enabled else None)
         self.counters = ServeCounters()
         # serving performance observatory (ISSUE 16): the compile ledger is
         # always on (no clock reads, no device work) and is the single source
@@ -423,16 +433,17 @@ class InferenceEngineV2:
         (``fwd_n32_t256_b20``): the device trace's program line and the
         compile ledger then say which bucket ran."""
         model, cfg, bs = self.model, self.model_config, self.block_size
+        kw = {}
+        if self._live_token_bound is not None:
+            kw["live_token_bound"] = self._live_token_bound
         if self.tp > 1:
-            def fwd(params, kv, tokens, n_tokens, start_pos, tables):
-                return model.forward_paged(cfg, params, tokens, n_tokens, start_pos,
-                                           tables, kv, block_size=bs,
-                                           tp_axis=TENSOR_AXIS)
+            kw["tp_axis"] = TENSOR_AXIS
+
+        def fwd(params, kv, tokens, n_tokens, start_pos, tables):
+            return model.forward_paged(cfg, params, tokens, n_tokens, start_pos,
+                                       tables, kv, block_size=bs, **kw)
+        if self.tp > 1:
             fwd = self._shard_mapped(fwd, (PartitionSpec(), self._kv_specs))
-        else:
-            def fwd(params, kv, tokens, n_tokens, start_pos, tables):
-                return model.forward_paged(cfg, params, tokens, n_tokens, start_pos,
-                                           tables, kv, block_size=bs)
         fwd.__name__ = f"fwd_n{n}_t{t}_b{b}"
         return jax.jit(fwd, donate_argnums=(1, ))  # dslint: disable=donation-after-use  # call-site contract: step() reassigns self.kv from the result in the same statement (the KV pool is donated so decode updates alias in place)
 
@@ -587,13 +598,22 @@ class InferenceEngineV2:
         chunks = self.scheduler.schedule(self.manager)
         if not chunks:
             return None
+        tokens_run = sum(c.n_tokens for c in chunks)
         self.tracer.event("dispatch", step=self.scheduler.steps, seqs=len(chunks),
-                          tokens=sum(c.n_tokens for c in chunks))
+                          tokens=tokens_run)
         if self.tracer.enabled:  # don't build the chunk list for an early-return
             self.tracer.on_chunks([(c.uid, c.n_tokens) for c in chunks],
                                   step=self.scheduler.steps)
         n = self._bucket(len(chunks))
         t = self._bucket(max(c.n_tokens for c in chunks))
+        flat = flat_slots(n, t, self._live_token_bound)
+        if flat is not None and tokens_run > flat:
+            # the compacted program has no slot for them: they would vanish
+            raise RuntimeError(
+                f"step of {tokens_run} live tokens over the {flat} token slots "
+                f"fwd_n{n}_t{t} computes: the scheduler's token_budget "
+                f"({self.scheduler.token_budget}) passed the bound the engine "
+                f"was built with ({self._live_token_bound})")
         # bucket the table width to the live maximum: the paged kernel's grid
         # walks every table slot, so dead trailing slots are pure waste
         b = self._table_width_for(max(len(self.manager.seqs[c.uid].blocks)
@@ -601,7 +621,7 @@ class InferenceEngineV2:
         key = (n, t, b)
         rows = []
         feeds = []
-        tokens_run = live_blocks = 0
+        live_blocks = 0
         with self._phase_annotation("scatter_upload"):
             for i, c in enumerate(chunks):
                 seq = self.manager.seqs[c.uid]
@@ -622,7 +642,6 @@ class InferenceEngineV2:
                 packed[2 + t] = seq.seen_tokens
                 packed[3 + t:] = self.manager.block_table_row(seq, width=b)
                 rows.append((i, packed))
-                tokens_run += c.n_tokens
                 live_blocks += len(seq.blocks)
             slot = self.batch_state.update(key, rows, n_active=len(chunks),
                                            trash_block=self.manager.trash_block)
@@ -640,7 +659,7 @@ class InferenceEngineV2:
         self.counters.dispatches += 1
         toks_dev, self._rng = pick(logits, slot.n_tokens, self._rng)
         self.phase_profiler.mark("dispatch")
-        self.counters.count_slots(n, t, b, tokens_run, live_blocks)
+        self.counters.count_slots(n, t, b, tokens_run, live_blocks, flat=flat)
         emits = []
         row_of: Dict[int, int] = {}
         for i, c in enumerate(chunks):

@@ -76,33 +76,43 @@ class ServeCounters:
 
     What the device was asked to compute against what was live (ISSUE 24),
     bumped by :meth:`count_slots` where a forward program is launched:
-    ``token_slots``  token positions the launched buckets hold (n x t a
-    forward pass: a step of 31 decode rows and one 225-token chunk is 32 x 256)
+    ``token_slots``  token positions the per-token layers of the launched
+    programs computed: n x t a forward pass over a padded bucket, and the S
+    flat slots of a compacted one (ISSUE 25: a step of 31 decode rows and one
+    225-token chunk is the bucket 32 x 256, computed over S = 256)
     ``live_tokens``  of those, the tokens that advanced a sequence
     ``table_slots``  block-table entries the paged kernel's grid walks (n x b
     a forward pass)
     ``live_blocks``  of those, the entries that name a sequence's own block
+    ``compact_passes``  forward passes that ran compacted: how often the
+    bucket held more slots than the step's live-token bound
     """
 
     FIELDS = ("host_syncs", "dispatches", "uploads", "upload_ints", "compiles",
               "loop_iterations", "step_tokens", "burst_tokens", "flushes",
               "spec_rounds", "spec_proposed", "spec_accepted",
-              "token_slots", "live_tokens", "table_slots", "live_blocks")
+              "token_slots", "live_tokens", "table_slots", "live_blocks",
+              "compact_passes")
 
     def __init__(self):
         for f in self.FIELDS:
             setattr(self, f, 0)
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
-                    live_blocks: int, passes: int = 1) -> None:
+                    live_blocks: int, passes: int = 1,
+                    flat: Optional[int] = None) -> None:
         """One launch of a forward program over the bucket ``[n, t]`` tokens
         x ``[n, b]`` table slots; a burst of k steps is ``passes=k`` forward
         passes over ``[n, 1]``, and its ``live_tokens`` are the whole
-        burst's.  Host integers only: no clock read, no device sync."""
-        self.token_slots += n * t * passes
+        burst's.  ``flat``: the flat slots the program's per-token layers ran
+        over in place of ``n x t`` (``models.transformer.flat_slots``), None
+        for a padded program.  Host integers only: no clock read, no device
+        sync."""
+        self.token_slots += (n * t if flat is None else flat) * passes
         self.live_tokens += live_tokens
         self.table_slots += n * b * passes
         self.live_blocks += live_blocks * passes
+        self.compact_passes += passes if flat is not None else 0
 
     def snapshot(self) -> Dict[str, int]:
         return {f: int(getattr(self, f)) for f in self.FIELDS}

@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .transformer import (apply_rotary, attention_block, cross_entropy_loss, init_linear,
+from .transformer import (apply_rotary, attention_block, cross_entropy_loss,
+                          flat_chunk_indices, flat_slots, init_linear,
                           kv_projection_shardable, paged_chunk_indices, rms_norm,
                           rotary_tables, sdpa, swiglu_mlp)
 
@@ -388,7 +389,8 @@ def init_paged_cache(config: LlamaConfig, num_blocks: int, block_size: int, dtyp
 
 def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, window: Optional[int] = None,
-                  tp_axis: Optional[str] = None, gather_logits: bool = True):
+                  tp_axis: Optional[str] = None, gather_logits: bool = True,
+                  live_token_bound: Optional[int] = None):
     """Ragged chunked forward over the paged KV pool (FastGen model-forward
     analog, inference/v2/model_implementations/llama_v2 + blocked flash).
 
@@ -396,6 +398,21 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
     start_pos [N] absolute start of this chunk, block_tables [N, MAXB]
     (padded entries point at the trash block).  ``window`` enables Mistral-style
     sliding-window attention.  Returns (logits [N, T, V], new kv_cache).
+
+    ``live_token_bound``: the caller's promise that ``sum(n_tokens)`` never
+    passes it (the serving engine hands its scheduler's ``token_budget``).
+    Where the bucket holds more slots than that (``flat_slots``, from the
+    static shapes: a mixed SplitFuse step of one 225-token chunk beside 31
+    decode rows is ``[32, 256]`` = 8,192 slots for 256 live tokens), the chunk
+    is compacted onto one flat axis of S slots and everything that is per
+    token (embedding, norms, the Q/K/V/O projections, rotary, the KV write,
+    SwiGLU, the final norm and the output head) runs over ``[S, ...]``.  Only
+    attention sees the padded layout: ``q`` is scattered into a zero
+    ``[N, T, H, Dh]`` for the paged kernel and its output gathered back.  The
+    logits come back as ``[N, T, V]`` all the same, zero wherever no live
+    token sits.  Without the bound, or where the bucket fits it (decode
+    ``[N, 1]``, a burst body, a spec verify), every slot of the bucket is
+    computed and the trace is the padded one.
 
     Attention runs in the Pallas paged kernel (ops/attention/paged.py) on TPU —
     only live blocks are read via scalar-prefetched table indices; off-TPU the
@@ -410,10 +427,32 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
     """
     from ..ops.attention.paged import paged_attention
 
-    b, tchunk = tokens.shape
+    n, t = tokens.shape
     cos, sin = rotary_tables(config.hidden_size // config.num_heads, config.max_seq_len, config.rope_theta)
-    safe_pos, valid, lengths, blk, off = paged_chunk_indices(
-        tokens, n_tokens, start_pos, block_tables, kv_cache["k"].shape[1], block_size)
+    num_blocks = kv_cache["k"].shape[1]
+    slots = flat_slots(n, t, live_token_bound)
+    if slots is None:
+        # the padded bucket as it is: the per-token layers see [N, T]
+        b, tchunk = n, t
+        safe_pos, _, lengths, blk, off = paged_chunk_indices(
+            tokens, n_tokens, start_pos, block_tables, num_blocks, block_size)
+        to_padded = from_padded = lambda a: a
+    else:
+        # the live tokens on one flat axis: the per-token layers see [1, S]
+        b, tchunk = 1, slots
+        row, col, live, safe_pos, blk, off = (a[None] for a in flat_chunk_indices(
+            n_tokens, start_pos, block_tables, num_blocks, block_size, slots))
+        lengths = start_pos + n_tokens
+        tokens = tokens[row, col]
+        drop_row = jnp.where(live, row, n)[0]  # out of bounds: a dead slot lands nowhere
+
+        def to_padded(a):  # [1, S, ...] -> [N, T, ...], zero wherever no live token sits
+            return jnp.zeros((n, t) + a.shape[2:], a.dtype).at[drop_row, col[0]].set(
+                a[0], mode="drop")
+
+        def from_padded(a):  # [N, T, ...] -> [1, S, ...]; a dead slot's value is never used
+            return a[row, col]
+
     x = params["embed"][tokens].astype(kv_cache["k"].dtype)
     Dh = config.hidden_size // config.num_heads  # true head dim: TP-invariant
     H = params["layers"]["attn"]["wq"].shape[-1] // Dh   # local (per-shard) heads
@@ -433,8 +472,9 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
         # pool [NB, KV, bs, Dh]: pool[blk, h, off] = k[n, t, h]
         kpool = kpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(k)
         vpool = vpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(v)
-        out = paged_attention(q, kpool, vpool, block_tables, lengths, start_pos, n_tokens,
-                              block_size=block_size, softmax_scale=scale, window=window)
+        out = from_padded(paged_attention(
+            to_padded(q), kpool, vpool, block_tables, lengths, start_pos, n_tokens,
+            block_size=block_size, softmax_scale=scale, window=window))
         x = x + preduce(out.reshape(b, tchunk, H * Dh) @ lp["attn"]["wo"].astype(x.dtype))
         mlp_in = rms_norm(x, lp["mlp_norm"], config.rms_eps)
         x = x + preduce(swiglu_mlp(lp["mlp"], mlp_in))
@@ -449,4 +489,4 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
         # Greedy decode skips this (gather_logits=False) and argmaxes the
         # vocab-local shard instead — O(1) scalars over ICI per token, not O(V).
         logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
-    return logits, {"k": new_k, "v": new_v}
+    return to_padded(logits), {"k": new_k, "v": new_v}

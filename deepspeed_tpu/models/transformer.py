@@ -368,6 +368,11 @@ def paged_chunk_indices(tokens, n_tokens, start_pos, block_tables, num_blocks: i
     Returns (safe_pos [N,T], valid [N,T], lengths [N], blk [N,T], off [N,T]):
     ``blk``/``off`` address pool[blk, :, off] for each token's KV write, with
     padded tokens routed to the trash block (``num_blocks - 1``).
+
+    These are the indices of the PADDED bucket: every one of the N x T slots
+    gets a position and a pool address, live or not.  A forward that runs its
+    per-token layers over the live tokens only takes
+    :func:`flat_chunk_indices` instead (``lengths`` is the same there).
     """
     b, tchunk = tokens.shape
     trash = num_blocks - 1
@@ -379,6 +384,49 @@ def paged_chunk_indices(tokens, n_tokens, start_pos, block_tables, num_blocks: i
     blk = jnp.where(valid, blk, trash)
     off = jnp.where(valid, safe_pos % block_size, 0)
     return safe_pos, valid, lengths, blk, off
+
+
+def flat_slots(n: int, t: int, live_token_bound: Optional[int]) -> Optional[int]:
+    """How many flat token slots the per-token layers of a ``[n, t]`` chunk run
+    over when the caller promises at most ``live_token_bound`` live tokens, or
+    None where the padded bucket is run as it is: no bound given, or the
+    bucket already fits it (a decode step ``[n, 1]``, a spec verify, a full
+    prefill bucket).  Decided from static shapes, so the engine's slot
+    counters and its check before dispatch ask the same question the traced
+    program asked."""
+    if live_token_bound is None:
+        return None
+    slots = -(-live_token_bound // 8) * 8  # whole sublanes
+    return slots if n * t > slots else None
+
+
+def flat_chunk_indices(n_tokens, start_pos, block_tables, num_blocks: int,
+                       block_size: int, slots: int):
+    """The index scaffolding of a ragged ``[N, T]`` chunk compacted onto one
+    flat token axis of ``slots`` static slots: row 0's live tokens first, then
+    row 1's, and so on, the tail dead.  More live tokens than ``slots`` would
+    fall off the end unseen, so the caller bounds them (the serving engine
+    refuses such a step on host integers before it dispatches).
+
+    Returns (row [S], col [S], live [S], safe_pos [S], blk [S], off [S]):
+    flat slot ``j`` holds ``chunk[row[j], col[j]]``.  A dead slot reads
+    ``chunk[0, 0]``, sits at position 0 and writes its KV to the trash block
+    (``num_blocks - 1``); scattering back onto ``[N, T]`` it must be dropped
+    (``jnp.where(live, row, N)`` under ``mode="drop"``), never written over
+    the live ``[0, 0]``.
+    """
+    n = n_tokens.shape[0]
+    ends = jnp.cumsum(n_tokens)
+    j = jnp.arange(slots)
+    # the row of slot j: how many rows end at or before it (N for a dead slot)
+    row = jnp.sum(j[:, None] >= ends[None, :], axis=1)
+    live = row < n
+    row = jnp.where(live, row, 0)
+    col = jnp.where(live, j - (ends - n_tokens)[row], 0)
+    safe_pos = jnp.where(live, start_pos[row] + col, 0)
+    blk = jnp.where(live, block_tables[row, safe_pos // block_size], num_blocks - 1)
+    off = safe_pos % block_size
+    return row, col, live, safe_pos, blk, off
 
 
 # ----------------------------------------------------------------- losses

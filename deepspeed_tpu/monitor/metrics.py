@@ -373,13 +373,17 @@ def populate_from_engine(reg: MetricsRegistry, engine) -> None:
         # what the device was asked to compute vs what was live (ISSUE 24):
         # live_tokens / token_slots is the padding of the launched buckets,
         # live_blocks / table_slots the waste of the paged kernel's grid
-        "token_slots": "token positions of the launched forward buckets "
-                       "(n x t a pass; a burst of k is k passes of n x 1)",
+        "token_slots": "token positions the launched forward programs' "
+                       "per-token layers computed (n x t a padded pass, the "
+                       "flat slots of a compacted one; a burst of k is k "
+                       "passes of n x 1)",
         "live_tokens": "tokens that advanced a sequence, of token_slots",
         "table_slots": "block-table entries the paged kernel's grid walked "
                        "(n x b a forward pass)",
         "live_blocks": "block-table entries naming a live sequence's own "
                        "block, of table_slots",
+        "compact_passes": "forward passes that ran over their live-token "
+                          "bound in place of the padded n x t bucket",
     }
     for field, help_text in counter_help.items():
         reg.set_counter(f"{reg.namespace}_fastpath_{field}_total",

@@ -5,15 +5,21 @@ count across a mixed-arrival scenario, and byte-identical results against the
 ``serving_fastpath.enabled=False`` reference loop (including under injected
 allocator faults and expiring deadlines)."""
 
+import inspect
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import bench
+from chipbench.entries.serve import LogitSpy
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.fastpath import (PENDING_TOKEN, DeferredTokens,
                                                  DeviceBatchState, ServeCounters)
-from deepspeed_tpu.models import llama
+from deepspeed_tpu.models import llama, mistral
+from deepspeed_tpu.models.transformer import flat_slots
+from deepspeed_tpu.parallel import MeshTopology
 from tests.unit.fault_injection_serving import FakeClock, FaultyBlockedAllocator
 
 NO_FUSION = 10**6  # fusion_min_steps too high to ever fire: forces stepwise
@@ -309,3 +315,142 @@ def test_fastpath_gauges_flow_through_telemetry(tmp_path):
                 "fastpath_burst_fraction", "fastpath_upload_ints"):
         assert key in last
     assert eng.health()["fastpath"]["host_syncs"] >= 1
+
+
+# ------------------------------- live tokens, not the padded bucket (ISSUE 25)
+# token_budget 16 under buckets of up to 4 x 16 slots: the mixed steps compact.
+# Request 2's 40-token prompt prefills in chunks beside the decodes of 0, 1 and
+# 3, and in the first step the prompts of 0 and 1 end while 2's first chunk
+# fills what is left of the budget: three chunks share that step.
+_COMPACT_PROMPTS = [[5, 6, 7], [9, 10, 11, 12, 13], list(range(20, 60)), [70, 71]]
+_FAMILIES = {
+    "llama": lambda: (llama, llama.LlamaConfig.tiny(vocab=128, hidden=64, layers=2, heads=4,
+                                                   kv_heads=4, seq=256)),
+    # the window (16) is shorter than the long prompt
+    "mistral": lambda: (mistral, mistral.MistralConfig.tiny(vocab=128, hidden=64, layers=2,
+                                                            heads=4, kv_heads=4, seq=256,
+                                                            window=16)),
+}
+
+
+def _compacting_engine(family, fastpath, tp=1):
+    module, cfg = _FAMILIES[family]()
+    topo = (MeshTopology.from_axis_dict({"tensor": tp}, devices=jax.devices()[:tp])
+            if tp > 1 else None)
+    return InferenceEngineV2(
+        module, cfg, module.init_params(cfg, jax.random.PRNGKey(1)), topology=topo,
+        config={"dtype": "float32", "serving_fastpath": {"enabled": fastpath}},
+        num_blocks=64, block_size=8, max_blocks_per_seq=8, token_budget=16,
+        max_seqs_per_step=4)
+
+
+@pytest.mark.parametrize("family,tp", [("llama", 1), ("mistral", 1), ("llama", 4)],
+                         ids=["llama", "mistral-window", "llama-tp4"])
+def test_compacted_mixed_wave_matches_the_padded_reference(family, tp):
+    """Tokens against ``_step_reference``, and the logits row that ends each
+    prompt's prefill read by the chip benchmark's own reader: through
+    ``engine._compiled_fwd(n, t, b)`` and its six-argument callable, at
+    ``logits[row, n_tokens[row] - 1]`` of a ``[n, t, V]`` result."""
+    served = {}
+    for fastpath in (True, False):
+        eng = _compacting_engine(family, fastpath, tp)
+        with LogitSpy(eng, _COMPACT_PROMPTS) as spy:
+            served[fastpath] = (eng.generate(_COMPACT_PROMPTS, max_new_tokens=8), spy.rows, eng)
+    (fast, fast_rows, fast_eng), (ref, ref_rows, ref_eng) = served[True], served[False]
+    assert fast == ref
+    _no_pending(fast)
+    assert sorted(fast_rows) == sorted(ref_rows) == [0, 1, 2, 3]
+    for i in fast_rows:
+        np.testing.assert_allclose(fast_rows[i], ref_rows[i], atol=1e-5, rtol=0)
+    # the wave really ran compacted: the two mixed buckets are over the bound
+    names = {e["name"] for e in fast_eng.ledger.events if e["site"] == "fwd"}
+    assert {"fwd_n4_t8_b4", "fwd_n4_t16_b8"} <= names
+    c = fast_eng.counters
+    assert c.compact_passes >= 2 and ref_eng.counters.compact_passes == 0
+    assert c.live_tokens == ref_eng.counters.live_tokens <= c.token_slots
+    assert c.token_slots < ref_eng.counters.token_slots
+    fast_eng.check_kv_invariant()
+
+
+def _ragged_chunk(rng, counts, t, block_size, num_blocks, width):
+    """A ragged [n, t] chunk with ``counts`` live tokens a row, each live row
+    at a random start inside its own blocks, and a pool of random content."""
+    n = len(counts)
+    counts = np.asarray(counts, np.int32)
+    room = width * block_size - counts
+    start = np.where(counts > 0, rng.integers(0, room + 1), 0).astype(np.int32)
+    tables = np.full((n, width), num_blocks - 1, np.int32)
+    free = rng.permutation(num_blocks - 1)
+    for i in np.nonzero(counts)[0]:
+        tables[i] = free[i * width:(i + 1) * width]
+    tokens = np.zeros((n, t), np.int32)
+    for i in range(n):
+        tokens[i, :counts[i]] = rng.integers(1, 128, size=counts[i])
+    return tokens, counts, start, tables
+
+
+@pytest.mark.parametrize("family", ["llama", "mistral"])
+@pytest.mark.parametrize("counts", [[10, 0, 1, 5], [16, 0, 0, 0], [0, 0, 0, 9], [1, 1, 13, 1],
+                                    [0, 7, 0, 0], [4, 4, 4, 4]],
+                         ids=["empty-row-between", "one-row-exactly-S", "leading-empty-rows",
+                              "decodes-around-a-chunk-exactly-S", "under-S", "every-row-exactly-S"])
+def test_forward_paged_compacted_agrees_with_padded(family, counts):
+    module, cfg = _FAMILIES[family]()
+    params = module.init_params(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(sum(c * 17**i for i, c in enumerate(counts)))
+    num_blocks, block_size, t, bound = 33, 8, 16, 16
+    tokens, counts, start, tables = _ragged_chunk(rng, counts, t, block_size, num_blocks, 8)
+    assert flat_slots(len(counts), t, bound) == 16 and counts.sum() <= 16
+    kv = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(rng.normal(size=a.shape), jnp.float32),
+        module.init_paged_cache(cfg, num_blocks, block_size, dtype=jnp.float32))
+    padded, kv_padded = module.forward_paged(cfg, params, tokens, counts, start, tables, kv,
+                                             block_size=block_size)
+    flat, kv_flat = module.forward_paged(cfg, params, tokens, counts, start, tables, kv,
+                                         block_size=block_size, live_token_bound=bound)
+    live = np.arange(t)[None, :] < counts[:, None]
+    assert flat.shape == padded.shape
+    np.testing.assert_allclose(np.asarray(flat)[live], np.asarray(padded)[live],
+                               atol=1e-5, rtol=0)
+    assert not np.asarray(flat)[~live].any()  # nothing lands where no live token sits
+    for name in ("k", "v"):  # every block but the trash block: written ones and untouched ones
+        np.testing.assert_allclose(np.asarray(kv_flat[name])[:, :-1],
+                                   np.asarray(kv_padded[name])[:, :-1], atol=1e-5, rtol=0)
+        written = np.asarray(kv_flat[name])[:, :-1] != np.asarray(kv[name])[:, :-1]
+        assert written.any(axis=(0, 2, 4)).sum() == counts.sum()  # one (block, offset) a token
+
+
+@pytest.mark.parametrize("n,t,bound,slots", [(32, 256, 256, 256), (4, 256, 256, 256),
+                                             (32, 1, 256, None), (1, 256, 256, None),
+                                             (8, 5, 32, 32), (8, 4, 32, None),
+                                             (4, 8, 20, 24), (4, 8, None, None)])
+def test_flat_slots_compacts_only_a_bucket_over_the_bound(n, t, bound, slots):
+    assert flat_slots(n, t, bound) == slots
+
+
+def test_a_step_over_the_bound_is_refused_before_dispatch():
+    eng = _compacting_engine("llama", True)
+    eng.scheduler.token_budget = 64  # raised behind the engine's back
+    eng.put([0], [list(range(1, 41))])
+    before = eng.counters.snapshot()
+    with pytest.raises(RuntimeError, match=r"40 live tokens over the 16 token slots"):
+        eng.step()
+    assert eng.counters.snapshot() == before  # nothing was launched or uploaded
+    # the reference step runs the padded bucket, which has room for them
+    ref = _compacting_engine("llama", False)
+    ref.scheduler.token_budget = 64
+    ref.put([0], [list(range(1, 41))])
+    assert len(ref.step()) == 1
+
+
+def test_the_seven_other_families_are_not_handed_the_bound():
+    from deepspeed_tpu.models import bloom, falcon, gptj, mixtral, opt, phi, qwen
+    for module in (bloom, falcon, gptj, mixtral, opt, phi, qwen):
+        assert "live_token_bound" not in inspect.signature(module.forward_paged).parameters
+    cfg = opt.OPTConfig.tiny()
+    eng = InferenceEngineV2(opt, cfg, opt.init_params(cfg, jax.random.PRNGKey(0)),
+                            config={"dtype": "float32"}, num_blocks=32, block_size=8,
+                            max_blocks_per_seq=8, token_budget=8, max_seqs_per_step=4)
+    assert eng._live_token_bound is None
+    eng.generate([[1, 2, 3, 4, 5, 6], [7, 8, 9]], max_new_tokens=4)
+    assert eng.counters.compact_passes == 0

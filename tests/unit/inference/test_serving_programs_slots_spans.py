@@ -47,12 +47,30 @@ def _names(eng, site):
 # hand.  The prefill step is the bucket [4, 4] (3 rows -> 4, longest chunk 4);
 # the table is 4 wide on the fast path (TABLE_STEP) and 1 wide on the
 # reference path (next power of two of one block).
+#
+# The fifth case gives the same prompts a token budget of 8: the step is 3 + 4
+# tokens and the first 1 of the third prompt, and its bucket [4, 4] holds twice
+# what the budget lets a step fill, so the program's per-token layers run over
+# 8 flat slots (ISSUE 25) and those are what ``token_slots`` counts.
 @pytest.mark.parametrize("path", ["_dispatch_step", "_step_reference", "decode_burst",
-                                  "decode_spec"])
+                                  "decode_spec", "compacted"])
 def test_slot_counters_equal_the_count_by_hand(path):
     conf = {"serving_fastpath": {"enabled": path != "_step_reference"}}
     if path == "decode_spec":
         conf["serving_spec_decode"] = {"enabled": True, "k": 4}
+    if path == "compacted":
+        eng = _tiny_engine(conf, token_budget=8)
+        eng.put([0, 1, 2], _PROMPTS)
+        assert len(eng.step()) == 2  # two prompts end; the third has a token to go
+        assert _names(eng, "fwd") == {"fwd_n4_t4_b4"}  # the bucket's name, compacted or not
+        # 8 flat slots for 8 live tokens of the [4, 4] bucket; the table as ever
+        assert _slots(eng) == (8, 8, 4 * 4, 3)
+        assert eng.counters.compact_passes == 1
+        assert len(eng.step()) == 3  # two decodes and the prompt's last token: [4, 1]
+        assert _slots(eng) == (8 + 4, 8 + 3, 16 + 16, 3 + 3)
+        assert eng.counters.compact_passes == 1  # 4 slots fit the bound: padded
+        assert eng.health()["fastpath"]["compact_passes"] == 1
+        return
     eng = _tiny_engine(conf)
     eng.put([0, 1, 2], _PROMPTS)
     assert len(eng.step()) == 3  # every prompt fits the budget: one prefill step
@@ -60,6 +78,7 @@ def test_slot_counters_equal_the_count_by_hand(path):
     assert _names(eng, "fwd") == {f"fwd_n4_t4_b{b}"}
     # 4 x 4 token slots for 9 prompt tokens; 4 x b table slots for 3 blocks
     assert _slots(eng) == (16, 9, 4 * b, 3)
+    assert eng.counters.compact_passes == 0  # 16 slots fit the budget of 32
 
     if path in ("_dispatch_step", "_step_reference"):
         assert len(eng.step()) == 3  # one decode step: the bucket [4, 1]

@@ -314,8 +314,8 @@ def test_spec_off_exposition_has_no_spec_families():
     fastpath_counters = sorted(n for n in reg.families
                                if n.startswith("dstpu_fastpath_"))
     assert fastpath_counters == [
-        "dstpu_fastpath_burst_tokens_total", "dstpu_fastpath_compiles_total",
-        "dstpu_fastpath_dispatches_total", "dstpu_fastpath_flushes_total",
+        "dstpu_fastpath_burst_tokens_total", "dstpu_fastpath_compact_passes_total",
+        "dstpu_fastpath_compiles_total", "dstpu_fastpath_dispatches_total", "dstpu_fastpath_flushes_total",
         "dstpu_fastpath_host_syncs_total",
         "dstpu_fastpath_live_blocks_total", "dstpu_fastpath_live_tokens_total",
         "dstpu_fastpath_loop_iterations_total",
@@ -326,11 +326,11 @@ def test_spec_off_exposition_has_no_spec_families():
 
 def test_serve_counters_fields_spec_tail():
     """The spec counters ride BEHIND the pre-spec fields, and the slot
-    counters (ISSUE 24) behind them, so every positional consumer of an older
-    field order still reads the same values."""
-    assert ServeCounters.FIELDS[-7:] == ("spec_rounds", "spec_proposed", "spec_accepted",
+    counters (ISSUE 24, 25) behind them, so every positional consumer of an
+    older field order still reads the same values."""
+    assert ServeCounters.FIELDS[-8:] == ("spec_rounds", "spec_proposed", "spec_accepted",
                                          "token_slots", "live_tokens", "table_slots",
-                                         "live_blocks")
+                                         "live_blocks", "compact_passes")
     c = ServeCounters()
     assert c.spec_rounds == 0 and c.spec_proposed == 0 and c.spec_accepted == 0
 

@@ -148,6 +148,34 @@ def test_mistral_training_attention_reaches_flash(chip):
     assert compile_and_count(attn, *flash_avals(chip, 1, WINDOW + 128)) == {}
 
 
+@pytest.mark.parametrize("n,t,b", [(32, 256, 20), (4, 256, 36)],
+                         ids=["chat-burst-n32", "long-prompt-n4"])
+def test_compacted_ragged_forward_compiles_and_holds_no_more_than_padded(chip, n, t, b):
+    """The Mistral ragged forward at its published widths (two layers; every
+    layer is one scan body) over a mixed SplitFuse bucket: compacted onto 256
+    flat slots (ISSUE 25) it compiles for the v5e, still calls the one paged
+    kernel, and holds no more than the padded program."""
+    from deepspeed_tpu.models import mistral
+    cfg = mistral.MistralConfig(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+                                num_layers=2, num_heads=H, num_kv_heads=KV, max_seq_len=32768,
+                                sliding_window=WINDOW)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    params = on_chip(jax.eval_shape(lambda: mistral.init_params(cfg, jax.random.PRNGKey(0))))
+    kv = on_chip(jax.eval_shape(lambda: mistral.init_paged_cache(cfg, 368, 128)))
+    ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, b))]
+    held = {}
+    for bound in (256, None):
+        def fwd(params, kv, tokens, n_tokens, start_pos, tables):
+            return mistral.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
+                                         block_size=128, live_token_bound=bound)
+        compiled = jax.jit(fwd, donate_argnums=(1, )).lower(params, kv, *ints).compile()
+        assert kernel_calls(compiled.as_text()) == {"paged_attention": 1}
+        m = compiled.memory_analysis()
+        held[bound] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                       + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert held[256] <= held[None]
+
+
 def test_fused_adamw_flat_compiles(chip):
     from deepspeed_tpu.ops.adam.fused_adam import fused_adamw_flat
     n = 1 << 26  # one stacked 4096 x 14336 FFN leaf is 2^25.8 elements
