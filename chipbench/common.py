@@ -4,6 +4,7 @@ configuration, the device block, the profiler window."""
 import contextlib
 import importlib
 import json
+import math
 import os
 import shutil
 import sys
@@ -12,10 +13,6 @@ import types
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 OUT = os.path.join(HERE, "out")
-# every key of a published config.json that is a size of the model
-SIZE_KEYS = ("hidden_size", "intermediate_size", "num_attention_heads", "num_hidden_layers",
-             "num_key_value_heads", "vocab_size", "max_position_embeddings", "rms_norm_eps",
-             "rope_theta", "sliding_window", "head_dim")
 
 
 class Refused(Exception):
@@ -40,7 +37,12 @@ def load_module(kind: str, name: str):
 
 
 def published_sizes(config: dict, rehearse: bool) -> dict:
-    sizes = {k: config[k] for k in SIZE_KEYS if k in config}
+    """The configuration's own value under every key that the source model
+    publishes (``chipbench/published/<model>.json``, the source's size keys):
+    whatever the architecture calls its sizes reaches the program's key map
+    and the reference with no list of names kept here."""
+    published = load_json("published", config["published"] + ".json")["config"]
+    sizes = {k: config[k] for k in published}
     if rehearse:
         sizes.update(config["rehearsal"]["sizes"])
     return sizes
@@ -63,6 +65,13 @@ def program_model(config: dict, sizes: dict, **overrides):
     kwargs = {theirs: sizes[ours] for ours, theirs in spec["config_keys"].items()}
     kwargs.update(overrides)
     return module, getattr(module, spec["config_class"])(**kwargs)
+
+
+def count_params(tree) -> int:
+    """Every element of a tree of arrays or of shapes: the model's size as it
+    was drawn, whatever its architecture."""
+    import jax
+    return sum(math.prod(leaf.shape) for leaf in jax.tree_util.tree_leaves(tree))
 
 
 def memory_peak_bytes(devices) -> int:

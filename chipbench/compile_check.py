@@ -11,6 +11,7 @@ real devices, so the script hands them shapes instead: it builds the program's
 own step function around ``jax.eval_shape`` state."""
 
 import argparse
+import inspect
 import os
 import sys
 
@@ -25,7 +26,6 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding  # noqa: E402
 
 from chipbench import common  # noqa: E402
-from chipbench.reduce import shapes  # noqa: E402
 from deepspeed_tpu.ops import _pallas  # noqa: E402
 
 # The program picks its kernels by jax.default_backend(), which is the CPU
@@ -84,7 +84,8 @@ def check_train(config, sizes, traffic, topo):
     batch = {"input_ids": leaf, "labels": leaf}
     compiled = engine.train_step_fn.lower(engine.state, batch).compile()
     from deepspeed_tpu.ops._pallas import kernel_calls
-    print(f"[train] layers={sizes['num_hidden_layers']} params={shapes.num_params(sizes) / 1e9:.3f}B "
+    print(f"[train] layers={sizes['num_hidden_layers']} "
+          f"params={common.count_params(engine.state.params) / 1e9:.3f}B "
           f"seq={seq} rows={rows} kernels={kernel_calls(compiled.as_text())}")
     return report("train step", compiled)
 
@@ -108,9 +109,16 @@ def check_serve(config, sizes, traffic_files, topo):
     kv = on_chip(jax.eval_shape(lambda: module.init_paged_cache(model_cfg, nb, bs,
                                                                 dtype=jnp.bfloat16)))
 
+    # what the engine hands its forward (engine_v2._build_fwd_jit): the step's
+    # live-token bound, where the model's forward takes one
+    kw = {}
+    if "live_token_bound" in inspect.signature(module.forward_paged).parameters:
+        kw["live_token_bound"] = eng.get("token_budget", inspect.signature(
+            InferenceEngineV2.__init__).parameters["token_budget"].default)
+
     def fwd(params, kv, tokens, n_tokens, start_pos, tables):
         return module.forward_paged(model_cfg, params, tokens, n_tokens, start_pos, tables, kv,
-                                    block_size=bs)
+                                    block_size=bs, **kw)
 
     worst = 0
     for name, traffic in traffic_files.items():
@@ -123,7 +131,7 @@ def check_serve(config, sizes, traffic_files, topo):
             args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
                     for s in ((n, t), (n,), (n,), (n, width))]
             compiled = jax.jit(fwd, donate_argnums=(1,)).lower(params, kv, *args).compile()
-            worst = max(worst, report(f"serve {name} fwd n={n} t={t} b={width}", compiled))
+            worst = max(worst, report(f"serve {name} fwd n={n} t={t} b={width} {kw}", compiled))
     return worst
 
 

@@ -18,11 +18,13 @@ import numpy as np
 
 from chipbench import common
 from chipbench.common import say
-from chipbench.reduce import shapes
 
 # spans the serve loop writes into the profiler's trace while a window is
-# open (engine_v2._phase_annotation), and the benchmark's own around the call
-HOST_ANNOTATIONS = ("admission_pump", "burst", "dispatch", "absorb_patch", "chipbench.generate")
+# open (engine_v2._phase_annotation), and the benchmark's own around the call;
+# a gap goes to the innermost of them that covers it (xplane.label_gaps)
+HOST_ANNOTATIONS = ("admission_pump", "burst", "burst.prepare", "burst.wait", "burst.absorb",
+                    "dispatch", "dispatch.wait", "scatter_upload", "absorb_patch", "flush",
+                    "expire", "chipbench.generate")
 REFERENCE_PAD = 1024  # reference sequences are padded to this multiple: few shapes to compile
 
 
@@ -154,7 +156,7 @@ def run(ctx):
         params = None
         served = jax.block_until_ready(jax.jit(
             lambda p: ref.round_weights_to(p, control["format"]), donate_argnums=0)(served))
-    say("serve", params=f"{shapes.num_params(sizes) / 1e9:.3f}B", layers=sizes["num_hidden_layers"],
+    say("serve", params=f"{common.count_params(served) / 1e9:.3f}B", layers=sizes["num_hidden_layers"],
         weights_s=f"{time.perf_counter() - t:.1f}")
 
     engine_args = dict(config["engine"])
@@ -164,6 +166,8 @@ def run(ctx):
     engine = InferenceEngineV2(module, model_cfg, served, config=engine_args.pop("config"),
                                telemetry=recorder, **engine_args)
     free_at_start = engine.manager.allocator.free_blocks
+    # the KV pool as the program holds it, for the readers that look for it in the trace
+    pool_shapes = sorted({tuple(leaf.shape) for leaf in jax.tree_util.tree_leaves(engine.kv)})
 
     traffic_params = ctx.traffic["params"]
     if args.rehearse:
@@ -225,6 +229,7 @@ def run(ctx):
         compiles_in_window=engine.ledger.total - compiles0,
         forwards=engine._kv_steps - forwards0,
         stepwise_forwards=engine.scheduler.steps - stepwise0, peaks=ctx.peaks, chips=1,
+        pool_shapes=pool_shapes,
         memory_peak_bytes=peak, attempted=len(results), failed=len(results) - len(ok),
         trace=None)
     say("window", seconds=f"{window_s:.3f}", waves=len(waves), requests=len(results),

@@ -99,8 +99,15 @@ def flash_attention_bytes(sizes, batch: int, seq: int, backward: bool = True,
 
 
 def num_matmul_params(sizes) -> int:
-    """Parameters that take part in a matrix multiplication: every projection
-    and the output head, not the embedding lookup and not the norm gains."""
+    """Parameters of a dense decoder that take part in a matrix
+    multiplication: every projection and the output head, not the embedding
+    lookup and not the norm gains.  Sizes that carry an expert count are
+    refused: an FFN of experts is not ``3 * d * f`` a layer, and a count that
+    is silently dense would put a wrong MFU under a right name."""
+    experts = sorted(k for k in sizes if "expert" in k)
+    if experts:
+        raise ValueError(f"num_matmul_params counts a dense FFN and these sizes carry {experts}: "
+                         "a configuration with experts brings a count of its own")
     d, f, dh = sizes["hidden_size"], sizes["intermediate_size"], _head_dim(sizes)
     h, kv = sizes["num_attention_heads"], sizes["num_key_value_heads"]
     layer = d * h * dh + 2 * d * kv * dh + h * dh * d + 3 * d * f

@@ -12,6 +12,7 @@ import sys
 
 import pytest
 
+from chipbench import common
 from chipbench.generators.waves import Traffic, quantile_lengths
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -38,8 +39,8 @@ def run_command(root, *argv, **env):
 
 
 # ---------------------------------------------------------------- the command
-@pytest.mark.parametrize("cell,trace", [("serve.decode-heavy", "0"), ("serve.long-prompt", "1"),
-                                        ("train.zero3-fsdp4", "1")])
+@pytest.mark.parametrize("cell,trace", [(w["name"], str(i % 2))
+                                        for i, w in enumerate(BENCH["workloads"])])
 def test_rehearsal_walks_the_cell_and_is_never_a_result(rehearse, cell, trace):
     got = rehearse("--workload", cell, "--seed", str(2 ** 31 + 12345), "--seconds", "0",
                    "--trace", trace)
@@ -47,15 +48,14 @@ def test_rehearsal_walks_the_cell_and_is_never_a_result(rehearse, cell, trace):
     assert '"correct": true' not in got.out
     assert got.line["correct"] is False and got.line["would_be_correct"] is True
     assert got.line["failed"] == 0 and got.line["attempted"] > 0
-    wanted = [m["name"] for m in (BENCH["per_layer"] if trace == "1" else BENCH["end_to_end"])
-              if reports(m, cell)]
+    mine = [m for m in (BENCH["per_layer"] if trace == "1" else BENCH["end_to_end"])
+            if reports(m, cell)]
     if trace == "0":
-        assert sorted(got.line["metrics"]) == sorted(wanted)
+        assert sorted(got.line["metrics"]) == sorted(m["name"] for m in mine)
         assert all(v["value"] > 0 for v in got.line["metrics"].values())
-    else:  # a CPU trace has no accelerator plane: only the counters can be read
-        assert set(got.line["metrics"]) <= set(wanted)
-        assert any(m["source"] == "program_counter" for m in BENCH["per_layer"]
-                   if m["name"] in got.line["metrics"]) or cell.startswith("train")
+    else:  # a CPU trace has no accelerator plane: the counters are read, and they alone
+        counters = {m["name"] for m in mine if m["source"] == "program_counter"}
+        assert set(got.line["metrics"]) == counters
 
 
 def test_without_a_chip_there_is_no_result():
@@ -119,16 +119,28 @@ def test_every_cell_resolves_to_files_that_exist(cell):
     assert any(reports(m, cell) for m in BENCH["per_layer"])
 
 
-def test_every_configuration_is_used_and_widths_are_published():
-    used = {w["config"] for w in BENCH["workloads"]}
-    assert used == {c["name"] for c in BENCH["configs"]}
-    published = {"hidden_size": 4096, "intermediate_size": 14336, "num_attention_heads": 32,
-                 "num_key_value_heads": 8, "vocab_size": 32000, "sliding_window": 4096,
-                 "max_position_embeddings": 32768, "rope_theta": 10000.0, "rms_norm_eps": 1e-05}
-    for c in BENCH["configs"]:
-        spec = data("configs", c["name"] + ".json")
-        assert {k: spec[k] for k in published} == published
-        assert c["reduced"] == ["num_hidden_layers"] and spec["num_hidden_layers"] < 32
+# A key of ``reduced`` is a count.  The depth may always be cut; how many
+# experts, heads or rows of the vocabulary this chip holds only as its share of
+# a deployment that the file's ``deployment`` states, naming the key
+# (model-configs guide, section 4).  Everything else is a width or a shape.
+DEPTH = re.compile(r"^(num|n)_(hidden_)?layers?$")
+SHARE = re.compile(r"^((num|n)_[a-z_]*(experts|heads|groups)|vocab_size)$")
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_every_configuration_is_used_and_widths_are_published(config):
+    entry = next(c for c in BENCH["configs"] if c["name"] == config)
+    assert config in {w["config"] for w in BENCH["workloads"]}
+    spec = data("configs", config + ".json")
+    published = data("published", spec["published"] + ".json")
+    assert spec["source"] == published["source"] == entry["source"]
+    assert sorted(spec["reduced"]) == sorted(entry["reduced"])
+    for key, value in published["config"].items():
+        assert key in spec["reduced"] or (key in spec and spec[key] == value), key
+    for key in spec["reduced"]:
+        assert "per_tok" not in key and (DEPTH.match(key) or SHARE.match(key)), key
+        assert DEPTH.match(key) or key in spec["deployment"], key
+        assert isinstance(spec[key], int) and 0 < spec[key] < published["config"][key], key
 
 
 @pytest.mark.parametrize("metric", [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]])
@@ -175,13 +187,60 @@ def test_the_order_of_a_wave_is_the_traffic_files_own(mix):
         Traffic({k: v for k, v in params.items() if k != "order_seed"}, 1, 32000)
 
 
-def test_the_pool_holds_what_each_wave_asks_for():
-    engine = data("configs", "mistral-7b-serve-16l.json")["engine"]
-    pool = engine["num_blocks"] * engine["block_size"]
-    for name in ("decode-heavy", "long-prompt"):  # waves admitted whole
-        p = data("traffic", name + ".json")["params"]
-        lengths = quantile_lengths(p["prompt_lengths"], p["requests_per_wave"])
-        assert sum(lengths) + len(lengths) * p["max_new_tokens"] <= pool
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]
+                                  if data("configs", w["config"] + ".json")["entry"] == "serve"])
+def test_the_pool_holds_what_each_wave_asks_for(cell):
+    spec = data("configs", CELLS[cell]["config"] + ".json")
+    mix = data("traffic", CELLS[cell]["traffic"] + ".json")
+    engine = spec["engine"]
+    wave = importlib.import_module(f"chipbench.generators.{mix['generator']}").Traffic(
+        mix["params"], 1, spec["vocab_size"])
+    blocks = [-(-(n + wave.max_new_tokens) // engine["block_size"]) for n in wave.lengths]
+    assert max(blocks) <= engine["max_blocks_per_seq"]
+    assert sum(blocks) < engine["num_blocks"]  # admitted whole; one block takes the padded writes
+
+
+# ------------------------------------------- sizes are the published keys
+TODAY = {"hidden_size": 4096, "intermediate_size": 14336, "num_attention_heads": 32,
+         "num_key_value_heads": 8, "vocab_size": 32000, "max_position_embeddings": 32768,
+         "rms_norm_eps": 1e-05, "rope_theta": 10000.0, "sliding_window": 4096}
+
+
+@pytest.mark.parametrize("config,layers", [("mistral-7b-serve-16l", 16),
+                                           ("mistral-7b-zero3-fsdp4", 10)])
+def test_the_sizes_of_the_first_two_configurations_are_what_they_were(config, layers):
+    spec = data("configs", config + ".json")
+    assert common.published_sizes(spec, False) == {**TODAY, "num_hidden_layers": layers}
+    assert common.published_sizes(spec, True) == {**TODAY, **spec["rehearsal"]["sizes"]}
+
+
+def test_sizes_under_any_published_key_reach_the_programs_configuration(tmp_path, monkeypatch):
+    # a sparse-expert model: its expert keys are in no list of this harness
+    keys = {"hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
+            "num_key_value_heads": 2, "num_hidden_layers": 4, "vocab_size": 256,
+            "num_local_experts": 8, "num_experts_per_tok": 2, "norm_topk_prob": False,
+            "rope_scaling": None, "layer_types": ["full", "full"]}
+    (tmp_path / "published").mkdir()
+    (tmp_path / "published" / "sparse.json").write_text(json.dumps(
+        {"source": "https://example.org/sparse", "config": {**keys, "num_hidden_layers": 32}}))
+    monkeypatch.setattr(common, "HERE", str(tmp_path))
+    spec = {**keys, "published": "sparse", "model_type": "sparse", "torch_dtype": "bfloat16",
+            "rehearsal": {"sizes": {"num_hidden_layers": 1}},
+            "program": {"model_module": "deepspeed_tpu.models.mixtral",
+                        "config_class": "MixtralConfig",
+                        "config_keys": {"num_local_experts": "num_experts",
+                                        "num_experts_per_tok": "top_k",
+                                        "hidden_size": "hidden_size",
+                                        "num_hidden_layers": "num_layers"}}}
+    sizes = common.published_sizes(spec, False)
+    assert sizes == keys  # the published keys at the configuration's values, and no other key
+    assert common.published_sizes(spec, True)["num_hidden_layers"] == 1
+    module, model_cfg = common.program_model(spec, sizes, max_seq_len=512)
+    assert module.__name__ == "deepspeed_tpu.models.mixtral"
+    assert (model_cfg.num_experts, model_cfg.top_k) == (8, 2)
+    assert (model_cfg.hidden_size, model_cfg.num_layers, model_cfg.max_seq_len) == (64, 4, 512)
+    with pytest.raises(KeyError, match="num_local_experts"):  # a published key the file lacks
+        common.published_sizes({k: v for k, v in spec.items() if k != "num_local_experts"}, False)
 
 
 # ----------------------------------------------- new files, no file edited
@@ -193,15 +252,32 @@ def test_a_new_cell_config_mix_and_metric_are_found_without_an_edit(tmp_path):
               for dp, _, files in os.walk(os.path.join(root, "chipbench")) for p in files}
     bench = json.loads(json.dumps(BENCH))
 
-    config = data("configs", "mistral-7b-serve-16l.json")
-    config["rehearsal"]["sizes"]["num_hidden_layers"] = 1
+    # another architecture: other widths, full attention and no window, a
+    # published file of its own, another module of the program
+    serving = next(c["name"] for c in BENCH["configs"]
+                   if data("configs", c["name"] + ".json")["entry"] == "serve")
+    config = data("configs", serving + ".json")
+    other = {"hidden_size": 2048, "intermediate_size": 8192, "max_position_embeddings": 4096,
+             "num_attention_heads": 16, "num_hidden_layers": 16, "num_key_value_heads": 16,
+             "rms_norm_eps": 1e-06, "rope_theta": 500000.0, "vocab_size": 50304}
+    source = "https://example.org/other-arch/config.json"
+    theirs = data("published", config["published"] + ".json")["config"]
+    config = {k: v for k, v in config.items() if k not in theirs}
+    config.update(other, source=source, published="other-arch", num_hidden_layers=8)
+    config["program"] = {"model_module": "deepspeed_tpu.models.llama", "config_class": "LlamaConfig",
+                         "config_keys": {k: v for k, v in config["program"]["config_keys"].items()
+                                         if k in other}}
+    config["rehearsal"]["sizes"].update(num_hidden_layers=1, num_attention_heads=4,
+                                        num_key_value_heads=4)
     mix = {"generator": "waves", "params": {"requests_per_wave": 16, "max_new_tokens": 32,
            "order_seed": 7, "prompt_lengths": {"dist": "uniform", "min": 64, "max": 128}}}
     metric = {"layer": "serve loop (engine_v2._serve_loop, fastpath.py)", "unit": "count",
               "better": "lower", "source": "program_counter", "moves": "serve_tok_s",
               "reader": "loop_iterations"}
     reader = 'def read(run):\n    return run.counters["loop_iterations"]\n'
-    for path, text in (("configs/new-config.json", json.dumps(config)),
+    for path, text in (("published/other-arch.json", json.dumps({"source": source,
+                                                                 "config": other})),
+                       ("configs/new-config.json", json.dumps(config)),
                        ("traffic/new-mix.json", json.dumps(mix)),
                        ("metrics/serve.loop_iterations.json", json.dumps(metric)),
                        ("readers/loop_iterations.py", reader)):

@@ -58,6 +58,24 @@ def test_reduction_keeps_only_the_named_host_annotations():
     assert red.breakdown()["idle_gaps"] == [["dispatch", 25e-9], ["host_unannotated", 15e-9]]
 
 
+def test_a_gap_under_nested_annotations_goes_to_the_innermost():
+    # the device idles 100..200 inside one burst: the host built tables until 115,
+    # blocked on the fetch 115..180 and kept its books 180..188
+    ops = [("fusion.1", 0, 100), ("fusion.2", 200, 10)]
+    host = [("burst", 90, 120), ("burst.prepare", 95, 20), ("burst.wait", 115, 65),
+            ("burst.absorb", 180, 8), ("flush", 300, 5)]
+    loaded = {"devices": {"d": {"ops": ops, "modules": []}}, "host": host}
+    from chipbench.entries import serve
+    assert {"burst", "burst.prepare", "burst.wait", "burst.absorb", "flush", "scatter_upload",
+            "expire", "dispatch.wait"} <= set(serve.HOST_ANNOTATIONS)
+    split = xplane.Reduction(loaded, serve.HOST_ANNOTATIONS).breakdown()["idle_gaps"]
+    assert split == [["burst.wait", 65e-9], ["burst.prepare", 15e-9], ["burst", 12e-9],
+                     ["burst.absorb", 8e-9]]
+    # the spans the list does not name fall to the one around them
+    whole = xplane.Reduction(loaded, ("burst",)).breakdown()["idle_gaps"]
+    assert whole == [["burst", 100e-9]]
+
+
 def test_exposed_collective_time():
     # an async all-gather flies 0..60 while a fusion computes 5..45: only its
     # start (5 ns) and the wait in its done (10 ns) are exposed.  A synchronous
@@ -137,6 +155,15 @@ def test_flash_forward_and_backward_by_hand():
     # forward: Q and O (32 heads) + K and V (8 heads), bf16, per token and layer
     fwd = 2 * 32 * 128 * 2 + 2 * 8 * 128 * 2
     assert shapes.flash_attention_bytes(MISTRAL, 1, 2048, backward=False) == 2048 * 16 * fwd
+
+
+def test_the_dense_count_refuses_sizes_with_experts():
+    assert shapes.num_params(MISTRAL) == 3_620_732_928 + 32000 * 4096 + 33 * 4096
+    for key in ("num_experts", "num_local_experts", "n_routed_experts"):
+        with pytest.raises(ValueError, match=key):
+            shapes.num_params({**MISTRAL, key: 8, "num_experts_per_tok": 2})
+        with pytest.raises(ValueError, match="dense FFN"):
+            shapes.train_flops_per_token({**MISTRAL, key: 8}, 2048)
 
 
 def test_training_flops_are_six_n_plus_attention():
