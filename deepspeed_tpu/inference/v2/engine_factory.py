@@ -2,7 +2,8 @@
 
 Reference ``build_hf_engine`` (inference/v2/engine_factory.py:66): resolves the
 model's policy by HF ``model_type`` and assembles InferenceEngineV2.  Supported:
-llama, mistral (sliding window), mixtral (MoE), opt, falcon, phi, qwen2, gptj.
+llama, mistral (sliding window), mixtral (MoE), olmoe (64-expert top-8 MoE with
+QK-norm; serving only, training not supported), opt, falcon, phi, qwen2, gptj.
 (BLOOM serves through the v1 engine — ALiBi needs the biased dense attention,
 models/bloom.py.)
 """
@@ -14,11 +15,12 @@ from .engine_v2 import InferenceEngineV2
 
 
 def _registry():
-    from ...models import falcon, gptj, llama, mistral, mixtral, opt, phi, qwen
+    from ...models import falcon, gptj, llama, mistral, mixtral, olmoe, opt, phi, qwen
     return {
         "llama": (llama, llama.config_from_hf),
         "mistral": (mistral, mistral.config_from_hf),
         "mixtral": (mixtral, None),  # config built field-by-field below
+        "olmoe": (olmoe, olmoe.config_from_hf),
         "opt": (opt, opt.config_from_hf),
         "falcon": (falcon, falcon.config_from_hf),
         "phi": (phi, phi.config_from_hf),

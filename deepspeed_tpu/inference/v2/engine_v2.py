@@ -10,6 +10,7 @@ one per ragged shape — the XLA analog of the reference's CUDA-graph-free
 ragged kernels.
 """
 
+import functools
 import inspect
 import json
 import os
@@ -269,7 +270,12 @@ class InferenceEngineV2:
             model_module.forward_paged).parameters
         self._live_token_bound: Optional[int] = (
             token_budget if takes_bound and self.fastpath.enabled else None)
-        self.counters = ServeCounters()
+        if hasattr(model_module, "moe_expert_rows"):  # a mixture of experts counts its rows
+            self.counters = ServeCounters(
+                moe_picks=model_module.moe_picks_per_token(model_config),
+                moe_rows=functools.partial(model_module.moe_expert_rows, model_config))
+        else:
+            self.counters = ServeCounters()
         # serving performance observatory (ISSUE 16): the compile ledger is
         # always on (no clock reads, no device work) and is the single source
         # of truth behind counters.compiles; the slot counters (ISSUE 24) are

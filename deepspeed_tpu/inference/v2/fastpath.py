@@ -37,7 +37,7 @@ forward under TP×DP meshes — the delta is broadcast once, never gathered.
 """
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,17 +86,26 @@ class ServeCounters:
     ``live_blocks``  of those, the entries that name a sequence's own block
     ``compact_passes``  forward passes that ran compacted: how often the
     bucket held more slots than the step's live-token bound
+
+    A mixture-of-experts model routes each token to k experts in every layer
+    (ISSUE 27; the model module states ``moe_picks`` = k x layers and
+    ``moe_rows(slots)``; a dense model has neither and both stay zero):
+    ``moe_expert_rows``  rows the expert FFNs' grouped matmuls ran over, from
+    the launched programs' static shapes: a pass's token slots x k, rounded up
+    to whole row tiles, in every layer
+    ``moe_routed_rows``  of those, the rows a live token was routed to
     """
 
     FIELDS = ("host_syncs", "dispatches", "uploads", "upload_ints", "compiles",
               "loop_iterations", "step_tokens", "burst_tokens", "flushes",
               "spec_rounds", "spec_proposed", "spec_accepted",
               "token_slots", "live_tokens", "table_slots", "live_blocks",
-              "compact_passes")
+              "compact_passes", "moe_routed_rows", "moe_expert_rows")
 
-    def __init__(self):
+    def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None):
         for f in self.FIELDS:
             setattr(self, f, 0)
+        self.moe_picks, self.moe_rows = moe_picks, moe_rows
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
                     live_blocks: int, passes: int = 1,
@@ -108,8 +117,12 @@ class ServeCounters:
         over in place of ``n x t`` (``models.transformer.flat_slots``), None
         for a padded program.  Host integers only: no clock read, no device
         sync."""
-        self.token_slots += (n * t if flat is None else flat) * passes
+        slots = n * t if flat is None else flat
+        self.token_slots += slots * passes
         self.live_tokens += live_tokens
+        if self.moe_rows is not None:
+            self.moe_expert_rows += self.moe_rows(slots) * passes
+            self.moe_routed_rows += live_tokens * self.moe_picks
         self.table_slots += n * b * passes
         self.live_blocks += live_blocks * passes
         self.compact_passes += passes if flat is not None else 0

@@ -1,4 +1,4 @@
-from . import (bert, bloom, falcon, gpt2, gptj, llama, mistral, mixtral, opt,
+from . import (bert, bloom, falcon, gpt2, gptj, llama, mistral, mixtral, olmoe, opt,
                phi, qwen, transformer)
 from .bert import BertConfig
 from .bloom import BloomConfig
@@ -8,6 +8,7 @@ from .gptj import GPTJConfig
 from .llama import LlamaConfig
 from .mistral import MistralConfig
 from .mixtral import MixtralConfig
+from .olmoe import OlmoeConfig
 from .opt import OPTConfig
 from .phi import PhiConfig
 from .qwen import QwenConfig

@@ -390,7 +390,8 @@ def init_paged_cache(config: LlamaConfig, num_blocks: int, block_size: int, dtyp
 def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, window: Optional[int] = None,
                   tp_axis: Optional[str] = None, gather_logits: bool = True,
-                  live_token_bound: Optional[int] = None):
+                  live_token_bound: Optional[int] = None,
+                  ffn: Optional[Callable] = None, qk_norm: Optional[Callable] = None):
     """Ragged chunked forward over the paged KV pool (FastGen model-forward
     analog, inference/v2/model_implementations/llama_v2 + blocked flash).
 
@@ -424,6 +425,16 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
     v2 sharding helpers, inference/v2/model_implementations/sharding/qkv.py +
     attn.py + mlp.py + unembed.py).  Head counts are derived from the (local)
     param shapes, so the same code serves single-chip and TP-sharded.
+
+    Two seams let a family that is this decoder but for one sub-layer run this
+    body and not a copy of it (models/mixtral.py, models/olmoe.py); both are
+    facts of an architecture, handed in by the family's module, never user
+    options.  ``ffn(layer_params, h, live)`` stands in for the dense SwiGLU:
+    ``h`` is the normed ``[b, s, D]`` the per-token layers run over (padded or
+    compacted), ``live`` the ``[b, s]`` mask of slots that hold a token, the
+    result the row-parallel partial this body psums.  ``qk_norm(layer_params,
+    q, k)`` acts on the projected queries ``[b, s, H, Dh]`` and keys
+    ``[b, s, KV, Dh]`` (the local heads) before they are rotated.
     """
     from ..ops.attention.paged import paged_attention
 
@@ -434,7 +445,7 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
     if slots is None:
         # the padded bucket as it is: the per-token layers see [N, T]
         b, tchunk = n, t
-        safe_pos, _, lengths, blk, off = paged_chunk_indices(
+        safe_pos, live, lengths, blk, off = paged_chunk_indices(
             tokens, n_tokens, start_pos, block_tables, num_blocks, block_size)
         to_padded = from_padded = lambda a: a
     else:
@@ -467,6 +478,8 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
         q = (attn_in @ lp["attn"]["wq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
         k = (attn_in @ lp["attn"]["wk"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
         v = (attn_in @ lp["attn"]["wv"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
+        if qk_norm is not None:
+            q, k = qk_norm(lp, q, k)
         q = apply_rotary(q, cos, sin, safe_pos)
         k = apply_rotary(k, cos, sin, safe_pos)
         # pool [NB, KV, bs, Dh]: pool[blk, h, off] = k[n, t, h]
@@ -477,7 +490,7 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
             block_size=block_size, softmax_scale=scale, window=window))
         x = x + preduce(out.reshape(b, tchunk, H * Dh) @ lp["attn"]["wo"].astype(x.dtype))
         mlp_in = rms_norm(x, lp["mlp_norm"], config.rms_eps)
-        x = x + preduce(swiglu_mlp(lp["mlp"], mlp_in))
+        x = x + preduce(swiglu_mlp(lp["mlp"], mlp_in) if ffn is None else ffn(lp, mlp_in, live))
         return x, (kpool, vpool)
 
     x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))

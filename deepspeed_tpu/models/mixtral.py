@@ -5,6 +5,10 @@ mixtral and trains MoE via deepspeed/moe; BASELINE.md config ladder step 5 is
 Mixtral-8x7B EP+Ulysses SP.  Llama backbone with the FFN replaced by a top-k
 gated expert layer; aux losses summed across layers and added to the LM loss
 (reference MoE aux-loss pattern, sharded_moe.py top2gating usage).
+
+Serving runs ``llama.forward_paged``'s one paged body with the expert FFN
+(``moe/serving.py``: sparse dispatch over a grouped matmul) in the dense FFN's
+place; ``models/olmoe.py`` is this module under OLMoE's configuration.
 """
 
 import dataclasses
@@ -17,8 +21,8 @@ import numpy as np
 from ..moe.experts import init_swiglu_experts, swiglu_experts
 from ..moe.sharded_moe import TopKGate, moe_layer
 from ..parallel.mesh import EXPERT_AXIS
-from .transformer import (attention_block, cross_entropy_loss, init_linear,
-                          paged_chunk_indices, rms_norm, rotary_tables)
+from .transformer import (attention_block, cross_entropy_loss, init_linear, rms_norm,
+                          rotary_tables)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +41,12 @@ class MixtralConfig:
     rope_theta: float = 1e6
     rms_eps: float = 1e-5
     remat: bool = True
+    # facts of a checkpoint's architecture, not switches: whether the top-k
+    # router weights are divided by their sum (Mixtral: yes; OLMoE: no), and
+    # whether q and k pass an RMSNorm over their whole width before rotary
+    # (OLMoE: yes).  Serving reads both; training stays Mixtral's.
+    norm_topk_prob: bool = True
+    qk_norm: bool = False
 
     @staticmethod
     def mixtral_8x7b():
@@ -67,15 +77,19 @@ def init_params(config: MixtralConfig, key, dtype=jnp.float32):
         return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_layer)
 
     gate_keys = jax.random.split(lk[4], L)
+    attn = {
+        "wq": stack(lk[0], D, H * head_dim),
+        "wk": stack(lk[1], D, KV * head_dim),
+        "wv": stack(lk[2], D, KV * head_dim),
+        "wo": stack(lk[3], H * head_dim, D),
+    }
+    if config.qk_norm:
+        attn["q_norm"] = jnp.ones((L, H * head_dim), dtype)
+        attn["k_norm"] = jnp.ones((L, KV * head_dim), dtype)
     return {
         "embed": jax.random.normal(k_emb, (config.vocab_size, D), dtype) * 0.02,
         "layers": {
-            "attn": {
-                "wq": stack(lk[0], D, H * head_dim),
-                "wk": stack(lk[1], D, KV * head_dim),
-                "wv": stack(lk[2], D, KV * head_dim),
-                "wo": stack(lk[3], H * head_dim, D),
-            },
+            "attn": attn,
             "moe": {
                 "gate": {"wg": jnp.stack([jax.random.normal(k, (D, config.num_experts), dtype) * 0.02
                                           for k in gate_keys])},
@@ -125,32 +139,6 @@ def make_loss_fn(config: MixtralConfig, attention_fn=None, topo=None) -> Callabl
         return lm + config.aux_loss_coef * aux, {"aux_loss": aux}
 
     return loss_fn
-
-
-# --------------------------------------------------------- paged (ragged) serve
-def dense_moe_ffn(moe_params, x, top_k: int):
-    """Serving-time MoE FFN: top-k routing with NO capacity dropping (the
-    reference's ragged moe_gather/moe_scatter semantics,
-    inference/v2/kernels/ragged_ops/moe_*): every token reaches its k experts.
-
-    Dense formulation: compute all experts, combine with the (renormalized)
-    top-k gate weights — exact at any batch size; a megablox-style grouped GEMM
-    is the later perf upgrade for many-expert configs.
-    """
-    ex = moe_params["experts"]
-    gate_logits = x @ moe_params["gate"]["wg"].astype(x.dtype)  # [.., E]
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    top_p, top_idx = jax.lax.top_k(probs, top_k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-    combine = jnp.zeros_like(probs).at[
-        jnp.arange(probs.shape[0])[:, None], top_idx].set(top_p)  # [T, E]
-
-    def one_expert(wg, wu, wd):
-        h = jax.nn.silu(x @ wg.astype(x.dtype)) * (x @ wu.astype(x.dtype))
-        return h @ wd.astype(x.dtype)
-
-    all_out = jax.vmap(one_expert)(ex["w_gate"], ex["w_up"], ex["w_down"])  # [E, T, D]
-    return jnp.einsum("te,etd->td", combine.astype(x.dtype), all_out)
 
 
 def from_hf_state_dict(config: MixtralConfig, state_dict, dtype=jnp.float32):
@@ -234,56 +222,79 @@ def make_tp_rules(config: MixtralConfig):
     def rules(path: str, shape) -> "int | None":
         if path.endswith(("attn.wk", "attn.wv")):
             return 2 if kv > 1 else None
+        if path.endswith("attn.q_norm"):
+            return 1  # [L, H * Dh]: a QK-norm gain follows its projection's heads
+        if path.endswith("attn.k_norm"):
+            return 1 if kv > 1 else None
         return tp_rules(path, shape)
 
     return rules
 
+
+# --------------------------------------------------------- paged (ragged) serve
+def moe_picks_per_token(config: MixtralConfig) -> int:
+    """Expert rows one token routes through a forward pass: k in every layer
+    (what ``ServeCounters.moe_routed_rows`` counts a live token as)."""
+    return config.top_k * config.num_layers
+
+
+def moe_expert_rows(config: MixtralConfig, slots: int) -> int:
+    """Rows the expert FFNs of one forward pass over ``slots`` token slots run
+    their grouped matmuls over (``ServeCounters.moe_expert_rows``)."""
+    from ..moe.serving import expert_rows
+    return expert_rows(slots, config.top_k) * config.num_layers
+
+
+def whole_width_qk_norm(config: MixtralConfig, tp_axis: Optional[str]):
+    """OLMoE's QK-norm for ``llama.forward_paged``'s ``qk_norm`` seam: an
+    RMSNorm with a learned gain over the WHOLE projected width (all heads
+    together, not head by head), before rotary.  The body hands the local
+    heads ``[b, s, heads, Dh]``; where they are a tensor-parallel shard of the
+    width, the sum of squares is psum'd so the statistic is the full width's."""
+    dh = config.hidden_size // config.num_heads
+
+    def norm(x, gain, full_width):
+        x32 = x.astype(jnp.float32)
+        ss = jnp.sum(x32 * x32, axis=(-2, -1), keepdims=True)
+        if tp_axis is not None and x.shape[-2] * dh != full_width:
+            ss = jax.lax.psum(ss, tp_axis)
+        x32 = x32 * jax.lax.rsqrt(ss / full_width + config.rms_eps)
+        return (x32 * gain.astype(jnp.float32).reshape(x.shape[-2:])).astype(x.dtype)
+
+    def qk_norm(lp, q, k):
+        return (norm(q, lp["attn"]["q_norm"], config.num_heads * dh),
+                norm(k, lp["attn"]["k_norm"], config.num_kv_heads * dh))
+
+    return qk_norm
+
+
 def forward_paged(config: MixtralConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
     """Ragged chunked forward (reference inference/v2/model_implementations/
-    mixtral): llama-style paged attention + no-drop top-k MoE FFN per layer.
+    mixtral): ``llama.forward_paged``'s body, compaction included, with the
+    dense SwiGLU of a layer replaced by the no-drop sparse top-k expert FFN
+    (moe/serving.py) and, where the checkpoint has it, QK-norm before rotary.
+    Under ``tp_axis`` the experts are sharded on their width and the body
+    psums the expert FFN's partial sum like a dense row-parallel FFN's."""
+    from . import llama
+    from ..moe.serving import sparse_moe_ffn
 
-    ``tp_axis``: see models/llama.py forward_paged — head counts come from the
-    local param shapes, row-parallel partials (wo, expert w_down) are psum'd."""
-    from ..ops.attention.paged import paged_attention
-    from .transformer import apply_rotary
+    # the body scans the layers' leaves; the experts stay one stack, and each
+    # layer is handed its index into it (moe/serving.py says why)
+    layers = params["layers"]
+    experts = layers["moe"]["experts"]
+    moe = {"gate": layers["moe"]["gate"], "layer": jnp.arange(config.num_layers, dtype=jnp.int32)}
+    params = {**params, "layers": {**layers, "moe": moe}}
 
-    b, tchunk = tokens.shape
-    cos, sin = rotary_tables(config.hidden_size // config.num_heads, config.max_seq_len,
-                             config.rope_theta)
-    safe_pos, valid, lengths, blk, off = paged_chunk_indices(
-        tokens, n_tokens, start_pos, block_tables, kv_cache["k"].shape[1], block_size)
-    x = params["embed"][tokens].astype(kv_cache["k"].dtype)
-    Dh = config.hidden_size // config.num_heads  # true head dim: TP-invariant
-    H = params["layers"]["attn"]["wq"].shape[-1] // Dh   # local (per-shard) heads
-    KV = params["layers"]["attn"]["wk"].shape[-1] // Dh
-    scale = 1.0 / np.sqrt(Dh)
-    head_idx = jnp.arange(KV)[None, None, :]
-    preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
+    def ffn(lp, h, live):
+        out = sparse_moe_ffn({"gate": lp["moe"]["gate"], "experts": experts},
+                             h.reshape(-1, h.shape[-1]), config.top_k, config.norm_topk_prob,
+                             live.reshape(-1), layer=lp["moe"]["layer"])
+        return out.reshape(h.shape)
 
-    def layer(x, inp):
-        lp, kpool, vpool = inp
-        attn_in = rms_norm(x, lp["attn_norm"], config.rms_eps)
-        q = (attn_in @ lp["attn"]["wq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        k = (attn_in @ lp["attn"]["wk"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        v = (attn_in @ lp["attn"]["wv"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        q = apply_rotary(q, cos, sin, safe_pos)
-        k = apply_rotary(k, cos, sin, safe_pos)
-        kpool = kpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(k)
-        vpool = vpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(v)
-        out = paged_attention(q, kpool, vpool, block_tables, lengths, start_pos, n_tokens,
-                              block_size=block_size, softmax_scale=scale)
-        x = x + preduce(out.reshape(b, tchunk, H * Dh) @ lp["attn"]["wo"].astype(x.dtype))
-        moe_in = rms_norm(x, lp["mlp_norm"], config.rms_eps)
-        flat = moe_in.reshape(b * tchunk, config.hidden_size)
-        moe_out = preduce(dense_moe_ffn(lp["moe"], flat, config.top_k))
-        x = x + moe_out.reshape(b, tchunk, config.hidden_size)
-        return x, (kpool, vpool)
-
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = rms_norm(x, params["final_norm"], config.rms_eps)
-    logits = x @ params["lm_head"].astype(x.dtype)
-    if tp_axis is not None and gather_logits:
-        logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
-    return logits, {"k": new_k, "v": new_v}
+    return llama.forward_paged(
+        _llama_view(config), params, tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, tp_axis=tp_axis, gather_logits=gather_logits,
+        live_token_bound=live_token_bound, ffn=ffn,
+        qk_norm=whole_width_qk_norm(config, tp_axis) if config.qk_norm else None)

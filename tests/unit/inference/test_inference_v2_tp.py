@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
-from deepspeed_tpu.models import llama, mistral, mixtral
+from deepspeed_tpu.models import llama, mistral, mixtral, olmoe
 from deepspeed_tpu.parallel import MeshTopology
 
 PROMPTS = [[1, 2, 3, 4, 5, 6, 7], [9, 10, 11], [20, 21, 22, 23, 24]]
@@ -45,11 +45,20 @@ def test_llama_tp2_stepwise_path():
     assert got == ref
 
 
-def test_mixtral_tp2_token_identical():
-    cfg = mixtral.MixtralConfig.tiny(vocab=128, hidden=64, layers=2, heads=4,
-                                     kv_heads=2, experts=4, seq=128)
-    params = mixtral.init_params(cfg, jax.random.PRNGKey(2))
-    single, sharded = _pair(mixtral, cfg, params)
+@pytest.mark.parametrize("module,cfg", [
+    (mixtral, mixtral.MixtralConfig.tiny(vocab=128, hidden=64, layers=2, heads=4,
+                                         kv_heads=2, experts=4, seq=128)),
+    # experts sharded on their width, QK-norm's statistic psum'd over the head shards
+    (olmoe, olmoe.OlmoeConfig.tiny(vocab=128, hidden=64, layers=2, heads=4, kv_heads=4,
+                                   experts=8, top_k=4, seq=128))], ids=["mixtral", "olmoe"])
+def test_mixtral_tp2_token_identical(module, cfg):
+    params = module.init_params(cfg, jax.random.PRNGKey(2))
+    if cfg.qk_norm:  # gains that are not one, or a gain sharded wrongly would change nothing
+        for i, name in enumerate(("q_norm", "k_norm")):
+            gain = params["layers"]["attn"][name]
+            params["layers"]["attn"][name] = gain + 0.5 * jax.random.normal(
+                jax.random.PRNGKey(7 + i), gain.shape)
+    single, sharded = _pair(module, cfg, params)
     ref = single.generate(PROMPTS, max_new_tokens=5)
     got = sharded.generate(PROMPTS, max_new_tokens=5)
     assert got == ref

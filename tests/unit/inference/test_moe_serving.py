@@ -1,0 +1,212 @@
+"""Serving-time mixture of experts: the sparse dispatch against a dense oracle
+of the test's own, OLMoE through the engine (compaction, counters), and the
+HF door (``engine_factory``)."""
+
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.v2 import InferenceEngineV2
+from deepspeed_tpu.models import llama, mixtral, olmoe
+from deepspeed_tpu.moe.serving import expert_rows, route, sparse_moe_ffn
+
+D, F = 32, 16
+
+
+def dense_oracle(moe, x, top_k, renormalise, live):
+    """Every expert over every token, combined with the router's weights at
+    each token's picks: what the program's dense formulation computed."""
+    probs = jax.nn.softmax(x @ moe["gate"]["wg"], axis=-1)
+    top_p, top_idx = jax.lax.top_k(probs, top_k)
+    if renormalise:
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+    combine = jnp.zeros_like(probs).at[jnp.arange(x.shape[0])[:, None], top_idx].set(top_p)
+    ex = moe["experts"]
+    every = jnp.einsum("etf,efd->etd", jax.nn.silu(jnp.einsum("td,edf->etf", x, ex["w_gate"]))
+                       * jnp.einsum("td,edf->etf", x, ex["w_up"]), ex["w_down"])
+    return jnp.einsum("te,etd->td", combine, every) * live[:, None]
+
+
+def drawn_moe(num_experts, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    moe = {"gate": {"wg": jax.random.normal(ks[0], (D, num_experts)) * D ** -0.5},
+           "experts": {"w_gate": jax.random.normal(ks[1], (num_experts, D, F)) * D ** -0.5,
+                       "w_up": jax.random.normal(ks[2], (num_experts, D, F)) * D ** -0.5,
+                       "w_down": jax.random.normal(ks[3], (num_experts, F, D)) * F ** -0.5}}
+    return moe, jax.random.normal(ks[4], (24, D))
+
+
+@pytest.mark.parametrize("routing", ["dead_slots", "one_expert_takes_every_token",
+                                     "an_expert_takes_none"])
+@pytest.mark.parametrize("num_experts,top_k", [(8, 2), (8, 4), (64, 8)])
+def test_sparse_dispatch_equals_the_dense_oracle(num_experts, top_k, routing):
+    moe, x = drawn_moe(num_experts)
+    live = jnp.ones(24, bool)
+    if routing == "dead_slots":
+        live = jnp.asarray(np.random.default_rng(0).random(24) < 0.6)
+    else:
+        # a constant direction added to the tokens makes expert 3 every token's
+        # first pick, or expert 5 no token's pick
+        x = x + 4.0
+        column, sign = (3, 1.0) if routing == "one_expert_takes_every_token" else (5, -1.0)
+        moe["gate"]["wg"] = moe["gate"]["wg"].at[:, column].set(sign)
+    with jax.default_matmul_precision("highest"):
+        for renormalise in (False, True):
+            _, picks = route(moe["gate"]["wg"], x, top_k, renormalise)
+            took = np.bincount(np.asarray(picks)[np.asarray(live)].ravel(), minlength=num_experts)
+            if routing == "one_expert_takes_every_token":
+                assert took[3] == 24
+            elif routing == "an_expert_takes_none":
+                assert took[5] == 0
+            got = jax.jit(sparse_moe_ffn, static_argnums=(2, 3))(moe, x, top_k, renormalise, live)
+            want = dense_oracle(moe, x, top_k, renormalise, live)
+            assert np.abs(np.asarray(got - want)).max() < 1e-5 * np.abs(np.asarray(want)).max()
+            assert (np.asarray(got)[~np.asarray(live)] == 0).all()
+
+
+@pytest.mark.parametrize("slots,top_k,rows", [(256, 8, 2048), (32, 8, 256), (16, 8, 128),
+                                              (1, 8, 16), (4, 2, 16), (100, 2, 256)])
+def test_expert_rows_are_whole_row_tiles(slots, top_k, rows):
+    assert expert_rows(slots, top_k) == rows
+
+
+def test_interpreted_gmm_kernel_equals_the_xla_path(monkeypatch):
+    from deepspeed_tpu.ops import _pallas
+    moe, x = drawn_moe(8)
+    live = jnp.asarray(np.random.default_rng(1).random(24) < 0.7)
+    want = sparse_moe_ffn(moe, x, 4, False, live)
+    monkeypatch.setattr(_pallas, "INTERPRET", True)
+    got = sparse_moe_ffn(moe, x, 4, False, live)  # 96 picks: one tile of 96 rows
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+    few = sparse_moe_ffn(moe, x[:3], 4, False, live[:3])  # 12 picks in a tile of 16
+    assert np.abs(np.asarray(few - want[:3])).max() < 1e-5
+
+
+def test_router_is_float32_whatever_the_activations_are():
+    moe, x = drawn_moe(8)
+    weights, picks = route(moe["gate"]["wg"].astype(jnp.bfloat16), x.astype(jnp.bfloat16), 4, False)
+    assert weights.dtype == jnp.float32 and picks.dtype == jnp.int32
+    assert (np.asarray(weights).sum(-1) < 1.0).all()  # not renormalised
+    renormalised, _ = route(moe["gate"]["wg"], x, 4, True)
+    assert np.allclose(np.asarray(renormalised).sum(-1), 1.0, atol=1e-6)
+
+
+# ------------------------------------------------------------------ the engine
+_KW = dict(num_blocks=64, block_size=8, max_blocks_per_seq=8, token_budget=16,
+           max_seqs_per_step=4)
+PROMPTS = [list(range(1, 30)), [9, 10, 11], [5, 6, 7, 8, 9, 10]]
+
+
+def _tiny(module):
+    if module is olmoe:
+        cfg = olmoe.OlmoeConfig.tiny(vocab=128, hidden=64, layers=2, heads=4, kv_heads=4,
+                                     experts=8, top_k=4, seq=128)
+    else:
+        cfg = mixtral.MixtralConfig.tiny(vocab=128, hidden=64, layers=2, heads=4, kv_heads=2,
+                                         experts=4, seq=128)
+    return cfg, module.init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("module", [mixtral, olmoe], ids=["mixtral", "olmoe"])
+def test_moe_families_serve_compacted_with_no_option_and_count_their_rows(module):
+    cfg, params = _tiny(module)
+    eng = InferenceEngineV2(module, cfg, params, config={"dtype": "float32"}, **_KW)
+    assert eng._live_token_bound == 16  # the one paged body's compaction, by the engine's sniff
+    got = eng.generate(PROMPTS, max_new_tokens=6)
+    padded = InferenceEngineV2(module, cfg, params, config={
+        "dtype": "float32", "serving_fastpath": {"enabled": False}}, **_KW)
+    assert got == padded.generate(PROMPTS, max_new_tokens=6)
+    c = eng.counters
+    picks = cfg.top_k * cfg.num_layers
+    assert c.compact_passes > 0 and padded.counters.compact_passes == 0
+    assert c.moe_routed_rows == c.live_tokens * picks > 0
+    # whole row tiles: a pass over 4 slots x top-2 is 8 routed rows in a tile of 16
+    assert c.token_slots * picks <= c.moe_expert_rows <= 2 * c.token_slots * picks
+    assert module.moe_expert_rows(cfg, 16) == expert_rows(16, cfg.top_k) * cfg.num_layers
+    assert set(eng.counters.delta_since(eng.counters.snapshot())) >= {"moe_routed_rows",
+                                                                     "moe_expert_rows"}
+
+
+def test_a_dense_model_routes_no_rows():
+    cfg = llama.LlamaConfig.tiny(vocab=128, hidden=64, layers=2, heads=4, kv_heads=2, seq=128)
+    eng = InferenceEngineV2(llama, cfg, llama.init_params(cfg, jax.random.PRNGKey(0)),
+                            config={"dtype": "float32"}, **_KW)
+    eng.generate(PROMPTS, max_new_tokens=4)
+    assert eng.counters.token_slots > 0
+    assert eng.counters.moe_routed_rows == eng.counters.moe_expert_rows == 0
+
+
+def test_mixtral_paged_forward_has_no_layer_loop_and_the_dense_ffn_is_gone():
+    import inspect
+    source = inspect.getsource(mixtral.forward_paged)
+    assert "lax.scan" not in source and "llama.forward_paged(" in source
+    assert not hasattr(mixtral, "dense_moe_ffn")
+    assert olmoe.forward_paged is mixtral.forward_paged
+
+
+def test_olmoe_training_is_refused():
+    cfg, params = _tiny(olmoe)
+    with pytest.raises(ValueError, match="k=1 or k=2"):
+        mixtral.forward(cfg, params, jnp.zeros((1, 8), jnp.int32))
+
+
+# --------------------------------------------------------------- the HF door
+def _hf_olmoe(cfg):
+    """A synthetic ``OlmoeForCausalLM``: HF names, torch layout [out, in]."""
+    rng = np.random.default_rng(0)
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    sd = {"model.embed_tokens.weight": rng.normal(size=(cfg.vocab_size, d)),
+          "model.norm.weight": rng.normal(size=(d,)),
+          "lm_head.weight": rng.normal(size=(cfg.vocab_size, d))}
+    for i in range(cfg.num_layers):
+        pre = f"model.layers.{i}."
+        for name in ("q_proj", "k_proj", "v_proj", "o_proj"):
+            sd[pre + f"self_attn.{name}.weight"] = rng.normal(size=(d, d))
+        for name in ("q_norm", "k_norm"):
+            sd[pre + f"self_attn.{name}.weight"] = rng.normal(size=(d,))
+        sd[pre + "input_layernorm.weight"] = rng.normal(size=(d,))
+        sd[pre + "post_attention_layernorm.weight"] = rng.normal(size=(d,))
+        sd[pre + "mlp.gate.weight"] = rng.normal(size=(cfg.num_experts, d))
+        for e in range(cfg.num_experts):
+            sd[pre + f"mlp.experts.{e}.gate_proj.weight"] = rng.normal(size=(f, d))
+            sd[pre + f"mlp.experts.{e}.up_proj.weight"] = rng.normal(size=(f, d))
+            sd[pre + f"mlp.experts.{e}.down_proj.weight"] = rng.normal(size=(d, f))
+    sd = {k: v.astype(np.float32) for k, v in sd.items()}
+    hf_config = types.SimpleNamespace(
+        model_type="olmoe", vocab_size=cfg.vocab_size, hidden_size=d, intermediate_size=f,
+        num_hidden_layers=cfg.num_layers, num_attention_heads=cfg.num_heads,
+        num_key_value_heads=cfg.num_kv_heads, num_experts=cfg.num_experts,
+        num_experts_per_tok=cfg.top_k, max_position_embeddings=cfg.max_seq_len,
+        rope_theta=10000.0, rms_norm_eps=1e-5, norm_topk_prob=False, clip_qkv=None)
+    return types.SimpleNamespace(config=hf_config, state_dict=lambda: sd), sd
+
+
+def test_olmoe_state_dict_loads_into_the_layout_the_reference_draws():
+    from chipbench.references import olmoe as ref
+    cfg = olmoe.OlmoeConfig.tiny(vocab=64, hidden=32, layers=2, heads=4, kv_heads=4,
+                                 experts=4, top_k=2, seq=64)
+    hf_model, sd = _hf_olmoe(cfg)
+    params = olmoe.from_hf_state_dict(cfg, sd)
+    sizes = {"hidden_size": 32, "intermediate_size": 16, "num_attention_heads": 4,
+             "num_key_value_heads": 4, "num_hidden_layers": 2, "vocab_size": 64, "num_experts": 4}
+    drawn = jax.eval_shape(lambda k: ref.init_params(sizes, k, jnp.float32), jax.random.PRNGKey(0))
+    shapes = lambda tree: jax.tree_util.tree_map(lambda a: a.shape, tree)
+    assert shapes(params) == shapes(drawn) == shapes(olmoe.init_params(cfg, jax.random.PRNGKey(0)))
+    layers = params["layers"]
+    assert np.array_equal(layers["moe"]["experts"]["w_down"][1, 3],
+                          sd["model.layers.1.mlp.experts.3.down_proj.weight"].T)
+    assert np.array_equal(layers["moe"]["gate"]["wg"][0], sd["model.layers.0.mlp.gate.weight"].T)
+    assert np.array_equal(layers["attn"]["k_norm"][1], sd["model.layers.1.self_attn.k_norm.weight"])
+
+    # the registry resolves model_type olmoe, and the engine it builds serves
+    from deepspeed_tpu.inference.v2.engine_factory import build_hf_engine
+    eng = build_hf_engine(hf_model, config={"dtype": "float32"}, num_blocks=16, block_size=8,
+                          max_blocks_per_seq=4)
+    assert eng.model is olmoe and eng.model_config == cfg
+    assert len(eng.generate([[1, 2, 3]], max_new_tokens=2)[0]) == 5
+    hf_model.config.clip_qkv = 8.0
+    with pytest.raises(ValueError, match="clip_qkv"):
+        olmoe.config_from_hf(hf_model.config)

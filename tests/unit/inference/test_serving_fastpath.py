@@ -443,10 +443,12 @@ def test_a_step_over_the_bound_is_refused_before_dispatch():
     assert len(ref.step()) == 1
 
 
-def test_the_seven_other_families_are_not_handed_the_bound():
-    from deepspeed_tpu.models import bloom, falcon, gptj, mixtral, opt, phi, qwen
-    for module in (bloom, falcon, gptj, mixtral, opt, phi, qwen):
+def test_the_six_other_families_are_not_handed_the_bound():
+    from deepspeed_tpu.models import bloom, falcon, gptj, mixtral, olmoe, opt, phi, qwen
+    for module in (bloom, falcon, gptj, opt, phi, qwen):
         assert "live_token_bound" not in inspect.signature(module.forward_paged).parameters
+    for module in (mixtral, olmoe):  # llama.forward_paged's body since ISSUE 27
+        assert "live_token_bound" in inspect.signature(module.forward_paged).parameters
     cfg = opt.OPTConfig.tiny()
     eng = InferenceEngineV2(opt, cfg, opt.init_params(cfg, jax.random.PRNGKey(0)),
                             config={"dtype": "float32"}, num_blocks=32, block_size=8,
