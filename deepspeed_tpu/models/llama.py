@@ -378,7 +378,13 @@ def init_paged_cache(config: LlamaConfig, num_blocks: int, block_size: int, dtyp
     """Paged KV pool (reference inference/v2/ragged blocked KV layout):
     [L, num_blocks, KV, block_size, Dh] — heads-major so the Pallas paged
     kernel's trailing (block_size, Dh) tile satisfies TPU tiling.  The last
-    block is reserved as a trash target for padded-token writes."""
+    block of each layer is reserved as a trash target for padded-token writes.
+
+    One stacked array per K and V, layer axis first: ``forward_paged`` writes
+    and reads it where it lies (block b of layer l is row ``l * num_blocks +
+    b`` of the free ``[L * num_blocks, KV, block_size, Dh]`` view), and the
+    engine's copy-on-write, its TP spec (heads on axis 2) and the benchmark's
+    pool-shape reader rest on this layout."""
     L, KV = config.num_layers, config.num_kv_heads
     Dh = config.hidden_size // config.num_heads
     return {
@@ -399,6 +405,17 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
     start_pos [N] absolute start of this chunk, block_tables [N, MAXB]
     (padded entries point at the trash block).  ``window`` enables Mistral-style
     sliding-window attention.  Returns (logits [N, T, V], new kv_cache).
+
+    ``kv_cache`` is ``{"k", "v"}`` of ``[L, NB, KV, bs, Dh]`` in and out.  The
+    layer scan CARRIES both pools whole beside the activations (its ``xs`` is
+    the layers' parameters and the layer's index); each layer scatters this
+    step's rows (live tokens x KV x Dh; a dead slot's into the layer's trash
+    block) into the carried stack in place and hands the paged kernel the
+    stack as one pool of ``L * NB`` blocks, with the block table offset by the
+    layer's first row ``l * NB``: the kernel knows nothing of layers.  No
+    layer is ever cut out of the pool or stacked back, so a jitted caller
+    that donates ``kv_cache`` (or carries it through a loop of its own, as the
+    fused burst does) runs with the one pool it was given and no copy of it.
 
     ``live_token_bound``: the caller's promise that ``sum(n_tokens)`` never
     passes it (the serving engine hands its scheduler's ``token_budget``).
@@ -472,8 +489,9 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
     head_idx = jnp.arange(KV)[None, None, :]
     preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
 
-    def layer(x, inp):
-        lp, kpool, vpool = inp
+    def layer(carry, inp):
+        x, kpool, vpool = carry  # the pools whole: [L*NB, KV, bs, Dh]
+        lp, l = inp
         attn_in = rms_norm(x, lp["attn_norm"], config.rms_eps)
         q = (attn_in @ lp["attn"]["wq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
         k = (attn_in @ lp["attn"]["wk"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
@@ -482,18 +500,32 @@ def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, bloc
             q, k = qk_norm(lp, q, k)
         q = apply_rotary(q, cos, sin, safe_pos)
         k = apply_rotary(k, cos, sin, safe_pos)
-        # pool [NB, KV, bs, Dh]: pool[blk, h, off] = k[n, t, h]
-        kpool = kpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(k)
-        vpool = vpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(v)
+        # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h].  One
+        # index per (token, head): a token's heads written as one window
+        # (.at[row, :, off]) makes the compiler relayout the pool, two copies a pass
+        first = l * num_blocks  # the layer's first row of the flat stack
+        row = (first + blk)[:, :, None]
+        kpool = kpool.at[row, head_idx, off[:, :, None]].set(k)
+        vpool = vpool.at[row, head_idx, off[:, :, None]].set(v)
+        # the kernel takes the flat stack as it would one layer's pool (a Pallas
+        # operand is materialised, so kpool[l] would be a copy): the table is offset
         out = from_padded(paged_attention(
-            to_padded(q), kpool, vpool, block_tables, lengths, start_pos, n_tokens,
+            to_padded(q), kpool, vpool, block_tables + first, lengths, start_pos, n_tokens,
             block_size=block_size, softmax_scale=scale, window=window))
         x = x + preduce(out.reshape(b, tchunk, H * Dh) @ lp["attn"]["wo"].astype(x.dtype))
         mlp_in = rms_norm(x, lp["mlp_norm"], config.rms_eps)
         x = x + preduce(swiglu_mlp(lp["mlp"], mlp_in) if ffn is None else ffn(lp, mlp_in, live))
-        return x, (kpool, vpool)
+        return (x, kpool, vpool), None
 
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
+    # The pool is carried, never sliced (xs) and restacked (ys): a scan's ys is
+    # a new [L, ...] array that cannot alias a donated argument still being
+    # read, which cost a slice, an update and a copy of the whole pool a pass.
+    pool_shape = kv_cache["k"].shape
+    flat = (-1, ) + pool_shape[2:]
+    (x, new_k, new_v), _ = jax.lax.scan(
+        layer, (x, kv_cache["k"].reshape(flat), kv_cache["v"].reshape(flat)),
+        (params["layers"], jnp.arange(pool_shape[0], dtype=jnp.int32)))
+    new_k, new_v = new_k.reshape(pool_shape), new_v.reshape(pool_shape)
     x = rms_norm(x, params["final_norm"], config.rms_eps)
     head = params["embed"].T if config.tie_embeddings else params["lm_head"]
     logits = x @ head.astype(x.dtype)

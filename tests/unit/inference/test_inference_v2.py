@@ -84,36 +84,76 @@ def test_splitfuse_long_prompt_across_steps():
 
 
 # ------------------------------------------------------- paged Pallas kernel
-def test_paged_attention_kernel_parity():
-    """Blocked kernel (interpret mode) == dense-gather fallback, with and
-    without sliding window and with padding rows."""
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
     from deepspeed_tpu.ops import _pallas
-    from deepspeed_tpu.ops.attention.paged import _dense_fallback, paged_attention
-    rng = np.random.default_rng(0)
-    N, T, H, KV, Dh, NB, BS, MAXB = 3, 4, 4, 2, 32, 16, 8, 4
+    monkeypatch.setattr(_pallas, "INTERPRET", True)
+
+
+def _paged_case(H, KV, T, layers=None, seed=0):
+    """A drawn ragged batch over a pool of 16 blocks of 8: one layer's pool
+    [NB, KV, bs, Dh], or ``layers`` of them stacked."""
+    rng = np.random.default_rng(seed)
+    N, Dh, NB, BS, MAXB = 3, 32, 16, 8, 4
+    pool = (NB, KV, BS, Dh) if layers is None else (layers, NB, KV, BS, Dh)
     q = jnp.asarray(rng.normal(size=(N, T, H, Dh)), jnp.float32)
-    kpool = jnp.asarray(rng.normal(size=(NB, KV, BS, Dh)), jnp.float32)
-    vpool = jnp.asarray(rng.normal(size=(NB, KV, BS, Dh)), jnp.float32)
+    kpool = jnp.asarray(rng.normal(size=pool), jnp.float32)
+    vpool = jnp.asarray(rng.normal(size=pool), jnp.float32)
     tables = jnp.asarray(rng.integers(0, NB - 1, (N, MAXB)), jnp.int32)
     lengths = jnp.asarray([5, 20, 31], jnp.int32)
-    n_tokens = jnp.asarray([3, 4, 4], jnp.int32)  # seq 0 has a padding row
-    start_pos = lengths - n_tokens
-    scale = 1.0 / np.sqrt(Dh)
-    old = _pallas.INTERPRET
-    _pallas.INTERPRET = True
-    try:
-        slopes = jnp.asarray([0.5, 0.25, 0.125, 0.0625], jnp.float32)  # [H]
-        for window, alibi in ((None, None), (6, None), (None, slopes)):
-            ref = _dense_fallback(q, kpool, vpool, tables, lengths, start_pos,
-                                  n_tokens, scale, window, alibi)
-            got = paged_attention(q, kpool, vpool, tables, lengths, start_pos,
-                                  n_tokens, block_size=BS, window=window,
-                                  alibi_slopes=alibi)
-            valid = np.asarray(jnp.arange(T)[None, :] < n_tokens[:, None])
-            np.testing.assert_allclose(np.asarray(got)[valid], np.asarray(ref)[valid],
-                                       atol=2e-5)
-    finally:
-        _pallas.INTERPRET = old
+    n_tokens = jnp.asarray([max(T - 1, 1), T, T], jnp.int32)  # seq 0 has a padding row
+    return q, kpool, vpool, tables, lengths, lengths - n_tokens, n_tokens
+
+
+@pytest.mark.parametrize("window,alibi", [(None, False), (6, False), (None, True)],
+                         ids=["full", "window6", "alibi"])
+def test_paged_attention_kernel_parity(interpreted_kernels, window, alibi):
+    """Blocked kernel (interpret mode) == dense-gather fallback, with and
+    without sliding window and with padding rows (one layer's pool: the
+    rank-4 call of the six families, ALiBi's among them)."""
+    from deepspeed_tpu.ops.attention.paged import _dense_fallback, paged_attention
+    q, kpool, vpool, tables, lengths, start_pos, n_tokens = _paged_case(H=4, KV=2, T=4)
+    slopes = jnp.asarray([0.5, 0.25, 0.125, 0.0625], jnp.float32) if alibi else None
+    ref = _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
+                          1.0 / np.sqrt(q.shape[-1]), window, slopes)
+    got = paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
+                          block_size=8, window=window, alibi_slopes=slopes)
+    valid = np.asarray(jnp.arange(q.shape[1])[None, :] < n_tokens[:, None])
+    np.testing.assert_allclose(np.asarray(got)[valid], np.asarray(ref)[valid], atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 6], ids=["full", "window6"])
+@pytest.mark.parametrize("T", [1, 4], ids=["decode", "chunk"])
+@pytest.mark.parametrize("H,KV", [(4, 1), (4, 4)], ids=["group4", "group1"])
+def test_paged_attention_reads_a_layer_of_the_flat_stack_through_offset_tables(
+        interpreted_kernels, monkeypatch, H, KV, T, window):
+    """What ``llama.forward_paged`` rests on: every layer's pool as one
+    [L*NB, KV, bs, Dh] and the block table offset by ``l*NB`` (traced, as the
+    layer scan's index is) give the kernel and the fallback the bits of layer
+    l handed alone, at the last layer (an offset lost would read layer 0) and
+    at a middle one."""
+    from deepspeed_tpu.ops.attention.paged import _dense_fallback, paged_attention
+    L, NB = 3, 16
+    q, kstack, vstack, tables, *rest = _paged_case(H, KV, T, layers=L)
+    lengths, start_pos, n_tokens = rest
+    kflat, vflat = (a.reshape((L * NB, ) + a.shape[2:]) for a in (kstack, vstack))
+    kw = dict(block_size=8, window=window)
+    valid = np.asarray(jnp.arange(T)[None, :] < n_tokens[:, None])
+    offset = jax.jit(lambda l: paged_attention(q, kflat, vflat, tables + l * NB, *rest, **kw))
+    first = np.asarray(paged_attention(q, kstack[0], vstack[0], tables, *rest, **kw))[valid]
+    for l in (L - 1, 1):
+        alone = np.asarray(paged_attention(q, kstack[l], vstack[l], tables, *rest, **kw))[valid]
+        np.testing.assert_array_equal(np.asarray(offset(jnp.int32(l)))[valid], alone)
+        assert not np.array_equal(alone, first)
+    ref = _dense_fallback(q, kstack[L - 1], vstack[L - 1], tables, lengths, start_pos, n_tokens,
+                          1.0 / np.sqrt(q.shape[-1]), window)
+    np.testing.assert_allclose(np.asarray(offset(jnp.int32(L - 1)))[valid],
+                               np.asarray(ref)[valid], atol=2e-5)
+    # off the TPU the same call is the fallback's own indexing: the same bits
+    from deepspeed_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, "INTERPRET", False)
+    fell_back = paged_attention(q, kflat, vflat, tables + (L - 1) * NB, *rest, **kw)
+    np.testing.assert_array_equal(np.asarray(fell_back)[valid], np.asarray(ref)[valid])
 
 
 # ------------------------------------------------------------- mistral v2
