@@ -2,7 +2,7 @@
 
 Analog of the reference BlockedAllocator (inference/v2/ragged/blocked_allocator.py):
 fixed number of KV blocks, O(1) allocate/free via a free list.  The last block
-id is reserved as the trash target for padded writes (models.llama.forward_paged).
+id is reserved as the trash target for padded writes (models.transformer.paged_forward).
 
 Block-level ref-counting (ISSUE 13): a block can be mapped read-only by more
 than one sequence at a time (copy-on-write prefix sharing —

@@ -238,12 +238,6 @@ class InferenceEngineV2:
             # TP-sharded serving (reference engine_v2.py:81 builds on a TP group;
             # sharding helpers inference/v2/model_implementations/sharding/)
             from . import tp as _tp
-            if "tp_axis" not in inspect.signature(model_module.forward_paged).parameters:
-                raise NotImplementedError(
-                    f"{model_module.__name__}.forward_paged has no tp_axis support; "
-                    f"all built-in paged families (llama/mistral/mixtral/opt/falcon/"
-                    f"phi/qwen) ship it — thread tp_axis through custom models the "
-                    f"same way (psum after row-parallel projections)")
             _tp.validate_model(model_config, self.tp, model_module=model_module)
             self._param_specs = _tp.param_specs(model_module, params, self.tp,
                                                 model_config=model_config)
@@ -261,15 +255,13 @@ class InferenceEngineV2:
         # state replicates over the engine's mesh (ISSUE 15) so the same
         # ≤1-sync loop drives the shard_mapped forward unchanged.
         self.fastpath = self.config.serving_fastpath
-        # the step's live-token bound, handed to a forward that takes it
-        # (models/llama.py forward_paged) so a bucket with more slots than the
-        # scheduler ever fills computes its live tokens, not its padding
-        # (ISSUE 25).  The reference step stays padded: it is the oracle the
-        # compacted program is compared with.
-        takes_bound = "live_token_bound" in inspect.signature(
-            model_module.forward_paged).parameters
+        # the step's live-token bound, handed to every family's forward
+        # (models/transformer.py paged_forward states the contract) so a bucket
+        # with more slots than the scheduler ever fills computes its live
+        # tokens, not its padding (ISSUE 25).  The reference step stays padded:
+        # it is the oracle the compacted program is compared with.
         self._live_token_bound: Optional[int] = (
-            token_budget if takes_bound and self.fastpath.enabled else None)
+            token_budget if self.fastpath.enabled else None)
         if hasattr(model_module, "moe_expert_rows"):  # a mixture of experts counts its rows
             self.counters = ServeCounters(
                 moe_picks=model_module.moe_picks_per_token(model_config),
@@ -439,15 +431,12 @@ class InferenceEngineV2:
         (``fwd_n32_t256_b20``): the device trace's program line and the
         compile ledger then say which bucket ran."""
         model, cfg, bs = self.model, self.model_config, self.block_size
-        kw = {}
-        if self._live_token_bound is not None:
-            kw["live_token_bound"] = self._live_token_bound
-        if self.tp > 1:
-            kw["tp_axis"] = TENSOR_AXIS
+        tp_axis, bound = TENSOR_AXIS if self.tp > 1 else None, self._live_token_bound
 
         def fwd(params, kv, tokens, n_tokens, start_pos, tables):
             return model.forward_paged(cfg, params, tokens, n_tokens, start_pos,
-                                       tables, kv, block_size=bs, **kw)
+                                       tables, kv, block_size=bs, tp_axis=tp_axis,
+                                       live_token_bound=bound)
         if self.tp > 1:
             fwd = self._shard_mapped(fwd, (PartitionSpec(), self._kv_specs))
         fwd.__name__ = f"fwd_n{n}_t{t}_b{b}"
@@ -1241,27 +1230,17 @@ class InferenceEngineV2:
         Jitted under its bucket's name (``spec_verify_n8_k4_b12``)."""
         model, cfg, bs = self.model, self.model_config, self.block_size
         width = jnp.full((n, ), k + 1, jnp.int32)
+        tp_axis = TENSOR_AXIS if self.tp > 1 else None
+
+        def verify(params, kv, tok0, draft, start0, tables, rng):
+            tokens = jnp.concatenate([tok0[:, None], draft], axis=1)
+            logits, kv = model.forward_paged(cfg, params, tokens, width, start0, tables, kv,
+                                             block_size=bs, tp_axis=tp_axis)
+            packed, rng = rejection_select(logits, draft, rng, sample_cfg=sample_cfg)
+            return kv, packed, rng
         if self.tp > 1:
-            def verify(params, kv, tok0, draft, start0, tables, rng):
-                tokens = jnp.concatenate([tok0[:, None], draft], axis=1)
-                logits, kv = model.forward_paged(cfg, params, tokens, width,
-                                                 start0, tables, kv,
-                                                 block_size=bs,
-                                                 tp_axis=TENSOR_AXIS)
-                packed, rng = rejection_select(logits, draft, rng,
-                                               sample_cfg=sample_cfg)
-                return kv, packed, rng
             verify = self._shard_mapped(
                 verify, (self._kv_specs, PartitionSpec(), PartitionSpec()))
-        else:
-            def verify(params, kv, tok0, draft, start0, tables, rng):
-                tokens = jnp.concatenate([tok0[:, None], draft], axis=1)
-                logits, kv = model.forward_paged(cfg, params, tokens, width,
-                                                 start0, tables, kv,
-                                                 block_size=bs)
-                packed, rng = rejection_select(logits, draft, rng,
-                                               sample_cfg=sample_cfg)
-                return kv, packed, rng
         verify.__name__ = (f"spec_verify_n{n}_k{k}_b{b}"
                            + ("" if sample_cfg is None else "_sampled"))
         return jax.jit(verify, donate_argnums=(1, ))  # dslint: disable=donation-after-use  # call-site contract: decode_spec() reassigns self.kv from the result in the same statement

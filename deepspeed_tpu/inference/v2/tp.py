@@ -7,7 +7,7 @@ reference hand-slices each weight per TP rank at load time; here the model's
 ``tp_rules`` (or AutoTP path inference) produce a PartitionSpec tree, params and
 the paged KV pool are placed with NamedShardings, and the ragged forward runs
 under ``shard_map`` with ``tp_axis`` threading psums through the row-parallel
-matmuls (models/llama.py forward_paged).
+matmuls (a family's ``finish`` callable; models/transformer.py paged_forward).
 
 Layout (matching the reference helpers):
   qkv (wq/wk/wv)      column-parallel — heads split over 'tensor'  (sharding/qkv.py)

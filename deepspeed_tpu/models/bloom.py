@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import transformer
 from .transformer import causal_lm_batch, count_params, cross_entropy_loss, layer_norm
 
 
@@ -93,7 +94,7 @@ def init_params(config: BloomConfig, key, dtype=jnp.float32):
             "ln1_w": jnp.ones((L, D), dtype), "ln1_b": jnp.zeros((L, D), dtype),
             "ln2_w": jnp.ones((L, D), dtype), "ln2_b": jnp.zeros((L, D), dtype),
             # fused per-head-interleaved qkv: [D, 3D] with rows grouped (q,k,v)
-            # per head (the HF layout, split in _split_qkv)
+            # per head (the HF layout, split in _qkv)
             "w_qkv": stack(ks[1], (D, 3 * D)), "b_qkv": jnp.zeros((L, 3 * D), dtype),
             "wo": stack(ks[2], (D, D)), "bo": jnp.zeros((L, D), dtype),
             "fc1": stack(ks[3], (D, 4 * D)), "b_fc1": jnp.zeros((L, 4 * D), dtype),
@@ -107,21 +108,21 @@ def num_params(config: BloomConfig) -> int:
     return count_params(lambda: init_params(config, jax.random.PRNGKey(0)))
 
 
-def _split_qkv(config: BloomConfig, fused, b, s):
-    """[B, S, 3D] per-head-interleaved -> q/k/v [B, S, H, Dh] each."""
-    H = config.num_heads
-    Dh = config.hidden_size // H
-    fused = fused.reshape(b, s, H, 3, Dh)
+def _qkv(config: BloomConfig, lp, x):
+    """Pre-LayerNorm and the fused biased projection, ``[B, S, 3D]``
+    per-head-interleaved, split into q/k/v ``[B, S, heads, Dh]`` each (the
+    local heads under TP: the fused width shards on head boundaries)."""
+    Dh = config.hidden_size // config.num_heads
+    h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], config.ln_eps)
+    fused = (h @ lp["w_qkv"].astype(x.dtype) + lp["b_qkv"].astype(x.dtype)).reshape(
+        x.shape[:2] + (-1, 3, Dh))
     return fused[..., 0, :], fused[..., 1, :], fused[..., 2, :]
 
 
 def _block(config: BloomConfig, lp, x, slopes, kpos, causal_mask):
-    b, s, D = x.shape
-    h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], config.ln_eps)
-    qkv = h @ lp["w_qkv"].astype(x.dtype) + lp["b_qkv"].astype(x.dtype)
-    q, k, v = _split_qkv(config, qkv, b, s)
+    q, k, v = _qkv(config, lp, x)
     attn = _biased_sdpa(q, k, v, slopes, kpos, causal_mask)
-    x = x + attn.reshape(b, s, D) @ lp["wo"].astype(x.dtype) + lp["bo"].astype(x.dtype)
+    x = x + attn.reshape(x.shape) @ lp["wo"].astype(x.dtype) + lp["bo"].astype(x.dtype)
     h = layer_norm(x, lp["ln2_w"], lp["ln2_b"], config.ln_eps)
     h = jax.nn.gelu(h @ lp["fc1"].astype(x.dtype) + lp["b_fc1"].astype(x.dtype),
                     approximate=True)
@@ -199,9 +200,7 @@ def forward_with_cache(config: BloomConfig, params, input_ids, cache, attention_
 
     def layer(x, inp):
         lp, kc, vc = inp
-        h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], config.ln_eps)
-        qkv = h @ lp["w_qkv"].astype(x.dtype) + lp["b_qkv"].astype(x.dtype)
-        q, k, v = _split_qkv(config, qkv, b, s)
+        q, k, v = _qkv(config, lp, x)
         kc = jax.lax.dynamic_update_slice_in_dim(kc, k, start, axis=1)
         vc = jax.lax.dynamic_update_slice_in_dim(vc, v, start, axis=1)
         attn = _biased_sdpa(q, kc, vc, slopes, kpos, causal_mask)
@@ -228,60 +227,47 @@ def init_paged_cache(config: BloomConfig, num_blocks: int, block_size: int,
 
 def forward_paged(config: BloomConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True):
-    """Ragged chunked BLOOM forward — ALiBi rides the paged kernel's
-    ``alibi_slopes`` operand (key-only form, absolute key index), making BLOOM
-    the 9th paged family (the reference's v2 zoo doesn't serve BLOOM at all;
-    its v1 path injects ALiBi through the softmax op binding,
-    ops/transformer/inference/op_binding/softmax.py).
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+    """Ragged chunked BLOOM forward (``transformer.paged_forward`` states the
+    contract): ALiBi rides the paged kernel's ``alibi_slopes`` operand
+    (key-only form, absolute key index).  The reference's v2 zoo doesn't serve
+    BLOOM at all; its v1 path injects ALiBi through the softmax op binding,
+    ops/transformer/inference/op_binding/softmax.py.
 
     TP: fused per-head-interleaved qkv is column-sharded on head boundaries
     (tp_rules), so the local shard holds H/tp whole heads; each shard slices
     its own run of the slope schedule by mesh position.  The tied unembedding
     uses the replicated embedding, so logits come out full-vocab on every
-    shard (no gather needed)."""
-    from ..ops.attention.paged import paged_attention
-    from .transformer import paged_chunk_indices
-
-    b, tchunk = tokens.shape
-    Dh = config.hidden_size // config.num_heads
-    H = params["layers"]["w_qkv"].shape[-1] // (3 * Dh)  # local heads
-    scale = 1.0 / np.sqrt(Dh)
+    shard (gather_logits is a no-op)."""
+    H = kv_cache["k"].shape[2]  # local heads
     slopes = jnp.asarray(alibi_slopes(config.num_heads))
     if tp_axis is not None and H < config.num_heads:
-        off = jax.lax.axis_index(tp_axis).astype(jnp.int32) * H
-        slopes = jax.lax.dynamic_slice(slopes, (off,), (H,))
-    safe_pos, valid, lengths, blk, off_tok = paged_chunk_indices(
-        tokens, n_tokens, start_pos, block_tables, kv_cache["k"].shape[1], block_size)
-    x = params["embed"][tokens].astype(kv_cache["k"].dtype)
-    x = layer_norm(x, params["embed_ln_w"], params["embed_ln_b"], config.ln_eps)
-    head_idx = jnp.arange(H)[None, None, :]
-    preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
+        first = jax.lax.axis_index(tp_axis).astype(jnp.int32) * H
+        slopes = jax.lax.dynamic_slice(slopes, (first,), (H,))
+    dtype = kv_cache["k"].dtype
+    preduce = transformer.tp_psum(tp_axis)
 
-    def layer(x, inp):
-        lp, kpool, vpool = inp
-        h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], config.ln_eps)
-        qkv = h @ lp["w_qkv"].astype(x.dtype) + lp["b_qkv"].astype(x.dtype)
-        fused = qkv.reshape(b, tchunk, H, 3, Dh)
-        q, k, v = fused[..., 0, :], fused[..., 1, :], fused[..., 2, :]
-        kpool = kpool.at[blk[:, :, None], head_idx, off_tok[:, :, None]].set(k)
-        vpool = vpool.at[blk[:, :, None], head_idx, off_tok[:, :, None]].set(v)
-        out = paged_attention(q, kpool, vpool, block_tables, lengths, start_pos, n_tokens,
-                              block_size=block_size, softmax_scale=scale,
-                              alibi_slopes=slopes)
-        x = x + preduce(out.reshape(b, tchunk, H * Dh) @ lp["wo"].astype(x.dtype)) \
-              + lp["bo"].astype(x.dtype)
+    def embed(tokens, safe_pos):
+        x = params["embed"][tokens].astype(dtype)
+        return layer_norm(x, params["embed_ln_w"], params["embed_ln_b"], config.ln_eps)
+
+    def finish(lp, x, kept, attn, live):
+        x = x + preduce(attn.reshape(x.shape[:2] + (-1, )) @ lp["wo"].astype(x.dtype)) \
+            + lp["bo"].astype(x.dtype)
         h = layer_norm(x, lp["ln2_w"], lp["ln2_b"], config.ln_eps)
         h = jax.nn.gelu(h @ lp["fc1"].astype(x.dtype) + lp["b_fc1"].astype(x.dtype),
                         approximate=True)
-        x = x + preduce(h @ lp["fc2"].astype(x.dtype)) + lp["b_fc2"].astype(x.dtype)
-        return x, (kpool, vpool)
+        return x + preduce(h @ lp["fc2"].astype(x.dtype)) + lp["b_fc2"].astype(x.dtype)
 
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
-    logits = x @ params["embed"].T.astype(x.dtype)
-    del gather_logits  # tied head is replicated: logits are already full-vocab
-    return logits, {"k": new_k, "v": new_v}
+    def head(x):
+        x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
+        return x @ params["embed"].T.astype(x.dtype)
+
+    return transformer.paged_forward(
+        params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, live_token_bound=live_token_bound,
+        embed=embed, qkv=lambda lp, x, safe_pos: (*_qkv(config, lp, x), None), finish=finish,
+        head=head, alibi_slopes=slopes)
 
 
 # ----------------------------------------------------------------- HF import
@@ -293,7 +279,7 @@ def config_from_hf(hf_config) -> BloomConfig:
 
 def from_hf_state_dict(config: BloomConfig, state_dict, dtype=jnp.float32):
     """Convert a BloomForCausalLM state dict.  The fused query_key_value keeps
-    HF's per-head (q, k, v) interleaving — _split_qkv consumes it directly."""
+    HF's per-head (q, k, v) interleaving — _qkv consumes it directly."""
     from .transformer import hf_stack, hf_tensor
     t = lambda name: hf_tensor(state_dict, name)
     L = config.num_layers

@@ -13,9 +13,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import transformer
 from .transformer import (apply_rotary, causal_lm_batch, count_params,
                           cross_entropy_loss, init_paged_kv_pool, layer_norm,
-                          paged_chunk_indices, rotary_tables, sdpa)
+                          rotary_tables, sdpa)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,18 +66,21 @@ def num_params(config: FalconConfig) -> int:
     return count_params(lambda: init_params(config, jax.random.PRNGKey(0)))
 
 
-def _block(config: FalconConfig, lp, x, cos, sin, attention_fn=None):
-    b, s, D = x.shape
-    H, KV = config.num_heads, config.num_kv_heads
-    Dh = D // H
+def _qkv(config: FalconConfig, lp, x, cos, sin, positions=None):
+    """The layer's one LayerNorm, the projections as heads ``[b, s, heads,
+    Dh]`` (the local ones under TP; MQA's one KV head) with rotary, and the
+    normed ``h`` the parallel MLP reads too: ``(q, k, v, h)``."""
+    Dh = config.hidden_size // config.num_heads  # TP-invariant
     h = layer_norm(x, lp["ln_w"], lp["ln_b"], config.ln_eps)
-    q = (h @ lp["wq"].astype(x.dtype)).reshape(b, s, H, Dh)
-    k = (h @ lp["wk"].astype(x.dtype)).reshape(b, s, KV, Dh)
-    v = (h @ lp["wv"].astype(x.dtype)).reshape(b, s, KV, Dh)
-    q = apply_rotary(q, cos, sin)
-    k = apply_rotary(k, cos, sin)
+    q, k, v = ((h @ lp[w].astype(x.dtype)).reshape(x.shape[:2] + (-1, Dh))
+               for w in ("wq", "wk", "wv"))
+    return apply_rotary(q, cos, sin, positions), apply_rotary(k, cos, sin, positions), v, h
+
+
+def _block(config: FalconConfig, lp, x, cos, sin, attention_fn=None):
+    q, k, v, h = _qkv(config, lp, x, cos, sin)
     attn = (attention_fn or sdpa)(q, k, v, causal=True)
-    attn_out = attn.reshape(b, s, H * Dh) @ lp["wo"].astype(x.dtype)
+    attn_out = attn.reshape(x.shape) @ lp["wo"].astype(x.dtype)
     # HF Falcon's 'gelu' is the exact erf form, not tanh (phi's gelu_new IS tanh)
     mlp_out = jax.nn.gelu(h @ lp["fc1"].astype(x.dtype), approximate=False) @ lp["fc2"].astype(x.dtype)
     return x + attn_out + mlp_out  # parallel residual
@@ -134,50 +138,37 @@ def make_tp_rules(config: FalconConfig):
 
 def forward_paged(config: FalconConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True):
-    """Ragged chunked Falcon forward — MQA KV pool (1 KV head) through the
-    Pallas paged kernel's GQA head mapping.
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+    """Ragged chunked Falcon forward (``transformer.paged_forward`` states the
+    contract): the MQA KV pool (1 KV head) goes through the Pallas paged
+    kernel's GQA head mapping.
 
     ``tp_axis``: q heads shard; MQA's single KV head (and its pool) replicates
     across shards — each computes the identical k/v, the GQA mapping folds all
     local q heads onto it.  The parallel-residual psum covers attn+mlp in ONE
     reduction (attn_out + mlp_out summed before the psum).  Tied unembed keeps
     full-vocab logits (gather_logits accepted for the engine's convention)."""
-    from ..ops.attention.paged import paged_attention
-
-    b, tchunk = tokens.shape
     Dh = config.hidden_size // config.num_heads  # TP-invariant
-    H = params["layers"]["wq"].shape[-1] // Dh   # local q heads
-    KV = kv_cache["k"].shape[2]                  # local kv heads (replicated MQA: full)
-    scale = 1.0 / np.sqrt(Dh)
     cos, sin = rotary_tables(Dh, config.max_seq_len, config.rope_theta)
-    safe_pos, valid, lengths, blk, off = paged_chunk_indices(
-        tokens, n_tokens, start_pos, block_tables, kv_cache["k"].shape[1], block_size)
-    x = params["embed"][tokens].astype(kv_cache["k"].dtype)
-    head_idx = jnp.arange(KV)[None, None, :]
-    preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
+    dtype = kv_cache["k"].dtype
+    preduce = transformer.tp_psum(tp_axis)
 
-    def layer(x, inp):
-        lp, kpool, vpool = inp
-        h = layer_norm(x, lp["ln_w"], lp["ln_b"], config.ln_eps)
-        q = (h @ lp["wq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        k = (h @ lp["wk"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        v = (h @ lp["wv"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        q = apply_rotary(q, cos, sin, safe_pos)
-        k = apply_rotary(k, cos, sin, safe_pos)
-        kpool = kpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(k)
-        vpool = vpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(v)
-        out = paged_attention(q, kpool, vpool, block_tables, lengths, start_pos, n_tokens,
-                              block_size=block_size, softmax_scale=scale)
-        attn_out = out.reshape(b, tchunk, H * Dh) @ lp["wo"].astype(x.dtype)
+    def finish(lp, x, h, attn, live):
+        attn_out = attn.reshape(x.shape[:2] + (-1, )) @ lp["wo"].astype(x.dtype)
         mlp_out = jax.nn.gelu(h @ lp["fc1"].astype(x.dtype),
                               approximate=False) @ lp["fc2"].astype(x.dtype)
-        return x + preduce(attn_out + mlp_out), (kpool, vpool)
+        return x + preduce(attn_out + mlp_out)
 
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
-    logits = x @ params["embed"].T.astype(x.dtype)
-    return logits, {"k": new_k, "v": new_v}
+    def head(x):
+        x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
+        return x @ params["embed"].T.astype(x.dtype)
+
+    return transformer.paged_forward(
+        params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, live_token_bound=live_token_bound,
+        embed=lambda tokens, safe_pos: params["embed"][tokens].astype(dtype),
+        qkv=lambda lp, x, safe_pos: _qkv(config, lp, x, cos, sin, safe_pos),
+        finish=finish, head=head)
 
 
 # ----------------------------------------------------------------- HF import

@@ -16,8 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import transformer
 from .transformer import (causal_lm_batch, count_params, cross_entropy_loss,
-                          init_paged_kv_pool, layer_norm, paged_chunk_indices, sdpa)
+                          init_paged_kv_pool, layer_norm, sdpa)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,26 +97,24 @@ def num_params(config: GPTJConfig) -> int:
     return count_params(lambda: init_params(config, jax.random.PRNGKey(0)))
 
 
-def _rotate_qk(config: GPTJConfig, q, k, cos, sin, positions=None):
-    rd = config.rotary_dim
-    q = jnp.concatenate([apply_rotary_interleaved(q[..., :rd], cos, sin, positions),
-                         q[..., rd:]], axis=-1)
-    k = jnp.concatenate([apply_rotary_interleaved(k[..., :rd], cos, sin, positions),
-                         k[..., rd:]], axis=-1)
-    return q, k
+def _qkv(config: GPTJConfig, lp, x, cos, sin, positions=None):
+    """The layer's one LayerNorm, the projections as heads ``[b, s, heads,
+    Dh]`` (the local ones under TP) with the leading ``rotary_dim`` of q and k
+    rotated pairwise, and the normed ``h`` the parallel MLP reads too:
+    ``(q, k, v, h)``."""
+    rd, Dh = config.rotary_dim, config.hidden_size // config.num_heads  # TP-invariant
+    h = layer_norm(x, lp["ln_w"], lp["ln_b"], config.ln_eps)
+    q, k, v = ((h @ lp[w].astype(x.dtype)).reshape(x.shape[:2] + (-1, Dh))
+               for w in ("wq", "wk", "wv"))
+    q, k = (jnp.concatenate([apply_rotary_interleaved(y[..., :rd], cos, sin, positions),
+                             y[..., rd:]], axis=-1) for y in (q, k))
+    return q, k, v, h
 
 
 def _block(config: GPTJConfig, lp, x, cos, sin, attention_fn=None):
-    b, s, D = x.shape
-    H = config.num_heads
-    Dh = D // H
-    h = layer_norm(x, lp["ln_w"], lp["ln_b"], config.ln_eps)
-    q = (h @ lp["wq"].astype(x.dtype)).reshape(b, s, H, Dh)
-    k = (h @ lp["wk"].astype(x.dtype)).reshape(b, s, H, Dh)
-    v = (h @ lp["wv"].astype(x.dtype)).reshape(b, s, H, Dh)
-    q, k = _rotate_qk(config, q, k, cos, sin)
+    q, k, v, h = _qkv(config, lp, x, cos, sin)
     attn = (attention_fn or sdpa)(q, k, v, causal=True)
-    attn_out = attn.reshape(b, s, D) @ lp["wo"].astype(x.dtype)
+    attn_out = attn.reshape(x.shape) @ lp["wo"].astype(x.dtype)
     mlp = jax.nn.gelu(h @ lp["fc_in"].astype(x.dtype) + lp["b_fc_in"].astype(x.dtype),
                       approximate=True)
     mlp_out = mlp @ lp["fc_out"].astype(x.dtype) + lp["b_fc_out"].astype(x.dtype)
@@ -170,47 +169,35 @@ def init_paged_cache(config: GPTJConfig, num_blocks: int, block_size: int, dtype
 
 def forward_paged(config: GPTJConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True):
-    """Ragged chunked GPT-J forward — interleaved partial rotary feeds the
-    paged kernel; the parallel residual reduces attn+mlp in one psum under TP;
-    vocab-parallel biased head like phi."""
-    from ..ops.attention.paged import paged_attention
-
-    b, tchunk = tokens.shape
-    Dh = config.hidden_size // config.num_heads  # TP-invariant
-    H = params["layers"]["wq"].shape[-1] // Dh   # local heads
-    scale = 1.0 / np.sqrt(Dh)
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+    """Ragged chunked GPT-J forward (``transformer.paged_forward`` states the
+    contract): interleaved partial rotary feeds the paged kernel; the parallel
+    residual reduces attn+mlp in one psum under TP; vocab-parallel biased head
+    like phi."""
     cos, sin = interleaved_rotary_tables(config.rotary_dim, config.max_seq_len)
-    safe_pos, valid, lengths, blk, off = paged_chunk_indices(
-        tokens, n_tokens, start_pos, block_tables, kv_cache["k"].shape[1], block_size)
-    x = params["embed"][tokens].astype(kv_cache["k"].dtype)
-    head_idx = jnp.arange(H)[None, None, :]
-    preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
+    dtype = kv_cache["k"].dtype
+    preduce = transformer.tp_psum(tp_axis)
 
-    def layer(x, inp):
-        lp, kpool, vpool = inp
-        h = layer_norm(x, lp["ln_w"], lp["ln_b"], config.ln_eps)
-        q = (h @ lp["wq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        k = (h @ lp["wk"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        v = (h @ lp["wv"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        q, k = _rotate_qk(config, q, k, cos, sin, safe_pos)
-        kpool = kpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(k)
-        vpool = vpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(v)
-        out = paged_attention(q, kpool, vpool, block_tables, lengths, start_pos, n_tokens,
-                              block_size=block_size, softmax_scale=scale)
-        attn_out = out.reshape(b, tchunk, H * Dh) @ lp["wo"].astype(x.dtype)
+    def finish(lp, x, h, attn, live):
+        attn_out = attn.reshape(x.shape[:2] + (-1, )) @ lp["wo"].astype(x.dtype)
         mlp = jax.nn.gelu(h @ lp["fc_in"].astype(x.dtype) + lp["b_fc_in"].astype(x.dtype),
                           approximate=True)
-        mlp_out = mlp @ lp["fc_out"].astype(x.dtype)
-        x = x + preduce(attn_out + mlp_out) + lp["b_fc_out"].astype(x.dtype)
-        return x, (kpool, vpool)
+        return x + preduce(attn_out + mlp @ lp["fc_out"].astype(x.dtype)) \
+            + lp["b_fc_out"].astype(x.dtype)
 
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
-    logits = x @ params["lm_head"].astype(x.dtype) + params["lm_head_b"].astype(x.dtype)
-    if tp_axis is not None and gather_logits:
-        logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
-    return logits, {"k": new_k, "v": new_v}
+    def head(x):
+        x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
+        logits = x @ params["lm_head"].astype(x.dtype) + params["lm_head_b"].astype(x.dtype)
+        if tp_axis is not None and gather_logits:
+            logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
+        return logits
+
+    return transformer.paged_forward(
+        params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, live_token_bound=live_token_bound,
+        embed=lambda tokens, safe_pos: params["embed"][tokens].astype(dtype),
+        qkv=lambda lp, x, safe_pos: _qkv(config, lp, x, cos, sin, safe_pos),
+        finish=finish, head=head)
 
 
 # ----------------------------------------------------------------- HF import

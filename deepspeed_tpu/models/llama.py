@@ -15,10 +15,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .transformer import (apply_rotary, attention_block, cross_entropy_loss,
-                          flat_chunk_indices, flat_slots, init_linear,
-                          kv_projection_shardable, paged_chunk_indices, rms_norm,
-                          rotary_tables, sdpa, swiglu_mlp)
+from . import transformer
+from .transformer import (apply_rotary, attention_block, cross_entropy_loss, init_linear,
+                          kv_projection_shardable, rms_norm, rotary_tables, sdpa, swiglu_mlp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -375,163 +374,65 @@ def config_from_hf(hf_config) -> LlamaConfig:
 
 # --------------------------------------------------------- paged (ragged) serve
 def init_paged_cache(config: LlamaConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16):
-    """Paged KV pool (reference inference/v2/ragged blocked KV layout):
-    [L, num_blocks, KV, block_size, Dh] — heads-major so the Pallas paged
-    kernel's trailing (block_size, Dh) tile satisfies TPU tiling.  The last
-    block of each layer is reserved as a trash target for padded-token writes.
+    """The paged KV pool of ``transformer.init_paged_kv_pool`` at this config's
+    layers, KV heads and head width."""
+    return transformer.init_paged_kv_pool(config.num_layers, config.num_kv_heads,
+                                          config.hidden_size // config.num_heads,
+                                          num_blocks, block_size, dtype)
 
-    One stacked array per K and V, layer axis first: ``forward_paged`` writes
-    and reads it where it lies (block b of layer l is row ``l * num_blocks +
-    b`` of the free ``[L * num_blocks, KV, block_size, Dh]`` view), and the
-    engine's copy-on-write, its TP spec (heads on axis 2) and the benchmark's
-    pool-shape reader rest on this layout."""
-    L, KV = config.num_layers, config.num_kv_heads
-    Dh = config.hidden_size // config.num_heads
-    return {
-        "k": jnp.zeros((L, num_blocks, KV, block_size, Dh), dtype),
-        "v": jnp.zeros((L, num_blocks, KV, block_size, Dh), dtype),
-    }
+
+def paged_callables(config, params, dtype, tp_axis: Optional[str], gather_logits: bool, *,
+                    on_heads: Optional[Callable] = None, ffn: Optional[Callable] = None):
+    """What is Llama's own of the ragged forward: ``embed``, ``qkv``, ``finish``
+    and ``head`` for ``transformer.paged_forward``, as its keywords.  A family
+    that is this decoder but for one sub-layer (mistral, qwen, mixtral, olmoe)
+    takes them and hands in what differs; both hooks are facts of an
+    architecture, never user options.  ``on_heads(lp, q, k, v) -> (q, k, v)``
+    acts on the projected local heads ``[b, s, heads, Dh]`` before they are
+    rotated (Qwen's biases, OLMoE's QK-norm).  ``ffn(lp, h, live)`` stands in
+    for the dense SwiGLU (the MoE families): ``h`` is the normed ``[b, s, D]``,
+    the result the row-parallel partial that ``finish`` psums."""
+    Dh = config.hidden_size // config.num_heads  # true head dim: TP-invariant
+    cos, sin = rotary_tables(Dh, config.max_seq_len, config.rope_theta)
+    preduce = transformer.tp_psum(tp_axis)
+
+    def embed(tokens, safe_pos):
+        return params["embed"][tokens].astype(dtype)
+
+    def qkv(lp, x, safe_pos):
+        h = rms_norm(x, lp["attn_norm"], config.rms_eps)
+        q, k, v = ((h @ lp["attn"][w].astype(x.dtype)).reshape(x.shape[:2] + (-1, Dh))
+                   for w in ("wq", "wk", "wv"))
+        if on_heads is not None:
+            q, k, v = on_heads(lp, q, k, v)
+        return apply_rotary(q, cos, sin, safe_pos), apply_rotary(k, cos, sin, safe_pos), v, None
+
+    def finish(lp, x, kept, attn, live):
+        x = x + preduce(attn.reshape(x.shape[:2] + (-1, )) @ lp["attn"]["wo"].astype(x.dtype))
+        h = rms_norm(x, lp["mlp_norm"], config.rms_eps)
+        return x + preduce(swiglu_mlp(lp["mlp"], h) if ffn is None else ffn(lp, h, live))
+
+    def head(x):
+        x = rms_norm(x, params["final_norm"], config.rms_eps)
+        w = params["embed"].T if config.tie_embeddings else params["lm_head"]
+        logits = x @ w.astype(x.dtype)
+        if tp_axis is not None and gather_logits and not config.tie_embeddings:
+            # lm_head is vocab-parallel (tp_rules: lm_head dim 1): gather shards.
+            # Greedy decode skips this (gather_logits=False) and argmaxes the
+            # vocab-local shard instead: O(1) scalars over ICI per token, not O(V).
+            logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
+        return logits
+
+    return {"embed": embed, "qkv": qkv, "finish": finish, "head": head}
 
 
 def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, block_tables,
-                  kv_cache, *, block_size: int, window: Optional[int] = None,
-                  tp_axis: Optional[str] = None, gather_logits: bool = True,
-                  live_token_bound: Optional[int] = None,
-                  ffn: Optional[Callable] = None, qk_norm: Optional[Callable] = None):
-    """Ragged chunked forward over the paged KV pool (FastGen model-forward
-    analog, inference/v2/model_implementations/llama_v2 + blocked flash).
-
-    tokens [N, T] (right-padded chunks), n_tokens [N] valid counts,
-    start_pos [N] absolute start of this chunk, block_tables [N, MAXB]
-    (padded entries point at the trash block).  ``window`` enables Mistral-style
-    sliding-window attention.  Returns (logits [N, T, V], new kv_cache).
-
-    ``kv_cache`` is ``{"k", "v"}`` of ``[L, NB, KV, bs, Dh]`` in and out.  The
-    layer scan CARRIES both pools whole beside the activations (its ``xs`` is
-    the layers' parameters and the layer's index); each layer scatters this
-    step's rows (live tokens x KV x Dh; a dead slot's into the layer's trash
-    block) into the carried stack in place and hands the paged kernel the
-    stack as one pool of ``L * NB`` blocks, with the block table offset by the
-    layer's first row ``l * NB``: the kernel knows nothing of layers.  No
-    layer is ever cut out of the pool or stacked back, so a jitted caller
-    that donates ``kv_cache`` (or carries it through a loop of its own, as the
-    fused burst does) runs with the one pool it was given and no copy of it.
-
-    ``live_token_bound``: the caller's promise that ``sum(n_tokens)`` never
-    passes it (the serving engine hands its scheduler's ``token_budget``).
-    Where the bucket holds more slots than that (``flat_slots``, from the
-    static shapes: a mixed SplitFuse step of one 225-token chunk beside 31
-    decode rows is ``[32, 256]`` = 8,192 slots for 256 live tokens), the chunk
-    is compacted onto one flat axis of S slots and everything that is per
-    token (embedding, norms, the Q/K/V/O projections, rotary, the KV write,
-    SwiGLU, the final norm and the output head) runs over ``[S, ...]``.  Only
-    attention sees the padded layout: ``q`` is scattered into a zero
-    ``[N, T, H, Dh]`` for the paged kernel and its output gathered back.  The
-    logits come back as ``[N, T, V]`` all the same, zero wherever no live
-    token sits.  Without the bound, or where the bucket fits it (decode
-    ``[N, 1]``, a burst body, a spec verify), every slot of the bucket is
-    computed and the trace is the padded one.
-
-    Attention runs in the Pallas paged kernel (ops/attention/paged.py) on TPU —
-    only live blocks are read via scalar-prefetched table indices; off-TPU the
-    identical-math dense-gather fallback runs.
-
-    ``tp_axis``: when called inside shard_map with params column/row-sharded per
-    tp_rules and the KV pool sharded on its head dim, names the mesh axis to
-    psum row-parallel partial outputs over (the TPU analog of the reference's
-    v2 sharding helpers, inference/v2/model_implementations/sharding/qkv.py +
-    attn.py + mlp.py + unembed.py).  Head counts are derived from the (local)
-    param shapes, so the same code serves single-chip and TP-sharded.
-
-    Two seams let a family that is this decoder but for one sub-layer run this
-    body and not a copy of it (models/mixtral.py, models/olmoe.py); both are
-    facts of an architecture, handed in by the family's module, never user
-    options.  ``ffn(layer_params, h, live)`` stands in for the dense SwiGLU:
-    ``h`` is the normed ``[b, s, D]`` the per-token layers run over (padded or
-    compacted), ``live`` the ``[b, s]`` mask of slots that hold a token, the
-    result the row-parallel partial this body psums.  ``qk_norm(layer_params,
-    q, k)`` acts on the projected queries ``[b, s, H, Dh]`` and keys
-    ``[b, s, KV, Dh]`` (the local heads) before they are rotated.
-    """
-    from ..ops.attention.paged import paged_attention
-
-    n, t = tokens.shape
-    cos, sin = rotary_tables(config.hidden_size // config.num_heads, config.max_seq_len, config.rope_theta)
-    num_blocks = kv_cache["k"].shape[1]
-    slots = flat_slots(n, t, live_token_bound)
-    if slots is None:
-        # the padded bucket as it is: the per-token layers see [N, T]
-        b, tchunk = n, t
-        safe_pos, live, lengths, blk, off = paged_chunk_indices(
-            tokens, n_tokens, start_pos, block_tables, num_blocks, block_size)
-        to_padded = from_padded = lambda a: a
-    else:
-        # the live tokens on one flat axis: the per-token layers see [1, S]
-        b, tchunk = 1, slots
-        row, col, live, safe_pos, blk, off = (a[None] for a in flat_chunk_indices(
-            n_tokens, start_pos, block_tables, num_blocks, block_size, slots))
-        lengths = start_pos + n_tokens
-        tokens = tokens[row, col]
-        drop_row = jnp.where(live, row, n)[0]  # out of bounds: a dead slot lands nowhere
-
-        def to_padded(a):  # [1, S, ...] -> [N, T, ...], zero wherever no live token sits
-            return jnp.zeros((n, t) + a.shape[2:], a.dtype).at[drop_row, col[0]].set(
-                a[0], mode="drop")
-
-        def from_padded(a):  # [N, T, ...] -> [1, S, ...]; a dead slot's value is never used
-            return a[row, col]
-
-    x = params["embed"][tokens].astype(kv_cache["k"].dtype)
-    Dh = config.hidden_size // config.num_heads  # true head dim: TP-invariant
-    H = params["layers"]["attn"]["wq"].shape[-1] // Dh   # local (per-shard) heads
-    KV = params["layers"]["attn"]["wk"].shape[-1] // Dh
-    scale = 1.0 / np.sqrt(Dh)
-    head_idx = jnp.arange(KV)[None, None, :]
-    preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
-
-    def layer(carry, inp):
-        x, kpool, vpool = carry  # the pools whole: [L*NB, KV, bs, Dh]
-        lp, l = inp
-        attn_in = rms_norm(x, lp["attn_norm"], config.rms_eps)
-        q = (attn_in @ lp["attn"]["wq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        k = (attn_in @ lp["attn"]["wk"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        v = (attn_in @ lp["attn"]["wv"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        if qk_norm is not None:
-            q, k = qk_norm(lp, q, k)
-        q = apply_rotary(q, cos, sin, safe_pos)
-        k = apply_rotary(k, cos, sin, safe_pos)
-        # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h].  One
-        # index per (token, head): a token's heads written as one window
-        # (.at[row, :, off]) makes the compiler relayout the pool, two copies a pass
-        first = l * num_blocks  # the layer's first row of the flat stack
-        row = (first + blk)[:, :, None]
-        kpool = kpool.at[row, head_idx, off[:, :, None]].set(k)
-        vpool = vpool.at[row, head_idx, off[:, :, None]].set(v)
-        # the kernel takes the flat stack as it would one layer's pool (a Pallas
-        # operand is materialised, so kpool[l] would be a copy): the table is offset
-        out = from_padded(paged_attention(
-            to_padded(q), kpool, vpool, block_tables + first, lengths, start_pos, n_tokens,
-            block_size=block_size, softmax_scale=scale, window=window))
-        x = x + preduce(out.reshape(b, tchunk, H * Dh) @ lp["attn"]["wo"].astype(x.dtype))
-        mlp_in = rms_norm(x, lp["mlp_norm"], config.rms_eps)
-        x = x + preduce(swiglu_mlp(lp["mlp"], mlp_in) if ffn is None else ffn(lp, mlp_in, live))
-        return (x, kpool, vpool), None
-
-    # The pool is carried, never sliced (xs) and restacked (ys): a scan's ys is
-    # a new [L, ...] array that cannot alias a donated argument still being
-    # read, which cost a slice, an update and a copy of the whole pool a pass.
-    pool_shape = kv_cache["k"].shape
-    flat = (-1, ) + pool_shape[2:]
-    (x, new_k, new_v), _ = jax.lax.scan(
-        layer, (x, kv_cache["k"].reshape(flat), kv_cache["v"].reshape(flat)),
-        (params["layers"], jnp.arange(pool_shape[0], dtype=jnp.int32)))
-    new_k, new_v = new_k.reshape(pool_shape), new_v.reshape(pool_shape)
-    x = rms_norm(x, params["final_norm"], config.rms_eps)
-    head = params["embed"].T if config.tie_embeddings else params["lm_head"]
-    logits = x @ head.astype(x.dtype)
-    if tp_axis is not None and gather_logits and not config.tie_embeddings:
-        # lm_head is vocab-parallel (tp_rules: lm_head dim 1): gather shards.
-        # Greedy decode skips this (gather_logits=False) and argmaxes the
-        # vocab-local shard instead — O(1) scalars over ICI per token, not O(V).
-        logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
-    return to_padded(logits), {"k": new_k, "v": new_v}
+                  kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+    """The v2 ragged forward (``transformer.paged_forward`` states the contract;
+    reference inference/v2/model_implementations/llama_v2): rotary GQA, dense
+    SwiGLU, vocab-parallel untied head."""
+    return transformer.paged_forward(
+        params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, live_token_bound=live_token_bound,
+        **paged_callables(config, params, kv_cache["k"].dtype, tp_axis, gather_logits))

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import llama
+from . import llama, transformer
 from .llama import LlamaConfig
 from .transformer import cross_entropy_loss, default_attention, sdpa
 
@@ -101,13 +101,13 @@ def make_loss_fn(config: MistralConfig, attention_fn=None) -> Callable:
 def forward_paged(config: MistralConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
                   gather_logits: bool = True, live_token_bound: Optional[int] = None):
-    """v2 ragged forward: the paged kernel applies the sliding window directly
-    (reference mistral serving uses windowed blocked flash)."""
-    return llama.forward_paged(config, params, tokens, n_tokens, start_pos, block_tables,
-                               kv_cache, block_size=block_size,
-                               window=config.sliding_window, tp_axis=tp_axis,
-                               gather_logits=gather_logits,
-                               live_token_bound=live_token_bound)
+    """v2 ragged forward: Llama's callables, and the paged kernel applies the
+    sliding window directly (reference mistral serving uses windowed blocked
+    flash)."""
+    return transformer.paged_forward(
+        params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, live_token_bound=live_token_bound, window=config.sliding_window,
+        **llama.paged_callables(config, params, kv_cache["k"].dtype, tp_axis, gather_logits))
 
 
 def config_from_hf(hf_config) -> MistralConfig:

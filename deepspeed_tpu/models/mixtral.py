@@ -6,9 +6,10 @@ Mixtral-8x7B EP+Ulysses SP.  Llama backbone with the FFN replaced by a top-k
 gated expert layer; aux losses summed across layers and added to the LM loss
 (reference MoE aux-loss pattern, sharded_moe.py top2gating usage).
 
-Serving runs ``llama.forward_paged``'s one paged body with the expert FFN
-(``moe/serving.py``: sparse dispatch over a grouped matmul) in the dense FFN's
-place; ``models/olmoe.py`` is this module under OLMoE's configuration.
+Serving runs the one paged driver (``transformer.paged_forward``) with
+Llama's callables and the expert FFN (``moe/serving.py``: sparse dispatch over
+a grouped matmul) in the dense FFN's place; ``models/olmoe.py`` is this module
+under OLMoE's configuration.
 """
 
 import dataclasses
@@ -246,10 +247,11 @@ def moe_expert_rows(config: MixtralConfig, slots: int) -> int:
 
 
 def whole_width_qk_norm(config: MixtralConfig, tp_axis: Optional[str]):
-    """OLMoE's QK-norm for ``llama.forward_paged``'s ``qk_norm`` seam: an
-    RMSNorm with a learned gain over the WHOLE projected width (all heads
-    together, not head by head), before rotary.  The body hands the local
-    heads ``[b, s, heads, Dh]``; where they are a tensor-parallel shard of the
+    """OLMoE's QK-norm, ``qk_norm(lp, q, k) -> (q, k)``: an RMSNorm with a
+    learned gain over the WHOLE projected width (all heads together, not head
+    by head), before rotary (``forward_paged`` hands it in as
+    ``llama.paged_callables``'s ``on_heads``).  It acts on the local heads
+    ``[b, s, heads, Dh]``; where they are a tensor-parallel shard of the
     width, the sum of squares is psum'd so the statistic is the full width's."""
     dh = config.hidden_size // config.num_heads
 
@@ -272,20 +274,19 @@ def forward_paged(config: MixtralConfig, params, tokens, n_tokens, start_pos, bl
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
                   gather_logits: bool = True, live_token_bound: Optional[int] = None):
     """Ragged chunked forward (reference inference/v2/model_implementations/
-    mixtral): ``llama.forward_paged``'s body, compaction included, with the
-    dense SwiGLU of a layer replaced by the no-drop sparse top-k expert FFN
-    (moe/serving.py) and, where the checkpoint has it, QK-norm before rotary.
-    Under ``tp_axis`` the experts are sharded on their width and the body
-    psums the expert FFN's partial sum like a dense row-parallel FFN's."""
-    from . import llama
+    mixtral): Llama's callables, compaction and all, with the dense SwiGLU of
+    a layer replaced by the no-drop sparse top-k expert FFN (moe/serving.py)
+    and, where the checkpoint has it, QK-norm before rotary.  Under
+    ``tp_axis`` the experts are sharded on their width and ``finish`` psums
+    the expert FFN's partial sum like a dense row-parallel FFN's."""
+    from . import llama, transformer
     from ..moe.serving import sparse_moe_ffn
 
-    # the body scans the layers' leaves; the experts stay one stack, and each
+    # the driver scans the layers' leaves; the experts stay one stack, and each
     # layer is handed its index into it (moe/serving.py says why)
     layers = params["layers"]
     experts = layers["moe"]["experts"]
     moe = {"gate": layers["moe"]["gate"], "layer": jnp.arange(config.num_layers, dtype=jnp.int32)}
-    params = {**params, "layers": {**layers, "moe": moe}}
 
     def ffn(lp, h, live):
         out = sparse_moe_ffn({"gate": lp["moe"]["gate"], "experts": experts},
@@ -293,8 +294,12 @@ def forward_paged(config: MixtralConfig, params, tokens, n_tokens, start_pos, bl
                              live.reshape(-1), layer=lp["moe"]["layer"])
         return out.reshape(h.shape)
 
-    return llama.forward_paged(
-        _llama_view(config), params, tokens, n_tokens, start_pos, block_tables, kv_cache,
-        block_size=block_size, tp_axis=tp_axis, gather_logits=gather_logits,
-        live_token_bound=live_token_bound, ffn=ffn,
-        qk_norm=whole_width_qk_norm(config, tp_axis) if config.qk_norm else None)
+    on_heads = None
+    if config.qk_norm:
+        qk_norm = whole_width_qk_norm(config, tp_axis)
+        on_heads = lambda lp, q, k, v: (*qk_norm(lp, q, k), v)
+    return transformer.paged_forward(
+        {**layers, "moe": moe}, tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, live_token_bound=live_token_bound,
+        **llama.paged_callables(_llama_view(config), params, kv_cache["k"].dtype, tp_axis,
+                                gather_logits, on_heads=on_heads, ffn=ffn))

@@ -5,7 +5,7 @@ A Mixtral-shaped decoder with three differences, all facts of the checkpoint:
 renormalised over the picked experts (``norm_topk_prob: false``), and QK-norm
 (an RMSNorm with a learned gain over the whole projected width of q and of k,
 before rotary).  ``OlmoeConfig`` states them; everything else delegates to
-models/mixtral, whose paged forward is ``llama.forward_paged``'s one body.
+models/mixtral, whose paged forward is Llama's callables on the one driver.
 
 Training is not supported: the training gate (``moe/sharded_moe.TopKGate``)
 takes k of 1 or 2, and ``mixtral.forward`` has no QK-norm.

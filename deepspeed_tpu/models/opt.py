@@ -18,8 +18,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import transformer
 from .transformer import (causal_lm_batch, count_params, cross_entropy_loss,
-                          init_paged_kv_pool, layer_norm, paged_chunk_indices, sdpa)
+                          init_paged_kv_pool, layer_norm, sdpa)
 
 POS_OFFSET = 2  # OPT reserves the first two position slots (HF modeling_opt)
 
@@ -74,16 +75,18 @@ def num_params(config: OPTConfig) -> int:
     return count_params(lambda: init_params(config, jax.random.PRNGKey(0)))
 
 
-def _block(config: OPTConfig, lp, x, attention_fn=None):
-    b, s, D = x.shape
-    H = config.num_heads
-    Dh = D // H
+def _qkv(config: OPTConfig, lp, x):
+    """Pre-LayerNorm and the biased projections as heads ``[b, s, heads, Dh]``
+    (the local ones under TP); positions are learned, nothing rotates."""
+    Dh = config.hidden_size // config.num_heads  # TP-invariant head dim
     h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], config.ln_eps)
-    q = (h @ lp["wq"].astype(x.dtype) + lp["bq"].astype(x.dtype)).reshape(b, s, H, Dh)
-    k = (h @ lp["wk"].astype(x.dtype) + lp["bk"].astype(x.dtype)).reshape(b, s, H, Dh)
-    v = (h @ lp["wv"].astype(x.dtype) + lp["bv"].astype(x.dtype)).reshape(b, s, H, Dh)
-    attn = (attention_fn or sdpa)(q, k, v, causal=True)
-    x = x + attn.reshape(b, s, D) @ lp["wo"].astype(x.dtype) + lp["bo"].astype(x.dtype)
+    return tuple((h @ lp["w" + c].astype(x.dtype) + lp["b" + c].astype(x.dtype)).reshape(
+        x.shape[:2] + (-1, Dh)) for c in "qkv")
+
+
+def _block(config: OPTConfig, lp, x, attention_fn=None):
+    attn = (attention_fn or sdpa)(*_qkv(config, lp, x), causal=True)
+    x = x + attn.reshape(x.shape) @ lp["wo"].astype(x.dtype) + lp["bo"].astype(x.dtype)
     h = layer_norm(x, lp["ln2_w"], lp["ln2_b"], config.ln_eps)
     h = jax.nn.relu(h @ lp["fc1"].astype(x.dtype) + lp["b_fc1"].astype(x.dtype))
     return x + h @ lp["fc2"].astype(x.dtype) + lp["b_fc2"].astype(x.dtype)
@@ -137,49 +140,37 @@ def tp_rules(path: str, shape) -> "int | None":
 
 def forward_paged(config: OPTConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True):
-    """Ragged chunked OPT forward (learned positions — no rotary on K/Q).
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+    """Ragged chunked OPT forward (``transformer.paged_forward`` states the
+    contract): learned positions, no rotary on K/Q.
 
-    ``tp_axis``: inside shard_map with params sharded per tp_rules, names the
-    mesh axis to psum row-parallel partials over.  Row-parallel biases (bo,
-    b_fc2) are replicated and added AFTER the psum so they count once.  Local
-    head counts derive from the shard shapes; the tied unembedding is
-    replicated, so logits are always full-vocab (gather_logits is a no-op,
-    accepted for the engine's uniform calling convention)."""
-    from ..ops.attention.paged import paged_attention
+    ``tp_axis``: row-parallel biases (bo, b_fc2) are replicated and added
+    AFTER the psum so they count once.  The tied unembedding is replicated, so
+    logits are always full-vocab (gather_logits is a no-op, accepted for the
+    engine's uniform calling convention)."""
+    dtype = kv_cache["k"].dtype
+    preduce = transformer.tp_psum(tp_axis)
 
-    b, tchunk = tokens.shape
-    safe_pos, valid, lengths, blk, off = paged_chunk_indices(
-        tokens, n_tokens, start_pos, block_tables, kv_cache["k"].shape[1], block_size)
-    Dh = config.hidden_size // config.num_heads  # TP-invariant head dim
-    H = params["layers"]["wq"].shape[-1] // Dh   # local (per-shard) heads
-    scale = 1.0 / np.sqrt(Dh)
-    x = params["embed"][tokens].astype(kv_cache["k"].dtype)
-    x = x + params["pos_embed"][safe_pos + POS_OFFSET].astype(x.dtype)
-    head_idx = jnp.arange(H)[None, None, :]
-    preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
+    def embed(tokens, safe_pos):
+        x = params["embed"][tokens].astype(dtype)
+        return x + params["pos_embed"][safe_pos + POS_OFFSET].astype(x.dtype)
 
-    def layer(x, inp):
-        lp, kpool, vpool = inp
-        h = layer_norm(x, lp["ln1_w"], lp["ln1_b"], config.ln_eps)
-        q = (h @ lp["wq"].astype(x.dtype) + lp["bq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        k = (h @ lp["wk"].astype(x.dtype) + lp["bk"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        v = (h @ lp["wv"].astype(x.dtype) + lp["bv"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        kpool = kpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(k)
-        vpool = vpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(v)
-        out = paged_attention(q, kpool, vpool, block_tables, lengths, start_pos, n_tokens,
-                              block_size=block_size, softmax_scale=scale)
-        x = x + preduce(out.reshape(b, tchunk, H * Dh) @ lp["wo"].astype(x.dtype)) \
+    def finish(lp, x, kept, attn, live):
+        x = x + preduce(attn.reshape(x.shape[:2] + (-1, )) @ lp["wo"].astype(x.dtype)) \
             + lp["bo"].astype(x.dtype)
         h = layer_norm(x, lp["ln2_w"], lp["ln2_b"], config.ln_eps)
         h = jax.nn.relu(h @ lp["fc1"].astype(x.dtype) + lp["b_fc1"].astype(x.dtype))
-        x = x + preduce(h @ lp["fc2"].astype(x.dtype)) + lp["b_fc2"].astype(x.dtype)
-        return x, (kpool, vpool)
+        return x + preduce(h @ lp["fc2"].astype(x.dtype)) + lp["b_fc2"].astype(x.dtype)
 
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
-    logits = x @ params["embed"].T.astype(x.dtype)
-    return logits, {"k": new_k, "v": new_v}
+    def head(x):
+        x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
+        return x @ params["embed"].T.astype(x.dtype)
+
+    return transformer.paged_forward(
+        params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, live_token_bound=live_token_bound,
+        embed=embed, qkv=lambda lp, x, safe_pos: (*_qkv(config, lp, x), None), finish=finish,
+        head=head)
 
 
 # ----------------------------------------------------------------- HF import

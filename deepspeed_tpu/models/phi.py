@@ -13,9 +13,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import transformer
 from .transformer import (apply_rotary, causal_lm_batch, count_params,
                           cross_entropy_loss, init_paged_kv_pool, layer_norm,
-                          paged_chunk_indices, rotary_tables, sdpa)
+                          rotary_tables, sdpa)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,18 +85,22 @@ def num_params(config: PhiConfig) -> int:
     return count_params(lambda: init_params(config, jax.random.PRNGKey(0)))
 
 
-def _block(config: PhiConfig, lp, x, cos, sin, attention_fn=None):
-    b, s, D = x.shape
-    H = config.num_heads
-    Dh = D // H
+def _qkv(config: PhiConfig, lp, x, cos, sin, positions=None):
+    """The layer's one LayerNorm, the biased projections as heads ``[b, s,
+    heads, Dh]`` (the local ones under TP) with partial rotary, and the normed
+    ``h`` the parallel MLP reads too: ``(q, k, v, h)``."""
+    Dh = config.hidden_size // config.num_heads  # TP-invariant
     h = layer_norm(x, lp["ln_w"], lp["ln_b"], config.ln_eps)
-    q = (h @ lp["wq"].astype(x.dtype) + lp["bq"].astype(x.dtype)).reshape(b, s, H, Dh)
-    k = (h @ lp["wk"].astype(x.dtype) + lp["bk"].astype(x.dtype)).reshape(b, s, H, Dh)
-    v = (h @ lp["wv"].astype(x.dtype) + lp["bv"].astype(x.dtype)).reshape(b, s, H, Dh)
-    q = partial_rotary(q, cos, sin, config.rotary_dim)
-    k = partial_rotary(k, cos, sin, config.rotary_dim)
+    q, k, v = ((h @ lp["w" + c].astype(x.dtype) + lp["b" + c].astype(x.dtype)).reshape(
+        x.shape[:2] + (-1, Dh)) for c in "qkv")
+    return (partial_rotary(q, cos, sin, config.rotary_dim, positions),
+            partial_rotary(k, cos, sin, config.rotary_dim, positions), v, h)
+
+
+def _block(config: PhiConfig, lp, x, cos, sin, attention_fn=None):
+    q, k, v, h = _qkv(config, lp, x, cos, sin)
     attn = (attention_fn or sdpa)(q, k, v, causal=True)
-    attn_out = attn.reshape(b, s, D) @ lp["wo"].astype(x.dtype) + lp["bo"].astype(x.dtype)
+    attn_out = attn.reshape(x.shape) @ lp["wo"].astype(x.dtype) + lp["bo"].astype(x.dtype)
     mlp = jax.nn.gelu(h @ lp["fc1"].astype(x.dtype) + lp["b_fc1"].astype(x.dtype),
                       approximate=True)
     mlp_out = mlp @ lp["fc2"].astype(x.dtype) + lp["b_fc2"].astype(x.dtype)
@@ -156,53 +161,39 @@ def tp_rules(path: str, shape) -> "int | None":
 
 def forward_paged(config: PhiConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True):
-    """Ragged chunked Phi forward — partial rotary feeds the paged kernel.
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+    """Ragged chunked Phi forward (``transformer.paged_forward`` states the
+    contract): partial rotary feeds the paged kernel.
 
     ``tp_axis``: heads shard; the parallel residual's attn+mlp partials reduce
     in ONE psum with the replicated bo/b_fc2 added after it.  The untied
     lm_head is vocab-parallel: the local bias slice lands on local logits
     before the (optional) gather, so greedy decode can argmax the local shard
     (gather_logits=False) without moving O(V) over ICI."""
-    from ..ops.attention.paged import paged_attention
-
-    b, tchunk = tokens.shape
-    Dh = config.hidden_size // config.num_heads  # TP-invariant
-    H = params["layers"]["wq"].shape[-1] // Dh   # local heads
-    scale = 1.0 / np.sqrt(Dh)
     cos, sin = rotary_tables(config.rotary_dim, config.max_seq_len, config.rope_theta)
-    safe_pos, valid, lengths, blk, off = paged_chunk_indices(
-        tokens, n_tokens, start_pos, block_tables, kv_cache["k"].shape[1], block_size)
-    x = params["embed"][tokens].astype(kv_cache["k"].dtype)
-    head_idx = jnp.arange(H)[None, None, :]
-    preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
+    dtype = kv_cache["k"].dtype
+    preduce = transformer.tp_psum(tp_axis)
 
-    def layer(x, inp):
-        lp, kpool, vpool = inp
-        h = layer_norm(x, lp["ln_w"], lp["ln_b"], config.ln_eps)
-        q = (h @ lp["wq"].astype(x.dtype) + lp["bq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        k = (h @ lp["wk"].astype(x.dtype) + lp["bk"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        v = (h @ lp["wv"].astype(x.dtype) + lp["bv"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        q = partial_rotary(q, cos, sin, config.rotary_dim, safe_pos)
-        k = partial_rotary(k, cos, sin, config.rotary_dim, safe_pos)
-        kpool = kpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(k)
-        vpool = vpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(v)
-        out = paged_attention(q, kpool, vpool, block_tables, lengths, start_pos, n_tokens,
-                              block_size=block_size, softmax_scale=scale)
-        attn_out = out.reshape(b, tchunk, H * Dh) @ lp["wo"].astype(x.dtype)
+    def finish(lp, x, h, attn, live):
+        attn_out = attn.reshape(x.shape[:2] + (-1, )) @ lp["wo"].astype(x.dtype)
         mlp = jax.nn.gelu(h @ lp["fc1"].astype(x.dtype) + lp["b_fc1"].astype(x.dtype),
                           approximate=True)
-        mlp_out = mlp @ lp["fc2"].astype(x.dtype)
-        x = x + preduce(attn_out + mlp_out) \
+        return x + preduce(attn_out + mlp @ lp["fc2"].astype(x.dtype)) \
             + lp["bo"].astype(x.dtype) + lp["b_fc2"].astype(x.dtype)
-        return x, (kpool, vpool)
 
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
-    logits = x @ params["lm_head"].astype(x.dtype) + params["lm_head_b"].astype(x.dtype)
-    if tp_axis is not None and gather_logits:
-        logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
-    return logits, {"k": new_k, "v": new_v}
+    def head(x):
+        x = layer_norm(x, params["final_ln_w"], params["final_ln_b"], config.ln_eps)
+        logits = x @ params["lm_head"].astype(x.dtype) + params["lm_head_b"].astype(x.dtype)
+        if tp_axis is not None and gather_logits:
+            logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
+        return logits
+
+    return transformer.paged_forward(
+        params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, live_token_bound=live_token_bound,
+        embed=lambda tokens, safe_pos: params["embed"][tokens].astype(dtype),
+        qkv=lambda lp, x, safe_pos: _qkv(config, lp, x, cos, sin, safe_pos),
+        finish=finish, head=head)
 
 
 # ----------------------------------------------------------------- HF import

@@ -8,8 +8,8 @@ bias terms folded in by pre-adding them through a wrapped forward.
 Implementation note: rather than forking llama's scan, the qkv biases are
 threaded as extra per-layer params and applied via a custom block that calls
 the same building blocks (transformer.attention_block has no bias slot, so
-the block is written out here; the paged path mirrors llama.forward_paged
-with the three bias adds).
+the block is written out here; the paged path is Llama's callables with
+the three bias adds handed in).
 """
 
 import dataclasses
@@ -19,10 +19,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import llama
+from . import llama, transformer
 from .llama import LlamaConfig
-from .transformer import (apply_rotary, count_params, cross_entropy_loss,
-                          paged_chunk_indices, rms_norm, rotary_tables, sdpa, swiglu_mlp)
+from .transformer import (apply_rotary, count_params, cross_entropy_loss, rms_norm,
+                          rotary_tables, sdpa, swiglu_mlp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,54 +133,24 @@ def make_tp_rules(config: QwenConfig):
 
     return rules
 
+
+def _add_qkv_biases(lp, q, k, v):
+    """Qwen's three biases on the projected local heads ``[b, s, heads, Dh]``
+    (a column-parallel weight's bias rides its shard)."""
+    add = lambda y, b: y + lp["attn"][b].astype(y.dtype).reshape(y.shape[-2:])
+    return add(q, "bq"), add(k, "bk"), add(v, "bv")
+
+
 def forward_paged(config: QwenConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True):
-    """Ragged chunked Qwen2 forward: llama's paged layer + qkv bias adds.
-
-    ``tp_axis`` threads TP exactly like llama.forward_paged (head-sharded
-    KV pool, psum after row-parallel wo/w_down, vocab-parallel lm_head);
-    the qkv biases ride their column-parallel weights' shards."""
-    from ..ops.attention.paged import paged_attention
-
-    b, tchunk = tokens.shape
-    cos, sin = rotary_tables(config.hidden_size // config.num_heads,
-                             config.max_seq_len, config.rope_theta)
-    safe_pos, valid, lengths, blk, off = paged_chunk_indices(
-        tokens, n_tokens, start_pos, block_tables, kv_cache["k"].shape[1], block_size)
-    x = params["embed"][tokens].astype(kv_cache["k"].dtype)
-    Dh = config.hidden_size // config.num_heads            # TP-invariant
-    H = params["layers"]["attn"]["wq"].shape[-1] // Dh     # local heads
-    KV = params["layers"]["attn"]["wk"].shape[-1] // Dh
-    scale = 1.0 / np.sqrt(Dh)
-    head_idx = jnp.arange(KV)[None, None, :]
-    preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
-
-    def layer(x, inp):
-        lp, kpool, vpool = inp
-        a = lp["attn"]
-        attn_in = rms_norm(x, lp["attn_norm"], config.rms_eps)
-        q = (attn_in @ a["wq"].astype(x.dtype) + a["bq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        k = (attn_in @ a["wk"].astype(x.dtype) + a["bk"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        v = (attn_in @ a["wv"].astype(x.dtype) + a["bv"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        q = apply_rotary(q, cos, sin, safe_pos)
-        k = apply_rotary(k, cos, sin, safe_pos)
-        kpool = kpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(k)
-        vpool = vpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(v)
-        out = paged_attention(q, kpool, vpool, block_tables, lengths, start_pos, n_tokens,
-                              block_size=block_size, softmax_scale=scale)
-        x = x + preduce(out.reshape(b, tchunk, H * Dh) @ a["wo"].astype(x.dtype))
-        mlp_in = rms_norm(x, lp["mlp_norm"], config.rms_eps)
-        x = x + preduce(swiglu_mlp(lp["mlp"], mlp_in))
-        return x, (kpool, vpool)
-
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = rms_norm(x, params["final_norm"], config.rms_eps)
-    head = params["embed"].T if config.tie_embeddings else params["lm_head"]
-    logits = x @ head.astype(x.dtype)
-    if tp_axis is not None and gather_logits and not config.tie_embeddings:
-        logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
-    return logits, {"k": new_k, "v": new_v}
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+    """Ragged chunked Qwen2 forward: Llama's callables with the qkv biases
+    added before rotary (``transformer.paged_forward`` states the contract)."""
+    return transformer.paged_forward(
+        params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
+        block_size=block_size, live_token_bound=live_token_bound,
+        **llama.paged_callables(config, params, kv_cache["k"].dtype, tp_axis, gather_logits,
+                                on_heads=_add_qkv_biases))
 
 
 # ----------------------------------------------------------------- HF import

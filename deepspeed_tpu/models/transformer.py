@@ -336,9 +336,16 @@ def count_params(init_fn) -> int:
 
 def init_paged_kv_pool(num_layers: int, num_kv_heads: int, head_dim: int,
                        num_blocks: int, block_size: int, dtype=jnp.bfloat16):
-    """Paged KV pool [L, NB, KV, bs, Dh] — heads-major so the Pallas paged
-    kernel's trailing (bs, Dh) tile satisfies TPU tiling; the last block is
-    the trash target for padded-token writes."""
+    """Paged KV pool (reference inference/v2/ragged blocked KV layout):
+    [L, NB, KV, bs, Dh], heads-major so the Pallas paged kernel's trailing
+    (bs, Dh) tile satisfies TPU tiling.  The last block of each layer is
+    reserved as a trash target for padded-token writes.
+
+    One stacked array per K and V, layer axis first: :func:`paged_forward`
+    writes and reads it where it lies (block b of layer l is row ``l * NB +
+    b`` of the free ``[L * NB, KV, bs, Dh]`` view), and the engine's
+    copy-on-write, its TP spec (heads on axis 2) and the benchmark's
+    pool-shape reader rest on this layout."""
     shape = (num_layers, num_blocks, num_kv_heads, block_size, head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -362,17 +369,15 @@ def hf_stack(state_dict, fmt, num_layers, dtype, transpose=True):
 # -------------------------------------------------------- paged-serving shared
 def paged_chunk_indices(tokens, n_tokens, start_pos, block_tables, num_blocks: int,
                         block_size: int):
-    """Shared index scaffolding for every family's ``forward_paged``: maps the
-    ragged chunk's absolute positions onto paged-KV pool coordinates.
+    """The index scaffolding of the PADDED bucket for :func:`paged_forward`:
+    maps the ragged chunk's absolute positions onto paged-KV pool coordinates.
 
     Returns (safe_pos [N,T], valid [N,T], lengths [N], blk [N,T], off [N,T]):
     ``blk``/``off`` address pool[blk, :, off] for each token's KV write, with
-    padded tokens routed to the trash block (``num_blocks - 1``).
-
-    These are the indices of the PADDED bucket: every one of the N x T slots
-    gets a position and a pool address, live or not.  A forward that runs its
-    per-token layers over the live tokens only takes
-    :func:`flat_chunk_indices` instead (``lengths`` is the same there).
+    padded tokens routed to the trash block (``num_blocks - 1``).  Every one
+    of the N x T slots gets a position and a pool address, live or not; the
+    compacted form takes :func:`flat_chunk_indices` instead (``lengths`` is
+    the same there).
     """
     b, tchunk = tokens.shape
     trash = num_blocks - 1
@@ -427,6 +432,138 @@ def flat_chunk_indices(n_tokens, start_pos, block_tables, num_blocks: int,
     blk = jnp.where(live, block_tables[row, safe_pos // block_size], num_blocks - 1)
     off = safe_pos % block_size
     return row, col, live, safe_pos, blk, off
+
+
+def tp_psum(tp_axis: Optional[str]):
+    """What a family's ``finish`` does with a row-parallel partial: the psum
+    over ``tp_axis`` inside shard_map, nothing on one chip."""
+    return (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
+
+
+def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *,
+                  block_size: int, live_token_bound: Optional[int],
+                  embed: Callable, qkv: Callable, finish: Callable, head: Callable,
+                  window: Optional[int] = None, alibi_slopes=None):
+    """The one ragged chunked forward over the paged KV pool (FastGen
+    model-forward analog, inference/v2/model_implementations + blocked flash):
+    every family's ``forward_paged`` is its own arithmetic as four callables
+    plus one call of this.  It alone under ``models/`` knows where a step's
+    tokens lie, where the pool lies and how a layer writes it, and what the
+    paged kernel is handed.
+
+    A family's ``forward_paged(config, params, tokens, n_tokens, start_pos,
+    block_tables, kv_cache, *, block_size, tp_axis=None, gather_logits=True,
+    live_token_bound=None)`` is the contract the serving engine calls, the
+    same keywords for every module: tokens [N, T] (right-padded chunks),
+    n_tokens [N] valid counts, start_pos [N] absolute start of this chunk,
+    block_tables [N, MAXB] (padded entries point at the trash block); returns
+    (logits [N, T, V], new kv_cache).  ``tp_axis`` names the mesh axis of the
+    enclosing shard_map (params column/row-sharded per the family's tp rules,
+    the pool sharded on its heads; head counts come from the local shapes, so
+    one code serves one chip and a TP shard); ``gather_logits=False`` leaves a
+    vocab-parallel head's logits local for a greedy pick.  The callables,
+    closed over the family's config, params and ``tp_axis``:
+
+    - ``embed(tokens, safe_pos) -> x`` ``[b, s, D]`` in the pool's dtype;
+    - ``qkv(lp, x, safe_pos) -> (q, k, v, kept)``: the layer's norm,
+      projections, biases, rotary or none, QK-norm; ``q`` ``[b, s, H, Dh]``,
+      ``k``/``v`` ``[b, s, KV, Dh]`` (local heads); ``kept`` is whatever the
+      family wants back (the normed ``h`` of a parallel residual, or None);
+    - ``finish(lp, x, kept, attn, live) -> x``: ``wo`` over ``attn``
+      ``[b, s, H, Dh]``, the residual form, the FFN, its psums and post-psum
+      biases; ``live`` is the ``[b, s]`` mask of slots that hold a token;
+    - ``head(x) -> logits``: final norm, tied or untied head, bias, TP gather;
+
+    and the two facts the kernel needs: ``window`` (Mistral's sliding window)
+    and ``alibi_slopes`` ([H] local heads, BLOOM).  ``[b, s]`` is ``[N, T]``
+    or, compacted, ``[1, S]``: a family never asks which.
+
+    ``kv_cache`` is ``{"k", "v"}`` of ``[L, NB, KV, bs, Dh]`` in and out.  The
+    layer scan CARRIES both pools whole beside the activations (its ``xs`` is
+    ``layers``, the stacked per-layer parameters, and the layer's index); each
+    layer scatters this step's rows (live tokens x KV x Dh; a dead slot's into
+    the layer's trash block, its last) into the carried stack in place and
+    hands the paged kernel the stack as one pool of ``L * NB`` blocks, with
+    the block table offset by the layer's first row ``l * NB``: the kernel
+    knows nothing of layers.  No layer is ever cut out of the pool or stacked
+    back, so a jitted caller that donates ``kv_cache`` (or carries it through
+    a loop of its own, as the fused burst does) runs with the one pool it was
+    given and no copy of it.
+
+    ``live_token_bound``: the caller's promise that ``sum(n_tokens)`` never
+    passes it (the serving engine hands its scheduler's ``token_budget``).
+    Where the bucket holds more slots than that (``flat_slots``, from the
+    static shapes: a mixed SplitFuse step of one 225-token chunk beside 31
+    decode rows is ``[32, 256]`` = 8,192 slots for 256 live tokens), the chunk
+    is compacted onto one flat axis of S slots and everything that is per
+    token (all four callables) runs over ``[1, S, ...]``.  Only attention sees
+    the padded layout: ``q`` is scattered into a zero ``[N, T, H, Dh]`` for
+    the paged kernel and its output gathered back.  The logits come back as
+    ``[N, T, V]`` all the same, zero wherever no live token sits.  With None,
+    or where the bucket fits the bound (decode ``[N, 1]``, a burst body, a
+    spec verify), every slot of the bucket is computed and the trace is the
+    padded one.
+
+    Attention runs in the Pallas paged kernel (ops/attention/paged.py) on TPU:
+    only live blocks are read via scalar-prefetched table indices; off-TPU the
+    identical-math dense-gather fallback runs."""
+    from ..ops.attention.paged import paged_attention
+
+    n, t = tokens.shape
+    pool_shape = kv_cache["k"].shape
+    num_blocks = pool_shape[1]
+    slots = flat_slots(n, t, live_token_bound)
+    if slots is None:
+        # the padded bucket as it is: the per-token layers see [N, T]
+        safe_pos, live, lengths, blk, off = paged_chunk_indices(
+            tokens, n_tokens, start_pos, block_tables, num_blocks, block_size)
+        to_padded = from_padded = lambda a: a
+    else:
+        # the live tokens on one flat axis: the per-token layers see [1, S]
+        row, col, live, safe_pos, blk, off = (a[None] for a in flat_chunk_indices(
+            n_tokens, start_pos, block_tables, num_blocks, block_size, slots))
+        lengths = start_pos + n_tokens
+        tokens = tokens[row, col]
+        drop_row = jnp.where(live, row, n)[0]  # out of bounds: a dead slot lands nowhere
+
+        def to_padded(a):  # [1, S, ...] -> [N, T, ...], zero wherever no live token sits
+            return jnp.zeros((n, t) + a.shape[2:], a.dtype).at[drop_row, col[0]].set(
+                a[0], mode="drop")
+
+        def from_padded(a):  # [N, T, ...] -> [1, S, ...]; a dead slot's value is never used
+            return a[row, col]
+
+    x = embed(tokens, safe_pos)
+    scale = 1.0 / np.sqrt(pool_shape[-1])
+    head_idx = jnp.arange(pool_shape[2])[None, None, :]  # the pool's (local) KV heads
+
+    def layer(carry, inp):
+        x, kpool, vpool = carry  # the pools whole: [L*NB, KV, bs, Dh]
+        lp, l = inp
+        q, k, v, kept = qkv(lp, x, safe_pos)
+        # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h].  One
+        # index per (token, head): a token's heads written as one window
+        # (.at[row, :, off]) makes the compiler relayout the pool, two copies a pass
+        first = l * num_blocks  # the layer's first row of the flat stack
+        row = (first + blk)[:, :, None]
+        kpool = kpool.at[row, head_idx, off[:, :, None]].set(k)
+        vpool = vpool.at[row, head_idx, off[:, :, None]].set(v)
+        # the kernel takes the flat stack as it would one layer's pool (a Pallas
+        # operand is materialised, so kpool[l] would be a copy): the table is offset
+        attn = from_padded(paged_attention(
+            to_padded(q), kpool, vpool, block_tables + first, lengths, start_pos, n_tokens,
+            block_size=block_size, softmax_scale=scale, window=window,
+            alibi_slopes=alibi_slopes))
+        return (finish(lp, x, kept, attn, live), kpool, vpool), None
+
+    # The pool is carried, never sliced (xs) and restacked (ys): a scan's ys is
+    # a new [L, ...] array that cannot alias a donated argument still being
+    # read, which cost a slice, an update and a copy of the whole pool a pass.
+    flat = (-1, ) + pool_shape[2:]
+    (x, new_k, new_v), _ = jax.lax.scan(
+        layer, (x, kv_cache["k"].reshape(flat), kv_cache["v"].reshape(flat)),
+        (layers, jnp.arange(pool_shape[0], dtype=jnp.int32)))
+    return to_padded(head(x)), {"k": new_k.reshape(pool_shape), "v": new_v.reshape(pool_shape)}
 
 
 # ----------------------------------------------------------------- losses

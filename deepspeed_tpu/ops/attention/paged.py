@@ -16,9 +16,9 @@ entries may point anywhere — never read past ``lengths``); lengths [N] = live
 context per sequence (including this chunk); start_pos/n_tokens [N] describe
 the chunk's absolute query positions.  Causality is absolute-position based so
 chunked prefill and decode share one kernel.  The kernel knows nothing of
-layers: ``llama.forward_paged`` hands it every layer's pool as one
-[L*NB, KV, bs, Dh] and tables offset by ``l*NB`` (a slice ``pool[l]`` handed to
-a Pallas call would be a copy of it); the other families hand one layer's.
+layers: ``models.transformer.paged_forward``, its one caller under ``models/``,
+hands it every layer's pool as one [L*NB, KV, bs, Dh] and tables offset by
+``l*NB`` (a slice ``pool[l]`` handed to a Pallas call would be a copy of it).
 
 GQA maps q-head -> kv-head in the index_map.  Off-TPU falls back to the dense
 gather + masked sdpa (identical math; tests compare the two).

@@ -1,13 +1,18 @@
 """The KV pool carried through the layer scan and written in place (ISSUE 28)
-against the oracle for pool contents: a twin of ``llama.forward_paged`` that
-hands each layer its own pool as the scan's ``xs`` and stacks the written
-pools as ``ys``, the form the program had, with the kernel's rank-4 call.
+against the oracle for pool contents: a twin of ``transformer.paged_forward``,
+the one paged driver (ISSUE 29), that hands each layer its own pool as the
+scan's ``xs`` and stacks the written pools as ``ys``, the form the program had,
+with the kernel's rank-4 call.
 
 Same rows to the same places in the same precision: logits and pools must be
 equal bit for bit, after a chunked prefill followed by decode steps, for the
-padded and the compacted form, inside a burst body, through Mixtral's and
-OLMoE's seams, and under ``tp_axis`` on two host devices.
+padded and the compacted form, inside a burst body, through every family's
+callables, and under ``tp_axis`` on two host devices.
 """
+
+import ast
+import inspect
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -15,12 +20,12 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec
 
+import deepspeed_tpu
 from deepspeed_tpu.compat import shard_map
 from deepspeed_tpu.inference.v2 import tp as tp_rules
-from deepspeed_tpu.models import llama, mistral, mixtral, olmoe
-from deepspeed_tpu.models.transformer import (apply_rotary, flat_chunk_indices, flat_slots,
-                                              paged_chunk_indices, rms_norm, rotary_tables,
-                                              swiglu_mlp)
+from deepspeed_tpu.models import (bloom, falcon, gptj, llama, mistral, mixtral, olmoe, opt, phi,
+                                  qwen, transformer)
+from deepspeed_tpu.models.transformer import flat_chunk_indices, flat_slots, paged_chunk_indices
 from deepspeed_tpu.ops.attention.paged import paged_attention
 from deepspeed_tpu.parallel import MeshTopology
 
@@ -28,22 +33,18 @@ NB, BS, MAXB = 14, 4, 4
 PROMPTS = (9, 5, 2)  # tokens; prefilled in chunks of at most 4, then decoded
 
 
-def sliced_forward_paged(config, params, tokens, n_tokens, start_pos, block_tables, kv_cache, *,
-                         block_size, window=None, tp_axis=None, gather_logits=True,
-                         live_token_bound=None, ffn=None, qk_norm=None):
-    """``llama.forward_paged`` with the pool as the layer scan's xs and ys."""
+def sliced_paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *,
+                         block_size, live_token_bound, embed, qkv, finish, head, window=None,
+                         alibi_slopes=None):
+    """``transformer.paged_forward`` with the pool as the layer scan's xs and ys."""
     n, t = tokens.shape
-    Dh = config.hidden_size // config.num_heads
-    cos, sin = rotary_tables(Dh, config.max_seq_len, config.rope_theta)
     num_blocks = kv_cache["k"].shape[1]
     slots = flat_slots(n, t, live_token_bound)
     if slots is None:
-        b, tchunk = n, t
         safe_pos, live, lengths, blk, off = paged_chunk_indices(
             tokens, n_tokens, start_pos, block_tables, num_blocks, block_size)
         to_padded = from_padded = lambda a: a
     else:
-        b, tchunk = 1, slots
         row, col, live, safe_pos, blk, off = (a[None] for a in flat_chunk_indices(
             n_tokens, start_pos, block_tables, num_blocks, block_size, slots))
         lengths = start_pos + n_tokens
@@ -53,51 +54,33 @@ def sliced_forward_paged(config, params, tokens, n_tokens, start_pos, block_tabl
             drop_row, col[0]].set(a[0], mode="drop")
         from_padded = lambda a: a[row, col]
 
-    x = params["embed"][tokens].astype(kv_cache["k"].dtype)
-    H = params["layers"]["attn"]["wq"].shape[-1] // Dh
-    KV = params["layers"]["attn"]["wk"].shape[-1] // Dh
-    head_idx = jnp.arange(KV)[None, None, :]
-    preduce = (lambda y: jax.lax.psum(y, tp_axis)) if tp_axis else (lambda y: y)
+    x = embed(tokens, safe_pos)
+    scale = 1.0 / np.sqrt(kv_cache["k"].shape[-1])
+    head_idx = jnp.arange(kv_cache["k"].shape[2])[None, None, :]
 
     def layer(x, inp):
         lp, kpool, vpool = inp
-        attn_in = rms_norm(x, lp["attn_norm"], config.rms_eps)
-        q = (attn_in @ lp["attn"]["wq"].astype(x.dtype)).reshape(b, tchunk, H, Dh)
-        k = (attn_in @ lp["attn"]["wk"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        v = (attn_in @ lp["attn"]["wv"].astype(x.dtype)).reshape(b, tchunk, KV, Dh)
-        if qk_norm is not None:
-            q, k = qk_norm(lp, q, k)
-        q = apply_rotary(q, cos, sin, safe_pos)
-        k = apply_rotary(k, cos, sin, safe_pos)
+        q, k, v, kept = qkv(lp, x, safe_pos)
         kpool = kpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(k)
         vpool = vpool.at[blk[:, :, None], head_idx, off[:, :, None]].set(v)
-        out = from_padded(paged_attention(
+        attn = from_padded(paged_attention(
             to_padded(q), kpool, vpool, block_tables, lengths, start_pos, n_tokens,
-            block_size=block_size, softmax_scale=1.0 / np.sqrt(Dh), window=window))
-        x = x + preduce(out.reshape(b, tchunk, H * Dh) @ lp["attn"]["wo"].astype(x.dtype))
-        mlp_in = rms_norm(x, lp["mlp_norm"], config.rms_eps)
-        x = x + preduce(swiglu_mlp(lp["mlp"], mlp_in) if ffn is None else ffn(lp, mlp_in, live))
-        return x, (kpool, vpool)
+            block_size=block_size, softmax_scale=scale, window=window, alibi_slopes=alibi_slopes))
+        return finish(lp, x, kept, attn, live), (kpool, vpool)
 
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = rms_norm(x, params["final_norm"], config.rms_eps)
-    head = params["embed"].T if config.tie_embeddings else params["lm_head"]
-    logits = x @ head.astype(x.dtype)
-    if tp_axis is not None and gather_logits and not config.tie_embeddings:
-        logits = jax.lax.all_gather(logits, tp_axis, axis=-1, tiled=True)
-    return to_padded(logits), {"k": new_k, "v": new_v}
+    x, (new_k, new_v) = jax.lax.scan(layer, x, (layers, kv_cache["k"], kv_cache["v"]))
+    return to_padded(head(x)), {"k": new_k, "v": new_v}
 
 
 def swap_in_the_twin(monkeypatch):
-    """Every family reaches the body through ``llama.forward_paged``, looked up
-    at call time by mistral's and the MoE modules' own ``forward_paged``.
-    Returns the list the twin appends to on each trace."""
+    """Every family reaches the driver as ``transformer.paged_forward``, looked
+    up at call time.  Returns the list the twin appends to on each trace."""
     traced = []
 
     def twin(*args, **kw):
         traced.append(1)
-        return sliced_forward_paged(*args, **kw)
-    monkeypatch.setattr(llama, "forward_paged", twin)
+        return sliced_paged_forward(*args, **kw)
+    monkeypatch.setattr(transformer, "paged_forward", twin)
     return traced
 
 
@@ -143,8 +126,14 @@ FAMILIES = {
     "llama-padded": (llama, llama.LlamaConfig.tiny(layers=3), None),
     "llama-compacted": (llama, llama.LlamaConfig.tiny(layers=3), 8),
     "mistral-window-compacted": (mistral, mistral.MistralConfig.tiny(layers=3, window=6), 8),
-    "mixtral-ffn-seam": (mixtral, mixtral.MixtralConfig.tiny(layers=3), None),
-    "olmoe-ffn-and-qk-norm-seams": (olmoe, olmoe.OlmoeConfig.tiny(layers=3), 8),
+    "mixtral-sparse-ffn": (mixtral, mixtral.MixtralConfig.tiny(layers=3), None),
+    "olmoe-sparse-ffn-and-qk-norm": (olmoe, olmoe.OlmoeConfig.tiny(layers=3), 8),
+    "qwen-qkv-biases-compacted": (qwen, qwen.QwenConfig.tiny(layers=3), 8),
+    "phi-parallel-residual-compacted": (phi, phi.PhiConfig.tiny(layers=3), 8),
+    "falcon-mqa-padded": (falcon, falcon.FalconConfig.tiny(layers=3), None),
+    "gptj-interleaved-rotary-compacted": (gptj, gptj.GPTJConfig.tiny(layers=3), 8),
+    "opt-learned-positions-padded": (opt, opt.OPTConfig.tiny(layers=3), None),
+    "bloom-alibi-compacted": (bloom, bloom.BloomConfig.tiny(layers=3), 8),
 }
 
 
@@ -180,7 +169,7 @@ def test_dead_slots_land_in_each_layers_trash_block():
     np.testing.assert_array_equal(written, want)
 
 
-@pytest.mark.parametrize("family", ["llama-padded", "olmoe-ffn-and-qk-norm-seams"])
+@pytest.mark.parametrize("family", ["llama-padded", "olmoe-sparse-ffn-and-qk-norm"])
 def test_carried_pool_inside_a_burst_body(monkeypatch, family):
     """The pool as the carry of an outer scan of three decode steps (the fused
     burst's form) after a chunked prefill: tokens, logits and pools."""
@@ -214,7 +203,7 @@ def test_carried_pool_inside_a_burst_body(monkeypatch, family):
     assert traced
 
 
-@pytest.mark.parametrize("family", ["llama-compacted", "olmoe-ffn-and-qk-norm-seams"])
+@pytest.mark.parametrize("family", ["llama-compacted", "olmoe-sparse-ffn-and-qk-norm"])
 def test_carried_pool_under_tp_axis_on_two_devices(monkeypatch, family):
     """Inside ``shard_map`` over a tensor axis of two host devices, the pool
     sharded on its heads (axis 2 of ``[L, NB, KV, bs, Dh]``)."""
@@ -244,3 +233,38 @@ def test_carried_pool_under_tp_axis_on_two_devices(monkeypatch, family):
     traced = swap_in_the_twin(monkeypatch)
     assert_bit_equal(got, run())
     assert traced
+
+
+TEN = (llama, mistral, mixtral, olmoe, qwen, phi, falcon, gptj, opt, bloom)
+
+
+def test_one_paged_driver_one_kernel_call_site_and_one_signature():
+    """ISSUE 29's structure: nothing under ``deepspeed_tpu/`` asks a
+    ``forward_paged`` for its signature, ``models/`` calls the paged kernel and
+    each set of chunk indices from one place, no family's ``forward_paged``
+    scans layers or writes a pool itself, and the ten share one signature."""
+    package = pathlib.Path(deepspeed_tpu.__file__).parent
+    calls = {"paged_attention": [], "paged_chunk_indices": [], "flat_chunk_indices": []}
+    for path in sorted(package.rglob("*.py")):
+        tree = ast.parse(path.read_text())
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.Call):
+                continue
+            name = getattr(node.func, "id", getattr(node.func, "attr", None))
+            if name in calls and path.parent.name == "models":
+                calls[name].append(f"{path.name}:{node.lineno}")
+            if name == "signature":
+                assert "forward_paged" not in ast.unparse(node), f"{path}:{node.lineno}"
+    assert {name: len(sites) for name, sites in calls.items()} == {
+        "paged_attention": 1, "paged_chunk_indices": 1, "flat_chunk_indices": 1}, calls
+    assert all(site.startswith("transformer.py:") for sites in calls.values() for site in sites)
+    want = inspect.signature(llama.forward_paged)
+    assert list(want.parameters)[-4:] == ["block_size", "tp_axis", "gather_logits",
+                                          "live_token_bound"]
+    for module in TEN:
+        source = inspect.getsource(module.forward_paged)
+        assert "lax.scan" not in source and ".at[" not in source, module.__name__
+        assert "transformer.paged_forward(" in source, module.__name__
+        got = inspect.signature(module.forward_paged)
+        assert [(p.name, p.kind, p.default) for p in got.parameters.values()] == [
+            (p.name, p.kind, p.default) for p in want.parameters.values()], module.__name__

@@ -127,7 +127,7 @@ def test_paged_attention_kernel_parity(interpreted_kernels, window, alibi):
 @pytest.mark.parametrize("H,KV", [(4, 1), (4, 4)], ids=["group4", "group1"])
 def test_paged_attention_reads_a_layer_of_the_flat_stack_through_offset_tables(
         interpreted_kernels, monkeypatch, H, KV, T, window):
-    """What ``llama.forward_paged`` rests on: every layer's pool as one
+    """What ``transformer.paged_forward`` rests on: every layer's pool as one
     [L*NB, KV, bs, Dh] and the block table offset by ``l*NB`` (traced, as the
     layer scan's index is) give the kernel and the fallback the bits of layer
     l handed alone, at the last layer (an offset lost would read layer 0) and

@@ -114,7 +114,7 @@ def _tiny(module):
 def test_moe_families_serve_compacted_with_no_option_and_count_their_rows(module):
     cfg, params = _tiny(module)
     eng = InferenceEngineV2(module, cfg, params, config={"dtype": "float32"}, **_KW)
-    assert eng._live_token_bound == 16  # the one paged body's compaction, by the engine's sniff
+    assert eng._live_token_bound == 16  # handed to every family: the one paged driver compacts
     got = eng.generate(PROMPTS, max_new_tokens=6)
     padded = InferenceEngineV2(module, cfg, params, config={
         "dtype": "float32", "serving_fastpath": {"enabled": False}}, **_KW)
@@ -142,7 +142,7 @@ def test_a_dense_model_routes_no_rows():
 def test_mixtral_paged_forward_has_no_layer_loop_and_the_dense_ffn_is_gone():
     import inspect
     source = inspect.getsource(mixtral.forward_paged)
-    assert "lax.scan" not in source and "llama.forward_paged(" in source
+    assert "lax.scan" not in source and "transformer.paged_forward(" in source
     assert not hasattr(mixtral, "dense_moe_ffn")
     assert olmoe.forward_paged is mixtral.forward_paged
 
