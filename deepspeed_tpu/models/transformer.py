@@ -506,7 +506,12 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
 
     Attention runs in the Pallas paged kernel (ops/attention/paged.py) on TPU:
     only live blocks are read via scalar-prefetched table indices; off-TPU the
-    identical-math dense-gather fallback runs."""
+    identical-math dense-gather fallback runs.  One grid step of it holds a
+    (sequence, table slot): the block fetched once for all its KV heads, every
+    q head that reads them stacked into the rows of one product, and no
+    arithmetic for the rows of the padded ``[N, T]`` that hold no token.  How
+    many KV heads a step holds the kernel decides from the shapes it is handed
+    here against one VMEM budget (``paged.step_tile``); nothing is passed for it."""
     from ..ops.attention.paged import paged_attention
 
     n, t = tokens.shape

@@ -20,8 +20,46 @@ layers: ``models.transformer.paged_forward``, its one caller under ``models/``,
 hands it every layer's pool as one [L*NB, KV, bs, Dh] and tables offset by
 ``l*NB`` (a slice ``pool[l]`` handed to a Pallas call would be a copy of it).
 
-GQA maps q-head -> kv-head in the index_map.  Off-TPU falls back to the dense
-gather + masked sdpa (identical math; tests compare the two).
+**What one grid step holds.**  The grid is (sequence, group of KV heads, row
+split, table slot), the slot innermost: one step per (sequence, slot) wherever
+a step can hold every KV head.  A step takes the block ``tables[n, b]`` ONCE
+for ``kvg`` KV heads — a [kvg, bs, Dh] tile of K and one of V, contiguous in
+the pool, 256 KiB each for 8 heads of 128 x 128 bf16 — and with it ALL the q
+heads that read those KV heads: q goes in as [N, KV, T * group, Dh], the
+``group = H // KV`` q heads of a KV head stacked into the rows of one product
+(row = token * group + head within the group, a token's group adjacent), so a
+decode step multiplies ``group`` live rows a KV head instead of one a q head
+and a chunk [group * T, Dh] x [Dh, bs]; the step's KV heads go through each
+product together, as its batch dimension ([kvg, rows, Dh] x [kvg, bs, Dh]:
+the body is traced once whatever the head count, and the compiler overlaps
+the heads' products and softmaxes).  The accumulator, ``m`` and ``l`` are
+per (KV head, row) and live in VMEM across a sequence's slots.  Operands are
+the pool's dtype (bf16 on the chip) with f32 accumulation, the probabilities
+cast to it for p . v as ``models.transformer.sdpa`` does; softmax state stays
+f32.  Per-row facts follow the rows: positions and the ``n_tokens`` mask are
+those of token ``row // group``, the ALiBi slope that of q head
+``kv * group + row % group``.
+
+**Rows that hold no token do no arithmetic.**  The live rows of a sequence are
+a prefix of its rows (``n_tokens * group``), so products, the state's
+initialisation and the final division run over the row tiles under that bound
+(tiles of ROW_TILE; one tile of SMALL_ROWS for a decode row riding in a chunk's
+bucket) and the other rows are written as zeros.  A table slot past the
+sequence's last live block is still a grid step, but names the last live block
+again in its index map, so it fetches nothing and computes nothing.
+
+**The tile is chosen from the static shapes** (``step_tile``: T, H, KV, Dh,
+bs and the two dtypes against one VMEM budget, ``VMEM_BUDGET_BYTES``): all KV
+heads a step wherever q, out, the accumulators and the double-buffered K/V
+tiles fit (every decode and verify shape; Mistral's [n, 256] too), a divisor
+of them where they do not (T = 512 with 32 q heads), and only where one KV
+head's rows alone pass the budget (MQA with 64 heads over 512 tokens) are the
+rows cut into several steps, each fetching the block again.  No option, no
+model name, no caller's hint; a block too large for any step is a readable
+error, in the manner of ``check_block_table_fits``.
+
+Off-TPU falls back to the dense gather + masked sdpa (identical math; tests
+compare the two).
 """
 
 import functools
@@ -70,59 +108,155 @@ def check_block_table_fits(n: int, maxb: int, n_vectors: int = 3) -> None:
             f"(n_seqs x max_blocks_per_seq must stay under ~{SMEM_BYTES // 4}).")
 
 
+# Vector memory (VMEM) a grid step may hold by this file's own reckoning
+# (``_step_vmem_bytes``), and the scoped limit the compiler is handed with it
+# (its default, 16 MiB, is under one [32, 256] step of 32 q heads).  A v5e core
+# has 128 MiB; the difference is left to the compiler's own temporaries.
+VMEM_BUDGET_BYTES = 40 << 20
+VMEM_LIMIT_BYTES = 64 << 20
+
+# Rows of q one product takes inside a grid step: a tile of ROW_TILE, or the
+# one tile of SMALL_ROWS where a sequence has no more live rows than that (a
+# decode row riding in a chunk's bucket).  Both are whole bf16 sublane tiles.
+ROW_TILE = 256
+SMALL_ROWS = 16
+
+
+def _step_vmem_bytes(kvg: int, rows: int, tile: int, dh: int, bs: int,
+                     q_bytes: int, kv_bytes: int) -> int:
+    """VMEM of one grid step holding ``kvg`` KV heads and ``rows`` q rows a
+    head: q and out (double-buffered by the pipeline), the K and V tiles
+    (likewise), the f32 accumulator with ``m`` and ``l`` (a lane tile each a
+    row), and one row tile's scores, probabilities and masks for every head."""
+    lanes = _round_up(dh, 128)
+    q_and_out = 2 * 2 * kvg * rows * lanes * q_bytes
+    k_and_v = 2 * 2 * kvg * _round_up(bs, 16) * lanes * kv_bytes
+    state = kvg * rows * (lanes + 2 * 128) * 4
+    work = kvg * tile * (4 * _round_up(bs, 128) + 2 * lanes) * 4
+    return q_and_out + k_and_v + state + work
+
+
+def step_tile(t: int, hq: int, kvh: int, dh: int, bs: int, q_dtype, pool_dtype):
+    """What one grid step holds, from the static shapes alone: ``(kvg, rows,
+    splits, tile)``.  ``kvg`` KV heads (a divisor of ``kvh``) with all their q
+    heads, ``rows`` q rows a KV head (``t * hq // kvh`` live at most, padded to
+    whole row tiles of ``tile``) and, only where one KV head's rows do not fit,
+    the rows cut into ``splits`` grid steps (K and V are then fetched once a
+    split).  The largest step under ``VMEM_BUDGET_BYTES`` wins: all KV heads
+    for every decode and verify shape, fewer for a wide chunk of many heads."""
+    group = hq // kvh
+    q_bytes, kv_bytes = jnp.dtype(q_dtype).itemsize, jnp.dtype(pool_dtype).itemsize
+    rows = _round_up(t * group, SMALL_ROWS)
+    tile = min(rows, ROW_TILE)
+    rows = _round_up(rows, tile)
+
+    def fits(kvg, rows):
+        return _step_vmem_bytes(kvg, rows, tile, dh, bs, q_bytes, kv_bytes) <= VMEM_BUDGET_BYTES
+
+    for kvg in range(kvh, 0, -1):
+        if kvh % kvg == 0 and fits(kvg, rows):
+            return kvg, rows, 1, tile
+    for splits in range(2, rows // tile + 1):
+        part = _round_up(-(-rows // splits), tile)
+        if fits(1, part):
+            return 1, part, splits, tile
+    need = _step_vmem_bytes(1, tile, tile, dh, bs, q_bytes, kv_bytes)
+    raise ValueError(
+        f"paged_attention: one grid step over KV blocks of [{bs}, {dh}] "
+        f"({jnp.dtype(pool_dtype).name}) needs {need} bytes of vector memory with a single "
+        f"KV head and one tile of {tile} q rows (q [T={t}, H={hq}], {kvh} KV heads); the "
+        f"kernel keeps a step under {VMEM_BUDGET_BYTES}. Serve with a smaller KV block "
+        f"size (block_size x head_dim must stay under ~{VMEM_BUDGET_BYTES // (8 * kv_bytes)}).")
+
+
 def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, *rest,
-                  scale, block_size, t_pad, window, alibi):
+                  scale, block_size, group, kvg, tile, window, alibi):
     if alibi:
         slopes_ref, q_ref, k_ref, v_ref, o_ref, acc, m_sc, l_sc = rest
     else:
         q_ref, k_ref, v_ref, o_ref, acc, m_sc, l_sc = rest
-    n, h, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nb = pl.num_programs(2)
+    n, g, r, b = (pl.program_id(i) for i in range(4))
+    nb = pl.num_programs(3)
+    rows = acc.shape[1]
+    length, start, ntok = lengths_ref[n], start_ref[n], ntok_ref[n]
+    first_row = r * rows
+    # row = token * group + (q head within the KV head's group): the rows that
+    # hold a token are a prefix, so leaving the others out is a loop bound
+    live = jnp.clip(ntok * group - first_row, 0, rows)
 
-    @pl.when(b == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+    def each_live_tile(when, fn):
+        """``fn(r0, size)`` for every row tile that holds a token, all local KV
+        heads at once: tiles of ``tile``, or the one of SMALL_ROWS where no more
+        rows are live (a decode row in a chunk's bucket does a decode row's work)."""
+        if rows <= SMALL_ROWS:
+            pl.when(when & (live > 0))(lambda: fn(0, rows))
+            return
+        pl.when(when & (live > 0) & (live <= SMALL_ROWS))(lambda: fn(0, SMALL_ROWS))
 
-    length = lengths_ref[n]
+        @pl.when(when & (live > SMALL_ROWS))
+        def _tiles():
+            jax.lax.fori_loop(0, pl.cdiv(live, tile),
+                              lambda i, _: fn(pl.multiple_of(i * tile, tile), tile), None)
 
-    @pl.when(b * block_size < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [T, Dh]
-        k = k_ref[0, 0].astype(jnp.float32)  # [bs, Dh]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale  # [T, bs]
-        kpos = b * block_size + jax.lax.broadcasted_iota(jnp.int32, (t_pad, block_size), 1)
-        t_iota = jax.lax.broadcasted_iota(jnp.int32, (t_pad, block_size), 0)
-        qp = start_ref[n] + t_iota  # absolute query positions
+    def init(r0, size):
+        at = pl.ds(r0, size)
+        acc[:, at, :] = jnp.zeros((kvg, size, acc.shape[2]), jnp.float32)
+        m_sc[:, at, :] = jnp.full((kvg, size, m_sc.shape[2]), NEG_INF, jnp.float32)
+        l_sc[:, at, :] = jnp.zeros((kvg, size, l_sc.shape[2]), jnp.float32)
+
+    def attend(r0, size):
+        """Rows [r0, r0 + size) of every local KV head against this block: one
+        batched product over the heads, [kvg, size, Dh] x [kvg, bs, Dh]."""
+        at = pl.ds(r0, size)
+        k, v = k_ref[0], v_ref[0]  # [kvg, bs, Dh], the pool's dtype
+        s = jax.lax.dot_general(q_ref[0, :, at, :].astype(k.dtype), k,
+                                (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale  # [kvg, size, bs]
+        row = first_row + r0 + jax.lax.broadcasted_iota(jnp.int32, (1, size, 1), 1)
+        tok = row // group
+        qp = start + tok  # absolute query positions
+        kpos = b * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_size), 2)
         if alibi:
             # ALiBi key-only form: slope_h * absolute key index (softmax-
             # equivalent to the relative-distance form per query row —
             # models/bloom.py docstring; HF build_alibi_tensor)
-            s = s + slopes_ref[h] * kpos.astype(jnp.float32)
-        mask = (kpos <= qp) & (kpos < length) & (t_iota < ntok_ref[n])
+            slopes = []
+            for h in range(kvg):  # q head of a row: (kv head) * group + row % group
+                head = (g * kvg + h) * group
+                slope = jnp.full((1, size, 1), slopes_ref[head], jnp.float32)
+                for j in range(1, group):
+                    slope = jnp.where(row - tok * group == j, slopes_ref[head + j], slope)
+                slopes.append(slope)
+            s = s + jnp.concatenate(slopes, axis=0) * kpos.astype(jnp.float32)
+        # causal, inside the live context, and only for a row that holds a token
+        mask = kpos <= jnp.where(tok < ntok, jnp.minimum(qp, length - 1), -1)
         if window is not None:
             mask = jnp.logical_and(mask, kpos > qp - window)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_sc[:, 0:1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
+        m_prev = m_sc[:, at, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_sc[:, 0:1] = l_sc[:, 0:1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_sc[:, 0:1] = m_new
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        l_sc[:, at, 0:1] = l_sc[:, at, 0:1] * corr + jnp.sum(p, axis=2, keepdims=True)
+        m_sc[:, at, 0:1] = m_new
+        acc[:, at, :] = acc[:, at, :] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+
+    def normalise(r0, size):
+        at = pl.ds(r0, size)
+        l = l_sc[:, at, 0:1]
+        o_ref[0, :, at, :] = (acc[:, at, :] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    each_live_tile(b == 0, init)
+    each_live_tile(b * block_size < length, attend)
 
     @pl.when(b == nb - 1)
-    def _finalize():
-        l = l_sc[:, 0:1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[:] / l_safe).astype(o_ref.dtype)
+    def _zero():  # rows that hold no token come back zero
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    each_live_tile(b == nb - 1, normalise)
 
 
 def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
@@ -145,27 +279,37 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
     alibi = alibi_slopes is not None
     check_block_table_fits(n, maxb, n_vectors=4 if alibi else 3)
     group = hq // kvh
-    t_pad = max(8, int(np.ceil(t / 8)) * 8)
-    qt = jnp.pad(q.transpose(0, 2, 1, 3), ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
+    kvg, rows, splits, tile = step_tile(t, hq, kvh, dh, bs, q.dtype, kpool.dtype)
+    # [N, T, KV, group, Dh] -> [N, KV, T * group, Dh]: a KV head's q rows, a
+    # token's group adjacent, padded with rows that hold no token
+    qr = q.reshape(n, t, kvh, group, dh).transpose(0, 2, 1, 3, 4).reshape(n, kvh, t * group, dh)
+    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, splits * rows - t * group), (0, 0)))
 
-    kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs,
-                               t_pad=t_pad, window=window, alibi=alibi)
-    nsp = 5 if alibi else 4
+    def q_block(ni, g, r, b, *refs):
+        return ni, g, r, 0
+
+    def kv_block(ni, g, r, b, tables, lengths, *refs):
+        # a slot past the sequence's last live block names that block again: the
+        # pipeline fetches a block only when its index changes, so a dead slot
+        # is a grid step and no fetch
+        last = jnp.maximum(lengths[ni] - 1, 0) // bs
+        return tables[ni, jnp.minimum(b, last)], g, 0, 0
+
+    kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs, group=group,
+                               kvg=kvg, tile=tile, window=window, alibi=alibi)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=nsp,
-        grid=(n, hq, maxb),
+        num_scalar_prefetch=5 if alibi else 4,
+        grid=(n, kvh // kvg, splits, maxb),
         in_specs=[
-            pl.BlockSpec((1, 1, t_pad, dh), lambda ni, h, b, *refs: (ni, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, dh),
-                         lambda ni, h, b, tables, *refs: (tables[ni, b], h // group, 0, 0)),
-            pl.BlockSpec((1, 1, bs, dh),
-                         lambda ni, h, b, tables, *refs: (tables[ni, b], h // group, 0, 0)),
+            pl.BlockSpec((1, kvg, rows, dh), q_block),
+            pl.BlockSpec((1, kvg, bs, dh), kv_block),
+            pl.BlockSpec((1, kvg, bs, dh), kv_block),
         ],
-        out_specs=pl.BlockSpec((1, 1, t_pad, dh), lambda ni, h, b, *refs: (ni, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvg, rows, dh), q_block),
         scratch_shapes=[
-            pltpu.VMEM((t_pad, dh), jnp.float32),
-            pltpu.VMEM((t_pad, 128), jnp.float32),
-            pltpu.VMEM((t_pad, 128), jnp.float32),
+            pltpu.VMEM((kvg, rows, dh), jnp.float32),
+            pltpu.VMEM((kvg, rows, 128), jnp.float32),
+            pltpu.VMEM((kvg, rows, 128), jnp.float32),
         ],
     )
     scalars = [tables.astype(jnp.int32), lengths.astype(jnp.int32),
@@ -175,13 +319,15 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, hq, t_pad, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
         compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=_pallas.INTERPRET,
         name="paged_attention",
-    )(*scalars, qt, kpool, vpool)
-    return out[:, :, :t].transpose(0, 2, 1, 3)
+    )(*scalars, qr, kpool, vpool)
+    out = out[:, :, :t * group].reshape(n, kvh, t, group, dh)
+    return out.transpose(0, 2, 1, 3, 4).reshape(n, t, hq, dh)
 
 
 def _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale,

@@ -1,7 +1,8 @@
 """The one file that asks the chip's compiler: the kernels of the main path,
 compiled for a described (not attached) v5e at the shapes ``chip_smoke.py``
-runs — Mistral-7B heads (H=32, KV=8, Dh=128), window 4096.  About 2 s a case,
-no chip time.  What interpret mode cannot show, this does: tiling, scalar and
+runs — Mistral-7B heads (H=32, KV=8, Dh=128), window 4096 — and, for the paged
+kernel, every shape the benchmark's four serving cells meet (OLMoE's 16 MHA
+heads among them).  About 2 s a case, no chip time.  What interpret mode cannot show, this does: tiling, scalar and
 vector memory limits, a kernel the compiler refuses.
 
 The topology is described inside a module-scoped fixture, never at import (only
@@ -65,29 +66,80 @@ def compile_and_count(fn, *avals):
     return kernel_calls(compiled.as_text())
 
 
-def paged_avals(chip, n, t, block, maxb, num_blocks=256, dh=DH, pool_dtype=jnp.bfloat16):
-    return (chip((n, t, H, dh), jnp.bfloat16),
-            chip((num_blocks, KV, block, dh), pool_dtype),
-            chip((num_blocks, KV, block, dh), pool_dtype),
+def paged_avals(chip, n, t, block, maxb, num_blocks=256, dh=DH, pool_dtype=jnp.bfloat16,
+                hq=H, kvh=KV):
+    return (chip((n, t, hq, dh), jnp.bfloat16),
+            chip((num_blocks, kvh, block, dh), pool_dtype),
+            chip((num_blocks, kvh, block, dh), pool_dtype),
             chip((n, maxb), jnp.int32), chip((n, ), jnp.int32),
             chip((n, ), jnp.int32), chip((n, ), jnp.int32))
 
 
-@pytest.mark.parametrize("n,t,block,maxb,window", [
-    pytest.param(32, 1, 128, 40, WINDOW, id="decode-T1-page128"),
-    pytest.param(32, 1, 16, 320, WINDOW, id="decode-T1-page16"),
-    pytest.param(8, 256, 128, 40, WINDOW, id="chunked-prefill-T256"),
-    pytest.param(32, 9, 128, 40, WINDOW, id="spec-verify-T9"),
-    pytest.param(32, 1, 128, 40, None, id="decode-no-window"),
+def _cell_shapes():
+    """Every kernel shape the four serving cells meet, by name from the ledger's
+    ``breakdown.device_ops`` (PR 29: ``_paged_attention.<k>_bf16_<n>_<hq>_<t_pad>_128_``):
+    Mistral 32q/8kv with its window, OLMoE 16q/16kv without; a decode row is t = 1."""
+    mistral = [(32, 1, 12), (32, 128, 12), (32, 256, 12), (32, 1, 20), (32, 128, 20), (32, 256, 20),
+               (16, 1, 12), (16, 256, 12), (4, 256, 36), (32, 5, 12)]
+    for n, t, maxb in mistral:
+        yield pytest.param(n, t, 128, maxb, WINDOW, H, KV, id=f"mistral-n{n}-T{t}-b{maxb}")
+    for n, t, maxb in [(32, 1, 12), (32, 256, 12)]:
+        yield pytest.param(n, t, 128, maxb, None, 16, 16, id=f"olmoe-n{n}-T{t}-b{maxb}")
+
+
+@pytest.mark.parametrize("n,t,block,maxb,window,hq,kvh", [
+    pytest.param(32, 1, 128, 40, WINDOW, H, KV, id="decode-T1-page128"),
+    pytest.param(32, 1, 16, 320, WINDOW, H, KV, id="decode-T1-page16"),
+    pytest.param(8, 256, 128, 40, WINDOW, H, KV, id="chunked-prefill-T256"),
+    pytest.param(32, 9, 128, 40, WINDOW, H, KV, id="spec-verify-T9"),
+    pytest.param(32, 1, 128, 40, None, H, KV, id="decode-no-window"),
+    # the widest steps the tile chooser hands out: KV heads cut to 2 a step, and
+    # an MQA group of 64 whose one KV head's rows are cut into three grid steps
+    pytest.param(4, 512, 128, 12, None, 64, 8, id="chunk-T512-64q8kv"),
+    pytest.param(4, 512, 128, 12, WINDOW, 64, 1, id="chunk-T512-64q1kv-rows-split"),
+    pytest.param(32, 1, 128, 12, WINDOW, 8, 2, id="decode-tensor4-shard-8q2kv"),
+    *_cell_shapes(),
 ])
-def test_paged_attention_compiles(chip, n, t, block, maxb, window):
+def test_paged_attention_compiles(chip, n, t, block, maxb, window, hq, kvh):
     from deepspeed_tpu.ops.attention.paged import paged_attention
 
     def fn(q, k, v, tables, lengths, start, n_tok):
         return paged_attention(q, k, v, tables, lengths, start, n_tok,
                                block_size=block, window=window)
 
-    assert compile_and_count(fn, *paged_avals(chip, n, t, block, maxb)) == {"paged_attention": 1}
+    avals = paged_avals(chip, n, t, block, maxb, hq=hq, kvh=kvh)
+    assert compile_and_count(fn, *avals) == {"paged_attention": 1}
+
+
+@pytest.mark.parametrize("hq,kvh", [(64, 8), (64, 1), (64, 64), (32, 8), (16, 16), (8, 2), (12, 4)])
+def test_a_grid_steps_vector_memory_stays_under_the_budget(hq, kvh):
+    """``step_tile`` reckons a step's VMEM from the static shapes and picks the
+    KV heads (and, past one head, the row split) a step holds: under the budget
+    for up to 64 q heads and chunks up to 512, all KV heads a step at decode
+    and verify, never a split while a whole KV head fits."""
+    from deepspeed_tpu.ops.attention import paged
+    for t in (1, 5, 9, 128, 256, 512):
+        for pool in (jnp.bfloat16, jnp.float32):
+            kvg, rows, splits, tile = paged.step_tile(t, hq, kvh, DH, 128, jnp.bfloat16, pool)
+            need = paged._step_vmem_bytes(kvg, rows, tile, DH, 128, 2, jnp.dtype(pool).itemsize)
+            assert need <= paged.VMEM_BUDGET_BYTES < paged.VMEM_LIMIT_BYTES
+            assert kvh % kvg == 0 and rows % tile == 0 and splits * rows >= t * (hq // kvh)
+            assert splits == 1 or kvg == 1
+            if t <= 9:
+                assert (kvg, splits) == (kvh, 1)
+
+
+def test_a_kv_block_too_large_for_a_grid_step_is_a_readable_error(chip):
+    """A K and a V tile of 32k x 128 bf16, double-buffered, pass the budget
+    with one KV head and one row tile: the chooser says so before the compiler."""
+    from deepspeed_tpu.ops.attention.paged import paged_attention, step_tile
+
+    def fn(q, k, v, tables, lengths, start, n_tok):
+        return paged_attention(q, k, v, tables, lengths, start, n_tok, block_size=32768)
+
+    with pytest.raises(ValueError, match=r"KV blocks of \[32768, 128\].*vector memory.*block size"):
+        jax.jit(fn).lower(*paged_avals(chip, 8, 1, 32768, 4, num_blocks=8))
+    step_tile(1, H, KV, DH, 8192, jnp.bfloat16, jnp.bfloat16)  # 8k blocks still fit
 
 
 def test_paged_attention_alibi_compiles(chip):
