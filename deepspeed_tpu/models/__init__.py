@@ -1,7 +1,8 @@
-from . import (bert, bloom, falcon, gpt2, gptj, llama, mistral, mixtral, olmoe, opt,
-               phi, qwen, transformer)
+from . import (bert, bloom, deepseek_v2, falcon, gpt2, gptj, llama, mistral, mixtral, olmoe,
+               opt, phi, qwen, transformer)
 from .bert import BertConfig
 from .bloom import BloomConfig
+from .deepseek_v2 import DeepseekV2Config
 from .falcon import FalconConfig
 from .gpt2 import GPT2Config
 from .gptj import GPTJConfig

@@ -443,7 +443,8 @@ def tp_psum(tp_axis: Optional[str]):
 def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *,
                   block_size: int, live_token_bound: Optional[int],
                   embed: Callable, qkv: Callable, finish: Callable, head: Callable,
-                  window: Optional[int] = None, alibi_slopes=None):
+                  window: Optional[int] = None, alibi_slopes=None,
+                  softmax_scale: Optional[float] = None, value_dim: Optional[int] = None):
     """The one ragged chunked forward over the paged KV pool (FastGen
     model-forward analog, inference/v2/model_implementations + blocked flash):
     every family's ``forward_paged`` is its own arithmetic as four callables
@@ -468,18 +469,32 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     - ``qkv(lp, x, safe_pos) -> (q, k, v, kept)``: the layer's norm,
       projections, biases, rotary or none, QK-norm; ``q`` ``[b, s, H, Dh]``,
       ``k``/``v`` ``[b, s, KV, Dh]`` (local heads); ``kept`` is whatever the
-      family wants back (the normed ``h`` of a parallel residual, or None);
+      family wants back (the normed ``h`` of a parallel residual, or None).
+      Between ``q`` and ``kept`` stands one row a leaf of the family's pool,
+      in the order of its leaves: ``(q, latent, kept)`` for a pool of one;
     - ``finish(lp, x, kept, attn, live) -> x``: ``wo`` over ``attn``
       ``[b, s, H, Dh]``, the residual form, the FFN, its psums and post-psum
       biases; ``live`` is the ``[b, s]`` mask of slots that hold a token;
     - ``head(x) -> logits``: final norm, tied or untied head, bias, TP gather;
 
-    and the two facts the kernel needs: ``window`` (Mistral's sliding window)
-    and ``alibi_slopes`` ([H] local heads, BLOOM).  ``[b, s]`` is ``[N, T]``
-    or, compacted, ``[1, S]``: a family never asks which.
+    and the facts the kernel needs: ``window`` (Mistral's sliding window),
+    ``alibi_slopes`` ([H] local heads, BLOOM), and for a family whose scores
+    are not ``q . k / sqrt(Dh)`` over a pool of K and a pool of V its
+    ``softmax_scale`` (None: one over the root of q's width) and
+    ``value_dim`` (a latent pool: the value is the first ``value_dim`` columns
+    of the one cached vector, which the kernel reads once).  ``[b, s]`` is
+    ``[N, T]`` or, compacted, ``[1, S]``: a family never asks which.
 
-    ``kv_cache`` is ``{"k", "v"}`` of ``[L, NB, KV, bs, Dh]`` in and out.  The
-    layer scan CARRIES both pools whole beside the activations (its ``xs`` is
+    ``layers`` is the stacked per-layer parameters ``[L, ...]``, or a list of
+    such stacks where the layers are not all alike (a leading dense layer,
+    then the expert layers: DeepSeek-V2).  Each stack is one scan, run in
+    order with the pool and the layer's index carried from one into the next;
+    the callables tell a stack's layers by what ``lp`` holds.
+
+    ``kv_cache`` is whatever tree of ``[L, NB, KV, bs, width]`` leaves the
+    family's ``init_paged_cache`` made (``{"k", "v"}``; one latent leaf for
+    MLA), in and out.  The
+    layer scan CARRIES the pools whole beside the activations (its ``xs`` is
     ``layers``, the stacked per-layer parameters, and the layer's index); each
     layer scatters this step's rows (live tokens x KV x Dh; a dead slot's into
     the layer's trash block, its last) into the carried stack in place and
@@ -515,7 +530,8 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     from ..ops.attention.paged import paged_attention
 
     n, t = tokens.shape
-    pool_shape = kv_cache["k"].shape
+    pool_leaves, pool_tree = jax.tree_util.tree_flatten(kv_cache)
+    pool_shape = pool_leaves[0].shape
     num_blocks = pool_shape[1]
     slots = flat_slots(n, t, live_token_bound)
     if slots is None:
@@ -539,36 +555,41 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
             return a[row, col]
 
     x = embed(tokens, safe_pos)
-    scale = 1.0 / np.sqrt(pool_shape[-1])
     head_idx = jnp.arange(pool_shape[2])[None, None, :]  # the pool's (local) KV heads
 
     def layer(carry, inp):
-        x, kpool, vpool = carry  # the pools whole: [L*NB, KV, bs, Dh]
+        x, *pools = carry  # the pools whole: [L*NB, KV, bs, width]
         lp, l = inp
-        q, k, v, kept = qkv(lp, x, safe_pos)
+        q, *rows, kept = qkv(lp, x, safe_pos)
         # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h].  One
         # index per (token, head): a token's heads written as one window
         # (.at[row, :, off]) makes the compiler relayout the pool, two copies a pass
         first = l * num_blocks  # the layer's first row of the flat stack
         row = (first + blk)[:, :, None]
-        kpool = kpool.at[row, head_idx, off[:, :, None]].set(k)
-        vpool = vpool.at[row, head_idx, off[:, :, None]].set(v)
+        pools = [pool.at[row, head_idx, off[:, :, None]].set(new)
+                 for pool, new in zip(pools, rows)]
         # the kernel takes the flat stack as it would one layer's pool (a Pallas
         # operand is materialised, so kpool[l] would be a copy): the table is offset
+        kpool, vpool = pools if value_dim is None else (pools[0], None)
         attn = from_padded(paged_attention(
             to_padded(q), kpool, vpool, block_tables + first, lengths, start_pos, n_tokens,
-            block_size=block_size, softmax_scale=scale, window=window,
-            alibi_slopes=alibi_slopes))
-        return (finish(lp, x, kept, attn, live), kpool, vpool), None
+            block_size=block_size, softmax_scale=softmax_scale, window=window,
+            alibi_slopes=alibi_slopes, value_dim=value_dim))
+        return (finish(lp, x, kept, attn, live), *pools), None
 
     # The pool is carried, never sliced (xs) and restacked (ys): a scan's ys is
     # a new [L, ...] array that cannot alias a donated argument still being
     # read, which cost a slice, an update and a copy of the whole pool a pass.
-    flat = (-1, ) + pool_shape[2:]
-    (x, new_k, new_v), _ = jax.lax.scan(
-        layer, (x, kv_cache["k"].reshape(flat), kv_cache["v"].reshape(flat)),
-        (layers, jnp.arange(pool_shape[0], dtype=jnp.int32)))
-    return to_padded(head(x)), {"k": new_k.reshape(pool_shape), "v": new_v.reshape(pool_shape)}
+    carry = (x, *(leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in pool_leaves))
+    done = 0  # layers behind the stack being scanned: its first layer's index
+    for stack in layers if isinstance(layers, (list, tuple)) else (layers, ):
+        depth = jax.tree_util.tree_leaves(stack)[0].shape[0]
+        carry, _ = jax.lax.scan(layer, carry,
+                                (stack, jnp.arange(done, done + depth, dtype=jnp.int32)))
+        done += depth
+    x, *pools = carry
+    return to_padded(head(x)), jax.tree_util.tree_unflatten(
+        pool_tree, [pool.reshape(leaf.shape) for pool, leaf in zip(pools, pool_leaves)])
 
 
 # ----------------------------------------------------------------- losses

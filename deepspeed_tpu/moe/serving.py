@@ -49,23 +49,40 @@ def grouped_matmul(lhs, rhs, group_sizes):
     return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None, False, _pallas.INTERPRET)
 
 
-def route(wg, x, top_k: int, renormalise: bool):
+def route(wg, x, top_k: int, renormalise: bool, n_group: int = 1, topk_group: int = 1,
+          scaling: float = 1.0):
     """Router of a top-k MoE layer, in float32: the logits accumulate in
     float32, the softmax runs over ALL experts, ``lax.top_k`` picks, and the
     picked probabilities are divided by their sum only where the checkpoint
     says so (Mixtral: yes; OLMoE ``norm_topk_prob: false``: no).
+
+    ``n_group`` > 1 is the group-limited greedy choice (DeepSeek-V2): the
+    experts lie in ``n_group`` equal runs (one a device of the deployment), a
+    group scores its best expert's probability, and the top-k is taken among
+    the experts of the ``topk_group`` best groups alone.  ``scaling``
+    multiplies the picked weights (``routed_scaling_factor``: 16 there, where
+    nothing is renormalised).  One group and factor 1 (every other family)
+    trace to the plain softmax top-k.
     x [S, D] -> (weights [S, k] float32, experts [S, k] int32)."""
     with jax.named_scope("moe_route"):
         logits = jnp.dot(x, wg.astype(x.dtype), preferred_element_type=jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
+        if n_group > 1:
+            by_group = probs.reshape(probs.shape[0], n_group, -1)
+            _, best = jax.lax.top_k(jnp.max(by_group, axis=-1), topk_group)
+            kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+            probs = jnp.where(kept[:, :, None], by_group, 0.0).reshape(probs.shape)
         top_p, top_idx = jax.lax.top_k(probs, top_k)
         if renormalise:
             top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        if scaling != 1.0:
+            top_p = top_p * scaling
     return top_p, top_idx.astype(jnp.int32)
 
 
 def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
-                   live: Optional[jax.Array] = None, layer: Optional[jax.Array] = None):
+                   live: Optional[jax.Array] = None, layer: Optional[jax.Array] = None,
+                   n_group: int = 1, topk_group: int = 1, scaling: float = 1.0):
     """x [S, D] -> [S, D]: SwiGLU experts under top-k routing.
 
     ``moe_params``: ``{"gate": {"wg": [D, E]}, "experts": {"w_gate": [E, D, F],
@@ -80,7 +97,23 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     the stack would be copied for it, 805 MB a layer at OLMoE's size, as much
     again as the kernels read.  The stack is one grouped matmul's ``L x E``
     groups instead, of which only the layer's hold rows; the kernel visits no
-    empty group, and fetches the others' tiles straight from the stack."""
+    empty group, and fetches the others' tiles straight from the stack.
+
+    **The chip's share of the experts.**  Which experts are held is read off
+    the shapes, as ``paged_forward`` reads its local heads: a router ``[D, E]``
+    over expert leaves ``[.., H, ...]`` with H < E says that this chip holds
+    experts 0..H-1 of a layer that other chips share (an expert-parallel
+    deployment: DeepSeek-V2's 160 as 40 a chip).  The router runs over all E;
+    a pick on an expert that is not here gets the dead group id, as a dead
+    slot's picks do, so it sorts to the tail, reads no weight and adds zero.
+    The result is this chip's experts' part of the layer's sum (what the
+    exchange of the deployment would gather from the other chips is theirs to
+    add: nothing here stands in for them).
+
+    ``moe_params["shared"]`` (``{"w_gate": [D, Fs], "w_up", "w_down"}``), where
+    a family has it, is the expert every token takes: a dense SwiGLU added to
+    the routed part.  ``n_group``, ``topk_group`` and ``scaling`` are
+    :func:`route`'s."""
     ex = moe_params["experts"]
     if layer is None:
         ex, layer = jax.tree_util.tree_map(lambda w: w[None], ex), 0
@@ -89,9 +122,15 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     stacked = {name: w.reshape((groups,) + w.shape[2:]).astype(x.dtype) for name, w in ex.items()}
     slots = x.shape[0]
     picks, rows = slots * top_k, expert_rows(slots, top_k)
-    weights, experts = route(moe_params["gate"]["wg"], x, top_k, renormalise)
+    # one group and no factor: the four arguments route always took, which is
+    # what tests/chipbench/test_reference_olmoe.py's stand-in for it accepts
+    # (a benchmark test: not this module's to edit); the program is the same
+    grouped = () if (n_group, scaling) == (1, 1.0) else (n_group, topk_group, scaling)
+    weights, experts = route(moe_params["gate"]["wg"], x, top_k, renormalise, *grouped)
     with jax.named_scope("moe_expert_ffn"):
         group = layer * num_experts + experts
+        if num_experts < moe_params["gate"]["wg"].shape[-1]:  # a pick on an expert held elsewhere
+            group = jnp.where(experts < num_experts, group, groups)
         if live is not None:
             group = jnp.where(live[:, None], group, groups)
         # row s * k + p is token s's p-th pick; the rows that fill the last tile are dead
@@ -106,4 +145,9 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
         ys = jnp.where((flat[order] < groups)[:, None], ys, 0)
         picked = ys[jnp.argsort(order)[:picks]].reshape(slots, top_k, -1)  # back in token order
         out = jnp.einsum("sk,skd->sd", weights, picked.astype(jnp.float32))
+    if "shared" in moe_params:
+        shared = {name: w.astype(x.dtype) for name, w in moe_params["shared"].items()}
+        with jax.named_scope("moe_shared_expert"):
+            hidden = jax.nn.silu(x @ shared["w_gate"]) * (x @ shared["w_up"])
+            out = out + (hidden @ shared["w_down"]).astype(jnp.float32)
     return out.astype(x.dtype)

@@ -58,6 +58,15 @@ rows cut into several steps, each fetching the block again.  No option, no
 model name, no caller's hint; a block too large for any step is a readable
 error, in the manner of ``check_block_table_fits``.
 
+**A value that is a prefix of the key** (latent attention, MLA absorbed: the
+cached token is one vector ``[c_kv | k_pe]``, the scores run over all of it and
+the weighted sum over its first ``value_dim`` columns).  ``vpool=None`` says
+so: there is no second pool, the step's one tile is read once and its leading
+columns are ``v``; the accumulator and the output are ``value_dim`` wide while
+q and the scores are the key's width (576 against 512 for DeepSeek-V2).  One
+KV head with the q group of every head stacked into the rows is then the whole
+of q: ``[N, T, H, Dk] -> [N, 1, T * H, Dk]`` is a reshape, no transpose.
+
 Off-TPU falls back to the dense gather + masked sdpa (identical math; tests
 compare the two).
 """
@@ -123,27 +132,38 @@ SMALL_ROWS = 16
 
 
 def _step_vmem_bytes(kvg: int, rows: int, tile: int, dh: int, bs: int,
-                     q_bytes: int, kv_bytes: int) -> int:
+                     q_bytes: int, kv_bytes: int, dv: Optional[int] = None) -> int:
     """VMEM of one grid step holding ``kvg`` KV heads and ``rows`` q rows a
     head: q and out (double-buffered by the pipeline), the K and V tiles
     (likewise), the f32 accumulator with ``m`` and ``l`` (a lane tile each a
-    row), and one row tile's scores, probabilities and masks for every head."""
+    row), and one row tile's scores, probabilities and masks for every head.
+    ``dv``: the value is the key tile's first ``dv`` columns (no V tile; out
+    and the accumulator are that wide); None where V is a pool of its own."""
     lanes = _round_up(dh, 128)
-    q_and_out = 2 * 2 * kvg * rows * lanes * q_bytes
-    k_and_v = 2 * 2 * kvg * _round_up(bs, 16) * lanes * kv_bytes
-    state = kvg * rows * (lanes + 2 * 128) * 4
-    work = kvg * tile * (4 * _round_up(bs, 128) + 2 * lanes) * 4
+    out_lanes = lanes if dv is None else _round_up(dv, 128)
+    q_and_out = 2 * kvg * rows * (lanes + out_lanes) * q_bytes
+    k_and_v = (2 if dv is None else 1) * 2 * kvg * _round_up(bs, 16) * lanes * kv_bytes
+    state = kvg * rows * (out_lanes + 2 * 128) * 4
+    work = kvg * tile * (4 * _round_up(bs, 128) + lanes + out_lanes) * 4
     return q_and_out + k_and_v + state + work
 
 
-def step_tile(t: int, hq: int, kvh: int, dh: int, bs: int, q_dtype, pool_dtype):
+def step_tile(t: int, hq: int, kvh: int, dh: int, bs: int, q_dtype, pool_dtype,
+              dv: Optional[int] = None):
     """What one grid step holds, from the static shapes alone: ``(kvg, rows,
     splits, tile)``.  ``kvg`` KV heads (a divisor of ``kvh``) with all their q
     heads, ``rows`` q rows a KV head (``t * hq // kvh`` live at most, padded to
     whole row tiles of ``tile``) and, only where one KV head's rows do not fit,
     the rows cut into ``splits`` grid steps (K and V are then fetched once a
     split).  The largest step under ``VMEM_BUDGET_BYTES`` wins: all KV heads
-    for every decode and verify shape, fewer for a wide chunk of many heads."""
+    for every decode and verify shape, fewer for a wide chunk of many heads.
+    ``dv`` is the value's width where it is a prefix of the key (``vpool=None``
+    in :func:`paged_attention`): one tile a block, out and the accumulator
+    ``dv`` wide.  Its one KV head makes q's rows a reshape, so padding them
+    would be the only copy of q: there the first split into equal whole parts
+    is taken where it costs at most a third more steps than the first that fits
+    (128 heads x 512 tokens: 16 parts of 32 tokens against 13 padded ones).
+    With K and V pools q is transposed anyway and the first fit stands."""
     group = hq // kvh
     q_bytes, kv_bytes = jnp.dtype(q_dtype).itemsize, jnp.dtype(pool_dtype).itemsize
     rows = _round_up(t * group, SMALL_ROWS)
@@ -151,16 +171,20 @@ def step_tile(t: int, hq: int, kvh: int, dh: int, bs: int, q_dtype, pool_dtype):
     rows = _round_up(rows, tile)
 
     def fits(kvg, rows):
-        return _step_vmem_bytes(kvg, rows, tile, dh, bs, q_bytes, kv_bytes) <= VMEM_BUDGET_BYTES
+        return _step_vmem_bytes(kvg, rows, tile, dh, bs, q_bytes, kv_bytes, dv) <= VMEM_BUDGET_BYTES
 
     for kvg in range(kvh, 0, -1):
         if kvh % kvg == 0 and fits(kvg, rows):
             return kvg, rows, 1, tile
-    for splits in range(2, rows // tile + 1):
-        part = _round_up(-(-rows // splits), tile)
-        if fits(1, part):
-            return 1, part, splits, tile
-    need = _step_vmem_bytes(1, tile, tile, dh, bs, q_bytes, kv_bytes)
+    fitting = [(splits, part) for splits in range(2, rows // tile + 1)
+               for part in [_round_up(-(-rows // splits), tile)] if fits(1, part)]
+    if fitting:
+        splits, part = fitting[0]
+        exact = [(s, p) for s, p in fitting if s * p == rows and 3 * s <= 4 * splits]
+        if dv is not None and exact:
+            splits, part = exact[0]
+        return 1, part, splits, tile
+    need = _step_vmem_bytes(1, tile, tile, dh, bs, q_bytes, kv_bytes, dv)
     raise ValueError(
         f"paged_attention: one grid step over KV blocks of [{bs}, {dh}] "
         f"({jnp.dtype(pool_dtype).name}) needs {need} bytes of vector memory with a single "
@@ -170,11 +194,13 @@ def step_tile(t: int, hq: int, kvh: int, dh: int, bs: int, q_dtype, pool_dtype):
 
 
 def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, *rest,
-                  scale, block_size, group, kvg, tile, window, alibi):
+                  scale, block_size, group, kvg, tile, window, alibi, value_dim):
     if alibi:
-        slopes_ref, q_ref, k_ref, v_ref, o_ref, acc, m_sc, l_sc = rest
-    else:
+        slopes_ref, *rest = rest
+    if value_dim is None:
         q_ref, k_ref, v_ref, o_ref, acc, m_sc, l_sc = rest
+    else:  # the value is the key tile's leading columns: one tile a block
+        q_ref, k_ref, o_ref, acc, m_sc, l_sc = rest
     n, g, r, b = (pl.program_id(i) for i in range(4))
     nb = pl.num_programs(3)
     rows = acc.shape[1]
@@ -208,7 +234,8 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, *rest,
         """Rows [r0, r0 + size) of every local KV head against this block: one
         batched product over the heads, [kvg, size, Dh] x [kvg, bs, Dh]."""
         at = pl.ds(r0, size)
-        k, v = k_ref[0], v_ref[0]  # [kvg, bs, Dh], the pool's dtype
+        k = k_ref[0]  # [kvg, bs, Dh], the pool's dtype
+        v = v_ref[0] if value_dim is None else k[:, :, :value_dim]
         s = jax.lax.dot_general(q_ref[0, :, at, :].astype(k.dtype), k,
                                 (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale  # [kvg, size, bs]
@@ -261,25 +288,33 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, *rest,
 
 def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
                     block_size: int, softmax_scale: Optional[float] = None,
-                    window: Optional[int] = None, alibi_slopes=None):
+                    window: Optional[int] = None, alibi_slopes=None,
+                    value_dim: Optional[int] = None):
     """q [N, T, H, Dh]; kpool/vpool [NB, KV, bs, Dh]; tables [N, MAXB] int32;
     lengths/start_pos/n_tokens [N] int32.  Returns [N, T, H, Dh] (rows at
     t >= n_tokens[n] are zero).  ``window`` = sliding-window size (Mistral);
     ``alibi_slopes`` [H] f32 adds slope_h * key_index to the scores (BLOOM —
     reference serves ALiBi through its softmax op's alibi path,
-    ops/transformer/inference/op_binding/softmax.py)."""
+    ops/transformer/inference/op_binding/softmax.py).  ``vpool=None``: the
+    value of a cached token is the first ``value_dim`` columns of its key (a
+    latent pool); the result is then [N, T, H, value_dim]."""
     n, t, hq, dh = q.shape
     kvh, bs = kpool.shape[1], kpool.shape[2]
     maxb = tables.shape[1]
+    if (vpool is None) != (value_dim is not None):
+        raise ValueError("paged_attention: a value pool, or the width of the value inside the "
+                         f"key (vpool=None with value_dim); got vpool={type(vpool).__name__}, "
+                         f"value_dim={value_dim}")
+    dv = dh if value_dim is None else value_dim
     scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(dh))
     if not _use_pallas():
         return _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
-                               scale, window, alibi_slopes)
+                               scale, window, alibi_slopes, value_dim)
 
     alibi = alibi_slopes is not None
     check_block_table_fits(n, maxb, n_vectors=4 if alibi else 3)
     group = hq // kvh
-    kvg, rows, splits, tile = step_tile(t, hq, kvh, dh, bs, q.dtype, kpool.dtype)
+    kvg, rows, splits, tile = step_tile(t, hq, kvh, dh, bs, q.dtype, kpool.dtype, value_dim)
     # [N, T, KV, group, Dh] -> [N, KV, T * group, Dh]: a KV head's q rows, a
     # token's group adjacent, padded with rows that hold no token
     qr = q.reshape(n, t, kvh, group, dh).transpose(0, 2, 1, 3, 4).reshape(n, kvh, t * group, dh)
@@ -296,18 +331,17 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
         return tables[ni, jnp.minimum(b, last)], g, 0, 0
 
     kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs, group=group,
-                               kvg=kvg, tile=tile, window=window, alibi=alibi)
+                               kvg=kvg, tile=tile, window=window, alibi=alibi,
+                               value_dim=value_dim)
+    pools = (kpool, vpool) if value_dim is None else (kpool, )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5 if alibi else 4,
         grid=(n, kvh // kvg, splits, maxb),
-        in_specs=[
-            pl.BlockSpec((1, kvg, rows, dh), q_block),
-            pl.BlockSpec((1, kvg, bs, dh), kv_block),
-            pl.BlockSpec((1, kvg, bs, dh), kv_block),
-        ],
-        out_specs=pl.BlockSpec((1, kvg, rows, dh), q_block),
+        in_specs=[pl.BlockSpec((1, kvg, rows, dh), q_block)]
+        + [pl.BlockSpec((1, kvg, bs, dh), kv_block) for _ in pools],
+        out_specs=pl.BlockSpec((1, kvg, rows, dv), q_block),
         scratch_shapes=[
-            pltpu.VMEM((kvg, rows, dh), jnp.float32),
+            pltpu.VMEM((kvg, rows, dv), jnp.float32),
             pltpu.VMEM((kvg, rows, 128), jnp.float32),
             pltpu.VMEM((kvg, rows, 128), jnp.float32),
         ],
@@ -319,19 +353,19 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qr.shape[:3] + (dv, ), q.dtype),
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=_pallas.INTERPRET,
         name="paged_attention",
-    )(*scalars, qr, kpool, vpool)
-    out = out[:, :, :t * group].reshape(n, kvh, t, group, dh)
-    return out.transpose(0, 2, 1, 3, 4).reshape(n, t, hq, dh)
+    )(*scalars, qr, *pools)
+    out = out[:, :, :t * group].reshape(n, kvh, t, group, dv)
+    return out.transpose(0, 2, 1, 3, 4).reshape(n, t, hq, dv)
 
 
 def _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale,
-                    window, alibi_slopes=None):
+                    window, alibi_slopes=None, value_dim: Optional[int] = None):
     """Reference-math path: gather the whole table, masked sdpa (the v2
     engine's original implementation — kept as the CPU/parity baseline)."""
     from ...models.transformer import sdpa
@@ -339,7 +373,10 @@ def _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale
     maxb = tables.shape[1]
     kvh, bs = kpool.shape[1], kpool.shape[2]
     ctx_k = kpool[tables].transpose(0, 1, 3, 2, 4).reshape(n, maxb * bs, kvh, dh)
-    ctx_v = vpool[tables].transpose(0, 1, 3, 2, 4).reshape(n, maxb * bs, kvh, dh)
+    if vpool is None:
+        ctx_v = ctx_k[..., :value_dim]
+    else:
+        ctx_v = vpool[tables].transpose(0, 1, 3, 2, 4).reshape(n, maxb * bs, kvh, dh)
     positions = start_pos[:, None] + jnp.arange(t)[None, :]
     qpos = jnp.where(jnp.arange(t)[None, :] < n_tokens[:, None], positions, -1)
     kpos = jnp.arange(maxb * bs)[None, None, :]

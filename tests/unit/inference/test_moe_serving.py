@@ -210,3 +210,103 @@ def test_olmoe_state_dict_loads_into_the_layout_the_reference_draws():
     hf_model.config.clip_qkv = 8.0
     with pytest.raises(ValueError, match="clip_qkv"):
         olmoe.config_from_hf(hf_model.config)
+
+
+# --------------------------------------- group-limited routing, a chip's share
+def plain_route(wg, x, top_k, renormalise):
+    """``route`` as it stood before it learnt of groups (PR 27), to the letter."""
+    logits = jnp.dot(x, wg.astype(x.dtype), preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_idx = jax.lax.top_k(probs, top_k)
+    if renormalise:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_idx.astype(jnp.int32)
+
+
+@pytest.mark.parametrize("num_experts,top_k,renormalise", [(64, 8, False), (8, 2, True)],
+                         ids=["olmoe", "mixtral"])
+def test_one_group_and_factor_one_route_bit_for_bit_as_before(num_experts, top_k, renormalise):
+    moe, x = drawn_moe(num_experts)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        got = jax.jit(lambda w, a: route(w, a, top_k, renormalise))(moe["gate"]["wg"], x.astype(dtype))
+        want = jax.jit(lambda w, a: plain_route(w, a, top_k, renormalise))(moe["gate"]["wg"],
+                                                                          x.astype(dtype))
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype and np.array_equal(np.asarray(a), np.asarray(b))
+    # and the traced program is the same program: no trace of groups or of a factor
+    text = lambda f: jax.jit(f).lower(moe["gate"]["wg"], x).as_text()
+    assert text(lambda w, a: route(w, a, top_k, renormalise)).replace("route", "") == \
+        text(lambda w, a: plain_route(w, a, top_k, renormalise)).replace("plain_route", "").replace(
+            "route", "")
+
+
+@pytest.mark.parametrize("n_group,topk_group,top_k", [(4, 2, 4), (8, 3, 6), (2, 1, 3)])
+def test_group_limited_route_is_a_plain_loops(n_group, topk_group, top_k):
+    experts = 8 * n_group
+    moe, x = drawn_moe(experts, seed=3)
+    weights, picks = route(moe["gate"]["wg"], x, top_k, False, n_group, topk_group, 16.0)
+    probs = np.asarray(jax.nn.softmax(x @ moe["gate"]["wg"], axis=-1))
+    differs = 0
+    for s in range(x.shape[0]):
+        best = np.argsort(-probs[s].reshape(n_group, -1).max(axis=1))[:topk_group]
+        allowed = [e for e in range(experts) if e // (experts // n_group) in best]
+        want = sorted(allowed, key=lambda e: -probs[s, e])[:top_k]
+        assert list(np.asarray(picks[s])) == want
+        np.testing.assert_allclose(np.asarray(weights[s]), 16.0 * probs[s, want], rtol=1e-6)
+        differs += set(want) != set(np.argsort(-probs[s])[:top_k])
+    assert differs  # the limit binds for some token, or the test shows nothing
+
+
+def test_a_share_of_the_experts_routes_over_all_and_computes_its_own():
+    """A router over 16 with expert leaves of 4: picks on experts 4..15 are dead
+    rows (no weight read, zero added); what comes back is the dense oracle's sum
+    restricted to experts 0..3, plus the shared expert where there is one."""
+    moe, x = drawn_moe(16, seed=2)
+    held = {name: w[:4] for name, w in moe["experts"].items()}
+    live = jnp.asarray(np.random.default_rng(1).random(24) < 0.7)
+    with jax.default_matmul_precision("highest"):
+        got = sparse_moe_ffn({"gate": moe["gate"], "experts": held}, x, 4, False, live)
+        masked = jax.tree_util.tree_map(lambda w: w.at[4:].set(0.0), moe["experts"])
+        want = dense_oracle({"gate": moe["gate"], "experts": masked}, x, 4, False, live)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+        _, picks = route(moe["gate"]["wg"], x, 4, False)
+        assert (np.asarray(picks) >= 4).any() and (np.asarray(picks) < 4).any()
+        ks = jax.random.split(jax.random.PRNGKey(9), 3)
+        shared = {"w_gate": jax.random.normal(ks[0], (D, F)) * D ** -0.5,
+                  "w_up": jax.random.normal(ks[1], (D, F)) * D ** -0.5,
+                  "w_down": jax.random.normal(ks[2], (F, D)) * F ** -0.5}
+        both = sparse_moe_ffn({"gate": moe["gate"], "experts": held, "shared": shared}, x, 4,
+                              False, live)
+        dense = (jax.nn.silu(x @ shared["w_gate"]) * (x @ shared["w_up"])) @ shared["w_down"]
+        np.testing.assert_allclose(np.asarray(both)[np.asarray(live)],
+                                   np.asarray(want + dense)[np.asarray(live)], atol=2e-5)
+    # every expert held: the program is the one it was (no compare against the held count)
+    text = jax.jit(lambda m, a: sparse_moe_ffn(m, a, 4, False, live)).lower(moe, x).as_text()
+    assert text.count("stablehlo.compare") < jax.jit(lambda m, a: sparse_moe_ffn(
+        m, a, 4, False, live)).lower({"gate": moe["gate"], "experts": held}, x).as_text().count(
+            "stablehlo.compare")
+
+
+def test_deepseek_v2_through_the_engine_counts_every_pick_and_compacts():
+    from deepspeed_tpu.models import deepseek_v2
+    cfg = deepseek_v2.DeepseekV2Config.tiny(local_experts=4)
+    params = deepseek_v2.init_params(cfg, jax.random.PRNGKey(0))
+    assert params["layers"]["moe"]["gate"]["wg"].shape == (2, 128, 16)
+    assert params["layers"]["moe"]["experts"]["w_gate"].shape == (2, 4, 128, 64)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (40, 7, 19)]
+    outs = {}
+    for name, conf in (("fast", {"dtype": "float32"}),
+                       ("padded", {"dtype": "float32", "serving_fastpath": {"enabled": False}})):
+        eng = InferenceEngineV2(deepseek_v2, cfg, params, config=conf, block_size=8,
+                                num_blocks=40, max_blocks_per_seq=8, token_budget=16)
+        outs[name] = [r.tokens for r in eng.generate(prompts, max_new_tokens=5, strict=False)]
+        if name == "fast":
+            c = eng.counters.snapshot()
+            assert c["compact_passes"] > 0
+            # all picks, held or not: live tokens x top-4 x the two expert layers
+            assert c["moe_routed_rows"] == c["live_tokens"] * 4 * 2
+            assert c["moe_expert_rows"] >= c["moe_routed_rows"]
+            assert [leaf.shape for leaf in jax.tree_util.tree_leaves(eng.kv)] == [(3, 40, 1, 8, 128)]
+            eng.check_kv_invariant()
+    assert outs["fast"] == outs["padded"]

@@ -111,6 +111,29 @@ def test_paged_attention_compiles(chip, n, t, block, maxb, window, hq, kvh):
     assert compile_and_count(fn, *avals) == {"paged_attention": 1}
 
 
+@pytest.mark.parametrize("n,t", [(8, 1), (1, 512), (8, 512), (2, 256), (8, 256), (8, 16)],
+                         ids=lambda v: str(v))
+def test_paged_attention_over_a_latent_pool_compiles(chip, n, t):
+    """DeepSeek-V2's shapes as ``serve.mla-long-prompt`` meets them: 128 q heads
+    over ONE 640-wide key (576 in whole lanes) whose first 512 columns are the
+    value, decode rows and chunks up to 512 tokens (65,536 q rows, cut into
+    equal parts), tables 68 wide; one kernel, no copy of the pool or of q."""
+    from deepspeed_tpu.ops.attention.paged import paged_attention, step_tile
+
+    def fn(q, pool, tables, lengths, start, n_tok):
+        return paged_attention(q, pool, None, tables, lengths, start, n_tok, block_size=128,
+                               softmax_scale=0.1147, value_dim=512)
+
+    avals = (chip((n, t, 128, 640), jnp.bfloat16), chip((5 * 1024, 1, 128, 640), jnp.bfloat16),
+             chip((n, 68), jnp.int32), chip((n, ), jnp.int32), chip((n, ), jnp.int32),
+             chip((n, ), jnp.int32))
+    compiled = jax.jit(fn).lower(*avals).compile()
+    assert kernel_calls(compiled.as_text()) == {"paged_attention": 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)  # nothing padded or relaid
+    kvg, rows, splits, tile = step_tile(t, 128, 1, 640, 128, jnp.bfloat16, jnp.bfloat16, 512)
+    assert kvg == 1 and splits * rows == max(t * 128, rows)  # equal whole parts: q is not padded
+
+
 @pytest.mark.parametrize("hq,kvh", [(64, 8), (64, 1), (64, 64), (32, 8), (16, 16), (8, 2), (12, 4)])
 def test_a_grid_steps_vector_memory_stays_under_the_budget(hq, kvh):
     """``step_tile`` reckons a step's VMEM from the static shapes and picks the
@@ -127,6 +150,24 @@ def test_a_grid_steps_vector_memory_stays_under_the_budget(hq, kvh):
             assert splits == 1 or kvg == 1
             if t <= 9:
                 assert (kvg, splits) == (kvh, 1)
+
+
+@pytest.mark.parametrize("hq,kvh,dh,t,want", [
+    (71, 1, 64, 128, (1, 9216, 1)), (71, 1, 64, 256, (1, 9216, 2)), (71, 1, 64, 512, (1, 12288, 3)),
+    (71, 1, 64, 1024, (1, 14592, 5)), (71, 1, 64, 4096, (1, 15360, 19)),
+    (64, 8, 128, 1024, (1, 8192, 1)), (64, 8, 128, 2048, (1, 8192, 2)),
+    (64, 8, 128, 4096, (1, 11008, 3)), (48, 1, 128, 2048, (1, 14080, 7)),
+], ids=lambda v: str(v))
+def test_rows_over_k_and_v_pools_split_at_the_first_fit(hq, kvh, dh, t, want):
+    """With K and V pools the rows that pass one step are cut at the FIRST
+    split that fits (PR 30's rule, its numbers): Falcon's 71 heads over one KV
+    head, a prime, have no equal split short of one head a step, which would
+    fetch every block 71 times.  Only a value inside the key (``dv``) prefers an
+    equal split, and only one that costs at most a third more steps."""
+    from deepspeed_tpu.ops.attention.paged import step_tile
+    assert step_tile(t, hq, kvh, dh, 128, jnp.bfloat16, jnp.bfloat16)[:3] == want
+    if hq == 71:  # the same heads over a latent pool: still never one head a step
+        assert step_tile(t, hq, 1, dh, 128, jnp.bfloat16, jnp.bfloat16, dh // 2)[2] <= want[2]
 
 
 def test_a_kv_block_too_large_for_a_grid_step_is_a_readable_error(chip):
