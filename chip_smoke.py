@@ -148,13 +148,15 @@ def build_serving_engine(cfg, params, sz: Sizes, num_blocks: int, topology=None)
 
 def serve_step_kernels(engine, sz: Sizes):
     """Kernel calls of every compiled ragged-forward program the engine holds,
-    by bucket ``(n_seqs, chunk, table_width)``; each must hold the paged kernel."""
+    by bucket ``(n_seqs, chunk, table_width)``; each must hold the paged kernel
+    and the KV writer (no dense gather and no scatter standing in)."""
     from deepspeed_tpu.ops._pallas import kernel_calls
     calls = {k: kernel_calls(v.as_text()) for k, v in engine._fwd_cache.items()
              if hasattr(v, "as_text")}
     if not sz.rehearsal:
-        require(calls and all(c.get("paged_attention", 0) > 0 for c in calls.values()),
-                f"a serve step compiled without the paged kernel: {calls}")
+        require(calls and all(c.get("paged_attention", 0) > 0 and c.get("kv_write", 0) > 0
+                              for c in calls.values()),
+                f"a serve step compiled without the paged kernel or the KV writer: {calls}")
     return calls
 
 

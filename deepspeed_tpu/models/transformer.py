@@ -496,8 +496,12 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     MLA), in and out.  The
     layer scan CARRIES the pools whole beside the activations (its ``xs`` is
     ``layers``, the stacked per-layer parameters, and the layer's index); each
-    layer scatters this step's rows (live tokens x KV x Dh; a dead slot's into
-    the layer's trash block, its last) into the carried stack in place and
+    layer writes this step's rows into the carried stack in place
+    (``ops/attention/kv_write.py``: on the TPU a Pallas writer, a 16-row tile
+    of a block for every KV head at a time from a list of the tiles the pass
+    touches, made once here from ``n_tokens``, ``start_pos`` and the tables,
+    and nothing for a dead slot; off it a scatter, one index per (token, KV
+    head), a dead slot's row into the layer's trash block, its last) and
     hands the paged kernel the stack as one pool of ``L * NB`` blocks, with
     the block table offset by the layer's first row ``l * NB``: the kernel
     knows nothing of layers.  No layer is ever cut out of the pool or stacked
@@ -527,6 +531,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     arithmetic for the rows of the padded ``[N, T]`` that hold no token.  How
     many KV heads a step holds the kernel decides from the shapes it is handed
     here against one VMEM budget (``paged.step_tile``); nothing is passed for it."""
+    from ..ops.attention.kv_write import kv_write, write_plan
     from ..ops.attention.paged import paged_attention
 
     n, t = tokens.shape
@@ -555,19 +560,18 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
             return a[row, col]
 
     x = embed(tokens, safe_pos)
-    head_idx = jnp.arange(pool_shape[2])[None, None, :]  # the pool's (local) KV heads
+    # which tiles of the pool this pass writes, the same in every layer (None
+    # off the TPU: the scatter's one index per (token, head) writes instead)
+    flat_pools = [leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in pool_leaves]
+    plan = write_plan(flat_pools, n_tokens, start_pos, block_tables, t=t, slots=slots)
 
     def layer(carry, inp):
         x, *pools = carry  # the pools whole: [L*NB, KV, bs, width]
         lp, l = inp
         q, *rows, kept = qkv(lp, x, safe_pos)
-        # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h].  One
-        # index per (token, head): a token's heads written as one window
-        # (.at[row, :, off]) makes the compiler relayout the pool, two copies a pass
+        # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h]
         first = l * num_blocks  # the layer's first row of the flat stack
-        row = (first + blk)[:, :, None]
-        pools = [pool.at[row, head_idx, off[:, :, None]].set(new)
-                 for pool, new in zip(pools, rows)]
+        pools = kv_write(pools, rows, first, blk, off, plan)
         # the kernel takes the flat stack as it would one layer's pool (a Pallas
         # operand is materialised, so kpool[l] would be a copy): the table is offset
         kpool, vpool = pools if value_dim is None else (pools[0], None)
@@ -580,7 +584,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     # The pool is carried, never sliced (xs) and restacked (ys): a scan's ys is
     # a new [L, ...] array that cannot alias a donated argument still being
     # read, which cost a slice, an update and a copy of the whole pool a pass.
-    carry = (x, *(leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in pool_leaves))
+    carry = (x, *flat_pools)
     done = 0  # layers behind the stack being scanned: its first layer's index
     for stack in layers if isinstance(layers, (list, tuple)) else (layers, ):
         depth = jax.tree_util.tree_leaves(stack)[0].shape[0]

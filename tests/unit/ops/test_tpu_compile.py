@@ -280,7 +280,7 @@ def test_compacted_ragged_forward_compiles_and_holds_no_tensor_more_than_padded(
             return mistral.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
                                          block_size=128, live_token_bound=bound)
         compiled = jax.jit(fwd, donate_argnums=(1, )).lower(params, kv, *ints).compile()
-        assert kernel_calls(compiled.as_text()) == {"paged_attention": 1}
+        assert kernel_calls(compiled.as_text()) == {"paged_attention": 1, "kv_write": 1}
         m = compiled.memory_analysis()
         held[bound] = (m.argument_size_in_bytes + m.output_size_in_bytes
                        + m.temp_size_in_bytes - m.alias_size_in_bytes)
@@ -308,26 +308,76 @@ def pool_shaped_results(compiled_text, pool_shape):
     return found
 
 
-@pytest.mark.parametrize("form", ["decode", "compacted", "padded-chunk", "burst"])
-def test_the_pool_is_carried_and_written_in_place(chip, form):
-    """ISSUE 28's guard.  ``forward_paged`` at Mistral's widths (three layers,
-    the serving cells' pool of 368 blocks: one that fits vector memory the
-    compiler prefetches there in slices, which no serving program sees) as a
-    decode step
-    ``[n, 1]``, a compacted and a padded chunk, and inside a two-step scan as
-    the burst runs it: in the optimised program nothing but the scatter that
-    writes a step's rows (and the fusion it sits in) has a pool-shaped result,
-    so no ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` of a layer
-    or of the stack; and the program's temporaries are smaller than the pool."""
+def olmoe_shapes(chip, layers):
+    """OLMoE-1B-7B at its published widths (16 MHA heads: 16 KV heads a block,
+    64 experts) over the serving cells' pool of 368 blocks of 128."""
+    from deepspeed_tpu.models import olmoe
+    cfg = olmoe.OlmoeConfig(num_layers=layers)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    return (olmoe, cfg,
+            on_chip(jax.eval_shape(lambda: olmoe.init_params(cfg, jax.random.PRNGKey(0)))),
+            on_chip(jax.eval_shape(lambda: olmoe.init_paged_cache(cfg, 368, 128))))
+
+
+def deepseek_v2_shapes(chip, layers):
+    """DeepSeek-V2 as ``serve.mla-long-prompt`` holds it (40 of 160 experts, a
+    quarter of the vocabulary; ``layers`` = the dense layer and ``layers - 1``
+    expert layers, a scan each) over its latent pool of 1,024 blocks: one leaf
+    ``[L, 1024, 1, 128, 640]``."""
+    from deepspeed_tpu.models import deepseek_v2
+    cfg = deepseek_v2.DeepseekV2Config(vocab_size=25600, num_layers=layers, num_local_experts=40)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    return (deepseek_v2, cfg,
+            on_chip(jax.eval_shape(lambda: deepseek_v2.init_params(cfg, jax.random.PRNGKey(0)))),
+            on_chip(jax.eval_shape(lambda: deepseek_v2.init_paged_cache(cfg, 1024, 128))))
+
+
+def mistral_module_and_shapes(chip, layers):
     from deepspeed_tpu.models import mistral
-    cfg, params, kv = mistral_shapes(chip, layers=3)
-    pool_shape = kv["k"].shape
-    n, t, bound = {"decode": (16, 1, 256), "compacted": (16, 64, 64), "padded-chunk": (4, 64, None),
-                   "burst": (16, 1, None)}[form]
+    return (mistral, ) + mistral_shapes(chip, layers)
+
+
+IN_PLACE = {  # model, (n, t, live_token_bound), a burst's scan around it
+    "decode": (mistral_module_and_shapes, (16, 1, 256), False),
+    "compacted": (mistral_module_and_shapes, (16, 64, 64), False),
+    "padded-chunk": (mistral_module_and_shapes, (4, 64, None), False),
+    "burst": (mistral_module_and_shapes, (16, 1, None), True),
+    "olmoe-16kv-decode": (olmoe_shapes, (32, 1, 256), False),
+    "olmoe-16kv-compacted": (olmoe_shapes, (32, 256, 256), False),
+    "deepseek-v2-latent-decode": (deepseek_v2_shapes, (8, 1, 512), False),
+    "deepseek-v2-latent-compacted": (deepseek_v2_shapes, (3, 512, 512), False),
+}
+
+
+# layers (every layer of a stack is one scan body: the count sets the pool's
+# size alone) and layer scans of each model's program
+LAYERS_AND_SCANS = {mistral_module_and_shapes: (3, 1), olmoe_shapes: (2, 1),
+                    deepseek_v2_shapes: (5, 2)}
+
+
+@pytest.mark.parametrize("form", list(IN_PLACE))
+def test_the_pool_is_carried_and_written_in_place(chip, form):
+    """ISSUE 28's guard, since ISSUE 32 with the Pallas writer in the scatter's
+    place.  ``forward_paged`` at Mistral's widths (three layers, the serving
+    cells' pool of 368 blocks: one that fits vector memory the compiler
+    prefetches there in slices, which no serving program sees) as a decode step
+    ``[n, 1]``, a compacted and a padded chunk, and inside a two-step scan as
+    the burst runs it; OLMoE's 16 KV heads and DeepSeek-V2's one latent leaf
+    ``[5, 1024, 1, 128, 640]`` (its dense layer and four expert layers: two
+    scans) as a decode step and a compacted chunk.  In the optimised program nothing
+    has a pool-shaped result but the writer's custom call, every leaf aliased in
+    and out: no ``copy``, ``dynamic-slice``, ``dynamic-update-slice`` or
+    scatter of a layer or of the stack; each scan body calls the paged kernel
+    once and the writer once; the program's temporaries are smaller than the
+    pool."""
+    shapes, (n, t, bound), in_a_burst = IN_PLACE[form]
+    layers, scans = LAYERS_AND_SCANS[shapes]
+    module, cfg, params, kv = shapes(chip, layers=layers)
+    leaves = jax.tree_util.tree_leaves(kv)
 
     def fwd(params, kv, tokens, n_tokens, start_pos, tables):
-        return mistral.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
-                                     block_size=128, live_token_bound=bound)
+        return module.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
+                                    block_size=128, live_token_bound=bound)
 
     def burst(params, kv, tokens, n_tokens, start_pos, tables):
         def body(carry, _):
@@ -338,14 +388,14 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
         return toks, kv
 
     ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, 8))]
-    compiled = jax.jit(burst if form == "burst" else fwd,
+    compiled = jax.jit(burst if in_a_burst else fwd,
                        donate_argnums=(1, )).lower(params, kv, *ints).compile()
     text = compiled.as_text()
-    assert kernel_calls(text) == {"paged_attention": 1}
-    results = pool_shaped_results(text, pool_shape)
-    assert [r for r in results if r[0] not in ("scatter", "fusion")] == []
-    assert len([r for r in results if r[0] == "fusion"]) == 2  # the K write and the V write
-    pool_bytes = 2 * int(np.prod(pool_shape)) * 2
+    calls = kernel_calls(text)
+    assert (calls["paged_attention"], calls["kv_write"]) == (scans, scans), calls
+    results = pool_shaped_results(text, leaves[0].shape)
+    assert [r[0] for r in results] == ["custom-call"] * scans, results  # the writer alone
+    pool_bytes = sum(int(np.prod(leaf.shape)) * 2 for leaf in leaves)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
