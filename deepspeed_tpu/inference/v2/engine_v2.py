@@ -100,7 +100,14 @@ class InferenceEngineV2:
         self.model_config = model_config
         self.dtype = _DTYPES[self.config.dtype]
         self.block_size = block_size
-        self.manager = RaggedStateManager(num_blocks, block_size, max_blocks_per_seq)
+        # a family whose layers keep a FIXED state a sequence beside the paged
+        # pool (ISSUE 33: short convolutions) says so by stating its bytes; it
+        # gets one state slot a row of a step, which the manager hands out
+        state_bytes = getattr(model_module, "state_bytes_per_seq", None)
+        self.state_bytes_per_seq = int(state_bytes(model_config)) if state_bytes else 0
+        self.manager = RaggedStateManager(
+            num_blocks, block_size, max_blocks_per_seq,
+            state_slots=max_seqs_per_step if state_bytes else 0)
         # copy-on-write prefix caching (ISSUE 13): requests whose leading full
         # prompt blocks match live computed blocks map them read-only
         # (allocator refcount) and prefill only their divergent tail — the
@@ -233,7 +240,16 @@ class InferenceEngineV2:
         self.tp = topology.axis_size(TENSOR_AXIS) if topology is not None else 1
         self._warn_truncated_nucleus()
         params = jax.tree_util.tree_map(lambda x: jnp.asarray(x, self.dtype), params)
-        kv = model_module.init_paged_cache(model_config, num_blocks, block_size, dtype=self.dtype)
+        if self.manager.state_slots and self.config.serving_spec_decode.enabled:
+            # a rejected draft is rolled back by blocks (rollback_blocks); a
+            # state that has absorbed the rejected tokens cannot follow
+            raise ValueError(
+                f"serving_spec_decode is not supported for {model_module.__name__}: its "
+                f"layers keep a per-sequence state that speculative decoding's rollback "
+                f"of rejected tokens cannot restore")
+        stateful = {"state_slots": self.manager.state_slots} if self.manager.state_slots else {}
+        kv = model_module.init_paged_cache(model_config, num_blocks, block_size, dtype=self.dtype,
+                                           **stateful)
         if self.tp > 1:
             # TP-sharded serving (reference engine_v2.py:81 builds on a TP group;
             # sharding helpers inference/v2/model_implementations/sharding/)
@@ -490,7 +506,7 @@ class InferenceEngineV2:
         self._fwd_cache[key] = fwd.lower(
             jax.tree_util.tree_map(abstract, self.params),
             jax.tree_util.tree_map(abstract, self.kv),
-            ints((n, t)), ints((n, )), ints((n, )), ints((n, b))).compile()
+            ints((n, t)), ints((n, )), ints((n, )), ints((n, self._table_columns(b)))).compile()
         self.ledger.record("fwd", key, wall_s=time.perf_counter() - t0,  # dslint: disable=raw-clock-in-serving  # same stopwatch as t0 above — host compile duration, never the engine clock
                            prewarmed=prewarmed, name=fwd.__name__)
 
@@ -525,6 +541,11 @@ class InferenceEngineV2:
     # batch-shape bucketing shares the ONE pow2 primitive with the scatter-row
     # padding in fastpath.DeviceBatchState (divergence would multiply shapes)
     _bucket = staticmethod(round_up_pow2)
+
+    def _table_columns(self, b: int) -> int:
+        """Columns of a step's table array for ``b`` table slots: one more for
+        a model with a per-sequence state, each row's state slot."""
+        return b + (1 if self.manager.state_slots else 0)
 
     def _stepped_width(self, blocks: int) -> int:
         """Block-table width rounded up in TABLE_STEP-slot increments, capped
@@ -613,7 +634,7 @@ class InferenceEngineV2:
         # walks every table slot, so dead trailing slots are pure waste
         b = self._table_width_for(max(len(self.manager.seqs[c.uid].blocks)
                                       for c in chunks))
-        key = (n, t, b)
+        held = (n, t, self._table_columns(b))  # the buffers' key: the table array's width
         rows = []
         feeds = []
         live_blocks = 0
@@ -621,7 +642,7 @@ class InferenceEngineV2:
             for i, c in enumerate(chunks):
                 seq = self.manager.seqs[c.uid]
                 sl = seq.tokens[seq.seen_tokens:seq.seen_tokens + c.n_tokens]
-                packed = np.zeros(3 + t + b, np.int32)
+                packed = np.zeros(3 + t + held[2], np.int32)
                 packed[0] = i
                 if c.n_tokens == 1 and sl[0] == PENDING_TOKEN:
                     # the input token is the previous step's sample, still on
@@ -638,10 +659,10 @@ class InferenceEngineV2:
                 packed[3 + t:] = self.manager.block_table_row(seq, width=b)
                 rows.append((i, packed))
                 live_blocks += len(seq.blocks)
-            slot = self.batch_state.update(key, rows, n_active=len(chunks),
+            slot = self.batch_state.update(held, rows, n_active=len(chunks),
                                            trash_block=self.manager.trash_block)
             if feeds:
-                self.batch_state.feed(key, self._inflight.toks_dev, feeds)
+                self.batch_state.feed(held, self._inflight.toks_dev, feeds)
         self.phase_profiler.mark("scatter_upload")
         fwd = self._compiled_fwd(n, t, b)
         self.counters.dispatches += 1
@@ -695,7 +716,7 @@ class InferenceEngineV2:
         tokens = np.zeros((n, t), np.int32)
         n_tokens = np.zeros((n, ), np.int32)
         start_pos = np.zeros((n, ), np.int32)
-        tables = np.full((n, b), self.manager.trash_block, np.int32)
+        tables = np.tile(self.manager.dead_table_row(b), (n, 1))
         live_blocks = 0
         for i, c in enumerate(chunks):
             seq = self.manager.seqs[c.uid]
@@ -1150,8 +1171,8 @@ class InferenceEngineV2:
         b = self._table_width_for(max(len(s.blocks) for s in live))
         tok0 = np.zeros((n, ), np.int32)
         start0 = np.zeros((n, ), np.int32)
-        # padded rows: decode into the trash block at position 0
-        tables = np.full((n, b), self.manager.trash_block, np.int32)
+        # padded rows: decode into the trash block (and trash state slot) at position 0
+        tables = np.tile(self.manager.dead_table_row(b), (n, 1))
         for i, seq in enumerate(live):
             tok0[i] = seq.tokens[seq.seen_tokens]
             start0[i] = seq.seen_tokens
@@ -2255,6 +2276,7 @@ class InferenceEngineV2:
                           for uid, s in self.manager.seqs.items()},
             "free_blocks": alloc.free_blocks,
             "num_blocks": alloc.num_blocks,
+            "state": self._state_snapshot(),
             "queue_depth": len(self.admission),
             "scheduler_steps": self.scheduler.steps,
             # block-level pool state (ISSUE 12): the full per-block census
@@ -2277,6 +2299,20 @@ class InferenceEngineV2:
             # recorder's tail rides every stall dump for postmortems
             "flight_recorder": self.tracer.recorder.tail(),
         }
+
+    def _state_snapshot(self) -> Dict[str, Any]:
+        """The fixed per-sequence state beside the pool (ISSUE 33): slots and
+        bytes, hand-outs (each starts a sequence from the zero state), prefix
+        hits declined because mapped blocks would not restore it."""
+        m = self.manager
+        if not m.state_slots:
+            return {"enabled": False}
+        return {"enabled": True, "state_slots": m.state_slots,
+                "state_slots_in_use": m.state_slots_in_use,
+                "state_bytes_per_seq": self.state_bytes_per_seq,
+                "state_slots_zeroed": m.state_slots_zeroed,
+                "prefix_declined_stateful": (m.prefix_cache.declined_stateful_total
+                                             if m.prefix_cache is not None else 0)}
 
     def _kv_snapshot(self, with_table: bool = False) -> Dict[str, Any]:
         """The ``health()["kv"]`` / ``state_snapshot()["kv"]`` payload:
@@ -2316,6 +2352,7 @@ class InferenceEngineV2:
             "queue_depth": len(self.admission),
             "free_blocks": self.manager.allocator.free_blocks,
             "kv_utilization": self.manager.kv_utilization(),
+            "state": self._state_snapshot(),
             # block-level pool observability (ISSUE 12): census rollups
             # (fragmentation, block-age, blocks-per-request), counterfactual
             # prefix-cache opportunity, and the steps-to-exhaustion forecast

@@ -94,6 +94,12 @@ class SequenceDescriptor:
     # lower-class victims
     tenant: str = "default"
     service_class: str = "interactive"
+    # --- fixed per-sequence state (ISSUE 33) ---
+    # the slot of the model's state array this sequence's rows name, for a
+    # family that keeps a fixed state a sequence beside the paged pool; None
+    # for every other family, and while a sequence waits for a free slot
+    state_slot: Optional[int] = None
+    prefix_declined: bool = False  # its would-be prefix hit was declined (counted once)
 
     @property
     def pending_tokens(self) -> int:
@@ -159,6 +165,8 @@ class PrefixCache:
         self.collision_rejects_total = 0  # hash matched, token ids/ancestry did not
         self.deferrals_total = 0         # prefill chunks deferred one step onto a
         # block another scheduled sequence is computing
+        self.declined_stateful_total = 0  # sequences whose hit was declined: their
+        # model keeps a per-sequence state that mapped KV blocks would not restore
 
     def __len__(self) -> int:
         return len(self.entries)
@@ -223,6 +231,7 @@ class PrefixCache:
             "evicted_total": self.evicted_total,
             "collision_rejects_total": self.collision_rejects_total,
             "deferrals_total": self.deferrals_total,
+            "declined_stateful_total": self.declined_stateful_total,
             "realized_hit_rate": self.realized_hit_rate(),
         }
 
@@ -230,8 +239,21 @@ class PrefixCache:
 class RaggedStateManager:
 
     def __init__(self, num_blocks: int, block_size: int, max_blocks_per_seq: int,
-                 prefix_cache: Optional[PrefixCache] = None):
+                 prefix_cache: Optional[PrefixCache] = None, state_slots: int = 0):
         self.allocator = BlockedAllocator(num_blocks)
+        # the second kind of cache (ISSUE 33): a model whose layers remember a
+        # FIXED state a sequence (a short convolution's last values) keeps it
+        # in ``state_slots`` slots beside the paged pool; 0 for every other
+        # model, and nothing below then happens.  A sequence takes a slot at
+        # intake, or where none is free when the scheduler first reserves for
+        # it (:meth:`ensure_state_slot`), names it in its table row's last
+        # column, and gives it back when it ends or is preempted.  A slot is
+        # never zeroed in memory: a sequence at position 0 does not read it
+        # (models/transformer.py paged_forward), so a hand-out IS the zeroing
+        # and ``state_slots_zeroed`` counts the hand-outs.
+        self.state_slots = int(state_slots)
+        self._free_state_slots = list(range(self.state_slots - 1, -1, -1))
+        self.state_slots_zeroed = 0
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
         # block census (inference/v2/kv_metrics.BlockCensus) — attached by the
@@ -262,6 +284,32 @@ class RaggedStateManager:
     @property
     def trash_block(self) -> int:
         return self.allocator.trash_block
+
+    @property
+    def trash_slot(self) -> int:
+        """The state slot a dead row's writes go to: the one past the last."""
+        return self.state_slots
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return self.state_slots - len(self._free_state_slots)
+
+    def ensure_state_slot(self, seq: SequenceDescriptor) -> bool:
+        """Give ``seq`` a state slot if it has none; False where none is free
+        (it then waits: some live sequence holds each slot and ends).  True
+        for a model without a state."""
+        if not self.state_slots or seq.state_slot is not None:
+            return True
+        if not self._free_state_slots:
+            return False
+        seq.state_slot = self._free_state_slots.pop()
+        self.state_slots_zeroed += 1
+        return True
+
+    def _release_state_slot(self, seq: SequenceDescriptor) -> None:
+        if seq.state_slot is not None:
+            self._free_state_slots.append(seq.state_slot)
+            seq.state_slot = None
 
     def add_sequence(self, uid: int, prompt_tokens: List[int], *, priority: int = 0,
                      deadline: Optional[float] = None,
@@ -303,6 +351,7 @@ class RaggedStateManager:
         self._arrivals += 1
         self.seqs[uid] = seq
         self.total_requests += 1
+        self.ensure_state_slot(seq)
         return seq
 
     def ensure_blocks(self, seq: SequenceDescriptor, upto_tokens: int) -> None:
@@ -360,6 +409,15 @@ class RaggedStateManager:
         """
         cache = self.prefix_cache
         if cache is None or seq.done or not seq.prefix_hashes:
+            return 0
+        if self.state_slots:
+            # a hit maps KV blocks; the sequence's state at that boundary is kept
+            # nowhere, so its layers would start from zero in mid-prompt: declined
+            # (the prompt is prefilled whole) and counted, once a sequence
+            if (not seq.prefix_declined and seq.seen_tokens == 0
+                    and seq.prefix_hashes[0] in cache.entries):
+                seq.prefix_declined = True
+                cache.declined_stateful_total += 1
             return 0
         bs = self.block_size
         saved = 0
@@ -419,8 +477,8 @@ class RaggedStateManager:
         :meth:`map_prefix` this is by construction a TREE MISS — the
         scheduler defers the chunk one step iff another scheduled sequence is
         computing exactly this block."""
-        if self.prefix_cache is None or not seq.prefix_hashes:
-            return None
+        if self.prefix_cache is None or not seq.prefix_hashes or self.state_slots:
+            return None  # (a model with a state maps no prefix: nothing to wait for)
         i = len(seq.blocks)
         if seq.seen_tokens != i * self.block_size or i >= len(seq.prefix_hashes):
             return None
@@ -461,6 +519,7 @@ class RaggedStateManager:
             seq.done = True
             self._reclaim(uid, seq.blocks)  # reclaim the KV pool immediately
             seq.blocks = []
+            self._release_state_slot(seq)
 
     def evict(self, seq: SequenceDescriptor, finish_reason: str) -> int:
         """End a sequence WITHOUT completion: done + finish reason + KV blocks
@@ -475,6 +534,7 @@ class RaggedStateManager:
         if seq.blocks:
             released = len(self._reclaim(seq.uid, seq.blocks))
             seq.blocks = []
+        self._release_state_slot(seq)
         return released
 
     def preempt(self, seq: SequenceDescriptor, keep_blocks: int = 0) -> int:
@@ -484,7 +544,15 @@ class RaggedStateManager:
         they are never rewritten); the dropped positions are simply recomputed
         when the sequence is rescheduled.  Returns the number of blocks
         ACTUALLY released to the pool — dropping a SHARED mapping returns no
-        capacity, and the scheduler's rescue policy keys on this."""
+        capacity, and the scheduler's rescue policy keys on this.
+
+        A model with a per-sequence state keeps no state of a block boundary,
+        so its victim keeps no block: it gives its slot back and resumes from
+        its first token with the zero state of a new sequence, to the logits
+        of an undisturbed run."""
+        if self.state_slots:
+            keep_blocks = 0
+            self._release_state_slot(seq)
         released = self.rollback_blocks(seq, keep_blocks)
         seq.seen_tokens = min(seq.seen_tokens, len(seq.blocks) * self.block_size)
         return released
@@ -531,8 +599,20 @@ class RaggedStateManager:
         the compiled width instead of building max_blocks_per_seq and
         slicing)."""
         width = self.max_blocks_per_seq if width is None else width
-        row = np.full(width, self.trash_block, np.int32)
+        row = self.dead_table_row(width)
         row[:len(seq.blocks)] = seq.blocks
+        if self.state_slots and seq.state_slot is not None:
+            row[width] = seq.state_slot
+        return row
+
+    def dead_table_row(self, width: int) -> np.ndarray:
+        """The table row of a batch row that holds no sequence: ``width``
+        entries naming the trash block and, for a model with a per-sequence
+        state, one more naming the trash slot (the row's last column is its
+        sequence's state slot: models/transformer.py paged_forward)."""
+        row = np.full(width + (1 if self.state_slots else 0), self.trash_block, np.int32)
+        if self.state_slots:
+            row[width] = self.trash_slot
         return row
 
     def retire(self, uid: int, *, completed: bool = True) -> None:
@@ -557,6 +637,7 @@ class RaggedStateManager:
             self.retired_uids.pop(next(iter(self.retired_uids)))
         self._reclaim(uid, seq.blocks)
         seq.blocks = []
+        self._release_state_slot(seq)
         if self.census is not None:
             self.census.on_terminal(uid)
         # neither a flushed failure nor an evicted request is a completion

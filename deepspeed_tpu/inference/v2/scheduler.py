@@ -296,6 +296,8 @@ class SplitFuseScheduler:
                 manager.fail(seq.uid, f"needs {upto} tokens > "
                              f"{manager.max_blocks_per_seq * manager.block_size} cap")
             return False
+        if not manager.ensure_state_slot(seq):
+            return False  # every state slot is held: wait for a sequence to end
         need = manager.blocks_needed(seq, upto)
         if need and not manager.can_allocate(need):
             return False

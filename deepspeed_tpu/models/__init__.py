@@ -1,4 +1,4 @@
-from . import (bert, bloom, deepseek_v2, falcon, gpt2, gptj, llama, mistral, mixtral, olmoe,
+from . import (bert, bloom, deepseek_v2, falcon, gpt2, gptj, lfm2, llama, mistral, mixtral, olmoe,
                opt, phi, qwen, transformer)
 from .bert import BertConfig
 from .bloom import BloomConfig
@@ -6,6 +6,7 @@ from .deepseek_v2 import DeepseekV2Config
 from .falcon import FalconConfig
 from .gpt2 import GPT2Config
 from .gptj import GPTJConfig
+from .lfm2 import Lfm2Config
 from .llama import LlamaConfig
 from .mistral import MistralConfig
 from .mixtral import MixtralConfig

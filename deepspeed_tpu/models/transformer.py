@@ -434,6 +434,10 @@ def flat_chunk_indices(n_tokens, start_pos, block_tables, num_blocks: int,
     return row, col, live, safe_pos, blk, off
 
 
+STATE = "state"  # the key of a family's cache tree that holds its fixed per-sequence state
+STATE_MIXER = "mixer"  # a layer whose parameters hold this key has no attention: ``mix`` runs it
+
+
 def tp_psum(tp_axis: Optional[str]):
     """What a family's ``finish`` does with a row-parallel partial: the psum
     over ``tp_axis`` inside shard_map, nothing on one chip."""
@@ -444,7 +448,8 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                   block_size: int, live_token_bound: Optional[int],
                   embed: Callable, qkv: Callable, finish: Callable, head: Callable,
                   window: Optional[int] = None, alibi_slopes=None,
-                  softmax_scale: Optional[float] = None, value_dim: Optional[int] = None):
+                  softmax_scale: Optional[float] = None, value_dim: Optional[int] = None,
+                  mix: Optional[Callable] = None):
     """The one ragged chunked forward over the paged KV pool (FastGen
     model-forward analog, inference/v2/model_implementations + blocked flash):
     every family's ``forward_paged`` is its own arithmetic as four callables
@@ -489,7 +494,29 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     such stacks where the layers are not all alike (a leading dense layer,
     then the expert layers: DeepSeek-V2).  Each stack is one scan, run in
     order with the pool and the layer's index carried from one into the next;
-    the callables tell a stack's layers by what ``lp`` holds.
+    the callables tell a stack's layers by what ``lp`` holds.  A stack that is
+    a TUPLE of stacks is one period of a layer pattern (attention, conv, conv,
+    conv): the scan runs over the periods and its body runs the period's
+    layers in order, so layers of several kinds share one scan.
+
+    **Layers without attention** (``STATE_MIXER`` among ``lp``'s keys; LFM2's
+    gated short convolutions).  Such a layer touches neither the pool nor the
+    write plan nor the kernel: ``mix(lp, x, taps, live) -> x`` is the whole
+    layer, and what it remembers of a sequence's past is a fixed state a
+    SEQUENCE, not rows a token: ``kv_cache[STATE]``, one array ``[Ls, slots +
+    1, k, D]`` (``Ls`` such layers; the last slot is the trash slot of a dead
+    row) beside the pool's leaves, carried through the scans as they are and
+    written in place.  The engine names each row's slot in one further,
+    trailing column of ``block_tables``.  ``taps(z) -> [z_{t-k}, ..., z_{t-1}]``
+    is the shift that is local to a sequence, which only this function can
+    give, since it alone knows where a step's tokens lie: for ``z`` ``[b, s,
+    D]`` in either layout it returns the ``k`` earlier values of every token's
+    own sequence, from the chunk itself where the chunk has them and from the
+    row's slot where it does not (a chunk's first ``k`` tokens; zeros where
+    ``start_pos == 0``: a slot is never zeroed in memory, a sequence that
+    starts over simply does not read it), and the chunk's last ``k`` values
+    go back to the slot (a chunk of one token shifts the state).  The pool's
+    row is counted over the attention layers alone, the state's over the rest.
 
     ``kv_cache`` is whatever tree of ``[L, NB, KV, bs, width]`` leaves the
     family's ``init_paged_cache`` made (``{"k", "v"}``; one latent leaf for
@@ -535,6 +562,13 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     from ..ops.attention.paged import paged_attention
 
     n, t = tokens.shape
+    state = None
+    if isinstance(kv_cache, dict) and STATE in kv_cache:
+        kv_cache = dict(kv_cache)
+        state = kv_cache.pop(STATE)
+        # the rows' state slots ride as the table's last column; a dead row's is the trash slot
+        seq_slot = jnp.where(n_tokens > 0, block_tables[:, -1], state.shape[1] - 1)
+        block_tables = block_tables[:, :-1]
     pool_leaves, pool_tree = jax.tree_util.tree_flatten(kv_cache)
     pool_shape = pool_leaves[0].shape
     num_blocks = pool_shape[1]
@@ -544,6 +578,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
         safe_pos, live, lengths, blk, off = paged_chunk_indices(
             tokens, n_tokens, start_pos, block_tables, num_blocks, block_size)
         to_padded = from_padded = lambda a: a
+        row = col = None
     else:
         # the live tokens on one flat axis: the per-token layers see [1, S]
         row, col, live, safe_pos, blk, off = (a[None] for a in flat_chunk_indices(
@@ -565,9 +600,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     flat_pools = [leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in pool_leaves]
     plan = write_plan(flat_pools, n_tokens, start_pos, block_tables, t=t, slots=slots)
 
-    def layer(carry, inp):
-        x, *pools = carry  # the pools whole: [L*NB, KV, bs, width]
-        lp, l = inp
+    def attention_layer(x, pools, lp, l):
         q, *rows, kept = qkv(lp, x, safe_pos)
         # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h]
         first = l * num_blocks  # the layer's first row of the flat stack
@@ -579,21 +612,97 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
             to_padded(q), kpool, vpool, block_tables + first, lengths, start_pos, n_tokens,
             block_size=block_size, softmax_scale=softmax_scale, window=window,
             alibi_slopes=alibi_slopes, value_dim=value_dim))
-        return (finish(lp, x, kept, attn, live), *pools), None
+        return finish(lp, x, kept, attn, live), pools
+
+    def mixer_layer(x, flat_state, lp, l):
+        """A layer whose past is its sequences' slots ``[N, k, D]`` of the flat state."""
+        if state is None or mix is None:
+            raise ValueError(f"a layer holds {STATE_MIXER!r} and the family gave no "
+                             f"{'mix' if mix is None else 'kv_cache[STATE]'}")
+        at = l * state.shape[1] + seq_slot
+        with jax.named_scope("conv_state"):
+            kept = jnp.where((start_pos > 0)[:, None, None], flat_state[at], 0)
+        tail = []
+
+        def taps(z):
+            earlier, last = sequence_taps(z, kept, n_tokens, row, col)
+            tail.append(last)
+            return earlier
+
+        x = mix(lp, x, taps, live)
+        with jax.named_scope("conv_state"):
+            (last, ) = tail  # one shift a layer
+            return x, flat_state.at[at].set(last.astype(flat_state.dtype))
 
     # The pool is carried, never sliced (xs) and restacked (ys): a scan's ys is
     # a new [L, ...] array that cannot alias a donated argument still being
     # read, which cost a slice, an update and a copy of the whole pool a pass.
-    carry = (x, *flat_pools)
-    done = 0  # layers behind the stack being scanned: its first layer's index
-    for stack in layers if isinstance(layers, (list, tuple)) else (layers, ):
-        depth = jax.tree_util.tree_leaves(stack)[0].shape[0]
-        carry, _ = jax.lax.scan(layer, carry,
-                                (stack, jnp.arange(done, done + depth, dtype=jnp.int32)))
-        done += depth
+    carry = (x, *flat_pools) if state is None else (
+        x, *flat_pools, state.reshape((-1, ) + state.shape[2:]))
+    done = {False: 0, True: 0}  # attention / mixer layers behind the stack being scanned
+    for stack in layers if isinstance(layers, list) else [layers]:
+        period = stack if isinstance(stack, tuple) else (stack, )
+        mixes = [STATE_MIXER in lp for lp in period]  # by what a layer's parameters hold
+        depth = jax.tree_util.tree_leaves(period[0])[0].shape[0]
+        # each kind's index of a period's first layer of that kind: the pool's and the state's row
+        # (no step where it is 1: a given step compiles every older family's program anew)
+        firsts = {kind: jnp.arange(done[kind], done[kind] + depth * mixes.count(kind),
+                                   *([mixes.count(kind)] if mixes.count(kind) > 1 else []),
+                                   dtype=jnp.int32)
+                  for kind in set(mixes)}
+
+        def body(carry, inp, mixes=mixes):
+            (x, *pools), (lps, first) = carry, inp
+            for j, (lp, is_mix) in enumerate(zip(lps, mixes)):
+                behind = mixes[:j].count(is_mix)  # layers of its kind before it in the period
+                l = first[is_mix] + behind if behind else first[is_mix]
+                if is_mix:
+                    x, pools[-1] = mixer_layer(x, pools[-1], lp, l)
+                else:
+                    x, pools[:len(flat_pools)] = attention_layer(x, pools[:len(flat_pools)], lp, l)
+            return (x, *pools), None
+
+        carry, _ = jax.lax.scan(body, carry, (period, firsts))
+        for kind in firsts:
+            done[kind] += depth * mixes.count(kind)
     x, *pools = carry
-    return to_padded(head(x)), jax.tree_util.tree_unflatten(
+    cache = jax.tree_util.tree_unflatten(
         pool_tree, [pool.reshape(leaf.shape) for pool, leaf in zip(pools, pool_leaves)])
+    if state is not None:
+        cache[STATE] = pools[-1].reshape(state.shape)
+    return to_padded(head(x)), cache
+
+
+def sequence_taps(z, kept, n_tokens, row, col):
+    """The shift of :func:`paged_forward`'s ``taps``.  ``z`` ``[b, s, D]`` in the
+    padded layout ``[N, T]`` (``row`` None) or the compacted ``[1, S]`` (``row``
+    / ``col`` ``[1, S]``: whose token a flat slot holds); ``kept`` ``[N, k, D]``
+    the rows' remembered values, oldest first.  Returns ``([z_{t-k}, ...,
+    z_{t-1}], last)``: each shifted copy like ``z``, and ``last`` ``[N, k, D]``
+    the rows' new remembered values: the last ``k`` of what was kept followed
+    by the chunk's live tokens (a row with no token keeps what it had)."""
+    k = kept.shape[1]
+    kept = kept.astype(z.dtype)
+    if row is None:
+        whole = jnp.concatenate([kept, z], axis=1)  # [N, k + T, D]: position p of it is token p - k
+        earlier = [whole[:, i:i + z.shape[1]] for i in range(k)]
+        pick = n_tokens[:, None] + jnp.arange(k)[None, :]  # the k before token n_tokens
+        return earlier, jnp.take_along_axis(whole, pick[:, :, None], axis=1)
+    flat, row, col = z[0], row[0], col[0]
+    earlier = []
+    for back in range(k, 0, -1):
+        # token col - back of the row's chunk: an earlier flat slot, or (col - back < 0) the
+        # kept value that many before the chunk's first token
+        shifted = jnp.pad(flat, ((back, 0), (0, 0)))[:flat.shape[0]]
+        from_kept = kept[row, jnp.clip(k + col - back, 0, k - 1)]
+        earlier.append(jnp.where((col >= back)[:, None], shifted, from_kept)[None])
+    ends = jnp.cumsum(n_tokens)
+    last = []
+    for back in range(k, 0, -1):  # the value `back` before the row's next token
+        in_chunk = flat[jnp.clip(ends - back, 0, flat.shape[0] - 1)]
+        from_kept = kept[jnp.arange(kept.shape[0]), jnp.clip(k + n_tokens - back, 0, k - 1)]
+        last.append(jnp.where((n_tokens >= back)[:, None], in_chunk, from_kept))
+    return earlier, jnp.stack(last, axis=1)
 
 
 # ----------------------------------------------------------------- losses

@@ -50,11 +50,18 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
 
 def route(wg, x, top_k: int, renormalise: bool, n_group: int = 1, topk_group: int = 1,
-          scaling: float = 1.0):
+          scaling: float = 1.0, scoring: str = "softmax", bias=None, norm_eps: float = 0.0):
     """Router of a top-k MoE layer, in float32: the logits accumulate in
     float32, the softmax runs over ALL experts, ``lax.top_k`` picks, and the
     picked probabilities are divided by their sum only where the checkpoint
     says so (Mixtral: yes; OLMoE ``norm_topk_prob: false``: no).
+
+    ``scoring="sigmoid"`` scores each expert by itself, ``sigmoid(logit)``
+    (LFM2, DeepSeek-V3's kind).  ``bias`` ``[E]`` is a selection bias: the
+    picks are the top-k of ``score + bias``, the weights the picked experts'
+    scores WITHOUT it (it balances loads, it never weighs).  ``norm_eps`` is
+    added to the picked scores' sum where they are renormalised.  Softmax, no
+    bias and no epsilon (every family before) trace as they did.
 
     ``n_group`` > 1 is the group-limited greedy choice (DeepSeek-V2): the
     experts lie in ``n_group`` equal runs (one a device of the deployment), a
@@ -66,15 +73,22 @@ def route(wg, x, top_k: int, renormalise: bool, n_group: int = 1, topk_group: in
     x [S, D] -> (weights [S, k] float32, experts [S, k] int32)."""
     with jax.named_scope("moe_route"):
         logits = jnp.dot(x, wg.astype(x.dtype), preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"route: scoring {scoring!r} is not implemented (softmax, sigmoid)")
+        probs = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
         if n_group > 1:
             by_group = probs.reshape(probs.shape[0], n_group, -1)
             _, best = jax.lax.top_k(jnp.max(by_group, axis=-1), topk_group)
             kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
             probs = jnp.where(kept[:, :, None], by_group, 0.0).reshape(probs.shape)
-        top_p, top_idx = jax.lax.top_k(probs, top_k)
+        if bias is None:
+            top_p, top_idx = jax.lax.top_k(probs, top_k)
+        else:
+            _, top_idx = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+            top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
         if renormalise:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            total = jnp.sum(top_p, axis=-1, keepdims=True)
+            top_p = top_p / (total + norm_eps if norm_eps else total)
         if scaling != 1.0:
             top_p = top_p * scaling
     return top_p, top_idx.astype(jnp.int32)
@@ -82,7 +96,8 @@ def route(wg, x, top_k: int, renormalise: bool, n_group: int = 1, topk_group: in
 
 def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
                    live: Optional[jax.Array] = None, layer: Optional[jax.Array] = None,
-                   n_group: int = 1, topk_group: int = 1, scaling: float = 1.0):
+                   n_group: int = 1, topk_group: int = 1, scaling: float = 1.0,
+                   scoring: str = "softmax", norm_eps: float = 0.0):
     """x [S, D] -> [S, D]: SwiGLU experts under top-k routing.
 
     ``moe_params``: ``{"gate": {"wg": [D, E]}, "experts": {"w_gate": [E, D, F],
@@ -112,8 +127,9 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
 
     ``moe_params["shared"]`` (``{"w_gate": [D, Fs], "w_up", "w_down"}``), where
     a family has it, is the expert every token takes: a dense SwiGLU added to
-    the routed part.  ``n_group``, ``topk_group`` and ``scaling`` are
-    :func:`route`'s."""
+    the routed part.  ``n_group``, ``topk_group``, ``scaling``, ``scoring`` and
+    ``norm_eps`` are :func:`route`'s, and so is ``moe_params["gate"]["bias"]``
+    (``[E]``), the selection bias of a family that stores one."""
     ex = moe_params["experts"]
     if layer is None:
         ex, layer = jax.tree_util.tree_map(lambda w: w[None], ex), 0
@@ -126,7 +142,9 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     # what tests/chipbench/test_reference_olmoe.py's stand-in for it accepts
     # (a benchmark test: not this module's to edit); the program is the same
     grouped = () if (n_group, scaling) == (1, 1.0) else (n_group, topk_group, scaling)
-    weights, experts = route(moe_params["gate"]["wg"], x, top_k, renormalise, *grouped)
+    scored = {} if scoring == "softmax" and "bias" not in moe_params["gate"] else {
+        "scoring": scoring, "bias": moe_params["gate"].get("bias"), "norm_eps": norm_eps}
+    weights, experts = route(moe_params["gate"]["wg"], x, top_k, renormalise, *grouped, **scored)
     with jax.named_scope("moe_expert_ffn"):
         group = layer * num_experts + experts
         if num_experts < moe_params["gate"]["wg"].shape[-1]:  # a pick on an expert held elsewhere

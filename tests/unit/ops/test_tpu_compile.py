@@ -332,6 +332,19 @@ def deepseek_v2_shapes(chip, layers):
             on_chip(jax.eval_shape(lambda: deepseek_v2.init_paged_cache(cfg, 1024, 128))))
 
 
+def lfm2_shapes(chip, layers):
+    """LFM2-24B-A2B as ``serve.conv-chat-burst`` holds it (``layers`` = 10: both
+    dense conv layers and two periods of attention, conv, conv, conv; 64
+    experts): a pool of the two attention layers alone, two 64-wide KV heads a
+    row ``[2, 1024, 4, 128, 128]``, and the conv state ``[8, 33, 2, 2048]``."""
+    from deepspeed_tpu.models import lfm2
+    cfg = lfm2.Lfm2Config(num_layers=layers)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    return (lfm2, cfg,
+            on_chip(jax.eval_shape(lambda: lfm2.init_params(cfg, jax.random.PRNGKey(0)))),
+            on_chip(jax.eval_shape(lambda: lfm2.init_paged_cache(cfg, 1024, 128))))
+
+
 def mistral_module_and_shapes(chip, layers):
     from deepspeed_tpu.models import mistral
     return (mistral, ) + mistral_shapes(chip, layers)
@@ -346,13 +359,16 @@ IN_PLACE = {  # model, (n, t, live_token_bound), a burst's scan around it
     "olmoe-16kv-compacted": (olmoe_shapes, (32, 256, 256), False),
     "deepseek-v2-latent-decode": (deepseek_v2_shapes, (8, 1, 512), False),
     "deepseek-v2-latent-compacted": (deepseek_v2_shapes, (3, 512, 512), False),
+    "lfm2-packed-heads-decode": (lfm2_shapes, (32, 1, 512), False),
+    "lfm2-packed-heads-compacted": (lfm2_shapes, (32, 256, 256), False),
+    "lfm2-packed-heads-burst": (lfm2_shapes, (32, 1, None), True),
 }
 
 
 # layers (every layer of a stack is one scan body: the count sets the pool's
 # size alone) and layer scans of each model's program
 LAYERS_AND_SCANS = {mistral_module_and_shapes: (3, 1), olmoe_shapes: (2, 1),
-                    deepseek_v2_shapes: (5, 2)}
+                    deepseek_v2_shapes: (5, 2), lfm2_shapes: (10, 1)}
 
 
 @pytest.mark.parametrize("form", list(IN_PLACE))
@@ -369,7 +385,11 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
     and out: no ``copy``, ``dynamic-slice``, ``dynamic-update-slice`` or
     scatter of a layer or of the stack; each scan body calls the paged kernel
     once and the writer once; the program's temporaries are smaller than the
-    pool."""
+    pool.  LFM2 (ISSUE 33: two scans, of which the period's body alone holds an
+    attention layer; heads of 64 packed two a 128-wide row, so no relayout) is
+    held to the same, and its second cache with it: the conv layers' state,
+    carried beside the pool, is written by scatters in place and never copied,
+    sliced or updated whole."""
     shapes, (n, t, bound), in_a_burst = IN_PLACE[form]
     layers, scans = LAYERS_AND_SCANS[shapes]
     module, cfg, params, kv = shapes(chip, layers=layers)
@@ -387,7 +407,8 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
         (kv, _, _), toks = jax.lax.scan(body, (kv, tokens, start_pos), None, length=2)
         return toks, kv
 
-    ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, 8))]
+    state = kv.get("state") if isinstance(kv, dict) else None  # a row's slot: one more column
+    ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, 8 + (state is not None)))]
     compiled = jax.jit(burst if in_a_burst else fwd,
                        donate_argnums=(1, )).lower(params, kv, *ints).compile()
     text = compiled.as_text()
@@ -395,6 +416,9 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
     assert (calls["paged_attention"], calls["kv_write"]) == (scans, scans), calls
     results = pool_shaped_results(text, leaves[0].shape)
     assert [r[0] for r in results] == ["custom-call"] * scans, results  # the writer alone
+    if state is not None:
+        whole = pool_shaped_results(text, (1, ) + state.shape)  # the state whole, however folded
+        assert whole and {r[0] for r in whole} <= {"scatter", "fusion"}, whole
     pool_bytes = sum(int(np.prod(leaf.shape)) * 2 for leaf in leaves)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
