@@ -23,7 +23,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from ...compat import shard_map
-from ...models.transformer import flat_slots
+from ...models.transformer import flat_slots, paged_step_slots
 from ...monitor.perf import PHASES, CompileLedger, StepPhaseProfiler
 from ...monitor.tracing import RequestTracer
 from ...parallel.mesh import TENSOR_AXIS, MeshTopology
@@ -278,12 +278,14 @@ class InferenceEngineV2:
         # it is the oracle the compacted program is compared with.
         self._live_token_bound: Optional[int] = (
             token_budget if self.fastpath.enabled else None)
+        kernel_slots = paged_step_slots(model_module, model_config, kv, self.dtype, self.tp)
         if hasattr(model_module, "moe_expert_rows"):  # a mixture of experts counts its rows
             self.counters = ServeCounters(
                 moe_picks=model_module.moe_picks_per_token(model_config),
-                moe_rows=functools.partial(model_module.moe_expert_rows, model_config))
+                moe_rows=functools.partial(model_module.moe_expert_rows, model_config),
+                kernel_slots=kernel_slots)
         else:
-            self.counters = ServeCounters()
+            self.counters = ServeCounters(kernel_slots=kernel_slots)
         # serving performance observatory (ISSUE 16): the compile ledger is
         # always on (no clock reads, no device work) and is the single source
         # of truth behind counters.compiles; the slot counters (ISSUE 24) are

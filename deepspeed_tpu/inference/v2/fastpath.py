@@ -84,6 +84,9 @@ class ServeCounters:
     ``table_slots``  block-table entries the paged kernel's grid walks (n x b
     a forward pass)
     ``live_blocks``  of those, the entries that name a sequence's own block
+    ``kernel_steps``  steps of the kernel's grid along the table (ISSUE 35: a
+    step takes ``kernel_slots(t)`` table slots at once, so n x ceil(b / slots) a
+    forward pass; ``table_slots / kernel_steps`` is the slots a step walked)
     ``compact_passes``  forward passes that ran compacted: how often the
     bucket held more slots than the step's live-token bound
 
@@ -100,12 +103,13 @@ class ServeCounters:
               "loop_iterations", "step_tokens", "burst_tokens", "flushes",
               "spec_rounds", "spec_proposed", "spec_accepted",
               "token_slots", "live_tokens", "table_slots", "live_blocks",
-              "compact_passes", "moe_routed_rows", "moe_expert_rows")
+              "compact_passes", "moe_routed_rows", "moe_expert_rows", "kernel_steps")
 
-    def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None):
+    def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None,
+                 kernel_slots: Callable[[int], int] = lambda t: 1):
         for f in self.FIELDS:
             setattr(self, f, 0)
-        self.moe_picks, self.moe_rows = moe_picks, moe_rows
+        self.moe_picks, self.moe_rows, self.kernel_slots = moe_picks, moe_rows, kernel_slots
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
                     live_blocks: int, passes: int = 1,
@@ -124,6 +128,7 @@ class ServeCounters:
             self.moe_expert_rows += self.moe_rows(slots) * passes
             self.moe_routed_rows += live_tokens * self.moe_picks
         self.table_slots += n * b * passes
+        self.kernel_steps += n * -(-b // self.kernel_slots(t)) * passes
         self.live_blocks += live_blocks * passes
         self.compact_passes += passes if flat is not None else 0
 

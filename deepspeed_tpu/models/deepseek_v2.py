@@ -233,6 +233,12 @@ def moe_picks_per_token(config: DeepseekV2Config) -> int:
     return config.top_k * (config.num_layers - config.first_k_dense)
 
 
+def paged_value_dim(config: DeepseekV2Config) -> int:
+    """The value's width inside the one cached vector (``paged_forward``'s
+    ``value_dim``; ``transformer.paged_step_slots`` reads it for the counters)."""
+    return config.kv_lora_rank
+
+
 def moe_expert_rows(config: DeepseekV2Config, slots: int) -> int:
     """Rows the expert layers' grouped matmuls of one pass over ``slots`` token
     slots run over (picks on experts held elsewhere are among them, dead)."""

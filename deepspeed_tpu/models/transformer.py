@@ -553,11 +553,13 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     Attention runs in the Pallas paged kernel (ops/attention/paged.py) on TPU:
     only live blocks are read via scalar-prefetched table indices; off-TPU the
     identical-math dense-gather fallback runs.  One grid step of it holds a
-    (sequence, table slot): the block fetched once for all its KV heads, every
-    q head that reads them stacked into the rows of one product, and no
-    arithmetic for the rows of the padded ``[N, T]`` that hold no token.  How
-    many KV heads a step holds the kernel decides from the shapes it is handed
-    here against one VMEM budget (``paged.step_tile``); nothing is passed for it."""
+    (sequence, a few consecutive table slots): their blocks fetched once for all
+    the step's KV heads, every q head that reads them stacked into the rows of
+    one product over all the step's keys, and no arithmetic for the rows of the
+    padded ``[N, T]`` that hold no token.  How many KV heads and table slots a
+    step holds the kernel decides from the shapes it is handed here against one
+    VMEM budget (``paged.step_tile``); nothing is passed for it
+    (:func:`paged_step_slots` works the same choice out for the engine's counters)."""
     from ..ops.attention.kv_write import kv_write, write_plan
     from ..ops.attention.paged import paged_attention
 
@@ -671,6 +673,29 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     if state is not None:
         cache[STATE] = pools[-1].reshape(state.shape)
     return to_padded(head(x)), cache
+
+
+def paged_step_slots(module, config, kv_cache, q_dtype, tp: int = 1) -> Callable[[int], int]:
+    """``t -> slots``: the table slots one grid step of the paged kernel takes in
+    a ``[n, t]`` program of this family (``paged.step_tile`` over the shapes
+    :func:`paged_forward` hands the kernel: the family's ``num_heads`` and its
+    pool's KV heads, a ``tp``-th of each (KV heads that ``tp`` does not divide
+    are held whole by every shard: ``inference/v2/tp.py kv_pool_spec``), the
+    pool's block and width, and, where the module states a ``paged_value_dim``,
+    the value's width inside the key).
+    ``ServeCounters.kernel_steps`` counts the kernel's grid with it."""
+    from ..ops.attention.paged import step_tile
+    pool = jax.tree_util.tree_leaves({k: v for k, v in kv_cache.items() if k != STATE})[0]
+    (_, _, kvh, bs, width), pool_dtype = pool.shape, pool.dtype  # the array itself is not kept
+    value_dim = getattr(module, "paged_value_dim", lambda config: None)(config)
+    local_kvh = kvh // tp if kvh % tp == 0 else kvh
+
+    @functools.lru_cache(maxsize=None)
+    def slots(t: int) -> int:
+        return step_tile(t, config.num_heads // tp, local_kvh, width, bs, q_dtype, pool_dtype,
+                         value_dim)[-1]
+
+    return slots
 
 
 def sequence_taps(z, kept, n_tokens, row, col):

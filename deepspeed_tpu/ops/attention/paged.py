@@ -5,9 +5,10 @@ the reference's blocked flash kernels, inference/v2/kernels/ragged_ops/
 blocked_flash + linear_blocked_kv_rotary): instead of gathering every
 sequence's whole block table into a dense [N, MAXB*bs, KV, Dh] context (HBM
 traffic O(MAXB) regardless of actual length), the kernel walks each sequence's
-block table with **scalar-prefetched indices** — the block index feeds the KV
-BlockSpec index_map, so only blocks below the sequence's live length are ever
-read, with online-softmax accumulation across blocks.
+block table with **scalar-prefetched indices** — the block index names the
+source of a copy out of the pool, which stays in HBM, so only blocks below the
+sequence's live length are ever read, with online-softmax accumulation across
+the steps of the walk.
 
 Layout: q [N, T, H, Dh] (T = SplitFuse chunk, 1 at decode); KV pool
 [NB, KV, bs, Dh] (one layer's pool — heads-major so the (bs, Dh) tile is the
@@ -21,32 +22,53 @@ hands it every layer's pool as one [L*NB, KV, bs, Dh] and tables offset by
 ``l*NB`` (a slice ``pool[l]`` handed to a Pallas call would be a copy of it).
 
 **What one grid step holds.**  The grid is (sequence, group of KV heads, row
-split, table slot), the slot innermost: one step per (sequence, slot) wherever
-a step can hold every KV head.  A step takes the block ``tables[n, b]`` ONCE
-for ``kvg`` KV heads — a [kvg, bs, Dh] tile of K and one of V, contiguous in
-the pool, 256 KiB each for 8 heads of 128 x 128 bf16 — and with it ALL the q
-heads that read those KV heads: q goes in as [N, KV, T * group, Dh], the
-``group = H // KV`` q heads of a KV head stacked into the rows of one product
-(row = token * group + head within the group, a token's group adjacent), so a
-decode step multiplies ``group`` live rows a KV head instead of one a q head
-and a chunk [group * T, Dh] x [Dh, bs]; the step's KV heads go through each
-product together, as its batch dimension ([kvg, rows, Dh] x [kvg, bs, Dh]:
-the body is traced once whatever the head count, and the compiler overlaps
-the heads' products and softmaxes).  The accumulator, ``m`` and ``l`` are
-per (KV head, row) and live in VMEM across a sequence's slots.  Operands are
-the pool's dtype (bf16 on the chip) with f32 accumulation, the probabilities
-cast to it for p . v as ``models.transformer.sdpa`` does; softmax state stays
-f32.  Per-row facts follow the rows: positions and the ``n_tokens`` mask are
-those of token ``row // group``, the ALiBi slope that of q head
-``kv * group + row % group``.
+split, step along the table), the table innermost: one step per (sequence,
+``slots`` consecutive table slots) wherever a step can hold every KV head.  A
+step takes the blocks ``tables[n, b * slots : (b + 1) * slots]`` ONCE for
+``kvg`` KV heads — a [kvg, slots * bs, Dh] tile of K and one of V, each block
+a contiguous [kvg, bs, Dh] of the pool, 256 KiB for 8 heads of 128 x 128 bf16 —
+and with them ALL the q heads that read those KV heads: q goes in as [N, KV,
+T * group, Dh], the ``group = H // KV`` q heads of a KV head stacked into the
+rows of one product (row = token * group + head within the group, a token's
+group adjacent), so a decode step multiplies ``group`` live rows a KV head
+instead of one a q head and a chunk [group * T, Dh] x [Dh, slots * bs]; the
+step's KV heads go through each product together, as its batch dimension
+([kvg, rows, Dh] x [kvg, slots * bs, Dh]: the body is traced once whatever the
+head count and whatever ``slots``, and the compiler overlaps the heads'
+products and softmaxes).  The scores of all the step's keys are that one
+product and the online softmax (``m``, ``l``, ``acc * corr``, ``p . v``) is
+updated once a step, not once a block: one wait for the blocks, one pass over
+the accumulator and one step's overhead for ``slots`` blocks.  The accumulator,
+``m`` and ``l`` are per (KV head, row) and live in VMEM across a sequence's
+steps.  Operands are the pool's dtype (bf16 on the chip) with f32 accumulation,
+the probabilities cast to it for p . v as ``models.transformer.sdpa`` does;
+softmax state stays f32.  Per-row facts follow the rows: positions and the
+``n_tokens`` mask are those of token ``row // group``, the ALiBi slope that of
+q head ``kv * group + row % group``.
+
+**The kernel fetches its own blocks.**  The pools are handed over where they
+lie (``memory_space=pl.ANY``) and a step's live blocks are copied by one loop
+of ``make_async_copy`` into one half of a [2, kvg, slots * bs, Dh] tile a pool:
+the loop is traced once whatever ``slots`` (a BlockSpec a slot cost more to
+trace and lower than this whole body, in every one of a cell's 38-70 programs),
+no copy joins the blocks, and a slot past the sequence's last live block is a
+shorter loop, not a fetch.  The copies run one live step ahead of the
+arithmetic, across row splits, KV-head groups and sequences (the grid runs in
+order: every axis is "arbitrary"): a live step starts the next live step's
+copies into the other half, then waits for its own.  Which step is next is
+read from a plan made once a call outside the kernel (``_fetch_plan``: live
+blocks and live row splits a sequence), so a grid step works nothing out.  A
+sequence behind a row of the bucket that holds no token starts its own copies;
+such a row's blocks are never fetched.  Slots never fetched hold zeros (the
+tiles are zeroed once a call: 0 x NaN would be NaN in p . v) and their keys
+lie past ``lengths``, which the mask already leaves out.
 
 **Rows that hold no token do no arithmetic.**  The live rows of a sequence are
-a prefix of its rows (``n_tokens * group``), so products, the state's
-initialisation and the final division run over the row tiles under that bound
-(tiles of ROW_TILE; one tile of SMALL_ROWS for a decode row riding in a chunk's
-bucket) and the other rows are written as zeros.  A table slot past the
-sequence's last live block is still a grid step, but names the last live block
-again in its index map, so it fetches nothing and computes nothing.
+a prefix of its rows (``n_tokens * group``), so products and the final division
+run over the row tiles under that bound (tiles of ROW_TILE; one tile of
+SMALL_ROWS for a decode row riding in a chunk's bucket) and the other rows are
+written as zeros.  A sequence's first step starts the softmax state instead of
+reading it, so nothing is initialised apart.
 
 **The tile is chosen from the static shapes** (``step_tile``: T, H, KV, Dh,
 bs and the two dtypes against one VMEM budget, ``VMEM_BUDGET_BYTES``): all KV
@@ -54,14 +76,17 @@ heads a step wherever q, out, the accumulators and the double-buffered K/V
 tiles fit (every decode and verify shape; Mistral's [n, 256] too), a divisor
 of them where they do not (T = 512 with 32 q heads), and only where one KV
 head's rows alone pass the budget (MQA with 64 heads over 512 tokens) are the
-rows cut into several steps, each fetching the block again.  No option, no
-model name, no caller's hint; a block too large for any step is a readable
-error, in the manner of ``check_block_table_fits``.
+rows cut into several steps, each fetching the blocks again.  Then the table
+slots a step takes, 4, 2 or 1: the most that still fit beside those heads and
+rows (``VMEM_SLOTS_BYTES``; never fewer KV heads for more slots).  The table's
+width need be no multiple of it: a slot past the table is a dead slot.  No
+option, no model name, no caller's hint; a block too large for any step is a
+readable error, in the manner of ``check_block_table_fits``.
 
 **A value that is a prefix of the key** (latent attention, MLA absorbed: the
 cached token is one vector ``[c_kv | k_pe]``, the scores run over all of it and
 the weighted sum over its first ``value_dim`` columns).  ``vpool=None`` says
-so: there is no second pool, the step's one tile is read once and its leading
+so: there is no second pool, the step's one tile is copied once and its leading
 columns are ``v``; the accumulator and the output are ``value_dim`` wide while
 q and the scores are the key's width (576 against 512 for DeepSeek-V2).  One
 KV head with the q group of every head stacked into the rows is then the whole
@@ -77,6 +102,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -123,6 +149,12 @@ def check_block_table_fits(n: int, maxb: int, n_vectors: int = 3) -> None:
 # has 128 MiB; the difference is left to the compiler's own temporaries.
 VMEM_BUDGET_BYTES = 40 << 20
 VMEM_LIMIT_BYTES = 64 << 20
+# What a step may hold by the same reckoning once its heads and rows are chosen
+# and only the table slots it takes at once are left to choose.  Measured on the
+# chip (PR 35): 8 KV heads of 1,024 rows with four slots reckon 42.0 MiB, compile
+# under the limit and run a long prompt's chunk call in 284 us where two slots
+# (32.0 MiB) take 355 and the one-slot kernel 390.
+VMEM_SLOTS_BYTES = 44 << 20
 
 # Rows of q one product takes inside a grid step: a tile of ROW_TILE, or the
 # one tile of SMALL_ROWS where a sequence has no more live rows than that (a
@@ -132,31 +164,43 @@ SMALL_ROWS = 16
 
 
 def _step_vmem_bytes(kvg: int, rows: int, tile: int, dh: int, bs: int,
-                     q_bytes: int, kv_bytes: int, dv: Optional[int] = None) -> int:
-    """VMEM of one grid step holding ``kvg`` KV heads and ``rows`` q rows a
-    head: q and out (double-buffered by the pipeline), the K and V tiles
-    (likewise), the f32 accumulator with ``m`` and ``l`` (a lane tile each a
-    row), and one row tile's scores, probabilities and masks for every head.
+                     q_bytes: int, kv_bytes: int, dv: Optional[int] = None,
+                     slots: int = 1) -> int:
+    """VMEM of one grid step holding ``kvg`` KV heads, ``rows`` q rows a head
+    and the blocks of ``slots`` table slots: q and out (double-buffered by the
+    pipeline), the K and V tiles of ``slots`` blocks each (two of each: the
+    next step's are fetched while this one computes), the f32 accumulator with
+    ``m`` and ``l`` (a lane tile each a row), and one row tile's scores,
+    probabilities and masks over the step's ``slots * bs`` keys for every head.
     ``dv``: the value is the key tile's first ``dv`` columns (no V tile; out
     and the accumulator are that wide); None where V is a pool of its own."""
     lanes = _round_up(dh, 128)
     out_lanes = lanes if dv is None else _round_up(dv, 128)
     q_and_out = 2 * kvg * rows * (lanes + out_lanes) * q_bytes
-    k_and_v = (2 if dv is None else 1) * 2 * kvg * _round_up(bs, 16) * lanes * kv_bytes
+    k_and_v = (2 if dv is None else 1) * 2 * kvg * slots * _round_up(bs, 16) * lanes * kv_bytes
     state = kvg * rows * (out_lanes + 2 * 128) * 4
-    work = kvg * tile * (4 * _round_up(bs, 128) + lanes + out_lanes) * 4
+    work = kvg * tile * (4 * _round_up(slots * bs, 128) + lanes + out_lanes) * 4
     return q_and_out + k_and_v + state + work
+
+
+# Table slots one grid step may take, most first (the engine's table widths are
+# multiples of 4: ``engine_v2.TABLE_STEP``).
+STEP_SLOTS = (4, 2, 1)
 
 
 def step_tile(t: int, hq: int, kvh: int, dh: int, bs: int, q_dtype, pool_dtype,
               dv: Optional[int] = None):
     """What one grid step holds, from the static shapes alone: ``(kvg, rows,
-    splits, tile)``.  ``kvg`` KV heads (a divisor of ``kvh``) with all their q
-    heads, ``rows`` q rows a KV head (``t * hq // kvh`` live at most, padded to
-    whole row tiles of ``tile``) and, only where one KV head's rows do not fit,
-    the rows cut into ``splits`` grid steps (K and V are then fetched once a
-    split).  The largest step under ``VMEM_BUDGET_BYTES`` wins: all KV heads
-    for every decode and verify shape, fewer for a wide chunk of many heads.
+    splits, tile, slots)``.  ``kvg`` KV heads (a divisor of ``kvh``) with all
+    their q heads, ``rows`` q rows a KV head (``t * hq // kvh`` live at most,
+    padded to whole row tiles of ``tile``) and, only where one KV head's rows
+    do not fit, the rows cut into ``splits`` grid steps (K and V are then
+    fetched once a split).  The largest step under ``VMEM_BUDGET_BYTES`` wins:
+    all KV heads for every decode and verify shape, fewer for a wide chunk of
+    many heads.  Then ``slots``, the consecutive table slots whose blocks the
+    step takes at once: the most of ``STEP_SLOTS`` that still fit beside those
+    heads and rows under ``VMEM_SLOTS_BYTES`` (never fewer KV heads a step for
+    more slots).
     ``dv`` is the value's width where it is a prefix of the key (``vpool=None``
     in :func:`paged_attention`): one tile a block, out and the accumulator
     ``dv`` wide.  Its one KV head makes q's rows a reshape, so padding them
@@ -164,8 +208,18 @@ def step_tile(t: int, hq: int, kvh: int, dh: int, bs: int, q_dtype, pool_dtype,
     is taken where it costs at most a third more steps than the first that fits
     (128 heads x 512 tokens: 16 parts of 32 tokens against 13 padded ones).
     With K and V pools q is transposed anyway and the first fit stands."""
-    group = hq // kvh
     q_bytes, kv_bytes = jnp.dtype(q_dtype).itemsize, jnp.dtype(pool_dtype).itemsize
+    kvg, rows, splits, tile = _heads_and_rows(t, hq, kvh, dh, bs, q_bytes, pool_dtype, dv)
+    slots = next(s for s in STEP_SLOTS if s == 1 or _step_vmem_bytes(
+        kvg, rows, tile, dh, bs, q_bytes, kv_bytes, dv, s) <= VMEM_SLOTS_BYTES)
+    return kvg, rows, splits, tile, slots
+
+
+def _heads_and_rows(t: int, hq: int, kvh: int, dh: int, bs: int, q_bytes: int, pool_dtype,
+                    dv: Optional[int]):
+    """``step_tile``'s KV heads and rows, reckoned at one table slot a step."""
+    group = hq // kvh
+    kv_bytes = jnp.dtype(pool_dtype).itemsize
     rows = _round_up(t * group, SMALL_ROWS)
     tile = min(rows, ROW_TILE)
     rows = _round_up(rows, tile)
@@ -193,56 +247,122 @@ def step_tile(t: int, hq: int, kvh: int, dh: int, bs: int, q_dtype, pool_dtype,
         f"size (block_size x head_dim must stay under ~{VMEM_BUDGET_BYTES // (8 * kv_bytes)}).")
 
 
-def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, *rest,
-                  scale, block_size, group, kvg, tile, window, alibi, value_dim):
+def _fetch_plan(lengths, n_tokens, bs: int, maxb: int, group: int, rows: int, splits: int):
+    """[1 or 2, N + 1] int32 (``lengths`` and ``n_tokens`` come as int32): what
+    the kernel's fetch asks of a sequence, worked out once a call and not once
+    a grid step: row 0 the table slots that name
+    a live block of a sequence that holds a token (0 for a row of the bucket
+    that holds none: its blocks are never fetched), and, only where a KV head's
+    rows are cut into ``splits`` grid steps, row 1 the splits that hold a
+    token.  Column N is the sequence past the last: no blocks, no fetch."""
+    i32 = np.int32  # numpy scalars are literals of the trace: no equation, no jnp wrapper
+    blocks = lax.min(lax.div(lax.add(lengths, i32(bs - 1)), i32(bs)), i32(maxb))
+    plan = [lax.select(lax.gt(n_tokens, i32(0)), blocks, lax.full_like(blocks, 0))]
+    if splits > 1:
+        plan.append(lax.min(lax.div(lax.add(lax.mul(n_tokens, i32(group)), i32(rows - 1)), i32(rows)),
+                            i32(splits)))
+    plan = lax.concatenate([lax.expand_dims(row, (0, )) for row in plan], 0)
+    return lax.pad(plan, i32(0), ((0, 0, 0), (0, 1, 0)))
+
+
+def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
+                  scale, block_size, group, kvg, tile, slots, head_steps, splits, window, alibi,
+                  value_dim):
+    # Every program traces and lowers this body once, and a cell meets 38-70
+    # programs: scalars and equal shapes go through ``lax`` (a jnp operator
+    # costs five times as much to trace), and nothing here loops over ``slots``.
     if alibi:
         slopes_ref, *rest = rest
     if value_dim is None:
-        q_ref, k_ref, v_ref, o_ref, acc, m_sc, l_sc = rest
+        q_ref, k_hbm, v_hbm, o_ref, acc, m_sc, l_sc, k_buf, v_buf, sems, turn = rest
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
     else:  # the value is the key tile's leading columns: one tile a block
-        q_ref, k_ref, o_ref, acc, m_sc, l_sc = rest
+        q_ref, k_hbm, o_ref, acc, m_sc, l_sc, k_buf, sems, turn = rest
+        pools = ((k_hbm, k_buf), )
     n, g, r, b = (pl.program_id(i) for i in range(4))
-    nb = pl.num_programs(3)
+    last = lax.eq(b, pl.num_programs(3) - 1)
     rows = acc.shape[1]
+    keys = slots * block_size
     length, start, ntok = lengths_ref[n], start_ref[n], ntok_ref[n]
-    first_row = r * rows
+    first_row = lax.mul(r, rows)
     # row = token * group + (q head within the KV head's group): the rows that
     # hold a token are a prefix, so leaving the others out is a loop bound
-    live = jnp.clip(ntok * group - first_row, 0, rows)
+    live = lax.max(lax.min(lax.sub(lax.mul(ntok, group), first_row), rows), 0)
 
-    def each_live_tile(when, fn):
-        """``fn(r0, size)`` for every row tile that holds a token, all local KV
-        heads at once: tiles of ``tile``, or the one of SMALL_ROWS where no more
-        rows are live (a decode row in a chunk's bucket does a decode row's work)."""
-        if rows <= SMALL_ROWS:
-            pl.when(when & (live > 0))(lambda: fn(0, rows))
-            return
-        pl.when(when & (live > 0) & (live <= SMALL_ROWS))(lambda: fn(0, SMALL_ROWS))
+    def each_block(i, gi, step, half, act):
+        """``act`` on the copy of every live block among the ``slots`` table
+        slots of sequence ``i``'s step ``step`` (KV heads ``gi``) into ``half``
+        of the tiles: one loop whatever ``slots``, shorter at a sequence's end
+        (a dead slot is no fetch), empty for a sequence that has no token."""
+        first = lax.mul(step, slots)
+        heads = pl.ds(lax.mul(gi, kvg), kvg)
 
-        @pl.when(when & (live > SMALL_ROWS))
-        def _tiles():
-            jax.lax.fori_loop(0, pl.cdiv(live, tile),
-                              lambda i, _: fn(pl.multiple_of(i * tile, tile), tile), None)
+        def one(j, _):
+            at = pl.ds(pl.multiple_of(lax.mul(lax.sub(j, first), block_size), block_size),
+                       block_size)
+            blk = tables_ref[i, j]
+            for p, (hbm, tiles) in enumerate(pools):
+                act(pltpu.make_async_copy(hbm.at[blk, heads], tiles.at[half, :, at, :],
+                                          sems.at[half, p]))
 
-    def init(r0, size):
-        at = pl.ds(r0, size)
-        acc[:, at, :] = jnp.zeros((kvg, size, acc.shape[2]), jnp.float32)
-        m_sc[:, at, :] = jnp.full((kvg, size, m_sc.shape[2]), NEG_INF, jnp.float32)
-        l_sc[:, at, :] = jnp.zeros((kvg, size, l_sc.shape[2]), jnp.float32)
+        lax.fori_loop(first, lax.min(lax.add(first, slots), plan_ref[0, i]), one, None)
+
+    @pl.when(lax.eq(lax.add(lax.add(n, g), lax.add(r, b)), 0))  # the grid's first step
+    def _first():  # a slot never fetched holds zeros, not what VMEM held (0 x NaN is NaN in p . v)
+        for _, tiles in pools:
+            tiles[...] = lax.full(tiles.shape, 0, tiles.dtype)
+        turn[0] = turn[1] = 0
+
+    # The fetch runs one live step ahead of the arithmetic, into the other half
+    # of the tiles: ``turn`` = (the half this step's blocks are in, whether the
+    # live step before this one started their copies).  The grid runs in order
+    # (every axis "arbitrary"), so the step before may be another sequence's.
+    half, fetched = turn[0], turn[1]
+    blocks = plan_ref[0, n]
+    step_live = lax.lt(lax.mul(b, slots), blocks)
+    if splits > 1:
+        step_live = lax.bitwise_and(step_live, lax.lt(r, plan_ref[1, n]))
+
+    @pl.when(step_live)
+    def _fetch():
+        # the next step in order: this sequence's next slots, row split or KV
+        # heads, else the sequence after it (no fetch if that one has no token)
+        b1, g1 = lax.add(b, 1), lax.add(g, 1)
+        more = lax.lt(lax.mul(b1, slots), blocks)
+        stay, g2 = more, g
+        if splits > 1:
+            stay = lax.bitwise_or(stay, lax.lt(lax.add(r, 1), plan_ref[1, n]))
+        if head_steps > 1:
+            g2 = lax.select(stay, g, lax.select(lax.lt(g1, head_steps), g1, 0))
+            stay = lax.bitwise_or(stay, lax.lt(g1, head_steps))
+        n1, other = lax.add(n, 1), lax.sub(1, half)
+        each_block(lax.select(stay, n, n1), g2, lax.select(more, b1, 0), other,
+                   lambda c: c.start())
+
+        def arrive(c):  # the kernel's first live step, and one behind a row with no token
+            pl.when(lax.eq(fetched, 0))(c.start)
+            c.wait()
+
+        each_block(n, g, b, half, arrive)
+        turn[0] = other
+        turn[1] = lax.convert_element_type(
+            lax.bitwise_or(stay, lax.gt(plan_ref[0, n1], 0)), jnp.int32)
 
     def attend(r0, size):
-        """Rows [r0, r0 + size) of every local KV head against this block: one
-        batched product over the heads, [kvg, size, Dh] x [kvg, bs, Dh]."""
+        """Rows [r0, r0 + size) of every local KV head against the step's
+        blocks: one batched product over the heads and over all the step's
+        keys, [kvg, size, Dh] x [kvg, slots * bs, Dh], and one softmax update
+        (the first step's starts the state: nothing is read of what VMEM held)."""
         at = pl.ds(r0, size)
-        k = k_ref[0]  # [kvg, bs, Dh], the pool's dtype
-        v = v_ref[0] if value_dim is None else k[:, :, :value_dim]
-        s = jax.lax.dot_general(q_ref[0, :, at, :].astype(k.dtype), k,
-                                (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * scale  # [kvg, size, bs]
-        row = first_row + r0 + jax.lax.broadcasted_iota(jnp.int32, (1, size, 1), 1)
-        tok = row // group
-        qp = start + tok  # absolute query positions
-        kpos = b * block_size + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_size), 2)
+        k = k_buf[half]  # [kvg, slots * bs, Dh], the pool's dtype
+        v = v_buf[half] if value_dim is None else lax.slice_in_dim(k, 0, value_dim, axis=2)
+        s = lax.mul(lax.dot_general(lax.convert_element_type(q_ref[0, :, at, :], k.dtype), k,
+                                    (((2,), (2,)), ((0,), (0,))),
+                                    preferred_element_type=jnp.float32), scale)  # [kvg, size, keys]
+        row = lax.add(lax.broadcasted_iota(jnp.int32, (1, size, 1), 1), lax.add(first_row, r0))
+        tok = lax.div(row, group)
+        qp = lax.add(tok, start)  # absolute query positions
+        kpos = lax.add(lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2), lax.mul(b, keys))
         if alibi:
             # ALiBi key-only form: slope_h * absolute key index (softmax-
             # equivalent to the relative-distance form per query row —
@@ -255,35 +375,58 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, *rest,
                     slope = jnp.where(row - tok * group == j, slopes_ref[head + j], slope)
                 slopes.append(slope)
             s = s + jnp.concatenate(slopes, axis=0) * kpos.astype(jnp.float32)
-        # causal, inside the live context, and only for a row that holds a token
-        mask = kpos <= jnp.where(tok < ntok, jnp.minimum(qp, length - 1), -1)
+        # causal, inside the live context (a slot past the sequence's last live
+        # block was not fetched: its keys lie past ``length``), and only for a
+        # row that holds a token
+        seen = lax.select(lax.lt(tok, ntok), lax.min(qp, lax.sub(length, 1)), lax.full_like(qp, -1))
+        mask = lax.le(kpos, seen)  # [1, size, keys]
         if window is not None:
-            mask = jnp.logical_and(mask, kpos > qp - window)
-        s = jnp.where(mask, s, NEG_INF)
+            mask = lax.bitwise_and(mask, lax.gt(kpos, lax.sub(qp, window)))
+        mask = lax.broadcast_in_dim(mask, s.shape, (0, 1, 2))
+        s = lax.select(mask, s, lax.full_like(s, NEG_INF))
 
-        m_prev = m_sc[:, at, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_sc[:, at, 0:1] = l_sc[:, at, 0:1] * corr + jnp.sum(p, axis=2, keepdims=True)
+        column = (kvg, size, 1)  # a number a row
+        begun = lax.broadcast(lax.gt(b, 0), column)
+        m_prev = lax.select(begun, m_sc[:, at, 0:1], lax.full(column, NEG_INF, jnp.float32))
+        m_new = lax.max(m_prev, lax.expand_dims(lax.reduce_max(s, (2, )), (2, )))
+        p = lax.select(mask, lax.exp(lax.sub(s, m_new)), lax.full_like(s, 0.0))
+        corr = lax.exp(lax.sub(m_prev, m_new))  # 0 at the first step: exp(NEG_INF - m)
+        l_prev = lax.select(begun, l_sc[:, at, 0:1], lax.full(column, 0.0, jnp.float32))
+        l_sc[:, at, 0:1] = lax.add(lax.mul(l_prev, corr), lax.expand_dims(lax.reduce_sum(p, (2, )), (2, )))
         m_sc[:, at, 0:1] = m_new
-        acc[:, at, :] = acc[:, at, :] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+        pv = lax.dot_general(lax.convert_element_type(p, v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                             preferred_element_type=jnp.float32)
+        a_prev = acc[:, at, :]
+        acc[:, at, :] = lax.add(lax.select(lax.broadcast_in_dim(begun, a_prev.shape, (0, 1, 2)),
+                                           lax.mul(a_prev, corr), lax.full_like(a_prev, 0.0)), pv)
 
     def normalise(r0, size):
         at = pl.ds(r0, size)
         l = l_sc[:, at, 0:1]
-        o_ref[0, :, at, :] = (acc[:, at, :] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        o_ref[0, :, at, :] = lax.convert_element_type(
+            lax.div(acc[:, at, :], lax.select(lax.eq(l, 0.0), lax.full_like(l, 1.0), l)), o_ref.dtype)
 
-    each_live_tile(b == 0, init)
-    each_live_tile(b * block_size < length, attend)
-
-    @pl.when(b == nb - 1)
+    @pl.when(last)
     def _zero():  # rows that hold no token come back zero
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[...] = lax.full(o_ref.shape, 0, o_ref.dtype)
 
-    each_live_tile(b == nb - 1, normalise)
+    def row_tile(r0, size):
+        pl.when(step_live)(lambda: attend(r0, size))
+        pl.when(lax.bitwise_and(last, lax.gt(length, 0)))(lambda: normalise(r0, size))
+
+    # every row tile that holds a token, all local KV heads at once: tiles of
+    # ``tile``, or the one of SMALL_ROWS where no more rows are live (a decode
+    # row in a chunk's bucket does a decode row's work)
+    if rows <= SMALL_ROWS:
+        pl.when(lax.gt(live, 0))(lambda: row_tile(0, rows))
+    else:
+        pl.when(lax.bitwise_and(lax.gt(live, 0), lax.le(live, SMALL_ROWS)))(
+            lambda: row_tile(0, SMALL_ROWS))
+
+        @pl.when(lax.gt(live, SMALL_ROWS))
+        def _tiles():
+            lax.fori_loop(0, pl.cdiv(live, tile),
+                          lambda i, _: row_tile(pl.multiple_of(lax.mul(i, tile), tile), tile), None)
 
 
 def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
@@ -312,42 +455,47 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
                                scale, window, alibi_slopes, value_dim)
 
     alibi = alibi_slopes is not None
-    check_block_table_fits(n, maxb, n_vectors=4 if alibi else 3)
+    check_block_table_fits(n, maxb, n_vectors=(4 if alibi else 3) + 2)  # and the plan's rows
     group = hq // kvh
-    kvg, rows, splits, tile = step_tile(t, hq, kvh, dh, bs, q.dtype, kpool.dtype, value_dim)
+    kvg, rows, splits, tile, slots = step_tile(t, hq, kvh, dh, bs, q.dtype, kpool.dtype,
+                                               value_dim)
     # [N, T, KV, group, Dh] -> [N, KV, T * group, Dh]: a KV head's q rows, a
     # token's group adjacent, padded with rows that hold no token
-    qr = q.reshape(n, t, kvh, group, dh).transpose(0, 2, 1, 3, 4).reshape(n, kvh, t * group, dh)
-    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, splits * rows - t * group), (0, 0)))
+    # (through ``lax`` like the body: every program traces and lowers this wrapper too)
+    qr = lax.reshape(lax.transpose(lax.reshape(q, (n, t, kvh, group, dh)), (0, 2, 1, 3, 4)),
+                     (n, kvh, t * group, dh))
+    padded = splits * rows - t * group
+    if padded:
+        qr = lax.pad(qr, np.zeros((), q.dtype), ((0, 0, 0), (0, 0, 0), (0, padded, 0), (0, 0, 0)))
 
     def q_block(ni, g, r, b, *refs):
         return ni, g, r, 0
 
-    def kv_block(ni, g, r, b, tables, lengths, *refs):
-        # a slot past the sequence's last live block names that block again: the
-        # pipeline fetches a block only when its index changes, so a dead slot
-        # is a grid step and no fetch
-        last = jnp.maximum(lengths[ni] - 1, 0) // bs
-        return tables[ni, jnp.minimum(b, last)], g, 0, 0
-
     kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs, group=group,
-                               kvg=kvg, tile=tile, window=window, alibi=alibi,
-                               value_dim=value_dim)
+                               kvg=kvg, tile=tile, slots=slots, head_steps=kvh // kvg,
+                               splits=splits, window=window, alibi=alibi, value_dim=value_dim)
     pools = (kpool, vpool) if value_dim is None else (kpool, )
+    # the pools stay where they are: the kernel copies a step's live blocks itself,
+    # ``slots`` of them into one tile a pool, the next step's while this one computes
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 if alibi else 4,
-        grid=(n, kvh // kvg, splits, maxb),
+        num_scalar_prefetch=6 if alibi else 5,
+        grid=(n, kvh // kvg, splits, pl.cdiv(maxb, slots)),
         in_specs=[pl.BlockSpec((1, kvg, rows, dh), q_block)]
-        + [pl.BlockSpec((1, kvg, bs, dh), kv_block) for _ in pools],
+        + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
         out_specs=pl.BlockSpec((1, kvg, rows, dv), q_block),
         scratch_shapes=[
             pltpu.VMEM((kvg, rows, dv), jnp.float32),
             pltpu.VMEM((kvg, rows, 128), jnp.float32),
             pltpu.VMEM((kvg, rows, 128), jnp.float32),
+            *(pltpu.VMEM((2, kvg, slots * bs, dh), pool.dtype) for pool in pools),
+            pltpu.SemaphoreType.DMA((2, len(pools))),
+            pltpu.SMEM((2, ), jnp.int32),
         ],
     )
-    scalars = [tables.astype(jnp.int32), lengths.astype(jnp.int32),
-               start_pos.astype(jnp.int32), n_tokens.astype(jnp.int32)]
+    tables, lengths, start_pos, n_tokens = (lax.convert_element_type(x, jnp.int32)
+                                            for x in (tables, lengths, start_pos, n_tokens))
+    scalars = [tables, lengths, start_pos, n_tokens,
+               _fetch_plan(lengths, n_tokens, bs, maxb, group, rows, splits)]
     if alibi:
         scalars.append(jnp.asarray(alibi_slopes, jnp.float32))
     out = pl.pallas_call(
@@ -355,13 +503,15 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qr.shape[:3] + (dv, ), q.dtype),
         compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", ) * 4,  # the fetch runs ahead across all four
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=_pallas.INTERPRET,
         name="paged_attention",
     )(*scalars, qr, *pools)
-    out = out[:, :, :t * group].reshape(n, kvh, t, group, dv)
-    return out.transpose(0, 2, 1, 3, 4).reshape(n, t, hq, dv)
+    if padded:
+        out = lax.slice_in_dim(out, 0, t * group, axis=2)
+    out = lax.transpose(lax.reshape(out, (n, kvh, t, group, dv)), (0, 2, 1, 3, 4))
+    return lax.reshape(out, (n, t, hq, dv))
 
 
 def _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale,

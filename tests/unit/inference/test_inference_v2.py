@@ -174,7 +174,7 @@ def test_paged_attention_parity_with_a_kv_heads_rows_cut_into_grid_steps(
     from deepspeed_tpu.ops.attention import paged
     case = _ragged_paged_case(8, 1, 128, jnp.float32)
     monkeypatch.setattr(paged, "VMEM_BUDGET_BYTES", 3 << 20)
-    kvg, rows, splits, tile = paged.step_tile(128, 8, 1, 32, 16, jnp.float32, jnp.float32)
+    kvg, rows, splits, tile, _ = paged.step_tile(128, 8, 1, 32, 16, jnp.float32, jnp.float32)
     assert (kvg, splits) == (1, 2) and rows % tile == 0 and splits * rows >= 128 * 8
     _assert_kernel_is_the_fallback(case, 16, 40, None, atol=2e-5)
 
@@ -207,7 +207,7 @@ def test_paged_attention_with_a_value_that_is_a_prefix_of_the_key(
     q, pool, tables, lengths, start_pos, n_tokens = _latent_paged_case(H, T, dk, jnp.dtype(dtype))
     if split:
         monkeypatch.setattr(paged, "VMEM_BUDGET_BYTES", 2 << 20)
-        kvg, rows, splits, tile = paged.step_tile(T, H, 1, dk, 16, q.dtype, pool.dtype, dv)
+        kvg, rows, splits, tile, _ = paged.step_tile(T, H, 1, dk, 16, q.dtype, pool.dtype, dv)
         assert kvg == 1 and splits > 1 and splits * rows == T * H  # equal parts: q is not padded
     ref = paged._dense_fallback(q, pool, None, tables, lengths, start_pos, n_tokens, 0.21, None,
                                 None, dv)

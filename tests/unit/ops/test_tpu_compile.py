@@ -98,6 +98,16 @@ def _cell_shapes():
     pytest.param(4, 512, 128, 12, None, 64, 8, id="chunk-T512-64q8kv"),
     pytest.param(4, 512, 128, 12, WINDOW, 64, 1, id="chunk-T512-64q1kv-rows-split"),
     pytest.param(32, 1, 128, 12, WINDOW, 8, 2, id="decode-tensor4-shard-8q2kv"),
+    # four table slots a step where the tile chooser reckons them over the budget that
+    # picks heads and rows and under VMEM_SLOTS_BYTES (41.0-43.5 MiB; Mistral's T = 256 below)
+    pytest.param(8, 128, 128, 12, None, 64, 8, id="slots4-T128-64q8kv"),
+    pytest.param(2, 1024, 128, 12, None, 16, 16, id="slots4-T1024-16q16kv"),
+    pytest.param(32, 1, 128, 12, None, 64, 64, id="slots4-decode-64q64kv"),
+    pytest.param(2, 1024, 128, 12, None, 12, 4, id="slots4-T1024-12q4kv"),
+    pytest.param(2, 512, 128, 12, None, 16, 16, id="slots2-T512-16q16kv-at-the-allowance"),
+    # a table that is no whole number of steps, and one narrower than a step
+    pytest.param(32, 1, 128, 3, WINDOW, H, KV, id="decode-table-3"),
+    pytest.param(8, 256, 128, 1, WINDOW, H, KV, id="chunk-table-1"),
     *_cell_shapes(),
 ])
 def test_paged_attention_compiles(chip, n, t, block, maxb, window, hq, kvh):
@@ -130,7 +140,7 @@ def test_paged_attention_over_a_latent_pool_compiles(chip, n, t):
     compiled = jax.jit(fn).lower(*avals).compile()
     assert kernel_calls(compiled.as_text()) == {"paged_attention": 1}
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)  # nothing padded or relaid
-    kvg, rows, splits, tile = step_tile(t, 128, 1, 640, 128, jnp.bfloat16, jnp.bfloat16, 512)
+    kvg, rows, splits, tile, _ = step_tile(t, 128, 1, 640, 128, jnp.bfloat16, jnp.bfloat16, 512)
     assert kvg == 1 and splits * rows == max(t * 128, rows)  # equal whole parts: q is not padded
 
 
@@ -143,9 +153,12 @@ def test_a_grid_steps_vector_memory_stays_under_the_budget(hq, kvh):
     from deepspeed_tpu.ops.attention import paged
     for t in (1, 5, 9, 128, 256, 512):
         for pool in (jnp.bfloat16, jnp.float32):
-            kvg, rows, splits, tile = paged.step_tile(t, hq, kvh, DH, 128, jnp.bfloat16, pool)
+            kvg, rows, splits, tile, slots = paged.step_tile(t, hq, kvh, DH, 128, jnp.bfloat16, pool)
             need = paged._step_vmem_bytes(kvg, rows, tile, DH, 128, 2, jnp.dtype(pool).itemsize)
-            assert need <= paged.VMEM_BUDGET_BYTES < paged.VMEM_LIMIT_BYTES
+            assert need <= paged.VMEM_BUDGET_BYTES < paged.VMEM_SLOTS_BYTES < paged.VMEM_LIMIT_BYTES
+            wide = paged._step_vmem_bytes(kvg, rows, tile, DH, 128, 2, jnp.dtype(pool).itemsize,
+                                          None, slots)
+            assert slots in paged.STEP_SLOTS and (slots == 1 or need < wide <= paged.VMEM_SLOTS_BYTES)
             assert kvh % kvg == 0 and rows % tile == 0 and splits * rows >= t * (hq // kvh)
             assert splits == 1 or kvg == 1
             if t <= 9:
