@@ -235,8 +235,16 @@ def compare_logits(tag, got, want, rel_tol, abs_tol):
     require(rel <= rel_tol and worst <= abs_tol, f"{tag}: logits disagree with the reference")
 
 
+def cache_events() -> dict:
+    """What the persistent compile cache has answered this process so far
+    (the set-up account's count, monitor/compile_events.py)."""
+    from deepspeed_tpu.monitor import compile_events
+    totals = compile_events.ACCOUNT.totals()
+    return {"cache_hits": totals["cache_hits"], "cache_misses": totals["cache_misses"]}
+
+
 # ------------------------------------------------------------------- one chip
-def serve_phase(sz: Sizes, seed: int, events) -> None:
+def serve_phase(sz: Sizes, seed: int) -> None:
     import jax
     from deepspeed_tpu.models import mistral
     dev = jax.devices()[0]
@@ -277,9 +285,12 @@ def serve_phase(sz: Sizes, seed: int, events) -> None:
         say("serve", run=label, wall_s=f"{wall:.2f}", per_request_s=f"{wall / len(prompts):.3f}",
             compiles=engine.ledger.total - before, peak=peak_gib(dev))
     require(engine.ledger.total == before, "the steady run compiled")
+    # compile_s is the ahead-of-time seam's stopwatch (the fwd programs alone);
+    # the stage seconds beside it are JAX's own, over every program the ledger recorded
     say("serve", compile_counter=engine.ledger.total, warm_recompiles=engine.ledger.warm_total,
         compile_s=f"{engine.ledger.compile_wall_s:.1f}",
-        **{f"cache_{k}": v for k, v in events.items()})
+        **{k: f"{v:.1f}" for k, v in engine.ledger.stage_totals().items() if k.endswith("_s")},
+        **cache_events())
 
     calls = serve_step_kernels(engine, sz)
     say("serve", programs=len(calls), decode_programs=sum(t == 1 for _, t, _ in calls),
@@ -355,13 +366,13 @@ def train_run(cfg, sz: Sizes, seed: int, topology, micro: int, tag: str, inspect
     return engine, losses, calls
 
 
-def train_phase(sz: Sizes, seed: int, events) -> None:
+def train_phase(sz: Sizes, seed: int) -> None:
     import jax
     from deepspeed_tpu.parallel import MeshTopology
     cfg = dataclasses.replace(mistral_config(sz, sz.train_layers), max_seq_len=sz.train_seq)
     topology = MeshTopology.from_axis_dict({}, devices=jax.devices()[:1])
     _, _, calls = train_run(cfg, sz, seed, topology, sz.train_micro, "train", inspect=True)
-    say("train", **{f"cache_{k}": v for k, v in events.items()})
+    say("train", **cache_events())
     if not sz.rehearsal:
         flash = sum(v for k, v in calls.items() if k.startswith("flash_attention"))
         require(flash >= 3, f"train step compiled without the flash kernels: {calls}")
@@ -459,6 +470,7 @@ def main() -> int:
     args = ap.parse_args()
 
     import jax
+    from deepspeed_tpu.monitor import compile_events
     from deepspeed_tpu.ops import _pallas
     from deepspeed_tpu.utils.compile_cache import place_compile_cache
     if args.rehearse:
@@ -467,15 +479,7 @@ def main() -> int:
         # an interpreted kernel is not the chip's kernel; nothing placed the
         # cache for a rehearsal either, whose programs no chip can load
         place_compile_cache(HERE)
-    events = {"hits": 0, "misses": 0}
-    names = {"/jax/compilation_cache/cache_hits": "hits",
-             "/jax/compilation_cache/cache_misses": "misses"}
-
-    def on_event(event, **_):
-        if event in names:
-            events[names[event]] += 1
-
-    jax.monitoring.register_event_listener(on_event)
+    compile_events.install()  # before the first program: the weights' draw counts too
 
     devices = jax.devices()
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
@@ -497,9 +501,9 @@ def main() -> int:
         four_chip_train(sz, args.seed)
         four_chip_serve(sz, args.seed)
     else:
-        serve_phase(sz, args.seed, events)
+        serve_phase(sz, args.seed)
         gc.collect()
-        train_phase(sz, args.seed, events)
+        train_phase(sz, args.seed)
     say("done", wall_s=f"{time.perf_counter() - t0:.0f}")
 
     if args.rehearse or _pallas.INTERPRET or device["platform"] != "tpu":

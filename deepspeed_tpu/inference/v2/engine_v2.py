@@ -24,6 +24,7 @@ from jax.sharding import PartitionSpec
 
 from ...compat import shard_map
 from ...models.transformer import flat_slots, paged_step_slots
+from ...monitor import compile_events
 from ...monitor.perf import PHASES, CompileLedger, StepPhaseProfiler
 from ...monitor.tracing import RequestTracer
 from ...parallel.mesh import TENSOR_AXIS, MeshTopology
@@ -88,6 +89,7 @@ class InferenceEngineV2:
     TABLE_STEP = 4
     TABLE_SHRINK_PATIENCE = 16
 
+    @compile_events.engine_init
     def __init__(self, model_module, model_config, params, config: Optional[Dict] = None,
                  num_blocks: int = 512, block_size: int = 16,
                  max_blocks_per_seq: int = 64, token_budget: int = 256,
@@ -292,9 +294,12 @@ class InferenceEngineV2:
         # host integers bumped where a program is launched, always on too;
         # the phase profiler reads the injectable clock at
         # phase boundaries and is gated on serving_perf.enabled so the off
-        # path performs zero extra clock reads (byte-identical FakeClock runs)
+        # path performs zero extra clock reads (byte-identical FakeClock runs).
+        # What each recorded program cost to trace, lower and load is the
+        # process's set-up account's (ISSUE 36), joined by program name
         self.perf_cfg = self.config.serving_perf
-        self.ledger = CompileLedger(self.counters, tracer=self.tracer)
+        self.ledger = CompileLedger(self.counters, tracer=self.tracer,
+                                    events=compile_events.ACCOUNT)
         self.phase_profiler = StepPhaseProfiler(self.perf_cfg, clock=self._clock,
                                                 tracer=self.tracer)
         self.batch_state = DeviceBatchState(
@@ -471,8 +476,6 @@ class InferenceEngineV2:
                 # AOT lowering can fail where plain jit works (backend
                 # quirks); serving must degrade to the lazy wrapper, not die
                 self._fwd_cache[key] = fwd = self._build_fwd_jit(n, t, b)
-                # lazy jit wrapper: XLA compiles at first dispatch, so the
-                # wall time shows up in the dispatch phase histogram instead
                 self.ledger.record("fwd", key, name=fwd.__name__)
         return self._fwd_cache[key]
 

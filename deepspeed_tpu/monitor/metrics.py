@@ -622,6 +622,27 @@ def populate_from_engine(reg: MetricsRegistry, engine) -> None:
                             help_text="warm recompiles: a bucket key rebuilt "
                                       "after being seen at its site (runtime "
                                       "twin of dslint's recompile-risk rule)")
+        # what the recorded programs cost before they could run (ISSUE 36):
+        # a replica that takes a minute to come up says which stage it spent
+        # it in, and whether the persistent cache was there for it
+        stages = ledger.stage_totals()
+        if stages:
+            for stage in ("trace", "lower", "load"):
+                reg.set_counter(f"{reg.namespace}_serving_compile_seconds_total",
+                                stages[stage + "_s"], labels={"stage": stage},
+                                help_text="seconds JAX spent tracing, lowering and "
+                                          "loading (an XLA compile on a persistent-"
+                                          "cache miss, a read on a hit) the programs "
+                                          "the compile ledger recorded")
+            reg.set_counter(f"{reg.namespace}_serving_compile_cache_hits_total",
+                            stages["cache_hits"],
+                            help_text="loads of recorded programs the persistent "
+                                      "compile cache answered")
+            reg.set_counter(f"{reg.namespace}_serving_compile_cache_misses_total",
+                            stages["cache_misses"],
+                            help_text="loads of recorded programs it did not answer "
+                                      "(no entry, no directory, or a program it does "
+                                      "not take): each was an XLA compile")
     # multi-tenant QoS (ISSUE 19): per-tenant admission, token, shed and
     # resident-KV families plus per-tenant SLO histograms — present only
     # when the policy layer is armed (serving_qos.enabled), so a QoS-off

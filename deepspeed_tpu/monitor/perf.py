@@ -179,12 +179,31 @@ class CompileLedger:
     runtime event dslint's ``recompile-risk`` rule predicts statically; it
     lands in the flight recorder and the per-site warm counters behind
     ``serving_recompiles_total{site=...}``.
+
+    ``compile_wall_s`` is the AOT seams' stopwatch and nothing else: trace +
+    lower + compile of the programs built ahead, 0 for a lazily jitted one.
+    What EVERY program cost, stage by stage, is the set-up account's
+    (``monitor/compile_events.py``, handed in as ``events=``; this module still
+    imports no jax): JAX's own trace / lower / load seconds under the program
+    names this ledger stores.  :meth:`snapshot` joins the two by name, so
+    ``health()["perf"]["compile_ledger"]`` answers for a replica that takes a
+    minute to come up: which programs (``slowest``), which stage (``trace_s``,
+    ``lower_s``, ``load_s``), cache or no cache (``cache_hits``: loads the
+    persistent cache answered; ``cache_misses``: the other loads, each an XLA
+    compile, whether the cache lacked the entry, takes no such program or has
+    no directory); ``serving_compile_seconds_total{stage=...}`` and
+    ``serving_compile_cache_{hits,misses}_total`` export the same totals.
+    The join is by name alone and the account is the process's: two engines
+    in one process that build programs of equal names (two replicas of one
+    model) each read the seconds of both.
     """
 
-    def __init__(self, counters=None, *, tracer=None):
+    def __init__(self, counters=None, *, tracer=None, events=None):
         self._counters = counters
         self._tracer = tracer
+        self._events = events  # the set-up account (by_program()), or None
         self._seen: Dict[Tuple[str, str], int] = {}
+        self._programs: Dict[str, Tuple[str, str]] = {}  # name -> (site, first class)
         self.by_site: Dict[str, Dict[str, int]] = {}
         self.warm_by_site: Dict[str, int] = {}
         self.compile_wall_s = 0.0
@@ -208,12 +227,17 @@ class CompileLedger:
             cls = CLASS_WARM
             self.warm_by_site[site] = self.warm_by_site.get(site, 0) + 1
             if self._tracer is not None:
+                # the seconds every build of this program has cost so far (a
+                # lazily jitted one compiles after this line: its newest
+                # build is not in them yet)
                 self._tracer.event("warm_recompile", site=site, key=k[1],
-                                   program=name, builds=seen + 1)
+                                   program=name, builds=seen + 1,
+                                   **self._stage_seconds([name]))
         else:
             cls = CLASS_PREWARMED if prewarmed else CLASS_COLD
         per_site = self.by_site.setdefault(site, {})
         per_site[cls] = per_site.get(cls, 0) + 1
+        self._programs.setdefault(name, (site, cls))
         self.compile_wall_s += float(wall_s)
         self.total += 1
         self.events.append({"site": site, "key": k[1], "name": name,
@@ -226,9 +250,35 @@ class CompileLedger:
     def warm_total(self) -> int:
         return sum(self.warm_by_site.values())
 
+    def _stage_seconds(self, names, by_program=None) -> Dict[str, Any]:
+        """The account's totals over ``names``; nothing without an account."""
+        if self._events is None:
+            return {}
+        by_program = self._events.by_program() if by_program is None else by_program
+        found = [by_program[n] for n in names if n in by_program]
+        return {key: round(sum(p[key] for p in found), 6) if key.endswith("_s")
+                else sum(p[key] for p in found)
+                for key in ("trace_s", "lower_s", "load_s", "cache_hits", "cache_misses")}
+
+    def stage_totals(self) -> Dict[str, Any]:
+        """``trace_s``, ``lower_s``, ``load_s``, ``cache_hits`` and
+        ``cache_misses`` of the programs this ledger recorded (the exporter's
+        and ``chip_smoke.py``'s numbers); empty without an account."""
+        return self._stage_seconds(self._programs)
+
     def snapshot(self) -> Dict[str, Any]:
-        return {"total": self.total,
+        snap = {"total": self.total,
                 "warm_total": self.warm_total,
                 "compile_wall_s": round(self.compile_wall_s, 6),
                 "by_site": {s: dict(c) for s, c in sorted(self.by_site.items())},
                 "recent": list(self.events)[-8:]}
+        if self._events is not None:
+            by_program = self._events.by_program()
+            snap.update(self._stage_seconds(self._programs, by_program))
+            cost = {n: by_program[n]["trace_s"] + by_program[n]["lower_s"] + by_program[n]["load_s"]
+                    for n in self._programs if n in by_program}
+            snap["slowest"] = [
+                {"name": n, "site": self._programs[n][0], "class": self._programs[n][1],
+                 "seconds": round(cost[n], 6), "inner_traces": by_program[n]["inner_traces"]}
+                for n in sorted(cost, key=lambda n: (-cost[n], n))[:8]]
+        return snap

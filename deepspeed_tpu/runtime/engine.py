@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..compat import shard_map
 from ..monitor.monitor import MonitorMaster
+from ..monitor import compile_events
 from ..monitor.telemetry import TelemetryCollector
 from ..parallel.mesh import MeshTopology, set_topology
 from ..utils.logging import log_dist, logger
@@ -92,6 +93,7 @@ class Engine:
     loss_fn(params, batch, rng) -> loss  (params arrive in compute dtype)
     """
 
+    @compile_events.engine_init
     def __init__(self,
                  loss_fn: Callable,
                  params: Any,
@@ -158,6 +160,7 @@ class Engine:
         self.telemetry = TelemetryCollector(config.telemetry, monitor=self.monitor,
                                             batch_size=self.train_batch_size)
         self._last_telemetry_record = None
+        self._setup_account_told = False  # the set-up account goes to telemetry once
         # per-rank liveness stamps for the elastic agent (runtime/heartbeat.py):
         # armed by the fault_tolerance config section OR the agent-exported
         # DSTPU_HEARTBEAT_DIR env; the NULL writer otherwise (no-op stamps)
@@ -446,7 +449,11 @@ class Engine:
         tree = jax.tree_util.tree_unflatten(self._offload_treedef, leaves)
         if self._offload_push_fn is None:
             shardings = self.plan.param_shardings(tree)
-            self._offload_push_fn = jax.jit(lambda p: p, out_shardings=shardings)
+
+            def push_compute_params(p):  # named: the set-up account's row is not "<lambda>"
+                return p
+
+            self._offload_push_fn = jax.jit(push_compute_params, out_shardings=shardings)
         self._compute_params = self._offload_push_fn(tree)
 
     def _offload_train_batch(self, batch):
@@ -799,6 +806,18 @@ class Engine:
         if self._ops is not None:
             self._ops.close()
 
+    def _tell_setup_account(self) -> None:
+        """Once, after this process's first step: what the programs built up
+        to here cost to trace, lower and compile or load (the process's
+        set-up account, ``monitor/compile_events.py``), as one gauge record
+        (``Train/Setup/trace_s`` ...).  Host floats the account already
+        holds: nothing is read from the device, and nothing on a later step."""
+        if self._setup_account_told:
+            return
+        self._setup_account_told = True
+        self.telemetry.record_gauges(compile_events.ACCOUNT.totals(), step=self.global_steps,
+                                     prefix="Train/Setup")
+
     def train_batch(self, batch):
         """Run one full optimizer step on a global macro-batch.
 
@@ -830,6 +849,7 @@ class Engine:
                     step=self.global_steps, samples=self.global_samples,
                     loss=loss, grad_norm=0.0, lr=lr, step_time_s=step_time,
                     tokens=self._batch_tokens(batch, seq_dim=1))
+            self._tell_setup_account()
             self._refresh_ops()
             self._watchdog_check(metrics, loss_val=loss)
             self._maybe_report(metrics)
@@ -907,6 +927,7 @@ class Engine:
             # memory_breakdown stands alone: the reference's top-level key must
             # snapshot even when per-step telemetry records are off
             see_memory_usage(f"after train step {self.global_steps}")
+        self._tell_setup_account()
         # ops-plane cache refresh (ISSUE 11): host-only, after the telemetry
         # record so a scrape sees THIS step; throttled; no-op when off
         self._refresh_ops()
@@ -1526,7 +1547,12 @@ class Engine:
         if self.offload_device is not None:
             return self._offload_host_state()["params"]
         rep = NamedSharding(self.topology.mesh, PartitionSpec())
-        gathered = jax.jit(lambda p: p, out_shardings=jax.tree_util.tree_map(lambda _: rep, self.state.params))(
+
+        def gather_fp32_params(p):
+            return p
+
+        gathered = jax.jit(gather_fp32_params,
+                           out_shardings=jax.tree_util.tree_map(lambda _: rep, self.state.params))(
             self.state.params)
         return jax.tree_util.tree_map(np.asarray, gathered)
 
@@ -1547,7 +1573,11 @@ class Engine:
         # cast BEFORE replicating: the gather then moves 2 bytes/param, not 4
         # (the reference gathers the bit16 copy for the same reason), which is
         # why this doesn't reuse checkpointing._gather_to_host (fp32 path)
-        gather16 = jax.jit(lambda x: x.astype(ct), out_shardings=rep)
+
+        def gather_16bit_leaf(x):
+            return x.astype(ct)
+
+        gather16 = jax.jit(gather_16bit_leaf, out_shardings=rep)
         rank0 = _is_rank0()
         out = {}
         for keypath, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
