@@ -280,14 +280,15 @@ class InferenceEngineV2:
         # it is the oracle the compacted program is compared with.
         self._live_token_bound: Optional[int] = (
             token_budget if self.fastpath.enabled else None)
-        kernel_slots = paged_step_slots(model_module, model_config, kv, self.dtype, self.tp)
+        kernel_slots, attn_slots = paged_step_slots(model_module, model_config, kv, self.dtype,
+                                                    self.tp)
         if hasattr(model_module, "moe_expert_rows"):  # a mixture of experts counts its rows
             self.counters = ServeCounters(
                 moe_picks=model_module.moe_picks_per_token(model_config),
                 moe_rows=functools.partial(model_module.moe_expert_rows, model_config),
-                kernel_slots=kernel_slots)
+                kernel_slots=kernel_slots, attn_slots=attn_slots)
         else:
-            self.counters = ServeCounters(kernel_slots=kernel_slots)
+            self.counters = ServeCounters(kernel_slots=kernel_slots, attn_slots=attn_slots)
         # serving performance observatory (ISSUE 16): the compile ledger is
         # always on (no clock reads, no device work) and is the single source
         # of truth behind counters.compiles; the slot counters (ISSUE 24) are

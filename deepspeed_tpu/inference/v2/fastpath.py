@@ -81,6 +81,12 @@ class ServeCounters:
     flat slots of a compacted one (ISSUE 25: a step of 31 decode rows and one
     225-token chunk is the bucket 32 x 256, computed over S = 256)
     ``live_tokens``  of those, the tokens that advanced a sequence
+    ``attn_token_slots``  token positions the ATTENTION layout of the launched
+    programs held (ISSUE 40): n x t a padded pass, as ``token_slots``; for a
+    compacted pass ``attn_slots(n, S)``, the positions of the paged kernel's
+    flat row axis, each sequence begun on a whole sublane tile of rows (n x t
+    until the kernel took q from the flat axis); ``live_tokens /
+    attn_token_slots`` is how full the kernel's q is
     ``table_slots``  block-table entries the paged kernel's grid walks (n x b
     a forward pass)
     ``live_blocks``  of those, the entries that name a sequence's own block
@@ -103,13 +109,16 @@ class ServeCounters:
               "loop_iterations", "step_tokens", "burst_tokens", "flushes",
               "spec_rounds", "spec_proposed", "spec_accepted",
               "token_slots", "live_tokens", "table_slots", "live_blocks",
-              "compact_passes", "moe_routed_rows", "moe_expert_rows", "kernel_steps")
+              "compact_passes", "moe_routed_rows", "moe_expert_rows", "kernel_steps",
+              "attn_token_slots")
 
     def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None,
-                 kernel_slots: Callable[[int], int] = lambda t: 1):
+                 kernel_slots: Callable[[int], int] = lambda t: 1,
+                 attn_slots: Callable[[int, int], int] = lambda n, flat: flat):
         for f in self.FIELDS:
             setattr(self, f, 0)
         self.moe_picks, self.moe_rows, self.kernel_slots = moe_picks, moe_rows, kernel_slots
+        self.attn_slots = attn_slots
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
                     live_blocks: int, passes: int = 1,
@@ -123,6 +132,7 @@ class ServeCounters:
         sync."""
         slots = n * t if flat is None else flat
         self.token_slots += slots * passes
+        self.attn_token_slots += (n * t if flat is None else self.attn_slots(n, flat)) * passes
         self.live_tokens += live_tokens
         if self.moe_rows is not None:
             self.moe_expert_rows += self.moe_rows(slots) * passes

@@ -542,10 +542,12 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     static shapes: a mixed SplitFuse step of one 225-token chunk beside 31
     decode rows is ``[32, 256]`` = 8,192 slots for 256 live tokens), the chunk
     is compacted onto one flat axis of S slots and everything that is per
-    token (all four callables) runs over ``[1, S, ...]``.  Only attention sees
-    the padded layout: ``q`` is scattered into a zero ``[N, T, H, Dh]`` for
-    the paged kernel and its output gathered back.  The logits come back as
-    ``[N, T, V]`` all the same, zero wherever no live token sits.  With None,
+    token (all four callables) runs over ``[1, S, ...]``, attention too: the
+    paged kernel takes ``q`` from the flat axis and returns its output there
+    (``paged_attention_flat``: a sequence's rows found by an offset that is
+    data), so nothing of the padded ``[N, T]`` size is built inside the layer
+    scan.  The logits come back as ``[N, T, V]`` all the same (scattered once,
+    after the scan), zero wherever no live token sits.  With None,
     or where the bucket fits the bound (decode ``[N, 1]``, a burst body, a
     spec verify), every slot of the bucket is computed and the trace is the
     padded one.
@@ -561,7 +563,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     VMEM budget (``paged.step_tile``); nothing is passed for it
     (:func:`paged_step_slots` works the same choice out for the engine's counters)."""
     from ..ops.attention.kv_write import kv_write, write_plan
-    from ..ops.attention.paged import paged_attention
+    from ..ops.attention.paged import paged_attention, paged_attention_flat
 
     n, t = tokens.shape
     state = None
@@ -579,7 +581,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
         # the padded bucket as it is: the per-token layers see [N, T]
         safe_pos, live, lengths, blk, off = paged_chunk_indices(
             tokens, n_tokens, start_pos, block_tables, num_blocks, block_size)
-        to_padded = from_padded = lambda a: a
+        to_padded = lambda a: a
         row = col = None
     else:
         # the live tokens on one flat axis: the per-token layers see [1, S]
@@ -592,9 +594,6 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
         def to_padded(a):  # [1, S, ...] -> [N, T, ...], zero wherever no live token sits
             return jnp.zeros((n, t) + a.shape[2:], a.dtype).at[drop_row, col[0]].set(
                 a[0], mode="drop")
-
-        def from_padded(a):  # [N, T, ...] -> [1, S, ...]; a dead slot's value is never used
-            return a[row, col]
 
     x = embed(tokens, safe_pos)
     # which tiles of the pool this pass writes, the same in every layer (None
@@ -610,10 +609,14 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
         # the kernel takes the flat stack as it would one layer's pool (a Pallas
         # operand is materialised, so kpool[l] would be a copy): the table is offset
         kpool, vpool = pools if value_dim is None else (pools[0], None)
-        attn = from_padded(paged_attention(
-            to_padded(q), kpool, vpool, block_tables + first, lengths, start_pos, n_tokens,
-            block_size=block_size, softmax_scale=softmax_scale, window=window,
-            alibi_slopes=alibi_slopes, value_dim=value_dim))
+        facts = dict(block_size=block_size, softmax_scale=softmax_scale, window=window,
+                     alibi_slopes=alibi_slopes, value_dim=value_dim)
+        if slots is None:
+            attn = paged_attention(q, kpool, vpool, block_tables + first, lengths, start_pos,
+                                   n_tokens, **facts)
+        else:  # q as it lies on the flat axis: the kernel finds a sequence's rows by an offset
+            attn = paged_attention_flat(q[0], kpool, vpool, block_tables + first, lengths,
+                                        start_pos, n_tokens, chunk=t, **facts)[None]
         return finish(lp, x, kept, attn, live), pools
 
     def mixer_layer(x, flat_state, lp, l):
@@ -675,27 +678,29 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     return to_padded(head(x)), cache
 
 
-def paged_step_slots(module, config, kv_cache, q_dtype, tp: int = 1) -> Callable[[int], int]:
-    """``t -> slots``: the table slots one grid step of the paged kernel takes in
-    a ``[n, t]`` program of this family (``paged.step_tile`` over the shapes
-    :func:`paged_forward` hands the kernel: the family's ``num_heads`` and its
-    pool's KV heads, a ``tp``-th of each (KV heads that ``tp`` does not divide
-    are held whole by every shard: ``inference/v2/tp.py kv_pool_spec``), the
-    pool's block and width, and, where the module states a ``paged_value_dim``,
-    the value's width inside the key).
-    ``ServeCounters.kernel_steps`` counts the kernel's grid with it."""
-    from ..ops.attention.paged import step_tile
+def paged_step_slots(module, config, kv_cache, q_dtype, tp: int = 1):
+    """``(t -> slots, (n, flat) -> positions)`` for the engine's counters, from
+    the shapes :func:`paged_forward` hands the kernel: the family's ``num_heads``
+    and its pool's KV heads, a ``tp``-th of each (KV heads that ``tp`` does not
+    divide are held whole by every shard: ``inference/v2/tp.py kv_pool_spec``),
+    the pool's block and width, and, where the module states a
+    ``paged_value_dim``, the value's width inside the key.  The first: the table
+    slots one grid step of the paged kernel takes in a ``[n, t]`` program
+    (``paged.step_tile``; ``ServeCounters.kernel_steps`` counts the kernel's grid
+    with it).  The second: the token positions of the kernel's flat row axis in
+    a compacted pass of ``flat`` slots over ``n`` sequences
+    (``paged.flat_token_slots``; ``ServeCounters.attn_token_slots``)."""
+    from ..ops.attention.paged import flat_token_slots, step_tile
     pool = jax.tree_util.tree_leaves({k: v for k, v in kv_cache.items() if k != STATE})[0]
     (_, _, kvh, bs, width), pool_dtype = pool.shape, pool.dtype  # the array itself is not kept
     value_dim = getattr(module, "paged_value_dim", lambda config: None)(config)
-    local_kvh = kvh // tp if kvh % tp == 0 else kvh
+    heads, local_kvh = config.num_heads // tp, kvh // tp if kvh % tp == 0 else kvh
 
     @functools.lru_cache(maxsize=None)
     def slots(t: int) -> int:
-        return step_tile(t, config.num_heads // tp, local_kvh, width, bs, q_dtype, pool_dtype,
-                         value_dim)[-1]
+        return step_tile(t, heads, local_kvh, width, bs, q_dtype, pool_dtype, value_dim)[-1]
 
-    return slots
+    return slots, lambda n, flat: flat_token_slots(n, flat, heads // local_kvh)
 
 
 def sequence_taps(z, kept, n_tokens, row, col):

@@ -10,7 +10,8 @@ source of a copy out of the pool, which stays in HBM, so only blocks below the
 sequence's live length are ever read, with online-softmax accumulation across
 the steps of the walk.
 
-Layout: q [N, T, H, Dh] (T = SplitFuse chunk, 1 at decode); KV pool
+Layout: q [N, T, H, Dh] (T = SplitFuse chunk, 1 at decode), or the flat
+[S, H, Dh] of a compacted pass; KV pool
 [NB, KV, bs, Dh] (one layer's pool — heads-major so the (bs, Dh) tile is the
 trailing pair, as the TPU lowering requires); tables [N, MAXB] int32 (padded
 entries may point anywhere — never read past ``lengths``); lengths [N] = live
@@ -27,10 +28,11 @@ split, step along the table), the table innermost: one step per (sequence,
 step takes the blocks ``tables[n, b * slots : (b + 1) * slots]`` ONCE for
 ``kvg`` KV heads — a [kvg, slots * bs, Dh] tile of K and one of V, each block
 a contiguous [kvg, bs, Dh] of the pool, 256 KiB for 8 heads of 128 x 128 bf16 —
-and with them ALL the q heads that read those KV heads: q goes in as [N, KV,
-T * group, Dh], the ``group = H // KV`` q heads of a KV head stacked into the
-rows of one product (row = token * group + head within the group, a token's
-group adjacent), so a decode step multiplies ``group`` live rows a KV head
+and with them ALL the q heads that read those KV heads: q goes in KV-major on
+ONE flat row axis, [KV, R, Dh] (below), the ``group = H // KV`` q heads of a KV
+head stacked into the rows of one product (row = token * group + head within
+the group, a token's group adjacent), so a decode step multiplies ``group``
+live rows a KV head
 instead of one a q head and a chunk [group * T, Dh] x [Dh, slots * bs]; the
 step's KV heads go through each product together, as its batch dimension
 ([kvg, rows, Dh] x [kvg, slots * bs, Dh]: the body is traced once whatever the
@@ -45,6 +47,22 @@ the probabilities cast to it for p . v as ``models.transformer.sdpa`` does;
 softmax state stays f32.  Per-row facts follow the rows: positions and the
 ``n_tokens`` mask are those of token ``row // group``, the ALiBi slope that of
 q head ``kv * group + row % group``.
+
+**q comes from, and the output goes back to, a flat row axis** (ISSUE 40).
+Sequence ``n``'s rows begin at ``row0[n]``, a number the plan carries (data, in
+whole sublane tiles), not at block ``n`` of a padded array: a grid step's q
+window is ``rows`` rows from ``row0[n] + r * rows`` (an element-indexed
+``BlockSpec``: the pipeline fetches it ahead like any block).  The padded bucket
+``[N, T, H, Dh]`` (:func:`paged_attention`: decode, a burst body, a verify) is
+that with ``row0[n] = n x splits x rows``; a compacted pass
+(:func:`paged_attention_flat`) hands the tokens as they lie, ``[S, H, Dh]``, each
+sequence begun on a whole tile of rows, so nothing of the padded ``[N, T]`` size
+exists: windows then lie over the rows of the sequences behind them.  Reading
+those is harmless.  The output is written by the kernel's own copies, the live
+rows alone and one copy on its way at a time, so the windows' rows reach HBM in
+the grid's order and a whole window's dead rows (zeros) land before the live
+rows of the sequences behind it; rows no window writes are the zeros the output
+began as (aliased in).
 
 **The kernel fetches its own blocks.**  The pools are handed over where they
 lie (``memory_space=pl.ANY``) and a step's live blocks are copied by one loop
@@ -67,8 +85,8 @@ lie past ``lengths``, which the mask already leaves out.
 a prefix of its rows (``n_tokens * group``), so products and the final division
 run over the row tiles under that bound (tiles of ROW_TILE; one tile of
 SMALL_ROWS for a decode row riding in a chunk's bucket) and the other rows are
-written as zeros.  A sequence's first step starts the softmax state instead of
-reading it, so nothing is initialised apart.
+zeros.  A sequence's first step starts the softmax state instead of reading it,
+so nothing is initialised apart.
 
 **The tile is chosen from the static shapes** (``step_tile``: T, H, KV, Dh,
 bs and the two dtypes against one VMEM budget, ``VMEM_BUDGET_BYTES``): all KV
@@ -90,13 +108,15 @@ so: there is no second pool, the step's one tile is copied once and its leading
 columns are ``v``; the accumulator and the output are ``value_dim`` wide while
 q and the scores are the key's width (576 against 512 for DeepSeek-V2).  One
 KV head with the q group of every head stacked into the rows is then the whole
-of q: ``[N, T, H, Dk] -> [N, 1, T * H, Dk]`` is a reshape, no transpose.
+of q: ``[S, H, Dk] -> [1, S * H, Dk]`` is a reshape, no transpose, and with a
+group of whole tiles (128) no gather either.
 
 Off-TPU falls back to the dense gather + masked sdpa (identical math; tests
 compare the two).
 """
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -167,8 +187,10 @@ def _step_vmem_bytes(kvg: int, rows: int, tile: int, dh: int, bs: int,
                      q_bytes: int, kv_bytes: int, dv: Optional[int] = None,
                      slots: int = 1) -> int:
     """VMEM of one grid step holding ``kvg`` KV heads, ``rows`` q rows a head
-    and the blocks of ``slots`` table slots: q and out (double-buffered by the
-    pipeline), the K and V tiles of ``slots`` blocks each (two of each: the
+    and the blocks of ``slots`` table slots: q (double-buffered by the pipeline)
+    and out (held once since ISSUE 40 and still reckoned twice: the heads and
+    rows a step takes stay the parent's), the K and V tiles of ``slots`` blocks
+    each (two of each: the
     next step's are fetched while this one computes), the f32 accumulator with
     ``m`` and ``l`` (a lane tile each a row), and one row tile's scores,
     probabilities and masks over the step's ``slots * bs`` keys for every head.
@@ -247,17 +269,24 @@ def _heads_and_rows(t: int, hq: int, kvh: int, dh: int, bs: int, q_bytes: int, p
         f"size (block_size x head_dim must stay under ~{VMEM_BUDGET_BYTES // (8 * kv_bytes)}).")
 
 
-def _fetch_plan(lengths, n_tokens, bs: int, maxb: int, group: int, rows: int, splits: int):
-    """[1 or 2, N + 1] int32 (``lengths`` and ``n_tokens`` come as int32): what
-    the kernel's fetch asks of a sequence, worked out once a call and not once
-    a grid step: row 0 the table slots that name
-    a live block of a sequence that holds a token (0 for a row of the bucket
-    that holds none: its blocks are never fetched), and, only where a KV head's
-    rows are cut into ``splits`` grid steps, row 1 the splits that hold a
-    token.  Column N is the sequence past the last: no blocks, no fetch."""
+# Rows of the plan the kernel reads as scalars (``_fetch_plan``).
+BLOCKS, ROW0, SPLITS = 0, 1, 2
+
+
+def _fetch_plan(lengths, n_tokens, row0, bs: int, maxb: int, group: int, rows: int, splits: int):
+    """[2 or 3, N + 1] int32 (``lengths``, ``n_tokens`` and ``row0`` come as
+    int32): what the kernel's fetch asks of a sequence, worked out once a call
+    and not once a grid step.  Row ``BLOCKS``: the table slots that name a live
+    block of a sequence that holds a token (0 for a row of the bucket that holds
+    none: its blocks are never fetched).  Row ``ROW0``: where the sequence's q
+    rows begin on the kernel's row axis, and its output's, in sublane tiles of
+    SMALL_ROWS (the compiler must see that a window begins on a whole one).
+    Only where a KV head's rows are cut into ``splits`` grid steps, row
+    ``SPLITS``: the splits that hold a token.  Column N is the sequence past the
+    last: no blocks, no fetch."""
     i32 = np.int32  # numpy scalars are literals of the trace: no equation, no jnp wrapper
     blocks = lax.min(lax.div(lax.add(lengths, i32(bs - 1)), i32(bs)), i32(maxb))
-    plan = [lax.select(lax.gt(n_tokens, i32(0)), blocks, lax.full_like(blocks, 0))]
+    plan = [lax.select(lax.gt(n_tokens, i32(0)), blocks, lax.full_like(blocks, 0)), row0]
     if splits > 1:
         plan.append(lax.min(lax.div(lax.add(lax.mul(n_tokens, i32(group)), i32(rows - 1)), i32(rows)),
                             i32(splits)))
@@ -270,14 +299,16 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
                   value_dim):
     # Every program traces and lowers this body once, and a cell meets 38-70
     # programs: scalars and equal shapes go through ``lax`` (a jnp operator
-    # costs five times as much to trace), and nothing here loops over ``slots``.
+    # costs five times as much to trace), a ``pl.when`` costs 2-3 ms (so what
+    # runs under one condition stands under one), and nothing here loops over
+    # ``slots``.
     if alibi:
         slopes_ref, *rest = rest
     if value_dim is None:
-        q_ref, k_hbm, v_hbm, o_ref, acc, m_sc, l_sc, k_buf, v_buf, sems, turn = rest
+        q_ref, k_hbm, v_hbm, _, o_hbm, acc, m_sc, l_sc, o_buf, k_buf, v_buf, sems, turn = rest
         pools = ((k_hbm, k_buf), (v_hbm, v_buf))
     else:  # the value is the key tile's leading columns: one tile a block
-        q_ref, k_hbm, o_ref, acc, m_sc, l_sc, k_buf, sems, turn = rest
+        q_ref, k_hbm, _, o_hbm, acc, m_sc, l_sc, o_buf, k_buf, sems, turn = rest
         pools = ((k_hbm, k_buf), )
     n, g, r, b = (pl.program_id(i) for i in range(4))
     last = lax.eq(b, pl.num_programs(3) - 1)
@@ -305,48 +336,24 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
                 act(pltpu.make_async_copy(hbm.at[blk, heads], tiles.at[half, :, at, :],
                                           sems.at[half, p]))
 
-        lax.fori_loop(first, lax.min(lax.add(first, slots), plan_ref[0, i]), one, None)
+        lax.fori_loop(first, lax.min(lax.add(first, slots), plan_ref[BLOCKS, i]), one, None)
 
     @pl.when(lax.eq(lax.add(lax.add(n, g), lax.add(r, b)), 0))  # the grid's first step
     def _first():  # a slot never fetched holds zeros, not what VMEM held (0 x NaN is NaN in p . v)
         for _, tiles in pools:
             tiles[...] = lax.full(tiles.shape, 0, tiles.dtype)
-        turn[0] = turn[1] = 0
+        turn[0] = turn[1] = turn[2] = 0
 
     # The fetch runs one live step ahead of the arithmetic, into the other half
     # of the tiles: ``turn`` = (the half this step's blocks are in, whether the
-    # live step before this one started their copies).  The grid runs in order
-    # (every axis "arbitrary"), so the step before may be another sequence's.
+    # live step before this one started their copies, the rows of ``o_buf`` still
+    # on their way out).  The grid runs in order (every axis "arbitrary"), so
+    # the step before may be another sequence's.
     half, fetched = turn[0], turn[1]
-    blocks = plan_ref[0, n]
+    blocks = plan_ref[BLOCKS, n]
     step_live = lax.lt(lax.mul(b, slots), blocks)
     if splits > 1:
-        step_live = lax.bitwise_and(step_live, lax.lt(r, plan_ref[1, n]))
-
-    @pl.when(step_live)
-    def _fetch():
-        # the next step in order: this sequence's next slots, row split or KV
-        # heads, else the sequence after it (no fetch if that one has no token)
-        b1, g1 = lax.add(b, 1), lax.add(g, 1)
-        more = lax.lt(lax.mul(b1, slots), blocks)
-        stay, g2 = more, g
-        if splits > 1:
-            stay = lax.bitwise_or(stay, lax.lt(lax.add(r, 1), plan_ref[1, n]))
-        if head_steps > 1:
-            g2 = lax.select(stay, g, lax.select(lax.lt(g1, head_steps), g1, 0))
-            stay = lax.bitwise_or(stay, lax.lt(g1, head_steps))
-        n1, other = lax.add(n, 1), lax.sub(1, half)
-        each_block(lax.select(stay, n, n1), g2, lax.select(more, b1, 0), other,
-                   lambda c: c.start())
-
-        def arrive(c):  # the kernel's first live step, and one behind a row with no token
-            pl.when(lax.eq(fetched, 0))(c.start)
-            c.wait()
-
-        each_block(n, g, b, half, arrive)
-        turn[0] = other
-        turn[1] = lax.convert_element_type(
-            lax.bitwise_or(stay, lax.gt(plan_ref[0, n1], 0)), jnp.int32)
+        step_live = lax.bitwise_and(step_live, lax.lt(r, plan_ref[SPLITS, n]))
 
     def attend(r0, size):
         """Rows [r0, r0 + size) of every local KV head against the step's
@@ -356,7 +363,7 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
         at = pl.ds(r0, size)
         k = k_buf[half]  # [kvg, slots * bs, Dh], the pool's dtype
         v = v_buf[half] if value_dim is None else lax.slice_in_dim(k, 0, value_dim, axis=2)
-        s = lax.mul(lax.dot_general(lax.convert_element_type(q_ref[0, :, at, :], k.dtype), k,
+        s = lax.mul(lax.dot_general(lax.convert_element_type(q_ref[:, at, :], k.dtype), k,
                                     (((2,), (2,)), ((0,), (0,))),
                                     preferred_element_type=jnp.float32), scale)  # [kvg, size, keys]
         row = lax.add(lax.broadcasted_iota(jnp.int32, (1, size, 1), 1), lax.add(first_row, r0))
@@ -400,33 +407,105 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
         acc[:, at, :] = lax.add(lax.select(lax.broadcast_in_dim(begun, a_prev.shape, (0, 1, 2)),
                                            lax.mul(a_prev, corr), lax.full_like(a_prev, 0.0)), pv)
 
+    out_at = pl.multiple_of(lax.add(lax.mul(plan_ref[ROW0, n], SMALL_ROWS), first_row), SMALL_ROWS)
+    out_heads = pl.ds(lax.mul(g, kvg), kvg)
+
+    def o_rows(size):
+        """The copy of this window's first ``size`` output rows to the flat axis
+        (a wait asks for the bytes alone: a later window's address will do)."""
+        return pltpu.make_async_copy(o_buf.at[:, pl.ds(0, size), :],
+                                     o_hbm.at[out_heads, pl.ds(out_at, size), :],
+                                     sems.at[0, len(pools)])
+
+    small = min(rows, SMALL_ROWS)
+    one = o_rows(small)  # a decode row's tile
+
     def normalise(r0, size):
         at = pl.ds(r0, size)
         l = l_sc[:, at, 0:1]
-        o_ref[0, :, at, :] = lax.convert_element_type(
-            lax.div(acc[:, at, :], lax.select(lax.eq(l, 0.0), lax.full_like(l, 1.0), l)), o_ref.dtype)
+        o_buf[:, at, :] = lax.convert_element_type(
+            lax.div(acc[:, at, :], lax.select(lax.eq(l, 0.0), lax.full_like(l, 1.0), l)), o_buf.dtype)
 
-    @pl.when(last)
-    def _zero():  # rows that hold no token come back zero
-        o_ref[...] = lax.full(o_ref.shape, 0, o_ref.dtype)
+    @pl.when(step_live)
+    def _step():
+        # the next step in order: this sequence's next slots, row split or KV
+        # heads, else the sequence after it (no fetch if that one has no token)
+        b1, g1 = lax.add(b, 1), lax.add(g, 1)
+        more = lax.lt(lax.mul(b1, slots), blocks)
+        stay, g2 = more, g
+        if splits > 1:
+            stay = lax.bitwise_or(stay, lax.lt(lax.add(r, 1), plan_ref[SPLITS, n]))
+        if head_steps > 1:
+            g2 = lax.select(stay, g, lax.select(lax.lt(g1, head_steps), g1, 0))
+            stay = lax.bitwise_or(stay, lax.lt(g1, head_steps))
+        n1, other = lax.add(n, 1), lax.sub(1, half)
+        each_block(lax.select(stay, n, n1), g2, lax.select(more, b1, 0), other,
+                   lambda c: c.start())
 
-    def row_tile(r0, size):
-        pl.when(step_live)(lambda: attend(r0, size))
-        pl.when(lax.bitwise_and(last, lax.gt(length, 0)))(lambda: normalise(r0, size))
+        def arrive(c):  # the kernel's first live step, and one behind a row with no token
+            pl.when(lax.eq(fetched, 0))(c.start)
+            c.wait()
 
-    # every row tile that holds a token, all local KV heads at once: tiles of
-    # ``tile``, or the one of SMALL_ROWS where no more rows are live (a decode
-    # row in a chunk's bucket does a decode row's work)
-    if rows <= SMALL_ROWS:
-        pl.when(lax.gt(live, 0))(lambda: row_tile(0, rows))
-    else:
-        pl.when(lax.bitwise_and(lax.gt(live, 0), lax.le(live, SMALL_ROWS)))(
-            lambda: row_tile(0, SMALL_ROWS))
+        each_block(n, g, b, half, arrive)
+        turn[0] = other
+        turn[1] = lax.convert_element_type(
+            lax.bitwise_or(stay, lax.gt(plan_ref[BLOCKS, n1], 0)), jnp.int32)
+        # every row tile that holds a token, all local KV heads at once: tiles of
+        # ``tile``, or the one of SMALL_ROWS where no more rows are live (a decode
+        # row in a chunk's bucket does a decode row's work)
+        if rows <= SMALL_ROWS:
+            attend(0, rows)
+        else:
+            pl.when(lax.le(live, SMALL_ROWS))(lambda: attend(0, SMALL_ROWS))
 
-        @pl.when(lax.gt(live, SMALL_ROWS))
-        def _tiles():
-            lax.fori_loop(0, pl.cdiv(live, tile),
-                          lambda i, _: row_tile(pl.multiple_of(lax.mul(i, tile), tile), tile), None)
+            @pl.when(lax.gt(live, SMALL_ROWS))
+            def _tiles():
+                lax.fori_loop(0, pl.cdiv(live, tile),
+                              lambda i, _: attend(pl.multiple_of(lax.mul(i, tile), tile), tile), None)
+
+        # A window's output leaves by a copy of the kernel's own, at the window's
+        # last live step: its live rows alone.  The one tile of a decode row is
+        # started here and waited for where the next window leaves (the grid's last
+        # step waits for what is left); a window of more rows goes whole (rows
+        # without a token as zeros) and is waited for on the spot.  So one copy is
+        # on its way at a time and the windows' rows go out in the grid's order: on
+        # the flat axis a whole window lies over the rows of the sequences BEHIND
+        # it, and those write their live rows later.  ``turn[2]``: a tile is on its way.
+        @pl.when(lax.bitwise_not(more))
+        def _leave():
+            pl.when(lax.ne(turn[2], 0))(one.wait)
+
+            def tile_out():
+                normalise(0, small)
+                one.start()
+                turn[2] = 1
+
+            if rows <= SMALL_ROWS:
+                return tile_out()
+            turn[2] = 0
+            pl.when(lax.le(live, SMALL_ROWS))(tile_out)
+
+            @pl.when(lax.gt(live, SMALL_ROWS))
+            def _whole():
+                o_buf[...] = lax.full(o_buf.shape, 0, o_buf.dtype)
+                lax.fori_loop(0, pl.cdiv(live, tile),
+                              lambda i, _: normalise(pl.multiple_of(lax.mul(i, tile), tile), tile), None)
+                whole = o_rows(rows)
+                whole.start()
+                whole.wait()
+
+    end = lax.bitwise_and(  # the grid's last step
+        lax.bitwise_and(lax.eq(n, pl.num_programs(0) - 1), lax.eq(g, head_steps - 1)),
+        lax.bitwise_and(lax.eq(r, splits - 1), last))
+    pl.when(lax.bitwise_and(end, lax.ne(turn[2], 0)))(one.wait)
+
+
+def _checked_scale(dh: int, vpool, value_dim, softmax_scale) -> float:
+    if (vpool is None) != (value_dim is not None):
+        raise ValueError("paged_attention: a value pool, or the width of the value inside the "
+                         f"key (vpool=None with value_dim); got vpool={type(vpool).__name__}, "
+                         f"value_dim={value_dim}")
+    return softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(dh))
 
 
 def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
@@ -440,85 +519,229 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
     reference serves ALiBi through its softmax op's alibi path,
     ops/transformer/inference/op_binding/softmax.py).  ``vpool=None``: the
     value of a cached token is the first ``value_dim`` columns of its key (a
-    latent pool); the result is then [N, T, H, value_dim]."""
+    latent pool); the result is then [N, T, H, value_dim].
+
+    The padded bucket is the flat form (:func:`paged_attention_flat`) with every
+    sequence's rows begun a whole window apart, ``row0[n] = n x splits x rows``:
+    the same kernel, and no index worked out from ``n_tokens``."""
     n, t, hq, dh = q.shape
-    kvh, bs = kpool.shape[1], kpool.shape[2]
-    maxb = tables.shape[1]
-    if (vpool is None) != (value_dim is not None):
-        raise ValueError("paged_attention: a value pool, or the width of the value inside the "
-                         f"key (vpool=None with value_dim); got vpool={type(vpool).__name__}, "
-                         f"value_dim={value_dim}")
-    dv = dh if value_dim is None else value_dim
-    scale = softmax_scale if softmax_scale is not None else 1.0 / float(np.sqrt(dh))
+    scale = _checked_scale(dh, vpool, value_dim, softmax_scale)
     if not _use_pallas():
         return _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
                                scale, window, alibi_slopes, value_dim)
-
-    alibi = alibi_slopes is not None
-    check_block_table_fits(n, maxb, n_vectors=(4 if alibi else 3) + 2)  # and the plan's rows
-    group = hq // kvh
-    kvg, rows, splits, tile, slots = step_tile(t, hq, kvh, dh, bs, q.dtype, kpool.dtype,
-                                               value_dim)
-    # [N, T, KV, group, Dh] -> [N, KV, T * group, Dh]: a KV head's q rows, a
-    # token's group adjacent, padded with rows that hold no token
+    kvh = kpool.shape[1]
+    group, dv = hq // kvh, dh if value_dim is None else value_dim
+    shape = step_tile(t, hq, kvh, dh, kpool.shape[2], q.dtype, kpool.dtype, value_dim)
+    held = shape[2] * shape[1]  # a sequence's rows: ``splits`` windows of ``rows``
+    # [N, T, KV, group, Dh] -> [KV, N, T * group, Dh]: a KV head's q rows, a
+    # token's group adjacent, each sequence padded with rows that hold no token
     # (through ``lax`` like the body: every program traces and lowers this wrapper too)
-    qr = lax.reshape(lax.transpose(lax.reshape(q, (n, t, kvh, group, dh)), (0, 2, 1, 3, 4)),
-                     (n, kvh, t * group, dh))
-    padded = splits * rows - t * group
+    qr = lax.reshape(lax.transpose(lax.reshape(q, (n, t, kvh, group, dh)), (2, 0, 1, 3, 4)),
+                     (kvh, n, t * group, dh))
+    padded = held - t * group
     if padded:
         qr = lax.pad(qr, np.zeros((), q.dtype), ((0, 0, 0), (0, 0, 0), (0, padded, 0), (0, 0, 0)))
+    out = _walk(lax.reshape(qr, (kvh, n * held, dh)),
+                lax.mul(lax.iota(jnp.int32, n), np.int32(held // SMALL_ROWS)), kpool, vpool,
+                tables, lengths, start_pos, n_tokens, group=group, shape=shape, scale=scale,
+                window=window, alibi_slopes=alibi_slopes, value_dim=value_dim)
+    out = lax.reshape(out, (kvh, n, held, dv))
+    if padded:
+        out = lax.slice_in_dim(out, 0, t * group, axis=2)
+    out = lax.transpose(lax.reshape(out, (kvh, n, t, group, dv)), (1, 2, 0, 3, 4))
+    return lax.reshape(out, (n, t, hq, dv))
 
-    def q_block(ni, g, r, b, *refs):
-        return ni, g, r, 0
 
+def flat_token_slots(n: int, s: int, group: int) -> int:
+    """Token positions of the kernel's row axis in a flat pass of ``s`` slots
+    over ``n`` sequences: every sequence begins on a whole sublane tile of rows
+    (``SMALL_ROWS``; a token is ``group`` rows), so up to ``align - 1`` positions
+    a sequence hold no token.  ``ServeCounters.attn_token_slots`` counts this
+    (the spare window behind them, which no sequence can begin in, apart)."""
+    return s + n * (SMALL_ROWS // math.gcd(group, SMALL_ROWS) - 1)
+
+
+def _rows_at(x, at):
+    """``x[at]`` along the first axis, every index in bounds (through ``lax``:
+    jnp's indexing costs a millisecond a use to trace, in every program)."""
+    numbers = lax.GatherDimensionNumbers(offset_dims=tuple(range(1, x.ndim)),
+                                         collapsed_slice_dims=(0, ), start_index_map=(0, ))
+    return lax.gather(x, lax.expand_dims(at, (1, )), numbers, (1, ) + x.shape[1:],
+                      mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def _flat_rows(n_tokens, s: int, held: int, align: int):
+    """Where a flat pass's tokens lie on the kernel's token axis of ``held``
+    positions, each sequence begun on a multiple of ``align``: ``(first [N],
+    take [held], back [s])``: sequence ``n`` begins at ``first[n]``, position
+    ``p`` holds flat slot ``take[p]`` (slot 0 where it holds none: the kernel
+    masks it), and flat slot ``j`` lies at ``back[j]`` (the last position, which
+    holds nothing and comes back zero, for a slot past the live tokens)."""
+    i32, n = np.int32, n_tokens.shape[0]
+    whole = lax.mul(lax.div(lax.add(n_tokens, i32(align - 1)), i32(align)), i32(align))
+    sums = lax.cumsum(lax.concatenate([lax.expand_dims(n_tokens, (0, )),
+                                       lax.expand_dims(whole, (0, ))], 0), axis=1)
+    ends = lax.index_in_dim(sums, 0, 0, keepdims=False)
+    first = lax.sub(lax.index_in_dim(sums, 1, 0, keepdims=False), whole)
+    gap = lax.sub(first, lax.sub(ends, n_tokens))  # positions without a token before sequence n's
+    j = lax.iota(jnp.int32, s)
+    past = lax.ge(lax.broadcast_in_dim(j, (s, n), (0, )), lax.broadcast_in_dim(ends, (s, n), (1, )))
+    # the sequence a slot's token is of: N for a slot past the live tokens
+    row = lax.reduce_sum(lax.convert_element_type(past, jnp.int32), (1, ))
+    back = lax.select(lax.lt(row, lax.full_like(row, n)),
+                      lax.add(j, _rows_at(gap, lax.min(row, lax.full_like(row, n - 1)))),
+                      lax.full_like(j, held - 1))
+    # the other way round: every position takes slot 0 but those a live slot lies at
+    numbers = lax.ScatterDimensionNumbers(update_window_dims=(), inserted_window_dims=(0, ),
+                                          scatter_dims_to_operand_dims=(0, ))
+    take = lax.scatter(lax.full((held, ), 0, jnp.int32), lax.expand_dims(back, (1, )), j, numbers,
+                       indices_are_sorted=True, mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    return first, take, back
+
+
+def paged_attention_flat(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *, chunk: int,
+                         block_size: int, softmax_scale: Optional[float] = None,
+                         window: Optional[int] = None, alibi_slopes=None,
+                         value_dim: Optional[int] = None):
+    """:func:`paged_attention` over a pass's tokens as they lie on ONE flat axis:
+    q [S, H, Dh], sequence 0's ``n_tokens[0]`` tokens first, then sequence 1's,
+    the tail past ``sum(n_tokens)`` dead (``models.transformer.flat_chunk_indices``);
+    ``chunk`` is the bucket's T, the most tokens a sequence may hold.  Returns
+    [S, H, Dv]; a dead slot's value is finite and never used (zero from the
+    kernel, slot 0's from the fallback).  No array of the padded ``[N, chunk]``
+    size is built: q is laid KV-major at the flat size (``[KV, R, Dh]``, row =
+    position x group + head within the group; one KV head: a reshape), each
+    sequence begun on a whole sublane tile of rows (a gather of the flat q where
+    ``group`` is no multiple of ``SMALL_ROWS``), and the kernel finds sequence
+    ``n``'s window at the row offset ``row0[n]`` of its plan, which is data.
+    A window is ``rows`` long whatever the sequence holds, so it lies over the
+    rows of the sequences behind it: reading them is harmless, and the kernel
+    writes a window's output only after every earlier window's.  One spare
+    window behind the last position keeps the last copy in bounds."""
+    s, hq, dh = q.shape
+    scale = _checked_scale(dh, vpool, value_dim, softmax_scale)
+    if not _use_pallas():
+        return _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
+                               scale, window, alibi_slopes, value_dim, chunk=chunk)
+    n, kvh = n_tokens.shape[0], kpool.shape[1]
+    group, dv = hq // kvh, dh if value_dim is None else value_dim
+    shape = step_tile(chunk, hq, kvh, dh, kpool.shape[2], q.dtype, kpool.dtype, value_dim)
+    align = SMALL_ROWS // math.gcd(group, SMALL_ROWS)  # tokens whose rows are whole tiles
+    held = flat_token_slots(n, s, group) + -(-shape[1] // group)  # and the spare window
+    n_tokens = lax.convert_element_type(n_tokens, jnp.int32)
+    if align == 1:  # every token's rows are whole tiles: the flat axis as it is
+        first, back = lax.sub(lax.cumsum(n_tokens, axis=0), n_tokens), None
+        qk = lax.pad(q, np.zeros((), q.dtype), ((0, held - s, 0), (0, 0, 0), (0, 0, 0)))
+    else:
+        first, take, back = _flat_rows(n_tokens, s, held, align)
+        qk = _rows_at(q, take)
+    qr = lax.reshape(lax.transpose(lax.reshape(qk, (held, kvh, group, dh)), (1, 0, 2, 3)),
+                     (kvh, held * group, dh))
+    out = _walk(qr, lax.div(lax.mul(first, np.int32(group)), np.int32(SMALL_ROWS)), kpool, vpool,
+                tables, lengths, start_pos, n_tokens, group=group, shape=shape, scale=scale,
+                window=window, alibi_slopes=alibi_slopes, value_dim=value_dim)
+    out = lax.reshape(lax.transpose(lax.reshape(out, (kvh, held, group, dv)), (1, 0, 2, 3)),
+                      (held, hq, dv))
+    return lax.slice_in_dim(out, 0, s, axis=0) if back is None else _rows_at(out, back)
+
+
+def _walk(qr, row0, kpool, vpool, tables, lengths, start_pos, n_tokens, *, group, shape, scale,
+          window, alibi_slopes, value_dim):
+    """The kernel's call.  ``qr`` [KV, R, Dh]: q KV-major on the flat row axis;
+    ``row0`` [N] int32: where each sequence's window of rows begins, in
+    sublane tiles of SMALL_ROWS (``R`` holds the last live window whole).  Returns the output
+    on the same rows, [KV, R, Dv]; rows of no token are zero."""
+    kvh, total, dh = qr.shape
+    (n, maxb), bs = tables.shape, kpool.shape[2]
+    kvg, rows, splits, tile, slots = shape
+    dv = dh if value_dim is None else value_dim
+    alibi = alibi_slopes is not None
+    check_block_table_fits(n, maxb, n_vectors=(4 if alibi else 3) + 3)  # and the plan's rows
     kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs, group=group,
                                kvg=kvg, tile=tile, slots=slots, head_steps=kvh // kvg,
                                splits=splits, window=window, alibi=alibi, value_dim=value_dim)
     pools = (kpool, vpool) if value_dim is None else (kpool, )
-    # the pools stay where they are: the kernel copies a step's live blocks itself,
-    # ``slots`` of them into one tile a pool, the next step's while this one computes
+    def q_window(ni, g, r, b, tables, lengths, start, ntok, plan, *_):
+        """Where the step's q rows begin on the flat axis (an element, not a
+        block: whole sublane tiles, which the compiler must see).  A row split
+        past the last that holds a token stays on that one: nothing to fetch."""
+        at = lax.mul(plan[ROW0, ni], SMALL_ROWS)
+        if splits > 1:
+            at = pl.multiple_of(lax.add(at, lax.mul(
+                lax.min(r, lax.max(lax.sub(plan[SPLITS, ni], 1), 0)), rows)), SMALL_ROWS)
+        return lax.mul(g, kvg), at, 0
+
+    # the pools and the output stay where they are: the kernel copies a step's live
+    # blocks itself (``slots`` of them into one tile a pool, the next step's while
+    # this one computes) and a window's output rows back; q's window comes by the
+    # pipeline, from wherever the plan says the sequence's rows begin
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6 if alibi else 5,
         grid=(n, kvh // kvg, splits, pl.cdiv(maxb, slots)),
-        in_specs=[pl.BlockSpec((1, kvg, rows, dh), q_block)]
-        + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
-        out_specs=pl.BlockSpec((1, kvg, rows, dv), q_block),
+        in_specs=[pl.BlockSpec((pl.Element(kvg), pl.Element(rows), pl.Element(dh)), q_window)]
+        + [anywhere] * (len(pools) + 1),
+        out_specs=anywhere,
         scratch_shapes=[
             pltpu.VMEM((kvg, rows, dv), jnp.float32),
             pltpu.VMEM((kvg, rows, 128), jnp.float32),
             pltpu.VMEM((kvg, rows, 128), jnp.float32),
+            pltpu.VMEM((kvg, rows, dv), qr.dtype),
             *(pltpu.VMEM((2, kvg, slots * bs, dh), pool.dtype) for pool in pools),
-            pltpu.SemaphoreType.DMA((2, len(pools))),
-            pltpu.SMEM((2, ), jnp.int32),
+            pltpu.SemaphoreType.DMA((2, len(pools) + 1)),  # a pool's two halves; [0, -1] the output's
+            pltpu.SMEM((3, ), jnp.int32),
         ],
     )
     tables, lengths, start_pos, n_tokens = (lax.convert_element_type(x, jnp.int32)
                                             for x in (tables, lengths, start_pos, n_tokens))
     scalars = [tables, lengths, start_pos, n_tokens,
-               _fetch_plan(lengths, n_tokens, bs, maxb, group, rows, splits)]
+               _fetch_plan(lengths, n_tokens, row0, bs, maxb, group, rows, splits)]
     if alibi:
         scalars.append(jnp.asarray(alibi_slopes, jnp.float32))
-    out = pl.pallas_call(
+    # rows no window writes come back zero: the output begins as zeros, aliased in
+    zeros = lax.full((kvh, total, dv), 0, qr.dtype)
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qr.shape[:3] + (dv, ), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(zeros.shape, zeros.dtype),
+        input_output_aliases={len(scalars) + 1 + len(pools): 0},
         compiler_params=CompilerParams(
             dimension_semantics=("arbitrary", ) * 4,  # the fetch runs ahead across all four
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=_pallas.INTERPRET,
         name="paged_attention",
-    )(*scalars, qr, *pools)
-    if padded:
-        out = lax.slice_in_dim(out, 0, t * group, axis=2)
-    out = lax.transpose(lax.reshape(out, (n, kvh, t, group, dv)), (0, 2, 1, 3, 4))
-    return lax.reshape(out, (n, t, hq, dv))
+    )(*scalars, qr, *pools, zeros)
+
+
+def _flat_slots(n_tokens, s: int):
+    """(row [s], col [s], live [s]): whose token a flat slot holds (a dead slot:
+    [0, 0]), from ``n_tokens`` alone (``transformer.flat_chunk_indices`` wants
+    the tables and positions as arrays too)."""
+    ends = jnp.cumsum(n_tokens)
+    j = jnp.arange(s)
+    row = jnp.sum(j[:, None] >= ends[None, :], axis=1)
+    live = row < n_tokens.shape[0]
+    row = jnp.where(live, row, 0)
+    return row, jnp.where(live, j - (ends - n_tokens)[row], 0), live
 
 
 def _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale,
-                    window, alibi_slopes=None, value_dim: Optional[int] = None):
+                    window, alibi_slopes=None, value_dim: Optional[int] = None,
+                    chunk: Optional[int] = None):
     """Reference-math path: gather the whole table, masked sdpa (the v2
-    engine's original implementation — kept as the CPU/parity baseline)."""
+    engine's original implementation — kept as the CPU/parity baseline).  Both
+    forms: q [N, T, H, Dh], or with ``chunk`` the flat q [S, H, Dh] of
+    :func:`paged_attention_flat`, scattered onto the padded ``[N, chunk]`` here
+    (it may pad as it likes) and gathered back."""
     from ...models.transformer import sdpa
+    if chunk is not None:
+        row, col, live = _flat_slots(n_tokens, q.shape[0])
+        n = n_tokens.shape[0]
+        padded = jnp.zeros((n, chunk) + q.shape[1:], q.dtype).at[
+            jnp.where(live, row, n), col].set(q, mode="drop")
+        out = _dense_fallback(padded, kpool, vpool, tables, lengths, start_pos, n_tokens, scale,
+                              window, alibi_slopes, value_dim)
+        return out[row, col]  # a dead slot: sequence 0's first token, as the padded form gathered it
     n, t, hq, dh = q.shape
     maxb = tables.shape[1]
     kvh, bs = kpool.shape[1], kpool.shape[2]

@@ -31,6 +31,7 @@ from deepspeed_tpu.models import (bloom, deepseek_v2, falcon, gptj, llama, mistr
 from deepspeed_tpu.models.transformer import flat_chunk_indices, flat_slots, paged_chunk_indices
 from deepspeed_tpu.ops import _pallas
 from deepspeed_tpu.ops.attention import kv_write as kvw
+from deepspeed_tpu.ops.attention import paged as paged_mod
 from deepspeed_tpu.ops.attention.paged import paged_attention
 from deepspeed_tpu.parallel import MeshTopology
 
@@ -492,3 +493,76 @@ def test_the_writer_under_tp_axis_on_two_devices(interpreted, monkeypatch):
     got = run()
     scatter_instead(monkeypatch)
     assert_bit_equal(got, run())
+
+
+# ------------------------------------------------------------------ ISSUE 40
+# The paged kernel takes q from, and returns its output to, the flat token axis:
+# a compacted chunk pass builds nothing of the padded [N, T, H, Dh] size around it.
+def _scan_bodies(jaxpr):
+    """Every equation inside a ``scan`` of the jaxpr, however deep (the Pallas
+    kernels' own bodies apart: their values are tiles in VMEM)."""
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if inside:
+                yield eqn
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple)) else [value]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from walk(inner, inside or eqn.primitive.name == "scan")
+    return walk(jaxpr, False)
+
+
+@pytest.mark.parametrize("family", ["mistral-7b-widths", "deepseek-v2-latent", "olmoe-mha"])
+def test_a_compacted_chunk_program_holds_nothing_of_the_padded_size_in_its_layer_scan(monkeypatch,
+                                                                                      family):
+    """``forward_paged`` at ``[32, 256]`` with 256 live tokens at most, traced as
+    the chip would run it (kernels on: tracing a ``pallas_call`` needs no chip,
+    and the dense fallback may pad as it likes): no value inside the layer scan
+    is as large as a quarter of the padded ``[N, T, H, Dh]`` q, and the kernel's
+    q is the flat axis laid KV-major.  The shapes are static, so the padding
+    cannot come back unseen."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, t, bound, bs, nb, maxb = 32, 256, 256, 128, 8, 20
+    module, cfg = {
+        "mistral-7b-widths": lambda: (mistral, mistral.MistralConfig(
+            vocab_size=512, hidden_size=4096, intermediate_size=14336, num_layers=2, num_heads=32,
+            num_kv_heads=8, max_seq_len=32768, sliding_window=4096)),
+        "deepseek-v2-latent": lambda: (deepseek_v2, deepseek_v2.DeepseekV2Config.tiny(local_experts=4)),
+        "olmoe-mha": lambda: (olmoe, olmoe.OlmoeConfig.tiny(layers=2)),
+    }[family]()
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16), module.init_params(cfg, jax.random.PRNGKey(0))))
+    kv = jax.eval_shape(lambda: module.init_paged_cache(cfg, nb, bs, dtype=jnp.bfloat16))
+    ints = [jax.ShapeDtypeStruct(s, jnp.int32) for s in ((n, t), (n, ), (n, ), (n, maxb))]
+
+    def trace(bound):
+        return jax.make_jaxpr(lambda p, kv, tokens, n_tokens, start_pos, tables: module.forward_paged(
+            cfg, p, tokens, n_tokens, start_pos, tables, kv, block_size=bs,
+            live_token_bound=bound))(params, kv, *ints)
+
+    calls = [e for e in _scan_bodies(trace(bound).jaxpr) if e.primitive.name == "pallas_call"
+             and e.params["name"] == "paged_attention"]
+    assert calls
+    for call in calls:
+        q = next(v.aval for v in call.invars if v.aval.ndim == 3 and v.aval.dtype != jnp.int32)
+        hq = cfg.num_heads
+        kvh = q.shape[0]
+        group, align = hq // kvh, 16 // np.gcd(hq // kvh, 16)
+        rows = paged_mod.step_tile(t, hq, kvh, q.shape[2], bs, q.dtype, jnp.bfloat16,
+                                   getattr(module, "paged_value_dim", lambda c: None)(cfg))[1]
+        assert q.shape[1] == (bound + n * (align - 1) + -(-rows // group)) * group
+        padded = n * t * hq * q.shape[2]
+        assert q.size < padded // 4
+    pools = {(leaf.shape[0] * leaf.shape[1], ) + leaf.shape[2:] for leaf in jax.tree_util.tree_leaves(kv)}
+    sizes = {}
+    for eqn in _scan_bodies(trace(bound).jaxpr):
+        for out in eqn.outvars:  # the carried pool apart, which the writer hands on whole
+            if getattr(out.aval, "size", 0) >= padded // 4 and out.aval.shape not in pools:
+                sizes[eqn.primitive.name] = out.aval.shape
+    assert not sizes, sizes
+    # the search does find the padded bucket where a program runs it: the same trace with no bound
+    assert any(getattr(out.aval, "size", 0) >= padded for eqn in _scan_bodies(trace(None).jaxpr)
+               for out in eqn.outvars)

@@ -127,6 +127,95 @@ def test_every_copy_is_waited_for_before_its_block_is_read(monkeypatch, hq, kvh,
     assert not interpreter.races.races_found
 
 
+# ------------------------------------------------------------ q on the flat axis
+def flat_of(case, spare=0):
+    """The case's live tokens on one flat axis, sequence after sequence, the
+    tail dead: ``(flat q [S, H, Dk], (row, col) of the live slots)``."""
+    q, n_tokens = case[0], np.asarray(case[-1])
+    row = np.repeat(np.arange(len(n_tokens)), n_tokens)
+    col = np.concatenate([np.arange(k) for k in n_tokens] or [np.zeros(0, int)])
+    s = max(8, -(-(len(row) + spare) // 8) * 8)
+    flat = jnp.zeros((s, ) + q.shape[2:], q.dtype).at[:len(row)].set(q[row, col])
+    return flat, (row, col)
+
+
+def _flat_cases():
+    chunk = [(300, 225), (40, 1), (17, 1), (290, 1)]  # decode rows behind a chunk, t = 256
+    yield "decode-rows-behind-a-225-token-chunk", dict(rows=chunk, t=256, hq=4, kvh=2, maxb=20), {}
+    yield "a-row-with-no-token-between-two", dict(
+        rows=[(20, 3), (0, 0), (33, 1), (0, 0), (0, 0), (64, 16)], t=16, hq=4, kvh=2, maxb=5), {}
+    # the flat axis full to its last slot: the last window ends where R's spare window begins
+    yield "the-last-window-a-chunks", dict(rows=[(9, 1), (40, 7), (256, 248)], t=256, hq=4, kvh=2,
+                                           maxb=20), {}
+    yield "the-last-window-a-decode-rows", dict(rows=[(256, 247), (40, 8), (9, 1)], t=256, hq=4,
+                                                kvh=2, maxb=20), {}
+    yield "no-token-at-all", dict(rows=[(0, 0), (0, 0)], t=16, hq=4, kvh=2, maxb=3), {}
+    yield "gqa-32q8kv", dict(rows=chunk, t=256, hq=32, kvh=8, maxb=20), {}
+    yield "mha-16q16kv", dict(rows=[(30, 5), (18, 1), (70, 16), (3, 3)], t=16, hq=16, kvh=16, maxb=6), {}
+    yield "packed-64-wide-heads-group8", dict(rows=[(30, 5), (18, 1), (70, 16)], t=16, hq=16, kvh=2,
+                                              maxb=6, dk=128), {}
+    yield "falcon-71q1kv", dict(rows=[(30, 5), (18, 1), (70, 9)], t=16, hq=71, kvh=1, maxb=6), {}
+    yield "window-40", dict(rows=[(150, 14), (90, 1), (64, 16)], t=16, hq=4, kvh=2, maxb=12), dict(window=40)
+    yield "alibi", dict(rows=[(30, 5), (18, 1), (70, 16)], t=16, hq=8, kvh=2, maxb=6), dict(alibi=True)
+    yield "latent-576-512", dict(rows=[(30, 5), (18, 1), (70, 16)], t=16, hq=8, kvh=1, maxb=6, dk=576,
+                                 dv=512), dict(dv=512, scale=0.07)
+    # a row split: one KV head's 2,048 rows in four grid steps of 512 (the budget cut to force it)
+    yield "value-dim-rows-split-in-four", dict(rows=[(100, 40), (18, 1), (64, 64), (70, 3)], t=64, hq=32,
+                                               kvh=1, maxb=8, dk=64, dv=32), dict(dv=32, scale=0.1, splits=4)
+    yield "bf16-32q8kv", dict(rows=chunk, t=256, hq=32, kvh=8, maxb=20, dtype=jnp.bfloat16), {}
+
+
+@pytest.mark.parametrize("path", ["kernel", "fallback"])
+@pytest.mark.parametrize("name,case,how", list(_flat_cases()), ids=lambda v: v if isinstance(v, str) else "")
+def test_q_on_the_flat_axis_is_the_padded_bucket_on_every_live_row(monkeypatch, name, case, how, path):
+    """``paged_attention_flat`` over the pass's tokens on one axis against the
+    padded entry over the same kernel (``row0 = n x rows``): bit for bit on every
+    live row (a live row's tiles, products and order are the same), finite and,
+    from the kernel, zero in the dead slots.  Both forms of ``_dense_fallback``
+    likewise."""
+    from deepspeed_tpu.ops import _pallas
+    how = dict(how)
+    monkeypatch.setattr(_pallas, "INTERPRET", path == "kernel")
+    splits = how.pop("splits", 1)
+    tile_args = (case["t"], case["hq"], case["kvh"], case.get("dk", 32), BS, case.get("dtype", jnp.float32),
+                 case.get("dtype", jnp.float32), case.get("dv"))
+    if splits > 1:
+        monkeypatch.setattr(paged, "VMEM_BUDGET_BYTES", paged._step_vmem_bytes(
+            1, 2048 // splits, 256, case["dk"], BS, 4, 4, case["dv"]))
+    assert paged.step_tile(*tile_args)[2] == splits
+    slopes = (jnp.asarray(2.0 ** -np.arange(1, case["hq"] + 1), jnp.float32)
+              if how.pop("alibi", False) else None)
+    facts = dict(block_size=BS, window=how.get("window"), alibi_slopes=slopes,
+                 softmax_scale=how.get("scale"), value_dim=how.get("dv"))
+    drawn = drawn_case(**case)
+    padded = paged.paged_attention(*drawn, **facts)
+    for spare in (0, 9):  # the flat axis full to its last slot, and with dead slots behind
+        flat, (row, col) = flat_of(drawn, spare)
+        got = paged.paged_attention_flat(flat, *drawn[1:], chunk=case["t"], **facts)
+        assert got.shape == flat.shape[:2] + (how.get("dv") or flat.shape[-1], ) and got.dtype == flat.dtype
+        got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, padded))
+        np.testing.assert_array_equal(got[:len(row)], want[row, col])
+        assert np.isfinite(got).all() and (path == "fallback" or (got[len(row):] == 0.0).all())
+
+
+def test_the_flat_forms_copies_are_waited_for_and_in_the_grids_order(monkeypatch):
+    """The interpreter that models DMA and semaphores, as above, over the flat
+    form: a window's output rows lie over the sequences behind it, so a copy that
+    left late, or one nobody waited for, shows as a race or a wrong row."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as interpreter
+    from jax.experimental.pallas import tpu as pltpu
+
+    from deepspeed_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, "INTERPRET", pltpu.InterpretParams(
+        dma_execution_mode="on_wait", detect_races=True))
+    drawn = drawn_case([(40, 1), (90, 30), (0, 0), (17, 1), (33, 2)], 32, 4, 2, 6)
+    flat, (row, col) = flat_of(drawn, 3)
+    got = paged.paged_attention_flat(flat, *drawn[1:], chunk=32, block_size=BS)
+    ref = paged._dense_fallback(*drawn, 1.0 / np.sqrt(32), None)
+    np.testing.assert_allclose(np.asarray(got)[:len(row)], np.asarray(ref)[row, col], atol=2e-5)
+    assert not interpreter.races.races_found
+
+
 # ------------------------------------------------------------ the tile chooser
 PARENTS_TILES = [  # (t, hq, kvh, dh, dv, (kvg, rows, splits, tile) at 7e19018, slots)
     (1, 32, 8, 128, None, (8, 16, 1, 16), 4), (9, 32, 8, 128, None, (8, 48, 1, 48), 4),
@@ -238,6 +327,27 @@ def test_kernel_steps_count_the_grids_table_axis():
     assert ServeCounters().kernel_slots(7) == 1 and "kernel_steps" in counters.snapshot()
 
 
+@pytest.mark.parametrize("group,align", [(4, 4), (1, 16), (8, 2), (128, 1), (71, 16), (6, 8)])
+def test_attention_slots_count_the_layout_the_kernel_was_handed(group, align):
+    """``attn_token_slots``: n x t a padded pass and every pass of a burst, the
+    flat row axis over ``group`` a compacted one: its S slots and, a sequence,
+    the positions that begin it on a whole sublane tile of rows."""
+    from deepspeed_tpu.inference.v2.fastpath import ServeCounters
+    assert paged.flat_token_slots(32, 256, group) == 256 + 32 * (align - 1)
+    counters = ServeCounters(attn_slots=lambda n, flat: paged.flat_token_slots(n, flat, group))
+    counters.count_slots(32, 1, 20, live_tokens=32, live_blocks=90)  # padded
+    assert (counters.attn_token_slots, counters.token_slots) == (32, 32)
+    counters.count_slots(32, 256, 18, live_tokens=256, live_blocks=90, flat=256)  # flat
+    assert counters.token_slots == 32 + 256
+    assert counters.attn_token_slots == 32 + 256 + 32 * (align - 1)
+    counters.count_slots(16, 1, 10, live_tokens=160, live_blocks=40, passes=10)  # a burst of ten
+    assert counters.attn_token_slots == 32 + 256 + 32 * (align - 1) + 160
+    assert counters.snapshot()["attn_token_slots"] == counters.attn_token_slots
+    plain = ServeCounters()  # no kernel's word on it: the flat slots themselves
+    plain.count_slots(32, 256, 18, live_tokens=256, live_blocks=90, flat=256)
+    assert plain.attn_token_slots == 256
+
+
 def _grid_of_the_kernel(module, config, n, t, b, stateful=False):
     """The grid of the ``paged_attention`` call in the family's traced forward."""
     kv = module.init_paged_cache(config, 8, BS, dtype=jnp.float32,
@@ -268,5 +378,5 @@ def test_the_engines_slots_are_the_launched_programs(monkeypatch, family, t, b):
               "deepseek_v2": lambda: module.DeepseekV2Config.tiny(local_experts=4),
               "lfm2": lambda: module.Lfm2Config.tiny()}[family]()
     kv, grids = _grid_of_the_kernel(module, config, 4, t, b, stateful=family == "lfm2")
-    slots = paged_step_slots(module, config, kv, jnp.float32)(t)
+    slots = paged_step_slots(module, config, kv, jnp.float32)[0](t)
     assert slots in paged.STEP_SLOTS and grids and {g[-1] for g in grids} == {-(-b // slots)}

@@ -144,6 +144,39 @@ def test_paged_attention_over_a_latent_pool_compiles(chip, n, t):
     assert kvg == 1 and splits * rows == max(t * 128, rows)  # equal whole parts: q is not padded
 
 
+@pytest.mark.parametrize("n,t,s,maxb,hq,kvh,dh,dv", [
+    pytest.param(32, 256, 256, 20, H, KV, DH, None, id="mistral-n32-T256-S256"),
+    pytest.param(4, 256, 256, 36, H, KV, DH, None, id="mistral-n4-T256-S256"),
+    pytest.param(32, 256, 256, 12, 16, 16, DH, None, id="olmoe-n32-T256-S256"),
+    pytest.param(32, 512, 512, 8, 32, 4, DH, None, id="lfm2-packed-n32-T512-S512"),
+    pytest.param(4, 512, 512, 64, 128, 1, 640, 512, id="latent-n4-T512-S512"),
+    pytest.param(8, 512, 512, 68, 128, 1, 640, 512, id="latent-n8-T512-S512"),
+    pytest.param(8, 256, 256, 12, 8, 2, DH, None, id="tensor4-shard-8q2kv"),
+    pytest.param(4, 128, 64, 12, 12, 4, DH, None, id="group-3-12q4kv"),
+])
+def test_paged_attention_on_the_flat_axis_compiles(chip, n, t, s, maxb, hq, kvh, dh, dv):
+    """ISSUE 40: q as a compacted pass holds it, ``[S, H, Dh]``, at the shapes the
+    five serving configurations meet: the window of a sequence's rows begins at
+    an element offset read from the plan, which the compiler must see to be whole
+    sublane tiles; one kernel, and nothing of the padded ``[N, T]`` size beside it
+    (the temporaries are q and the output laid KV-major at the flat size, twice)."""
+    from deepspeed_tpu.ops.attention.paged import flat_token_slots, paged_attention_flat, step_tile
+
+    def fn(q, k, v, tables, lengths, start, n_tok):
+        return paged_attention_flat(q, k, None if dv else v, tables, lengths, start, n_tok, chunk=t,
+                                    block_size=128, window=None if dv else WINDOW,
+                                    softmax_scale=0.1147 if dv else None, value_dim=dv)
+
+    _, k, v, *ints = paged_avals(chip, n, t, 128, maxb, dh=dh, hq=hq, kvh=kvh)
+    compiled = jax.jit(fn).lower(chip((s, hq, dh), jnp.bfloat16), k, v, *ints).compile()
+    assert kernel_calls(compiled.as_text()) == {"paged_attention": 1}
+    rows = step_tile(t, hq, kvh, dh, 128, jnp.bfloat16, jnp.bfloat16, dv)[1]
+    held = flat_token_slots(n, s, hq // kvh) + -(-rows // (hq // kvh))
+    flat = held * hq * (dh + (dv or dh)) * 2  # q and the output on the kernel's row axis
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * flat + (1 << 20)
+    assert 4 * flat < n * t * hq * (dh + (dv or dh)) * 2 or n * t <= 4 * held
+
+
 @pytest.mark.parametrize("hq,kvh", [(64, 8), (64, 1), (64, 64), (32, 8), (16, 16), (8, 2), (12, 4)])
 def test_a_grid_steps_vector_memory_stays_under_the_budget(hq, kvh):
     """``step_tile`` reckons a step's VMEM from the static shapes and picks the
