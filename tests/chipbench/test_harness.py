@@ -14,8 +14,8 @@ import pytest
 
 from chipbench import common
 from chipbench.generators.waves import Traffic, quantile_lengths
+from tests.chipbench.conftest import REPO, ROOT
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -35,7 +35,7 @@ def reports(metric, cell):
 def run_command(root, *argv, **env):
     return subprocess.run([sys.executable, os.path.join(root, "chipbench", "run.py"), *argv],
                           capture_output=True, text=True, timeout=900,
-                          env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT, **env})
+                          env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO, **env})
 
 
 # ---------------------------------------------------------------- the command
@@ -70,6 +70,7 @@ def test_unknown_workload_is_refused():
 
 
 # ------------------------------------------------------------ BENCHMARK.json
+@pytest.mark.reads_benchmark
 def test_top_level_form():
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads",
                           "end_to_end", "per_layer"}
@@ -81,6 +82,7 @@ def test_top_level_form():
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
 
 
+@pytest.mark.reads_benchmark
 def test_names_and_units_hold_only_what_the_driver_takes():
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
         names = [e["name"] for e in BENCH[group]]
@@ -102,6 +104,7 @@ def test_names_and_units_hold_only_what_the_driver_takes():
     assert len(pairs) == len(set(pairs))
 
 
+@pytest.mark.reads_benchmark
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_every_cell_resolves_to_files_that_exist(cell):
     w = CELLS[cell]
@@ -127,6 +130,7 @@ DEPTH = re.compile(r"^(num|n)_(hidden_)?layers?$")
 SHARE = re.compile(r"^((num|n)_[a-z_]*(experts|heads|groups)|vocab_size)$")
 
 
+@pytest.mark.reads_benchmark
 @pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
 def test_every_configuration_is_used_and_widths_are_published(config):
     entry = next(c for c in BENCH["configs"] if c["name"] == config)
@@ -143,6 +147,7 @@ def test_every_configuration_is_used_and_widths_are_published(config):
         assert isinstance(spec[key], int) and 0 < spec[key] < published["config"][key], key
 
 
+@pytest.mark.reads_benchmark
 @pytest.mark.parametrize("metric", [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]])
 def test_every_metric_has_its_file_and_its_reader(metric):
     entry = next(m for m in BENCH["end_to_end"] + BENCH["per_layer"] if m["name"] == metric)
@@ -153,6 +158,7 @@ def test_every_metric_has_its_file_and_its_reader(metric):
     assert callable(reader.read)
 
 
+@pytest.mark.reads_benchmark
 @pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
 def test_a_per_layer_metric_moves_a_metric_its_cells_report(metric):
     entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
@@ -187,6 +193,7 @@ def test_the_order_of_a_wave_is_the_traffic_files_own(mix):
         Traffic({k: v for k, v in params.items() if k != "order_seed"}, 1, 32000)
 
 
+@pytest.mark.reads_benchmark
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]
                                   if data("configs", w["config"] + ".json")["entry"] == "serve"])
 def test_the_pool_holds_what_each_wave_asks_for(cell):
@@ -310,3 +317,52 @@ def test_a_new_cell_config_mix_and_metric_are_found_without_an_edit(tmp_path):
              for dp, _, files in os.walk(os.path.join(root, "chipbench"))
              for p in files if "__pycache__" not in dp and os.sep + "out" not in dp}
     assert all(after[p] == before[p] for p in before if p in after)
+
+
+def test_the_tests_take_a_cell_and_a_metric_without_an_edit(tmp_path):
+    """What the test above proves of the harness, proved of the tests: a copy
+    of the benchmark grown by an eighth cell (on the configuration of
+    ``serve.chat-burst`` and a copy of its traffic file under another name) and
+    by one per-layer entry after the last, then every ``reads_benchmark`` test
+    of this directory over that copy (``CHIPBENCH_ROOT``), no file edited.  A
+    test that finds a cell or a metric by its place, or counts its kind, fails
+    here and not in the PR that brings the next model."""
+    root = str(tmp_path / "copy")
+    shutil.copytree(os.path.join(ROOT, "chipbench"), os.path.join(root, "chipbench"),
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    bench = json.loads(json.dumps(BENCH))
+    like = CELLS["serve.chat-burst"]
+    shutil.copy(os.path.join(root, "chipbench", "traffic", like["traffic"] + ".json"),
+                os.path.join(root, "chipbench", "traffic", "guard-mix.json"))
+    bench["workloads"].append({"name": "serve.guard-cell", "config": like["config"],
+                               "traffic": "guard-mix", "chips": 1, "why": "a test"})
+    for m in bench["end_to_end"]:
+        if m["name"] in ("serve_tok_s", "ttft_p95_ms"):
+            m["workloads"].append("serve.guard-cell")
+    # no list of cells: read wherever serve_tok_s is reported, the new cell among them
+    layer = next(m["layer"] for m in BENCH["per_layer"] if m["name"] == "serve.host_syncs_per_tok")
+    metric = {"name": "serve.guard_metric", "unit": "count", "better": "lower",
+              "source": "program_counter", "layer": layer, "moves": "serve_tok_s"}
+    bench["per_layer"].append(metric)
+    for path, text in (("metrics/serve.guard_metric.json", json.dumps({**metric, "reader": "guard"})),
+                       ("readers/guard.py", "def read(run):\n    return None\n")):
+        with open(os.path.join(root, "chipbench", path), "w") as f:
+            f.write(text)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    env = {k: v for k, v in os.environ.items() if not k.startswith("PYTEST_")}
+    done = subprocess.run(
+        [sys.executable, "-m", "pytest", os.path.join(REPO, "tests", "chipbench"), "-m",
+         "reads_benchmark", "-v", "-p", "no:cacheprovider"], cwd=REPO, capture_output=True,
+        text=True, timeout=120, env={**env, "JAX_PLATFORMS": "cpu", "CHIPBENCH_ROOT": root})
+    assert done.returncode == 0, done.stdout[-4000:] + done.stderr[-2000:]
+    passed = [l.split(" ")[0].split("::")[1] for l in done.stdout.splitlines() if " PASSED" in l]
+    for test in ("test_every_cell_resolves_to_files_that_exist[serve.guard-cell]",
+                 "test_the_pool_holds_what_each_wave_asks_for[serve.guard-cell]",
+                 "test_every_metric_has_its_file_and_its_reader[serve.guard_metric]",
+                 "test_a_per_layer_metric_moves_a_metric_its_cells_report[serve.guard_metric]",
+                 "test_the_metric_file_and_the_benchmarks_entry_agree",  # the two that pinned it
+                 "test_the_metric_files_make_entries_the_benchmark_can_take",
+                 "test_the_borrowed_readers_are_right_for_this_cell_and_the_others_are_not"):
+        assert test in passed, test  # the copy was the root, and the new entries were walked

@@ -1,11 +1,9 @@
 """The five ``setup.*`` readers (PR 36) over a hand-built set-up account and
 run: the cut at the window's start, outermost rows only, a miss among hits, an
 engine's construction less JAX's stages inside it, nothing without an origin;
-and their metric files, which ``BENCHMARK.json`` does not list yet
-(``test_readers_slots_per_step.py`` holds PR 35's entry to be the LAST of
-``per_layer``, and only a ``benchmark`` PR may edit that file): the entries a
-``benchmark`` PR appends are built here from the metric files and driven
-through the harness in a copy."""
+and their metric files beside the entries that ``BENCHMARK.json`` lists since
+PR 42: each entry is its metric file's own fields, and the harness reads them
+through a copy of the committed files."""
 
 import json
 import os
@@ -21,8 +19,8 @@ from chipbench.readers import (setup_engine_init_s, setup_load_s, setup_lower_s,
 from chipbench.reduce import setup_account
 from deepspeed_tpu.monitor import compile_events
 from deepspeed_tpu.monitor.compile_events import ENGINE_INIT, LOAD, LOWER, TRACE, Account
+from tests.chipbench.conftest import REPO, ROOT, SERVING_THEN
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 T0 = 1000.0  # the process's start on the account's clock
 HIT = "/jax/compilation_cache/cache_hits"
 MISS = None  # a load the cache did not answer: no hit fires inside it
@@ -148,58 +146,51 @@ def test_spans_are_covered_once():
     assert setup_account.covered([]) == 0.0
 
 
-def entries():
-    """What a ``benchmark`` PR appends to ``per_layer``: each metric file's
-    own fields, reported in every cell (every cell has a set-up)."""
+@pytest.mark.reads_benchmark
+def test_the_metric_files_make_entries_the_benchmark_can_take():
+    """Each listed entry is its metric file's own fields, in the cells it was
+    accepted with (every cell has a set-up); a cell added later may join."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    found = []
+    listed = {m["name"]: m for m in bench["per_layer"]}
+    cells = {w["name"] for w in bench["workloads"]}
+    assert set(NAMES) <= set(listed)
     for name in NAMES:
         with open(os.path.join(ROOT, "chipbench", "metrics", name + ".json")) as f:
             metric = json.load(f)
         assert metric["name"] == name and metric["reader"] == name.replace(".", "_")
-        found.append({**{k: metric[k] for k in ("name", "unit", "better", "source", "layer", "moves")},
-                      "workloads": [w["name"] for w in bench["workloads"]]})
-    return bench, found
-
-
-def test_the_metric_files_make_entries_the_benchmark_can_take():
-    bench, found = entries()
-    listed = {m["name"] for m in bench["per_layer"]}
-    assert not listed & set(NAMES)  # withheld: appending them fails the test named above
-    for entry in found:
+        entry = dict(listed[name])
+        assert SERVING_THEN | {"train.zero3-fsdp4"} <= set(entry.pop("workloads")) <= cells
+        assert entry == {k: metric[k] for k in ("name", "unit", "better", "source", "layer", "moves")}
         assert entry["moves"] == "setup_s" and entry["better"] == "lower"
-        assert entry["layer"] == found[0]["layer"] and entry["layer"].startswith("set-up (")
-        assert len(entry["layer"]) <= 200 and entry["layer"] not in {m["layer"] for m in bench["per_layer"]}
+        assert entry["layer"] == listed[NAMES[0]]["layer"] and entry["layer"].startswith("set-up (")
+        assert len(entry["layer"]) <= 200
         assert (entry["source"], entry["unit"]) == (
-            ("program_counter", "count") if entry["name"] == "setup.programs"
-            else ("program_span", "s"))
+            ("program_counter", "count") if name == "setup.programs" else ("program_span", "s"))
     setup_s = next(m for m in bench["end_to_end"] if m["name"] == "setup_s")
-    assert "workloads" not in setup_s  # every cell reports it, so every cell lists the five
+    assert "workloads" not in setup_s  # every cell reports it, so every cell can list the five
 
 
-def test_appended_to_a_copy_the_harness_reads_them_with_no_file_edited(tmp_path):
+def test_in_a_copy_of_the_committed_files_the_harness_reads_them(tmp_path):
     """The command as ``__main__`` (where the readers find ``T_START``), the
     train cell traced at its rehearsal size: the count reads, the seconds do
     not (a time comes only from a chip run), ``would_be_correct`` as before."""
     root = str(tmp_path / "copy")
     shutil.copytree(os.path.join(ROOT, "chipbench"), os.path.join(root, "chipbench"),
                     ignore=shutil.ignore_patterns("out", "__pycache__"))
-    bench, found = entries()
-    bench["per_layer"] += found
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
     done = subprocess.run(
         [sys.executable, os.path.join(root, "chipbench", "run.py"), "--workload",
          "train.zero3-fsdp4", "--seed", str(2 ** 31 + 36), "--seconds", "0", "--trace", "1",
          "--rehearse"], capture_output=True, text=True, timeout=900,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT})
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO})
     assert done.returncode == 3, done.stderr[-2000:]
     line = [l for l in done.stdout.splitlines() if l.startswith("[rehearsal-not-a-result] ")][-1]
     result = json.loads(line.split(" ", 1)[1])
     assert result["would_be_correct"] is True
-    assert set(result["metrics"]) == {"setup.programs"}  # this cell's one counter metric
+    assert set(NAMES) & set(result["metrics"]) == {"setup.programs"}  # the one counter of the five
     assert result["metrics"]["setup.programs"]["value"] >= 2  # train_step and make_state at least
-    printed = [l for l in done.stdout.splitlines() if l.startswith("[metric] name=setup.")]
-    assert len(printed) == 5 and sum("nothing to read" in l for l in printed) == 4
-    assert "in_window=0" in next(l for l in printed if "setup.programs" in l)
+    printed = {name: next(l for l in done.stdout.splitlines() if l.startswith(f"[metric] name={name} "))
+               for name in NAMES}
+    assert all(("nothing to read" in l) == (name != "setup.programs") for name, l in printed.items())
+    assert "in_window=0" in printed["setup.programs"]

@@ -8,8 +8,7 @@ import types
 import pytest
 
 from chipbench.readers import slots_per_step
-
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from tests.chipbench.conftest import ROOT, SERVING_THEN
 
 
 def serve_run(**fields):
@@ -39,15 +38,16 @@ def test_a_program_without_the_counter_is_nothing_to_read(counters):
                                                                  "kernel_steps": 1})) is None
 
 
+@pytest.mark.reads_benchmark
 def test_the_metric_file_and_the_benchmarks_entry_agree():
     with open(os.path.join(ROOT, "chipbench", "metrics", "paged.slots_per_step.json")) as f:
         metric = json.load(f)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]  # appended, nothing before it moved
-    assert entry["name"] == metric["name"] == "paged.slots_per_step"
+    entry = next(e for e in bench["per_layer"] if e["name"] == "paged.slots_per_step")
+    assert metric["name"] == entry["name"]
     assert all(entry[k] == metric[k] for k in ("unit", "better", "source", "layer", "moves"))
     assert entry["moves"] == "serve_tok_s" and entry["layer"].startswith("kernels")
-    serving = [w["name"] for w in bench["workloads"] if w["name"].startswith("serve.")]
-    assert entry["workloads"] == serving and len(serving) == 6
+    serving = {w["name"] for w in bench["workloads"] if w["name"].startswith("serve.")}
+    assert SERVING_THEN <= set(entry["workloads"]) <= serving  # a new serving cell may join or not
     assert metric["reader"] == "slots_per_step" and "kernel_steps" in metric["what"]
