@@ -23,7 +23,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from ...compat import shard_map
-from ...models.transformer import flat_slots, paged_step_slots
+from ...models.transformer import STATE, flat_slots, paged_step_slots
 from ...monitor import compile_events
 from ...monitor.perf import PHASES, CompileLedger, StepPhaseProfiler
 from ...monitor.tracing import RequestTracer
@@ -96,8 +96,15 @@ class InferenceEngineV2:
                  max_seqs_per_step: int = 32,
                  topology: Optional[MeshTopology] = None,
                  telemetry=None, clock: Optional[Callable[[], float]] = None,
-                 journal: Optional[RequestJournal] = None):
+                 journal: Optional[RequestJournal] = None,
+                 table_step: Optional[int] = None):
         self.config = load_inference_config(config)
+        if table_step is not None:
+            # the table's width grows in steps of this many slots (class default 4): every width
+            # is a program of its own, so an engine whose sequences span a hundred blocks and
+            # more (ISSUE 43: 16k-token prompts, 132 slots) takes coarser steps and compiles a
+            # handful of widths, at the price of up to ``table_step - 1`` dead slots a row
+            self.TABLE_STEP = int(table_step)
         self.model = model_module
         self.model_config = model_config
         self.dtype = _DTYPES[self.config.dtype]
@@ -282,13 +289,13 @@ class InferenceEngineV2:
             token_budget if self.fastpath.enabled else None)
         kernel_slots, attn_slots = paged_step_slots(model_module, model_config, kv, self.dtype,
                                                     self.tp)
+        counted = dict(kernel_slots=kernel_slots, attn_slots=attn_slots)
         if hasattr(model_module, "moe_expert_rows"):  # a mixture of experts counts its rows
-            self.counters = ServeCounters(
-                moe_picks=model_module.moe_picks_per_token(model_config),
-                moe_rows=functools.partial(model_module.moe_expert_rows, model_config),
-                kernel_slots=kernel_slots, attn_slots=attn_slots)
-        else:
-            self.counters = ServeCounters(kernel_slots=kernel_slots, attn_slots=attn_slots)
+            counted.update(moe_picks=model_module.moe_picks_per_token(model_config),
+                           moe_rows=functools.partial(model_module.moe_expert_rows, model_config))
+        if hasattr(model_module, "state_scan"):  # a state scanned in chunks counts them
+            counted.update(scan=model_module.state_scan(model_config))
+        self.counters = ServeCounters(**counted)
         # serving performance observatory (ISSUE 16): the compile ledger is
         # always on (no clock reads, no device work) and is the single source
         # of truth behind counters.compiles; the slot counters (ISSUE 24) are
@@ -2309,16 +2316,22 @@ class InferenceEngineV2:
     def _state_snapshot(self) -> Dict[str, Any]:
         """The fixed per-sequence state beside the pool (ISSUE 33): slots and
         bytes, hand-outs (each starts a sequence from the zero state), prefix
-        hits declined because mapped blocks would not restore it."""
+        hits declined because mapped blocks would not restore it.  Where the
+        state is a tree of named leaves (ISSUE 43), what a sequence holds in
+        each, from the arrays themselves."""
         m = self.manager
         if not m.state_slots:
             return {"enabled": False}
-        return {"enabled": True, "state_slots": m.state_slots,
+        snap = {"enabled": True, "state_slots": m.state_slots,
                 "state_slots_in_use": m.state_slots_in_use,
                 "state_bytes_per_seq": self.state_bytes_per_seq,
                 "state_slots_zeroed": m.state_slots_zeroed,
                 "prefix_declined_stateful": (m.prefix_cache.declined_stateful_total
                                              if m.prefix_cache is not None else 0)}
+        if isinstance(self.kv[STATE], dict):
+            snap["state_bytes_by_leaf"] = {name: int(leaf.nbytes // leaf.shape[1])
+                                           for name, leaf in self.kv[STATE].items()}
+        return snap
 
     def _kv_snapshot(self, with_table: bool = False) -> Dict[str, Any]:
         """The ``health()["kv"]`` / ``state_snapshot()["kv"]`` payload:

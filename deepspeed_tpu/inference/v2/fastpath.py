@@ -103,6 +103,14 @@ class ServeCounters:
     the launched programs' static shapes: a pass's token slots x k, rounded up
     to whole row tiles, in every layer
     ``moe_routed_rows``  of those, the rows a live token was routed to
+
+    A family whose state is a recurrence scanned over a step's tokens in chunks
+    (ISSUE 43; the model module states ``state_scan``; zero for every other):
+    ``scan_chunks``  chunks the scans of the launched programs walked, from their
+    static shapes (every sequence of a compacted pass begins on a chunk's edge;
+    a step of one token a row walks none), in every such layer
+    ``scan_positions``  the token positions of those chunks
+    ``scan_live_positions``  of those, the positions that held a live token
     """
 
     FIELDS = ("host_syncs", "dispatches", "uploads", "upload_ints", "compiles",
@@ -110,15 +118,17 @@ class ServeCounters:
               "spec_rounds", "spec_proposed", "spec_accepted",
               "token_slots", "live_tokens", "table_slots", "live_blocks",
               "compact_passes", "moe_routed_rows", "moe_expert_rows", "kernel_steps",
-              "attn_token_slots")
+              "attn_token_slots", "scan_chunks", "scan_positions", "scan_live_positions")
 
     def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None,
                  kernel_slots: Callable[[int], int] = lambda t: 1,
-                 attn_slots: Callable[[int, int], int] = lambda n, flat: flat):
+                 attn_slots: Callable[[int, int], int] = lambda n, flat: flat,
+                 scan: Optional[tuple] = None):
         for f in self.FIELDS:
             setattr(self, f, 0)
         self.moe_picks, self.moe_rows, self.kernel_slots = moe_picks, moe_rows, kernel_slots
         self.attn_slots = attn_slots
+        self.scan = scan  # (chunks(n, t, flat), positions a chunk, layers that scan)
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
                     live_blocks: int, passes: int = 1,
@@ -137,6 +147,13 @@ class ServeCounters:
         if self.moe_rows is not None:
             self.moe_expert_rows += self.moe_rows(slots) * passes
             self.moe_routed_rows += live_tokens * self.moe_picks
+        if self.scan is not None:
+            chunks_of, width, layers = self.scan
+            chunks = chunks_of(n, t, flat) * passes
+            if chunks:
+                self.scan_chunks += chunks
+                self.scan_positions += chunks * width
+                self.scan_live_positions += live_tokens * layers
         self.table_slots += n * b * passes
         self.kernel_steps += n * -(-b // self.kernel_slots(t)) * passes
         self.live_blocks += live_blocks * passes

@@ -9,10 +9,10 @@ not attention.  One block is ``h = x + Op(rms(x))``, ``y = h + FFN(rms(h))``:
   X``, a depth-wise causal filter of ``conv_L_cache`` taps over ``z`` along the
   sequence, ``Op = (C * conv) W_out``.  What a sequence must remember is the
   last ``conv_L_cache - 1`` values of ``z``: a FIXED state a sequence, whatever
-  its length.  It lives beside the paged pool (``kv_cache[STATE]``, one slot a
-  live sequence); ``transformer.paged_forward`` hands this module the shift
-  that is local to a sequence (``taps``) and writes the slot back.  Nothing
-  here knows where a step's tokens lie.
+  its length.  It lives beside the paged pool (``kv_cache[STATE]``, one leaf,
+  one slot a live sequence); ``transformer.paged_forward`` states the contract
+  of such layers (``mix``, ``taps``, the carried leaves) and nothing here knows
+  where a step's tokens lie.
 - **Attention** (``full_attention``): GQA with an RMSNorm over each head of q
   and of k before rotate-half rotary, no window, over the paged pool.  Heads
   are 64 wide, half a lane tile: ``pack`` KV heads share one 128-wide row of
@@ -123,19 +123,8 @@ def layer_segments(config: Lfm2Config):
     of one).  A layer's kind is its mixer and whether its FFN is dense, so a
     run never crosses from the dense layers into the expert layers.  Published:
     ``[(0, 1, 2), (2, 4, 9), (38, 1, 1), (39, 1, 1)]``."""
-    kinds = [(kind, i < config.num_dense_layers) for i, kind in enumerate(config.layer_types)]
-    out, at = [], 0
-    while at < len(kinds):
-        best = (1, 1)
-        for period in range(1, (len(kinds) - at) // 2 + 1):
-            repeats = 1
-            while kinds[at + repeats * period:at + (repeats + 1) * period] == kinds[at:at + period]:
-                repeats += 1
-            if repeats > 1 and period * repeats > best[0] * best[1]:
-                best = (period, repeats)
-        out.append((at, *best))
-        at += best[0] * best[1]
-    return out
+    return transformer.repeating_runs(
+        [(kind, i < config.num_dense_layers) for i, kind in enumerate(config.layer_types)])
 
 
 def init_params(config: Lfm2Config, key, dtype=jnp.float32):
@@ -277,7 +266,7 @@ def forward_paged(config: Lfm2Config, params, tokens, n_tokens, start_pos, block
     def embed(tokens, safe_pos):
         return params["embed"][tokens].astype(dtype)
 
-    def mix(lp, x, taps, live):
+    def mix(lp, x, taps, live, kept, places):
         m = lp[STATE_MIXER]
         u = rms_norm(x, lp["op_norm"], config.norm_eps)
         with jax.named_scope("conv_mixer"):
@@ -285,10 +274,11 @@ def forward_paged(config: Lfm2Config, params, tokens, n_tokens, start_pos, block
             z = b * xs
             w = m["filter"].astype(dtype)  # [taps, D]: the last weighs z_t itself
             conv = w[-1] * z
-            for tap, earlier in zip(w[:-1], taps(z)):
+            before, last = taps(z, kept)  # the one leaf of the state is this shift's
+            for tap, earlier in zip(w[:-1], before):
                 conv = conv + tap * earlier
             x = x + (c * conv) @ m["w_out"].astype(dtype)
-        return block_ffn(lp, x, live)
+        return block_ffn(lp, x, live), last
 
     def qkv(lp, x, safe_pos):
         a = lp["attn"]

@@ -16,7 +16,7 @@ DistributedAttention wrapping "any local attention" (deepspeed/sequence/layer.py
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -438,6 +438,37 @@ STATE = "state"  # the key of a family's cache tree that holds its fixed per-seq
 STATE_MIXER = "mixer"  # a layer whose parameters hold this key has no attention: ``mix`` runs it
 
 
+class SeqPlaces(NamedTuple):
+    """Where a step's sequences lie on the ``[b, s]`` axes a mixer is handed:
+    ``n_tokens`` ``[N]`` live tokens a row; ``row`` / ``col`` None for the padded
+    ``[N, T]`` (row ``r`` holds sequence ``r`` from column 0) or ``[1, S]`` for
+    the compacted layout (flat slot ``j`` holds token ``col[j]`` of row
+    ``row[j]``'s chunk, the rows one after another)."""
+    n_tokens: jax.Array
+    row: Optional[jax.Array]
+    col: Optional[jax.Array]
+
+
+def repeating_runs(kinds):
+    """``[(start, period, repeats)]``: a list of layer kinds as runs that repeat
+    a pattern of ``period`` kinds ``repeats`` times, greedily the longest run
+    from each start (a run must repeat at least twice; a layer that starts none
+    is a run of one).  A run is one scan of :func:`paged_forward` whose body is
+    the pattern (a stack that is a tuple of stacks)."""
+    out, at = [], 0
+    while at < len(kinds):
+        best = (1, 1)
+        for period in range(1, (len(kinds) - at) // 2 + 1):
+            repeats = 1
+            while kinds[at + repeats * period:at + (repeats + 1) * period] == kinds[at:at + period]:
+                repeats += 1
+            if repeats > 1 and period * repeats > best[0] * best[1]:
+                best = (period, repeats)
+        out.append((at, *best))
+        at += best[0] * best[1]
+    return out
+
+
 def tp_psum(tp_axis: Optional[str]):
     """What a family's ``finish`` does with a row-parallel partial: the psum
     over ``tp_axis`` inside shard_map, nothing on one chip."""
@@ -499,24 +530,40 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     conv): the scan runs over the periods and its body runs the period's
     layers in order, so layers of several kinds share one scan.
 
-    **Layers without attention** (``STATE_MIXER`` among ``lp``'s keys; LFM2's
-    gated short convolutions).  Such a layer touches neither the pool nor the
-    write plan nor the kernel: ``mix(lp, x, taps, live) -> x`` is the whole
-    layer, and what it remembers of a sequence's past is a fixed state a
-    SEQUENCE, not rows a token: ``kv_cache[STATE]``, one array ``[Ls, slots +
-    1, k, D]`` (``Ls`` such layers; the last slot is the trash slot of a dead
-    row) beside the pool's leaves, carried through the scans as they are and
-    written in place.  The engine names each row's slot in one further,
-    trailing column of ``block_tables``.  ``taps(z) -> [z_{t-k}, ..., z_{t-1}]``
-    is the shift that is local to a sequence, which only this function can
-    give, since it alone knows where a step's tokens lie: for ``z`` ``[b, s,
-    D]`` in either layout it returns the ``k`` earlier values of every token's
-    own sequence, from the chunk itself where the chunk has them and from the
-    row's slot where it does not (a chunk's first ``k`` tokens; zeros where
-    ``start_pos == 0``: a slot is never zeroed in memory, a sequence that
-    starts over simply does not read it), and the chunk's last ``k`` values
-    go back to the slot (a chunk of one token shifts the state).  The pool's
-    row is counted over the attention layers alone, the state's over the rest.
+    **Layers without attention** (``STATE_MIXER`` among ``lp``'s keys: LFM2's
+    gated short convolutions, Qwen3-Next's Gated DeltaNet).  Such a layer
+    touches neither the pool nor the write plan nor the kernel: ``mix(lp, x,
+    taps, live, carried, places) -> (x, carried)`` is the whole layer, and what
+    it remembers of a sequence's past is a fixed state a SEQUENCE, not rows a
+    token: ``kv_cache[STATE]``, a TREE OF LEAVES ``[Ls, slots + 1, ...]`` (one
+    array, or a dict of arrays of any trailing shapes and dtypes: a shift's
+    last values ``[.., k, D]``, a recurrence's matrix a head ``[.., H, dk,
+    dv]`` in float32; ``Ls`` such layers; the last slot is the trash slot of a
+    dead row) beside the pool's leaves, carried through the scans as they are
+    and written in place.  The engine names each row's slot in one further,
+    trailing column of ``block_tables``.  ``carried`` is the same tree with
+    every leaf ``[N, ...]``: the rows' own slots of this layer, zeros where
+    ``start_pos == 0`` (a slot is never zeroed in memory: a sequence that starts
+    over simply does not read it); what ``mix`` returns in its place goes back
+    to the rows' slots, a dead row's to the trash slot.  What only this function
+    can give, since it alone knows where a step's tokens lie, comes beside it:
+
+    - ``taps(z, kept) -> ([z_{t-k}, ..., z_{t-1}], last)`` for a leaf that is a
+      shift: for ``z`` ``[b, s, D]`` in either layout and ``kept`` ``[N, k, D]``
+      (the carried leaf) the ``k`` earlier values of every token's own
+      sequence, from the chunk itself where the chunk has them and from
+      ``kept`` where it does not (a chunk's first ``k`` tokens), and ``last``
+      ``[N, k, D]``, the leaf's new value: the chunk's last ``k`` values (a
+      chunk of one token shifts it; :func:`sequence_taps`);
+    - ``places`` (:class:`SeqPlaces`) for a leaf that is a recurrence over the
+      step's tokens: ``n_tokens`` and, compacted, whose token each flat slot
+      holds, so that the family's scan can walk each sequence from its own
+      carried matrix (several sequences a pass, each continuing from its slot;
+      a decode row is a chunk of one token; a fused burst carries the state in
+      its loop, as it does the pool).
+
+    The pool's row is counted over the attention layers alone, the state's over
+    the rest.
 
     ``kv_cache`` is whatever tree of ``[L, NB, KV, bs, width]`` leaves the
     family's ``init_paged_cache`` made (``{"k", "v"}``; one latent leaf for
@@ -566,12 +613,13 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     from ..ops.attention.paged import paged_attention, paged_attention_flat
 
     n, t = tokens.shape
-    state = None
+    state_leaves = []
     if isinstance(kv_cache, dict) and STATE in kv_cache:
         kv_cache = dict(kv_cache)
-        state = kv_cache.pop(STATE)
+        state_leaves, state_tree = jax.tree_util.tree_flatten(kv_cache.pop(STATE))
+        state_slots = state_leaves[0].shape[1]
         # the rows' state slots ride as the table's last column; a dead row's is the trash slot
-        seq_slot = jnp.where(n_tokens > 0, block_tables[:, -1], state.shape[1] - 1)
+        seq_slot = jnp.where(n_tokens > 0, block_tables[:, -1], state_slots - 1)
         block_tables = block_tables[:, :-1]
     pool_leaves, pool_tree = jax.tree_util.tree_flatten(kv_cache)
     pool_shape = pool_leaves[0].shape
@@ -619,31 +667,26 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                                         start_pos, n_tokens, chunk=t, **facts)[None]
         return finish(lp, x, kept, attn, live), pools
 
-    def mixer_layer(x, flat_state, lp, l):
-        """A layer whose past is its sequences' slots ``[N, k, D]`` of the flat state."""
-        if state is None or mix is None:
+    def mixer_layer(x, flat_states, lp, l):
+        """A layer whose past is its sequences' slots ``[N, ...]`` of each flat state leaf."""
+        if not state_leaves or mix is None:
             raise ValueError(f"a layer holds {STATE_MIXER!r} and the family gave no "
                              f"{'mix' if mix is None else 'kv_cache[STATE]'}")
-        at = l * state.shape[1] + seq_slot
-        with jax.named_scope("conv_state"):
-            kept = jnp.where((start_pos > 0)[:, None, None], flat_state[at], 0)
-        tail = []
-
-        def taps(z):
-            earlier, last = sequence_taps(z, kept, n_tokens, row, col)
-            tail.append(last)
-            return earlier
-
-        x = mix(lp, x, taps, live)
-        with jax.named_scope("conv_state"):
-            (last, ) = tail  # one shift a layer
-            return x, flat_state.at[at].set(last.astype(flat_state.dtype))
+        at = l * state_slots + seq_slot
+        with jax.named_scope("seq_state"):
+            kept = [jnp.where((start_pos > 0).reshape((-1, ) + (1, ) * (leaf.ndim - 1)), leaf[at], 0)
+                    for leaf in flat_states]
+        x, carried = mix(lp, x, taps, live, jax.tree_util.tree_unflatten(state_tree, kept), places)
+        with jax.named_scope("seq_state"):
+            return x, [leaf.at[at].set(new.astype(leaf.dtype)) for leaf, new in zip(
+                flat_states, state_tree.flatten_up_to(carried))]
 
     # The pool is carried, never sliced (xs) and restacked (ys): a scan's ys is
     # a new [L, ...] array that cannot alias a donated argument still being
     # read, which cost a slice, an update and a copy of the whole pool a pass.
-    carry = (x, *flat_pools) if state is None else (
-        x, *flat_pools, state.reshape((-1, ) + state.shape[2:]))
+    places = SeqPlaces(n_tokens, row, col)
+    taps = lambda z, kept: sequence_taps(z, kept, *places)
+    carry = (x, *flat_pools, *(leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in state_leaves))
     done = {False: 0, True: 0}  # attention / mixer layers behind the stack being scanned
     for stack in layers if isinstance(layers, list) else [layers]:
         period = stack if isinstance(stack, tuple) else (stack, )
@@ -662,7 +705,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                 behind = mixes[:j].count(is_mix)  # layers of its kind before it in the period
                 l = first[is_mix] + behind if behind else first[is_mix]
                 if is_mix:
-                    x, pools[-1] = mixer_layer(x, pools[-1], lp, l)
+                    x, pools[len(flat_pools):] = mixer_layer(x, pools[len(flat_pools):], lp, l)
                 else:
                     x, pools[:len(flat_pools)] = attention_layer(x, pools[:len(flat_pools)], lp, l)
             return (x, *pools), None
@@ -673,8 +716,9 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     x, *pools = carry
     cache = jax.tree_util.tree_unflatten(
         pool_tree, [pool.reshape(leaf.shape) for pool, leaf in zip(pools, pool_leaves)])
-    if state is not None:
-        cache[STATE] = pools[-1].reshape(state.shape)
+    if state_leaves:
+        cache[STATE] = jax.tree_util.tree_unflatten(state_tree, [
+            flat.reshape(leaf.shape) for flat, leaf in zip(pools[len(flat_pools):], state_leaves)])
     return to_padded(head(x)), cache
 
 

@@ -129,7 +129,9 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     a family has it, is the expert every token takes: a dense SwiGLU added to
     the routed part.  ``n_group``, ``topk_group``, ``scaling``, ``scoring`` and
     ``norm_eps`` are :func:`route`'s, and so is ``moe_params["gate"]["bias"]``
-    (``[E]``), the selection bias of a family that stores one."""
+    (``[E]``), the selection bias of a family that stores one.
+    ``moe_params["shared_gate"]`` (``[D, 1]``), where a family has it, scales the
+    shared expert's output by ``sigmoid(x w_g)``, one gate a token (Qwen3-Next)."""
     ex = moe_params["experts"]
     if layer is None:
         ex, layer = jax.tree_util.tree_map(lambda w: w[None], ex), 0
@@ -167,5 +169,11 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
         shared = {name: w.astype(x.dtype) for name, w in moe_params["shared"].items()}
         with jax.named_scope("moe_shared_expert"):
             hidden = jax.nn.silu(x @ shared["w_gate"]) * (x @ shared["w_up"])
-            out = out + (hidden @ shared["w_down"]).astype(jnp.float32)
+            added = (hidden @ shared["w_down"]).astype(jnp.float32)
+            if "shared_gate" in moe_params:
+                with jax.named_scope("moe_shared_gate"):
+                    added = added * jax.nn.sigmoid(jnp.dot(
+                        x, moe_params["shared_gate"].astype(x.dtype),
+                        preferred_element_type=jnp.float32))
+            out = out + added
     return out.astype(x.dtype)

@@ -1,0 +1,311 @@
+"""The gated delta rule (Gated DeltaNet; FLA's ``chunk_gated_delta_rule`` and
+``fused_recurrent_gated_delta_rule`` are the reference's kernels): a linear
+attention whose memory of a sequence is one matrix ``S`` ``[dk, dv]`` a head,
+
+    S <- alpha_t S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;  o_t = S^T q_t
+
+with ``alpha_t = exp(g_t)`` a decay and ``beta_t`` a write strength, both one a
+head a token.  Two forms:
+
+**One token** (:func:`gated_delta_step`): a decode row and a burst's step.  The
+recurrence as written, float32, element-wise over the state.
+
+**A chunked scan** (:func:`gated_delta_scan`): the tokens of a step in chunks of
+``CHUNK`` = 64.  With ``gamma_i`` the chunk's running sum of ``g`` and ``Gamma_ij
+= exp(gamma_i - gamma_j)`` (i >= j), the ``C`` sequential updates of a chunk are
+
+    A  = (I + tril(diag(beta) (Gamma * K K^T), -1))^-1        (unit lower triangular)
+    W  = A (beta * exp(gamma) * K),   U = A (beta * V)
+    V' = U - W S_0                                            ([C, dk] x [dk, dv])
+    O  = (exp(gamma) * Q) S_0 + tril(Gamma * Q K^T) V'        ([C, dk] x [dk, dv])
+    S_1 = exp(gamma_C) S_0 + (exp(gamma_C - gamma) * K)^T V'
+
+and a sequence's chunks are walked in order with ``S`` carried.  The inverse is
+taken without a sequential solve (``_unit_lower_inverse``): the strict lower
+triangle's diagonal blocks of 16 are nilpotent, so each is inverted by ``(I -
+D)(I + D^2)(I + D^4)(I + D^8)``, all four at once as one block-diagonal
+matrix, and the blocks are then joined two by two by the block formula: ten
+``[C, C]`` products.  Accumulations are float32 everywhere; the
+products' operands are in the dtype q, k and v come in (bfloat16 on the chip,
+as FLA's: the state is float32 in memory and rounded where a product reads it),
+but for the inverse's chain, whose float32 operands are split into a bfloat16
+head and tail and multiplied in three passes (about 16 bits).  With float32
+inputs every product is float32.
+
+**Sequences on one axis.**  A step's tokens come as ``[N, T]`` (a row a
+sequence, ``n_tokens`` live) or compacted onto one flat axis ``[1, S]`` (``row``
+/ ``col``: whose token a flat slot holds; ``models/transformer.py
+paged_forward``).  Either way every sequence is laid out to BEGIN ON A CHUNK'S
+EDGE (a gather, outside the kernel): chunk ``c`` belongs to one sequence, the
+first chunk of a sequence loads its carried matrix, the last stores it, and a
+sequence's last chunk is padded with positions that change nothing (k = v = q
+= 0, beta = 0, g = 0).  A flat axis of S slots over N sequences is at most
+``ceil(S / C) + N`` chunks (:func:`scan_chunks`, which the serving counters ask
+too); the chunks past the live ones are skipped.
+
+On the TPU (or with ``_pallas.INTERPRET``) the walk is a Pallas kernel,
+``gdn_scan``: grid (value head, chunk), the chunk table scalar-prefetched, the
+carried state of the chunk's sequence fetched by its ``BlockSpec`` when the
+sequence changes, ``S`` in VMEM scratch between a sequence's chunks, the new
+state stored (aliased onto the old) at a sequence's last chunk.  Key head ``j``
+serves value heads ``j * r .. j * r + r - 1`` through the index map: q and k
+are not repeated in memory.  Off the TPU the same chunk mathematics runs under
+``lax.scan``.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ...compat import CompilerParams
+from .. import _pallas
+
+CHUNK = 64
+SEQ, FIRST, LAST, LIVE = range(4)  # rows of the chunk table
+
+
+def scan_chunks(n: int, t: int, flat=None) -> int:
+    """Chunks the scan walks for a ``[n, t]`` bucket (``flat``: the flat slots
+    it is compacted onto, or None): none for a step of one token a row (the
+    one-token update), a row's ``ceil(t / CHUNK)`` padded, ``ceil(flat / CHUNK)
+    + n`` compacted (every sequence begins on a chunk's edge)."""
+    if flat is not None:
+        return -(-flat // CHUNK) + n
+    return 0 if t == 1 else n * -(-t // CHUNK)
+
+
+def gated_delta_step(q, k, v, g, beta, state):
+    """One token a row.  q, k ``[N, H, dk]``, v ``[N, H, dv]``, g, beta ``[N,
+    H]``, state ``[N, H, dk, dv]`` float32 -> (o ``[N, H, dv]`` float32, state)."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    s = state * jnp.exp(g.astype(jnp.float32))[..., None, None]
+    d = beta.astype(jnp.float32)[..., None] * (v - jnp.sum(s * k[..., None], axis=-2))
+    s = s + k[..., None] * d[..., None, :]
+    return jnp.sum(s * q[..., None], axis=-2), s
+
+
+# ------------------------------------------------------------ one chunk's algebra
+def _dot(a, b, dims, dtype):
+    return jax.lax.dot_general(a.astype(dtype), b.astype(dtype), (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_f32(a, b):
+    """``a @ b`` of float32 ``[C, C]`` matrices to about 16 bits on an MXU that
+    multiplies bfloat16: head x head + head x tail + tail x head."""
+    a_hi, b_hi = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
+    a_lo = (a - a_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    b_lo = (b - b_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    mm = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    return mm(a_hi, b_hi) + mm(a_hi, b_lo) + mm(a_lo, b_hi)
+
+
+def _unit_lower_inverse(n, r, col, mm, leaf: int = 16):
+    """``(I + N)^-1`` of a strictly lower triangular ``N`` ``[C, C]`` (``r``,
+    ``col`` its row and column indices, ``mm`` the product) without a sequential
+    solve and without the growth of one: ``N``'s diagonal blocks of ``leaf``
+    rows, all at once as one block-diagonal matrix ``D`` (``D^leaf = 0``), by
+    ``(I - D)(I + D^2)(I + D^4) ...``, whose partial products stay within the
+    binomials of ``leaf`` and not of ``C`` (over the whole chunk they reach
+    1e17 for keys that resemble one another, and the cancellation that brings
+    them back to the inverse's O(1) entries is lost in float32: my chip run, PR
+    43); then the blocks are joined two by two, ``[[A, 0], [E, B]]^-1 = [[A^-1,
+    0], [-B^-1 E A^-1, B^-1]]``, again for every pair at once: ``P - P E P``
+    with ``E`` the part of ``N`` between the halves of each pair.  Six products
+    for the leaves of 16, two a doubling: ten for a chunk of 64."""
+    c = n.shape[0]
+    same = lambda width: (r // width) == (col // width)
+    diag = jnp.where(same(leaf), n, 0.0)
+    inv, power, reach = jnp.where(r == col, 1.0, 0.0) - diag, diag, 2
+    while reach < leaf:
+        power = mm(power, power)
+        inv = inv + mm(inv, power)
+        reach *= 2
+    width = leaf
+    while width < c:
+        between = jnp.where(same(2 * width) & jnp.logical_not(same(width)), n, 0.0)
+        inv = inv - mm(mm(inv, between), inv)
+        width *= 2
+    return inv
+
+
+def _chunk(q, k, v, gamma, beta, s0):
+    """One chunk of one head.  q, k ``[C, dk]``, v ``[C, dv]`` (their dtype is
+    the products' operand dtype), gamma, beta ``[1, C]`` float32 rows (the
+    running sum of g, the write strengths), s0 ``[dk, dv]`` float32.  Returns
+    (o ``[C, dv]`` float32, s1)."""
+    c, dtype = q.shape[0], q.dtype
+    r = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # a row vector as a column: the diagonal of its broadcast
+    column = lambda row: jnp.sum(jnp.where(r == col, row, 0.0), axis=1, keepdims=True)
+    gamma_c, beta_c = column(gamma), column(beta)
+    decay = jnp.exp(jnp.where(r >= col, gamma_c - gamma, -1e30))  # Gamma, zero above the diagonal
+    nt = (((1, ), (1, )))  # a @ b^T
+    n = jnp.where(r > col, beta_c * decay * _dot(k, k, nt, dtype), 0.0)
+    inv = _unit_lower_inverse(n, r, col, _dot_f32 if dtype != jnp.float32 else functools.partial(
+        jnp.dot, preferred_element_type=jnp.float32))
+    k32, e = k.astype(jnp.float32), jnp.exp(gamma_c)
+    nn = (((1, ), (0, )))  # a @ b
+    w = _dot(inv, beta_c * e * k32, nn, dtype)
+    u = _dot(inv, beta_c * v.astype(jnp.float32), nn, dtype)
+    v_new = u - _dot(w, s0, nn, dtype)
+    within = jnp.where(r >= col, decay * _dot(q, k, nt, dtype), 0.0)
+    o = _dot(e * q.astype(jnp.float32), s0, nn, dtype) + _dot(within, v_new, nn, dtype)
+    total = jnp.sum(gamma[:, c - 1:c])  # the chunk's whole decay, a scalar
+    tn = (((0, ), (0, )))  # a^T @ b
+    s1 = jnp.exp(total) * s0 + _dot(jnp.exp(total - gamma_c) * k32, v_new, tn, dtype)
+    return o, s1
+
+
+# ------------------------------------------------------- sequences on a chunk's edge
+def _chunk_table(seq, nth, count, per_seq):
+    """``[4, chunks]`` int32 from each chunk's sequence, its place among the
+    sequence's chunks and its live positions (0: an empty chunk, skipped).  SEQ
+    of an empty chunk is the nearest live chunk's before it (the first live
+    one's where none is): its grid step then names blocks that are there
+    already, fetches nothing and stores nothing."""
+    c = jnp.arange(seq.shape[0], dtype=jnp.int32)
+    live = count > 0
+    before = jax.lax.cummax(jnp.where(live, c, -1))
+    named = seq[jnp.where(before >= 0, before, jnp.argmax(live))]
+    return jnp.stack([named, live & (nth == 0), live & (nth == per_seq[seq] - 1),
+                      count]).astype(jnp.int32)
+
+
+def gated_delta_scan(q, k, v, g, beta, state, n_tokens, row=None, col=None):
+    """The chunked scan over a step's tokens.  q, k ``[b, s, Hk, dk]`` (l2-
+    normalised, q scaled), v ``[b, s, Hv, dv]``, g, beta ``[b, s, Hv]`` float32,
+    ``[b, s]`` = ``[N, T]`` (``row`` None) or the compacted ``[1, S]`` (``row``,
+    ``col`` ``[1, S]``); state ``[N, Hv, dk, dv]`` float32, each row's carried
+    matrix (zeros for a sequence that begins); n_tokens ``[N]``.  Returns (o
+    ``[b, s, Hv, dv]`` in v's dtype, the rows' new states).  A row with no
+    token keeps its state."""
+    n = n_tokens.shape[0]
+    hk, hv = q.shape[2], v.shape[2]
+    per_seq = -(-n_tokens // CHUNK)
+    if row is None:
+        t = q.shape[1]
+        per_row = -(-t // CHUNK)
+        chunks, width = n * per_row, per_row * CHUNK
+
+        def aligned(a):  # [N, T, ...] -> [N * T', ...], T' whole chunks
+            a = jnp.pad(a, ((0, 0), (0, width - t)) + ((0, 0), ) * (a.ndim - 2))
+            return a.reshape((n * width, ) + a.shape[2:])
+
+        live = aligned(jnp.arange(t)[None, :] < n_tokens[:, None])
+        # a row's chunks in place, the empty chunks of rows that hold fewer among them
+        c = jnp.arange(chunks, dtype=jnp.int32)
+        seq, nth = c // per_row, c % per_row
+        table = _chunk_table(seq, nth, jnp.clip(n_tokens[seq] - nth * CHUNK, 0, CHUNK), per_seq)
+        back = lambda o: o.reshape(n, width, hv, -1)[:, :t]
+    else:
+        slots = q.shape[1]
+        chunks = scan_chunks(n, 0, slots)
+        ends = jnp.cumsum(per_seq)
+        first_chunk = ends - per_seq
+        c = jnp.arange(chunks, dtype=jnp.int32)
+        seq = jnp.minimum(jnp.sum((c[:, None] >= ends[None, :]).astype(jnp.int32), axis=1), n - 1)
+        nth = c - first_chunk[seq]  # past a sequence's chunks where c is past the last sequence's
+        table = _chunk_table(seq, nth, jnp.clip(n_tokens[seq] - nth * CHUNK, 0, CHUNK), per_seq)
+        p = jnp.arange(chunks * CHUNK, dtype=jnp.int32)
+        live = (p % CHUNK) < table[LIVE][p // CHUNK]
+        # the position's token: its place in its sequence's chunk of this step, on the flat axis
+        source = jnp.where(live, (jnp.cumsum(n_tokens) - n_tokens)[seq[p // CHUNK]]
+                           + nth[p // CHUNK] * CHUNK + p % CHUNK, 0)
+        aligned = lambda a: a[0][source]
+        place = first_chunk[row[0]] * CHUNK + col[0]  # where a flat slot's token went
+        back = lambda o: o[jnp.clip(place, 0, chunks * CHUNK - 1)][None]
+
+    def laid(a):  # [b, s, H, d] -> [H, chunks * C, d], zero where no token sits
+        a = aligned(a)
+        mask = live.reshape((-1, ) + (1, ) * (a.ndim - 1))
+        return jnp.moveaxis(jnp.where(mask, a, jnp.zeros((), a.dtype)), 1, 0)
+
+    qa, ka, va = laid(q), laid(k), laid(v)
+    ga = laid(g.astype(jnp.float32)).reshape(hv, chunks, CHUNK)
+    ba = laid(beta.astype(jnp.float32)).reshape(hv, chunks, CHUNK)
+    rows = jnp.stack([jnp.cumsum(ga, axis=-1), ba], axis=2)  # [Hv, chunks, 2, C]
+    walk = _walk_kernel if _pallas.use_pallas() else _walk_scan
+    o, state = walk(table, qa, ka, va, rows, state.astype(jnp.float32), hv // hk)
+    return back(jnp.moveaxis(o, 0, 1)).astype(v.dtype), state
+
+
+def _walk_scan(table, q, k, v, rows, state, rep):
+    """The walk in XLA: a ``lax.scan`` over the chunks, every head at once."""
+    hv, dk, dv = v.shape[0], q.shape[-1], v.shape[-1]
+    chunks = table.shape[1]
+    q, k = (jnp.repeat(a, rep, axis=0).reshape(hv, chunks, CHUNK, dk) for a in (q, k))
+    v = v.reshape(hv, chunks, CHUNK, dv)
+    per_head = jax.vmap(_chunk)
+
+    def one(carry, inp):
+        s, states = carry
+        (seq, first, last, live), qc, kc, vc, rc = inp
+        s = jnp.where(first > 0, states[seq], s)
+        o, s1 = per_head(qc, kc, vc, rc[:, 0:1], rc[:, 1:2], s)
+        s = jnp.where(live > 0, s1, s)
+        states = states.at[jnp.where(last > 0, seq, states.shape[0])].set(s, mode="drop")
+        return (s, states), jnp.where(live > 0, o, 0.0)
+
+    (_, state), o = jax.lax.scan(
+        one, (jnp.zeros((hv, dk, dv), jnp.float32), state),
+        (table.T, *(jnp.moveaxis(a, 1, 0) for a in (q, k, v, rows))))
+    return jnp.moveaxis(o, 0, 1).reshape(hv, chunks * CHUNK, dv), state
+
+
+def _scan_body(table_ref, q_ref, k_ref, v_ref, rows_ref, state_ref, o_ref, out_state_ref, s_ref):
+    c = pl.program_id(1)
+
+    @pl.when(table_ref[FIRST, c] > 0)
+    def _load():
+        s_ref[...] = state_ref[0, 0]
+
+    @pl.when(table_ref[LIVE, c] > 0)
+    def _compute():
+        rows = rows_ref[0, 0]
+        o, s1 = _chunk(q_ref[0], k_ref[0], v_ref[0], rows[0:1], rows[1:2], s_ref[...])
+        o_ref[0] = o.astype(o_ref.dtype)
+        s_ref[...] = s1
+
+    @pl.when(table_ref[LIVE, c] == 0)
+    def _empty():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(table_ref[LAST, c] > 0)
+    def _store():
+        out_state_ref[0, 0] = s_ref[...]
+
+
+# jitted for its trace cache: every chunk program of a cell traces the kernel once a layer kind
+@functools.partial(jax.jit, static_argnames=("rep", "interpret"), inline=True)
+def _walk_pallas(table, q, k, v, rows, state, *, rep, interpret):
+    hv, dk, dv = v.shape[0], q.shape[-1], v.shape[-1]
+    chunks = table.shape[1]
+    seq_state = lambda h, c, table: (table[SEQ, c], h, 0, 0)
+    return pl.pallas_call(
+        _scan_body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(hv, chunks),
+            in_specs=[pl.BlockSpec((1, CHUNK, dk), lambda h, c, table: (h // rep, c, 0)),
+                      pl.BlockSpec((1, CHUNK, dk), lambda h, c, table: (h // rep, c, 0)),
+                      pl.BlockSpec((1, CHUNK, dv), lambda h, c, table: (h, c, 0)),
+                      pl.BlockSpec((1, 1, 2, CHUNK), lambda h, c, table: (h, c, 0, 0)),
+                      pl.BlockSpec((1, 1, dk, dv), seq_state)],
+            out_specs=[pl.BlockSpec((1, CHUNK, dv), lambda h, c, table: (h, c, 0)),
+                       pl.BlockSpec((1, 1, dk, dv), seq_state)],
+            scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={5: 1},  # the carried states, in place: a row with no chunk keeps its own
+        compiler_params=CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="gdn_scan",
+    )(table, q, k, v, rows, state)
+
+
+def _walk_kernel(table, q, k, v, rows, state, rep):
+    return _walk_pallas(table, q, k, v, rows, state, rep=rep, interpret=_pallas.INTERPRET)

@@ -109,6 +109,10 @@ def _cell_shapes():
     pytest.param(32, 1, 128, 3, WINDOW, H, KV, id="decode-table-3"),
     pytest.param(8, 256, 128, 1, WINDOW, H, KV, id="chunk-table-1"),
     *_cell_shapes(),
+    # Qwen3-Next's gated attention (ISSUE 43): 16 q heads over 2 KV heads of 256, a pool row two
+    # lane tiles wide and a q group of 8: a decode step and a padded chunk of the whole budget
+    pytest.param(8, 1, 128, 132, None, 16, 2, id="qwen3-next-decode-16q2kv-dh256"),
+    pytest.param(1, 2048, 128, 132, None, 16, 2, id="qwen3-next-T2048-16q2kv-dh256"),
 ])
 def test_paged_attention_compiles(chip, n, t, block, maxb, window, hq, kvh):
     from deepspeed_tpu.ops.attention.paged import paged_attention
@@ -117,7 +121,8 @@ def test_paged_attention_compiles(chip, n, t, block, maxb, window, hq, kvh):
         return paged_attention(q, k, v, tables, lengths, start, n_tok,
                                block_size=block, window=window)
 
-    avals = paged_avals(chip, n, t, block, maxb, hq=hq, kvh=kvh)
+    dh = 256 if (hq, kvh) == (16, 2) else DH
+    avals = paged_avals(chip, n, t, block, maxb, hq=hq, kvh=kvh, dh=dh)
     assert compile_and_count(fn, *avals) == {"paged_attention": 1}
 
 
@@ -153,6 +158,8 @@ def test_paged_attention_over_a_latent_pool_compiles(chip, n, t):
     pytest.param(8, 512, 512, 68, 128, 1, 640, 512, id="latent-n8-T512-S512"),
     pytest.param(8, 256, 256, 12, 8, 2, DH, None, id="tensor4-shard-8q2kv"),
     pytest.param(4, 128, 64, 12, 12, 4, DH, None, id="group-3-12q4kv"),
+    pytest.param(8, 2048, 2048, 132, 16, 2, 256, None, id="qwen3-next-n8-T2048-S2048-dh256"),
+    pytest.param(8, 512, 512, 132, 16, 2, 256, None, id="qwen3-next-n8-T512-S512-dh256"),
 ])
 def test_paged_attention_on_the_flat_axis_compiles(chip, n, t, s, maxb, hq, kvh, dh, dv):
     """ISSUE 40: q as a compacted pass holds it, ``[S, H, Dh]``, at the shapes the
@@ -391,6 +398,42 @@ def lfm2_shapes(chip, layers):
             on_chip(jax.eval_shape(lambda: lfm2.init_paged_cache(cfg, 1024, 128))))
 
 
+def qwen3_next_shapes(chip, layers):
+    """Qwen3-Next-80B-A3B as ``serve.gdn-long-prompt`` holds it (128 of 512
+    experts, a quarter of the vocabulary; ``layers`` = 8: two periods of three
+    Gated DeltaNet layers and a gated attention, one scan): a pool of the
+    attention layers alone at heads of 256 ``[2, 800, 2, 128, 256]`` and the
+    DeltaNet layers' state, two leaves of eight slots and a trash slot: the
+    shift ``[6, 9, 3, 8192]`` and the float32 matrices ``[6, 9, 32, 128, 128]``."""
+    from deepspeed_tpu.models import qwen3_next
+    cfg = qwen3_next.Qwen3NextConfig(vocab_size=37984, num_layers=layers, num_local_experts=128)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    kv = jax.eval_shape(lambda: qwen3_next.init_paged_cache(cfg, 800, 128, state_slots=8))
+    return (qwen3_next, cfg,
+            on_chip(jax.eval_shape(lambda: qwen3_next.init_params(cfg, jax.random.PRNGKey(0)))),
+            jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype), kv))
+
+
+@pytest.mark.parametrize("budget,n", [(512, 8), (2048, 8)], ids=lambda v: str(v))
+def test_the_gated_delta_scan_compiles_at_the_cells_shapes(chip, budget, n):
+    """ISSUE 43: the chunked-scan kernel at Qwen3-Next's 32 value heads over 16
+    key heads of 128 x 128, for a compacted pass of ``budget`` tokens over ``n``
+    sequences laid on chunk edges: one Mosaic kernel, the carried matrices
+    aliased in and out, nothing else held."""
+    from deepspeed_tpu.ops.linear_attention import gated_delta
+
+    chunks = gated_delta.scan_chunks(n, 0, budget)
+    t = chunks * gated_delta.CHUNK
+    avals = (chip((4, chunks), jnp.int32), chip((16, t, 128), jnp.bfloat16),
+             chip((16, t, 128), jnp.bfloat16), chip((32, t, 128), jnp.bfloat16),
+             chip((32, chunks, 2, gated_delta.CHUNK), jnp.float32), chip((n, 32, 128, 128), jnp.float32))
+    compiled = jax.jit(lambda *a: gated_delta._walk_pallas(*a, rep=2, interpret=False),
+                       donate_argnums=(5, )).lower(*avals).compile()
+    assert kernel_calls(compiled.as_text()) == {"gdn_scan": 1}
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == n * 32 * 128 * 128 * 4 and memory.temp_size_in_bytes == 0
+
+
 def mistral_module_and_shapes(chip, layers):
     from deepspeed_tpu.models import mistral
     return (mistral, ) + mistral_shapes(chip, layers)
@@ -408,13 +451,16 @@ IN_PLACE = {  # model, (n, t, live_token_bound), a burst's scan around it
     "lfm2-packed-heads-decode": (lfm2_shapes, (32, 1, 512), False),
     "lfm2-packed-heads-compacted": (lfm2_shapes, (32, 256, 256), False),
     "lfm2-packed-heads-burst": (lfm2_shapes, (32, 1, None), True),
+    "qwen3-next-state-tree-decode": (qwen3_next_shapes, (8, 1, 2048), False),
+    "qwen3-next-state-tree-compacted": (qwen3_next_shapes, (8, 512, 512), False),
+    "qwen3-next-state-tree-burst": (qwen3_next_shapes, (8, 1, None), True),
 }
 
 
 # layers (every layer of a stack is one scan body: the count sets the pool's
 # size alone) and layer scans of each model's program
 LAYERS_AND_SCANS = {mistral_module_and_shapes: (3, 1), olmoe_shapes: (2, 1),
-                    deepseek_v2_shapes: (5, 2), lfm2_shapes: (10, 1)}
+                    deepseek_v2_shapes: (5, 2), lfm2_shapes: (10, 1), qwen3_next_shapes: (8, 1)}
 
 
 @pytest.mark.parametrize("form", list(IN_PLACE))
@@ -435,7 +481,10 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
     attention layer; heads of 64 packed two a 128-wide row, so no relayout) is
     held to the same, and its second cache with it: the conv layers' state,
     carried beside the pool, is written by scatters in place and never copied,
-    sliced or updated whole."""
+    sliced or updated whole.  Qwen3-Next (ISSUE 43: heads of 256, a state that
+    is a TREE of two leaves, one of them float32 matrices) is held to the same
+    for every leaf, in a decode step (the one-token update), a compacted chunk
+    (the scan kernel, once a DeltaNet layer of the period) and a burst."""
     shapes, (n, t, bound), in_a_burst = IN_PLACE[form]
     layers, scans = LAYERS_AND_SCANS[shapes]
     module, cfg, params, kv = shapes(chip, layers=layers)
@@ -462,9 +511,11 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
     assert (calls["paged_attention"], calls["kv_write"]) == (scans, scans), calls
     results = pool_shaped_results(text, leaves[0].shape)
     assert [r[0] for r in results] == ["custom-call"] * scans, results  # the writer alone
-    if state is not None:
-        whole = pool_shaped_results(text, (1, ) + state.shape)  # the state whole, however folded
+    for leaf in jax.tree_util.tree_leaves(state):
+        whole = pool_shaped_results(text, (1, ) + leaf.shape)  # the leaf whole, however folded
         assert whole and {r[0] for r in whole} <= {"scatter", "fusion"}, whole
+    if shapes is qwen3_next_shapes:  # the scan kernel where a step has chunks, and only there
+        assert calls.get("gdn_scan", 0) == (3 if t > 1 else 0), calls
     pool_bytes = sum(int(np.prod(leaf.shape)) * 2 for leaf in leaves)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
