@@ -165,7 +165,6 @@ def engine_logits(engine, uids, prompts, n_decode: int):
     logits each ragged forward produced: {uid: [n_decode + 1 rows of [V]]} and
     the tokens it picked.  Rows are matched to requests by their absolute
     position, so the prompts must differ in length by more than ``n_decode``."""
-    import jax.numpy as jnp
     import numpy as np
     seen = []
     compiled_fwd = engine._compiled_fwd  # inspection only: generate() is what serves
@@ -175,9 +174,8 @@ def engine_logits(engine, uids, prompts, n_decode: int):
 
         def call(params, kv, tokens, n_tokens, start_pos, tables):
             logits, kv = fwd(params, kv, tokens, n_tokens, start_pos, tables)
-            last = jnp.maximum(n_tokens - 1, 0)
-            rows = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-            seen.append((np.asarray(rows, np.float32), np.asarray(start_pos + n_tokens),
+            # a step's forward returns each row's last live logits alone: [n, 1, V]
+            seen.append((np.asarray(logits[:, 0], np.float32), np.asarray(start_pos + n_tokens),
                          np.asarray(n_tokens)))
             return logits, kv
 

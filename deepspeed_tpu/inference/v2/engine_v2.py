@@ -460,14 +460,16 @@ class InferenceEngineV2:
     def _build_fwd_jit(self, n: int, t: int, b: int):
         """The ragged forward for one bucket, jitted under the bucket's name
         (``fwd_n32_t256_b20``): the device trace's program line and the
-        compile ledger then say which bucket ran."""
+        compile ledger then say which bucket ran.  A step samples one token a
+        sequence, so the forward is asked for each row's last live logits alone
+        (``last_rows``): ``[n, 1, V]`` whatever ``t``."""
         model, cfg, bs = self.model, self.model_config, self.block_size
         tp_axis, bound = TENSOR_AXIS if self.tp > 1 else None, self._live_token_bound
 
         def fwd(params, kv, tokens, n_tokens, start_pos, tables):
             return model.forward_paged(cfg, params, tokens, n_tokens, start_pos,
                                        tables, kv, block_size=bs, tp_axis=tp_axis,
-                                       live_token_bound=bound)
+                                       live_token_bound=bound, last_rows=True)
         if self.tp > 1:
             fwd = self._shard_mapped(fwd, (PartitionSpec(), self._kv_specs))
         fwd.__name__ = f"fwd_n{n}_t{t}_b{b}"
@@ -686,7 +688,7 @@ class InferenceEngineV2:
         # (reference: ragged sampling stays device-side, engine_v2.py:107)
         pick = self._compiled_step_pick(n, greedy)
         self.counters.dispatches += 1
-        toks_dev, self._rng = pick(logits, slot.n_tokens, self._rng)
+        toks_dev, self._rng = pick(logits, self._rng)
         self.phase_profiler.mark("dispatch")
         self.counters.count_slots(n, t, b, tokens_run, live_blocks, flat=flat)
         emits = []
@@ -742,15 +744,13 @@ class InferenceEngineV2:
 
         fwd = self._compiled_fwd(n, t, b)
         self.counters.dispatches += 2
-        # five uploads: four batch arrays into the forward + n_tokens again
-        # into the pick (the fast path derives the pick's input on device)
-        self.counters.uploads += 5
-        self.counters.upload_ints += int(tokens.size + 2 * n_tokens.size
+        self.counters.uploads += 4  # the four batch arrays into the forward
+        self.counters.upload_ints += int(tokens.size + n_tokens.size
                                          + start_pos.size + tables.size)
         logits, self.kv = fwd(self.params, self.kv, jnp.asarray(tokens), jnp.asarray(n_tokens),
                               jnp.asarray(start_pos), jnp.asarray(tables))
         pick = self._compiled_step_pick(n, greedy)
-        toks_dev, self._rng = pick(logits, jnp.asarray(n_tokens), self._rng)
+        toks_dev, self._rng = pick(logits, self._rng)
         tokens_run = int(n_tokens.sum())
         self.counters.count_slots(n, t, b, tokens_run, live_blocks)
         with self._phase_annotation("dispatch", "wait"):
@@ -989,11 +989,10 @@ class InferenceEngineV2:
             temperature, top_k, top_p = (self.config.temperature, self.config.top_k,
                                          self.config.top_p)
 
-            def pick(logits, n_tokens, rng):
-                # last valid position per row, derived on device so the host
-                # uploads nothing pick-specific
-                last = jnp.maximum(n_tokens - 1, 0)
-                row = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
+            def pick(logits, rng):
+                # the step's forward returned each row's last live logits alone
+                # (_build_fwd_jit asks for last_rows): [n, 1, V], nothing to gather
+                row = logits[:, 0]
                 if greedy:
                     return jnp.argmax(row, axis=-1).astype(jnp.int32), rng
                 return _sample(row, rng, temperature=temperature, top_k=top_k, top_p=top_p)
@@ -1459,7 +1458,7 @@ class InferenceEngineV2:
             max_run = max(max_run, len(run))
             out[seq.uid] = run
         self.counters.count_slots(n, k + 1, b, sum(len(r) for r in out.values()),
-                                  live_blocks)
+                                  live_blocks, every_position=True)
         self.counters.spec_rounds += 1
         self.counters.spec_proposed += len(live) * k
         self.counters.spec_accepted += accepted_total

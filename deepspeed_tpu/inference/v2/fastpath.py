@@ -95,6 +95,11 @@ class ServeCounters:
     forward pass; ``table_slots / kernel_steps`` is the slots a step walked)
     ``compact_passes``  forward passes that ran compacted: how often the
     bucket held more slots than the step's live-token bound
+    ``head_rows``  rows the head (final norm and vocabulary product) of the
+    launched programs multiplied (ISSUE 44): n a forward pass of a step or a
+    burst, which take each row's last live token before the head (it was
+    ``token_slots`` while the head ran over every slot); n x t a speculative
+    verify, which scores every position
 
     A mixture-of-experts model routes each token to k experts in every layer
     (ISSUE 27; the model module states ``moe_picks`` = k x layers and
@@ -118,7 +123,8 @@ class ServeCounters:
               "spec_rounds", "spec_proposed", "spec_accepted",
               "token_slots", "live_tokens", "table_slots", "live_blocks",
               "compact_passes", "moe_routed_rows", "moe_expert_rows", "kernel_steps",
-              "attn_token_slots", "scan_chunks", "scan_positions", "scan_live_positions")
+              "attn_token_slots", "scan_chunks", "scan_positions", "scan_live_positions",
+              "head_rows")
 
     def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None,
                  kernel_slots: Callable[[int], int] = lambda t: 1,
@@ -132,16 +138,19 @@ class ServeCounters:
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
                     live_blocks: int, passes: int = 1,
-                    flat: Optional[int] = None) -> None:
+                    flat: Optional[int] = None, every_position: bool = False) -> None:
         """One launch of a forward program over the bucket ``[n, t]`` tokens
         x ``[n, b]`` table slots; a burst of k steps is ``passes=k`` forward
         passes over ``[n, 1]``, and its ``live_tokens`` are the whole
         burst's.  ``flat``: the flat slots the program's per-token layers ran
         over in place of ``n x t`` (``models.transformer.flat_slots``), None
-        for a padded program.  Host integers only: no clock read, no device
+        for a padded program.  ``every_position``: the program's head scored
+        every slot (a speculative verify) and not each row's last live token
+        alone (a step, a burst).  Host integers only: no clock read, no device
         sync."""
         slots = n * t if flat is None else flat
         self.token_slots += slots * passes
+        self.head_rows += (slots if every_position else n) * passes
         self.attn_token_slots += (n * t if flat is None else self.attn_slots(n, flat)) * passes
         self.live_tokens += live_tokens
         if self.moe_rows is not None:

@@ -227,7 +227,8 @@ def init_paged_cache(config: BloomConfig, num_blocks: int, block_size: int,
 
 def forward_paged(config: BloomConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """Ragged chunked BLOOM forward (``transformer.paged_forward`` states the
     contract): ALiBi rides the paged kernel's ``alibi_slopes`` operand
     (key-only form, absolute key index).  The reference's v2 zoo doesn't serve
@@ -265,7 +266,7 @@ def forward_paged(config: BloomConfig, params, tokens, n_tokens, start_pos, bloc
 
     return transformer.paged_forward(
         params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
-        block_size=block_size, live_token_bound=live_token_bound,
+        block_size=block_size, live_token_bound=live_token_bound, last_rows=last_rows,
         embed=embed, qkv=lambda lp, x, safe_pos: (*_qkv(config, lp, x), None), finish=finish,
         head=head, alibi_slopes=slopes)
 

@@ -248,7 +248,8 @@ def moe_expert_rows(config: DeepseekV2Config, slots: int) -> int:
 
 def forward_paged(config: DeepseekV2Config, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """Ragged chunked forward (``transformer.paged_forward`` states the
     contract): absorbed MLA over the latent pool, a dense stack and an expert
     stack, the expert FFN of ``moe/serving.py`` over the experts held here."""
@@ -310,7 +311,7 @@ def forward_paged(config: DeepseekV2Config, params, tokens, n_tokens, start_pos,
     return transformer.paged_forward(
         [params["dense_layers"], {**moe_layers, "moe": moe}], tokens, n_tokens, start_pos,
         block_tables, kv_cache, block_size=block_size, live_token_bound=live_token_bound,
-        embed=embed, qkv=qkv, finish=finish, head=head,
+        last_rows=last_rows, embed=embed, qkv=qkv, finish=finish, head=head,
         softmax_scale=softmax_scale(config), value_dim=rank)
 
 

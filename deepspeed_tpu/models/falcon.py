@@ -138,7 +138,8 @@ def make_tp_rules(config: FalconConfig):
 
 def forward_paged(config: FalconConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """Ragged chunked Falcon forward (``transformer.paged_forward`` states the
     contract): the MQA KV pool (1 KV head) goes through the Pallas paged
     kernel's GQA head mapping.
@@ -165,7 +166,7 @@ def forward_paged(config: FalconConfig, params, tokens, n_tokens, start_pos, blo
 
     return transformer.paged_forward(
         params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
-        block_size=block_size, live_token_bound=live_token_bound,
+        block_size=block_size, live_token_bound=live_token_bound, last_rows=last_rows,
         embed=lambda tokens, safe_pos: params["embed"][tokens].astype(dtype),
         qkv=lambda lp, x, safe_pos: _qkv(config, lp, x, cos, sin, safe_pos),
         finish=finish, head=head)

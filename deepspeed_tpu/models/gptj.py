@@ -169,7 +169,8 @@ def init_paged_cache(config: GPTJConfig, num_blocks: int, block_size: int, dtype
 
 def forward_paged(config: GPTJConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """Ragged chunked GPT-J forward (``transformer.paged_forward`` states the
     contract): interleaved partial rotary feeds the paged kernel; the parallel
     residual reduces attn+mlp in one psum under TP; vocab-parallel biased head
@@ -194,7 +195,7 @@ def forward_paged(config: GPTJConfig, params, tokens, n_tokens, start_pos, block
 
     return transformer.paged_forward(
         params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
-        block_size=block_size, live_token_bound=live_token_bound,
+        block_size=block_size, live_token_bound=live_token_bound, last_rows=last_rows,
         embed=lambda tokens, safe_pos: params["embed"][tokens].astype(dtype),
         qkv=lambda lp, x, safe_pos: _qkv(config, lp, x, cos, sin, safe_pos),
         finish=finish, head=head)

@@ -235,7 +235,8 @@ def rotate_half(x, positions, inv_freq):
 
 def forward_paged(config: Lfm2Config, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """Ragged chunked forward (``transformer.paged_forward`` states the
     contract): the conv layers through ``mix`` and their sequences' state, the
     attention layers over the packed pool, dense and expert FFNs."""
@@ -318,5 +319,5 @@ def forward_paged(config: Lfm2Config, params, tokens, n_tokens, start_pos, block
         layers.append(tuple(positions))
     return transformer.paged_forward(
         layers, tokens, n_tokens, start_pos, block_tables, kv_cache, block_size=block_size,
-        live_token_bound=live_token_bound, embed=embed, qkv=qkv, finish=finish, head=head,
-        mix=mix, softmax_scale=dh ** -0.5)
+        live_token_bound=live_token_bound, last_rows=last_rows, embed=embed, qkv=qkv, finish=finish,
+        head=head, mix=mix, softmax_scale=dh ** -0.5)

@@ -428,11 +428,12 @@ def paged_callables(config, params, dtype, tp_axis: Optional[str], gather_logits
 
 def forward_paged(config: LlamaConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """The v2 ragged forward (``transformer.paged_forward`` states the contract;
     reference inference/v2/model_implementations/llama_v2): rotary GQA, dense
     SwiGLU, vocab-parallel untied head."""
     return transformer.paged_forward(
         params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
-        block_size=block_size, live_token_bound=live_token_bound,
+        block_size=block_size, live_token_bound=live_token_bound, last_rows=last_rows,
         **paged_callables(config, params, kv_cache["k"].dtype, tp_axis, gather_logits))

@@ -100,13 +100,15 @@ def make_loss_fn(config: MistralConfig, attention_fn=None) -> Callable:
 
 def forward_paged(config: MistralConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """v2 ragged forward: Llama's callables, and the paged kernel applies the
     sliding window directly (reference mistral serving uses windowed blocked
     flash)."""
     return transformer.paged_forward(
         params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
-        block_size=block_size, live_token_bound=live_token_bound, window=config.sliding_window,
+        block_size=block_size, live_token_bound=live_token_bound, last_rows=last_rows,
+        window=config.sliding_window,
         **llama.paged_callables(config, params, kv_cache["k"].dtype, tp_axis, gather_logits))
 
 

@@ -272,7 +272,8 @@ def whole_width_qk_norm(config: MixtralConfig, tp_axis: Optional[str]):
 
 def forward_paged(config: MixtralConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """Ragged chunked forward (reference inference/v2/model_implementations/
     mixtral): Llama's callables, compaction and all, with the dense SwiGLU of
     a layer replaced by the no-drop sparse top-k expert FFN (moe/serving.py)
@@ -300,6 +301,6 @@ def forward_paged(config: MixtralConfig, params, tokens, n_tokens, start_pos, bl
         on_heads = lambda lp, q, k, v: (*qk_norm(lp, q, k), v)
     return transformer.paged_forward(
         {**layers, "moe": moe}, tokens, n_tokens, start_pos, block_tables, kv_cache,
-        block_size=block_size, live_token_bound=live_token_bound,
+        block_size=block_size, live_token_bound=live_token_bound, last_rows=last_rows,
         **llama.paged_callables(_llama_view(config), params, kv_cache["k"].dtype, tp_axis,
                                 gather_logits, on_heads=on_heads, ffn=ffn))

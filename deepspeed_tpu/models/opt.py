@@ -140,7 +140,8 @@ def tp_rules(path: str, shape) -> "int | None":
 
 def forward_paged(config: OPTConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """Ragged chunked OPT forward (``transformer.paged_forward`` states the
     contract): learned positions, no rotary on K/Q.
 
@@ -168,7 +169,7 @@ def forward_paged(config: OPTConfig, params, tokens, n_tokens, start_pos, block_
 
     return transformer.paged_forward(
         params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
-        block_size=block_size, live_token_bound=live_token_bound,
+        block_size=block_size, live_token_bound=live_token_bound, last_rows=last_rows,
         embed=embed, qkv=lambda lp, x, safe_pos: (*_qkv(config, lp, x), None), finish=finish,
         head=head)
 

@@ -143,12 +143,13 @@ def _add_qkv_biases(lp, q, k, v):
 
 def forward_paged(config: QwenConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """Ragged chunked Qwen2 forward: Llama's callables with the qkv biases
     added before rotary (``transformer.paged_forward`` states the contract)."""
     return transformer.paged_forward(
         params["layers"], tokens, n_tokens, start_pos, block_tables, kv_cache,
-        block_size=block_size, live_token_bound=live_token_bound,
+        block_size=block_size, live_token_bound=live_token_bound, last_rows=last_rows,
         **llama.paged_callables(config, params, kv_cache["k"].dtype, tp_axis, gather_logits,
                                 on_heads=_add_qkv_biases))
 

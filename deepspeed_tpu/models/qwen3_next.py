@@ -253,7 +253,8 @@ def l2norm(x):
 
 def forward_paged(config: Qwen3NextConfig, params, tokens, n_tokens, start_pos, block_tables,
                   kv_cache, *, block_size: int, tp_axis: Optional[str] = None,
-                  gather_logits: bool = True, live_token_bound: Optional[int] = None):
+                  gather_logits: bool = True, live_token_bound: Optional[int] = None,
+                  last_rows: bool = False):
     """Ragged chunked forward (``transformer.paged_forward`` states the
     contract): the Gated DeltaNet layers through ``mix`` and their sequences'
     carried leaves, the gated attention layers over the pool, the expert FFN."""
@@ -359,5 +360,5 @@ def forward_paged(config: Qwen3NextConfig, params, tokens, n_tokens, start_pos, 
             for j, lp in enumerate(segment)))
     return transformer.paged_forward(
         layers, tokens, n_tokens, start_pos, block_tables, kv_cache, block_size=block_size,
-        live_token_bound=live_token_bound, embed=embed, qkv=qkv, finish=finish, head=head,
-        mix=mix, softmax_scale=dh ** -0.5)
+        live_token_bound=live_token_bound, last_rows=last_rows, embed=embed, qkv=qkv, finish=finish,
+        head=head, mix=mix, softmax_scale=dh ** -0.5)

@@ -476,7 +476,7 @@ def tp_psum(tp_axis: Optional[str]):
 
 
 def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *,
-                  block_size: int, live_token_bound: Optional[int],
+                  block_size: int, live_token_bound: Optional[int], last_rows: bool = False,
                   embed: Callable, qkv: Callable, finish: Callable, head: Callable,
                   window: Optional[int] = None, alibi_slopes=None,
                   softmax_scale: Optional[float] = None, value_dim: Optional[int] = None,
@@ -490,11 +490,12 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
 
     A family's ``forward_paged(config, params, tokens, n_tokens, start_pos,
     block_tables, kv_cache, *, block_size, tp_axis=None, gather_logits=True,
-    live_token_bound=None)`` is the contract the serving engine calls, the
-    same keywords for every module: tokens [N, T] (right-padded chunks),
+    live_token_bound=None, last_rows=False)`` is the contract the serving engine
+    calls, the same keywords for every module: tokens [N, T] (right-padded chunks),
     n_tokens [N] valid counts, start_pos [N] absolute start of this chunk,
     block_tables [N, MAXB] (padded entries point at the trash block); returns
-    (logits [N, T, V], new kv_cache).  ``tp_axis`` names the mesh axis of the
+    (logits [N, T, V], new kv_cache), or with ``last_rows`` (logits [N, 1, V],
+    new kv_cache).  ``tp_axis`` names the mesh axis of the
     enclosing shard_map (params column/row-sharded per the family's tp rules,
     the pool sharded on its heads; head counts come from the local shapes, so
     one code serves one chip and a TP shard); ``gather_logits=False`` leaves a
@@ -593,11 +594,20 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     paged kernel takes ``q`` from the flat axis and returns its output there
     (``paged_attention_flat``: a sequence's rows found by an offset that is
     data), so nothing of the padded ``[N, T]`` size is built inside the layer
-    scan.  The logits come back as ``[N, T, V]`` all the same (scattered once,
-    after the scan), zero wherever no live token sits.  With None,
-    or where the bucket fits the bound (decode ``[N, 1]``, a burst body, a
-    spec verify), every slot of the bucket is computed and the trace is the
-    padded one.
+    scan.  With None, or where the bucket fits the bound (decode ``[N, 1]``, a
+    burst body, a spec verify), every slot of the bucket is computed and the
+    trace is the padded one.
+
+    ``last_rows``: the caller's statement that it reads each row's last live
+    token alone (the serving engine's step does: one sampled token a sequence;
+    a speculative verify and the benchmark's references read every position and
+    leave it False).  The N rows ``x[n, n_tokens[n] - 1]`` (compacted: row n's
+    last token is flat slot ``cumsum(n_tokens)[n] - 1``) are then taken BEFORE
+    the head, which runs over ``[N, 1, D]`` and returns ``[N, 1, V]``: no head
+    over the other slots, nothing of the size ``[N, T, V]``.  A row with no
+    token returns some live row's logits (a finite row nobody reads).  False:
+    every position's logits ``[N, T, V]``, a compacted pass's scattered once
+    after the scan, zero wherever no live token sits.
 
     Attention runs in the Pallas paged kernel (ops/attention/paged.py) on TPU:
     only live blocks are read via scalar-prefetched table indices; off-TPU the
@@ -719,7 +729,15 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     if state_leaves:
         cache[STATE] = jax.tree_util.tree_unflatten(state_tree, [
             flat.reshape(leaf.shape) for flat, leaf in zip(pools[len(flat_pools):], state_leaves)])
-    return to_padded(head(x)), cache
+    if not last_rows:
+        return to_padded(head(x)), cache
+    if slots is not None:  # the rows lie one after another: row n ends where the first n + 1 counts do
+        last = x[0, jnp.clip(jnp.cumsum(n_tokens) - 1, 0, slots - 1)][:, None]
+    elif t > 1:
+        last = jnp.take_along_axis(x, jnp.maximum(n_tokens - 1, 0)[:, None, None], axis=1)
+    else:  # a decode step: the one slot a row has
+        last = x
+    return head(last), cache
 
 
 def paged_step_slots(module, config, kv_cache, q_dtype, tp: int = 1):

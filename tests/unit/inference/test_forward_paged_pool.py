@@ -40,9 +40,11 @@ PROMPTS = (9, 5, 2)  # tokens; prefilled in chunks of at most 4, then decoded
 
 
 def sliced_paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *,
-                         block_size, live_token_bound, embed, qkv, finish, head, window=None,
-                         alibi_slopes=None):
-    """``transformer.paged_forward`` with the pool as the layer scan's xs and ys."""
+                         block_size, live_token_bound, last_rows, embed, qkv, finish, head,
+                         window=None, alibi_slopes=None):
+    """``transformer.paged_forward`` with the pool as the layer scan's xs and ys
+    (every position's logits: the twin's callers leave ``last_rows`` False)."""
+    assert not last_rows
     n, t = tokens.shape
     num_blocks = kv_cache["k"].shape[1]
     slots = flat_slots(n, t, live_token_bound)
@@ -265,8 +267,8 @@ def test_one_paged_driver_one_kernel_call_site_and_one_signature():
         "paged_attention": 1, "paged_chunk_indices": 1, "flat_chunk_indices": 1}, calls
     assert all(site.startswith("transformer.py:") for sites in calls.values() for site in sites)
     want = inspect.signature(llama.forward_paged)
-    assert list(want.parameters)[-4:] == ["block_size", "tp_axis", "gather_logits",
-                                          "live_token_bound"]
+    assert list(want.parameters)[-5:] == ["block_size", "tp_axis", "gather_logits",
+                                          "live_token_bound", "last_rows"]
     for module in TEN:
         source = inspect.getsource(module.forward_paged)
         assert "lax.scan" not in source and ".at[" not in source, module.__name__

@@ -354,9 +354,11 @@ def test_compacted_mixed_wave_matches_the_padded_reference(family, tp):
     """Tokens against ``_step_reference``, and the logits row that ends each
     prompt's prefill read by the chip benchmark's own reader: through
     ``engine._compiled_fwd(n, t, b)`` and its six-argument callable, at
-    ``logits[row, n_tokens[row] - 1]`` of a ``[n, t, V]`` result.  Every
-    family is handed the bound (ISSUE 29): the engine asks no module what its
-    forward takes."""
+    ``logits[row, n_tokens[row] - 1]`` of its result, which since ISSUE 44 is
+    each row's last live logits alone, ``[n, 1, V]``: the reader's index past
+    the end clamps to ``[row, 0]`` (``test_last_rows_head.py`` pins that).
+    Every family is handed the bound (ISSUE 29): the engine asks no module what
+    its forward takes."""
     served = {}
     for fastpath in (True, False):
         eng = _compacting_engine(family, fastpath, tp)

@@ -66,10 +66,12 @@ def test_slot_counters_equal_the_count_by_hand(path):
         # 8 flat slots for 8 live tokens of the [4, 4] bucket; the table as ever
         assert _slots(eng) == (8, 8, 4 * 4, 3)
         assert eng.counters.compact_passes == 1
+        assert eng.counters.head_rows == 4  # the head over a last row a sequence, not 8 slots
         assert len(eng.step()) == 3  # two decodes and the prompt's last token: [4, 1]
         assert _slots(eng) == (8 + 4, 8 + 3, 16 + 16, 3 + 3)
         assert eng.counters.compact_passes == 1  # 4 slots fit the bound: padded
         assert eng.health()["fastpath"]["compact_passes"] == 1
+        assert eng.health()["fastpath"]["head_rows"] == 4 + 4
         return
     eng = _tiny_engine(conf)
     eng.put([0, 1, 2], _PROMPTS)
@@ -79,11 +81,13 @@ def test_slot_counters_equal_the_count_by_hand(path):
     # 4 x 4 token slots for 9 prompt tokens; 4 x b table slots for 3 blocks
     assert _slots(eng) == (16, 9, 4 * b, 3)
     assert eng.counters.compact_passes == 0  # 16 slots fit the budget of 32
+    assert eng.counters.head_rows == 4  # n rows of the padded [4, 4] too
 
     if path in ("_dispatch_step", "_step_reference"):
         assert len(eng.step()) == 3  # one decode step: the bucket [4, 1]
         assert _names(eng, "fwd") == {f"fwd_n4_t4_b{b}", f"fwd_n4_t1_b{b}"}
         assert _slots(eng) == (16 + 4, 9 + 3, 4 * b + 4 * b, 3 + 3)
+        assert eng.counters.head_rows == 4 + 4
     elif path == "decode_burst":
         out = eng.decode_burst(4)
         assert sorted(len(v) for v in out.values()) == [4, 4, 4]
@@ -91,6 +95,7 @@ def test_slot_counters_equal_the_count_by_hand(path):
         # 4 forward passes over [4, 1]; positions up to 8, 9 and 7 need 1, 2
         # and 1 blocks, and every pass walks the [4, 4] table
         assert _slots(eng) == (16 + 4 * 4, 9 + 12, 16 + 4 * 16, 3 + 4 * (1 + 2 + 1))
+        assert eng.counters.head_rows == 4 + 4 * 4  # n a pass, k passes
     else:
         out = eng.decode_spec(3)
         assert out is not None and all(1 <= len(run) <= 4 for run in out.values())
@@ -98,6 +103,7 @@ def test_slot_counters_equal_the_count_by_hand(path):
         # one forward pass over [4, 3 + 1]; the accepted runs are the live
         # tokens; positions up to 7, 8 and 6 fit the one block each row has
         assert _slots(eng) == (16 + 16, 9 + sum(len(r) for r in out.values()), 16 + 16, 3 + 3)
+        assert eng.counters.head_rows == 4 + 16  # a verify scores every position: n x (k + 1)
     token_slots, live_tokens, table_slots, live_blocks = _slots(eng)
     assert live_tokens <= token_slots and live_blocks <= table_slots
     assert eng.health()["fastpath"]["token_slots"] == token_slots
@@ -127,7 +133,7 @@ def _module_name(eng, event):
     ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     n = key[1]
     if event["site"] == "pick":
-        args = (jax.ShapeDtypeStruct((n, 1, 64), jnp.float32), ints(n), eng._rng)
+        args = (jax.ShapeDtypeStruct((n, 1, 64), jnp.float32), eng._rng)
     else:  # burst: neither the table width nor the pool is part of its key
         args = (eng.params, eng.kv, ints(n), ints(n), ints(n, 4), eng._rng,
                 jax.ShapeDtypeStruct((n, ), jnp.bool_))

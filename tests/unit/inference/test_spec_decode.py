@@ -326,11 +326,12 @@ def test_spec_off_exposition_has_no_spec_families():
 
 def test_serve_counters_fields_spec_tail():
     """The spec counters ride BEHIND the pre-spec fields, and the slot
-    counters (ISSUE 24, 25, 27, 35, 40, 43) behind them, so every positional consumer of an
+    counters (ISSUE 24, 25, 27, 35, 40, 43, 44) behind them, so every positional consumer of an
     older field order still reads the same values."""
-    assert ServeCounters.FIELDS[-3:] == ("scan_chunks", "scan_positions", "scan_live_positions")
-    assert ServeCounters.FIELDS[-4] == "attn_token_slots"
-    assert ServeCounters.FIELDS[-15:-4] == ("spec_rounds", "spec_proposed", "spec_accepted",
+    assert ServeCounters.FIELDS[-1] == "head_rows"
+    assert ServeCounters.FIELDS[-4:-1] == ("scan_chunks", "scan_positions", "scan_live_positions")
+    assert ServeCounters.FIELDS[-5] == "attn_token_slots"
+    assert ServeCounters.FIELDS[-16:-5] == ("spec_rounds", "spec_proposed", "spec_accepted",
                                           "token_slots", "live_tokens", "table_slots",
                                           "live_blocks", "compact_passes",
                                           "moe_routed_rows", "moe_expert_rows", "kernel_steps")

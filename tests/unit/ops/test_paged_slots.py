@@ -327,6 +327,24 @@ def test_kernel_steps_count_the_grids_table_axis():
     assert ServeCounters().kernel_slots(7) == 1 and "kernel_steps" in counters.snapshot()
 
 
+@pytest.mark.parametrize("launch,rows", [
+    (dict(n=32, t=1, b=20, live_tokens=32, live_blocks=90), 32),
+    (dict(n=32, t=256, b=18, live_tokens=256, live_blocks=90, flat=256), 32),
+    (dict(n=32, t=256, b=18, live_tokens=256, live_blocks=90), 32),
+    (dict(n=16, t=1, b=10, live_tokens=160, live_blocks=40, passes=10), 160),
+    (dict(n=8, t=5, b=10, live_tokens=24, live_blocks=40, every_position=True), 40),
+], ids=["decode-step", "compacted-chunk", "padded-chunk", "burst-of-ten", "spec-verify"])
+def test_head_rows_count_a_last_row_a_sequence_a_pass(launch, rows):
+    """``head_rows``: n a forward pass of a step or a burst whatever the bucket's
+    ``t`` or its flat slots (ISSUE 44: the head runs over each row's last live
+    token alone), every slot of a program that scores every position."""
+    from deepspeed_tpu.inference.v2.fastpath import ServeCounters
+    counters = ServeCounters()
+    counters.count_slots(**launch)
+    assert counters.head_rows == counters.snapshot()["head_rows"] == rows
+    assert counters.head_rows <= counters.token_slots
+
+
 @pytest.mark.parametrize("group,align", [(4, 4), (1, 16), (8, 2), (128, 1), (71, 16), (6, 8)])
 def test_attention_slots_count_the_layout_the_kernel_was_handed(group, align):
     """``attn_token_slots``: n x t a padded pass and every pass of a burst, the
