@@ -295,6 +295,8 @@ class InferenceEngineV2:
                            moe_rows=functools.partial(model_module.moe_expert_rows, model_config))
         if hasattr(model_module, "state_scan"):  # a state scanned in chunks counts them
             counted.update(scan=model_module.state_scan(model_config))
+        if hasattr(model_module, "selected_keys"):  # a learned selection of the cache counts its keys
+            counted.update(selected=(*model_module.selected_keys(model_config), block_size))
         self.counters = ServeCounters(**counted)
         # serving performance observatory (ISSUE 16): the compile ledger is
         # always on (no clock reads, no device work) and is the single source
@@ -525,6 +527,11 @@ class InferenceEngineV2:
         self.ledger.record("fwd", key, wall_s=time.perf_counter() - t0,  # dslint: disable=raw-clock-in-serving  # same stopwatch as t0 above — host compile duration, never the engine clock
                            prewarmed=prewarmed, name=fwd.__name__)
 
+    def _selected_spans(self, spans):
+        """Each launched row's ``(start_pos, n_tokens)`` for the ``dsa_*`` counters:
+        a list only where the family attends a selection (nothing is built for any other)."""
+        return list(spans) if self.counters.selected is not None else None
+
     def _cow_copy_block(self, src: int, dst: int) -> None:
         """Copy-on-write block duplication (ISSUE 13): copy one KV block's
         contents device-side so a fully-prefix-cached prompt's single
@@ -674,6 +681,8 @@ class InferenceEngineV2:
                 packed[3 + t:] = self.manager.block_table_row(seq, width=b)
                 rows.append((i, packed))
                 live_blocks += len(seq.blocks)
+            spans = self._selected_spans((self.manager.seqs[c.uid].seen_tokens, c.n_tokens)
+                                         for c in chunks)
             slot = self.batch_state.update(held, rows, n_active=len(chunks),
                                            trash_block=self.manager.trash_block)
             if feeds:
@@ -690,7 +699,7 @@ class InferenceEngineV2:
         self.counters.dispatches += 1
         toks_dev, self._rng = pick(logits, self._rng)
         self.phase_profiler.mark("dispatch")
-        self.counters.count_slots(n, t, b, tokens_run, live_blocks, flat=flat)
+        self.counters.count_slots(n, t, b, tokens_run, live_blocks, flat=flat, spans=spans)
         emits = []
         row_of: Dict[int, int] = {}
         for i, c in enumerate(chunks):
@@ -752,7 +761,8 @@ class InferenceEngineV2:
         pick = self._compiled_step_pick(n, greedy)
         toks_dev, self._rng = pick(logits, self._rng)
         tokens_run = int(n_tokens.sum())
-        self.counters.count_slots(n, t, b, tokens_run, live_blocks)
+        self.counters.count_slots(n, t, b, tokens_run, live_blocks, spans=self._selected_spans(
+            (int(start_pos[i]), c.n_tokens) for i, c in enumerate(chunks)))
         with self._phase_annotation("dispatch", "wait"):
             toks = materialize(toks_dev, self.counters)  # one sync: n sampled ints
 
@@ -1131,7 +1141,8 @@ class InferenceEngineV2:
             out = self._absorb_burst(live, k, eos, fetched)
         # k forward passes over [n, 1] token slots and [n, b] table slots each
         self.counters.count_slots(n, 1, b, sum(len(v) for v in out.values()),
-                                  live_blocks, passes=k)
+                                  live_blocks, passes=k, spans=self._selected_spans(
+                                      (int(start0[i]), 1) for i in range(len(live))))
         return out
 
     def _prepare_burst(self, k: int):
@@ -1458,7 +1469,8 @@ class InferenceEngineV2:
             max_run = max(max_run, len(run))
             out[seq.uid] = run
         self.counters.count_slots(n, k + 1, b, sum(len(r) for r in out.values()),
-                                  live_blocks, every_position=True)
+                                  live_blocks, every_position=True, spans=self._selected_spans(
+                                      (int(start0[i]), k + 1) for i in range(len(live))))
         self.counters.spec_rounds += 1
         self.counters.spec_proposed += len(live) * k
         self.counters.spec_accepted += accepted_total

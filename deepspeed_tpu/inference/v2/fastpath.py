@@ -116,6 +116,19 @@ class ServeCounters:
     a step of one token a row walks none), in every such layer
     ``scan_positions``  the token positions of those chunks
     ``scan_live_positions``  of those, the positions that held a live token
+
+    A family that attends a learned selection of the cache (ISSUE 45; the model
+    module states ``selected_keys`` = (top-k, attention layers); zero, and absent
+    from a snapshot, for every other: ``SELECTED_FIELDS``), counted from each launched row's ``start_pos`` and ``n_tokens``
+    (``spans``), summed over live query tokens and attention layers:
+    ``dsa_causal_keys``  ``position + 1``: the keys plain causal attention sees
+    ``dsa_selected_keys``  ``min(position + 1, top-k)``: the keys attended
+    ``dsa_scored_keys``  keys the indexer's score kernel scored the token: its
+    tile's steps of ``INDEX_BLOCKS`` blocks up to the tile's last position
+    (``ops/attention/dsa.py``)
+    ``dsa_attended_keys``  key rows the attention kernel multiplied the token's
+    rows with: every step of ``kernel_slots(t)`` blocks up to the sequence's
+    length, selected or not (``ops/attention/paged.py`` masks what was not)
     """
 
     FIELDS = ("host_syncs", "dispatches", "uploads", "upload_ints", "compiles",
@@ -125,20 +138,27 @@ class ServeCounters:
               "compact_passes", "moe_routed_rows", "moe_expert_rows", "kernel_steps",
               "attn_token_slots", "scan_chunks", "scan_positions", "scan_live_positions",
               "head_rows")
+    # counted, and reported by ``snapshot`` / ``delta_since``, only for a family that
+    # attends a learned selection of the cache (``selected``): no other engine's
+    # snapshot gains a key
+    SELECTED_FIELDS = ("dsa_causal_keys", "dsa_selected_keys", "dsa_scored_keys",
+                       "dsa_attended_keys")
 
     def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None,
                  kernel_slots: Callable[[int], int] = lambda t: 1,
                  attn_slots: Callable[[int, int], int] = lambda n, flat: flat,
-                 scan: Optional[tuple] = None):
-        for f in self.FIELDS:
+                 scan: Optional[tuple] = None, selected: Optional[tuple] = None):
+        for f in self.FIELDS + self.SELECTED_FIELDS:
             setattr(self, f, 0)
         self.moe_picks, self.moe_rows, self.kernel_slots = moe_picks, moe_rows, kernel_slots
         self.attn_slots = attn_slots
         self.scan = scan  # (chunks(n, t, flat), positions a chunk, layers that scan)
+        self.selected = selected  # (top-k, attention layers, the pool's block size)
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
                     live_blocks: int, passes: int = 1,
-                    flat: Optional[int] = None, every_position: bool = False) -> None:
+                    flat: Optional[int] = None, every_position: bool = False,
+                    spans: Optional[List[Tuple[int, int]]] = None) -> None:
         """One launch of a forward program over the bucket ``[n, t]`` tokens
         x ``[n, b]`` table slots; a burst of k steps is ``passes=k`` forward
         passes over ``[n, 1]``, and its ``live_tokens`` are the whole
@@ -146,8 +166,10 @@ class ServeCounters:
         over in place of ``n x t`` (``models.transformer.flat_slots``), None
         for a padded program.  ``every_position``: the program's head scored
         every slot (a speculative verify) and not each row's last live token
-        alone (a step, a burst).  Host integers only: no clock read, no device
-        sync."""
+        alone (a step, a burst).  ``spans``: each live row's ``(start_pos,
+        n_tokens)`` of the first pass (a later pass of a burst begins one token
+        further), read only where the family attends a selection.  Host integers
+        only: no clock read, no device sync."""
         slots = n * t if flat is None else flat
         self.token_slots += slots * passes
         self.head_rows += (slots if every_position else n) * passes
@@ -167,12 +189,40 @@ class ServeCounters:
         self.kernel_steps += n * -(-b // self.kernel_slots(t)) * passes
         self.live_blocks += live_blocks * passes
         self.compact_passes += passes if flat is not None else 0
+        if self.selected is not None and spans:
+            self._count_selected(t, b, spans, passes)
+
+    def _count_selected(self, t: int, b: int, spans, passes: int) -> None:
+        from ...ops.attention.dsa import INDEX_BLOCKS, token_tile
+        topk, layers, bs = self.selected
+        tile, scored_step = token_tile(t), INDEX_BLOCKS * bs
+        walked_step = self.kernel_slots(t) * bs
+        through = lambda m: m * (m + 1) // 2  # 1 + 2 + ... + m
+        causal = chosen = scored = walked = 0
+        for first, count in spans:
+            for start in range(first, first + passes * count, count):
+                end = start + count  # the row's tokens of this pass: positions start .. end - 1
+                causal += through(end) - through(start)
+                chosen += through(min(end, topk)) - through(min(start, topk)) \
+                    + topk * (end - max(start, topk) if end > topk else 0)
+                for at in range(start, end, tile):  # a tile scores up to its last token's position
+                    upto = min(at + tile, end)
+                    scored += (upto - at) * min(-(-upto // scored_step) * scored_step, b * bs)
+                walked += count * min(-(-end // walked_step) * walked_step,
+                                      -(-b * bs // walked_step) * walked_step)
+        self.dsa_causal_keys += causal * layers
+        self.dsa_selected_keys += chosen * layers
+        self.dsa_scored_keys += scored * layers
+        self.dsa_attended_keys += walked * layers
+
+    def _reported(self) -> Tuple[str, ...]:
+        return self.FIELDS + (self.SELECTED_FIELDS if self.selected is not None else ())
 
     def snapshot(self) -> Dict[str, int]:
-        return {f: int(getattr(self, f)) for f in self.FIELDS}
+        return {f: int(getattr(self, f)) for f in self._reported()}
 
     def delta_since(self, snap: Dict[str, int]) -> Dict[str, int]:
-        return {f: int(getattr(self, f)) - snap.get(f, 0) for f in self.FIELDS}
+        return {f: int(getattr(self, f)) - snap.get(f, 0) for f in self._reported()}
 
 
 def materialize(dev_array, counters: Optional[ServeCounters] = None) -> np.ndarray:

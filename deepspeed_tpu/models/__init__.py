@@ -1,9 +1,10 @@
-from . import (bert, bloom, deepseek_v2, falcon, gpt2, gptj, lfm2, llama, mistral, mixtral, olmoe,
-               opt, phi, qwen, transformer)
+from . import (bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, lfm2, llama, mistral, mixtral,
+               olmoe, opt, phi, qwen, transformer)
 from .bert import BertConfig
 from .bloom import BloomConfig
 from .deepseek_v2 import DeepseekV2Config
 from .falcon import FalconConfig
+from .glm_moe_dsa import GlmMoeDsaConfig
 from .gpt2 import GPT2Config
 from .gptj import GPTJConfig
 from .lfm2 import Lfm2Config

@@ -165,6 +165,66 @@ def rotate_pairs(x, positions, inv_freq, table_scale: float = 1.0):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def mla_qkv(config, a, h, safe_pos, inv_freq, table_scale: float, width: int):
+    """Absorbed latent attention's projections of one layer, for any family
+    whose config names the MLA sizes as :class:`DeepseekV2Config` does
+    (``num_heads``, ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``, ``rms_eps``): ``a`` the layer's attention weights, ``h``
+    ``[b, s, D]`` the normed input.  Returns ``(q, latent, c_q)``: ``q`` ``[b, s,
+    H, width]`` = ``[q_nope W_kvb[k]^T | rotated q_pe]`` in whole lanes, a head's
+    query against the cached vector itself; ``latent`` ``[b, s, 1, width]`` =
+    ``[c_kv | rotated k_pe]``, the token's row of the pool; ``c_q`` ``[b, s,
+    q_lora_rank]``, the normed low-rank query (an indexer projects it again)."""
+    H, rank, rope = config.num_heads, config.kv_lora_rank, config.qk_rope_head_dim
+    nope, dv = config.qk_nope_head_dim, config.v_head_dim
+    dtype = h.dtype
+    c_q = rms_norm(h @ a["wq_a"].astype(dtype), a["q_norm"], config.rms_eps)
+    q = (c_q @ a["wq_b"].astype(dtype)).reshape(h.shape[:2] + (H, nope + rope))
+    kv = h @ a["wkv_a"].astype(dtype)
+    c_kv = rms_norm(kv[..., :rank], a["kv_norm"], config.rms_eps)
+    q_pe = rotate_pairs(q[..., nope:], safe_pos, inv_freq, table_scale)
+    k_pe = rotate_pairs(kv[..., None, rank:], safe_pos, inv_freq, table_scale)[..., 0, :]
+    with jax.named_scope("mla_absorb"):
+        # q_nope W_kvb[k]^T: a head's query against the latent itself
+        w_k = a["wkv_b"].astype(dtype).reshape(rank, H, nope + dv)[..., :nope]
+        q_lat = jnp.einsum("bshd,chd->bshc", q[..., :nope], w_k)
+    to_lanes = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, width - rank - rope)])
+    latent = to_lanes(jnp.concatenate([c_kv, k_pe], axis=-1))[:, :, None, :]
+    return to_lanes(jnp.concatenate([q_lat, q_pe], axis=-1)), latent, c_q
+
+
+def mla_out(config, a, attn):
+    """What a layer's attention adds to the residual stream: ``attn`` ``[b, s,
+    H, kv_lora_rank]``, the kernel's weighted sums of latents, out of the latent
+    through ``W_kvb[v]`` and through ``W_o``."""
+    H, rank = config.num_heads, config.kv_lora_rank
+    nope, dv = config.qk_nope_head_dim, config.v_head_dim
+    dtype = attn.dtype
+    with jax.named_scope("mla_absorb"):
+        w_v = a["wkv_b"].astype(dtype).reshape(rank, H, nope + dv)[..., nope:]
+        heads = jnp.einsum("bshc,chd->bshd", attn, w_v)
+    return heads.reshape(attn.shape[:2] + (H * dv, )) @ a["wo"].astype(dtype)
+
+
+def mla_finish(config, lp, x, attn, live, experts, **routing):
+    """The rest of a layer after the kernel, for a family of MLA layers over a
+    dense stack and an expert stack: the attention's output into the residual
+    stream, the norm, and the layer's FFN: the dense SwiGLU where ``lp`` holds
+    no ``moe``, else ``moe/serving.py sparse_moe_ffn`` over the stack
+    ``experts`` at the layer's index with the family's ``routing`` keywords
+    (``config.top_k``, ``config.norm_topk_prob`` and the shared expert in ``lp``)."""
+    from ..moe.serving import sparse_moe_ffn
+    x = x + mla_out(config, lp["attn"], attn)
+    h = rms_norm(x, lp["mlp_norm"], config.rms_eps)
+    if "moe" not in lp:
+        return x + swiglu_mlp(lp["mlp"], h)
+    out = sparse_moe_ffn({"gate": lp["moe"]["gate"], "shared": lp["moe"]["shared"],
+                          "experts": experts},
+                         h.reshape(-1, h.shape[-1]), config.top_k, config.norm_topk_prob,
+                         live.reshape(-1), layer=lp["moe"]["layer"], **routing)
+    return x + out.reshape(h.shape)
+
+
 def init_params(config: DeepseekV2Config, key, dtype=jnp.float32):
     """``{"embed", "dense_layers": [first_k_dense, ...], "layers": [the expert
     layers, ...], "final_norm", "lm_head"}``: two stacks, attention alike in
@@ -253,12 +313,9 @@ def forward_paged(config: DeepseekV2Config, params, tokens, n_tokens, start_pos,
     """Ragged chunked forward (``transformer.paged_forward`` states the
     contract): absorbed MLA over the latent pool, a dense stack and an expert
     stack, the expert FFN of ``moe/serving.py`` over the experts held here."""
-    from ..moe.serving import sparse_moe_ffn
     if tp_axis is not None:
         raise NotImplementedError("deepseek_v2: tensor-parallel serving is not implemented "
                                   "(the deployment it is cut for is expert-parallel)")
-    H, rank, rope = config.num_heads, config.kv_lora_rank, config.qk_rope_head_dim
-    nope, dv = config.qk_nope_head_dim, config.v_head_dim
     dtype = kv_cache["latent"].dtype
     width = kv_cache["latent"].shape[-1]
     inv_freq, table_scale = rotary_inv_freq(config), rotary_table_scale(config)
@@ -273,37 +330,13 @@ def forward_paged(config: DeepseekV2Config, params, tokens, n_tokens, start_pos,
         return params["embed"][tokens].astype(dtype)
 
     def qkv(lp, x, safe_pos):
-        a = lp["attn"]
         h = rms_norm(x, lp["attn_norm"], config.rms_eps)
-        c_q = rms_norm(h @ a["wq_a"].astype(dtype), a["q_norm"], config.rms_eps)
-        q = (c_q @ a["wq_b"].astype(dtype)).reshape(x.shape[:2] + (H, nope + rope))
-        kv = h @ a["wkv_a"].astype(dtype)
-        c_kv = rms_norm(kv[..., :rank], a["kv_norm"], config.rms_eps)
-        q_pe = rotate_pairs(q[..., nope:], safe_pos, inv_freq, table_scale)
-        k_pe = rotate_pairs(kv[..., None, rank:], safe_pos, inv_freq, table_scale)[..., 0, :]
-        with jax.named_scope("mla_absorb"):
-            # q_nope W_kvb[k]^T: a head's query against the latent itself
-            w_k = a["wkv_b"].astype(dtype).reshape(rank, H, nope + dv)[..., :nope]
-            q_lat = jnp.einsum("bshd,chd->bshc", q[..., :nope], w_k)
-        to_lanes = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, width - rank - rope)])
-        latent = to_lanes(jnp.concatenate([c_kv, k_pe], axis=-1))[:, :, None, :]
-        return to_lanes(jnp.concatenate([q_lat, q_pe], axis=-1)), latent, None
+        q, latent, _ = mla_qkv(config, lp["attn"], h, safe_pos, inv_freq, table_scale, width)
+        return q, latent, None
 
     def finish(lp, x, kept, attn, live):
-        a = lp["attn"]
-        with jax.named_scope("mla_absorb"):
-            w_v = a["wkv_b"].astype(dtype).reshape(rank, H, nope + dv)[..., nope:]
-            heads = jnp.einsum("bshc,chd->bshd", attn, w_v)
-        x = x + heads.reshape(x.shape[:2] + (H * dv, )) @ a["wo"].astype(dtype)
-        h = rms_norm(x, lp["mlp_norm"], config.rms_eps)
-        if "moe" not in lp:
-            return x + swiglu_mlp(lp["mlp"], h)
-        out = sparse_moe_ffn({"gate": lp["moe"]["gate"], "shared": lp["moe"]["shared"],
-                              "experts": experts},
-                             h.reshape(-1, h.shape[-1]), config.top_k, config.norm_topk_prob,
-                             live.reshape(-1), layer=lp["moe"]["layer"], n_group=config.n_group,
-                             topk_group=config.topk_group, scaling=config.routed_scaling_factor)
-        return x + out.reshape(h.shape)
+        return mla_finish(config, lp, x, attn, live, experts, n_group=config.n_group,
+                          topk_group=config.topk_group, scaling=config.routed_scaling_factor)
 
     def head(x):
         return rms_norm(x, params["final_norm"], config.rms_eps) @ params["lm_head"].astype(dtype)
@@ -312,7 +345,7 @@ def forward_paged(config: DeepseekV2Config, params, tokens, n_tokens, start_pos,
         [params["dense_layers"], {**moe_layers, "moe": moe}], tokens, n_tokens, start_pos,
         block_tables, kv_cache, block_size=block_size, live_token_bound=live_token_bound,
         last_rows=last_rows, embed=embed, qkv=qkv, finish=finish, head=head,
-        softmax_scale=softmax_scale(config), value_dim=rank)
+        softmax_scale=softmax_scale(config), value_dim=config.kv_lora_rank)
 
 
 def config_from_hf(hf_config) -> DeepseekV2Config:

@@ -438,6 +438,17 @@ STATE = "state"  # the key of a family's cache tree that holds its fixed per-seq
 STATE_MIXER = "mixer"  # a layer whose parameters hold this key has no attention: ``mix`` runs it
 
 
+class Selection(NamedTuple):
+    """A family's learned selection of the cache (:func:`paged_forward`):
+    ``leaf`` names the pool leaf that holds the index keys (every layer writes
+    it, the kernel never attends it), ``indexer(lp, kept) -> (q_i [b, s, J, Di],
+    w [b, s, J])`` is the family's own projections for the layer, and ``topk``
+    how many cached tokens a query token attends."""
+    leaf: str
+    indexer: Callable
+    topk: int
+
+
 class SeqPlaces(NamedTuple):
     """Where a step's sequences lie on the ``[b, s]`` axes a mixer is handed:
     ``n_tokens`` ``[N]`` live tokens a row; ``row`` / ``col`` None for the padded
@@ -480,7 +491,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                   embed: Callable, qkv: Callable, finish: Callable, head: Callable,
                   window: Optional[int] = None, alibi_slopes=None,
                   softmax_scale: Optional[float] = None, value_dim: Optional[int] = None,
-                  mix: Optional[Callable] = None):
+                  mix: Optional[Callable] = None, selection: Optional[Selection] = None):
     """The one ragged chunked forward over the paged KV pool (FastGen
     model-forward analog, inference/v2/model_implementations + blocked flash):
     every family's ``forward_paged`` is its own arithmetic as four callables
@@ -531,6 +542,27 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     conv): the scan runs over the periods and its body runs the period's
     layers in order, so layers of several kinds share one scan.
 
+    **A learned selection of the cache** (``selection``, a :class:`Selection`:
+    DeepSeek sparse attention, GLM-5's ``glm_moe_dsa``).  A query token then
+    attends only ``selection.topk`` of the cached tokens of its sequence (all of
+    them where it has no more), chosen a layer by the family's *indexer*.  The
+    pool gains a leaf that is WRITTEN a token as any other leaf and NEVER
+    ATTENDED: ``selection.leaf`` names it (``[L, NB, 1, bs, Di]``, a token's
+    index key; its width need not be another leaf's), ``qkv`` returns its row in
+    the leaf's place among the rows, and the kernel is handed the other leaves
+    (K and V, or the one latent leaf with ``value_dim``) exactly as without a
+    selection.  After the write, so that a chunk's own tokens and every earlier
+    chunk's are scored from the pool alike, ``selection.indexer(lp, kept)`` gives
+    the layer's index queries and head weights for the step's tokens, in
+    whichever layout they lie, and ``ops/attention/dsa.py select_keys`` turns
+    them and the index-key leaf (walked through the same offset block table as
+    the kernel's pool) into each token's set: the exact top-k of ``sum_j w_j
+    relu(q_j . k_s)`` over ``s <= position``, ties to the lower position, as a
+    mask over the sequence's positions that ``paged_attention`` /
+    ``paged_attention_flat`` take as ``selection``.  One path for chunks (padded
+    or compacted), decode steps and a burst's body; with no ``selection`` given
+    nothing of it is traced.
+
     **Layers without attention** (``STATE_MIXER`` among ``lp``'s keys: LFM2's
     gated short convolutions, Qwen3-Next's Gated DeltaNet).  Such a layer
     touches neither the pool nor the write plan nor the kernel: ``mix(lp, x,
@@ -568,7 +600,8 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
 
     ``kv_cache`` is whatever tree of ``[L, NB, KV, bs, width]`` leaves the
     family's ``init_paged_cache`` made (``{"k", "v"}``; one latent leaf for
-    MLA), in and out.  The
+    MLA; a latent leaf and a narrower leaf of index keys for a family with a
+    ``selection``), in and out.  The
     layer scan CARRIES the pools whole beside the activations (its ``xs`` is
     ``layers``, the stacked per-layer parameters, and the layer's index); each
     layer writes this step's rows into the carried stack in place
@@ -622,6 +655,9 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     from ..ops.attention.kv_write import kv_write, write_plan
     from ..ops.attention.paged import paged_attention, paged_attention_flat
 
+    if selection is not None:  # where its leaf lies among the pool's (a dict's leaves: by name)
+        from ..ops.attention.dsa import select_keys
+        index_leaf = sorted(k for k in kv_cache if k != STATE).index(selection.leaf)
     n, t = tokens.shape
     state_leaves = []
     if isinstance(kv_cache, dict) and STATE in kv_cache:
@@ -666,9 +702,19 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
         pools = kv_write(pools, rows, first, blk, off, plan)
         # the kernel takes the flat stack as it would one layer's pool (a Pallas
         # operand is materialised, so kpool[l] would be a copy): the table is offset
-        kpool, vpool = pools if value_dim is None else (pools[0], None)
         facts = dict(block_size=block_size, softmax_scale=softmax_scale, window=window,
                      alibi_slopes=alibi_slopes, value_dim=value_dim)
+        attended = pools
+        if selection is not None:
+            # the index-key leaf is scored, not attended: the kernel's pools are the others
+            q_i, w = selection.indexer(lp, kept)
+            if slots is not None:  # the flat tokens as they lie
+                q_i, w = q_i[0], w[0]
+            facts["selection"] = select_keys(
+                q_i, w, pools[index_leaf], block_tables + first, start_pos, n_tokens,
+                topk=selection.topk, chunk=None if slots is None else t)
+            attended = [pool for i, pool in enumerate(pools) if i != index_leaf]
+        kpool, vpool = attended if value_dim is None else (attended[0], None)
         if slots is None:
             attn = paged_attention(q, kpool, vpool, block_tables + first, lengths, start_pos,
                                    n_tokens, **facts)
@@ -753,7 +799,8 @@ def paged_step_slots(module, config, kv_cache, q_dtype, tp: int = 1):
     a compacted pass of ``flat`` slots over ``n`` sequences
     (``paged.flat_token_slots``; ``ServeCounters.attn_token_slots``)."""
     from ..ops.attention.paged import flat_token_slots, step_tile
-    pool = jax.tree_util.tree_leaves({k: v for k, v in kv_cache.items() if k != STATE})[0]
+    unattended = (STATE, getattr(module, "PAGED_SELECT_LEAF", None))  # a state; a leaf of index keys
+    pool = jax.tree_util.tree_leaves({k: v for k, v in kv_cache.items() if k not in unattended})[0]
     (_, _, kvh, bs, width), pool_dtype = pool.shape, pool.dtype  # the array itself is not kept
     value_dim = getattr(module, "paged_value_dim", lambda config: None)(config)
     heads, local_kvh = config.num_heads // tp, kvh // tp if kvh % tp == 0 else kvh

@@ -269,6 +269,31 @@ def _heads_and_rows(t: int, hq: int, kvh: int, dh: int, bs: int, q_bytes: int, p
         f"size (block_size x head_dim must stay under ~{VMEM_BUDGET_BYTES // (8 * kv_bytes)}).")
 
 
+# Tokens whose selections share the sublanes of one float32 tile of the selection's
+# layout (``_selection_tiles``).
+SEL_GROUP = 8
+
+
+def _selection_span(size: int, group: int) -> int:
+    """Groups of ``SEL_GROUP`` tokens that hold the tokens of ``size`` q rows
+    wherever among a group's eight the first of them lies."""
+    return max(2, (max(size // group, 1) + 2 * (SEL_GROUP - 1)) // SEL_GROUP)  # 16 rows at least
+
+
+def _selection_tiles(selection, tokens: int, block_groups: int, steps: int, keys: int):
+    """``selection`` bool ``[tokens', C]`` (the kernel's token axis, ``tokens'
+    <= tokens``; ``C`` positions of each token's own sequence) as the kernel
+    reads it: float32 ``[G, steps, SEL_GROUP, keys]``, token ``g x 8 + i``'s
+    selection among step ``b``'s keys at ``[g, b, i]``: a window of tokens that
+    begins anywhere is then whole leading-axis entries, and what a grid step
+    takes is one rectangle.  ``G`` holds every block of ``block_groups`` groups."""
+    groups = -(-tokens // SEL_GROUP) + block_groups + 1
+    sel = lax.convert_element_type(selection, jnp.float32)
+    sel = lax.pad(sel, np.float32(0), ((0, groups * SEL_GROUP - sel.shape[0], 0),
+                                       (0, steps * keys - sel.shape[1], 0)))
+    return lax.transpose(lax.reshape(sel, (groups, SEL_GROUP, steps, keys)), (0, 2, 1, 3))
+
+
 # Rows of the plan the kernel reads as scalars (``_fetch_plan``).
 BLOCKS, ROW0, SPLITS = 0, 1, 2
 
@@ -296,7 +321,7 @@ def _fetch_plan(lengths, n_tokens, row0, bs: int, maxb: int, group: int, rows: i
 
 def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
                   scale, block_size, group, kvg, tile, slots, head_steps, splits, window, alibi,
-                  value_dim):
+                  value_dim, selected=False):
     # Every program traces and lowers this body once, and a cell meets 38-70
     # programs: scalars and equal shapes go through ``lax`` (a jnp operator
     # costs five times as much to trace), a ``pl.when`` costs 2-3 ms (so what
@@ -304,6 +329,9 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
     # ``slots``.
     if alibi:
         slopes_ref, *rest = rest
+    if selected:  # the window's tokens' selected keys, behind q (``_selection_tiles``)
+        rest = list(rest)
+        sel_ref = rest.pop(1)
     if value_dim is None:
         q_ref, k_hbm, v_hbm, _, o_hbm, acc, m_sc, l_sc, o_buf, k_buf, v_buf, sems, turn = rest
         pools = ((k_hbm, k_buf), (v_hbm, v_buf))
@@ -319,6 +347,9 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
     # row = token * group + (q head within the KV head's group): the rows that
     # hold a token are a prefix, so leaving the others out is a loop bound
     live = lax.max(lax.min(lax.sub(lax.mul(ntok, group), first_row), rows), 0)
+    if selected:  # where the window's first token lies in the first group of SEL_GROUP its block holds
+        sel_base = lax.rem(lax.add(lax.div(lax.mul(plan_ref[ROW0, n], SMALL_ROWS), group),
+                                   lax.mul(r, rows // group)), SEL_GROUP)
 
     def each_block(i, gi, step, half, act):
         """``act`` on the copy of every live block among the ``slots`` table
@@ -389,6 +420,22 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
         mask = lax.le(kpos, seen)  # [1, size, keys]
         if window is not None:
             mask = lax.bitwise_and(mask, lax.gt(kpos, lax.sub(qp, window)))
+        if selected:
+            # A token's selection is one row of ``sel_ref`` for all its ``group`` rows
+            # here: the rows' tokens are picked out of the groups that hold them by a
+            # product with a one-hot [size, tokens] (0 and 1 are exact in any dtype;
+            # no sublane is shuffled and no offset need be a whole tile).
+            span = _selection_span(size, group)
+            at_tok = lax.add(sel_base, r0 // group if isinstance(r0, int) else lax.div(r0, group))
+            first_group = lax.div(at_tok, SEL_GROUP)
+            held = sel_ref[pl.ds(first_group, span), 0].reshape(span * SEL_GROUP, keys)
+            whose = lax.add(lax.div(lax.broadcasted_iota(jnp.int32, (size, span * SEL_GROUP), 0), group),
+                            lax.sub(at_tok, lax.mul(first_group, SEL_GROUP)))
+            onehot = lax.eq(whose, lax.broadcasted_iota(jnp.int32, (size, span * SEL_GROUP), 1))
+            chosen = lax.dot_general(lax.convert_element_type(onehot, jnp.bfloat16),
+                                     lax.convert_element_type(held, jnp.bfloat16),
+                                     (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            mask = lax.bitwise_and(mask, lax.expand_dims(lax.gt(chosen, 0.5), (0, )))
         mask = lax.broadcast_in_dim(mask, s.shape, (0, 1, 2))
         s = lax.select(mask, s, lax.full_like(s, NEG_INF))
 
@@ -511,7 +558,7 @@ def _checked_scale(dh: int, vpool, value_dim, softmax_scale) -> float:
 def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
                     block_size: int, softmax_scale: Optional[float] = None,
                     window: Optional[int] = None, alibi_slopes=None,
-                    value_dim: Optional[int] = None):
+                    value_dim: Optional[int] = None, selection=None):
     """q [N, T, H, Dh]; kpool/vpool [NB, KV, bs, Dh]; tables [N, MAXB] int32;
     lengths/start_pos/n_tokens [N] int32.  Returns [N, T, H, Dh] (rows at
     t >= n_tokens[n] are zero).  ``window`` = sliding-window size (Mistral);
@@ -519,7 +566,12 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
     reference serves ALiBi through its softmax op's alibi path,
     ops/transformer/inference/op_binding/softmax.py).  ``vpool=None``: the
     value of a cached token is the first ``value_dim`` columns of its key (a
-    latent pool); the result is then [N, T, H, value_dim].
+    latent pool); the result is then [N, T, H, value_dim].  ``selection`` bool
+    [N, T, MAXB x bs]: the positions of its own sequence a token attends, and no
+    others (``ops/attention/dsa.py select_keys``: a learned selection of the
+    cache; under the causal limit by construction).  The kernel walks the live
+    blocks as it does without one and masks what was not selected: a step's
+    keys are multiplied whether or not any token of the window selected them.
 
     The padded bucket is the flat form (:func:`paged_attention_flat`) with every
     sequence's rows begun a whole window apart, ``row0[n] = n x splits x rows``:
@@ -528,11 +580,16 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
     scale = _checked_scale(dh, vpool, value_dim, softmax_scale)
     if not _use_pallas():
         return _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
-                               scale, window, alibi_slopes, value_dim)
+                               scale, window, alibi_slopes, value_dim, selection=selection)
     kvh = kpool.shape[1]
     group, dv = hq // kvh, dh if value_dim is None else value_dim
     shape = step_tile(t, hq, kvh, dh, kpool.shape[2], q.dtype, kpool.dtype, value_dim)
     held = shape[2] * shape[1]  # a sequence's rows: ``splits`` windows of ``rows``
+    if selection is not None:  # onto the kernel's token axis: a sequence's ``held`` rows of tokens
+        _check_selected_rows(group, shape)
+        selection = lax.reshape(
+            lax.pad(selection, np.zeros((), bool), ((0, 0, 0), (0, held // group - t, 0), (0, 0, 0))),
+            (n * (held // group), selection.shape[-1]))
     # [N, T, KV, group, Dh] -> [KV, N, T * group, Dh]: a KV head's q rows, a
     # token's group adjacent, each sequence padded with rows that hold no token
     # (through ``lax`` like the body: every program traces and lowers this wrapper too)
@@ -544,7 +601,7 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
     out = _walk(lax.reshape(qr, (kvh, n * held, dh)),
                 lax.mul(lax.iota(jnp.int32, n), np.int32(held // SMALL_ROWS)), kpool, vpool,
                 tables, lengths, start_pos, n_tokens, group=group, shape=shape, scale=scale,
-                window=window, alibi_slopes=alibi_slopes, value_dim=value_dim)
+                window=window, alibi_slopes=alibi_slopes, value_dim=value_dim, selection=selection)
     out = lax.reshape(out, (kvh, n, held, dv))
     if padded:
         out = lax.slice_in_dim(out, 0, t * group, axis=2)
@@ -602,7 +659,7 @@ def _flat_rows(n_tokens, s: int, held: int, align: int):
 def paged_attention_flat(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *, chunk: int,
                          block_size: int, softmax_scale: Optional[float] = None,
                          window: Optional[int] = None, alibi_slopes=None,
-                         value_dim: Optional[int] = None):
+                         value_dim: Optional[int] = None, selection=None):
     """:func:`paged_attention` over a pass's tokens as they lie on ONE flat axis:
     q [S, H, Dh], sequence 0's ``n_tokens[0]`` tokens first, then sequence 1's,
     the tail past ``sum(n_tokens)`` dead (``models.transformer.flat_chunk_indices``);
@@ -622,11 +679,14 @@ def paged_attention_flat(q, kpool, vpool, tables, lengths, start_pos, n_tokens, 
     scale = _checked_scale(dh, vpool, value_dim, softmax_scale)
     if not _use_pallas():
         return _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
-                               scale, window, alibi_slopes, value_dim, chunk=chunk)
+                               scale, window, alibi_slopes, value_dim, chunk=chunk,
+                               selection=selection)
     n, kvh = n_tokens.shape[0], kpool.shape[1]
     group, dv = hq // kvh, dh if value_dim is None else value_dim
     shape = step_tile(chunk, hq, kvh, dh, kpool.shape[2], q.dtype, kpool.dtype, value_dim)
     align = SMALL_ROWS // math.gcd(group, SMALL_ROWS)  # tokens whose rows are whole tiles
+    if selection is not None:
+        _check_selected_rows(group, shape)  # align is 1: the kernel's token axis is the flat one
     held = flat_token_slots(n, s, group) + -(-shape[1] // group)  # and the spare window
     n_tokens = lax.convert_element_type(n_tokens, jnp.int32)
     if align == 1:  # every token's rows are whole tiles: the flat axis as it is
@@ -639,18 +699,31 @@ def paged_attention_flat(q, kpool, vpool, tables, lengths, start_pos, n_tokens, 
                      (kvh, held * group, dh))
     out = _walk(qr, lax.div(lax.mul(first, np.int32(group)), np.int32(SMALL_ROWS)), kpool, vpool,
                 tables, lengths, start_pos, n_tokens, group=group, shape=shape, scale=scale,
-                window=window, alibi_slopes=alibi_slopes, value_dim=value_dim)
+                window=window, alibi_slopes=alibi_slopes, value_dim=value_dim, selection=selection)
     out = lax.reshape(lax.transpose(lax.reshape(out, (kvh, held, group, dv)), (1, 0, 2, 3)),
                       (held, hq, dv))
     return lax.slice_in_dim(out, 0, s, axis=0) if back is None else _rows_at(out, back)
 
 
+def _check_selected_rows(group: int, shape) -> None:
+    """A selection is a token's, and the kernel's rows are (token, q head of
+    the group): it is handed over by token, so a window and a row tile must be
+    whole tokens (or a tile lie inside one) and a token's rows whole sublane tiles."""
+    _, rows, _, tile, _ = shape
+    if group % SMALL_ROWS or rows % group or (tile % group and group % tile):
+        raise ValueError(
+            f"paged_attention: a selection needs the {group} q heads of a KV head to be whole "
+            f"tiles of {SMALL_ROWS} rows and a step's {rows} rows in tiles of {tile} to be whole "
+            f"tokens; serve this family with a head count that is a multiple of {SMALL_ROWS}")
+
+
 def _walk(qr, row0, kpool, vpool, tables, lengths, start_pos, n_tokens, *, group, shape, scale,
-          window, alibi_slopes, value_dim):
+          window, alibi_slopes, value_dim, selection=None):
     """The kernel's call.  ``qr`` [KV, R, Dh]: q KV-major on the flat row axis;
     ``row0`` [N] int32: where each sequence's window of rows begins, in
     sublane tiles of SMALL_ROWS (``R`` holds the last live window whole).  Returns the output
-    on the same rows, [KV, R, Dv]; rows of no token are zero."""
+    on the same rows, [KV, R, Dv]; rows of no token are zero.  ``selection`` bool
+    [tokens, MAXB x bs] on the same axis, a token ``group`` rows: what each may attend."""
     kvh, total, dh = qr.shape
     (n, maxb), bs = tables.shape, kpool.shape[2]
     kvg, rows, splits, tile, slots = shape
@@ -659,7 +732,8 @@ def _walk(qr, row0, kpool, vpool, tables, lengths, start_pos, n_tokens, *, group
     check_block_table_fits(n, maxb, n_vectors=(4 if alibi else 3) + 3)  # and the plan's rows
     kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs, group=group,
                                kvg=kvg, tile=tile, slots=slots, head_steps=kvh // kvg,
-                               splits=splits, window=window, alibi=alibi, value_dim=value_dim)
+                               splits=splits, window=window, alibi=alibi, value_dim=value_dim,
+                               **({} if selection is None else {"selected": True}))
     pools = (kpool, vpool) if value_dim is None else (kpool, )
     def q_window(ni, g, r, b, tables, lengths, start, ntok, plan, *_):
         """Where the step's q rows begin on the flat axis (an element, not a
@@ -676,11 +750,25 @@ def _walk(qr, row0, kpool, vpool, tables, lengths, start_pos, n_tokens, *, group
     # this one computes) and a window's output rows back; q's window comes by the
     # pipeline, from wherever the plan says the sequence's rows begin
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    steps = pl.cdiv(maxb, slots)
+    selections = []
+    if selection is not None:
+        # the window's tokens' selections among the step's keys: the groups of
+        # SEL_GROUP tokens from the one the window's first token lies in
+        groups = -(-(rows // group) // SEL_GROUP) + _selection_span(tile, group)
+        selections = [_selection_tiles(selection, total // group, groups, steps, slots * bs)]
+
+        def selected_window(ni, g, r, b, tables, lengths, start, ntok, plan, *_):
+            _, at, _ = q_window(ni, g, r, b, tables, lengths, start, ntok, plan)
+            return lax.div(lax.div(at, group), SEL_GROUP), b, 0, 0
+
+        select_spec = [pl.BlockSpec((pl.Element(groups), pl.Element(1), pl.Element(SEL_GROUP),
+                                     pl.Element(slots * bs)), selected_window)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6 if alibi else 5,
-        grid=(n, kvh // kvg, splits, pl.cdiv(maxb, slots)),
+        grid=(n, kvh // kvg, splits, steps),
         in_specs=[pl.BlockSpec((pl.Element(kvg), pl.Element(rows), pl.Element(dh)), q_window)]
-        + [anywhere] * (len(pools) + 1),
+        + (select_spec if selections else []) + [anywhere] * (len(pools) + 1),
         out_specs=anywhere,
         scratch_shapes=[
             pltpu.VMEM((kvg, rows, dv), jnp.float32),
@@ -704,13 +792,13 @@ def _walk(qr, row0, kpool, vpool, tables, lengths, start_pos, n_tokens, *, group
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(zeros.shape, zeros.dtype),
-        input_output_aliases={len(scalars) + 1 + len(pools): 0},
+        input_output_aliases={len(scalars) + 1 + len(selections) + len(pools): 0},
         compiler_params=CompilerParams(
             dimension_semantics=("arbitrary", ) * 4,  # the fetch runs ahead across all four
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=_pallas.INTERPRET,
         name="paged_attention",
-    )(*scalars, qr, *pools, zeros)
+    )(*scalars, qr, *selections, *pools, zeros)
 
 
 def _flat_slots(n_tokens, s: int):
@@ -727,7 +815,7 @@ def _flat_slots(n_tokens, s: int):
 
 def _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale,
                     window, alibi_slopes=None, value_dim: Optional[int] = None,
-                    chunk: Optional[int] = None):
+                    chunk: Optional[int] = None, selection=None):
     """Reference-math path: gather the whole table, masked sdpa (the v2
     engine's original implementation — kept as the CPU/parity baseline).  Both
     forms: q [N, T, H, Dh], or with ``chunk`` the flat q [S, H, Dh] of
@@ -737,10 +825,11 @@ def _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale
     if chunk is not None:
         row, col, live = _flat_slots(n_tokens, q.shape[0])
         n = n_tokens.shape[0]
-        padded = jnp.zeros((n, chunk) + q.shape[1:], q.dtype).at[
-            jnp.where(live, row, n), col].set(q, mode="drop")
-        out = _dense_fallback(padded, kpool, vpool, tables, lengths, start_pos, n_tokens, scale,
-                              window, alibi_slopes, value_dim)
+        onto = lambda a: jnp.zeros((n, chunk) + a.shape[1:], a.dtype).at[
+            jnp.where(live, row, n), col].set(a, mode="drop")
+        out = _dense_fallback(onto(q), kpool, vpool, tables, lengths, start_pos, n_tokens, scale,
+                              window, alibi_slopes, value_dim,
+                              selection=None if selection is None else onto(selection))
         return out[row, col]  # a dead slot: sequence 0's first token, as the padded form gathered it
     n, t, hq, dh = q.shape
     maxb = tables.shape[1]
@@ -757,6 +846,8 @@ def _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale
     mask = (kpos <= qp) & (kpos < lengths[:, None, None]) & (qp >= 0)
     if window is not None:
         mask = jnp.logical_and(mask, kpos > qp - window)
+    if selection is not None:  # a learned selection: of what is visible, only what was chosen
+        mask = jnp.logical_and(mask, selection)
     bias = None
     if alibi_slopes is not None:
         bias = (jnp.asarray(alibi_slopes, jnp.float32)[None, :, None, None]

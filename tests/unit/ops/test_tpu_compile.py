@@ -434,6 +434,52 @@ def test_the_gated_delta_scan_compiles_at_the_cells_shapes(chip, budget, n):
     assert memory.alias_size_in_bytes == n * 32 * 128 * 128 * 4 and memory.temp_size_in_bytes == 0
 
 
+def glm_moe_dsa_shapes(chip, layers):
+    """GLM-5 as ``serve.dsa-long-prompt`` holds it (16 of 256 experts, an eighth
+    of the vocabulary; ``layers`` = the three dense layers and ``layers - 3``
+    expert layers, a scan each) over its pool of 1,024 blocks: TWO leaves of
+    unlike widths, the index keys ``[L, 1024, 1, 128, 128]`` and the latent
+    ``[L, 1024, 1, 128, 640]``."""
+    from deepspeed_tpu.models import glm_moe_dsa
+    cfg = glm_moe_dsa.GlmMoeDsaConfig(vocab_size=19360, num_layers=layers, num_local_experts=16)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    return (glm_moe_dsa, cfg,
+            on_chip(jax.eval_shape(lambda: glm_moe_dsa.init_params(cfg, jax.random.PRNGKey(0)))),
+            on_chip(jax.eval_shape(lambda: glm_moe_dsa.init_paged_cache(cfg, 1024, 128))))
+
+
+@pytest.mark.parametrize("n,t,s", [(4, 1024, 1024), (2, 512, 1024), (8, 1, None)],
+                         ids=["chunk-1024", "two-chunks-of-512", "decode"])
+def test_the_selection_compiles_at_the_cells_shapes(chip, n, t, s):
+    """ISSUE 45: GLM-5's 32 index heads of 128 over a 132-slot table of index
+    keys and its 64 heads over the 640-wide latent: the index-score kernel, the
+    exact top-2,048 by bisection and the paged kernel attending the selection,
+    for a compacted chunk at the swept ``token_budget`` (``s`` flat slots), a
+    decode step or a burst's body ``[8, 1]``: two Mosaic
+    kernels, the chip's vector and scalar memory not passed."""
+    from deepspeed_tpu.ops.attention import dsa, paged
+
+    maxb, bs, pool = 132, 128, 7 * 1024
+    lead = (n, t) if s is None else (s, )
+    flat = {} if s is None else {"chunk": t}
+
+    def attend(q, q_i, w, latent, keys, tables, lengths, start, count):
+        chosen = dsa.select_keys(q_i, w, keys, tables, start, count, topk=2048, **flat)
+        entry = paged.paged_attention if s is None else paged.paged_attention_flat
+        return entry(q, latent, None, tables, lengths, start, count, block_size=bs,
+                     softmax_scale=1 / 16, value_dim=512, selection=chosen, **flat)
+
+    avals = (chip(lead + (64, 640), jnp.bfloat16), chip(lead + (32, 128), jnp.bfloat16),
+             chip(lead + (32, ), jnp.float32), chip((pool, 1, bs, 640), jnp.bfloat16),
+             chip((pool, 1, bs, 128), jnp.bfloat16), chip((n, maxb), jnp.int32),
+             chip((n, ), jnp.int32), chip((n, ), jnp.int32), chip((n, ), jnp.int32))
+    compiled = jax.jit(attend).lower(*avals).compile()
+    assert kernel_calls(compiled.as_text()) == {"dsa_index_scores": 1, "paged_attention": 1}
+    # the scores, their image and the selection of 1,024 tokens over 16,896 positions, a few
+    # times over: nothing of the size [tokens, heads, positions] (1,024 x 32 x 16,896 x 4 B = 2.2 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 640 << 20
+
+
 def mistral_module_and_shapes(chip, layers):
     from deepspeed_tpu.models import mistral
     return (mistral, ) + mistral_shapes(chip, layers)
@@ -454,13 +500,17 @@ IN_PLACE = {  # model, (n, t, live_token_bound), a burst's scan around it
     "qwen3-next-state-tree-decode": (qwen3_next_shapes, (8, 1, 2048), False),
     "qwen3-next-state-tree-compacted": (qwen3_next_shapes, (8, 512, 512), False),
     "qwen3-next-state-tree-burst": (qwen3_next_shapes, (8, 1, None), True),
+    "glm-5-two-leaves-decode": (glm_moe_dsa_shapes, (8, 1, 512), False),
+    "glm-5-two-leaves-compacted": (glm_moe_dsa_shapes, (4, 1024, 1024), False),
+    "glm-5-two-leaves-burst": (glm_moe_dsa_shapes, (8, 1, None), True),
 }
 
 
 # layers (every layer of a stack is one scan body: the count sets the pool's
 # size alone) and layer scans of each model's program
 LAYERS_AND_SCANS = {mistral_module_and_shapes: (3, 1), olmoe_shapes: (2, 1),
-                    deepseek_v2_shapes: (5, 2), lfm2_shapes: (10, 1), qwen3_next_shapes: (8, 1)}
+                    deepseek_v2_shapes: (5, 2), lfm2_shapes: (10, 1), qwen3_next_shapes: (8, 1),
+                    glm_moe_dsa_shapes: (5, 2)}
 
 
 @pytest.mark.parametrize("form", list(IN_PLACE))
@@ -484,7 +534,10 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
     sliced or updated whole.  Qwen3-Next (ISSUE 43: heads of 256, a state that
     is a TREE of two leaves, one of them float32 matrices) is held to the same
     for every leaf, in a decode step (the one-token update), a compacted chunk
-    (the scan kernel, once a DeltaNet layer of the period) and a burst."""
+    (the scan kernel, once a DeltaNet layer of the period) and a burst.  GLM-5
+    (ISSUE 45: two pool leaves of unlike widths, index keys beside the latent,
+    one of them scored and never attended) is held to the same for both leaves:
+    one writer call and one paged kernel a scan body, no copy of either."""
     shapes, (n, t, bound), in_a_burst = IN_PLACE[form]
     layers, scans = LAYERS_AND_SCANS[shapes]
     module, cfg, params, kv = shapes(chip, layers=layers)
