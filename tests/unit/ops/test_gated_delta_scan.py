@@ -4,7 +4,8 @@ delta_rule``, which shares no algebra with it): the Pallas kernel in interpret
 mode and the ``lax.scan`` form, sequences as rows of a padded ``[N, T]`` and
 compacted onto one flat axis, lengths that are and are not multiples of the
 chunk, with and without a carried state, decays near 0 and near 1, and the
-one-token update."""
+one-token update; each at one, two and four value heads a key head (the kernel
+takes one or two of a key head's value heads a grid step: ``heads_a_step``)."""
 
 import jax
 import jax.numpy as jnp
@@ -17,12 +18,23 @@ from deepspeed_tpu.ops.linear_attention import (CHUNK, gated_delta_scan, gated_d
                                                 scan_chunks)
 from deepspeed_tpu.ops.linear_attention import gated_delta
 
-HK, HV, DK, DV = 2, 4, 16, 8
+HK, HV, DK, DV = 2, 4, 16, 8  # HK, HV: what the ``heads`` fixture sets for a test
 TOL = 5e-6  # float32 throughout: the chunk's triangular inverse against 64 sequential updates
 
 
+@pytest.fixture(params=[(2, 2), (2, 4), (1, 4)], ids=lambda v: f"hk{v[0]}-hv{v[1]}")
+def heads(request, monkeypatch):
+    """(key heads, value heads): one, two and four value heads a key head, so a
+    step of one head, a key head in one step and a key head in two."""
+    hk, hv = request.param
+    monkeypatch.setitem(globals(), "HK", hk)
+    monkeypatch.setitem(globals(), "HV", hv)
+    assert gated_delta.heads_a_step(hv // hk) == (1 if hk == hv else 2)
+    return request.param
+
+
 @pytest.fixture(params=["scan", "kernel"])
-def form(request, monkeypatch):
+def form(request, monkeypatch, heads):
     monkeypatch.setattr(_pallas, "INTERPRET", request.param == "kernel")
     return request.param
 
@@ -145,11 +157,39 @@ def test_bfloat16_inputs_keep_float32_accumulations(form):
     assert np.abs(np.asarray(new[0]) - want_s).max() < 0.03 * np.abs(want_s).max()
 
 
-def test_the_one_token_update_is_the_rule():
+def test_the_heads_of_a_step_do_not_leak_into_each_other(form):
+    """The value heads a step takes together lie on one diagonal and share its
+    products: what is between their blocks is an exact zero, so the first
+    head's output and new state are the same to the bit whether the others hold
+    nothing (v = 0, beta = 0, no decay, no state) or write at full strength
+    (beta = 1) values and a carried state a thousand times larger."""
+    rng = np.random.default_rng(13)
+    q, k, v, g, beta = draw(rng, 150)
+    state = rng.normal(size=(HV, DK, DV)).astype(np.float32)
+
+    def first_head(others_v, others_g, others_beta, others_state):
+        vv, gg, bb, ss = v.copy(), g.copy(), beta.copy(), state.copy()
+        vv[:, 1:], gg[:, 1:], bb[:, 1:], ss[1:] = others_v, others_g, others_beta, others_state
+        o, new = jax.jit(gated_delta_scan)(*(jnp.asarray(a)[None] for a in (q, k, vv, gg, bb)),
+                                           jnp.asarray(ss)[None], jnp.asarray([150]))
+        return np.asarray(o[0, :, 0]), np.asarray(new[0, 0])
+
+    quiet = first_head(0.0, 0.0, 0.0, 0.0)
+    loud = first_head(1e3 * rng.normal(size=(150, HV - 1, DV)).astype(np.float32), g[:, 1:], 1.0,
+                      1e3 * rng.normal(size=(HV - 1, DK, DV)).astype(np.float32))
+    np.testing.assert_array_equal(quiet[0], loud[0])
+    np.testing.assert_array_equal(quiet[1], loud[1])
+    want_o, want_s = token_by_token((q, k, v, g, beta), jnp.asarray(state))
+    np.testing.assert_allclose(quiet[0], want_o[:, 0], atol=TOL, rtol=0)
+    np.testing.assert_allclose(quiet[1], want_s[0], atol=TOL, rtol=0)
+
+
+def test_the_one_token_update_is_the_rule(heads):
     rng = np.random.default_rng(7)
     q, k, v, g, beta = draw(rng, 3)
     states = rng.normal(size=(3, HV, DK, DV)).astype(np.float32)
-    o, new = gated_delta_step(jnp.repeat(q, 2, 1), jnp.repeat(k, 2, 1), v, g, beta, jnp.asarray(states))
+    o, new = gated_delta_step(jnp.repeat(q, HV // HK, 1), jnp.repeat(k, HV // HK, 1), v, g, beta,
+                              jnp.asarray(states))
     for r in range(3):
         want_o, want_s = token_by_token(tuple(a[r:r + 1] for a in (q, k, v, g, beta)),
                                         jnp.asarray(states[r]))

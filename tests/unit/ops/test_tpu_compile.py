@@ -414,24 +414,31 @@ def qwen3_next_shapes(chip, layers):
             jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype), kv))
 
 
+@pytest.mark.parametrize("hk", [16, 32], ids=["two-value-heads-a-key-head", "one-value-head-a-key-head"])
 @pytest.mark.parametrize("budget,n", [(512, 8), (2048, 8)], ids=lambda v: str(v))
-def test_the_gated_delta_scan_compiles_at_the_cells_shapes(chip, budget, n):
+def test_the_gated_delta_scan_compiles_at_the_cells_shapes(chip, budget, n, hk):
     """ISSUE 43: the chunked-scan kernel at Qwen3-Next's 32 value heads over 16
     key heads of 128 x 128, for a compacted pass of ``budget`` tokens over ``n``
     sequences laid on chunk edges: one Mosaic kernel, the carried matrices
-    aliased in and out, nothing else held."""
+    aliased in and out, nothing else held.  ISSUE 46: a grid step takes a key
+    head's two value heads (v and the output ``[2, 64, 128]``, the rows ``[2, 1,
+    2, 64]``, the state and its scratch ``[2, 128, 128]`` float32, one
+    block-diagonal ``[128, 128]`` chain); with as many key heads as value heads
+    a step is one head, as before: the same kernel, the same name."""
     from deepspeed_tpu.ops.linear_attention import gated_delta
 
+    hv = 32
+    assert gated_delta.heads_a_step(hv // hk) == (2 if hk == 16 else 1)
     chunks = gated_delta.scan_chunks(n, 0, budget)
     t = chunks * gated_delta.CHUNK
-    avals = (chip((4, chunks), jnp.int32), chip((16, t, 128), jnp.bfloat16),
-             chip((16, t, 128), jnp.bfloat16), chip((32, t, 128), jnp.bfloat16),
-             chip((32, chunks, 2, gated_delta.CHUNK), jnp.float32), chip((n, 32, 128, 128), jnp.float32))
-    compiled = jax.jit(lambda *a: gated_delta._walk_pallas(*a, rep=2, interpret=False),
+    avals = (chip((4, chunks), jnp.int32), chip((hk, t, 128), jnp.bfloat16),
+             chip((hk, t, 128), jnp.bfloat16), chip((hv, t, 128), jnp.bfloat16),
+             chip((hv, chunks, 2, gated_delta.CHUNK), jnp.float32), chip((n, hv, 128, 128), jnp.float32))
+    compiled = jax.jit(lambda *a: gated_delta._walk_pallas(*a, rep=hv // hk, interpret=False),
                        donate_argnums=(5, )).lower(*avals).compile()
     assert kernel_calls(compiled.as_text()) == {"gdn_scan": 1}
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes == n * 32 * 128 * 128 * 4 and memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes == n * hv * 128 * 128 * 4 and memory.temp_size_in_bytes == 0
 
 
 def glm_moe_dsa_shapes(chip, layers):
