@@ -111,6 +111,23 @@ KV head with the q group of every head stacked into the rows is then the whole
 of q: ``[S, H, Dk] -> [1, S * H, Dk]`` is a reshape, no transpose, and with a
 group of whole tiles (128) no gather either.
 
+**A selection of the cache** (``selection``: a learned top-k, ``ops/attention/
+dsa.py``; a bit a (token, position) pair).  The walk, the products and the
+softmax are those of a call without one: every live block is fetched and
+multiplied, and what a token did not select is masked.  The selection is a
+TOKEN's and the rows are (token, q head of the group), so it is handed over by
+token (``_selection_tiles``: float32, eight tokens the sublanes of a tile, a
+step's keys its lanes) and a grid step's window of it rides beside q's.  A row
+tile's mask is its tokens' rows, each read where it lies (one row at its own
+sublane: a window's first token lies on no tile edge, and a one-row read needs
+none) and broadcast over the token's ``group`` rows, which are whole sublane
+tiles (``_check_selected_rows``), laid under one another and compared once.  No
+product, no one-hot: the body holds ``q k^T`` and ``p v`` whether or not a
+selection is handed in, and without one it is traced as if there were no such
+thing.  A grid step that does no arithmetic (past a sequence's last live
+block, or of a row of the bucket that holds no token) asks for the window the
+live step before it took, so the pipeline fetches nothing for it.
+
 Off-TPU falls back to the dense gather + masked sdpa (identical math; tests
 compare the two).
 """
@@ -270,14 +287,9 @@ def _heads_and_rows(t: int, hq: int, kvh: int, dh: int, bs: int, q_bytes: int, p
 
 
 # Tokens whose selections share the sublanes of one float32 tile of the selection's
-# layout (``_selection_tiles``).
+# layout (``_selection_tiles``): the kernel reads a token's row at sublane
+# ``token % SEL_GROUP`` of group ``token // SEL_GROUP``.
 SEL_GROUP = 8
-
-
-def _selection_span(size: int, group: int) -> int:
-    """Groups of ``SEL_GROUP`` tokens that hold the tokens of ``size`` q rows
-    wherever among a group's eight the first of them lies."""
-    return max(2, (max(size // group, 1) + 2 * (SEL_GROUP - 1)) // SEL_GROUP)  # 16 rows at least
 
 
 def _selection_tiles(selection, tokens: int, block_groups: int, steps: int, keys: int):
@@ -285,13 +297,23 @@ def _selection_tiles(selection, tokens: int, block_groups: int, steps: int, keys
     <= tokens``; ``C`` positions of each token's own sequence) as the kernel
     reads it: float32 ``[G, steps, SEL_GROUP, keys]``, token ``g x 8 + i``'s
     selection among step ``b``'s keys at ``[g, b, i]``: a window of tokens that
-    begins anywhere is then whole leading-axis entries, and what a grid step
-    takes is one rectangle.  ``G`` holds every block of ``block_groups`` groups."""
+    begins anywhere is then whole leading-axis entries, what a grid step takes
+    is one rectangle, and a token's row in it is one sublane of 32-bit lanes,
+    which the kernel reads wherever it lies and broadcasts over the token's
+    rows.  ``G`` holds every block of ``block_groups`` groups."""
     groups = -(-tokens // SEL_GROUP) + block_groups + 1
     sel = lax.convert_element_type(selection, jnp.float32)
     sel = lax.pad(sel, np.float32(0), ((0, groups * SEL_GROUP - sel.shape[0], 0),
                                        (0, steps * keys - sel.shape[1], 0)))
     return lax.transpose(lax.reshape(sel, (groups, SEL_GROUP, steps, keys)), (0, 2, 1, 3))
+
+
+def _last_live_step(b, blocks, slots: int):
+    """Step ``b`` of a sequence's walk, or the last that holds a live block where
+    ``b`` lies past it (step 0 for a row of the bucket that holds no token, which
+    has none): a step that does no arithmetic asks for the selection's window of
+    the live step before it, so the pipeline fetches nothing for it."""
+    return lax.min(b, lax.max(lax.sub(lax.div(lax.add(blocks, slots - 1), slots), 1), 0))
 
 
 # Rows of the plan the kernel reads as scalars (``_fetch_plan``).
@@ -422,19 +444,16 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
             mask = lax.bitwise_and(mask, lax.gt(kpos, lax.sub(qp, window)))
         if selected:
             # A token's selection is one row of ``sel_ref`` for all its ``group`` rows
-            # here: the rows' tokens are picked out of the groups that hold them by a
-            # product with a one-hot [size, tokens] (0 and 1 are exact in any dtype;
-            # no sublane is shuffled and no offset need be a whole tile).
-            span = _selection_span(size, group)
+            # here: each of the tile's tokens' rows is read where it lies (one row at
+            # its own sublane: no offset need be a whole tile), broadcast over the
+            # token's rows, which are whole sublane tiles, and compared once.
             at_tok = lax.add(sel_base, r0 // group if isinstance(r0, int) else lax.div(r0, group))
-            first_group = lax.div(at_tok, SEL_GROUP)
-            held = sel_ref[pl.ds(first_group, span), 0].reshape(span * SEL_GROUP, keys)
-            whose = lax.add(lax.div(lax.broadcasted_iota(jnp.int32, (size, span * SEL_GROUP), 0), group),
-                            lax.sub(at_tok, lax.mul(first_group, SEL_GROUP)))
-            onehot = lax.eq(whose, lax.broadcasted_iota(jnp.int32, (size, span * SEL_GROUP), 1))
-            chosen = lax.dot_general(lax.convert_element_type(onehot, jnp.bfloat16),
-                                     lax.convert_element_type(held, jnp.bfloat16),
-                                     (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            held = []
+            for j in range(max(size // group, 1)):
+                t = lax.add(at_tok, j)
+                own = sel_ref[lax.div(t, SEL_GROUP), 0, pl.ds(lax.rem(t, SEL_GROUP), 1), :]  # [1, keys]
+                held.append(lax.broadcast_in_dim(own, (min(group, size), keys), (0, 1)))
+            chosen = held[0] if len(held) == 1 else lax.concatenate(held, 0)
             mask = lax.bitwise_and(mask, lax.expand_dims(lax.gt(chosen, 0.5), (0, )))
         mask = lax.broadcast_in_dim(mask, s.shape, (0, 1, 2))
         s = lax.select(mask, s, lax.full_like(s, NEG_INF))
@@ -572,6 +591,8 @@ def paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens, *,
     cache; under the causal limit by construction).  The kernel walks the live
     blocks as it does without one and masks what was not selected: a step's
     keys are multiplied whether or not any token of the window selected them.
+    A token's row of it reaches the token's ``H / KV`` rows of the kernel by a
+    broadcast (no product), so that group must be whole tiles of 16 rows.
 
     The padded bucket is the flat form (:func:`paged_attention_flat`) with every
     sequence's rows begun a whole window apart, ``row0[n] = n x splits x rows``:
@@ -754,13 +775,15 @@ def _walk(qr, row0, kpool, vpool, tables, lengths, start_pos, n_tokens, *, group
     selections = []
     if selection is not None:
         # the window's tokens' selections among the step's keys: the groups of
-        # SEL_GROUP tokens from the one the window's first token lies in
-        groups = -(-(rows // group) // SEL_GROUP) + _selection_span(tile, group)
+        # SEL_GROUP tokens from the one the window's first token lies in (one more
+        # than the window's tokens fill: the first lies anywhere among its group's eight)
+        groups = -(-(rows // group) // SEL_GROUP) + 1
         selections = [_selection_tiles(selection, total // group, groups, steps, slots * bs)]
 
         def selected_window(ni, g, r, b, tables, lengths, start, ntok, plan, *_):
             _, at, _ = q_window(ni, g, r, b, tables, lengths, start, ntok, plan)
-            return lax.div(lax.div(at, group), SEL_GROUP), b, 0, 0
+            return (lax.div(lax.div(at, group), SEL_GROUP),
+                    _last_live_step(b, plan[BLOCKS, ni], slots), 0, 0)
 
         select_spec = [pl.BlockSpec((pl.Element(groups), pl.Element(1), pl.Element(SEL_GROUP),
                                      pl.Element(slots * bs)), selected_window)]
