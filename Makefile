@@ -1,11 +1,10 @@
 # Test lanes (VERDICT r3 #9: kernel-parity regressions must not hide behind
 # the default `-m "not slow"` lane).  `make fast_then_slow` is the CI target;
-# it also writes TESTS_LANES.json with both lanes' counts, which bench.py
-# folds into the bench artifact's extra section.
+# its last line is a JSON summary of every lane's count.
 
 PY ?= python
 
-.PHONY: test test-slow fast_then_slow bench telemetry-smoke resilience-smoke serving-resilience-smoke serving-fastpath-smoke tracing-smoke ops-smoke ops-stress-smoke kv-obs-smoke prefix-cache-smoke serving-recovery-smoke elastic-smoke perf-smoke fleet-smoke qos-smoke spec-decode-smoke bench-diff drift-families lint lint-baseline lint-api-surface lint-mesh-manifest lint-changed lint-suppressions
+.PHONY: test test-slow fast_then_slow telemetry-smoke resilience-smoke serving-resilience-smoke serving-fastpath-smoke tracing-smoke ops-smoke ops-stress-smoke kv-obs-smoke prefix-cache-smoke serving-recovery-smoke elastic-smoke perf-smoke fleet-smoke qos-smoke spec-decode-smoke drift-families lint lint-baseline lint-api-surface lint-mesh-manifest lint-changed lint-suppressions
 
 test:
 	$(PY) -m pytest tests/ -q
@@ -56,9 +55,6 @@ test-slow:
 
 fast_then_slow:
 	$(PY) run_tests.py
-
-bench:
-	$(PY) bench.py
 
 # 3-step CPU train loop with telemetry enabled; asserts 3 well-formed JSONL
 # records (loss/step_time/throughput/mfu/hbm) + jax.profiler trace files
@@ -174,10 +170,3 @@ qos-smoke:
 # strict-parse and agree with the engine counters, spec-off exposition clean
 spec-decode-smoke:
 	JAX_PLATFORMS=cpu $(PY) run_tests.py --spec-decode-smoke
-
-# bench regression gate (ISSUE 16): bin/dstpu-benchdiff under the committed
-# benchtrack.json policy — a trajectory pair whose base timed out must pass and
-# an injected 30% serving-throughput regression must exit 1 (records built from
-# literals in a temp directory)
-bench-diff:
-	$(PY) run_tests.py --bench-diff

@@ -1,8 +1,9 @@
 """Published per-chip peaks, keyed by the ``device_kind`` JAX reports.
 
-The one table ``bench.py`` and ``monitor/telemetry.py`` read.  A device that is
-not in it has no peak: ``device_peaks`` raises, and a utilization against an
-unknown device is ``null`` or an error, never another chip's figure.
+The table ``monitor/telemetry.py`` reads (``chipbench/reduce/peaks.json`` holds
+the benchmark's copy).  A device that is not in it has no peak: ``device_peaks``
+raises, and a utilization against an unknown device is ``null`` or an error,
+never another chip's figure.
 
 Source: Google Cloud TPU documentation, the "System architecture" page of each
 version (v5e: 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s, 1,600

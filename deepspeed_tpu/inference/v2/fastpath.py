@@ -24,7 +24,7 @@ This module holds the three host-link levers the engine composes:
 - :class:`ServeCounters` — host-sync / dispatch / upload / compile counters
   that make the win provable (the fastpath tests assert <=1 host sync per
   serve-loop iteration in steady-state decode and a bounded compile count
-  across a mixed-arrival scenario; bench.py reports syncs-per-token).
+  across a mixed-arrival scenario).
 
 Nothing here schedules or owns sequences — that stays in the scheduler and
 the ragged manager; this is purely the host<->device traffic layer.

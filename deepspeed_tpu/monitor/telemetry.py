@@ -9,8 +9,8 @@ tokens/sec, model-FLOPs-utilization, HBM high-water mark — and fans it out to
 
 - ``MonitorMaster`` (TensorBoard / W&B / CSV writers, rank-0 only), and
 - a rank-0 JSONL sink (``TelemetryConfig.jsonl_path``), one json object per
-  line, machine-readable for regression tracking (bench.py computes the same
-  MFU externally; this makes the engine report about itself).
+  line, machine-readable for regression tracking (the engine's report about
+  itself).
 
 It also owns config-driven ``jax.profiler`` capture windows
 (``profile_step_start``/``profile_step_stop`` → ``start_trace``/``stop_trace``
@@ -18,13 +18,12 @@ into a TensorBoard-readable directory) and hands out ``StepTraceAnnotation`` /
 ``TraceAnnotation`` context managers so the engine's step, batch-prep and
 checkpoint IO show up as named ranges in the trace.
 
-MFU derivation (ISSUE: bench.py parity): ``flops_per_step`` comes ONCE from
-the XLA cost analysis of the compiled train step (FlopsProfiler), divided by
-the measured wall-time and the per-chip peak FLOPs × chip count.  Peak FLOPs
-resolve from ``TelemetryConfig.peak_flops_per_chip`` or from the device kind
-(accelerator/device_peaks.py, the table bench.py reads too); a device that is
-not in the table (CPU test backend) yields ``mfu: null`` unless the config
-pins a peak.
+MFU derivation: ``flops_per_step`` comes ONCE from the XLA cost analysis of the
+compiled train step (FlopsProfiler), divided by the measured wall-time and the
+per-chip peak FLOPs × chip count.  Peak FLOPs resolve from
+``TelemetryConfig.peak_flops_per_chip`` or from the device kind
+(accelerator/device_peaks.py); a device that is not in the table (CPU test
+backend) yields ``mfu: null`` unless the config pins a peak.
 """
 
 import json

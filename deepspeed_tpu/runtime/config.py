@@ -81,8 +81,6 @@ class OffloadOptimizerConfig(ConfigModel):
     nvme_path: Optional[str] = None
     buffer_count: int = Field(4, ge=1)
     pin_memory: bool = False
-    pipeline_read: bool = False
-    pipeline_write: bool = False
     fast_init: bool = False
     ratio: float = Field(1.0, ge=0.0, le=1.0)
 

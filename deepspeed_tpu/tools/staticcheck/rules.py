@@ -155,12 +155,11 @@ class HostSyncInHotPath(Rule):
                    "monitor/exposition.py, monitor/ops_server.py) AND the "
                    "KV-pool observability layer (inference/v2/kv_metrics.py) "
                    "AND the serving perf observatory (monitor/perf.py) AND "
-                   "the spec-decode layer (inference/v2/spec_decode.py) AND "
-                   "the bench regression tooling (tools/benchtrack/) "
+                   "the spec-decode layer (inference/v2/spec_decode.py) "
                    "any explicit device fetch (np.asarray/np.array/device_get/"
                    "block_until_ready/.item) anywhere in the file — liveness "
-                   "stamps, metrics scrapes, pool census hooks, phase/compile "
-                   "instruments and bench diffs are contractually "
+                   "stamps, metrics scrapes, pool census hooks and phase/compile "
+                   "instruments are contractually "
                    "zero-device-sync (float() on host config "
                    "values stays legal there; float-of-device-value isn't "
                    "statically separable from it)")
@@ -199,11 +198,6 @@ class HostSyncInHotPath(Rule):
     # engine already owns — a device fetch here would charge every serve
     # iteration a hidden sync, so the whole file is scanned
     PERF_PATH_FRAGMENT = "monitor/perf.py"
-    # the bench regression tooling (ISSUE 16) must run on accelerator-free
-    # CI hosts: it reads committed JSON records only, so ANY device fetch
-    # (or jax/numpy dependency sneaking one in) is a contract break — the
-    # fragment is a directory, matched anywhere in the relpath
-    BENCHTRACK_PATH_FRAGMENT = "tools/benchtrack/"
     # the fleet router (ISSUE 17) holds the same whole-file promise, stricter
     # than the per-function v2 scan that would otherwise apply: routing and
     # failover decisions read health dicts and journal files only — a device
@@ -259,14 +253,6 @@ class HostSyncInHotPath(Rule):
                 "zero-device-sync: it consumes only the engine's injectable "
                 "clock and host floats, and its hooks run inside the serve "
                 "loop at every iteration and compile seam")
-            return
-        if self.BENCHTRACK_PATH_FRAGMENT in relpath:
-            yield from self._check_zero_sync_file(
-                module, jit_roots,
-                " in tools/benchtrack/ — bench regression diffs are "
-                "contractually zero-device-sync: they run on accelerator-free "
-                "CI hosts over committed JSON records, so a device fetch "
-                "here breaks the pure-stdlib contract")
             return
         if relpath.endswith(self.ROUTER_PATH_FRAGMENT):
             yield from self._check_zero_sync_file(

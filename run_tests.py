@@ -3,9 +3,8 @@
 
 VERDICT r3 weak #7 / next #9: the default lane deselects the deepest kernel
 parity tests (`pytest.ini` addopts `-m "not slow"`); this runner makes the
-full sweep one command and leaves a machine-readable artifact
-(TESTS_LANES.json) that bench.py folds into the bench output so every round's
-artifact shows both lanes' counts.
+full sweep one command, and its last line is a JSON summary of every lane's
+count.
 
 Exit code is non-zero if EITHER lane fails.
 """
@@ -1070,8 +1069,7 @@ def serving_recovery_smoke():
     #      of the TYPICAL serve pass (median over 9 passes).
     # An end-to-end wall-clock A/B delta is deliberately NOT the meter: two
     # IDENTICAL engines measure ±10% apart under CI load, an order of
-    # magnitude above the journal's true cost; bench.py reports the
-    # end-to-end serving_mixed_journal_overhead_pct on quiet bench hosts.
+    # magnitude above the journal's true cost.
     on = InferenceEngineV2(
         llama, cfg, params,
         config={"dtype": "float32",
@@ -1733,59 +1731,6 @@ def spec_decode_smoke():
     return 0
 
 
-def run_bench_diff_lane():
-    """bench regression gate (ISSUE 16): a trajectory pair whose base timed
-    out must pass (a timed-out record carries zero metrics -> all-missing
-    verdicts, never a failure), and an injected-regression fixture must exit 1
-    — both via the standalone bin/dstpu-benchdiff CLI (same loading discipline
-    as the lint lane: works even when the library is broken at import time).
-    The records are built here from literals, in a temp directory."""
-    import os
-    import tempfile
-    t0 = time.time()
-    root = os.path.dirname(os.path.abspath(__file__))
-    cli = os.path.join(root, "bin", "dstpu-benchdiff")
-    policy = os.path.join(root, "benchtrack.json")
-    tmp = tempfile.mkdtemp(prefix="dstpu_benchdiff_")
-
-    def write(name, obj):
-        path = os.path.join(tmp, name)
-        with open(path, "w") as fh:
-            json.dump(obj, fh)
-        return path
-
-    metrics = {"serving_mixed_tok_s": 90.4, "serving_mixed_p50_step_ms": 113.9,
-               "decode_tok_s": 1907.0, "mfu": 0.58}
-    timed_out = write("timed_out.json", {
-        "n": 4, "cmd": "python bench.py", "rc": 124, "parsed": None,
-        "tail": "[INFO] Engine: zero_stage=3 dp_world=1 batch=6\n"})
-    completed = write("completed.json", {
-        "n": 5, "cmd": "python bench.py", "rc": 0, "parsed": None,
-        "tail": json.dumps(metrics)[1:-1]})
-    committed = subprocess.run(
-        [sys.executable, cli, timed_out, completed, "--policy", policy],
-        capture_output=True, text=True)
-    # injected regression: the same metrics with the serving throughput cut
-    # 30% — must trip the gate
-    injected = subprocess.run(
-        [sys.executable, cli, write("base.json", metrics),
-         write("degraded.json", {**metrics, "serving_mixed_tok_s": 90.4 * 0.7}),
-         "--policy", policy],
-        capture_output=True, text=True)
-    dt = time.time() - t0
-    ok = committed.returncode == 0 and injected.returncode == 1
-    tail = (f"trajectory pair rc={committed.returncode} (want 0), "
-            f"injected regression rc={injected.returncode} (want 1)")
-    print(f"[bench_diff] {tail}  ({dt:.0f}s)")
-    if not ok:
-        print(committed.stdout[-2000:])
-        print(injected.stdout[-2000:])
-        print(committed.stderr[-1000:], file=sys.stderr)
-        print(injected.stderr[-1000:], file=sys.stderr)
-    return {"name": "bench_diff", "rc": 0 if ok else 1, "seconds": round(dt, 1),
-            "summary": tail}
-
-
 def run_smoke_lane(name: str, flag: str):
     """Run one of the smoke entry points as its own recorded lane (subprocess:
     each smoke pins its own env and must not contaminate the pytest lanes)."""
@@ -1916,14 +1861,11 @@ def main():
              run_smoke_lane("fleet_smoke", "--fleet-smoke"),
              run_smoke_lane("qos_smoke", "--qos-smoke"),
              run_smoke_lane("spec_decode_smoke", "--spec-decode-smoke"),
-             run_bench_diff_lane(),
              run_drift_families_lane(),
              run_lane("default", []), run_lane("slow", ["-m", "slow"])]
-    out = {"lanes": lanes, "ok": all(l["rc"] == 0 for l in lanes)}
-    with open("TESTS_LANES.json", "w") as fh:
-        json.dump(out, fh, indent=1)
-    print(json.dumps({"lanes": {l["name"]: l.get("passed", 0) for l in lanes}, "ok": out["ok"]}))
-    return 0 if out["ok"] else 1
+    ok = all(l["rc"] == 0 for l in lanes)
+    print(json.dumps({"lanes": {l["name"]: l.get("passed", 0) for l in lanes}, "ok": ok}))
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":
@@ -1957,8 +1899,6 @@ if __name__ == "__main__":
         sys.exit(qos_smoke())
     if "--spec-decode-smoke" in sys.argv:
         sys.exit(spec_decode_smoke())
-    if "--bench-diff" in sys.argv:
-        sys.exit(run_bench_diff_lane()["rc"])
     if "--lint" in sys.argv:
         sys.exit(run_lint_lane()["rc"])
     if "--drift-families" in sys.argv:

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import bench
 from chipbench.entries.serve import LogitSpy
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.fastpath import (PENDING_TOKEN, DeferredTokens,
@@ -19,6 +18,7 @@ from deepspeed_tpu.models import bloom, falcon, gptj, llama, mistral, opt, phi, 
 from deepspeed_tpu.models.transformer import flat_slots
 from deepspeed_tpu.parallel import MeshTopology
 from tests.unit.fault_injection_serving import FakeClock, FaultyBlockedAllocator
+from tests.unit.inference.scenario import run_scenario
 
 NO_FUSION = 10**6  # fusion_min_steps too high to ever fire: forces stepwise
 
@@ -150,22 +150,45 @@ def test_fused_decode_is_sub_one_sync_per_token():
 
 
 def test_bounded_compiles_across_three_wave_scenario():
-    """The bench mixed-arrival scenario (3 waves landing mid-decode): the cold
+    """The mixed-arrival scenario (3 waves landing mid-decode): the cold
     pass compiles a bounded program set; an identical warm pass — same widths
     thanks to the sticky-table reset on idle — compiles NOTHING."""
     eng = _engine(num_blocks=128, max_blocks_per_seq=16, token_budget=64)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 128, 16).tolist() for _ in range(6)]
     arrivals = {0: [0, 1, 2], 5: [3], 9: [4, 5]}
-    bench._run_serving_scenario(eng, prompts, arrivals, max_new=8)
+    run_scenario(eng, prompts, arrivals, max_new=8)
     cold = eng.counters.snapshot()
     assert 0 < cold["compiles"] <= 24, cold
-    tokens, _, _, stalled, link = bench._run_serving_scenario(eng, prompts, arrivals,
-                                                              max_new=8)
+    tokens, _, _, stalled, link = run_scenario(eng, prompts, arrivals, max_new=8)
     assert not stalled and tokens == 6 * 8
     assert link["compiles"] == 0, link
     assert link["burst_tokens"] > 0
     assert link["host_syncs"] < tokens
+
+
+def test_serving_scenario_stall_guard():
+    """A scheduler that never emits must not spin the scenario forever."""
+
+    class StuckEngine:
+        def __init__(self):
+            self.manager = type("M", (), {"seqs": {0: type("S", (), {
+                "pending_tokens": 1, "done": False})()}})()
+            self.counters = ServeCounters()
+        def put(self, uids, prompts):
+            pass
+        def step(self):
+            return {}
+        def decode_burst(self, k, **kw):
+            return None  # not fusible: the scenario must fall back to step()
+        def flush(self, uid):
+            pass
+
+    tokens, dt, lats, hit_stall, link = run_scenario(
+        StuckEngine(), [[1, 2]], {0: [0]}, max_new=4)
+    assert tokens == 0 and lats == []  # bailed via the stall counter
+    assert hit_stall  # and the bail is reported, not silent (ISSUE 4 review)
+    assert link["host_syncs"] == 0  # nothing ever reached the device
 
 
 # ------------------------------------------------------------ rng determinism

@@ -15,12 +15,12 @@ import jax
 import numpy as np
 import pytest
 
-import bench
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.fastpath import PENDING_TOKEN
 from deepspeed_tpu.parallel import MeshTopology
 from deepspeed_tpu.models import llama
 from tests.unit.fault_injection_serving import FakeClock, FaultyBlockedAllocator
+from tests.unit.inference.scenario import run_scenario
 
 NO_FUSION = 10**6  # fusion_min_steps too high to ever fire: forces stepwise
 
@@ -148,11 +148,10 @@ def test_tp2_bounded_compiles_across_three_wave_scenario():
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 128, 16).tolist() for _ in range(6)]
     arrivals = {0: [0, 1, 2], 5: [3], 9: [4, 5]}
-    bench._run_serving_scenario(eng, prompts, arrivals, max_new=8)
+    run_scenario(eng, prompts, arrivals, max_new=8)
     cold = eng.counters.snapshot()
     assert 0 < cold["compiles"] <= 24, cold
-    tokens, _, _, stalled, link = bench._run_serving_scenario(eng, prompts,
-                                                              arrivals, max_new=8)
+    tokens, _, _, stalled, link = run_scenario(eng, prompts, arrivals, max_new=8)
     assert not stalled and tokens == 6 * 8
     assert link["compiles"] == 0, link
     assert link["burst_tokens"] > 0

@@ -1,4 +1,4 @@
-"""Data pipeline / compression / 1-bit / PLD / eigenvalue tests
+"""Data pipeline / compression / 1-bit / PLD tests
 (reference tests/unit/runtime/test_data.py, compression/, onebit/, test_pld.py)."""
 
 import functools
@@ -15,7 +15,6 @@ from deepspeed_tpu.compression import (fake_quantize, init_compression, row_prun
 from deepspeed_tpu.runtime.comm import onebit_allreduce
 from deepspeed_tpu.runtime.data_pipeline import (CurriculumScheduler, DeepSpeedDataSampler,
                                                  RandomLTDScheduler, random_ltd_layer)
-from deepspeed_tpu.runtime.eigenvalue import Eigenvalue
 from deepspeed_tpu.runtime.progressive_layer_drop import ProgressiveLayerDrop, layer_keep_prob
 
 from .simple_model import init_mlp_params, mlp_loss_fn, random_batch
@@ -122,7 +121,7 @@ def test_onebit_allreduce_error_feedback_converges():
     assert np.corrcoef(accum / 24, ref)[0, 1] > 0.97
 
 
-# ------------------------------------------------------------------ PLD + eig
+# ------------------------------------------------------------------------ PLD
 def test_pld_theta_schedule():
     pld = ProgressiveLayerDrop(theta=0.5, gamma=0.01)
     t0 = pld.update_state(0)
@@ -132,19 +131,6 @@ def test_pld_theta_schedule():
     assert abs(t_end - 0.5) < 1e-3
     assert layer_keep_prob(0.5, 9, 10) == pytest.approx(0.5)
     assert layer_keep_prob(0.5, 0, 10) == pytest.approx(0.95)
-
-
-def test_eigenvalue_power_iteration_quadratic():
-    # loss = 0.5 x^T A x has Hessian A; dominant eigenvalue known
-    a = np.diag([5.0, 2.0, 1.0]).astype(np.float32)
-
-    def loss_fn(p, batch, rng):
-        x = p["x"]
-        return 0.5 * x @ jnp.asarray(a) @ x
-
-    eig = Eigenvalue(max_iter=50, tol=1e-4)
-    out = eig.compute_eigenvalue(loss_fn, {"x": jnp.asarray([1.0, 1.0, 1.0])}, None)
-    assert abs(out["eigenvalue"] - 5.0) < 0.05
 
 
 def test_head_prune_mask_whole_heads():

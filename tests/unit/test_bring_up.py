@@ -6,6 +6,7 @@ sequence fits the window, the flash kernel per shard under a mesh, and a
 
 import importlib.util
 import os
+import re
 import subprocess
 import sys
 
@@ -20,9 +21,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 # ------------------------------------------------------- one process per chip
 def test_imports_initialise_no_backend():
     """A parent that touches a device holds the chip; the modules whose
-    processes spawn workers (and bench) must stay off it at import."""
+    processes spawn workers must stay off it at import."""
     code = (
-        "import deepspeed_tpu, bench\n"
+        "import deepspeed_tpu\n"
         "import deepspeed_tpu.inference.v2.engine_v2, deepspeed_tpu.inference.v2.supervisor\n"
         "import deepspeed_tpu.elasticity.elastic_agent, deepspeed_tpu.launcher.runner\n"
         "import deepspeed_tpu.utils.compile_cache, deepspeed_tpu.accelerator.device_peaks\n"
@@ -73,6 +74,47 @@ def test_no_literal_cache_dir_outside_the_helper():
     assert hits == ["deepspeed_tpu/utils/compile_cache.py"]
 
 
+# ------------------------------------------------------------- one instrument
+# Names of what PR 48 retired (the pre-chip harness, its gate, its records, two
+# orphans), each in parts so that this file does not hold them.
+RETIRED = {
+    "harness-import": r"import " + r"bench\b",
+    "gate-package": "bench" + "track",
+    "gate-cli": "dstpu-" + "benchdiff",
+    "scripts-dir": "bench" + "marks/",
+    "lanes-record": "TESTS_" + "LANES",
+    "infinity-script": "run_infinity" + "_7b",
+    "hessian-module": "eigen" + "value",
+    "offload-dead-keys": "pipeline" + "_read",
+}
+
+
+@pytest.fixture(scope="module")
+def live_lines():
+    """What speaks for the tree as it is, read once: every ``*.py``, the
+    Makefile, pytest.ini, the root's ``*.json``, the README and the verify
+    notes.  The records that tell history (CHANGES, ROADMAP, PERF, ISSUE, ...)
+    are not among them."""
+    paths = [os.path.join(REPO, name) for name in sorted(os.listdir(REPO))
+             if name in ("Makefile", "pytest.ini", "README.md") or name.endswith(".json")]
+    paths.append(os.path.join(REPO, ".claude", "skills", "verify", "SKILL.md"))
+    for root, dirs, files in os.walk(REPO):
+        dirs[:] = [d for d in dirs if not d.startswith(".") and d not in ("chiprun_out",
+                                                                          "__pycache__")]
+        paths += [os.path.join(root, f) for f in sorted(files) if f.endswith(".py")]
+    lines = []
+    for path in paths:
+        with open(path, encoding="utf-8") as fh:
+            lines += [(f"{os.path.relpath(path, REPO)}:{i}", line) for i, line in enumerate(fh, 1)]
+    return lines
+
+
+@pytest.mark.parametrize("name", sorted(RETIRED))
+def test_nothing_live_names_a_retired_file(name, live_lines):
+    pattern = re.compile(RETIRED[name])
+    assert [where for where, line in live_lines if pattern.search(line)] == []
+
+
 # ----------------------------------------------------------------- peak table
 def test_peaks_known_kind():
     from deepspeed_tpu.accelerator.device_peaks import device_peaks
@@ -88,13 +130,12 @@ def test_peaks_unknown_kind_is_an_error(kind):
 
 
 def test_unknown_device_has_no_mfu_peak():
-    """Telemetry's MFU is null, bench's raises: never the v5e figure."""
-    import bench
-    from deepspeed_tpu.accelerator.device_peaks import UnknownDeviceError
+    """Telemetry's MFU is null, the table's lookup raises: never the v5e figure."""
+    from deepspeed_tpu.accelerator.device_peaks import UnknownDeviceError, device_peaks
     from deepspeed_tpu.monitor.telemetry import detect_peak_flops_per_chip
     assert detect_peak_flops_per_chip() is None
     with pytest.raises(UnknownDeviceError):
-        bench.detect_peak()
+        device_peaks(jax.devices()[0].device_kind)
 
 
 def test_autotuner_refuses_to_guess_device_memory():

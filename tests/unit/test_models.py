@@ -43,6 +43,16 @@ def test_llama_gqa_heads():
     assert np.isfinite(np.asarray(logits)).all()
 
 
+def test_llama_gqa_wk_width_at_a_wide_shape():
+    """The kv projection is ``KV * head_dim`` wide where heads and KV heads
+    differ (20 heads over 4 KV heads of 128), by shapes alone."""
+    D, F, H, KV = 2560, 6912, 20, 4
+    cfg = llama.LlamaConfig(hidden_size=D, intermediate_size=F, num_heads=H,
+                            num_kv_heads=KV, num_layers=2)
+    p = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    assert p["layers"]["attn"]["wk"].shape == (2, D, KV * (D // H))
+
+
 def test_llama_hf_parity():
     """Logit parity against transformers' LlamaForCausalLM with copied weights."""
     torch = pytest.importorskip("torch")

@@ -180,8 +180,7 @@ def test_llama_trains_with_ring_attention():
 
 def test_ring_memory_beats_ulysses_at_long_seq():
     """VERDICT r3 #5 'done': ring's compiled per-device peak memory undercuts
-    Ulysses by the O(S/P) vs O(S) activation gap (crossover measured at 131k
-    tokens on a v5e budget — benchmarks/bench_ring_vs_ulysses.py)."""
+    Ulysses by the O(S/P) vs O(S) activation gap."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
     from deepspeed_tpu.sequence.layer import ulysses_attention
