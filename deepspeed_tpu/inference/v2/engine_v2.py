@@ -23,7 +23,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from ...compat import shard_map
-from ...models.transformer import STATE, flat_slots, paged_step_slots
+from ...models.transformer import STATE, TALLY, flat_slots, paged_step_slots
 from ...monitor import compile_events
 from ...monitor.perf import PHASES, CompileLedger, StepPhaseProfiler
 from ...monitor.tracing import RequestTracer
@@ -297,6 +297,8 @@ class InferenceEngineV2:
             counted.update(scan=model_module.state_scan(model_config))
         if hasattr(model_module, "selected_keys"):  # a learned selection of the cache counts its keys
             counted.update(selected=(*model_module.selected_keys(model_config), block_size))
+        if hasattr(model_module, "pick_tallies"):  # picks whose kind only the device knows
+            counted.update(tallied=model_module.pick_tallies(model_config))
         self.counters = ServeCounters(**counted)
         # serving performance observatory (ISSUE 16): the compile ledger is
         # always on (no clock reads, no device work) and is the single source
@@ -541,9 +543,12 @@ class InferenceEngineV2:
         ([L, num_blocks, ...] — models/transformer.py), which this relies on."""
         fn = self._fwd_cache.get("cow_copy")
         if fn is None:
+            pools = [name for name in self.kv if name != TALLY]  # running tallies hold no block
+
             def cow_copy(kv, pair):
-                return jax.tree_util.tree_map(
-                    lambda leaf: leaf.at[:, pair[1]].set(leaf[:, pair[0]]), kv)
+                return {**kv, **jax.tree_util.tree_map(
+                    lambda leaf: leaf.at[:, pair[1]].set(leaf[:, pair[0]]),
+                    {name: kv[name] for name in pools})}
             if self.tp > 1:
                 # the pool's head-sharding must survive the copy: pin
                 # out_shardings to the live pool's NamedShardings so the
@@ -1709,6 +1714,10 @@ class InferenceEngineV2:
             self._tell("serve_profile_begin")
             self._serve_loop(uids, my, results, produced, max_new_tokens=max_new_tokens,
                              eos_token_id=eos_token_id, greedy=greedy, strict=strict)
+            if self.counters.tallied is not None:
+                # the wave is over and its tokens are on the host: one fetch of the
+                # device's running pick tallies, never one a step
+                self.counters.absorb_tallies(materialize(self.kv[TALLY], self.counters))
             # post-pass pool state: final census/forecast refresh, then the
             # census-vs-allocator partition invariant (the PR-4 double-free
             # guard, continuously checked)

@@ -129,6 +129,20 @@ class ServeCounters:
     ``dsa_attended_keys``  key rows the attention kernel multiplied the token's
     rows with: every step of ``kernel_slots(t)`` blocks up to the sequence's
     length, selected or not (``ops/attention/paged.py`` masks what was not)
+
+    A family whose router may pick experts that compute nothing (ISSUE 49; the
+    model module states ``pick_tallies``; absent from a snapshot for every
+    other: ``TALLIED_FIELDS``).  Which kind a pick is only the device knows: the
+    family's forward adds each pass's counts to ``kv_cache[TALLY]`` on the
+    device and the engine reads the running sums ONCE A WAVE (the end of a
+    ``generate()``: :meth:`absorb_tallies`), so they are current wherever a
+    window begins or ends and cost no synchronisation a step.  Summed over the
+    layers and over the rows the program took as live (a burst's padded and
+    frozen rows among them: a burst's every row holds one token):
+    ``moe_identity_picks``  picks on identity experts: ``w x`` added, no row of
+    a grouped matmul
+    ``moe_held_picks``  picks on experts held here: the rows of the grouped
+    matmuls that multiply (the rest of ``moe_routed_rows`` is held elsewhere)
     """
 
     FIELDS = ("host_syncs", "dispatches", "uploads", "upload_ints", "compiles",
@@ -143,17 +157,22 @@ class ServeCounters:
     # snapshot gains a key
     SELECTED_FIELDS = ("dsa_causal_keys", "dsa_selected_keys", "dsa_scored_keys",
                        "dsa_attended_keys")
+    # the same for a family that tallies its picks on the device (``tallied``)
+    TALLIED_FIELDS = ("moe_identity_picks", "moe_held_picks")
 
     def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None,
                  kernel_slots: Callable[[int], int] = lambda t: 1,
                  attn_slots: Callable[[int, int], int] = lambda n, flat: flat,
-                 scan: Optional[tuple] = None, selected: Optional[tuple] = None):
-        for f in self.FIELDS + self.SELECTED_FIELDS:
+                 scan: Optional[tuple] = None, selected: Optional[tuple] = None,
+                 tallied: Optional[tuple] = None):
+        for f in self.FIELDS + self.SELECTED_FIELDS + self.TALLIED_FIELDS:
             setattr(self, f, 0)
         self.moe_picks, self.moe_rows, self.kernel_slots = moe_picks, moe_rows, kernel_slots
         self.attn_slots = attn_slots
         self.scan = scan  # (chunks(n, t, flat), positions a chunk, layers that scan)
         self.selected = selected  # (top-k, attention layers, the pool's block size)
+        self.tallied = tallied  # the fields the device's running tallies are, in their order
+        self._tallies_seen = (0, ) * len(tallied or ())
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
                     live_blocks: int, passes: int = 1,
@@ -215,8 +234,17 @@ class ServeCounters:
         self.dsa_scored_keys += scored * layers
         self.dsa_attended_keys += walked * layers
 
+    def absorb_tallies(self, running) -> None:
+        """The device's running int32 sums as fetched: what they grew by since the
+        last fetch goes to their fields (a sum wraps around at 2**32; a wave's
+        growth is far under that)."""
+        for field, now, seen in zip(self.tallied, running, self._tallies_seen):
+            setattr(self, field, getattr(self, field) + (int(now) - seen) % 2 ** 32)
+        self._tallies_seen = tuple(int(now) for now in running)
+
     def _reported(self) -> Tuple[str, ...]:
-        return self.FIELDS + (self.SELECTED_FIELDS if self.selected is not None else ())
+        return (self.FIELDS + (self.SELECTED_FIELDS if self.selected is not None else ())
+                + (self.TALLIED_FIELDS if self.tallied is not None else ()))
 
     def snapshot(self) -> Dict[str, int]:
         return {f: int(getattr(self, f)) for f in self._reported()}

@@ -1,5 +1,5 @@
-from . import (bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, lfm2, llama, mistral, mixtral,
-               olmoe, opt, phi, qwen, transformer)
+from . import (bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, lfm2, llama, longcat_flash,
+               mistral, mixtral, olmoe, opt, phi, qwen, transformer)
 from .bert import BertConfig
 from .bloom import BloomConfig
 from .deepseek_v2 import DeepseekV2Config
@@ -9,6 +9,7 @@ from .gpt2 import GPT2Config
 from .gptj import GPTJConfig
 from .lfm2 import Lfm2Config
 from .llama import LlamaConfig
+from .longcat_flash import LongcatFlashConfig
 from .mistral import MistralConfig
 from .mixtral import MixtralConfig
 from .olmoe import OlmoeConfig

@@ -169,7 +169,10 @@ def mla_qkv(config, a, h, safe_pos, inv_freq, table_scale: float, width: int):
     """Absorbed latent attention's projections of one layer, for any family
     whose config names the MLA sizes as :class:`DeepseekV2Config` does
     (``num_heads``, ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
-    ``v_head_dim``, ``rms_eps``): ``a`` the layer's attention weights, ``h``
+    ``v_head_dim``, ``rms_eps``).  Borrowed by ``glm_moe_dsa.py`` (which reads
+    ``c_q`` again for its indexer) and by ``longcat_flash.py`` (twice a layer;
+    its two LoRA scales ride in the softmax scale and in the ``kv_norm`` gain it
+    hands over, so nothing here knows of them).  ``a`` the layer's attention weights, ``h``
     ``[b, s, D]`` the normed input.  Returns ``(q, latent, c_q)``: ``q`` ``[b, s,
     H, width]`` = ``[q_nope W_kvb[k]^T | rotated q_pe]`` in whole lanes, a head's
     query against the cached vector itself; ``latent`` ``[b, s, 1, width]`` =

@@ -436,6 +436,10 @@ def flat_chunk_indices(n_tokens, start_pos, block_tables, num_blocks: int,
 
 STATE = "state"  # the key of a family's cache tree that holds its fixed per-sequence state
 STATE_MIXER = "mixer"  # a layer whose parameters hold this key has no attention: ``mix`` runs it
+# the key of a family's cache tree that holds running tallies its forward adds to on the
+# device (int32 ``[k]``): no pool leaf (the family takes it out before ``paged_forward``,
+# the engine moves no block of it) and read by the host once a wave
+TALLY = "tally"
 
 
 class Selection(NamedTuple):
@@ -491,7 +495,8 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                   embed: Callable, qkv: Callable, finish: Callable, head: Callable,
                   window: Optional[int] = None, alibi_slopes=None,
                   softmax_scale: Optional[float] = None, value_dim: Optional[int] = None,
-                  mix: Optional[Callable] = None, selection: Optional[Selection] = None):
+                  mix: Optional[Callable] = None, selection: Optional[Selection] = None,
+                  hand_on: bool = False):
     """The one ragged chunked forward over the paged KV pool (FastGen
     model-forward analog, inference/v2/model_implementations + blocked flash):
     every family's ``forward_paged`` is its own arithmetic as four callables
@@ -598,6 +603,22 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     The pool's row is counted over the attention layers alone, the state's over
     the rest.
 
+    **A hand-on inside a period** (``hand_on``: LongCat-Flash's shortcut expert
+    layer, computed from the first sublayer's normed stream and added at the end
+    of the second).  ``finish`` is then ``finish(lp, x, kept, attn, live, handed)
+    -> (x, handed)``: whatever tree of arrays an attention layer's ``finish``
+    returns beside ``x`` is handed, as it is, to the ``finish`` of the NEXT
+    attention layer of the SAME period (same scan step, same layout, padded or
+    compacted, in a burst's body as anywhere); a period's first layer is handed
+    None.  The hand-on ends with the period: it is no part of the scan's carry,
+    so nothing reaches a later period through it.  What the period's LAST layer
+    hands on is handed to no layer: it leaves the scan, stacked a period
+    ``[depth, ...]``, and this function returns ``(logits, cache, [that, a
+    stack of ``layers``])``: a family's per-period by-product (its tallies),
+    or None where it has none.  A mixer layer is passed over.  Without
+    ``hand_on`` (every other family) ``finish`` keeps its five arguments and
+    one result, and nothing of this is traced.
+
     ``kv_cache`` is whatever tree of ``[L, NB, KV, bs, width]`` leaves the
     family's ``init_paged_cache`` made (``{"k", "v"}``; one latent leaf for
     MLA; a latent leaf and a narrower leaf of index keys for a family with a
@@ -695,7 +716,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     flat_pools = [leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in pool_leaves]
     plan = write_plan(flat_pools, n_tokens, start_pos, block_tables, t=t, slots=slots)
 
-    def attention_layer(x, pools, lp, l):
+    def attention_layer(x, pools, lp, l, handed=None):
         q, *rows, kept = qkv(lp, x, safe_pos)
         # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h]
         first = l * num_blocks  # the layer's first row of the flat stack
@@ -721,7 +742,10 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
         else:  # q as it lies on the flat axis: the kernel finds a sequence's rows by an offset
             attn = paged_attention_flat(q[0], kpool, vpool, block_tables + first, lengths,
                                         start_pos, n_tokens, chunk=t, **facts)[None]
-        return finish(lp, x, kept, attn, live), pools
+        if not hand_on:
+            return finish(lp, x, kept, attn, live), pools
+        x, handed = finish(lp, x, kept, attn, live, handed)
+        return x, pools, handed
 
     def mixer_layer(x, flat_states, lp, l):
         """A layer whose past is its sequences' slots ``[N, ...]`` of each flat state leaf."""
@@ -744,6 +768,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     taps = lambda z, kept: sequence_taps(z, kept, *places)
     carry = (x, *flat_pools, *(leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in state_leaves))
     done = {False: 0, True: 0}  # attention / mixer layers behind the stack being scanned
+    left_over = []  # with ``hand_on``: what each stack's periods handed to no layer
     for stack in layers if isinstance(layers, list) else [layers]:
         period = stack if isinstance(stack, tuple) else (stack, )
         mixes = [STATE_MIXER in lp for lp in period]  # by what a layer's parameters hold
@@ -757,16 +782,21 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
 
         def body(carry, inp, mixes=mixes):
             (x, *pools), (lps, first) = carry, inp
+            handed = None  # a period begins with nothing handed, and what it ends with leaves the scan
             for j, (lp, is_mix) in enumerate(zip(lps, mixes)):
                 behind = mixes[:j].count(is_mix)  # layers of its kind before it in the period
                 l = first[is_mix] + behind if behind else first[is_mix]
                 if is_mix:
                     x, pools[len(flat_pools):] = mixer_layer(x, pools[len(flat_pools):], lp, l)
+                elif hand_on:
+                    x, pools[:len(flat_pools)], handed = attention_layer(
+                        x, pools[:len(flat_pools)], lp, l, handed)
                 else:
                     x, pools[:len(flat_pools)] = attention_layer(x, pools[:len(flat_pools)], lp, l)
-            return (x, *pools), None
+            return (x, *pools), handed
 
-        carry, _ = jax.lax.scan(body, carry, (period, firsts))
+        carry, left = jax.lax.scan(body, carry, (period, firsts))
+        left_over.append(left)
         for kind in firsts:
             done[kind] += depth * mixes.count(kind)
     x, *pools = carry
@@ -775,15 +805,16 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     if state_leaves:
         cache[STATE] = jax.tree_util.tree_unflatten(state_tree, [
             flat.reshape(leaf.shape) for flat, leaf in zip(pools[len(flat_pools):], state_leaves)])
+    by_product = (left_over, ) if hand_on else ()
     if not last_rows:
-        return to_padded(head(x)), cache
+        return (to_padded(head(x)), cache) + by_product
     if slots is not None:  # the rows lie one after another: row n ends where the first n + 1 counts do
         last = x[0, jnp.clip(jnp.cumsum(n_tokens) - 1, 0, slots - 1)][:, None]
     elif t > 1:
         last = jnp.take_along_axis(x, jnp.maximum(n_tokens - 1, 0)[:, None, None], axis=1)
     else:  # a decode step: the one slot a row has
         last = x
-    return head(last), cache
+    return (head(last), cache) + by_product
 
 
 def paged_step_slots(module, config, kv_cache, q_dtype, tp: int = 1):
@@ -799,7 +830,8 @@ def paged_step_slots(module, config, kv_cache, q_dtype, tp: int = 1):
     a compacted pass of ``flat`` slots over ``n`` sequences
     (``paged.flat_token_slots``; ``ServeCounters.attn_token_slots``)."""
     from ..ops.attention.paged import flat_token_slots, step_tile
-    unattended = (STATE, getattr(module, "PAGED_SELECT_LEAF", None))  # a state; a leaf of index keys
+    # a state; running tallies; a leaf of index keys
+    unattended = (STATE, TALLY, getattr(module, "PAGED_SELECT_LEAF", None))
     pool = jax.tree_util.tree_leaves({k: v for k, v in kv_cache.items() if k not in unattended})[0]
     (_, _, kvh, bs, width), pool_dtype = pool.shape, pool.dtype  # the array itself is not kept
     value_dim = getattr(module, "paged_value_dim", lambda config: None)(config)

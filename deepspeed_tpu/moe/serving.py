@@ -9,6 +9,16 @@ handed to one grouped matmul per projection.  A dead slot's picks are given
 the expert id ``E``, past every group, so they sort to the tail, belong to no
 group and cost no read of any expert's weights.
 
+A pick is one of three kinds (:func:`sparse_moe_ffn`).  *Held*: the expert's
+weights are here; the pick is a row of its group.  *Held elsewhere*: the router
+is wider than the experts the leaves hold (one chip's share of an
+expert-parallel layer); the pick gets the dead group id, reads no weight and
+adds nothing here.  *Identity* (LongCat-Flash's zero-computation experts): the
+router's last ``identity_experts`` outputs are experts that return their input;
+such a pick keeps its weight, adds ``w x`` beside the routed sum on the token's
+own chip, is never dispatched, and gets the dead group id too.  Two kinds read
+no weight; only one of them adds nothing.
+
 The grouped matmul is the Pallas ``gmm`` of ``jax.experimental.pallas.ops.tpu.
 megablox`` on TPU and ``jax.lax.ragged_dot``, the same mathematics in XLA,
 elsewhere.  On the v5e at OLMoE's ``[rows, 64 groups, 2048 x 1024]`` the three
@@ -31,7 +41,12 @@ K_TILE, N_TILE = 2048, 1024  # an expert's whole 2048 x 1024 matrix in one step
 def expert_rows(slots: int, top_k: int) -> int:
     """Rows the expert FFN's program computes for ``slots`` token slots:
     ``slots x top_k``, rounded up to whole row tiles (to 16, a bf16 sublane
-    pair, under one tile).  Static, so the serving counters ask it too."""
+    pair, under one tile).  Static, so the serving counters ask it too.  Every
+    pick is a row, whatever its kind: picks on experts held elsewhere and on
+    identity experts are among the dead rows behind the last group (gathered,
+    sorted and combined, multiplied by nothing) until a later PR compacts them:
+    of LongCat-Flash's 12 rows a token as one chip of 32, 4 are identity picks
+    and 7.75 are held elsewhere under uniform routing, 0.25 a held expert's."""
     tile = ROW_TILE if slots * top_k > ROW_TILE else 16
     return -(-slots * top_k // tile) * tile
 
@@ -97,7 +112,8 @@ def route(wg, x, top_k: int, renormalise: bool, n_group: int = 1, topk_group: in
 def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
                    live: Optional[jax.Array] = None, layer: Optional[jax.Array] = None,
                    n_group: int = 1, topk_group: int = 1, scaling: float = 1.0,
-                   scoring: str = "softmax", norm_eps: float = 0.0):
+                   scoring: str = "softmax", norm_eps: float = 0.0,
+                   identity_experts: Optional[int] = None):
     """x [S, D] -> [S, D]: SwiGLU experts under top-k routing.
 
     ``moe_params``: ``{"gate": {"wg": [D, E]}, "experts": {"w_gate": [E, D, F],
@@ -124,6 +140,22 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     The result is this chip's experts' part of the layer's sum (what the
     exchange of the deployment would gather from the other chips is theirs to
     add: nothing here stands in for them).
+
+    **Identity experts** (``identity_experts`` = Z, not None: LongCat-Flash's
+    ``zero_expert_num``).  The router's width is ``real + Z``: outputs
+    ``real ..`` are experts that return their input.  The softmax, the
+    selection bias and the factor run over all of them alike; a pick at or
+    past ``real`` keeps its weight and adds ``w x`` in float32 beside the
+    routed sum (scope ``moe_identity``), here, for this chip's own tokens: it
+    is never dispatched, so it gets the dead group id and no row of a grouped
+    matmul is its own.  A pick under ``real`` that is not held stays a pick
+    held elsewhere and adds nothing.  The three kinds of pick in one layer:
+    held, held elsewhere, identity.  The call then returns ``(out, tally)``:
+    ``tally`` int32 ``[2]``, the live slots' picks on identity experts and on
+    held experts (which kind a pick is only the device knows; a family sums
+    them over its layers for ``ServeCounters.moe_identity_picks`` /
+    ``moe_held_picks``).  With ``identity_experts`` None (every other family)
+    nothing of this is traced and the result is ``out`` alone.
 
     ``moe_params["shared"]`` (``{"w_gate": [D, Fs], "w_up", "w_down"}``), where
     a family has it, is the expert every token takes: a dense SwiGLU added to
@@ -176,4 +208,15 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
                         x, moe_params["shared_gate"].astype(x.dtype),
                         preferred_element_type=jnp.float32))
             out = out + added
+    if identity_experts is not None:
+        real = moe_params["gate"]["wg"].shape[-1] - identity_experts
+        with jax.named_scope("moe_identity"):
+            alive = jnp.ones((slots, 1), bool) if live is None else live[:, None]
+            identity = (experts >= real) & alive
+            # an identity expert returns its input: its picks' weights times x, on this chip
+            out = out + jnp.sum(jnp.where(identity, weights, 0.0), axis=-1,
+                                keepdims=True) * x.astype(jnp.float32)
+            tally = jnp.stack([jnp.sum(identity, dtype=jnp.int32),
+                               jnp.sum((experts < num_experts) & alive, dtype=jnp.int32)])
+        return out.astype(x.dtype), tally
     return out.astype(x.dtype)

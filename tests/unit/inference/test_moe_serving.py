@@ -310,3 +310,93 @@ def test_deepseek_v2_through_the_engine_counts_every_pick_and_compacts():
             assert [leaf.shape for leaf in jax.tree_util.tree_leaves(eng.kv)] == [(3, 40, 1, 8, 128)]
             eng.check_kv_invariant()
     assert outs["fast"] == outs["padded"]
+
+
+# ------------------------------------------------------- identity experts (ISSUE 49)
+def identity_oracle(moe, x, top_k, scaling, real, held, live, bias=None):
+    """Softmax over ALL the router's outputs, the top-k of score (+ bias), the
+    picked scores times the factor; experts under ``held`` through the dense
+    oracle's loop, outputs from ``real`` on the identity, the rest nothing."""
+    probs = jax.nn.softmax(x @ moe["gate"]["wg"], axis=-1)
+    _, picks = jax.lax.top_k(probs if bias is None else probs + bias, top_k)
+    rows = jnp.arange(x.shape[0])[:, None]
+    combine = jnp.zeros_like(probs).at[rows, picks].set(probs[rows, picks] * scaling)
+    ex = {name: w[:held] for name, w in moe["experts"].items()}
+    every = jnp.einsum("etf,efd->etd", jax.nn.silu(jnp.einsum("td,edf->etf", x, ex["w_gate"]))
+                       * jnp.einsum("td,edf->etf", x, ex["w_up"]), ex["w_down"])
+    out = jnp.einsum("te,etd->td", combine[:, :held], every) \
+        + combine[:, real:].sum(-1, keepdims=True) * x
+    counts = [int(((picks >= real) & live[:, None]).sum()), int(((picks < held) & live[:, None]).sum())]
+    return out * live[:, None], counts, picks
+
+
+@pytest.mark.parametrize("held", [12, 4], ids=["every_real_expert_held", "a_share_of_the_real_experts"])
+@pytest.mark.parametrize("biased", [False, True], ids=["no_bias", "selection_bias"])
+def test_identity_experts_add_w_x_and_the_three_kinds_of_pick_are_tallied(held, biased):
+    """A router over 12 real + 6 identity outputs: a pick at or past 12 keeps
+    its weight and adds ``w x``; a pick under 12 that is not held adds nothing;
+    the weights are the softmax over all 18, not renormalised, times the factor;
+    the tallies count live slots' picks alone."""
+    real, zero, top_k = 12, 6, 5
+    moe, x = drawn_moe(real + zero, seed=3)
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(4), (real + zero, )) if biased else None
+    gate = {"wg": moe["gate"]["wg"], **({"bias": bias} if biased else {})}
+    experts = {name: w[:held] for name, w in moe["experts"].items()}
+    live = jnp.asarray(np.random.default_rng(1).random(24) < 0.7)
+    with jax.default_matmul_precision("highest"):
+        got, tally = sparse_moe_ffn({"gate": gate, "experts": experts}, x, top_k, False, live,
+                                    scaling=6.0, identity_experts=zero)
+        want, counts, picks = identity_oracle(moe, x, top_k, 6.0, real, held, live, bias)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert np.asarray(tally).tolist() == counts and counts[0] > 0 and counts[1] > 0
+    picks = np.asarray(picks)
+    assert ((picks >= held) & (picks < real)).any() == (held < real)  # the third kind occurs
+    # identity picks read no weight: the grouped matmuls' groups hold the held picks alone
+    without = sparse_moe_ffn({"gate": gate, "experts": experts}, x, top_k, False, live, scaling=6.0)
+    np.testing.assert_allclose(
+        np.asarray(got - without),
+        np.asarray(identity_oracle(moe, x, top_k, 6.0, real, 0, live, bias)[0]), atol=2e-5)
+
+
+def test_no_identity_experts_is_none_and_zero_of_them_is_a_tally_of_nothing():
+    moe, x = drawn_moe(8, seed=5)
+    plain = sparse_moe_ffn(moe, x, 2, True)
+    out, tally = sparse_moe_ffn(moe, x, 2, True, identity_experts=0)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
+    assert np.asarray(tally).tolist() == [0, 24 * 2]  # every pick is on a held expert
+    text = jax.jit(lambda m, a: sparse_moe_ffn(m, a, 2, True)).lower(moe, x).as_text()
+    assert "moe_identity" not in text
+
+
+# What six families' step programs lowered to at the parent of ISSUE 49 (sha256 of
+# ``jit(forward_paged).lower(...).as_text()``, first 16 digits): a hand-on inside a
+# period, identity experts and a tally leaf are traced for the family that has them
+# and for no other.  Whoever changes ``paged_forward`` or ``sparse_moe_ffn`` on
+# purpose re-pins these from the new tree and says in PERF.md that every cell's
+# programs, and with them ``setup_s``, are compiled anew.
+PROGRAMS_BEFORE = {"olmoe_decode": "be129bc1388a5808", "olmoe_compacted": "1157e790add8daad",
+                   "deepseek_v2_share_compacted": "f9f66894abb41f67",
+                   "glm_moe_dsa_share_padded": "68d732d5a1164316",
+                   "lfm2_period_compacted": "6dd10a4bfc4f41c2", "llama_decode": "c6d24d39c1fabc19"}
+
+
+@pytest.mark.parametrize("case", sorted(PROGRAMS_BEFORE))
+def test_a_family_without_a_hand_on_or_identity_experts_lowers_to_the_program_it_was(case):
+    import hashlib
+    from deepspeed_tpu.models import deepseek_v2, glm_moe_dsa, lfm2
+    module, cfg, cache_kw, n, t, b, bound = {
+        "olmoe_decode": (olmoe, olmoe.OlmoeConfig.tiny(), {}, 4, 1, 4, 32),
+        "olmoe_compacted": (olmoe, olmoe.OlmoeConfig.tiny(), {}, 4, 16, 4, 32),
+        "deepseek_v2_share_compacted": (deepseek_v2, deepseek_v2.DeepseekV2Config.tiny(
+            local_experts=4), {}, 4, 16, 4, 32),
+        "glm_moe_dsa_share_padded": (glm_moe_dsa, glm_moe_dsa.GlmMoeDsaConfig.tiny(
+            local_experts=1), {}, 2, 16, 4, None),
+        "lfm2_period_compacted": (lfm2, lfm2.Lfm2Config.tiny(), {"state_slots": 5}, 4, 16, 5, 32),
+        "llama_decode": (llama, llama.LlamaConfig.tiny(), {}, 4, 1, 4, 32)}[case]
+    params = jax.eval_shape(lambda: module.init_params(cfg, jax.random.PRNGKey(0)))
+    kv = jax.eval_shape(lambda: module.init_paged_cache(cfg, 16, 8, dtype=jnp.float32, **cache_kw))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    text = jax.jit(lambda p, kv, tok, nt, sp, tab: module.forward_paged(
+        cfg, p, tok, nt, sp, tab, kv, block_size=8, live_token_bound=bound, last_rows=True)).lower(
+            params, kv, ints(n, t), ints(n), ints(n), ints(n, b)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PROGRAMS_BEFORE[case]
