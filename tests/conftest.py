@@ -38,6 +38,13 @@ def _reset_global_topology():
 
 
 @pytest.fixture
+def interpreted_kernels(monkeypatch):
+    """The Pallas kernels run interpreted, in place of their XLA reference math."""
+    from deepspeed_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, "INTERPRET", True)
+
+
+@pytest.fixture
 def mesh8():
     """An 8-device (data=8) topology."""
     from deepspeed_tpu.parallel import MeshTopology

@@ -17,6 +17,13 @@ import deepspeed_tpu.runtime.checkpoint_engine.checkpoint_engine as ce_mod
 from deepspeed_tpu.runtime.checkpoint_engine import AsyncCheckpointEngine
 
 
+def drained(eng, timeout=30.0):
+    """``eng._queue.join()`` held to ``timeout`` (``Queue.join`` takes none)."""
+    with eng._queue.all_tasks_done:
+        assert eng._queue.all_tasks_done.wait_for(lambda: not eng._queue.unfinished_tasks, timeout), \
+            "the worker never emptied its queue"
+
+
 @pytest.fixture
 def failing_save(monkeypatch):
     calls = {"n": 0}
@@ -41,7 +48,7 @@ def test_worker_failure_surfaces_with_original_type(tmp_path, failing_save):
 def test_save_reraises_pending_error_before_enqueueing(tmp_path, failing_save):
     eng = AsyncCheckpointEngine()
     eng.save(np.zeros(4), str(tmp_path / "a.npy"))
-    eng._queue.join()
+    drained(eng)
     with pytest.raises(OSError):
         eng.save(np.zeros(4), str(tmp_path / "b.npy"))
 
@@ -53,11 +60,11 @@ def test_error_raised_exactly_once_across_concurrent_drains(tmp_path,
     (the unlocked swap could lose it to a torn read-then-None-write)."""
     eng = AsyncCheckpointEngine()
     eng.save(np.zeros(4), str(tmp_path / "a.npy"))
-    eng._queue.join()
+    drained(eng)
 
     raised = []
     raised_lock = threading.Lock()
-    barrier = threading.Barrier(8)
+    barrier = threading.Barrier(8, timeout=30)
 
     def drain():
         barrier.wait()
@@ -71,7 +78,8 @@ def test_error_raised_exactly_once_across_concurrent_drains(tmp_path,
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
     assert len(raised) == 1
     assert "mount flaked" in str(raised[0])
 
@@ -86,7 +94,7 @@ def test_error_survives_until_raised_never_lost(tmp_path, failing_save):
             eng.save(np.zeros(2), str(tmp_path / f"{i}.npy"))
         except OSError:
             reported += 1
-        eng._queue.join()
+        drained(eng)
     try:
         eng.flush()
     except OSError:

@@ -195,76 +195,101 @@ def test_the_selected_sets_are_the_references(params, monkeypatch, tied):
 
 
 # ----------------------------------------------------------- through the engine
-def engine(params, fast=True, budget=32, seqs=4, **sections):
+def build_engine(params, fast=True, budget=32, **sections):
     conf = {"dtype": "float32", **sections}
     if not fast:
         conf["serving_fastpath"] = {"enabled": False}
     return InferenceEngineV2(glm_moe_dsa, CFG, params, config=conf, num_blocks=96, block_size=8,
-                             max_blocks_per_seq=24, token_budget=budget, max_seqs_per_step=seqs)
+                             max_blocks_per_seq=24, token_budget=budget, max_seqs_per_step=4)
+
+
+@pytest.fixture(scope="module")
+def engine(params):
+    """``engine(fast=True, budget=32)``: one engine a configuration, built when
+    first asked for.  A drained engine replays a wave step for step, so a case
+    serves through it and reads tokens, and counters as deltas; a case that
+    reaches into the manager, or brings sections, takes ``build_engine``."""
+    made = {}
+
+    def get(fast=True, budget=32):
+        if (fast, budget) not in made:
+            made[fast, budget] = build_engine(params, fast, budget)
+        return made[fast, budget]
+    return get
+
+
+GREEDY = {}  # (prompt, new) -> the reference's continuation: ``params`` is the module's one draw
 
 
 def greedy(params, prompt, new):
-    ids = list(prompt)
-    for _ in range(new):
-        ids.append(int(np.argmax(want(params, ids + [0] * (-len(ids) % 16), [len(ids) - 1])[0])))
-    return ids
+    if (tuple(prompt), new) not in GREEDY:
+        ids = list(prompt)
+        for _ in range(new):
+            ids.append(int(np.argmax(want(params, ids + [0] * (-len(ids) % 16), [len(ids) - 1])[0])))
+        GREEDY[tuple(prompt), new] = ids
+    return list(GREEDY[tuple(prompt), new])
 
 
 @pytest.mark.parametrize("budget", [32, 48])
-def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params, budget):
+def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params, engine, budget):
     """Two ``token_budget``s cut a prompt at different places; the tokens are
     the reference's either way, through compacted passes and fused bursts."""
     prompts = [ids_of(10 + i, n) for i, n in enumerate((5, 90, 140, 9))]
-    eng = engine(params, budget=budget)
+    eng = engine(budget=budget)
+    before = eng.counters.snapshot()
     got = eng.generate(prompts, max_new_tokens=5)
-    assert eng.counters.burst_tokens > 0 and eng.counters.compact_passes > 0
+    c = eng.counters.delta_since(before)
+    assert c["burst_tokens"] > 0 and c["compact_passes"] > 0
     for p, g in list(zip(prompts, got))[:3]:  # one decode-only, one cut in three, one in five
         assert list(g) == greedy(params, p, 5)
-    c = eng.counters
-    assert c.moe_routed_rows == c.live_tokens * 4  # k picks in the one expert layer
+    assert c["moe_routed_rows"] == c["live_tokens"] * 4  # k picks in the one expert layer
     # the selection's counters: every live token, in each of the two layers
-    assert 0 < c.dsa_selected_keys < c.dsa_causal_keys <= c.dsa_scored_keys
-    assert c.dsa_selected_keys <= c.dsa_attended_keys and c.dsa_causal_keys <= c.dsa_attended_keys
-    assert c.dsa_selected_keys <= c.live_tokens * TOPK * 2
+    assert 0 < c["dsa_selected_keys"] < c["dsa_causal_keys"] <= c["dsa_scored_keys"]
+    assert c["dsa_selected_keys"] <= c["dsa_attended_keys"] >= c["dsa_causal_keys"]
+    assert c["dsa_selected_keys"] <= c["live_tokens"] * TOPK * 2
     eng.check_kv_invariant()
 
 
-def test_the_fast_path_and_the_padded_oracle_serve_the_same_tokens(params):
+def test_the_fast_path_and_the_padded_oracle_serve_the_same_tokens(engine):
     prompts = [ids_of(50 + i, n) for i, n in enumerate((33, 7, 81))]
-    fast, slow = engine(params), engine(params, fast=False)
+    fast, slow = engine(), engine(fast=False)
+    before = fast.counters.snapshot(), slow.counters.snapshot()
     assert [list(g) for g in fast.generate(prompts, max_new_tokens=3)] == \
         [list(g) for g in slow.generate(prompts, max_new_tokens=3)]
-    assert slow.counters.compact_passes == 0 < fast.counters.compact_passes
+    fast, slow = fast.counters.delta_since(before[0]), slow.counters.delta_since(before[1])
+    assert slow["compact_passes"] == 0 < fast["compact_passes"]
     for name in ("dsa_causal_keys", "dsa_selected_keys"):
         # the traffic's, whatever the layout; a burst's last passes may run past a sequence's end
-        assert getattr(fast.counters, name) >= getattr(slow.counters, name) > 0
+        assert fast[name] >= slow[name] > 0
 
 
-def test_the_counters_are_the_sums_over_positions(params):
-    eng = engine(params, budget=32)
+def test_the_counters_are_the_sums_over_positions(engine):
+    eng = engine()
+    before = eng.counters.snapshot()
     eng.generate([ids_of(60, 50)], max_new_tokens=3)
     positions = np.arange(50 + 3 - 1)  # every token that went through a forward pass
-    c = eng.counters
-    assert c.dsa_causal_keys == 2 * int((positions + 1).sum())
-    assert c.dsa_selected_keys == 2 * int(np.minimum(positions + 1, TOPK).sum())
+    c = eng.counters.delta_since(before)
+    assert c["dsa_causal_keys"] == 2 * int((positions + 1).sum())
+    assert c["dsa_selected_keys"] == 2 * int(np.minimum(positions + 1, TOPK).sum())
 
 
-def test_index_keys_in_a_shared_prefix_block_are_the_ones_a_later_prompt_scores(params):
+def test_index_keys_in_a_shared_prefix_block_are_the_ones_a_later_prompt_scores(params, engine):
     """Prompts of one wave share a header of whole blocks: the later ones take
     the first's blocks (both leaves of them: a block is a block) and score the
     index keys they find there."""
     head = ids_of(70, 64)
     prompts = [head + ids_of(71 + i, 20 + 7 * i) for i in range(2)]
-    eng = engine(params)
+    eng = engine()
+    hits = eng.health()["prefix_cache"]["hits_total"]
     got = eng.generate(prompts, max_new_tokens=3)
-    assert eng.health()["prefix_cache"]["hits_total"] >= 64 // 8 - 1
+    assert eng.health()["prefix_cache"]["hits_total"] - hits >= 64 // 8 - 1
     for p, g in zip(prompts, got):
         assert list(g) == greedy(params, p, 3)
     eng.check_kv_invariant()
 
 
-def test_a_copied_block_carries_both_leaves(params):
-    eng = engine(params)
+def test_a_copied_block_carries_both_leaves(engine):
+    eng = engine()
     eng.generate([ids_of(80, 40)], max_new_tokens=2)
     before = jax.tree_util.tree_map(np.asarray, eng.kv)
     eng._cow_copy_block(0, 50)
@@ -276,7 +301,7 @@ def test_a_copied_block_carries_both_leaves(params):
 def test_a_preempted_sequence_resumes_to_the_undisturbed_tokens(params):
     prompt = ids_of(30, 100)
     undisturbed = greedy(params, prompt, 5)
-    eng = engine(params, budget=32)
+    eng = build_engine(params)
     eng.put([7], [prompt])
     for _ in range(2):
         eng.step()
@@ -289,12 +314,12 @@ def test_a_preempted_sequence_resumes_to_the_undisturbed_tokens(params):
     assert prompt + out == list(undisturbed)
 
 
-def test_speculative_decoding_serves_the_same_tokens_and_tensor_parallelism_is_refused(params):
+def test_speculative_decoding_serves_the_same_tokens_and_tensor_parallelism_is_refused(params, engine):
     """A rejected draft is rolled back by blocks, and the index keys live in
     those blocks: the verify path needs nothing of its own."""
     prompt = ids_of(40, 60)
-    plain = engine(params).generate([prompt], max_new_tokens=6)[0]
-    spec = engine(params, serving_spec_decode={"enabled": True, "k": 3})
+    plain = engine().generate([prompt], max_new_tokens=6)[0]
+    spec = build_engine(params, serving_spec_decode={"enabled": True, "k": 3})
     assert list(spec.generate([prompt], max_new_tokens=6)[0]) == list(plain)
     assert spec.counters.spec_rounds > 0
     with pytest.raises(NotImplementedError, match="tensor-parallel"):

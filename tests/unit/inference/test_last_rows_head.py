@@ -17,7 +17,8 @@ from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.models import lfm2, llama, qwen3_next
 from deepspeed_tpu.models.transformer import flat_slots
 from tests.unit.inference.test_serving_fastpath import (_COMPACT_PROMPTS, _COUNTS, _FAMILIES,
-                                                        _compacting_engine, _ragged_chunk)
+                                                        _family_forward, _ragged_chunk,
+                                                        _shared_compacting_engine)
 
 # the families of test_compacted_mixed_wave_matches_the_padded_reference, and
 # the two whose layers without attention carry a state a sequence beside the pool
@@ -29,12 +30,8 @@ NUM_BLOCKS, BLOCK, T, BOUND, WIDTH = 33, 8, 16, 16, 8
 
 
 def _chunk(module, cfg, counts):
-    """``(params, tokens, counts, start, tables, cache)``: a ragged ``[4, 16]``
-    chunk over a pool (and, for a stateful family, slots) of random content."""
-    leaves, tree = jax.tree_util.tree_flatten(module.init_params(cfg, jax.random.PRNGKey(3)))
-    params = jax.tree_util.tree_unflatten(tree, [
-        leaf + 0.05 * jax.random.normal(jax.random.PRNGKey(i), leaf.shape, leaf.dtype)
-        for i, leaf in enumerate(leaves)])
+    """``(tokens, counts, start, tables, cache)``: a ragged ``[4, 16]`` chunk
+    over a pool (and, for a stateful family, slots) of random content."""
     rng = np.random.default_rng(sum(c * 17**i for i, c in enumerate(counts)))
     tokens, counts, start, tables = _ragged_chunk(rng, counts, T, BLOCK, NUM_BLOCKS, WIDTH)
     stateful = hasattr(module, "state_bytes_per_seq")
@@ -45,23 +42,19 @@ def _chunk(module, cfg, counts):
     if stateful:  # a row's slot rides as its table's last column; a dead row's is the trash slot
         slot = np.where(counts > 0, np.arange(len(counts)), len(counts)).astype(np.int32)
         tables = np.concatenate([tables, slot[:, None]], axis=1)
-    return params, tokens, counts, start, tables, cache
+    return tokens, counts, start, tables, cache
 
 
 @pytest.mark.parametrize("layout", ["compacted", "padded"])
 @pytest.mark.parametrize("shape", list(_COUNTS))
 @pytest.mark.parametrize("family", list(FAMILIES))
 def test_last_rows_equal_the_all_positions_forwards_last_live_row(family, shape, layout):
-    module, cfg = FAMILIES[family]()
-    params, tokens, counts, start, tables, cache = _chunk(module, cfg, _COUNTS[shape])
+    module, cfg, params, forward = _family_forward(FAMILIES[family])
+    tokens, counts, start, tables, cache = _chunk(module, cfg, _COUNTS[shape])
     bound = BOUND if layout == "compacted" else None
     assert (flat_slots(len(counts), T, bound) is not None) == (layout == "compacted")
-    chunk = [jnp.asarray(a) for a in (tokens, counts, start, tables)]
-    forward = jax.jit(lambda last_rows: module.forward_paged(
-        cfg, params, *chunk, cache, block_size=BLOCK, live_token_bound=bound,
-        last_rows=last_rows), static_argnums=0)
-    every, cache_every = forward(False)
-    last, cache_last = forward(True)
+    every, cache_every = forward(params, tokens, counts, start, tables, cache, bound, False)
+    last, cache_last = forward(params, tokens, counts, start, tables, cache, bound, True)
     assert every.shape == (len(counts), T, cfg.vocab_size)
     assert last.shape == (len(counts), 1, cfg.vocab_size)
     live = counts > 0
@@ -127,13 +120,14 @@ def _all_positions_twin(eng, seen=None):
     position's ``[n, t, V]`` from the family's forward without ``last_rows``,
     then the parent's ``pick`` gather of ``[row, n_tokens - 1]``, handed on as
     ``[n, 1, V]``.  ``seen`` collects ``(tokens, n_tokens, start_pos, [n, t, V])``."""
-    bound = eng._live_token_bound
+    every_position = jax.jit(lambda params, kv, tokens, n_tokens, start_pos, tables:
+                             eng.model.forward_paged(
+                                 eng.model_config, params, tokens, n_tokens, start_pos, tables, kv,
+                                 block_size=eng.block_size, live_token_bound=eng._live_token_bound))
 
     def compiled_fwd(n, t, b):
         def fwd(params, kv, tokens, n_tokens, start_pos, tables):
-            every, kv = eng.model.forward_paged(
-                eng.model_config, params, tokens, n_tokens, start_pos, tables, kv,
-                block_size=eng.block_size, live_token_bound=bound)
+            every, kv = every_position(params, kv, tokens, n_tokens, start_pos, tables)
             if seen is not None:
                 seen.append(tuple(np.asarray(a) for a in (tokens, n_tokens, start_pos, every)))
             last = jnp.maximum(n_tokens - 1, 0)
@@ -145,7 +139,7 @@ def _all_positions_twin(eng, seen=None):
 @pytest.mark.parametrize("sampling", [{}, {"temperature": 0.8, "top_k": 20, "top_p": 0.9}],
                          ids=["greedy", "sampled"])
 @pytest.mark.parametrize("fastpath", [True, False], ids=["fastpath", "reference-step"])
-def test_pick_returns_the_tokens_of_the_all_positions_forward(fastpath, sampling):
+def test_pick_returns_the_tokens_of_the_all_positions_forward(monkeypatch, fastpath, sampling):
     """``pick`` over ``logits[:, 0]`` of the last-rows forward against the parent's
     pair: the all-positions forward and the gather of each row's last live row."""
     def engine():
@@ -155,26 +149,30 @@ def test_pick_returns_the_tokens_of_the_all_positions_forward(fastpath, sampling
             config={"dtype": "float32", "serving_fastpath": {"enabled": fastpath}, **sampling},
             num_blocks=64, block_size=8, max_blocks_per_seq=8, token_budget=16,
             max_seqs_per_step=4)
-    eng, parent = engine(), engine()
-    parent._compiled_fwd = _all_positions_twin(parent)
+    if sampling:  # a draw moves an engine's rng: a pair of its own, each from the seed
+        eng, parent = engine(), engine()
+    else:  # the shared engine's configuration: the one engine serves the wave both ways
+        eng = parent = _shared_compacting_engine("llama", fastpath)
     tokens = eng.generate(_COMPACT_PROMPTS, max_new_tokens=8, greedy=not sampling)
+    monkeypatch.setattr(parent, "_compiled_fwd", _all_positions_twin(parent))
     assert tokens == parent.generate(_COMPACT_PROMPTS, max_new_tokens=8, greedy=not sampling)
     assert all(len(t) == len(p) + 8 for t, p in zip(tokens, _COMPACT_PROMPTS))
     picks = {e["name"] for e in eng.ledger.events if e["site"] == "pick"}
     assert picks == ({"pick_n4_sampled"} if sampling else {"pick_n4"})
     if sampling:  # and the draw really sampled: a greedy engine picks other tokens
-        greedy = _compacting_engine("llama", fastpath).generate(_COMPACT_PROMPTS, max_new_tokens=8)
+        greedy = _shared_compacting_engine("llama", fastpath).generate(_COMPACT_PROMPTS,
+                                                                       max_new_tokens=8)
         assert tokens != greedy
 
 
-def test_logit_spy_reads_row_zero_of_a_last_rows_program_through_a_clamped_index():
+def test_logit_spy_reads_row_zero_of_a_last_rows_program_through_a_clamped_index(monkeypatch):
     """The reliance ISSUE 44 names: the chip benchmark's ``LogitSpy`` (not edited)
     indexes the step forward's result at ``[row, n_tokens[row] - 1]``; of a
     ``[n, 1, V]`` ``jax.Array`` an index past the end clamps, so it reads
     ``[row, 0]``, the row the all-positions forward puts at
     ``[row, len(prompt) - start - 1]``.  The day the index stops clamping, or
     the result is no ``jax.Array``, this fails before a chip run does."""
-    eng = _compacting_engine("llama", True)
+    eng = _shared_compacting_engine("llama", True)
     shapes = []
     compiled_fwd = eng._compiled_fwd
 
@@ -186,7 +184,7 @@ def test_logit_spy_reads_row_zero_of_a_last_rows_program_through_a_clamped_index
             shapes.append((t, logits.shape))
             return logits, kv
         return call
-    eng._compiled_fwd = watched
+    monkeypatch.setattr(eng, "_compiled_fwd", watched)
     with LogitSpy(eng, _COMPACT_PROMPTS) as spy:
         served = eng.generate(_COMPACT_PROMPTS, max_new_tokens=4)
         rows = spy.rows
@@ -194,9 +192,8 @@ def test_logit_spy_reads_row_zero_of_a_last_rows_program_through_a_clamped_index
     assert max(t for t, _ in shapes) > 1 and all(shape[1] == 1 for _, shape in shapes)
     # the same wave through the all-positions forward, every call's [n, t, V] kept
     seen = []
-    twin = _compacting_engine("llama", True)
-    twin._compiled_fwd = _all_positions_twin(twin, seen)
-    assert twin.generate(_COMPACT_PROMPTS, max_new_tokens=4) == served
+    monkeypatch.setattr(eng, "_compiled_fwd", _all_positions_twin(eng, seen))
+    assert eng.generate(_COMPACT_PROMPTS, max_new_tokens=4) == served
     for i, prompt in enumerate(_COMPACT_PROMPTS):
         found = [every[row, len(prompt) - starts[row] - 1]
                  for tokens, counts, starts, every in seen
@@ -207,10 +204,10 @@ def test_logit_spy_reads_row_zero_of_a_last_rows_program_through_a_clamped_index
 
 
 @pytest.mark.parametrize("fastpath", [True, False], ids=["fastpath", "reference-step"])
-def test_head_rows_are_n_a_forward_and_n_times_k_a_burst(fastpath):
+def test_head_rows_are_n_a_forward_and_n_times_k_a_burst(monkeypatch, fastpath):
     """``head_rows`` against the sum of ``n x passes`` over the launched step and
     burst programs, in ``snapshot()`` and so in ``health()``."""
-    eng = _compacting_engine("llama", fastpath)
+    eng = _shared_compacting_engine("llama", fastpath)
     launched = []
     compiled_fwd, compiled_burst = eng._compiled_fwd, eng._compiled_burst
 
@@ -221,11 +218,14 @@ def test_head_rows_are_n_a_forward_and_n_times_k_a_burst(fastpath):
     def burst(n, k, *args, **kwargs):
         launched.append(n * k)
         return compiled_burst(n, k, *args, **kwargs)
-    eng._compiled_fwd, eng._compiled_burst = fwd, burst
+    monkeypatch.setattr(eng, "_compiled_fwd", fwd)
+    monkeypatch.setattr(eng, "_compiled_burst", burst)
+    before = eng.counters.snapshot()
     eng.generate(_COMPACT_PROMPTS, max_new_tokens=12)
-    c = eng.counters
-    assert c.head_rows == sum(launched) > 0
-    assert eng.health()["fastpath"]["head_rows"] == c.snapshot()["head_rows"] == c.head_rows
-    assert c.head_rows < c.token_slots  # the head ran over every slot before
+    c = eng.counters.delta_since(before)
+    assert c["head_rows"] == sum(launched) > 0
+    assert eng.health()["fastpath"]["head_rows"] == eng.counters.snapshot()["head_rows"] \
+        == eng.counters.head_rows
+    assert c["head_rows"] < c["token_slots"]  # the head ran over every slot before
     if fastpath:
-        assert c.burst_tokens > 0 and c.compact_passes > 0  # both kinds of program ran
+        assert c["burst_tokens"] > 0 and c["compact_passes"] > 0  # both kinds of program ran

@@ -162,12 +162,18 @@ def test_the_shift_is_local_to_a_sequence_in_both_layouts(layout):
 
 
 # ----------------------------------------------------------- through the engine
-def engine(params, fast=True, budget=16, seqs=4, **sections):
-    conf = {"dtype": "float32", **sections}
-    if not fast:
-        conf["serving_fastpath"] = {"enabled": False}
-    return InferenceEngineV2(lfm2, CFG, params, config=conf, num_blocks=64, block_size=8,
-                             max_blocks_per_seq=16, token_budget=budget, max_seqs_per_step=seqs)
+def engine(params, budget=16, seqs=4, **sections):
+    return InferenceEngineV2(lfm2, CFG, params, config={"dtype": "float32", **sections},
+                             num_blocks=64, block_size=8, max_blocks_per_seq=16,
+                             token_budget=budget, max_seqs_per_step=seqs)
+
+
+@pytest.fixture(scope="module")
+def served(params):
+    """The default engine, built once for the cases that only serve a wave
+    through it (drained, it replays a wave step for step) and read tokens, and
+    the manager's and the prefix tree's totals as deltas."""
+    return engine(params)
 
 
 def greedy(params, prompt, new):
@@ -177,19 +183,21 @@ def greedy(params, prompt, new):
     return ids
 
 
-def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params):
+def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params, served):
     prompts = [ids_of(10 + i, n) for i, n in enumerate((5, 23, 40, 9, 17, 3))]
-    eng = engine(params)
+    eng, before = served, (served.counters.snapshot(), served.health()["state"])
     got = eng.generate(prompts, max_new_tokens=6)
-    assert eng.counters.burst_tokens > 0 and eng.counters.compact_passes > 0
+    c = eng.counters.delta_since(before[0])
+    assert c["burst_tokens"] > 0 and c["compact_passes"] > 0
     for p, g in list(zip(prompts, got))[:3]:  # one decode-only, one chunked, one cut in three
         assert list(g) == greedy(params, p, 6)
     state = eng.health()["state"]
     # six sequences through four slots: every hand-out starts a sequence from zero
     assert state == {"enabled": True, "state_slots": 4, "state_slots_in_use": 0,
                      "state_bytes_per_seq": lfm2.state_bytes_per_seq(CFG),
-                     "state_slots_zeroed": 6, "prefix_declined_stateful": 0}
-    assert engine(params).manager.trash_slot == 4 and eng.kv[STATE].shape[1] == 5
+                     "state_slots_zeroed": before[1]["state_slots_zeroed"] + 6,
+                     "prefix_declined_stateful": before[1]["prefix_declined_stateful"]}
+    assert eng.manager.trash_slot == 4 and eng.kv[STATE].shape[1] == 5
 
 
 def test_a_slot_reused_after_retire_starts_from_zero(params):
@@ -237,17 +245,18 @@ def test_a_preempted_sequence_resumes_to_the_undisturbed_tokens(params):
     assert eng.manager.state_slots_zeroed == 2
 
 
-def test_a_prefix_hit_is_declined_and_counted(params):
+def test_a_prefix_hit_is_declined_and_counted(params, served):
     """Mapped blocks would restore the KV and start the conv state at zero in
     mid-prompt: the tree never serves a model with a state."""
     shared = ids_of(40, 24)
     prompts = [shared + ids_of(41, 5), shared + ids_of(42, 7)]
-    eng = engine(params, budget=16)
+    eng, cache = served, served.manager.prefix_cache
+    declined = cache.declined_stateful_total
     got = eng.generate(prompts, max_new_tokens=4)
     assert [list(g) for g in got] == [greedy(params, p, 4) for p in prompts]
-    cache = eng.manager.prefix_cache
     assert cache.hit_blocks_total == 0 and cache.tokens_saved_total == 0
-    assert cache.declined_stateful_total == 1 == eng.health()["state"]["prefix_declined_stateful"]
+    assert cache.declined_stateful_total == declined + 1 \
+        == eng.health()["state"]["prefix_declined_stateful"]
     m = RaggedStateManager(16, 4, 4, prefix_cache=PrefixCache(4), state_slots=2)
     a = m.add_sequence(1, list(range(9)))
     m.ensure_blocks(a, 9)

@@ -112,6 +112,15 @@ def chunks_then_decode(forward, params, ids, chunks, decode=3, cache=None):
     return got, cache
 
 
+@pytest.fixture(scope="module")
+def sound(params):
+    """``(forward, ids, rows)``: the sound program, traced before any case plants
+    a fault and compiled once a shape for every case that serves through it, and
+    what it gives for ``ids`` in chunks of (64, 64, 22) and three decode steps."""
+    forward, ids = forward_of(), ids_of(1, 150 + 3)
+    return forward, ids, chunks_then_decode(forward, params, ids, (64, 64, 22))[0]
+
+
 def test_the_layout_is_one_latent_leaf_of_two_rows_a_layer_and_the_tallies(params):
     own = longcat_flash.init_params(CFG, jax.random.PRNGKey(0))
     assert jax.tree_util.tree_structure(own) == jax.tree_util.tree_structure(params)
@@ -137,12 +146,12 @@ def test_the_layout_is_one_latent_leaf_of_two_rows_a_layer_and_the_tallies(param
 
 @pytest.mark.parametrize("chunks", [(150, ), (64, 64, 22), (1, 70, 79), (5, 131, 1, 2, 11)],
                          ids=lambda c: "x".join(map(str, c)))
-def test_prefill_in_chunks_then_decode_steps_equal_the_reference(params, chunks):
+def test_prefill_in_chunks_then_decode_steps_equal_the_reference(params, sound, chunks):
     """A later chunk attends what an earlier chunk wrote to BOTH of a layer's
     pool rows; a decode step is a chunk of one; the tallies are the reference's
     counts of the same tokens' picks."""
-    ids = ids_of(1, 150 + 3)
-    got, cache = chunks_then_decode(forward_of(), params, ids, chunks)
+    forward, ids, _ = sound
+    got, cache = chunks_then_decode(forward, params, ids, chunks)
     wanted = want(params, ids + [0] * 7, [at for at, _ in got])
     for (at, row), w in zip(got, wanted):
         close(row, w)
@@ -152,11 +161,11 @@ def test_prefill_in_chunks_then_decode_steps_equal_the_reference(params, chunks)
     assert counts[0] > counts[1] > 0 and counts.sum() < len(ids) * TOPK * 2  # all three kinds occur
 
 
-def test_a_compacted_mixed_step_gives_each_sequence_what_it_gets_alone(params):
+def test_a_compacted_mixed_step_gives_each_sequence_what_it_gets_alone(params, sound):
     """Two chunks and a decode row of three sequences on the flat [1, S] axis:
     the shortcut is handed on slot by slot, the dead slots add nothing and are
     not tallied."""
-    forward = forward_of()
+    forward = sound[0]
     seqs = [(ids_of(2, 160), list(range(0, 41))), (ids_of(3, 80), list(range(41, 61))),
             (ids_of(4, 40), list(range(61, 71)))]  # block 71 is the trash block
     heads = (70, 5, 39)
@@ -247,14 +256,14 @@ def wrong_reference(wrong, params, ids, rows):
     "no_lora_scale", "k_pe_scaled_too", "weights_renormalised", "bias_in_the_weights",
     "identity_pick_adds_zero", "a_pick_held_elsewhere_lands_on_a_held_expert",
     "sublayers_share_a_cache_row", "shortcut_from_N_b0", "shortcut_added_after_sublayer_0"])
-def test_each_wrong_reading_of_the_architecture_fails_the_tolerance(wrong, params, monkeypatch):
-    ids = ids_of(1, 150 + 3)
-    sound, _ = chunks_then_decode(forward_of(), params, ids, (64, 64, 22))
-    rows = [at for at, _ in sound]
+def test_each_wrong_reading_of_the_architecture_fails_the_tolerance(wrong, params, sound,
+                                                                   monkeypatch):
+    _, ids, served = sound
+    rows = [at for at, _ in served]
     if wrong.startswith("shortcut"):  # the program stands; the reference reads the layer wrongly
-        got, wanted = sound, wrong_reference(wrong, params, ids, rows)
+        got, wanted = served, wrong_reference(wrong, params, ids, rows)
         right = wrong_reference(None, params, ids, rows)  # the copy itself is the reference
-        assert max(error(row, w) for (_, row), w in zip(sound, right)) < REL_TOL
+        assert max(error(row, w) for (_, row), w in zip(served, right)) < REL_TOL
     else:
         wrong_program(wrong, monkeypatch)
         got, _ = chunks_then_decode(forward_of(), params, ids, (64, 64, 22))
@@ -263,19 +272,39 @@ def test_each_wrong_reading_of_the_architecture_fails_the_tolerance(wrong, param
 
 
 # ----------------------------------------------------------- through the engine
-def engine(params, fast=True, budget=32, seqs=4, **sections):
+def build_engine(params, fast=True, budget=32, **sections):
     conf = {"dtype": "float32", **sections}
     if not fast:
         conf["serving_fastpath"] = {"enabled": False}
     return InferenceEngineV2(longcat_flash, CFG, params, config=conf, num_blocks=96, block_size=8,
-                             max_blocks_per_seq=24, token_budget=budget, max_seqs_per_step=seqs)
+                             max_blocks_per_seq=24, token_budget=budget, max_seqs_per_step=4)
+
+
+@pytest.fixture(scope="module")
+def engine(params):
+    """``engine(fast=True, budget=32)``: one engine a configuration, built when
+    first asked for.  A drained engine replays a wave step for step, so a case
+    serves through it and reads tokens, and counters as deltas; a case that
+    changes the engine it is handed, or its sections, takes ``build_engine``."""
+    made = {}
+
+    def get(fast=True, budget=32):
+        if (fast, budget) not in made:
+            made[fast, budget] = build_engine(params, fast, budget)
+        return made[fast, budget]
+    return get
+
+
+GREEDY = {}  # (prompt, new) -> the reference's continuation: ``params`` is the module's one draw
 
 
 def greedy(params, prompt, new):
-    ids = list(prompt)
-    for _ in range(new):
-        ids.append(int(np.argmax(want(params, ids + [0] * (-len(ids) % 16), [len(ids) - 1])[0])))
-    return ids
+    if (tuple(prompt), new) not in GREEDY:
+        ids = list(prompt)
+        for _ in range(new):
+            ids.append(int(np.argmax(want(params, ids + [0] * (-len(ids) % 16), [len(ids) - 1])[0])))
+        GREEDY[tuple(prompt), new] = ids
+    return list(GREEDY[tuple(prompt), new])
 
 
 def reference_counts(params, sequences):
@@ -284,11 +313,20 @@ def reference_counts(params, sequences):
     and the last too where the loop had launched the next step before it knew
     the sequence was done (``live_tokens`` counts that token as well)."""
     least, most = np.zeros(2, np.int64), np.zeros(2, np.int64)
-    with jax.default_matmul_precision("highest"):
-        for ids in sequences:
-            least += np.asarray(ref.hidden_states(SIZES, params, jnp.asarray(ids[:-1]))[1])
-            most += np.asarray(ref.hidden_states(SIZES, params, jnp.asarray(ids))[1])
+    for ids in sequences:
+        least += picks_of(params, tuple(ids[:-1]))
+        most += picks_of(params, tuple(ids))
     return least, most
+
+
+PICKS = {}  # a sequence -> the reference's counts of its picks, worked out once
+
+
+def picks_of(params, ids):
+    if ids not in PICKS:
+        with jax.default_matmul_precision("highest"):
+            PICKS[ids] = np.asarray(ref.hidden_states(SIZES, params, jnp.asarray(ids))[1])
+    return PICKS[ids]
 
 
 def tallied_within(counters, least, most, slack=2):
@@ -299,33 +337,33 @@ def tallied_within(counters, least, most, slack=2):
 
 
 @pytest.mark.parametrize("budget", [32, 48])
-def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params, budget):
+def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params, engine, budget):
     """Two ``token_budget``s cut a prompt at different places; the tokens are the
     reference's either way, through compacted passes and fused bursts, and the
     tallies read once a wave are the reference's counts."""
     prompts = [ids_of(10 + i, n) for i, n in enumerate((5, 90, 140, 9))]
-    eng = engine(params, budget=budget)
-    syncs = eng.counters.host_syncs
+    eng = engine(budget=budget)
+    before = eng.counters.snapshot()
     got = eng.generate(prompts, max_new_tokens=5)
-    assert eng.counters.burst_tokens > 0 and eng.counters.compact_passes > 0
+    c = eng.counters.delta_since(before)
+    assert c["burst_tokens"] > 0 and c["compact_passes"] > 0
     for p, g in zip(prompts, got):
         assert list(g) == greedy(params, p, 5)
-    c = eng.counters
-    assert c.moe_routed_rows == c.live_tokens * TOPK * 2  # every pick, in each layer's one expert layer
-    assert tallied_within(c.snapshot(), *reference_counts(params, [list(g) for g in got]))
-    assert 0 < c.moe_held_picks < c.moe_identity_picks
-    assert c.moe_identity_picks < c.moe_routed_rows - c.moe_identity_picks - c.moe_held_picks
-    assert set(c.snapshot()) == set(c.FIELDS + c.TALLIED_FIELDS)
+    assert c["moe_routed_rows"] == c["live_tokens"] * TOPK * 2  # every pick, in each layer's one expert layer
+    assert tallied_within(c, *reference_counts(params, [list(g) for g in got]))
+    assert 0 < c["moe_held_picks"] < c["moe_identity_picks"]
+    assert c["moe_identity_picks"] < c["moe_routed_rows"] - c["moe_identity_picks"] - c["moe_held_picks"]
+    assert set(c) == set(eng.counters.FIELDS + eng.counters.TALLIED_FIELDS)
     # one fetch a wave beyond the steps' and the bursts' own
-    other = engine(params, budget=budget)
+    other = build_engine(params, budget=budget)
     other.counters.tallied = None
     other.generate(prompts, max_new_tokens=5)
-    assert c.host_syncs - syncs == other.counters.host_syncs + 1
+    assert c["host_syncs"] == other.counters.host_syncs + 1
     eng.check_kv_invariant()
 
 
-def test_the_tallies_are_window_deltas_and_wrap_around(params):
-    eng = engine(params)
+def test_the_tallies_are_window_deltas_and_wrap_around(params, engine):
+    eng = engine()
     eng.generate([ids_of(20, 30)], max_new_tokens=3)
     first = eng.counters.snapshot()
     eng.generate([ids_of(21, 40)], max_new_tokens=3)
@@ -339,24 +377,27 @@ def test_the_tallies_are_window_deltas_and_wrap_around(params):
     assert "moe_identity_picks" not in ServeCounters().snapshot()
 
 
-def test_the_fast_path_and_the_padded_oracle_serve_the_same_tokens(params):
+def test_the_fast_path_and_the_padded_oracle_serve_the_same_tokens(engine):
     prompts = [ids_of(50 + i, n) for i, n in enumerate((33, 7, 81))]
-    fast, slow = engine(params), engine(params, fast=False)
+    fast, slow = engine(), engine(fast=False)
+    before = fast.counters.snapshot(), slow.counters.snapshot()
     assert [list(g) for g in fast.generate(prompts, max_new_tokens=3)] == \
         [list(g) for g in slow.generate(prompts, max_new_tokens=3)]
-    assert slow.counters.compact_passes == 0 < fast.counters.compact_passes
+    fast, slow = fast.counters.delta_since(before[0]), slow.counters.delta_since(before[1])
+    assert slow["compact_passes"] == 0 < fast["compact_passes"]
     # the fast path's are a few tokens more: a step launched before the last token was known
     # done, and a burst's padded row (every row of a burst holds one token as the program sees it)
-    assert 0 < slow.counters.moe_identity_picks <= fast.counters.moe_identity_picks
-    assert fast.counters.moe_identity_picks < 1.1 * slow.counters.moe_identity_picks
+    assert 0 < slow["moe_identity_picks"] <= fast["moe_identity_picks"]
+    assert fast["moe_identity_picks"] < 1.1 * slow["moe_identity_picks"]
 
 
-def test_a_shared_prefix_block_holds_both_sublayers_rows_and_a_copy_moves_no_tally(params):
+def test_a_shared_prefix_block_holds_both_sublayers_rows_and_a_copy_moves_no_tally(params, engine):
     head = ids_of(70, 64)
     prompts = [head + ids_of(71 + i, 20 + 7 * i) for i in range(2)]
-    eng = engine(params)
+    eng = engine()
+    hits = eng.health()["prefix_cache"]["hits_total"]
     got = eng.generate(prompts, max_new_tokens=3)
-    assert eng.health()["prefix_cache"]["hits_total"] >= 64 // 8 - 1
+    assert eng.health()["prefix_cache"]["hits_total"] - hits >= 64 // 8 - 1
     for p, g in zip(prompts, got):
         assert list(g) == greedy(params, p, 3)
     before = jax.tree_util.tree_map(np.asarray, eng.kv)
@@ -368,10 +409,10 @@ def test_a_shared_prefix_block_holds_both_sublayers_rows_and_a_copy_moves_no_tal
     eng.check_kv_invariant()
 
 
-def test_speculative_decoding_serves_the_same_tokens_and_tensor_parallelism_is_refused(params):
+def test_speculative_decoding_serves_the_same_tokens_and_tensor_parallelism_is_refused(params, engine):
     prompt = ids_of(40, 60)
-    plain = engine(params).generate([prompt], max_new_tokens=6)[0]
-    spec = engine(params, serving_spec_decode={"enabled": True, "k": 3})
+    plain = engine().generate([prompt], max_new_tokens=6)[0]
+    spec = build_engine(params, serving_spec_decode={"enabled": True, "k": 3})
     assert list(spec.generate([prompt], max_new_tokens=6)[0]) == list(plain)
     assert spec.counters.spec_rounds > 0
     with pytest.raises(NotImplementedError, match="tensor-parallel"):

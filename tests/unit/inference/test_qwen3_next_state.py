@@ -171,6 +171,14 @@ def engine(params, fast=True, budget=32, seqs=4, **sections):
                              max_blocks_per_seq=24, token_budget=budget, max_seqs_per_step=seqs)
 
 
+@pytest.fixture(scope="module")
+def served(params):
+    """The default engine, built once for the cases that only serve a wave
+    through it (drained, it replays a wave step for step) and read tokens, and
+    its counters and the manager's totals as deltas."""
+    return engine(params)
+
+
 def greedy(params, prompt, new):
     ids = list(prompt)
     for _ in range(new):
@@ -178,11 +186,12 @@ def greedy(params, prompt, new):
     return ids
 
 
-def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params):
+def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params, served):
     prompts = [ids_of(10 + i, n) for i, n in enumerate((5, 90, 140, 9, 70, 3))]
-    eng = engine(params)
+    eng, before = served, (served.counters.snapshot(), served.health()["state"])
     got = eng.generate(prompts, max_new_tokens=6)
-    assert eng.counters.burst_tokens > 0 and eng.counters.compact_passes > 0
+    c = eng.counters.delta_since(before[0])
+    assert c["burst_tokens"] > 0 and c["compact_passes"] > 0
     for p, g in list(zip(prompts, got))[:3]:  # one decode-only, one cut in three, one in five
         assert list(g) == greedy(params, p, 6)
     state = eng.health()["state"]
@@ -192,22 +201,24 @@ def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(pa
     # six sequences through four slots: every hand-out starts a sequence from zero
     assert state == {"enabled": True, "state_slots": 4, "state_slots_in_use": 0,
                      "state_bytes_per_seq": qwen3_next.state_bytes_per_seq(CFG),
-                     "state_slots_zeroed": 6, "prefix_declined_stateful": 0}
-    c = eng.counters
+                     "state_slots_zeroed": before[1]["state_slots_zeroed"] + 6,
+                     "prefix_declined_stateful": 0}
     # the scan's counters: a pass that walks chunks counts its live tokens (a mixed pass's decode
     # rows among them) in each of the six DeltaNet layers; a decode step or a burst walks none
-    assert c.scan_positions == c.scan_chunks * CHUNK and 0 < c.scan_live_positions <= c.scan_positions
-    assert c.scan_live_positions % 6 == 0
-    assert sum(map(len, prompts)) <= c.scan_live_positions // 6 < c.live_tokens
-    assert c.moe_routed_rows == c.live_tokens * 4 * 8
+    assert c["scan_positions"] == c["scan_chunks"] * CHUNK
+    assert 0 < c["scan_live_positions"] <= c["scan_positions"]
+    assert c["scan_live_positions"] % 6 == 0
+    assert sum(map(len, prompts)) <= c["scan_live_positions"] // 6 < c["live_tokens"]
+    assert c["moe_routed_rows"] == c["live_tokens"] * 4 * 8
 
 
-def test_the_fast_path_and_the_padded_oracle_serve_the_same_tokens(params):
+def test_the_fast_path_and_the_padded_oracle_serve_the_same_tokens(params, served):
     prompts = [ids_of(50 + i, n) for i, n in enumerate((33, 7, 81))]
-    fast, slow = engine(params), engine(params, fast=False)
+    fast, slow = served, engine(params, fast=False)
+    compacted = fast.counters.compact_passes
     assert [list(g) for g in fast.generate(prompts, max_new_tokens=5)] == \
         [list(g) for g in slow.generate(prompts, max_new_tokens=5)]
-    assert slow.counters.compact_passes == 0 < fast.counters.compact_passes
+    assert slow.counters.compact_passes == 0 < fast.counters.compact_passes - compacted
 
 
 def test_a_slot_reused_after_retire_starts_from_zero(params):

@@ -5,6 +5,8 @@ count across a mixed-arrival scenario, and byte-identical results against the
 ``serving_fastpath.enabled=False`` reference loop (including under injected
 allocator faults and expiring deadlines)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -42,6 +44,20 @@ def _engine(config=None, *, seq=256, **kw):
     return InferenceEngineV2(llama, cfg, _PARAMS[seq], **defaults)
 
 
+_REFERENCE = {"dtype": "float32", "serving_fastpath": {"enabled": False}}
+_STEPWISE = {"dtype": "float32", "serving_fastpath": {"fusion_min_steps": NO_FUSION}}
+
+
+@functools.lru_cache(maxsize=None)
+def _shared_engine(kind="fast"):
+    """One engine a configuration for the cases that only serve waves through
+    it and read tokens, or counters as deltas: the default, the padded oracle
+    (``reference``) and the pipeline with fusion off (``stepwise``).  A case
+    that counts compiles, injects faults, samples or brings a clock or a
+    collector builds its own with ``_engine``."""
+    return _engine({"fast": None, "reference": _REFERENCE, "stepwise": _STEPWISE}[kind])
+
+
 def _no_pending(results):
     for r in results:
         toks = r.tokens if hasattr(r, "tokens") else r
@@ -53,13 +69,11 @@ PROMPTS = [[1, 2, 3, 4, 5], [7, 8, 9], [11, 12, 13, 14, 15, 16, 17], [20, 21]]
 
 # ----------------------------------------------------- reference equivalence
 def test_fastpath_matches_reference_strict_and_nonstrict():
-    fast = _engine().generate(PROMPTS, max_new_tokens=9)
-    ref = _engine({"dtype": "float32",
-                   "serving_fastpath": {"enabled": False}}).generate(PROMPTS,
-                                                                     max_new_tokens=9)
+    fast = _shared_engine().generate(PROMPTS, max_new_tokens=9)
+    ref = _shared_engine("reference").generate(PROMPTS, max_new_tokens=9)
     assert fast == ref
     _no_pending(fast)
-    fast_ns = _engine().generate(PROMPTS, max_new_tokens=9, strict=False)
+    fast_ns = _shared_engine().generate(PROMPTS, max_new_tokens=9, strict=False)
     assert [r.tokens for r in fast_ns] == ref
     assert all(r.status == "ok" for r in fast_ns)
 
@@ -68,18 +82,13 @@ def test_pipelined_stepwise_matches_reference_incl_eos():
     """Fusion disabled: every decode step goes through the deferred-pick
     pipeline (dispatch N, absorb N-1), including the eos/max_new overshoot
     truncation — tokens must still be byte-identical."""
-    ref_eng = _engine({"dtype": "float32", "serving_fastpath": {"enabled": False}})
-    ref = ref_eng.generate(PROMPTS, max_new_tokens=7)
-    pl_eng = _engine({"dtype": "float32",
-                      "serving_fastpath": {"fusion_min_steps": NO_FUSION}})
-    got = pl_eng.generate(PROMPTS, max_new_tokens=7)
+    a, b = _shared_engine("stepwise"), _shared_engine("reference")
+    ref = b.generate(PROMPTS, max_new_tokens=7)
+    got = a.generate(PROMPTS, max_new_tokens=7)
     assert got == ref
-    assert pl_eng.counters.burst_tokens == 0  # really went stepwise
+    assert a.counters.burst_tokens == 0  # really went stepwise
     # eos mid-decode: the in-flight overshoot token must be truncated away
     eos = ref[0][len(PROMPTS[0]) + 3]
-    a = _engine({"dtype": "float32",
-                 "serving_fastpath": {"fusion_min_steps": NO_FUSION}})
-    b = _engine({"dtype": "float32", "serving_fastpath": {"enabled": False}})
     got = a.generate(PROMPTS, max_new_tokens=7, eos_token_id=eos)
     want = b.generate(PROMPTS, max_new_tokens=7, eos_token_id=eos)
     assert got == want
@@ -103,7 +112,7 @@ def test_fastpath_matches_reference_under_allocator_faults():
         return [(r.status, r.tokens) for r in res]
 
     fast = run({"dtype": "float32"})
-    ref = run({"dtype": "float32", "serving_fastpath": {"enabled": False}})
+    ref = run(_REFERENCE)
     assert fast == ref
     healthy = _engine().generate(PROMPTS, max_new_tokens=6)
     assert [t for _, t in fast] == healthy
@@ -121,7 +130,7 @@ def test_fastpath_matches_reference_under_expiring_deadlines():
         return [(r.uid, r.status, r.tokens) for r in res], clock.calls
 
     fast, fast_calls = run({"dtype": "float32"})
-    ref, ref_calls = run({"dtype": "float32", "serving_fastpath": {"enabled": False}})
+    ref, ref_calls = run(_REFERENCE)
     assert fast == ref
     assert fast_calls == ref_calls  # identical clock consumption = same policy
     assert any(status == "deadline_expired" for _, status, _ in fast)
@@ -131,22 +140,23 @@ def test_fastpath_matches_reference_under_expiring_deadlines():
 
 # ------------------------------------------------------- host-sync invariants
 def test_steady_state_decode_at_most_one_sync_per_iteration():
-    eng = _engine({"dtype": "float32",
-                   "serving_fastpath": {"fusion_min_steps": NO_FUSION}})
+    eng = _shared_engine("stepwise")
+    before = eng.counters.snapshot()
     eng.generate(PROMPTS, max_new_tokens=12)
-    c = eng.counters
-    assert c.loop_iterations > 0
-    assert c.host_syncs <= c.loop_iterations + c.flushes, c.snapshot()
+    c = eng.counters.delta_since(before)
+    assert c["loop_iterations"] > 0
+    assert c["host_syncs"] <= c["loop_iterations"] + c["flushes"], c
 
 
 def test_fused_decode_is_sub_one_sync_per_token():
-    eng = _engine()
+    eng = _shared_engine()
+    before = eng.counters.snapshot()
     out = eng.generate(PROMPTS, max_new_tokens=16)
-    c = eng.counters
+    c = eng.counters.delta_since(before)
     tokens = sum(len(t) - len(p) for t, p in zip(out, PROMPTS))
-    assert c.burst_tokens > c.step_tokens  # fusion carried the decode
-    assert c.host_syncs < tokens / 2, c.snapshot()
-    assert c.host_syncs <= c.loop_iterations + c.flushes
+    assert c["burst_tokens"] > c["step_tokens"]  # fusion carried the decode
+    assert c["host_syncs"] < tokens / 2, c
+    assert c["host_syncs"] <= c["loop_iterations"] + c["flushes"]
 
 
 def test_bounded_compiles_across_three_wave_scenario():
@@ -241,8 +251,7 @@ def test_table_width_steps_and_hysteresis():
 
 
 def test_table_width_reference_mode_keeps_doubling():
-    eng = _engine({"dtype": "float32", "serving_fastpath": {"enabled": False}},
-                  max_blocks_per_seq=64)
+    eng = _engine(_REFERENCE, max_blocks_per_seq=64)
     assert eng._table_width_for(5) == 8
     assert eng._table_width_for(9) == 16
     assert eng._table_width_for(2) == 2  # no hysteresis in the oracle
@@ -369,6 +378,13 @@ def _compacting_engine(family, fastpath, tp=1):
         max_seqs_per_step=4)
 
 
+# One engine a (family, fastpath, tp) for the cases that only serve through it:
+# a drained engine replays a wave step for step (its prefix tree is empty, its
+# table width reset), so they read tokens, and counters as deltas.  A case that
+# breaks a step or reaches into the scheduler builds its own.
+_shared_compacting_engine = functools.lru_cache(maxsize=None)(_compacting_engine)
+
+
 @pytest.mark.parametrize("family,tp", [("llama", 1), ("mistral", 1), ("llama", 4), ("falcon", 1),
                                        ("bloom", 1), ("opt", 1)],
                          ids=["llama", "mistral-window", "llama-tp4", "falcon-parallel-residual",
@@ -384,10 +400,12 @@ def test_compacted_mixed_wave_matches_the_padded_reference(family, tp):
     its forward takes."""
     served = {}
     for fastpath in (True, False):
-        eng = _compacting_engine(family, fastpath, tp)
+        eng = _shared_compacting_engine(family, fastpath, tp)
+        before = eng.counters.snapshot()
         with LogitSpy(eng, _COMPACT_PROMPTS) as spy:
-            served[fastpath] = (eng.generate(_COMPACT_PROMPTS, max_new_tokens=8), spy.rows, eng)
-    (fast, fast_rows, fast_eng), (ref, ref_rows, ref_eng) = served[True], served[False]
+            served[fastpath] = (eng.generate(_COMPACT_PROMPTS, max_new_tokens=8), spy.rows,
+                                eng.counters.delta_since(before), eng)
+    (fast, fast_rows, c, fast_eng), (ref, ref_rows, ref_c, _) = served[True], served[False]
     assert fast == ref
     _no_pending(fast)
     assert sorted(fast_rows) == sorted(ref_rows) == [0, 1, 2, 3]
@@ -396,10 +414,9 @@ def test_compacted_mixed_wave_matches_the_padded_reference(family, tp):
     # the wave really ran compacted: the two mixed buckets are over the bound
     names = {e["name"] for e in fast_eng.ledger.events if e["site"] == "fwd"}
     assert {"fwd_n4_t8_b4", "fwd_n4_t16_b8"} <= names
-    c = fast_eng.counters
-    assert c.compact_passes >= 2 and ref_eng.counters.compact_passes == 0
-    assert c.live_tokens == ref_eng.counters.live_tokens <= c.token_slots
-    assert c.token_slots < ref_eng.counters.token_slots
+    assert c["compact_passes"] >= 2 and ref_c["compact_passes"] == 0
+    assert c["live_tokens"] == ref_c["live_tokens"] <= c["token_slots"]
+    assert c["token_slots"] < ref_c["token_slots"]
     fast_eng.check_kv_invariant()
 
 
@@ -420,6 +437,25 @@ def _ragged_chunk(rng, counts, t, block_size, num_blocks, width):
     return tokens, counts, start, tables
 
 
+@functools.lru_cache(maxsize=None)
+def _family_forward(make):
+    """``make() -> (module, cfg)``, once a family: the module, its config, its
+    parameters with every leaf moved off its start (biases start at zero and
+    gains at one) and ONE jitted ``forward_paged(params, tokens, counts, start,
+    tables, kv, bound, last_rows)`` over blocks of 8, compiled once a ``(bound,
+    last_rows)``: the chunk and the pool are arguments, so a case is a call."""
+    module, cfg = make()
+    leaves, tree = jax.tree_util.tree_flatten(module.init_params(cfg, jax.random.PRNGKey(3)))
+    params = jax.tree_util.tree_unflatten(tree, [
+        leaf + 0.05 * jax.random.normal(jax.random.PRNGKey(i), leaf.shape, leaf.dtype)
+        for i, leaf in enumerate(leaves)])
+    forward = jax.jit(lambda params, tokens, counts, start, tables, kv, bound, last_rows:
+                      module.forward_paged(cfg, params, tokens, counts, start, tables, kv,
+                                           block_size=8, live_token_bound=bound,
+                                           last_rows=last_rows), static_argnums=(6, 7))
+    return module, cfg, params, forward
+
+
 _COUNTS = {"empty-row-between": [10, 0, 1, 5], "one-row-exactly-S": [16, 0, 0, 0],
            "leading-empty-rows": [0, 0, 0, 9], "decodes-around-a-chunk-exactly-S": [1, 1, 13, 1],
            "under-S": [0, 7, 0, 0], "every-row-exactly-S": [4, 4, 4, 4]}
@@ -433,12 +469,7 @@ _THREE = ("empty-row-between", "leading-empty-rows", "decodes-around-a-chunk-exa
     for shape, counts in _COUNTS.items() for family in _FAMILIES
     if family in ("llama", "mistral") or shape in _THREE])
 def test_forward_paged_compacted_agrees_with_padded(family, counts):
-    module, cfg = _FAMILIES[family]()
-    # biases start at zero and gains at one: every leaf is moved off its start
-    leaves, tree = jax.tree_util.tree_flatten(module.init_params(cfg, jax.random.PRNGKey(3)))
-    params = jax.tree_util.tree_unflatten(tree, [
-        leaf + 0.05 * jax.random.normal(jax.random.PRNGKey(i), leaf.shape, leaf.dtype)
-        for i, leaf in enumerate(leaves)])
+    module, cfg, params, forward = _family_forward(_FAMILIES[family])
     rng = np.random.default_rng(sum(c * 17**i for i, c in enumerate(counts)))
     num_blocks, block_size, t, bound = 33, 8, 16, 16
     tokens, counts, start, tables = _ragged_chunk(rng, counts, t, block_size, num_blocks, 8)
@@ -446,10 +477,8 @@ def test_forward_paged_compacted_agrees_with_padded(family, counts):
     kv = jax.tree_util.tree_map(
         lambda a: jnp.asarray(rng.normal(size=a.shape), jnp.float32),
         module.init_paged_cache(cfg, num_blocks, block_size, dtype=jnp.float32))
-    padded, kv_padded = module.forward_paged(cfg, params, tokens, counts, start, tables, kv,
-                                             block_size=block_size)
-    flat, kv_flat = module.forward_paged(cfg, params, tokens, counts, start, tables, kv,
-                                         block_size=block_size, live_token_bound=bound)
+    padded, kv_padded = forward(params, tokens, counts, start, tables, kv, None, False)
+    flat, kv_flat = forward(params, tokens, counts, start, tables, kv, bound, False)
     live = np.arange(t)[None, :] < counts[:, None]
     assert flat.shape == padded.shape
     np.testing.assert_allclose(np.asarray(flat)[live], np.asarray(padded)[live],

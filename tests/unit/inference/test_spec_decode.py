@@ -6,6 +6,7 @@ non-strict), journal replay of a crash mid-stream (accepted-prefix frames
 only, never draft tokens), and census/allocator invariants when a rejected
 draft's block allocation crosses a block boundary."""
 
+import functools
 import os
 
 import jax
@@ -50,6 +51,15 @@ def _spec_conf(extra=None, **spec):
             "serving_spec_decode": {"enabled": True, **spec}}
     conf.update(extra or {})
     return conf
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_engine():
+    """The default engine, one for every case that wants the spec-off greedy
+    tokens of a wave (drained, it replays a wave step for step).  A spec engine
+    is never shared: its controller walks ``k`` down on what earlier waves
+    accepted, to where a later case would run no round at all."""
+    return _engine()
 
 
 PROMPTS = [[1, 2, 3, 4, 5], [7, 8, 9], [11, 12, 13, 14, 15, 16, 17], [20, 21]]
@@ -198,7 +208,7 @@ def test_spec_stats_snapshot_and_acceptance():
 
 # ==================================================== engine sample identity
 def test_spec_greedy_identity_fastpath_strict_and_nonstrict():
-    ref = _engine().generate(PROMPTS, max_new_tokens=9)
+    ref = _plain_engine().generate(PROMPTS, max_new_tokens=9)
     spec = _engine(_spec_conf()).generate(PROMPTS, max_new_tokens=9)
     assert spec == ref
     spec_ns = _engine(_spec_conf()).generate(PROMPTS, max_new_tokens=9,
@@ -220,12 +230,11 @@ def test_spec_greedy_identity_reference_loop():
 
 
 def test_spec_greedy_identity_with_eos():
-    ref_eng = _engine()
-    ref = ref_eng.generate(PROMPTS, max_new_tokens=9)
+    ref = _plain_engine().generate(PROMPTS, max_new_tokens=9)
     eos = ref[0][len(PROMPTS[0]) + 4]
     a = _engine(_spec_conf()).generate(PROMPTS, max_new_tokens=9,
                                        eos_token_id=eos)
-    b = _engine().generate(PROMPTS, max_new_tokens=9, eos_token_id=eos)
+    b = _plain_engine().generate(PROMPTS, max_new_tokens=9, eos_token_id=eos)
     assert a == b
 
 
@@ -236,7 +245,7 @@ def test_spec_model_drafter_identity_and_full_acceptance():
     eng = _engine(_spec_conf(drafter="model"))
     eng.attach_draft_model(llama, _cfg(), _PARAMS[256])
     got = eng.generate(PROMPTS, max_new_tokens=12)
-    ref = _engine().generate(PROMPTS, max_new_tokens=12)
+    ref = _plain_engine().generate(PROMPTS, max_new_tokens=12)
     assert got == ref
     spec = eng.health()["spec_decode"]
     assert spec["rounds_total"] > 0
@@ -291,7 +300,7 @@ def test_spec_declines_when_deadline_armed():
 
 # ====================================================== spec OFF byte-identity
 def test_spec_off_is_default_and_inert():
-    eng = _engine()
+    eng = _plain_engine()
     assert not eng.spec_cfg.enabled
     assert eng.spec_stats is None and eng._drafter is None
     out = eng.generate(PROMPTS, max_new_tokens=9)
@@ -299,12 +308,12 @@ def test_spec_off_is_default_and_inert():
     assert eng.counters.spec_proposed == 0
     assert eng.counters.spec_accepted == 0
     assert eng.health()["spec_decode"] == {"enabled": False}
-    assert out == _engine().generate(PROMPTS, max_new_tokens=9)
+    assert out == eng.generate(PROMPTS, max_new_tokens=9)
 
 
 def test_spec_off_exposition_has_no_spec_families():
     from deepspeed_tpu.monitor.metrics import MetricsRegistry, populate_from_engine
-    eng = _engine()
+    eng = _plain_engine()
     eng.generate(PROMPTS, max_new_tokens=6)
     reg = MetricsRegistry()
     populate_from_engine(reg, eng)
@@ -365,7 +374,7 @@ def test_journal_replay_crash_mid_stream_accepted_prefixes_only(tmp_path):
             break
     assert spec_rounds > 0, "no draft/verify round ran before the crash"
     # crash: abandon the engine mid-stream — the WAL holds flushed frames only
-    ref = _engine().generate([list(p) for p in prompts], max_new_tokens=24)
+    ref = _plain_engine().generate([list(p) for p in prompts], max_new_tokens=24)
     state = replay_journal(path)
     for uid, p in enumerate(prompts):
         entry = state.entries[uid]
@@ -386,7 +395,7 @@ def test_rejected_draft_across_block_boundary_rolls_back_clean():
     census/allocator partition invariant must hold."""
     eng = _engine(_spec_conf())
     prompt = list(range(1, 16))  # 15 tokens: 2 blocks of 8
-    ref = _engine().generate([list(prompt)], max_new_tokens=4)[0]
+    ref = _plain_engine().generate([list(prompt)], max_new_tokens=4)[0]
     eng.put([0], [list(prompt)])
     while len(eng.manager.seqs[0].tokens) < 16:
         eng.step()  # prefill + the first decode step

@@ -1,0 +1,238 @@
+"""What ``test_tpu_compile.py`` asks of the kernels, asked of whole step programs:
+every family's ``forward_paged`` at its published widths, compiled for the
+described v5e as a decode step, a chunk and a burst's body."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops._pallas import kernel_calls
+
+from .test_tpu_compile import DH, H, KV, WINDOW
+
+
+def mistral_shapes(chip, layers):
+    """Mistral-7B at its published widths and ``layers`` layers (every layer is
+    one scan body) with the serving cells' pool of 368 blocks of 128, as shapes
+    on the described chip: ``(config, params, kv)``."""
+    from deepspeed_tpu.models import mistral
+    cfg = mistral.MistralConfig(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+                                num_layers=layers, num_heads=H, num_kv_heads=KV,
+                                max_seq_len=32768, sliding_window=WINDOW)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    return (cfg, on_chip(jax.eval_shape(lambda: mistral.init_params(cfg, jax.random.PRNGKey(0)))),
+            on_chip(jax.eval_shape(lambda: mistral.init_paged_cache(cfg, 368, 128))))
+
+
+@pytest.mark.parametrize("n,t,b", [(32, 256, 20), (4, 256, 36)],
+                         ids=["chat-burst-n32", "long-prompt-n4"])
+def test_compacted_ragged_forward_compiles_and_holds_no_tensor_more_than_padded(chip, n, t, b):
+    """The Mistral ragged forward at its published widths (two layers; every
+    layer is one scan body) over a mixed SplitFuse bucket: compacted onto 256
+    flat slots (ISSUE 25) it compiles for the v5e, still calls the one paged
+    kernel, and holds not one tensor more than the padded program.  Since the
+    pool is carried in place (ISSUE 28) a program's temporaries are a megabyte
+    where its activations fit the logits' buffer (n = 4: 1,160,704 bytes
+    compacted, 1,064,960 padded) and how the compiler packs the index vectors
+    decides the rest: 94 KiB more here, and not in proportion to the slots.  So
+    the guard is one tensor: the excess stays under the smallest array the
+    per-token layers make, a step's K rows ``[S, KV, Dh]`` (512 KiB).  At
+    n = 32 the compacted program holds 161 MiB less."""
+    from deepspeed_tpu.models import mistral
+    cfg, params, kv = mistral_shapes(chip, layers=2)
+    ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, b))]
+    held = {}
+    for bound in (256, None):
+        def fwd(params, kv, tokens, n_tokens, start_pos, tables):
+            return mistral.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
+                                         block_size=128, live_token_bound=bound)
+        compiled = jax.jit(fwd, donate_argnums=(1, )).lower(params, kv, *ints).compile()
+        assert kernel_calls(compiled.as_text()) == {"paged_attention": 1, "kv_write": 1}
+        m = compiled.memory_analysis()
+        held[bound] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                       + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert held[256] - held[None] < 256 * KV * DH * 2
+
+
+def pool_shaped_results(compiled_text, pool_shape):
+    """``(opcode, shape)`` of every instruction of an optimised HLO text with a
+    result shaped like the pool: whole layers of it (one, or all L) with the
+    head dimension last, however the dimensions before are folded
+    (``[L,NB,KV,bs,Dh]``, ``[NB,KV,bs,Dh]``, ``[L*NB*KV*bs,Dh]``).  Left out:
+    the instructions that only hand a buffer on."""
+    layer = int(np.prod(pool_shape[1:]))
+    found = []
+    for line in compiled_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if m is None or m.group(2) in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                                       "while", "conditional", "call"):
+            continue
+        for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1)):
+            dims = [int(d) for d in dims.split(",")]
+            if dims[-1] == pool_shape[-1] and int(np.prod(dims)) % layer == 0:
+                found.append((m.group(2), m.group(1)))
+                break
+    return found
+
+
+def olmoe_shapes(chip, layers):
+    """OLMoE-1B-7B at its published widths (16 MHA heads: 16 KV heads a block,
+    64 experts) over the serving cells' pool of 368 blocks of 128."""
+    from deepspeed_tpu.models import olmoe
+    cfg = olmoe.OlmoeConfig(num_layers=layers)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    return (olmoe, cfg,
+            on_chip(jax.eval_shape(lambda: olmoe.init_params(cfg, jax.random.PRNGKey(0)))),
+            on_chip(jax.eval_shape(lambda: olmoe.init_paged_cache(cfg, 368, 128))))
+
+
+def deepseek_v2_shapes(chip, layers):
+    """DeepSeek-V2 as ``serve.mla-long-prompt`` holds it (40 of 160 experts, a
+    quarter of the vocabulary; ``layers`` = the dense layer and ``layers - 1``
+    expert layers, a scan each) over its latent pool of 1,024 blocks: one leaf
+    ``[L, 1024, 1, 128, 640]``."""
+    from deepspeed_tpu.models import deepseek_v2
+    cfg = deepseek_v2.DeepseekV2Config(vocab_size=25600, num_layers=layers, num_local_experts=40)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    return (deepseek_v2, cfg,
+            on_chip(jax.eval_shape(lambda: deepseek_v2.init_params(cfg, jax.random.PRNGKey(0)))),
+            on_chip(jax.eval_shape(lambda: deepseek_v2.init_paged_cache(cfg, 1024, 128))))
+
+
+def lfm2_shapes(chip, layers):
+    """LFM2-24B-A2B as ``serve.conv-chat-burst`` holds it (``layers`` = 10: both
+    dense conv layers and two periods of attention, conv, conv, conv; 64
+    experts): a pool of the two attention layers alone, two 64-wide KV heads a
+    row ``[2, 1024, 4, 128, 128]``, and the conv state ``[8, 33, 2, 2048]``."""
+    from deepspeed_tpu.models import lfm2
+    cfg = lfm2.Lfm2Config(num_layers=layers)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    return (lfm2, cfg,
+            on_chip(jax.eval_shape(lambda: lfm2.init_params(cfg, jax.random.PRNGKey(0)))),
+            on_chip(jax.eval_shape(lambda: lfm2.init_paged_cache(cfg, 1024, 128))))
+
+
+def qwen3_next_shapes(chip, layers):
+    """Qwen3-Next-80B-A3B as ``serve.gdn-long-prompt`` holds it (128 of 512
+    experts, a quarter of the vocabulary; ``layers`` = 8: two periods of three
+    Gated DeltaNet layers and a gated attention, one scan): a pool of the
+    attention layers alone at heads of 256 ``[2, 800, 2, 128, 256]`` and the
+    DeltaNet layers' state, two leaves of eight slots and a trash slot: the
+    shift ``[6, 9, 3, 8192]`` and the float32 matrices ``[6, 9, 32, 128, 128]``."""
+    from deepspeed_tpu.models import qwen3_next
+    cfg = qwen3_next.Qwen3NextConfig(vocab_size=37984, num_layers=layers, num_local_experts=128)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    kv = jax.eval_shape(lambda: qwen3_next.init_paged_cache(cfg, 800, 128, state_slots=8))
+    return (qwen3_next, cfg,
+            on_chip(jax.eval_shape(lambda: qwen3_next.init_params(cfg, jax.random.PRNGKey(0)))),
+            jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype), kv))
+
+
+def glm_moe_dsa_shapes(chip, layers):
+    """GLM-5 as ``serve.dsa-long-prompt`` holds it (16 of 256 experts, an eighth
+    of the vocabulary; ``layers`` = the three dense layers and ``layers - 3``
+    expert layers, a scan each) over its pool of 1,024 blocks: TWO leaves of
+    unlike widths, the index keys ``[L, 1024, 1, 128, 128]`` and the latent
+    ``[L, 1024, 1, 128, 640]``."""
+    from deepspeed_tpu.models import glm_moe_dsa
+    cfg = glm_moe_dsa.GlmMoeDsaConfig(vocab_size=19360, num_layers=layers, num_local_experts=16)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    return (glm_moe_dsa, cfg,
+            on_chip(jax.eval_shape(lambda: glm_moe_dsa.init_params(cfg, jax.random.PRNGKey(0)))),
+            on_chip(jax.eval_shape(lambda: glm_moe_dsa.init_paged_cache(cfg, 1024, 128))))
+
+
+def mistral_module_and_shapes(chip, layers):
+    from deepspeed_tpu.models import mistral
+    return (mistral, ) + mistral_shapes(chip, layers)
+
+
+IN_PLACE = {  # model, (n, t, live_token_bound), a burst's scan around it
+    "decode": (mistral_module_and_shapes, (16, 1, 256), False),
+    "compacted": (mistral_module_and_shapes, (16, 64, 64), False),
+    "padded-chunk": (mistral_module_and_shapes, (4, 64, None), False),
+    "burst": (mistral_module_and_shapes, (16, 1, None), True),
+    "olmoe-16kv-decode": (olmoe_shapes, (32, 1, 256), False),
+    "olmoe-16kv-compacted": (olmoe_shapes, (32, 256, 256), False),
+    "deepseek-v2-latent-decode": (deepseek_v2_shapes, (8, 1, 512), False),
+    "deepseek-v2-latent-compacted": (deepseek_v2_shapes, (3, 512, 512), False),
+    "lfm2-packed-heads-decode": (lfm2_shapes, (32, 1, 512), False),
+    "lfm2-packed-heads-compacted": (lfm2_shapes, (32, 256, 256), False),
+    "lfm2-packed-heads-burst": (lfm2_shapes, (32, 1, None), True),
+    "qwen3-next-state-tree-decode": (qwen3_next_shapes, (8, 1, 2048), False),
+    "qwen3-next-state-tree-compacted": (qwen3_next_shapes, (8, 512, 512), False),
+    "qwen3-next-state-tree-burst": (qwen3_next_shapes, (8, 1, None), True),
+    "glm-5-two-leaves-decode": (glm_moe_dsa_shapes, (8, 1, 512), False),
+    "glm-5-two-leaves-compacted": (glm_moe_dsa_shapes, (4, 1024, 1024), False),
+    "glm-5-two-leaves-burst": (glm_moe_dsa_shapes, (8, 1, None), True),
+}
+
+
+# layers (every layer of a stack is one scan body: the count sets the pool's
+# size alone) and layer scans of each model's program
+LAYERS_AND_SCANS = {mistral_module_and_shapes: (3, 1), olmoe_shapes: (2, 1),
+                    deepseek_v2_shapes: (5, 2), lfm2_shapes: (10, 1), qwen3_next_shapes: (8, 1),
+                    glm_moe_dsa_shapes: (5, 2)}
+
+
+@pytest.mark.parametrize("form", list(IN_PLACE))
+def test_the_pool_is_carried_and_written_in_place(chip, form):
+    """ISSUE 28's guard, since ISSUE 32 with the Pallas writer in the scatter's
+    place.  ``forward_paged`` at Mistral's widths (three layers, the serving
+    cells' pool of 368 blocks: one that fits vector memory the compiler
+    prefetches there in slices, which no serving program sees) as a decode step
+    ``[n, 1]``, a compacted and a padded chunk, and inside a two-step scan as
+    the burst runs it; OLMoE's 16 KV heads and DeepSeek-V2's one latent leaf
+    ``[5, 1024, 1, 128, 640]`` (its dense layer and four expert layers: two
+    scans) as a decode step and a compacted chunk.  In the optimised program nothing
+    has a pool-shaped result but the writer's custom call, every leaf aliased in
+    and out: no ``copy``, ``dynamic-slice``, ``dynamic-update-slice`` or
+    scatter of a layer or of the stack; each scan body calls the paged kernel
+    once and the writer once; the program's temporaries are smaller than the
+    pool.  LFM2 (ISSUE 33: two scans, of which the period's body alone holds an
+    attention layer; heads of 64 packed two a 128-wide row, so no relayout) is
+    held to the same, and its second cache with it: the conv layers' state,
+    carried beside the pool, is written by scatters in place and never copied,
+    sliced or updated whole.  Qwen3-Next (ISSUE 43: heads of 256, a state that
+    is a TREE of two leaves, one of them float32 matrices) is held to the same
+    for every leaf, in a decode step (the one-token update), a compacted chunk
+    (the scan kernel, once a DeltaNet layer of the period) and a burst.  GLM-5
+    (ISSUE 45: two pool leaves of unlike widths, index keys beside the latent,
+    one of them scored and never attended) is held to the same for both leaves:
+    one writer call and one paged kernel a scan body, no copy of either."""
+    shapes, (n, t, bound), in_a_burst = IN_PLACE[form]
+    layers, scans = LAYERS_AND_SCANS[shapes]
+    module, cfg, params, kv = shapes(chip, layers=layers)
+    leaves = jax.tree_util.tree_leaves(kv)
+
+    def fwd(params, kv, tokens, n_tokens, start_pos, tables):
+        return module.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
+                                    block_size=128, live_token_bound=bound)
+
+    def burst(params, kv, tokens, n_tokens, start_pos, tables):
+        def body(carry, _):
+            kv, tok, start = carry
+            logits, kv = fwd(params, kv, tok, n_tokens, start, tables)
+            return (kv, jnp.argmax(logits, axis=-1).astype(jnp.int32), start + 1), tok
+        (kv, _, _), toks = jax.lax.scan(body, (kv, tokens, start_pos), None, length=2)
+        return toks, kv
+
+    state = kv.get("state") if isinstance(kv, dict) else None  # a row's slot: one more column
+    ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, 8 + (state is not None)))]
+    compiled = jax.jit(burst if in_a_burst else fwd,
+                       donate_argnums=(1, )).lower(params, kv, *ints).compile()
+    text = compiled.as_text()
+    calls = kernel_calls(text)
+    assert (calls["paged_attention"], calls["kv_write"]) == (scans, scans), calls
+    results = pool_shaped_results(text, leaves[0].shape)
+    assert [r[0] for r in results] == ["custom-call"] * scans, results  # the writer alone
+    for leaf in jax.tree_util.tree_leaves(state):
+        whole = pool_shaped_results(text, (1, ) + leaf.shape)  # the leaf whole, however folded
+        assert whole and {r[0] for r in whole} <= {"scatter", "fusion"}, whole
+    if shapes is qwen3_next_shapes:  # the scan kernel where a step has chunks, and only there
+        assert calls.get("gdn_scan", 0) == (3 if t > 1 else 0), calls
+    pool_bytes = sum(int(np.prod(leaf.shape)) * 2 for leaf in leaves)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes

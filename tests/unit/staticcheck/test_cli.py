@@ -171,7 +171,7 @@ def test_sarif_clean_tree_has_empty_results(tree, capsys):
 def _git(tree, *args):
     import subprocess
     subprocess.run(["git", *args], cwd=str(tree), check=True,
-                   capture_output=True,
+                   capture_output=True, timeout=60,
                    env={**os.environ, "GIT_AUTHOR_NAME": "t", "GIT_AUTHOR_EMAIL": "t@t",
                         "GIT_COMMITTER_NAME": "t", "GIT_COMMITTER_EMAIL": "t@t"})
 

@@ -30,7 +30,8 @@ def test_imports_initialise_no_backend():
         "from jax._src import xla_bridge\n"
         "assert not xla_bridge._backends, list(xla_bridge._backends)\n"
         "print('no backend')\n")
-    r = subprocess.run([sys.executable, "-c", code], cwd=REPO, capture_output=True, text=True)
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO, capture_output=True, text=True,
+                       timeout=120)
     assert r.returncode == 0 and "no backend" in r.stdout, r.stderr[-2000:]
 
 
@@ -258,7 +259,7 @@ def test_chip_smoke_refuses_on_cpu():
     """As the driver runs it, in a sandbox without an accelerator: non-zero,
     before any phase, and no result line."""
     r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")], cwd=REPO,
-                       capture_output=True, text=True,
+                       capture_output=True, text=True, timeout=120,
                        env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 2, (r.returncode, r.stderr[-2000:])
     assert '"ok"' not in r.stdout and "[serve]" not in r.stdout

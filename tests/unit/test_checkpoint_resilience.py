@@ -454,9 +454,10 @@ def test_sigterm_triggers_best_effort_save(tmp_path):
         engine.save_checkpoint(str(tmp_path))  # arms the handler
         train(engine, 1)
         os.kill(os.getpid(), signal.SIGTERM)
-        time.sleep(0.05)  # let the signal be delivered at a bytecode boundary
-        tag = get_latest_tag(str(tmp_path))
-        assert tag == f"preempt_step{engine.global_steps}"
+        want, deadline = f"preempt_step{engine.global_steps}", time.monotonic() + 30
+        while (tag := get_latest_tag(str(tmp_path))) != want and time.monotonic() < deadline:
+            time.sleep(0.01)  # the handler runs at a bytecode boundary of this thread, then saves
+        assert tag == want
         assert is_valid_tag(str(tmp_path), tag, verify_integrity=True)
         _, client = make_engine().load_checkpoint(str(tmp_path))
         assert client["preempted"] is True
@@ -661,7 +662,8 @@ def test_agent_consensus_skips_harness_corrupted_tag_end_to_end(tmp_path):
         max_restarts=2, poll_interval=0.1, env=env,
         checkpoint_dir=os.path.join(tmp, "ckpt"), per_rank_checkpoints=True,
         term_grace_secs=10.0)
-    assert agent.run() == 0
+    from tests.unit.test_elastic_agent import run_bounded
+    assert run_bounded(agent, timeout=120.0) == 0
     assert agent.restart_count == 1
     # rank1's dir held gs1 + TORN gs2 at the crash: consensus must land on gs1
     assert agent.resume_tags[1] == "global_step1"

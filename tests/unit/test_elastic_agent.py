@@ -19,6 +19,29 @@ ELASTIC = {"max_train_batch_size": 8, "micro_batch_sizes": [1, 2],
            "min_gpus": 1, "max_gpus": 8}
 
 
+def run_bounded(agent, timeout=90.0):
+    """``agent.run()`` held to ``timeout``: past it the agent is interrupted, so
+    that it reaps its workers, and the test fails.  (On a thread the agent
+    installs no signal handlers, as in ``test_interrupt_tears_down_worker_group``.)"""
+    result = {}
+
+    def target():
+        try:
+            result["rc"] = agent.run()
+        except BaseException as e:  # noqa: BLE001 - handed to the test's thread
+            result["error"] = e
+    runner = threading.Thread(target=target, daemon=True)
+    runner.start()
+    runner.join(timeout)
+    if runner.is_alive():
+        agent._interrupt_signum = signal.SIGTERM
+        runner.join(30)
+        pytest.fail(f"agent.run() was still running after {timeout} s")
+    if "error" in result:
+        raise result["error"]
+    return result["rc"]
+
+
 def test_valid_world_sizes_from_config():
     agent = DSElasticAgent(["true"], world_size=8, elastic_config=ELASTIC)
     assert agent.valid_world_sizes() == [1, 2, 4, 8]
@@ -29,7 +52,7 @@ def test_valid_world_sizes_from_config():
 def test_clean_run_exits_zero(tmp_path):
     agent = DSElasticAgent([sys.executable, "-c", "import os; assert 'RANK' in os.environ"],
                            world_size=2, poll_interval=0.05)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
     assert agent.restart_count == 0
 
 
@@ -50,7 +73,7 @@ def test_failure_rescales_and_recovers(tmp_path):
         "sys.exit(0)\n")
     agent = DSElasticAgent([sys.executable, "-c", script], world_size=4,
                            elastic_config=ELASTIC, max_restarts=2, poll_interval=0.05)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
     assert agent.restart_count == 1
 
 
@@ -59,7 +82,7 @@ def test_restart_budget_exhausted(tmp_path):
     agent = DSElasticAgent([sys.executable, "-c", "import sys; sys.exit(7)"],
                            world_size=2, elastic_config=ELASTIC,
                            max_restarts=1, poll_interval=0.05)
-    assert agent.run() == 1
+    assert run_bounded(agent) == 1
     assert agent.restart_count == 1
 
 
@@ -70,7 +93,7 @@ def test_initial_world_clamped_to_valid():
         [sys.executable, "-c",
          "import os, sys; sys.exit(0 if os.environ['WORLD_SIZE'] == '4' else 3)"],
         world_size=6, elastic_config=ELASTIC, poll_interval=0.05)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
 
 
 # ------------------------------------------------------- solver edge cases
@@ -86,7 +109,7 @@ def test_min_gpus_exceeding_max_gpus_yields_no_valid_world():
     agent = DSElasticAgent(["true"], world_size=8, elastic_config=cfg)
     assert agent.valid_world_sizes() == []
     # run() must refuse to launch rather than spawn an invalid world
-    assert agent.run() == 1
+    assert run_bounded(agent) == 1
     assert agent.restart_count == 0
 
 
@@ -113,7 +136,7 @@ def test_failure_at_min_world_respawns_same_size(tmp_path):
         "sys.exit(0 if os.environ['WORLD_SIZE'] == '1' else 5)\n")
     agent = DSElasticAgent([sys.executable, "-c", script], world_size=1,
                            elastic_config=ELASTIC, max_restarts=2, poll_interval=0.05)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
     assert agent.restart_count == 1  # respawned, same world
 
 
@@ -129,7 +152,7 @@ def test_agent_exports_collective_and_init_retry_env():
     agent = DSElasticAgent([sys.executable, "-c", script], world_size=2,
                            poll_interval=0.05, collective_timeout_s=2.5,
                            init_retries=5, init_retry_backoff_s=0.1)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
 
 
 def test_agent_scrubs_stale_fault_tolerance_env_by_default():
@@ -145,7 +168,7 @@ def test_agent_scrubs_stale_fault_tolerance_env_by_default():
         "assert 'DSTPU_INIT_RETRY_BACKOFF_S' not in os.environ\n")
     agent = DSElasticAgent([sys.executable, "-c", script], world_size=1,
                            poll_interval=0.05, env=stale)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
 
 
 def test_heartbeat_timeout_without_dir_refused_at_construction():
@@ -169,7 +192,7 @@ def test_stale_heartbeat_env_scrubbed_when_unsupervised():
         "assert 'DSTPU_HEARTBEAT_INTERVAL_S' not in os.environ\n")
     agent = DSElasticAgent([sys.executable, "-c", script], world_size=1,
                            poll_interval=0.05, env=stale)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
 
 
 def test_run_resets_stale_interrupt_flag():
@@ -179,7 +202,7 @@ def test_run_resets_stale_interrupt_flag():
     agent = DSElasticAgent([sys.executable, "-c", "pass"], world_size=1,
                            poll_interval=0.05)
     agent._interrupt_signum = signal.SIGTERM  # stale from an interrupted run
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
 
 
 # -------------------------------------------- non-restartable exit codes
@@ -190,7 +213,7 @@ def test_non_restartable_rc_returned_immediately():
     agent = DSElasticAgent([sys.executable, "-c", "import sys; sys.exit(2)"],
                            world_size=2, elastic_config=ELASTIC,
                            max_restarts=3, poll_interval=0.05)
-    assert agent.run() == 2
+    assert run_bounded(agent) == 2
     assert agent.restart_count == 0
     events = [e["event"] for e in agent.recorder.tail()]
     assert "worker_failed" in events and "rescale" not in events
@@ -201,7 +224,7 @@ def test_non_restartable_class_is_configurable():
     agent = DSElasticAgent([sys.executable, "-c", "import sys; sys.exit(2)"],
                            world_size=1, elastic_config=ELASTIC, max_restarts=1,
                            poll_interval=0.05, non_restartable_exit_codes=(77, ))
-    assert agent.run() == 1  # rc 2 is restartable now; budget exhausts
+    assert run_bounded(agent) == 1  # rc 2 is restartable now; budget exhausts
     assert agent.restart_count == 1
 
 
@@ -259,8 +282,10 @@ def test_sigterm_to_agent_process_reaps_workers(tmp_path):
     proc.send_signal(signal.SIGTERM)
     rc = proc.wait(timeout=20)
     assert rc == 128 + signal.SIGTERM
-    time.sleep(0.2)
-    for pid in pid_file.read_text().split():
+    pids, deadline = pid_file.read_text().split(), time.time() + 10
+    while any(os.path.exists(f"/proc/{pid}") for pid in pids) and time.time() < deadline:
+        time.sleep(0.05)  # reaped by init once the agent is gone
+    for pid in pids:
         assert not os.path.exists(f"/proc/{pid}"), f"worker {pid} orphaned"
 
 
@@ -297,7 +322,7 @@ def test_hang_detected_by_heartbeat_staleness(tmp_path):
                            poll_interval=0.05, term_grace_secs=1.0,
                            heartbeat_dir=str(tmp_path / "hb"),
                            heartbeat_timeout_s=1.0, startup_grace_s=30.0)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
     assert agent.restart_count == 1
     hangs = [e for e in agent.recorder.tail() if e["event"] == "hang_detected"]
     assert len(hangs) == 1
@@ -318,7 +343,7 @@ def test_never_stamping_rank_caught_after_startup_grace(tmp_path):
                            poll_interval=0.05, term_grace_secs=1.0,
                            heartbeat_dir=str(tmp_path / "hb"),
                            heartbeat_timeout_s=0.5, startup_grace_s=1.5)
-    agent.run()
+    run_bounded(agent)
     hangs = [e for e in agent.recorder.tail() if e["event"] == "hang_detected"]
     assert hangs and 0 in hangs[0]["ranks"]
 
@@ -407,7 +432,7 @@ def test_resume_tag_pinned_via_env(tmp_path, monkeypatch):
     agent = DSElasticAgent([sys.executable, "-c", script], world_size=2,
                            poll_interval=0.05, checkpoint_dir=str(tmp_path / "ck"))
     monkeypatch.setattr(agent, "select_resume_tag", lambda world: "global_step7")
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
     assert out.read_text().split() == ["global_step7"] * 2
 
 
@@ -420,7 +445,7 @@ def test_stale_resume_tag_never_leaks_from_parent_env(tmp_path):
     env = dict(os.environ, DSTPU_RESUME_TAG="stale_tag_from_previous_life")
     agent = DSElasticAgent([sys.executable, "-c", script], world_size=1,
                            poll_interval=0.05, env=env)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
     assert out.read_text().split() == ["<none>"]  # no checkpoint dir -> no pin
 
 
@@ -475,7 +500,7 @@ def test_straggler_then_dropped_heartbeat_with_real_workers(tmp_path):
         heartbeat_dir=str(tmp_path / "hb"), heartbeat_timeout_s=2.0,
         heartbeat_interval_s=0.1, startup_grace_s=180.0,
         straggler_lag_steps=2, term_grace_secs=5.0)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
     assert agent.restart_count == 1
     events = agent.recorder.tail()
     stragglers = [e for e in events if e["event"] == "straggler"]
@@ -540,7 +565,7 @@ def test_agent_serves_merged_fleet_metrics(tmp_path):
                            ops_dir=str(tmp_path / "ops"))
     try:
         assert agent.ops is not None and agent.ops.port > 0
-        assert agent.run() == 0
+        assert run_bounded(agent) == 0
         agent._refresh_ops(group=None)  # final sweep after the run
         body = scrape(agent.ops.url("/metrics"))
         fams = parse_exposition(body)
@@ -565,7 +590,7 @@ def test_agent_default_ops_tempdir_swept_on_clean_run(tmp_path):
     try:
         derived = agent._ops_dir
         assert agent._ops_own_dir and os.path.isdir(derived)
-        assert agent.run() == 0
+        assert run_bounded(agent) == 0
         assert not os.path.exists(derived), "tempdir exchange files leaked"
     finally:
         agent.close_ops()
@@ -578,5 +603,5 @@ def test_agent_without_ops_flags_scrubs_inherited_dir(tmp_path, monkeypatch):
               "os.environ.get('DSTPU_OPS_DIR', '<none>'))")
     agent = DSElasticAgent([sys.executable, "-c", script], world_size=1,
                            poll_interval=0.05)
-    assert agent.run() == 0
+    assert run_bounded(agent) == 0
     assert seen.read_text() == "<none>"

@@ -49,11 +49,11 @@ def test_two_process_zero3_collectives_and_checkpoint(tmp_path):
     outs = []
     for p in procs:
         try:
-            out, _ = p.communicate(timeout=420)
+            out, _ = p.communicate(timeout=120)
         except subprocess.TimeoutExpired:
             for q in procs:
                 q.kill()
-            pytest.fail("2-process lane hung (420s timeout)")
+            pytest.fail("2-process lane hung (120s timeout)")
         outs.append(out)
     for r, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"rank {r} failed:\n{out[-3000:]}"
