@@ -105,9 +105,14 @@ class ServeCounters:
     (ISSUE 27; the model module states ``moe_picks`` = k x layers and
     ``moe_rows(slots)``; a dense model has neither and both stay zero):
     ``moe_expert_rows``  rows the expert FFNs' grouped matmuls ran over, from
-    the launched programs' static shapes: a pass's token slots x k, rounded up
-    to whole row tiles, in every layer
-    ``moe_routed_rows``  of those, the rows a live token was routed to
+    the launched programs' static shapes, in every layer: a pass's token slots
+    x k, rounded up to whole row tiles, where the leaves hold every routed
+    expert; on a share (ISSUE 51) the window the held picks are compacted into
+    (``moe/serving.py expert_rows``: what uniform routing sends here, with
+    headroom), which a pass that holds more runs again (counted only where the
+    device tallies it: ``moe_overflow_windows``)
+    ``moe_routed_rows``  the picks live tokens made, whatever kind each is: all
+    of them rows where every expert is held, on a share the held ones alone
 
     A family whose state is a recurrence scanned over a step's tokens in chunks
     (ISSUE 43; the model module states ``state_scan``; zero for every other):
@@ -143,6 +148,10 @@ class ServeCounters:
     a grouped matmul
     ``moe_held_picks``  picks on experts held here: the rows of the grouped
     matmuls that multiply (the rest of ``moe_routed_rows`` is held elsewhere)
+    ``moe_overflow_windows``  trips the expert layers ran beyond their first: a
+    pass that held more picks than a window's rows (ISSUE 51); each ran the
+    layer's ``moe_expert_rows`` rows once more, so ``moe_held_picks <=
+    moe_expert_rows + rows a window x moe_overflow_windows``
     """
 
     FIELDS = ("host_syncs", "dispatches", "uploads", "upload_ints", "compiles",
@@ -158,7 +167,7 @@ class ServeCounters:
     SELECTED_FIELDS = ("dsa_causal_keys", "dsa_selected_keys", "dsa_scored_keys",
                        "dsa_attended_keys")
     # the same for a family that tallies its picks on the device (``tallied``)
-    TALLIED_FIELDS = ("moe_identity_picks", "moe_held_picks")
+    TALLIED_FIELDS = ("moe_identity_picks", "moe_held_picks", "moe_overflow_windows")
 
     def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None,
                  kernel_slots: Callable[[int], int] = lambda t: 1,

@@ -304,9 +304,12 @@ def paged_value_dim(config: DeepseekV2Config) -> int:
 
 def moe_expert_rows(config: DeepseekV2Config, slots: int) -> int:
     """Rows the expert layers' grouped matmuls of one pass over ``slots`` token
-    slots run over (picks on experts held elsewhere are among them, dead)."""
+    slots run over: on a share the window its held picks are compacted into
+    (``moe/serving.py expert_rows``), the first trip's."""
     from ..moe.serving import expert_rows
-    return expert_rows(slots, config.top_k) * (config.num_layers - config.first_k_dense)
+    held = config.num_local_experts or config.num_experts
+    return expert_rows(slots, config.top_k, held, config.num_experts) \
+        * (config.num_layers - config.first_k_dense)
 
 
 def forward_paged(config: DeepseekV2Config, params, tokens, n_tokens, start_pos, block_tables,

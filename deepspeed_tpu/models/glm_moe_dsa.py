@@ -190,9 +190,12 @@ def moe_picks_per_token(config: GlmMoeDsaConfig) -> int:
 
 
 def moe_expert_rows(config: GlmMoeDsaConfig, slots: int) -> int:
-    """Rows the expert layers' grouped matmuls of one pass over ``slots`` token slots run over."""
+    """Rows the expert layers' grouped matmuls of one pass over ``slots`` token slots run
+    over: on a share the window its held picks are compacted into, the first trip's."""
     from ..moe.serving import expert_rows
-    return expert_rows(slots, config.top_k) * (config.num_layers - config.first_k_dense)
+    held = config.num_local_experts or config.num_experts
+    return expert_rows(slots, config.top_k, held, config.num_experts) \
+        * (config.num_layers - config.first_k_dense)
 
 
 def paged_value_dim(config: GlmMoeDsaConfig) -> int:

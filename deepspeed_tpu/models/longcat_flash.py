@@ -33,9 +33,11 @@ layers is TWO sublayers and ONE expert layer (``N`` = RMSNorm, ``h`` the stream)
   its value columns with it, as the source's ``k_pass`` feeds both).
 - **Pick tallies.**  How many picks fall on identity experts and on held ones
   varies a token by construction and only the device knows: every pass adds
-  its layers' two counts to ``kv_cache[transformer.TALLY]`` (int32 ``[2]``,
-  carried with the pool), which the engine reads once a wave into
-  ``ServeCounters.moe_identity_picks`` / ``moe_held_picks``.
+  its layers' counts to ``kv_cache[transformer.TALLY]`` (int32 ``[3]``, carried
+  with the pool), which the engine reads once a wave into
+  ``ServeCounters.moe_identity_picks`` / ``moe_held_picks`` /
+  ``moe_overflow_windows`` (the trips an expert layer ran beyond its first
+  because a pass held more picks than the window: ``moe/serving.py``).
 
 Rotary is plain (``rope_theta`` 1e7, no scaling) over interleaved pairs.
 Embedding and head are untied.  Training and tensor parallelism are not
@@ -165,10 +167,11 @@ def init_paged_cache(config: LongcatFlashConfig, num_blocks: int, block_size: in
     token's scaled ``[c_kv | k_pe]`` once a SUBLAYER (row ``2 l`` is layer
     ``l``'s first attention, ``2 l + 1`` its second), blocks on axis 1 as every
     family's pool; and beside it the pick tallies (``transformer.TALLY``: int32
-    ``[identity, held]``, running sums that wrap around; no pool leaf)."""
+    ``[identity, held, trips of the held picks' window beyond the first]``,
+    running sums that wrap around; no pool leaf)."""
     return {"latent": jnp.zeros((2 * config.num_layers, num_blocks, 1, block_size,
                                  latent_width(config)), dtype),
-            TALLY: jnp.zeros((2, ), jnp.int32)}
+            TALLY: jnp.zeros((3, ), jnp.int32)}
 
 
 def moe_picks_per_token(config: LongcatFlashConfig) -> int:
@@ -179,9 +182,13 @@ def moe_picks_per_token(config: LongcatFlashConfig) -> int:
 
 def moe_expert_rows(config: LongcatFlashConfig, slots: int) -> int:
     """Rows the expert layers' grouped matmuls of one pass over ``slots`` token
-    slots run over (identity picks and picks held elsewhere among them, dead)."""
+    slots run over: the window the held picks are compacted into (identity
+    picks and picks held elsewhere never become rows), the first trip's; the
+    trips beyond it are tallied on the device (``moe_overflow_windows``)."""
     from ..moe.serving import expert_rows
-    return expert_rows(slots, config.moe_topk) * config.num_layers
+    held = config.num_local_experts or config.n_routed_experts
+    return expert_rows(slots, config.moe_topk, held,
+                       config.n_routed_experts + config.zero_expert_num) * config.num_layers
 
 
 def paged_value_dim(config: LongcatFlashConfig) -> int:
@@ -191,7 +198,7 @@ def paged_value_dim(config: LongcatFlashConfig) -> int:
 
 def pick_tallies(config: LongcatFlashConfig) -> tuple:
     """The ``ServeCounters`` fields that ``kv_cache[TALLY]``'s entries are, in order."""
-    return "moe_identity_picks", "moe_held_picks"
+    return "moe_identity_picks", "moe_held_picks", "moe_overflow_windows"
 
 
 def forward_paged(config: LongcatFlashConfig, params, tokens, n_tokens, start_pos, block_tables,
@@ -202,7 +209,7 @@ def forward_paged(config: LongcatFlashConfig, params, tokens, n_tokens, start_po
     contract): a layer is the period (sublayer 0, sublayer 1), each absorbed MLA
     over its own row of the latent pool and a dense SwiGLU; sublayer 0 computes
     the shortcut expert layer and hands it to sublayer 1, which adds it."""
-    from ..moe.serving import sparse_moe_ffn
+    from ..moe.serving import expert_rows, sparse_moe_ffn, window_trips
     if tp_axis is not None:
         raise NotImplementedError("longcat_flash: tensor-parallel serving is not implemented "
                                   "(the deployment it is cut for is expert-parallel)")
@@ -238,6 +245,11 @@ def forward_paged(config: LongcatFlashConfig, params, tokens, n_tokens, start_po
                 {"gate": lp["moe"]["gate"], "experts": experts}, u.reshape(-1, u.shape[-1]),
                 config.moe_topk, False, live.reshape(-1), layer=lp["moe"]["layer"],
                 scaling=config.routed_scaling_factor, identity_experts=config.zero_expert_num)
+            # the trips the layer ran beyond its first: more held picks than a window's rows
+            # (added here: sparse_moe_ffn's tally is the pair tests/chipbench compares whole)
+            window = expert_rows(live.size, config.moe_topk, experts["w_gate"].shape[1],
+                                 lp["moe"]["gate"]["wg"].shape[-1])
+            picks = jnp.append(picks, jnp.maximum(window_trips(picks[1], window) - 1, 0))
         return x + swiglu_mlp(lp["mlp"], u), (shortcut.reshape(u.shape), picks)
 
     def head(x):
@@ -248,7 +260,7 @@ def forward_paged(config: LongcatFlashConfig, params, tokens, n_tokens, start_po
         block_tables, kv_cache, block_size=block_size, live_token_bound=live_token_bound,
         last_rows=last_rows, embed=embed, qkv=qkv, finish=finish, head=head,
         softmax_scale=softmax_scale(config), value_dim=config.kv_lora_rank, hand_on=True)
-    cache[TALLY] = tally + jnp.sum(picks, axis=0)  # [layers, 2] of this pass; int32 wraps around
+    cache[TALLY] = tally + jnp.sum(picks, axis=0)  # [layers, 3] of this pass; int32 wraps around
     return logits, cache
 
 

@@ -242,8 +242,11 @@ def moe_picks_per_token(config: Qwen3NextConfig) -> int:
 
 
 def moe_expert_rows(config: Qwen3NextConfig, slots: int) -> int:
+    """Rows the expert layers' grouped matmuls of one pass over ``slots`` token slots run
+    over: on a share the window its held picks are compacted into, the first trip's."""
     from ..moe.serving import expert_rows
-    return expert_rows(slots, config.top_k) * config.num_layers
+    held = config.num_local_experts or config.num_experts
+    return expert_rows(slots, config.top_k, held, config.num_experts) * config.num_layers
 
 
 def l2norm(x):

@@ -3,21 +3,31 @@
 The reference's ragged ``moe_gather`` / ``moe_scatter`` around CUTLASS
 ``moe_gemm`` (inference/v2/kernels/ragged_ops, cutlass_ops/moe_gemm): every
 token reaches its k experts and each expert multiplies only the rows routed
-to it.  Shapes are static and follow from the slot count alone: ``S`` token
-slots give ``S x k`` routed rows (:func:`expert_rows`), sorted by expert and
-handed to one grouped matmul per projection.  A dead slot's picks are given
-the expert id ``E``, past every group, so they sort to the tail, belong to no
-group and cost no read of any expert's weights.
+to it.  Shapes are static and follow from the slot count and the leaves' shapes
+alone (:func:`expert_rows`).  Where the leaves hold every routed expert, ``S``
+token slots give ``S x k`` routed rows, sorted by expert and handed to one
+grouped matmul per projection; a dead slot's picks are given the expert id
+``E``, past every group, so they sort to the tail, belong to no group and cost
+no read of any expert's weights.
 
 A pick is one of three kinds (:func:`sparse_moe_ffn`).  *Held*: the expert's
 weights are here; the pick is a row of its group.  *Held elsewhere*: the router
 is wider than the experts the leaves hold (one chip's share of an
-expert-parallel layer); the pick gets the dead group id, reads no weight and
-adds nothing here.  *Identity* (LongCat-Flash's zero-computation experts): the
-router's last ``identity_experts`` outputs are experts that return their input;
-such a pick keeps its weight, adds ``w x`` beside the routed sum on the token's
-own chip, is never dispatched, and gets the dead group id too.  Two kinds read
-no weight; only one of them adds nothing.
+expert-parallel layer); the pick reads no weight and adds nothing here.
+*Identity* (LongCat-Flash's zero-computation experts): the router's last
+``identity_experts`` outputs are experts that return their input; such a pick
+keeps its weight, adds ``w x`` beside the routed sum on the token's own chip and
+is never dispatched.  Two kinds read no weight; only one of them adds nothing.
+
+**A share compacts its held picks first** (ISSUE 51).  On a share only the held
+picks become rows at all: one sort lists them by expert, and the row gather,
+the three grouped matmuls, the activation, the mask and the combine run over a
+static window of :func:`expert_rows` rows (what uniform
+routing sends here, with headroom), not over every pick.  Still no capacity and
+no drop: a pass that holds more picks than a window's rows runs the window
+again (a ``while_loop`` whose body is traced once).  The rows' outputs reach
+their tokens through :func:`combine_rows`, which builds nothing ``[slots,
+top_k, D]``.
 
 The grouped matmul is the Pallas ``gmm`` of ``jax.experimental.pallas.ops.tpu.
 megablox`` on TPU and ``jax.lax.ragged_dot``, the same mathematics in XLA,
@@ -27,28 +37,50 @@ matmuls of an expert FFN took 1.28 ms (gmm, tiles 128 x 2048 x 1024) against
 where reading the 64 experts' weights once is 0.98 ms (PERF.md, PR 27).
 """
 
+import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..compat import CompilerParams
 from ..ops import _pallas
 
 ROW_TILE = 128          # rows a gmm program step multiplies: the MXU's height
+HEADROOM = 1.25         # a share's window of held picks over what uniform routing sends it
 K_TILE, N_TILE = 2048, 1024  # an expert's whole 2048 x 1024 matrix in one step
+COMBINE_WIDTH = 2048    # columns a step of the combine kernel holds: 1.5 MB of blocks
 
 
-def expert_rows(slots: int, top_k: int) -> int:
-    """Rows the expert FFN's program computes for ``slots`` token slots:
-    ``slots x top_k``, rounded up to whole row tiles (to 16, a bf16 sublane
-    pair, under one tile).  Static, so the serving counters ask it too.  Every
-    pick is a row, whatever its kind: picks on experts held elsewhere and on
-    identity experts are among the dead rows behind the last group (gathered,
-    sorted and combined, multiplied by nothing) until a later PR compacts them:
-    of LongCat-Flash's 12 rows a token as one chip of 32, 4 are identity picks
-    and 7.75 are held elsewhere under uniform routing, 0.25 a held expert's."""
-    tile = ROW_TILE if slots * top_k > ROW_TILE else 16
-    return -(-slots * top_k // tile) * tile
+def expert_rows(slots: int, top_k: int, held: int = 1, routed: int = 1) -> int:
+    """Rows the expert FFN's program computes a trip for ``slots`` token slots.
+    Static, so the serving counters ask it too.
+
+    Every routed expert held (``held == routed``): every pick is a row, ``slots
+    x top_k``, rounded up to whole row tiles (to 16, a bf16 sublane pair, under
+    one tile).  A share (the leaves hold ``held`` experts of a router ``routed``
+    wide, identity outputs among them): the picks on held experts alone become
+    rows, and the window they are compacted into is what uniform routing sends
+    here times :data:`HEADROOM` in whole row tiles (a narrower one saves the
+    chip nothing: the grouped matmul takes 128 rows a step either way), never
+    more than every pick: of LongCat-Flash's 768 picks a decode step as one
+    chip of 32 (16 of 768), 128 rows; of 12,288 a chunk pass, 384; of
+    Qwen3-Next's 20,480 (128 of 512), 6,400.  A pass that holds more runs
+    another trip (:func:`sparse_moe_ffn`)."""
+    rows = slots * top_k
+    tile = ROW_TILE if rows > ROW_TILE else 16
+    every = -(-rows // tile) * tile
+    if held >= routed:
+        return every
+    return min(every, -(-math.ceil(rows * held * HEADROOM / routed) // ROW_TILE) * ROW_TILE)
+
+
+def window_trips(held_picks, rows: int):
+    """Trips a share's expert FFN makes over ``held_picks`` picks, ``rows`` a window."""
+    return -(-held_picks // rows)
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -62,6 +94,147 @@ def grouped_matmul(lhs, rhs, group_sizes):
     tiling = (min(m, ROW_TILE), min(k, K_TILE), min(n, N_TILE))
     # positionally: the custom-vjp wrapper takes its static arguments by place
     return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None, False, _pallas.INTERPRET)
+
+
+def combine_rows(ys, token, weight, slots: int):
+    """``out[s] = sum of weight[j] * ys[j] over the rows j with token[j] == s``,
+    in float32: ys ``[R, D]`` in token order (``token`` ``[R]`` rises; a dead
+    row's is ``slots`` and its weight 0), -> ``[slots, D]``.
+
+    Rising tokens make a tile of 128 tokens' rows one run, so the sum is a
+    grouped product over token tiles: the Pallas kernel ``moe_combine`` walks the
+    (token tile, row tile) pairs whose rows meet, at most ``token tiles + row
+    tiles - 1`` of them (a scalar-prefetched list), and adds ``W [128 tokens,
+    128 rows] x ys [128 rows, D]`` a pair, ``W`` holding row j's weight at its
+    token's line.  Nothing ``[slots, top_k, D]`` is built.  The weights stay
+    exact: against bfloat16 rows ``W`` is split into three bfloat16 terms whose
+    products with a row are exact in float32 (the sum's order alone differs from
+    a multiply-and-add a pick); float32 rows are multiplied at the highest
+    precision.  Off the TPU it is a segment sum."""
+    if not _pallas.use_pallas():
+        return jax.ops.segment_sum(weight[:, None] * ys.astype(jnp.float32), token,
+                                   num_segments=slots, indices_are_sorted=True)
+    return _combine_tiles(ys, token, weight, slots=slots, interpret=_pallas.INTERPRET)
+
+
+def _combine_kernel(tile_ref, rows_ref, first_ref, adds_ref, token_ref, weight_ref, ys_ref, out_ref):
+    del rows_ref  # the index maps' own
+    p = pl.program_id(1)
+
+    @pl.when(first_ref[p] == 1)
+    def _begin():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(adds_ref[p] == 1)
+    def _add():
+        tokens, rows = out_ref.shape[0], ys_ref.shape[0]
+        line = tile_ref[p] * tokens + jax.lax.broadcasted_iota(jnp.int32, (tokens, rows), 0)
+        w = jnp.where(token_ref[0] == line, weight_ref[0], 0.0)  # [tokens, rows], a weight a column
+        ys = ys_ref[...]
+        if ys.dtype == jnp.float32:
+            out_ref[...] += jnp.dot(w, ys, precision=jax.lax.Precision.HIGHEST,
+                                    preferred_element_type=jnp.float32)
+            return
+        added = 0.0
+        for _ in range(3):  # float32's 24 bits as three bfloat16 terms
+            term = w.astype(jnp.bfloat16)
+            added += jnp.dot(term, ys.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
+            w = w - term.astype(jnp.float32)
+        out_ref[...] += added
+
+
+# jitted for its trace cache alone (inlined where it is called)
+@functools.partial(jax.jit, static_argnames=("slots", "interpret"), inline=True)
+def _combine_tiles(ys, token, weight, *, slots, interpret):
+    rows, width = ys.shape
+    tt, rt = min(slots, ROW_TILE), min(rows, ROW_TILE)
+    n_t, n_r = -(-slots // tt), rows // rt  # rows are whole tiles (expert_rows)
+    wide = width if width % 128 else next(  # whole lanes, a divisor of the width
+        w for w in range(min(width, COMBINE_WIDTH), 0, -128) if width % w == 0)
+    # a token tile's run of rows [start, end) and the row tiles it meets (one, not added,
+    # where the run is empty: its lines are begun and stay zero)
+    bounds = jnp.sum(token[None, :] < (jnp.arange(n_t + 1, dtype=jnp.int32) * tt)[:, None], axis=1,
+                     dtype=jnp.int32)
+    start, end = bounds[:-1], bounds[1:]
+    lo = jnp.minimum(start // rt, n_r - 1)
+    met = jnp.where(end > start, (end - 1) // rt - lo + 1, 1)
+    ends = jnp.cumsum(met)
+    # pairs past the last one are the last one again: nothing fetched, begun or added for them
+    p = jnp.arange(n_t + n_r - 1, dtype=jnp.int32)
+    q = jnp.minimum(p, ends[-1] - 1)
+    tile = jnp.sum(q[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    before = (ends - met)[tile]
+    first = ((q == before) & (p == q)).astype(jnp.int32)
+    adds = ((end > start)[tile] & (p == q)).astype(jnp.int32)
+    out = pl.pallas_call(
+        _combine_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(width // wide, n_t + n_r - 1),
+            in_specs=[pl.BlockSpec((1, 1, rt), lambda d, p, tile, row, first, adds: (row[p], 0, 0)),
+                      pl.BlockSpec((1, 1, rt), lambda d, p, tile, row, first, adds: (row[p], 0, 0)),
+                      pl.BlockSpec((rt, wide), lambda d, p, tile, row, first, adds: (row[p], d))],
+            out_specs=pl.BlockSpec((tt, wide), lambda d, p, tile, row, first, adds: (tile[p], d))),
+        out_shape=jax.ShapeDtypeStruct((n_t * tt, width), jnp.float32),
+        compiler_params=CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name="moe_combine",
+    )(tile, lo[tile] + q - before, first, adds, token.reshape(n_r, 1, rt),
+      weight.reshape(n_r, 1, rt), ys)
+    return out[:slots]
+
+
+def _held_picks_ffn(stacked, x, weights, experts, held, layer, num_experts: int, rows: int):
+    """The held picks' part of a share's expert FFN, ``rows`` of them a trip.
+
+    ``experts`` / ``held`` ``[S, k]``: each pick's expert and whether it is a
+    live slot's pick on an expert held here; the leaves ``stacked`` are ``[L x
+    E, ...]``, E = ``num_experts``, and ``layer`` says which E groups to use.
+    ONE stable sort of the picks by expert, every other pick behind the last
+    expert, lays the held ones first with their tokens and weights beside them
+    (a sort of 20,480 keys takes the chip 17-30 us where a scatter of them
+    takes 95 and each gather of 6,400 scalars 43: PERF.md, PR 51); trip ``w``
+    takes places ``w x rows ..`` of that list: rows already in their experts'
+    order, which it multiplies, sorts by token and adds to their tokens
+    (:func:`combine_rows`).  Routing is data: a pass may hold any number, so the
+    trips are a ``while_loop`` (its body traced once), ``ceil(held / rows)`` of
+    them: none where nothing is held, one in nearly every call, more where a
+    prompt or a popular expert sends more picks here; a later trip's rows name
+    only the experts the earlier ones did not finish, so no expert's weights
+    are read twice but the one a window's edge cuts.  -> out [S, D] float32."""
+    (slots, top_k), groups = experts.shape, stacked["w_gate"].shape[0]
+    picks = slots * top_k
+    held = held.reshape(picks)
+    listed = jax.lax.sort((jnp.where(held, experts.reshape(picks), num_experts),
+                           jnp.arange(picks, dtype=jnp.int32) // top_k, weights.reshape(picks)),
+                          num_keys=1)
+    # whole windows: the last one's tail is no expert's
+    listed = [jnp.pad(a, (0, -picks % rows), constant_values=fill)
+              for a, fill in zip(listed, (num_experts, 0, 0.0))]
+    trips = window_trips(jnp.sum(held, dtype=jnp.int32), rows)
+    row = jnp.arange(rows, dtype=jnp.int32)
+
+    def trip(carry):
+        w, out = carry
+        expert, token, weight = (jax.lax.dynamic_slice(a, (w * rows,), (rows,)) for a in listed)
+        alive = expert < num_experts  # the rows past the held picks are no expert's
+        sizes = jnp.sum(expert[:, None] == jnp.arange(num_experts)[None, :], axis=0,
+                        dtype=jnp.int32)
+        group_sizes = jax.lax.dynamic_update_slice(jnp.zeros((groups,), jnp.int32), sizes,
+                                                   (layer * num_experts,))
+        xs = x[token]
+        gate = grouped_matmul(xs, stacked["w_gate"], group_sizes)
+        up = grouped_matmul(xs, stacked["w_up"], group_sizes)
+        ys = grouped_matmul(jax.nn.silu(gate) * up, stacked["w_down"], group_sizes)
+        # rows past the last group are whatever the kernel left: zeroed; then all in token
+        # order (the dead ones last), each with its weight, for the combine
+        token, order, weight = jax.lax.sort(
+            (jnp.where(alive, token, slots), row, jnp.where(alive, weight, 0.0)), num_keys=1)
+        ys = jnp.where(alive[:, None], ys, 0)[order]
+        return w + 1, out + combine_rows(ys, token, weight, slots)
+
+    _, out = jax.lax.while_loop(lambda carry: carry[0] < trips, trip,
+                                (jnp.zeros((), jnp.int32),
+                                 jnp.zeros((slots, x.shape[-1]), jnp.float32)))
+    return out
 
 
 def route(wg, x, top_k: int, renormalise: bool, n_group: int = 1, topk_group: int = 1,
@@ -135,8 +308,12 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     over expert leaves ``[.., H, ...]`` with H < E says that this chip holds
     experts 0..H-1 of a layer that other chips share (an expert-parallel
     deployment: DeepSeek-V2's 160 as 40 a chip).  The router runs over all E;
-    a pick on an expert that is not here gets the dead group id, as a dead
-    slot's picks do, so it sorts to the tail, reads no weight and adds zero.
+    a pick on an expert that is not here never becomes a row: the held picks
+    are compacted into a window of ``expert_rows(S, k, H, E)`` rows (sorted by
+    expert, whatever their number: a pass that holds more runs the window
+    again over the rest, nothing is dropped) and the gather, the grouped
+    matmuls and the combine run over that window.  Where H = E none of that is traced and the
+    program is the one it was.
     The result is this chip's experts' part of the layer's sum (what the
     exchange of the deployment would gather from the other chips is theirs to
     add: nothing here stands in for them).
@@ -147,8 +324,7 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     selection bias and the factor run over all of them alike; a pick at or
     past ``real`` keeps its weight and adds ``w x`` in float32 beside the
     routed sum (scope ``moe_identity``), here, for this chip's own tokens: it
-    is never dispatched, so it gets the dead group id and no row of a grouped
-    matmul is its own.  A pick under ``real`` that is not held stays a pick
+    is never dispatched and no row of a grouped matmul is its own.  A pick under ``real`` that is not held stays a pick
     held elsewhere and adds nothing.  The three kinds of pick in one layer:
     held, held elsewhere, identity.  The call then returns ``(out, tally)``:
     ``tally`` int32 ``[2]``, the live slots' picks on identity experts and on
@@ -171,7 +347,8 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     groups = num_layers * num_experts
     stacked = {name: w.reshape((groups,) + w.shape[2:]).astype(x.dtype) for name, w in ex.items()}
     slots = x.shape[0]
-    picks, rows = slots * top_k, expert_rows(slots, top_k)
+    picks, routed = slots * top_k, moe_params["gate"]["wg"].shape[-1]
+    rows = expert_rows(slots, top_k, num_experts, routed)
     # one group and no factor: the four arguments route always took, which is
     # what tests/chipbench/test_reference_olmoe.py's stand-in for it accepts
     # (a benchmark test: not this module's to edit); the program is the same
@@ -180,23 +357,27 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
         "scoring": scoring, "bias": moe_params["gate"].get("bias"), "norm_eps": norm_eps}
     weights, experts = route(moe_params["gate"]["wg"], x, top_k, renormalise, *grouped, **scored)
     with jax.named_scope("moe_expert_ffn"):
-        group = layer * num_experts + experts
-        if num_experts < moe_params["gate"]["wg"].shape[-1]:  # a pick on an expert held elsewhere
-            group = jnp.where(experts < num_experts, group, groups)
-        if live is not None:
-            group = jnp.where(live[:, None], group, groups)
-        # row s * k + p is token s's p-th pick; the rows that fill the last tile are dead
-        flat = jnp.full((rows,), groups, jnp.int32).at[:picks].set(group.reshape(picks))
-        order = jnp.argsort(flat)  # stable: rows sorted by expert, dead rows last
-        group_sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1, mode="drop")
-        xs = x[jnp.minimum(order // top_k, slots - 1)]
-        gate = grouped_matmul(xs, stacked["w_gate"], group_sizes)
-        up = grouped_matmul(xs, stacked["w_up"], group_sizes)
-        ys = grouped_matmul(jax.nn.silu(gate) * up, stacked["w_down"], group_sizes)
-        # rows past the last group are no expert's: whatever sits there is dropped
-        ys = jnp.where((flat[order] < groups)[:, None], ys, 0)
-        picked = ys[jnp.argsort(order)[:picks]].reshape(slots, top_k, -1)  # back in token order
-        out = jnp.einsum("sk,skd->sd", weights, picked.astype(jnp.float32))
+        if num_experts < routed:  # a share: the held picks alone become rows, a window a trip
+            held = experts < num_experts
+            if live is not None:
+                held = held & live[:, None]
+            out = _held_picks_ffn(stacked, x, weights, experts, held, layer, num_experts, rows)
+        else:
+            group = layer * num_experts + experts
+            if live is not None:
+                group = jnp.where(live[:, None], group, groups)
+            # row s * k + p is token s's p-th pick; the rows that fill the last tile are dead
+            flat = jnp.full((rows,), groups, jnp.int32).at[:picks].set(group.reshape(picks))
+            order = jnp.argsort(flat)  # stable: rows sorted by expert, dead rows last
+            group_sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1, mode="drop")
+            xs = x[jnp.minimum(order // top_k, slots - 1)]
+            gate = grouped_matmul(xs, stacked["w_gate"], group_sizes)
+            up = grouped_matmul(xs, stacked["w_up"], group_sizes)
+            ys = grouped_matmul(jax.nn.silu(gate) * up, stacked["w_down"], group_sizes)
+            # rows past the last group are no expert's: whatever sits there is dropped
+            ys = jnp.where((flat[order] < groups)[:, None], ys, 0)
+            picked = ys[jnp.argsort(order)[:picks]].reshape(slots, top_k, -1)  # back in token order
+            out = jnp.einsum("sk,skd->sd", weights, picked.astype(jnp.float32))
     if "shared" in moe_params:
         shared = {name: w.astype(x.dtype) for name, w in moe_params["shared"].items()}
         with jax.named_scope("moe_shared_expert"):

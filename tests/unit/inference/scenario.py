@@ -1,7 +1,19 @@
 """The mixed-arrival serving scenario that the fast-path tests drive an engine
-through: a test driver over ``put`` / ``step`` / ``decode_burst`` / ``flush``."""
+through: a test driver over ``put`` / ``step`` / ``decode_burst`` / ``flush``;
+and a recorder of the forward programs an engine launches."""
 
 import time
+
+
+def launches_of(eng, monkeypatch):
+    """``[(token slots, passes)]`` of every forward program ``eng`` launches from here on."""
+    launched, count = [], eng.counters.count_slots
+
+    def counted(n, t, b, live_tokens, live_blocks, passes=1, flat=None, **kw):
+        launched.append((n * t if flat is None else flat, passes))
+        return count(n, t, b, live_tokens, live_blocks, passes=passes, flat=flat, **kw)
+    monkeypatch.setattr(eng.counters, "count_slots", counted)
+    return launched
 
 
 def run_scenario(eng, prompts, arrivals, max_new: int):

@@ -23,6 +23,7 @@ from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.models import longcat_flash, transformer
 from deepspeed_tpu.models.transformer import TALLY
 from deepspeed_tpu.moe import serving
+from tests.unit.inference.scenario import launches_of
 
 HELD, ZERO, TOPK = 2, 32, 6  # held of 32 x 2 = 64 real experts; identity experts; picks a token
 SIZES = {"hidden_size": 64, "ffn_hidden_size": 128, "expert_ffn_hidden_size": 32, "num_layers": 2,
@@ -130,15 +131,19 @@ def test_the_layout_is_one_latent_leaf_of_two_rows_a_layer_and_the_tallies(param
     assert own["layers"]["moe"]["gate"]["wg"].shape[-1] == 32 * HELD + ZERO  # the router's width
     cache = fresh_cache()
     assert cache["latent"].shape == (4, NB, 1, BS, 128)  # 32 + 8 values in whole lanes, 2 rows a layer
-    assert cache[TALLY].shape == (2, ) and cache[TALLY].dtype == jnp.int32
+    assert cache[TALLY].shape == (3, ) and cache[TALLY].dtype == jnp.int32
     full = longcat_flash.LongcatFlashConfig()
     whole = jax.eval_shape(lambda: longcat_flash.init_paged_cache(full, 8, 128))
     assert whole["latent"].shape == (56, 8, 1, 128, 640)
     assert longcat_flash.moe_picks_per_token(full) == 12 * 28
-    assert longcat_flash.moe_expert_rows(full, 64) == 768 * 28
+    assert longcat_flash.moe_expert_rows(full, 64) == 640 * 28  # every real expert held: 512 of 768 outputs
+    share = longcat_flash.LongcatFlashConfig(num_local_experts=16)  # one chip of 32: 16 of 768
+    assert longcat_flash.moe_expert_rows(share, 64) == 128 * 28
+    assert longcat_flash.moe_expert_rows(share, 1024) == 384 * 28
     assert longcat_flash.paged_value_dim(full) == 512
     assert longcat_flash.lora_scales(full) == (2.0, 12 ** 0.5)
-    assert longcat_flash.pick_tallies(full) == ("moe_identity_picks", "moe_held_picks")
+    assert longcat_flash.pick_tallies(full) == ("moe_identity_picks", "moe_held_picks",
+                                                "moe_overflow_windows")
     tiny = longcat_flash.LongcatFlashConfig.tiny(local_experts=2)
     assert jax.eval_shape(lambda: longcat_flash.init_params(tiny, jax.random.PRNGKey(0)))[
         "layers"]["moe"]["gate"]["wg"].shape == (2, 128, 16 + 8)
@@ -157,8 +162,26 @@ def test_prefill_in_chunks_then_decode_steps_equal_the_reference(params, sound, 
         close(row, w)
     with jax.default_matmul_precision("highest"):
         _, counts = ref.hidden_states(SIZES, params, jnp.asarray(ids))
-    assert np.abs(np.asarray(cache[TALLY]) - np.asarray(counts)).max() <= 2  # a near-tie at the cut
+    assert np.abs(np.asarray(cache[TALLY][:2]) - np.asarray(counts)).max() <= 2  # a near-tie at the cut
     assert counts[0] > counts[1] > 0 and counts.sum() < len(ids) * TOPK * 2  # all three kinds occur
+
+
+def test_a_pass_that_holds_more_picks_than_its_window_runs_it_again_and_tallies_the_trips(params, sound):
+    """A selection bias sends two of every token's six picks to the two held
+    experts: a chunk of 150 tokens holds 300 picks where a window has 128 rows,
+    so each layer runs its window three times; no pick is dropped (the logits are
+    the reference's) and the third tally counts the two trips beyond the first."""
+    forward, ids = sound[0], ids_of(5, 151)
+    bias = params["layers"]["moe"]["gate"]["bias"].at[:, :HELD].add(10.0)
+    moe = params["layers"]["moe"]
+    sent_here = {**params, "layers": {**params["layers"],
+                                      "moe": {**moe, "gate": {**moe["gate"], "bias": bias}}}}
+    got, cache = chunks_then_decode(forward, sent_here, ids, (150, ), decode=1)
+    for (at, row), w in zip(got, want(sent_here, ids + [0] * 7, [at for at, _ in got])):
+        close(row, w)
+    assert serving.expert_rows(256, TOPK, HELD, 32 * HELD + ZERO) == 128  # the chunk's bucket
+    identity, held, beyond = np.asarray(cache[TALLY]).tolist()
+    assert held == 151 * HELD * 2 and beyond == (serving.window_trips(150 * HELD, 128) - 1) * 2 == 4
 
 
 def test_a_compacted_mixed_step_gives_each_sequence_what_it_gets_alone(params, sound):
@@ -337,13 +360,14 @@ def tallied_within(counters, least, most, slack=2):
 
 
 @pytest.mark.parametrize("budget", [32, 48])
-def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params, engine, budget):
+def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(params, engine, budget,
+                                                                              monkeypatch):
     """Two ``token_budget``s cut a prompt at different places; the tokens are the
     reference's either way, through compacted passes and fused bursts, and the
     tallies read once a wave are the reference's counts."""
     prompts = [ids_of(10 + i, n) for i, n in enumerate((5, 90, 140, 9))]
     eng = engine(budget=budget)
-    before = eng.counters.snapshot()
+    before, launched = eng.counters.snapshot(), launches_of(eng, monkeypatch)
     got = eng.generate(prompts, max_new_tokens=5)
     c = eng.counters.delta_since(before)
     assert c["burst_tokens"] > 0 and c["compact_passes"] > 0
@@ -353,6 +377,14 @@ def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(pa
     assert tallied_within(c, *reference_counts(params, [list(g) for g in got]))
     assert 0 < c["moe_held_picks"] < c["moe_identity_picks"]
     assert c["moe_identity_picks"] < c["moe_routed_rows"] - c["moe_identity_picks"] - c["moe_held_picks"]
+    # the rows are the window of held picks (2 of 96 outputs, with headroom) a layer a pass; a
+    # pass that holds more runs its window again, tallied: nothing is dropped for want of rows
+    window = longcat_flash.moe_expert_rows(CFG, budget) // 2
+    assert window == serving.expert_rows(budget, TOPK, HELD, 32 * HELD + ZERO) == 128
+    assert c["moe_held_picks"] <= c["moe_expert_rows"] + window * c["moe_overflow_windows"]
+    assert c["moe_expert_rows"] < c["moe_routed_rows"] and c["moe_overflow_windows"] >= 0
+    assert c["moe_expert_rows"] == sum(
+        longcat_flash.moe_expert_rows(CFG, slots) * passes for slots, passes in launched)
     assert set(c) == set(eng.counters.FIELDS + eng.counters.TALLIED_FIELDS)
     # one fetch a wave beyond the steps' and the bursts' own
     other = build_engine(params, budget=budget)
@@ -370,10 +402,10 @@ def test_the_tallies_are_window_deltas_and_wrap_around(params, engine):
     delta = eng.counters.delta_since(first)
     assert tallied_within(delta, *reference_counts(params, [greedy(params, ids_of(21, 40), 3)]))
     from deepspeed_tpu.inference.v2.fastpath import ServeCounters
-    c = ServeCounters(tallied=("moe_identity_picks", "moe_held_picks"))
-    c.absorb_tallies(np.asarray([2 ** 31 - 5, 7], np.int32))
-    c.absorb_tallies(np.asarray([-2 ** 31 + 10, 9], np.int32))  # the device's int32 wrapped
-    assert (c.moe_identity_picks, c.moe_held_picks) == (2 ** 31 - 5 + 15, 9)
+    c = ServeCounters(tallied=ServeCounters.TALLIED_FIELDS)
+    c.absorb_tallies(np.asarray([2 ** 31 - 5, 7, 0], np.int32))
+    c.absorb_tallies(np.asarray([-2 ** 31 + 10, 9, 1], np.int32))  # the device's int32 wrapped
+    assert (c.moe_identity_picks, c.moe_held_picks, c.moe_overflow_windows) == (2 ** 31 - 5 + 15, 9, 1)
     assert "moe_identity_picks" not in ServeCounters().snapshot()
 
 

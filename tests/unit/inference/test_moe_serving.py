@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.moe.serving import expert_rows, route, sparse_moe_ffn
+from deepspeed_tpu.moe.serving import expert_rows, route, sparse_moe_ffn, window_trips
 
 D, F = 32, 16
 
@@ -34,6 +34,82 @@ def drawn_moe(num_experts, seed=0):
                        "w_up": jax.random.normal(ks[2], (num_experts, D, F)) * D ** -0.5,
                        "w_down": jax.random.normal(ks[3], (num_experts, F, D)) * F ** -0.5}}
     return moe, jax.random.normal(ks[4], (24, D))
+
+
+def held_oracle(moe, x, top_k, held, live, n_group=1, topk_group=1, bias=None, identity=0):
+    """A share's part of the layer, densely: the router over ALL its outputs
+    (``route``, which has oracles of its own below), every held expert over
+    every token, combined at each live token's picks on them; ``identity``
+    outputs at the router's end add ``w x``."""
+    weights, picks = route(moe["gate"]["wg"], x, top_k, False, n_group, topk_group, bias=bias)
+    wide = moe["gate"]["wg"].shape[-1]
+    combine = jnp.zeros((x.shape[0], wide)).at[jnp.arange(x.shape[0])[:, None], picks].set(weights)
+    ex = {name: w[:held] for name, w in moe["experts"].items()}
+    every = jnp.einsum("etf,efd->etd", jax.nn.silu(jnp.einsum("td,edf->etf", x, ex["w_gate"]))
+                       * jnp.einsum("td,edf->etf", x, ex["w_up"]), ex["w_down"])
+    out = jnp.einsum("te,etd->td", combine[:, :held], every)
+    if identity:
+        out = out + combine[:, wide - identity:].sum(-1, keepdims=True) * x
+    return out * live[:, None], picks
+
+
+SHARES = {  # held of routed, top_k, slots, n_group, topk_group, identity outputs, every pick sent here
+    "a-quarter-of-grouped-experts": (8, 32, 6, 200, 8, 3, 0, False),
+    "a-sixteenth": (4, 64, 8, 300, 1, 1, 0, False),
+    "a-thirty-second-and-identity-experts": (2, 64 + 16, 6, 200, 1, 1, 16, False),
+    "a-last-tile-part-full": (8, 32, 4, 37, 1, 1, 0, False),
+    "every-pick-held-here": (4, 16, 4, 100, 1, 1, 0, True),
+}
+
+
+@pytest.mark.parametrize("interpreted", [False, True], ids=["xla", "interpreted-kernels"])
+@pytest.mark.parametrize("share", SHARES)
+def test_a_shares_compacted_dispatch_equals_the_dense_oracle(share, interpreted, monkeypatch):
+    """One chip's share of an expert-parallel layer: the held picks alone are
+    compacted into a window of ``expert_rows(.., held, routed)`` rows a trip,
+    sorted, multiplied and added to their tokens; dead slots make holes; no
+    pick is dropped when a selection bias sends EVERY pick here (the window
+    runs again: the third tally counts the trips beyond the first)."""
+    from deepspeed_tpu.ops import _pallas
+    held, routed, top_k, slots, n_group, topk_group, identity, all_here = SHARES[share]
+    moe, _ = drawn_moe(routed, seed=7)
+    x = jax.random.normal(jax.random.PRNGKey(11), (slots, D))
+    live = jnp.asarray(np.random.default_rng(2).random(slots) < 0.8)
+    bias = jnp.where(jnp.arange(routed) < held, 10.0, 0.0) if all_here else None
+    gate = {"wg": moe["gate"]["wg"], **({"bias": bias} if all_here else {})}
+    experts = {name: w[:held] for name, w in moe["experts"].items()}
+    monkeypatch.setattr(_pallas, "INTERPRET", interpreted)
+    with jax.default_matmul_precision("highest"):
+        got, tally = jax.jit(lambda m, a: sparse_moe_ffn(
+            m, a, top_k, False, live, n_group=n_group, topk_group=topk_group,
+            identity_experts=identity))({"gate": gate, "experts": experts}, x)
+        monkeypatch.setattr(_pallas, "INTERPRET", False)
+        want, picks = held_oracle(moe, x, top_k, held, live, n_group, topk_group, bias, identity)
+    assert np.abs(np.asarray(got - want)).max() < 1e-5 * max(1.0, np.abs(np.asarray(want)).max())
+    assert (np.asarray(got)[~np.asarray(live)] == 0).all()
+    here = int(((np.asarray(picks) < held) & np.asarray(live)[:, None]).sum())
+    assert np.asarray(tally).tolist()[1] == here > 0
+    # more held picks than a window's rows only where every pick is sent here: then the
+    # window ran again (``window_trips``), or the sum above would lack the picks past it
+    window = expert_rows(slots, top_k, held, routed)
+    assert (window_trips(here, window) > 1) == all_here
+    if all_here:
+        assert here == int(np.asarray(live).sum()) * top_k
+
+
+def test_every_routed_expert_held_traces_no_loop_and_every_pick_a_row():
+    """A layer whose leaves hold every routed expert is the program it was: no
+    ``while``, no compaction, grouped matmuls over ``slots x top_k`` rows."""
+    moe, _ = drawn_moe(8)
+    x = jax.random.normal(jax.random.PRNGKey(1), (200, D))
+    text = jax.jit(lambda m, a: sparse_moe_ffn(m, a, 2, True)).lower(moe, x).as_text()
+    assert "stablehlo.while" not in text and f"tensor<{expert_rows(200, 2)}x{D}xf32>" in text
+    held = {name: w[:2] for name, w in moe["experts"].items()}
+    share = jax.jit(lambda m, a: sparse_moe_ffn(m, a, 2, True)).lower(
+        {"gate": moe["gate"], "experts": held}, x).as_text()
+    assert expert_rows(200, 2, 2, 8) == 128 < expert_rows(200, 2) == 512
+    assert "stablehlo.while" in share and f"tensor<128x{D}xf32>" in share
+    assert f"tensor<512x{D}xf32>" not in share
 
 
 @pytest.mark.parametrize("routing", ["dead_slots", "one_expert_takes_every_token",
@@ -68,6 +144,20 @@ def test_sparse_dispatch_equals_the_dense_oracle(num_experts, top_k, routing):
                                               (1, 8, 16), (4, 2, 16), (100, 2, 256)])
 def test_expert_rows_are_whole_row_tiles(slots, top_k, rows):
     assert expert_rows(slots, top_k) == rows
+    assert expert_rows(slots, top_k, 64, 64) == rows  # every routed expert held: every pick a row
+
+
+@pytest.mark.parametrize("slots,top_k,held,routed,rows", [
+    (64, 12, 16, 768, 128), (1024, 12, 16, 768, 384), (2048, 10, 128, 512, 6400),
+    (512, 6, 40, 160, 1024), (1024, 8, 16, 256, 640), (1, 8, 16, 256, 16), (8, 10, 128, 512, 80),
+    (24, 4, 15, 16, 96)],
+    ids=["scmoe-decode", "scmoe-chunk", "gdn-chunk", "mla-chunk", "dsa-chunk", "one-slot",
+         "gdn-decode-every-pick-is-fewer", "never-more-than-every-pick"])
+def test_a_shares_rows_are_its_part_of_the_picks_with_headroom(slots, top_k, held, routed, rows):
+    """The window a share's held picks are compacted into: picks x held / routed
+    x HEADROOM in whole row tiles, never more than every pick, at the share
+    cells' shapes."""
+    assert expert_rows(slots, top_k, held, routed) == rows <= expert_rows(slots, top_k)
 
 
 def test_interpreted_gmm_kernel_equals_the_xla_path(monkeypatch):

@@ -12,6 +12,7 @@ import pytest
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.models import llama, mixtral, olmoe
 from deepspeed_tpu.moe.serving import expert_rows
+from tests.unit.inference.scenario import launches_of
 
 
 # ------------------------------------------------------------------ the engine
@@ -132,7 +133,7 @@ def test_olmoe_state_dict_loads_into_the_layout_the_reference_draws():
         olmoe.config_from_hf(hf_model.config)
 
 
-def test_deepseek_v2_through_the_engine_counts_every_pick_and_compacts():
+def test_deepseek_v2_through_the_engine_counts_every_pick_and_compacts(monkeypatch):
     from deepspeed_tpu.models import deepseek_v2
     cfg = deepseek_v2.DeepseekV2Config.tiny(local_experts=4)
     params = deepseek_v2.init_params(cfg, jax.random.PRNGKey(0))
@@ -145,13 +146,20 @@ def test_deepseek_v2_through_the_engine_counts_every_pick_and_compacts():
                        ("padded", {"dtype": "float32", "serving_fastpath": {"enabled": False}})):
         eng = InferenceEngineV2(deepseek_v2, cfg, params, config=conf, block_size=8,
                                 num_blocks=40, max_blocks_per_seq=8, token_budget=16)
+        launched = launches_of(eng, monkeypatch)
         outs[name] = [r.tokens for r in eng.generate(prompts, max_new_tokens=5, strict=False)]
         if name == "fast":
             c = eng.counters.snapshot()
             assert c["compact_passes"] > 0
             # all picks, held or not: live tokens x top-4 x the two expert layers
             assert c["moe_routed_rows"] == c["live_tokens"] * 4 * 2
-            assert c["moe_expert_rows"] >= c["moe_routed_rows"]
+            # the rows are the share's window of held picks (4 of 16 experts, with headroom,
+            # in whole row tiles) in the two expert layers, a pass: a quarter of a large
+            # pass's picks, every pick of one as small as this engine's
+            assert deepseek_v2.moe_expert_rows(cfg, 512) == expert_rows(512, 4, 4, 16) * 2 == 640 * 2
+            assert deepseek_v2.moe_expert_rows(cfg, 16) == expert_rows(16, 4, 4, 16) * 2 == 64 * 2
+            assert c["moe_expert_rows"] == sum(
+                deepseek_v2.moe_expert_rows(cfg, slots) * passes for slots, passes in launched) > 0
             assert [leaf.shape for leaf in jax.tree_util.tree_leaves(eng.kv)] == [(3, 40, 1, 8, 128)]
             eng.check_kv_invariant()
     assert outs["fast"] == outs["padded"]
@@ -160,12 +168,14 @@ def test_deepseek_v2_through_the_engine_counts_every_pick_and_compacts():
 # What six families' step programs lowered to at the parent of ISSUE 49 (sha256 of
 # ``jit(forward_paged).lower(...).as_text()``, first 16 digits): a hand-on inside a
 # period, identity experts and a tally leaf are traced for the family that has them
-# and for no other.  Whoever changes ``paged_forward`` or ``sparse_moe_ffn`` on
+# and for no other.  The two shares were re-pinned in ISSUE 51, which compacts a
+# share's held picks (the four others, whose leaves hold every routed expert or
+# none, lowered to what they were).  Whoever changes ``paged_forward`` or ``sparse_moe_ffn`` on
 # purpose re-pins these from the new tree and says in PERF.md that every cell's
 # programs, and with them ``setup_s``, are compiled anew.
 PROGRAMS_BEFORE = {"olmoe_decode": "be129bc1388a5808", "olmoe_compacted": "1157e790add8daad",
-                   "deepseek_v2_share_compacted": "f9f66894abb41f67",
-                   "glm_moe_dsa_share_padded": "68d732d5a1164316",
+                   "deepseek_v2_share_compacted": "d59248c8f6384b9a",
+                   "glm_moe_dsa_share_padded": "70fa7bbde87e1038",
                    "lfm2_period_compacted": "6dd10a4bfc4f41c2", "llama_decode": "c6d24d39c1fabc19"}
 
 

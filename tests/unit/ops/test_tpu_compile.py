@@ -319,6 +319,35 @@ def test_the_selection_compiles_at_the_cells_shapes(chip, n, t, s):
     assert compiled.memory_analysis().temp_size_in_bytes < 640 << 20
 
 
+@pytest.mark.parametrize("slots,top_k,width,expert_width,held,routed,layers,identity,rows", [
+    (64, 12, 6144, 2048, 16, 768, 4, 256, 128), (1024, 12, 6144, 2048, 16, 768, 4, 256, 384),
+    (2048, 10, 2048, 512, 128, 512, 12, None, 6400), (512, 6, 5120, 1536, 40, 160, 4, None, 1024),
+    (1024, 8, 6144, 2048, 16, 256, 4, None, 640)],
+    ids=["scmoe-decode", "scmoe-chunk", "gdn-chunk", "mla-chunk", "dsa-chunk"])
+def test_a_shares_expert_ffn_compiles_at_the_cells_shapes(chip, slots, top_k, width, expert_width,
+                                                          held, routed, layers, identity, rows):
+    """ISSUE 51: one chip's share of an expert-parallel layer at the four share
+    cells' shapes: the held picks compacted into a window of ``rows`` rows, three
+    ``gmm`` calls over that window inside the trips' loop and the combine kernel
+    (blocks of 128 x up to 2,048 and, under one tile, of a decode bucket's few
+    rows); nothing as large as every pick's row is among the temporaries."""
+    from deepspeed_tpu.moe.serving import expert_rows, sparse_moe_ffn
+    assert expert_rows(slots, top_k, held, routed) == rows
+    moe = {"gate": {"wg": chip((width, routed), jnp.bfloat16)},
+           "experts": {"w_gate": chip((layers, held, width, expert_width), jnp.bfloat16),
+                       "w_up": chip((layers, held, width, expert_width), jnp.bfloat16),
+                       "w_down": chip((layers, held, expert_width, width), jnp.bfloat16)}}
+
+    def layer(moe, x, live, at):
+        return sparse_moe_ffn(moe, x, top_k, False, live, layer=at, identity_experts=identity)
+
+    compiled = jax.jit(layer).lower(moe, chip((slots, width), jnp.bfloat16), chip((slots, ), jnp.bool_),
+                                    chip((), jnp.int32)).compile()
+    assert kernel_calls(compiled.as_text()) == {"gmm": 3, "moe_combine": 1}
+    every_pick = slots * top_k * width * 2  # the bf16 rows the parent gathered a pass
+    assert compiled.memory_analysis().temp_size_in_bytes < max(every_pick // 2, 4 << 20)
+
+
 def test_fused_adamw_flat_compiles(chip):
     from deepspeed_tpu.ops.adam.fused_adam import fused_adamw_flat
     n = 1 << 26  # one stacked 4096 x 14336 FFN leaf is 2^25.8 elements
