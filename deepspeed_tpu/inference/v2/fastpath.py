@@ -115,7 +115,8 @@ class ServeCounters:
     of them rows where every expert is held, on a share the held ones alone
 
     A family whose state is a recurrence scanned over a step's tokens in chunks
-    (ISSUE 43; the model module states ``state_scan``; zero for every other):
+    (a gated delta rule, a state-space scan: the model module states
+    ``state_scan`` with its own chunk; zero for every other):
     ``scan_chunks``  chunks the scans of the launched programs walked, from their
     static shapes (every sequence of a compacted pass begins on a chunk's edge;
     a step of one token a row walks none), in every such layer

@@ -1,5 +1,5 @@
-from . import (bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, lfm2, llama, longcat_flash,
-               mistral, mixtral, olmoe, opt, phi, qwen, transformer)
+from . import (bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, granite_moe_hybrid, lfm2, llama,
+               longcat_flash, mistral, mixtral, olmoe, opt, phi, qwen, transformer)
 from .bert import BertConfig
 from .bloom import BloomConfig
 from .deepseek_v2 import DeepseekV2Config
@@ -7,6 +7,7 @@ from .falcon import FalconConfig
 from .glm_moe_dsa import GlmMoeDsaConfig
 from .gpt2 import GPT2Config
 from .gptj import GPTJConfig
+from .granite_moe_hybrid import GraniteMoeHybridConfig
 from .lfm2 import Lfm2Config
 from .llama import LlamaConfig
 from .longcat_flash import LongcatFlashConfig

@@ -300,11 +300,7 @@ def forward_paged(config: Qwen3NextConfig, params, tokens, n_tokens, start_pos, 
                                      preferred_element_type=jnp.float32), 2, axis=-1)
             with jax.named_scope("gdn_state"):
                 earlier, last = taps(mixed, carried["conv"])
-            w = m["filter"].astype(jnp.float32)  # [taps, columns]: the last weighs the token itself
-            conv = w[-1] * mixed.astype(jnp.float32)
-            for tap, before in zip(w[:-1], earlier):
-                conv = conv + tap * before.astype(jnp.float32)
-            conv = jax.nn.silu(conv)
+            conv = jax.nn.silu(transformer.causal_filter(mixed, earlier, m["filter"]))
             q = (l2norm(conv[..., :key_dim].reshape(lead + (hk, dk))) * dk ** -0.5).astype(dtype)
             k = l2norm(conv[..., key_dim:2 * key_dim].reshape(lead + (hk, dk))).astype(dtype)
             v = conv[..., 2 * key_dim:].reshape(lead + (hv, dv)).astype(dtype)
@@ -355,13 +351,8 @@ def forward_paged(config: Qwen3NextConfig, params, tokens, n_tokens, start_pos, 
         return norm(x, params["final_norm"]) @ params["lm_head"].astype(dtype)
 
     # every layer is handed its index into the one stack of experts
-    layers = []
-    for (start, period, repeats), segment in zip(layer_segments(config), params["segments"]):
-        layers.append(tuple(
-            {**lp, "moe": {**lp["moe"], "layer": start + j + jnp.arange(
-                0, repeats * period, period, dtype=jnp.int32)}}
-            for j, lp in enumerate(segment)))
     return transformer.paged_forward(
-        layers, tokens, n_tokens, start_pos, block_tables, kv_cache, block_size=block_size,
+        transformer.layers_of_one_expert_stack(layer_segments(config), params["segments"]), tokens,
+        n_tokens, start_pos, block_tables, kv_cache, block_size=block_size,
         live_token_bound=live_token_bound, last_rows=last_rows, embed=embed, qkv=qkv, finish=finish,
         head=head, mix=mix, softmax_scale=dh ** -0.5)

@@ -484,6 +484,29 @@ def repeating_runs(kinds):
     return out
 
 
+def layers_of_one_expert_stack(runs, segments):
+    """A family whose experts are ONE stack over all layers (``[layers, held,
+    ...]``) hands every layer its index into it: ``segments`` (one tuple of
+    per-position stacks a run of ``runs`` = :func:`repeating_runs`) with
+    ``lp["moe"]["layer"]`` ``[repeats]`` added, the list :func:`paged_forward`
+    takes as ``layers``."""
+    return [tuple({**lp, "moe": {**lp["moe"], "layer": start + j + jnp.arange(
+        0, repeats * period, period, dtype=jnp.int32)}} for j, lp in enumerate(segment))
+            for (start, period, repeats), segment in zip(runs, segments)]
+
+
+def causal_filter(z, earlier, w, bias=None):
+    """A depth-wise causal filter over a mixer's columns, float32: ``w``
+    ``[taps, columns]`` whose LAST row weighs the token itself, ``earlier`` the
+    ``taps - 1`` shifted copies of ``z`` that :func:`paged_forward`'s ``taps``
+    gave (oldest first)."""
+    w = w.astype(jnp.float32)
+    out = w[-1] * z.astype(jnp.float32)
+    for tap, before in zip(w[:-1], earlier):
+        out = out + tap * before.astype(jnp.float32)
+    return out if bias is None else out + bias.astype(jnp.float32)
+
+
 def tp_psum(tp_axis: Optional[str]):
     """What a family's ``finish`` does with a row-parallel partial: the psum
     over ``tp_axis`` inside shard_map, nothing on one chip."""
@@ -569,7 +592,8 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     nothing of it is traced.
 
     **Layers without attention** (``STATE_MIXER`` among ``lp``'s keys: LFM2's
-    gated short convolutions, Qwen3-Next's Gated DeltaNet).  Such a layer
+    gated short convolutions, Qwen3-Next's Gated DeltaNet, Granite 4.0-H's
+    Mamba-2).  Such a layer
     touches neither the pool nor the write plan nor the kernel: ``mix(lp, x,
     taps, live, carried, places) -> (x, carried)`` is the whole layer, and what
     it remembers of a sequence's past is a fixed state a SEQUENCE, not rows a

@@ -1,2 +1,5 @@
-"""Linear-attention kernels: the gated delta rule's chunked scan and its one-token update."""
+"""Linear-attention kernels: the gated delta rule's and the state-space duality's chunked scans
+and their one-token updates (``CHUNK`` and ``scan_chunks`` here are the gated delta rule's;
+``ssd.py`` has its own)."""
 from .gated_delta import CHUNK, gated_delta_scan, gated_delta_step, scan_chunks
+from .ssd import ssd_scan, ssd_update

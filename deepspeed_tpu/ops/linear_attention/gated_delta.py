@@ -89,14 +89,14 @@ CHUNK = 64
 SEQ, FIRST, LAST, LIVE = range(4)  # rows of the chunk table
 
 
-def scan_chunks(n: int, t: int, flat=None) -> int:
+def scan_chunks(n: int, t: int, flat=None, chunk: int = CHUNK) -> int:
     """Chunks the scan walks for a ``[n, t]`` bucket (``flat``: the flat slots
     it is compacted onto, or None): none for a step of one token a row (the
-    one-token update), a row's ``ceil(t / CHUNK)`` padded, ``ceil(flat / CHUNK)
+    one-token update), a row's ``ceil(t / chunk)`` padded, ``ceil(flat / chunk)
     + n`` compacted (every sequence begins on a chunk's edge)."""
     if flat is not None:
-        return -(-flat // CHUNK) + n
-    return 0 if t == 1 else n * -(-t // CHUNK)
+        return -(-flat // chunk) + n
+    return 0 if t == 1 else n * -(-t // chunk)
 
 
 def gated_delta_step(q, k, v, g, beta, state):
@@ -229,21 +229,19 @@ def _chunk_table(seq, nth, count, per_seq):
                       count]).astype(jnp.int32)
 
 
-def gated_delta_scan(q, k, v, g, beta, state, n_tokens, row=None, col=None):
-    """The chunked scan over a step's tokens.  q, k ``[b, s, Hk, dk]`` (l2-
-    normalised, q scaled), v ``[b, s, Hv, dv]``, g, beta ``[b, s, Hv]`` float32,
-    ``[b, s]`` = ``[N, T]`` (``row`` None) or the compacted ``[1, S]`` (``row``,
-    ``col`` ``[1, S]``); state ``[N, Hv, dk, dv]`` float32, each row's carried
-    matrix (zeros for a sequence that begins); n_tokens ``[N]``.  Returns (o
-    ``[b, s, Hv, dv]`` in v's dtype, the rows' new states).  A row with no
-    token keeps its state."""
+def lay_on_chunk_edges(n_tokens, shape, row=None, col=None, chunk: int = CHUNK):
+    """Every sequence of a step laid out to begin on a chunk's edge.  ``shape``
+    is the ``[b, s]`` the step's tokens come in: ``[N, T]`` (``row`` None) or the
+    compacted ``[1, S]`` (``row``, ``col`` ``[1, S]``); ``n_tokens`` ``[N]``.
+    Returns ``(table, laid, back, chunks)``: the chunk table ``[4, chunks]``,
+    ``laid(a [b, s, H, ...]) -> [H, chunks * chunk, ...]`` (heads first, zero
+    where no token sits) and ``back(o [chunks * chunk, H, d]) -> [b, s, H, d]``."""
     n = n_tokens.shape[0]
-    hk, hv = q.shape[2], v.shape[2]
-    per_seq = -(-n_tokens // CHUNK)
+    per_seq = -(-n_tokens // chunk)
     if row is None:
-        t = q.shape[1]
-        per_row = -(-t // CHUNK)
-        chunks, width = n * per_row, per_row * CHUNK
+        t = shape[1]
+        per_row = -(-t // chunk)
+        chunks, width = n * per_row, per_row * chunk
 
         def aligned(a):  # [N, T, ...] -> [N * T', ...], T' whole chunks
             a = jnp.pad(a, ((0, 0), (0, width - t)) + ((0, 0), ) * (a.ndim - 2))
@@ -253,31 +251,44 @@ def gated_delta_scan(q, k, v, g, beta, state, n_tokens, row=None, col=None):
         # a row's chunks in place, the empty chunks of rows that hold fewer among them
         c = jnp.arange(chunks, dtype=jnp.int32)
         seq, nth = c // per_row, c % per_row
-        table = _chunk_table(seq, nth, jnp.clip(n_tokens[seq] - nth * CHUNK, 0, CHUNK), per_seq)
-        back = lambda o: o.reshape(n, width, hv, -1)[:, :t]
+        table = _chunk_table(seq, nth, jnp.clip(n_tokens[seq] - nth * chunk, 0, chunk), per_seq)
+        back = lambda o: o.reshape((n, width) + o.shape[1:])[:, :t]
     else:
-        slots = q.shape[1]
-        chunks = scan_chunks(n, 0, slots)
+        slots = shape[1]
+        chunks = scan_chunks(n, 0, slots, chunk)
         ends = jnp.cumsum(per_seq)
         first_chunk = ends - per_seq
         c = jnp.arange(chunks, dtype=jnp.int32)
         seq = jnp.minimum(jnp.sum((c[:, None] >= ends[None, :]).astype(jnp.int32), axis=1), n - 1)
         nth = c - first_chunk[seq]  # past a sequence's chunks where c is past the last sequence's
-        table = _chunk_table(seq, nth, jnp.clip(n_tokens[seq] - nth * CHUNK, 0, CHUNK), per_seq)
-        p = jnp.arange(chunks * CHUNK, dtype=jnp.int32)
-        live = (p % CHUNK) < table[LIVE][p // CHUNK]
+        table = _chunk_table(seq, nth, jnp.clip(n_tokens[seq] - nth * chunk, 0, chunk), per_seq)
+        p = jnp.arange(chunks * chunk, dtype=jnp.int32)
+        live = (p % chunk) < table[LIVE][p // chunk]
         # the position's token: its place in its sequence's chunk of this step, on the flat axis
-        source = jnp.where(live, (jnp.cumsum(n_tokens) - n_tokens)[seq[p // CHUNK]]
-                           + nth[p // CHUNK] * CHUNK + p % CHUNK, 0)
+        source = jnp.where(live, (jnp.cumsum(n_tokens) - n_tokens)[seq[p // chunk]]
+                           + nth[p // chunk] * chunk + p % chunk, 0)
         aligned = lambda a: a[0][source]
-        place = first_chunk[row[0]] * CHUNK + col[0]  # where a flat slot's token went
-        back = lambda o: o[jnp.clip(place, 0, chunks * CHUNK - 1)][None]
+        place = first_chunk[row[0]] * chunk + col[0]  # where a flat slot's token went
+        back = lambda o: o[jnp.clip(place, 0, chunks * chunk - 1)][None]
 
     def laid(a):  # [b, s, H, d] -> [H, chunks * C, d], zero where no token sits
         a = aligned(a)
         mask = live.reshape((-1, ) + (1, ) * (a.ndim - 1))
         return jnp.moveaxis(jnp.where(mask, a, jnp.zeros((), a.dtype)), 1, 0)
 
+    return table, laid, back, chunks
+
+
+def gated_delta_scan(q, k, v, g, beta, state, n_tokens, row=None, col=None):
+    """The chunked scan over a step's tokens.  q, k ``[b, s, Hk, dk]`` (l2-
+    normalised, q scaled), v ``[b, s, Hv, dv]``, g, beta ``[b, s, Hv]`` float32,
+    ``[b, s]`` = ``[N, T]`` (``row`` None) or the compacted ``[1, S]`` (``row``,
+    ``col`` ``[1, S]``); state ``[N, Hv, dk, dv]`` float32, each row's carried
+    matrix (zeros for a sequence that begins); n_tokens ``[N]``.  Returns (o
+    ``[b, s, Hv, dv]`` in v's dtype, the rows' new states).  A row with no
+    token keeps its state."""
+    hk, hv = q.shape[2], v.shape[2]
+    table, laid, back, chunks = lay_on_chunk_edges(n_tokens, q.shape[:2], row, col)
     qa, ka, va = laid(q), laid(k), laid(v)
     ga = laid(g.astype(jnp.float32)).reshape(hv, chunks, CHUNK)
     ba = laid(beta.astype(jnp.float32)).reshape(hv, chunks, CHUNK)

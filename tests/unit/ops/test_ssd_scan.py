@@ -1,0 +1,154 @@
+"""The state-space duality's chunked scan (``ops/linear_attention/ssd.py``)
+against the recurrence token by token (``chipbench/references/
+granite_moe_hybrid.py selective_scan``, which shares no algebra with it): the
+Pallas kernels in interpret mode and the ``jax.numpy`` forms, sequences as rows
+of a padded ``[N, T]`` and compacted onto one flat axis, lengths that are and
+are not multiples of the chunk, with and without a carried state, decays near 0
+and near 1, a scan continued from its state, and the one-token update."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.references.granite_moe_hybrid import selective_scan
+from deepspeed_tpu.ops import _pallas
+from deepspeed_tpu.ops.linear_attention import ssd
+from deepspeed_tpu.ops.linear_attention.ssd import CHUNK, scan_chunks, ssd_scan, ssd_update
+
+H, P, NS = 16, 8, 16  # 16 heads: two grid steps of the scan kernel's eight
+TOL = 1e-5  # float32 throughout, of the largest value (``near``): a chunk's products against 64 steps
+
+
+def near(got, want):
+    """1e-5 of the largest value (outputs reach 60 under a carried state of unit scale: sums of 16
+    to 64 float32 products; sound forms read 2e-6 apart)."""
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, atol=TOL * max(1.0, float(np.abs(want).max())), rtol=0)
+
+
+@pytest.fixture(params=["numpy", "kernel"])
+def form(request, monkeypatch):
+    monkeypatch.setattr(_pallas, "INTERPRET", request.param == "kernel")
+    return request.param
+
+
+RNG = np.random.default_rng(0)
+A = -RNG.uniform(1e-3, 2.0, size=H).astype(np.float32)  # with dt: decays a token from 0.05 to 0.9999
+D = RNG.normal(size=H).astype(np.float32)
+
+
+def draw(rng, s):
+    x = rng.normal(size=(s, H, P)).astype(np.float32)
+    dt = rng.uniform(0.01, 1.5, size=(s, H)).astype(np.float32)
+    b, c = (rng.normal(size=(s, NS)).astype(np.float32) for _ in range(2))
+    return x, dt, b, c
+
+
+def token_by_token(seq, state):
+    x, dt, b, c = (jnp.asarray(a) for a in seq)
+    y, last = selective_scan(x, dt, jnp.asarray(A), b, c, jnp.asarray(D),
+                             None if state is None else jnp.asarray(state))
+    return np.asarray(y), np.asarray(last)
+
+
+def padded(seqs, counts, t):
+    fill = lambda a, c: np.concatenate([a[:c], np.full((t - c, ) + a.shape[1:], 7.0, np.float32)])
+    return [jnp.asarray(np.stack([fill(s[i], c) for s, c in zip(seqs, counts)])) for i in range(4)]
+
+
+def flat(seqs, counts, slots):
+    """(arrays [1, S, ...], row, col): the rows' live tokens one after another, the tail dead."""
+    row = np.repeat(np.arange(len(counts)), counts)
+    col = np.concatenate([np.arange(c) for c in counts])
+    dead = slots - len(row)
+    arrays = [jnp.asarray(np.concatenate(
+        [np.concatenate([s[i][:c] for s, c in zip(seqs, counts)]),
+         np.full((dead, ) + seqs[0][i].shape[1:], 5.0, np.float32)]))[None] for i in range(4)]
+    at = lambda a: jnp.asarray(np.concatenate([a, np.zeros(dead, int)]))[None]
+    return arrays, at(row), at(col)
+
+
+def scan(arrays, state, counts, row=None, col=None):
+    x, dt, b, c = arrays
+    return ssd_scan(x, dt, jnp.asarray(A), b, c, jnp.asarray(D), jnp.asarray(state),
+                    jnp.asarray(counts, jnp.int32), row, col)
+
+
+@pytest.mark.parametrize("counts,carried", [
+    ((130, 0, 1, 64, 77), True), ((130, 0, 1, 64, 77), False), ((64, 128), True), ((5, ), False),
+    ((0, 0, 9), True)], ids=lambda v: "-".join(map(str, v)) if isinstance(v, tuple) else str(v))
+@pytest.mark.parametrize("layout", ["padded", "flat"])
+def test_the_chunked_scan_is_the_recurrence_token_by_token(form, layout, counts, carried):
+    """Several sequences a pass, each from its own carried matrices (zeros where
+    it begins), each laid onto a chunk's edge; what lies in the dead slots
+    (sevens, fives) reaches nothing; a row with no token keeps its state."""
+    rng = np.random.default_rng(sum(counts))
+    seqs = [draw(rng, max(c, 1)) for c in counts]
+    state = (rng.normal(size=(len(counts), H, P, NS)) if carried
+             else np.zeros((len(counts), H, P, NS))).astype(np.float32)
+    if layout == "padded":
+        y, last = scan(padded(seqs, counts, max(counts)), state, counts)
+        mine = lambda i, c: np.asarray(y[i, :c])
+    else:
+        arrays, row, col = flat(seqs, counts, sum(counts) + 11)
+        y, last = scan(arrays, state, counts, row, col)
+        starts = np.cumsum((0, ) + counts)
+        mine = lambda i, c: np.asarray(y[0, starts[i]:starts[i] + c])
+    for i, c in enumerate(counts):
+        if c == 0:
+            np.testing.assert_array_equal(np.asarray(last[i]), state[i])
+            continue
+        want, want_last = token_by_token([a[:c] for a in seqs[i]], state[i])
+        near(mine(i, c), want)
+        near(np.asarray(last[i]), want_last)
+
+
+def test_a_scan_continued_from_its_state_is_the_whole_scan(form):
+    rng = np.random.default_rng(3)
+    seq = draw(rng, 150)
+    zero = np.zeros((1, H, P, NS), np.float32)
+    whole, end = scan(padded([seq], (150, ), 150), zero, (150, ))
+    head, kept = scan(padded([seq], (70, ), 70), zero, (70, ))
+    tail, last = scan(padded([[a[70:] for a in seq]], (80, ), 80), kept, (80, ))
+    near(np.concatenate([head[0], tail[0]]), np.asarray(whole[0]))
+    near(np.asarray(last), np.asarray(end))
+
+
+def test_the_one_token_update_is_a_scan_of_one(form):
+    rng = np.random.default_rng(4)
+    n = 3
+    seqs = [draw(rng, 1) for _ in range(n)]
+    state = rng.normal(size=(n, H, P, NS)).astype(np.float32)
+    x, dt, b, c = padded(seqs, (1, ) * n, 1)
+    y, last = ssd_update(x[:, 0], dt[:, 0], jnp.asarray(A), b[:, 0], c[:, 0], jnp.asarray(D),
+                         jnp.asarray(state))
+    scanned, scanned_last = scan((x, dt, b, c), state, (1, ) * n)
+    near(np.asarray(y), np.asarray(scanned[:, 0]))
+    near(np.asarray(last), np.asarray(scanned_last))
+    for i, seq in enumerate(seqs):
+        want, want_last = token_by_token(seq, state[i])
+        near(np.asarray(y[i]), want[0])
+        near(np.asarray(last[i]), want_last)
+
+
+def test_bfloat16_operands_keep_the_state_and_the_decays_in_float32(form):
+    """The chip's form: x, B and C bfloat16, dt and the state float32.  The new
+    state is float32 and within bfloat16's rounding of the float32 scan's."""
+    rng = np.random.default_rng(5)
+    seq = draw(rng, 100)
+    zero = np.zeros((1, H, P, NS), np.float32)
+    x, dt, b, c = padded([seq], (100, ), 100)
+    half = lambda a: a.astype(jnp.bfloat16)
+    y, last = scan((half(x), dt, half(b), half(c)), zero, (100, ))
+    want, want_last = scan((x, dt, b, c), zero, (100, ))
+    assert (y.dtype, last.dtype) == (jnp.bfloat16, jnp.float32)
+    scale = float(np.abs(np.asarray(want_last)).max())
+    assert np.abs(np.asarray(last) - np.asarray(want_last)).max() < 0.02 * scale
+    assert np.abs(np.asarray(y, np.float32) - np.asarray(want)).max() < 0.03 * float(np.abs(want).max())
+
+
+def test_the_chunks_a_bucket_walks():
+    assert CHUNK == 64 and ssd.SCAN_HEADS == 8 and ssd.UPDATE_HEADS == 32
+    assert scan_chunks(32, 1) == 0  # a decode step: the one-token update
+    assert scan_chunks(4, 256) == 16 and scan_chunks(4, 65) == 8
+    assert scan_chunks(32, 256, 512) == 8 + 32  # compacted: every sequence on a chunk's edge
