@@ -22,9 +22,12 @@ the embedding) divided by ``logits_scaling``.
   head in float32 (4 MB at 128 heads of 64 x 128) and the last ``taps - 1``
   rows of ``xBC`` before the filter.  Both are leaves of ``kv_cache[STATE]``,
   one slot a live sequence, beside the paged pool; ``transformer.paged_forward``
-  (which states the contract) hands ``mix`` the rows' carried leaves, the
+  (which states the contract) hands ``mix`` the shift's rows BY VALUE, with the
   shift local to a sequence and where the sequences lie, and writes back what
-  ``mix`` returns.  Nothing here knows of slots.
+  ``mix`` returns for them; the matrices go BY REFERENCE (``STATE_BY_REFERENCE``:
+  ``ssd_update`` and ``ssd_scan`` index the rows' slots of the carried leaf
+  themselves, so 4 MB a row a layer is read where it lies once and written there
+  once).  Nothing here computes a slot.
 - **Attention**: GQA over the paged pool with NO positions at all
   (``position_embedding_type: "nope"``), scores times ``attention_multiplier``
   (published 1/128 at heads of 128: not one over the root).
@@ -205,6 +208,11 @@ def init_params(config: GraniteMoeHybridConfig, key, dtype=jnp.float32):
 
 
 # --------------------------------------------------------- paged (ragged) serve
+# Which leaves of ``kv_cache[STATE]`` ``paged_forward`` hands ``mix`` by reference: what the
+# kernels of ``ops/linear_attention/ssd.py`` take whole, with the rows' slots.
+STATE_BY_REFERENCE = {"conv": False, "ssm": True}
+
+
 def init_paged_cache(config: GraniteMoeHybridConfig, num_blocks: int, block_size: int,
                      dtype=jnp.bfloat16, state_slots: int = 32):
     """The KV pool of the ATTENTION layers alone and, under ``STATE``, the
@@ -306,14 +314,15 @@ def forward_paged(config: GraniteMoeHybridConfig, params, tokens, n_tokens, star
             b, c = xbc[..., inner:inner + ns], xbc[..., inner + ns:]
             dt = jax.nn.softplus(dt + m["dt_bias"].astype(jnp.float32))
             a = -jnp.exp(m["A_log"].astype(jnp.float32))
+            # carried["ssm"] is a ``StateRef`` (leaf, at, begins): the kernels' last state arguments
             if places.row is None and x.shape[1] == 1:  # a decode row, a burst's step
                 with jax.named_scope("ssm_update"), jax.named_scope("ssm_state"):
                     y, state = ssd_update(xs[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], m["D"],
-                                          carried["ssm"])
+                                          *carried["ssm"])
                 y = y[:, None]
             else:
                 with jax.named_scope("ssm_scan"):
-                    y, state = ssd_scan(xs, dt, a, b, c, m["D"], carried["ssm"], places.n_tokens,
+                    y, state = ssd_scan(xs, dt, a, b, c, m["D"], *carried["ssm"], places.n_tokens,
                                         places.row, places.col)
             # GraniteMoeHybridRMSNormGated: the gate INSIDE the norm, one group over all columns
             y = y.reshape(lead + (inner, )).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
@@ -343,4 +352,5 @@ def forward_paged(config: GraniteMoeHybridConfig, params, tokens, n_tokens, star
         transformer.layers_of_one_expert_stack(layer_segments(config), params["segments"]), tokens,
         n_tokens, start_pos, block_tables, kv_cache, block_size=block_size,
         live_token_bound=live_token_bound, last_rows=last_rows, embed=embed, qkv=qkv, finish=finish,
-        head=head, mix=mix, softmax_scale=config.attention_multiplier)
+        head=head, mix=mix, by_reference=STATE_BY_REFERENCE,
+        softmax_scale=config.attention_multiplier)

@@ -464,6 +464,17 @@ class SeqPlaces(NamedTuple):
     col: Optional[jax.Array]
 
 
+class StateRef(NamedTuple):
+    """A state leaf handed to ``mix`` BY REFERENCE (:func:`paged_forward`,
+    ``by_reference``): ``leaf`` the carried leaf whole and flat ``[Ls x slots,
+    ...]``, ``at`` ``[N]`` the rows' slots of this layer in it (a dead row's:
+    the layer's trash slot), ``begins`` ``[N]`` bool, the rows whose sequence
+    begins (what their slot holds counts as zero)."""
+    leaf: jax.Array
+    at: jax.Array
+    begins: jax.Array
+
+
 def repeating_runs(kinds):
     """``[(start, period, repeats)]``: a list of layer kinds as runs that repeat
     a pattern of ``period`` kinds ``repeats`` times, greedily the longest run
@@ -519,7 +530,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                   window: Optional[int] = None, alibi_slopes=None,
                   softmax_scale: Optional[float] = None, value_dim: Optional[int] = None,
                   mix: Optional[Callable] = None, selection: Optional[Selection] = None,
-                  hand_on: bool = False):
+                  hand_on: bool = False, by_reference=None):
     """The one ragged chunked forward over the paged KV pool (FastGen
     model-forward analog, inference/v2/model_implementations + blocked flash):
     every family's ``forward_paged`` is its own arithmetic as four callables
@@ -624,6 +635,21 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
       a decode row is a chunk of one token; a fused burst carries the state in
       its loop, as it does the pool).
 
+    That is a leaf BY VALUE: a read of the rows' slots, a select and a scatter
+    around ``mix``, each a pass over the rows, which suits a few rows of filter
+    taps that ``taps`` must gather and shift anyway.  A leaf whose kernels index
+    the slots themselves goes BY REFERENCE: ``by_reference`` is the family's
+    statement, in its code, of which leaves those are (a tree of bools shaped
+    like ``kv_cache[STATE]``; None: none).  For such a leaf nothing is read,
+    selected or scattered here: ``carried`` holds in its place a
+    :class:`StateRef` (the carried leaf whole and flat ``[Ls x slots, ...]``,
+    the rows' slots ``at`` ``[N]`` in it, a dead row's the trash slot of the
+    layer, and ``begins`` ``[N]``, ``start_pos == 0``), and what ``mix``
+    returns in its place is the NEW FLAT LEAF, which goes back into the layer
+    scan's carry as it is: the rows' slots updated (a beginning row's from
+    zeros), every other slot as it was (Granite's ``ssm``: 4 MB a row a layer,
+    read and written once, inside ``ssd_update`` / ``ssd_scan``).
+
     The pool's row is counted over the attention layers alone, the state's over
     the rest.
 
@@ -709,6 +735,8 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
         kv_cache = dict(kv_cache)
         state_leaves, state_tree = jax.tree_util.tree_flatten(kv_cache.pop(STATE))
         state_slots = state_leaves[0].shape[1]
+        by_ref = ([False] * len(state_leaves) if by_reference is None
+                  else state_tree.flatten_up_to(by_reference))
         # the rows' state slots ride as the table's last column; a dead row's is the trash slot
         seq_slot = jnp.where(n_tokens > 0, block_tables[:, -1], state_slots - 1)
         block_tables = block_tables[:, :-1]
@@ -777,13 +805,14 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
             raise ValueError(f"a layer holds {STATE_MIXER!r} and the family gave no "
                              f"{'mix' if mix is None else 'kv_cache[STATE]'}")
         at = l * state_slots + seq_slot
-        with jax.named_scope("seq_state"):
-            kept = [jnp.where((start_pos > 0).reshape((-1, ) + (1, ) * (leaf.ndim - 1)), leaf[at], 0)
-                    for leaf in flat_states]
+        with jax.named_scope("seq_state"):  # a leaf by reference: whole, with where its rows lie
+            kept = [StateRef(leaf, at, start_pos == 0) if ref else
+                    jnp.where((start_pos > 0).reshape((-1, ) + (1, ) * (leaf.ndim - 1)), leaf[at], 0)
+                    for leaf, ref in zip(flat_states, by_ref)]
         x, carried = mix(lp, x, taps, live, jax.tree_util.tree_unflatten(state_tree, kept), places)
         with jax.named_scope("seq_state"):
-            return x, [leaf.at[at].set(new.astype(leaf.dtype)) for leaf, new in zip(
-                flat_states, state_tree.flatten_up_to(carried))]
+            return x, [new if ref else leaf.at[at].set(new.astype(leaf.dtype))
+                       for leaf, new, ref in zip(flat_states, state_tree.flatten_up_to(carried), by_ref)]
 
     # The pool is carried, never sliced (xs) and restacked (ys): a scan's ys is
     # a new [L, ...] array that cannot alias a donated argument still being

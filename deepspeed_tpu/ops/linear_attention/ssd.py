@@ -11,11 +11,29 @@ HEAD (one group), ``dt_t > 0`` a step a head a token, ``A < 0`` and ``D`` a
 scalar a head.  No delta term: nothing is solved for, and the chunked form is
 matrix products alone.  Two forms:
 
+**The state by reference**: both forms take the carried state as the WHOLE
+flat leaf ``[slots, H, P, N]`` float32 (every such layer's slots on one axis,
+as ``transformer.paged_forward`` carries it), the rows' slots ``at`` ``[rows]``
+and a flag a row, ``begins``: what the slot of a sequence that begins holds
+counts as zero and reaches nothing.  They return the leaf: each row's slot
+holds its new matrices, every slot no live row names is bit for bit what it
+was.  On the TPU the kernels index the slots themselves (``at`` and the flags
+scalar-prefetched, the state's block of a grid step ``(slot, heads)`` for input
+and output alike, the leaf aliased in and out), so a row's matrices are read
+from where they lie once and written there once and nothing else moves; off it
+``_by_value`` gathers the rows, the ``jax.numpy`` form updates them and a
+scatter puts them back.  Live rows name distinct slots; the dead rows of a
+step all name one trash slot, which is sound on these grids because a grid
+step touches no block but its own slot's: whatever order the dead rows'
+reads and writes of the trash slot fall in (a block named twice in a row is
+fetched once and written once), they reach the trash slot alone, whose
+content no live row reads.
+
 **One token** (:func:`ssd_update`): a decode row and a burst's step; the
 recurrence as written.  On the TPU (or with ``_pallas.INTERPRET``) the Pallas
 kernel ``ssd_update``: grid (row, ``UPDATE_HEADS`` heads), a step reads its
-heads' ``[P, N]`` float32 matrices once and writes them once, aliased onto what
-it read; the decay comes as a row broadcast along the state's lanes, the outer
+heads' ``[P, N]`` float32 matrices once and writes them once, in their slot;
+the decay comes as a row broadcast along the state's lanes, the outer
 product ``(dt x) B^T`` of all the step's heads as ONE product with a padded
 contraction of ``PAD`` rows (a column vector costs a whole lane tile an element
 in memory, a row nothing), and ``y = S C`` as one product ``C S^T`` whose
@@ -38,13 +56,16 @@ delta rule's), the accumulations float32.
 **Sequences on one axis**: as ``gated_delta.py`` (whose layout this imports):
 every sequence of a step, padded ``[N, T]`` or compacted ``[1, S]``, is laid
 out to begin on a chunk's edge; the first chunk of a sequence loads its carried
-matrices, the last stores them; dead positions hold ``dt = 0`` and change
-nothing.  On the TPU the walk is the Pallas kernel ``ssd_scan``: grid (``SCAN_HEADS``
-heads, chunk), x and y ``[heads, C, P]``, B and C ``[C, N]``, the running sums
-steps and ``D`` of the heads both as rows ``[3 heads, C]`` and as columns ``[C, 3
-heads]`` (the mask needs ``l_i - l_j``: a column minus a row), the chunk table
-scalar-prefetched, ``S`` in VMEM scratch between a sequence's chunks.  Off the
-TPU the same chunk mathematics, every head at once, under ``lax.scan``.
+matrices from its slot (zeros where it begins), the last stores them there; a
+row with no token walks no chunk and its slot is never written; dead positions
+hold ``dt = 0`` and change nothing.  On the TPU the walk is the Pallas kernel
+``ssd_scan``: grid (``SCAN_HEADS`` heads, chunk), x and y ``[heads, C, P]``, B
+and C ``[C, N]``, the running sums, steps and ``D`` of the heads both as rows
+``[3 heads, C]`` and as columns ``[C, 3 heads]`` (the mask needs ``l_i - l_j``:
+a column minus a row), the chunk table (its ``SEQ`` row the sequences' slots, a
+fifth row their flags) scalar-prefetched, ``S`` in VMEM scratch between a
+sequence's chunks.  Off the TPU the same chunk mathematics, every head at
+once, under ``lax.scan``.
 """
 
 import functools
@@ -63,6 +84,7 @@ CHUNK = 64         # positions of one chunk of the scan (PERF.md, PR 52: the swe
 SCAN_HEADS = 8     # heads one grid step of ``ssd_scan`` takes
 UPDATE_HEADS = 32  # heads one grid step of ``ssd_update`` takes: 32 x [64, 128] float32 = 1 MiB
 PAD = 16           # rows a one-row operand is padded to: a whole sublane tile of bfloat16
+BEGINS = 4         # the scan kernel's row of the chunk table, after ``gated_delta``'s four
 
 
 def scan_chunks(n: int, t: int, flat=None) -> int:
@@ -86,60 +108,92 @@ NT = ((1, ), (1, ))  # a @ b^T
 TN = ((0, ), (0, ))  # a^T @ b
 
 
+# ------------------------------------------------------- the state, off the TPU
+def _by_value(leaf, at, begins, step, live=None):
+    """The by-reference contract where no kernel indexes the slots: the rows'
+    matrices gathered (zeros where a sequence begins), ``step(rows) -> (y,
+    rows)``, and the rows scattered back to their slots; a row that is not
+    ``live`` writes nothing."""
+    y, rows = step(jnp.where(begins.reshape(-1, 1, 1, 1), 0.0, leaf[at]))
+    if live is not None:
+        at = jnp.where(live, at, leaf.shape[0])  # out of bounds: dropped
+    return y, leaf.at[at].set(rows, mode="drop")
+
+
 # ------------------------------------------------------------------- one token
-def ssd_update(x, dt, A, B, C, D, state):
+def ssd_update(x, dt, A, B, C, D, leaf, at, begins):
     """One token a row.  x ``[N, H, P]``, dt ``[N, H]`` float32 (after its
-    softplus), A, D ``[H]``, B, C ``[N, Ns]``, state ``[N, H, P, Ns]`` float32
-    -> (y ``[N, H, P]`` float32, state)."""
-    dt, state = dt.astype(jnp.float32), state.astype(jnp.float32)
+    softplus), A, D ``[H]``, B, C ``[N, Ns]``; leaf ``[slots, H, P, Ns]``
+    float32, the carried state whole, at ``[N]`` the rows' slots (distinct, but
+    for the dead rows' one trash slot), begins ``[N]`` bool -> (y ``[N, H, P]``
+    float32, leaf): every row's slot updated, no other touched."""
+    dt = dt.astype(jnp.float32)
     decay = jnp.exp(dt * A.astype(jnp.float32))  # [N, H]
     dtx = dt[..., None] * x.astype(jnp.float32)
     if _pallas.use_pallas():
-        y, state = _update_pallas(dtx.astype(x.dtype), decay, B.astype(x.dtype), C.astype(x.dtype),
-                                  state, interpret=_pallas.INTERPRET)
+        y, leaf = _update_pallas(at.astype(jnp.int32), begins.astype(jnp.int32), dtx.astype(x.dtype),
+                                 decay, B.astype(x.dtype), C.astype(x.dtype), leaf,
+                                 interpret=_pallas.INTERPRET)
     else:
-        state = state * decay[..., None, None] + dtx[..., None] * B.astype(jnp.float32)[:, None, None, :]
-        y = jnp.sum(state * C.astype(jnp.float32)[:, None, None, :], axis=-1)
-    return y + D.astype(jnp.float32)[None, :, None] * x.astype(jnp.float32), state
+        def step(rows):
+            rows = (rows * decay[..., None, None]
+                    + dtx[..., None] * B.astype(jnp.float32)[:, None, None, :])
+            return jnp.sum(rows * C.astype(jnp.float32)[:, None, None, :], axis=-1), rows
+
+        y, leaf = _by_value(leaf, at, begins, step)
+    return y + D.astype(jnp.float32)[None, :, None] * x.astype(jnp.float32), leaf
 
 
-def _update_body(dtx_ref, decay_ref, b_ref, c_ref, state_ref, y_ref, out_ref):
+def _update_body(at_ref, begins_ref, dtx_ref, decay_ref, b_ref, c_ref, state_ref, y_ref, out_ref):
     heads, p, ns = state_ref.shape[1:]
     dtype = dtx_ref.dtype
     first = jax.lax.broadcasted_iota(jnp.int32, (PAD, ns), 0) == 0
     # (dt x) B^T of every head of the step at once: [PAD, heads P]^T [PAD, Ns], one live row
     outer = _dot(jnp.broadcast_to(dtx_ref[0], (PAD, heads * p)),
                  jnp.where(first, b_ref[0].astype(jnp.float32), 0.0), TN, dtype)
-    for h in range(heads):
-        out_ref[0, h] = state_ref[0, h] * decay_ref[0, h:h + 1, :] + outer[h * p:(h + 1) * p]
+    begins = begins_ref[pl.program_id(0)] > 0
+
+    @pl.when(jnp.logical_not(begins))
+    def _continues():
+        for h in range(heads):
+            out_ref[0, h] = state_ref[0, h] * decay_ref[0, h:h + 1, :] + outer[h * p:(h + 1) * p]
+
+    @pl.when(begins)
+    def _begins():  # what the slot held is not read: it may be anything
+        for h in range(heads):
+            out_ref[0, h] = outer[h * p:(h + 1) * p]
+
     # y = S C as C S^T: the result lies along lanes, one row of PAD alike
     y = _dot(jnp.broadcast_to(c_ref[0], (PAD, ns)), out_ref[0].reshape(heads * p, ns), NT, dtype)
     y_ref[0] = y[0:1]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", ), inline=True)
-def _update_pallas(dtx, decay, b, c, state, *, interpret):
-    n, heads, p, ns = state.shape
+def _update_pallas(at, begins, dtx, decay, b, c, leaf, *, interpret):
+    n, (heads, p, ns) = at.shape[0], leaf.shape[1:]
     step = _heads_a_step(heads, UPDATE_HEADS)
-    a_row = lambda r, g: (r, 0, 0)
-    y, state = pl.pallas_call(
+    a_row = lambda r, g, at, begins: (r, 0, 0)
+    of_heads = lambda r, g, at, begins: (r, 0, g)
+    in_slot = lambda r, g, at, begins: (at[r], g, 0, 0)
+    y, leaf = pl.pallas_call(
         _update_body,
-        grid=(n, heads // step),
-        in_specs=[pl.BlockSpec((1, 1, step * p), lambda r, g: (r, 0, g)),
-                  pl.BlockSpec((1, step, ns), lambda r, g: (r, g, 0)),
-                  pl.BlockSpec((1, 1, ns), a_row), pl.BlockSpec((1, 1, ns), a_row),
-                  pl.BlockSpec((1, step, p, ns), lambda r, g: (r, g, 0, 0))],
-        out_specs=[pl.BlockSpec((1, 1, step * p), lambda r, g: (r, 0, g)),
-                   pl.BlockSpec((1, step, p, ns), lambda r, g: (r, g, 0, 0))],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n, heads // step),
+            in_specs=[pl.BlockSpec((1, 1, step * p), of_heads),
+                      pl.BlockSpec((1, step, ns), lambda r, g, at, begins: (r, g, 0)),
+                      pl.BlockSpec((1, 1, ns), a_row), pl.BlockSpec((1, 1, ns), a_row),
+                      pl.BlockSpec((1, step, p, ns), in_slot)],
+            out_specs=[pl.BlockSpec((1, 1, step * p), of_heads),
+                       pl.BlockSpec((1, step, p, ns), in_slot)]),
         out_shape=[jax.ShapeDtypeStruct((n, 1, heads * p), jnp.float32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={4: 1},  # a row's matrices, in place
+                   jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)],
+        input_output_aliases={6: 1},  # the leaf, in place: a row's slot alone is read and written
         compiler_params=CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="ssd_update",
-    )(dtx.reshape(n, 1, heads * p), jnp.broadcast_to(decay[..., None], (n, heads, ns)),
-      b[:, None], c[:, None], state)
-    return y.reshape(n, heads, p), state
+    )(at, begins, dtx.reshape(n, 1, heads * p), jnp.broadcast_to(decay[..., None], (n, heads, ns)),
+      b[:, None], c[:, None], leaf)
+    return y.reshape(n, heads, p), leaf
 
 
 # ------------------------------------------------------------ one chunk's algebra
@@ -164,14 +218,15 @@ def _causal(c: int):
 
 
 # ----------------------------------------------------------------- the chunked scan
-def ssd_scan(x, dt, A, B, C, D, state, n_tokens, row=None, col=None):
+def ssd_scan(x, dt, A, B, C, D, leaf, at, begins, n_tokens, row=None, col=None):
     """The chunked scan over a step's tokens.  x ``[b, s, H, P]``, dt ``[b, s,
     H]`` float32 (after its softplus), A, D ``[H]``, B, C ``[b, s, Ns]``, ``[b,
     s]`` = ``[N, T]`` (``row`` None) or the compacted ``[1, S]`` (``row``,
-    ``col`` ``[1, S]``); state ``[N, H, P, Ns]`` float32, each row's carried
-    matrices (zeros for a sequence that begins); n_tokens ``[N]``.  Returns (y
-    ``[b, s, H, P]`` in x's dtype, the rows' new states).  A row with no token
-    keeps its state."""
+    ``col`` ``[1, S]``); leaf ``[slots, H, P, Ns]`` float32, the carried state
+    whole, at ``[N]`` the rows' slots, begins ``[N]`` bool (a sequence that
+    begins walks from zeros whatever its slot holds); n_tokens ``[N]``.
+    Returns (y ``[b, s, H, P]`` in x's dtype, leaf).  A row with no token
+    writes no slot."""
     heads = x.shape[2]
     table, laid, back, chunks = lay_on_chunk_edges(n_tokens, x.shape[:2], row, col, CHUNK)
     dt = dt.astype(jnp.float32)
@@ -179,10 +234,17 @@ def ssd_scan(x, dt, A, B, C, D, state, n_tokens, row=None, col=None):
     ba, ca = laid(B[:, :, None])[0], laid(C[:, :, None])[0]  # [chunks C, Ns]
     dta = laid(dt[..., None])[..., 0].reshape(heads, chunks, CHUNK)  # 0 where no token sits
     la = jnp.cumsum(dta * A.astype(jnp.float32)[:, None, None], axis=-1)
-    walk = _walk_kernel if _pallas.use_pallas() else _walk_scan
     d = jnp.broadcast_to(D.astype(jnp.float32)[:, None, None], la.shape)
-    y, state = walk(table, xa, ba, ca, (la, dta, d), state.astype(jnp.float32))
-    return back(jnp.moveaxis(y, 0, 1)), state
+    if _pallas.use_pallas():
+        # a chunk's slot in its sequence's place, and whether the sequence begins
+        seq = table[SEQ]
+        table = jnp.concatenate([at.astype(jnp.int32)[seq][None], table[SEQ + 1:],
+                                 begins.astype(jnp.int32)[seq][None]])
+        y, leaf = _walk_pallas(table, xa, ba, ca, (la, dta, d), leaf, interpret=_pallas.INTERPRET)
+    else:
+        walk = lambda rows: _walk_scan(table, xa, ba, ca, (la, dta, d), rows)
+        y, leaf = _by_value(leaf, at, begins, walk, live=n_tokens > 0)
+    return back(jnp.moveaxis(y, 0, 1)), leaf
 
 
 def _walk_scan(table, x, b, c, scalars, state):
@@ -217,9 +279,13 @@ def _scan_body(table_ref, x_ref, b_ref, c_ref, rows_ref, cols_ref, state_ref, y_
     k = pl.program_id(1)
     heads = x_ref.shape[0]
 
-    @pl.when(table_ref[FIRST, k] > 0)
+    @pl.when((table_ref[FIRST, k] > 0) & (table_ref[BEGINS, k] == 0))
     def _load():
         s_ref[...] = state_ref[0]
+
+    @pl.when((table_ref[FIRST, k] > 0) & (table_ref[BEGINS, k] > 0))
+    def _from_zero():  # what the slot held is not read: it may be anything
+        s_ref[...] = jnp.zeros(s_ref.shape, s_ref.dtype)
 
     @pl.when(table_ref[LIVE, k] > 0)
     def _compute():
@@ -246,7 +312,9 @@ def _scan_body(table_ref, x_ref, b_ref, c_ref, rows_ref, cols_ref, state_ref, y_
 
 # jitted for its trace cache: every chunk program of a cell traces the kernel once
 @functools.partial(jax.jit, static_argnames=("interpret", ), inline=True)
-def _walk_pallas(table, x, b, c, scalars, state, *, interpret):
+def _walk_pallas(table, x, b, c, scalars, leaf, *, interpret):
+    """``table`` ``[5, chunks]``: ``SEQ`` holds each chunk's SLOT of ``leaf``, ``BEGINS`` whether
+    its sequence begins."""
     heads, p = x.shape[0], x.shape[-1]
     chunks, ns = table.shape[1], b.shape[-1]
     size = scalars[0].shape[-1]  # a chunk's positions
@@ -257,7 +325,7 @@ def _walk_pallas(table, x, b, c, scalars, state, *, interpret):
     of_heads = lambda g, k, table: (g, k, 0)
     of_chunk = lambda g, k, table: (k, 0)
     of_both = lambda g, k, table: (g, k, 0, 0)
-    seq_state = lambda g, k, table: (table[SEQ, k], g, 0, 0)
+    in_slot = lambda g, k, table: (table[SEQ, k], g, 0, 0)
     return pl.pallas_call(
         _scan_body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -266,18 +334,14 @@ def _walk_pallas(table, x, b, c, scalars, state, *, interpret):
                       pl.BlockSpec((size, ns), of_chunk), pl.BlockSpec((size, ns), of_chunk),
                       pl.BlockSpec((1, 1, 3 * step, size), of_both),
                       pl.BlockSpec((1, 1, size, 3 * step), of_both),
-                      pl.BlockSpec((1, step, p, ns), seq_state)],
+                      pl.BlockSpec((1, step, p, ns), in_slot)],
             out_specs=[pl.BlockSpec((step, size, p), of_heads),
-                       pl.BlockSpec((1, step, p, ns), seq_state)],
+                       pl.BlockSpec((1, step, p, ns), in_slot)],
             scratch_shapes=[pltpu.VMEM((step, p, ns), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={6: 1},  # the carried states, in place: a row with no chunk keeps its own
+                   jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)],
+        input_output_aliases={6: 1},  # the leaf, in place: a slot no chunk names is never touched
         compiler_params=CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="ssd_scan",
-    )(table, x, b, c, rows, jnp.moveaxis(rows, 2, 3), state)
-
-
-def _walk_kernel(table, x, b, c, scalars, state):
-    return _walk_pallas(table, x, b, c, scalars, state, interpret=_pallas.INTERPRET)
+    )(table, x, b, c, rows, jnp.moveaxis(rows, 2, 3), leaf)

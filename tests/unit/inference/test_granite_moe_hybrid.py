@@ -84,7 +84,19 @@ FORWARD = jax.jit(functools.partial(family.forward_paged, CFG),
                   static_argnames=("block_size", "live_token_bound"))
 
 
-def step(params, cache, rows, t, bound=None):
+@pytest.fixture(params=["numpy", "kernels"])
+def forward(request, monkeypatch):
+    """The jitted forward in both forms: ``jax.numpy`` (the module's one) and the
+    Pallas kernels interpreted (its own trace: the form is read as it is traced)."""
+    if request.param == "numpy":
+        return FORWARD
+    from deepspeed_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, "INTERPRET", True)
+    return jax.jit(functools.partial(family.forward_paged, CFG),
+                   static_argnames=("block_size", "live_token_bound"))
+
+
+def step(params, cache, rows, t, bound=None, forward=FORWARD):
     """One forward over ``rows`` = [(tokens, start_pos, blocks, slot)]; returns
     (logits at each row's last token, cache).  Rows are padded to a power of two."""
     n = 1 << (len(rows) - 1).bit_length()
@@ -94,7 +106,7 @@ def step(params, cache, rows, t, bound=None):
     for i, (toks, start, blocks, slot) in enumerate(rows):
         tokens[i, :len(toks)], counts[i], starts[i] = toks, len(toks), start
         tables[i, :len(blocks)], tables[i, -1] = blocks, slot
-    logits, cache = FORWARD(params, jnp.asarray(tokens), jnp.asarray(counts), jnp.asarray(starts),
+    logits, cache = forward(params, jnp.asarray(tokens), jnp.asarray(counts), jnp.asarray(starts),
                             jnp.asarray(tables), cache, block_size=BS, live_token_bound=bound)
     return [np.asarray(logits[i, len(r[0]) - 1], np.float32) for i, r in enumerate(rows)], cache
 
@@ -171,6 +183,53 @@ def test_a_compacted_mixed_step_gives_each_sequence_what_it_gets_alone(params):
     for leaf in ("conv", "ssm"):  # the slot no row named is untouched
         np.testing.assert_array_equal(np.asarray(after[STATE][leaf][:, 2]),
                                       np.asarray(cache[STATE][leaf][:, 2]))
+
+
+def test_rows_find_their_own_slots_in_whatever_order_the_slots_lie(params, forward):
+    """ISSUE 53: the matrices go to the kernels by reference, a row's slot an
+    index.  Two sequences whose slots (3, then 1) are neither their rows nor in
+    row order, beside a dead row on the trash slot: a chunk each in one padded
+    step, then decode steps of both; each reads the reference's logits, and the
+    slots no row names hold what they held."""
+    seqs = [(ids_of(20, 70 + 3), list(range(0, 20)), 3), (ids_of(21, 9 + 3), list(range(20, 24)), 1)]
+    done = [70, 9]
+    cache = fresh_cache()
+    cache[STATE] = {leaf: rows.at[:, (0, 2)].set(3.0) for leaf, rows in cache[STATE].items()}
+    before = cache[STATE]
+    chunks = [(ids[:n], 0, blocks, slot) for (ids, blocks, slot), n in zip(seqs, done)]
+    got, cache = step(params, cache, chunks + [([], 0, [], SLOTS)], t=256, forward=forward)
+    for _ in range(3):
+        for (ids, _, _), n, row in zip(seqs, done, got):
+            close(row, want(params, ids, [n - 1])[0])
+        got, cache = step(params, cache, [(ids[n:n + 1], n, blocks, slot)
+                                          for (ids, blocks, slot), n in zip(seqs, done)], t=1,
+                          forward=forward)
+        done = [n + 1 for n in done]
+    for leaf in ("conv", "ssm"):
+        np.testing.assert_array_equal(np.asarray(cache[STATE][leaf][:, (0, 2)]),
+                                      np.asarray(before[leaf][:, (0, 2)]))
+
+
+def test_a_sequence_that_begins_reads_nothing_its_slot_was_left_with(params, forward):
+    """A slot is never zeroed: the sequence that takes it over begins
+    (``start_pos == 0``) over what the last one left, here the last one's
+    matrices and then NaNs, and is served as over a fresh cache, in a chunk
+    pass and in the decode steps that follow."""
+    first, second = ids_of(22, 90), ids_of(23, 40 + 2)
+    blocks, slot = list(range(5, 30)), 2
+    _, used = step(params, fresh_cache(), [(first, 0, blocks, slot)], t=256)
+    assert np.abs(np.asarray(used[STATE]["ssm"][:, slot])).max() > 0.1
+    spoiled = dict(used)
+    spoiled[STATE] = {leaf: rows.at[:, slot].set(jnp.nan) for leaf, rows in used[STATE].items()}
+    for cache in (used, spoiled):
+        at = 40
+        (got, ), cache = step(params, cache, [(second[:at], 0, blocks, slot)], t=256, forward=forward)
+        for _ in range(2):
+            close(got, want(params, second, [at - 1])[0])
+            (got, ), cache = step(params, cache, [(second[at:at + 1], at, blocks, slot)], t=1,
+                                  forward=forward)
+            at += 1
+        close(got, want(params, second, [at - 1])[0])
 
 
 # ------------------------------------------------- readings that must not pass

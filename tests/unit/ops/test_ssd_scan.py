@@ -4,7 +4,10 @@ granite_moe_hybrid.py selective_scan``, which shares no algebra with it): the
 Pallas kernels in interpret mode and the ``jax.numpy`` forms, sequences as rows
 of a padded ``[N, T]`` and compacted onto one flat axis, lengths that are and
 are not multiples of the chunk, with and without a carried state, decays near 0
-and near 1, a scan continued from its state, and the one-token update."""
+and near 1, a scan continued from its state, and the one-token update; and the
+state BY REFERENCE (ISSUE 53): the rows' slots of a leaf of more slots than
+rows, in any order, a sequence that begins over whatever its slot holds, dead
+rows on one trash slot, and every slot no live row names left bit for bit."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -68,9 +71,17 @@ def flat(seqs, counts, slots):
     return arrays, at(row), at(col)
 
 
-def scan(arrays, state, counts, row=None, col=None):
+def in_order(n):
+    """Row r in slot r of a leaf of as many slots as rows, none beginning: the rows by value."""
+    return jnp.arange(n, dtype=jnp.int32), jnp.zeros(n, bool)
+
+
+def scan(arrays, state, counts, row=None, col=None, at=None, begins=None):
     x, dt, b, c = arrays
+    own = in_order(len(counts))
     return ssd_scan(x, dt, jnp.asarray(A), b, c, jnp.asarray(D), jnp.asarray(state),
+                    own[0] if at is None else jnp.asarray(at, jnp.int32),
+                    own[1] if begins is None else jnp.asarray(begins),
                     jnp.asarray(counts, jnp.int32), row, col)
 
 
@@ -121,7 +132,7 @@ def test_the_one_token_update_is_a_scan_of_one(form):
     state = rng.normal(size=(n, H, P, NS)).astype(np.float32)
     x, dt, b, c = padded(seqs, (1, ) * n, 1)
     y, last = ssd_update(x[:, 0], dt[:, 0], jnp.asarray(A), b[:, 0], c[:, 0], jnp.asarray(D),
-                         jnp.asarray(state))
+                         jnp.asarray(state), *in_order(n))
     scanned, scanned_last = scan((x, dt, b, c), state, (1, ) * n)
     near(np.asarray(y), np.asarray(scanned[:, 0]))
     near(np.asarray(last), np.asarray(scanned_last))
@@ -129,6 +140,60 @@ def test_the_one_token_update_is_a_scan_of_one(form):
         want, want_last = token_by_token(seq, state[i])
         near(np.asarray(y[i]), want[0])
         near(np.asarray(last[i]), want_last)
+
+
+SLOTS = 11  # of the flat leaf: more than a step's rows; the last is the dead rows' trash slot
+BY_REFERENCE = {  # counts (the update: 1 a row, 0 a dead row), the rows' slots, which rows begin
+    "scrambled-slots": ((70, 5, 1, 130), (7, 2, 9, 0), (False, ) * 4),
+    "begins-over-garbage": ((70, 5, 1, 130), (3, 8, 1, 6), (True, False, True, True)),
+    "dead-rows-share-the-trash-slot": ((0, 70, 0, 9), (SLOTS - 1, 4, SLOTS - 1, 5),
+                                       (True, False, True, False)),
+}
+
+
+@pytest.mark.parametrize("case", list(BY_REFERENCE))
+@pytest.mark.parametrize("kernel", ["update", "scan-padded", "scan-flat"])
+def test_the_state_is_read_and_written_in_the_rows_slots_alone(form, kernel, case):
+    """The leaf whole, the rows' slots and their flags equal the rows by value
+    (slot r for row r, zeros where a sequence begins: what the kernels took
+    before ISSUE 53) in what is computed and in what the rows' slots hold after;
+    NaNs in the slot of a sequence that begins reach nothing; two dead rows on
+    the one trash slot disturb no other (a dead row of a scan walks no chunk and
+    writes not even the trash slot); every slot no live row names is bit for bit
+    what it was."""
+    counts, at, begins = BY_REFERENCE[case]
+    rng = np.random.default_rng(len(case))
+    leaf = rng.normal(size=(SLOTS, H, P, NS)).astype(np.float32)
+    for slot, fresh in zip(at, begins):
+        if fresh:
+            leaf[slot] = np.nan
+    rows = np.where(np.asarray(begins)[:, None, None, None], np.float32(0), leaf[list(at)])
+    live = [i for i, c in enumerate(counts) if c > 0]
+    if kernel == "update":
+        seqs = [draw(rng, 1) for _ in counts]
+        x, dt, b, c = (a[:, 0] for a in padded(seqs, (1, ) * len(counts), 1))
+        update = lambda state, at, begins: ssd_update(
+            x, dt, jnp.asarray(A), b, c, jnp.asarray(D), jnp.asarray(state), at, begins)
+        y, after = update(leaf, jnp.asarray(at, jnp.int32), jnp.asarray(begins))
+        want, want_rows = update(rows, *in_order(len(counts)))
+        written = set(at)  # a dead row updates the trash slot, as a padded bucket's always did
+    else:
+        seqs = [draw(rng, max(c, 1)) for c in counts]
+        if kernel == "scan-padded":
+            arrays, where = padded(seqs, counts, max(counts)), {}
+        else:
+            arrays, row, col = flat(seqs, counts, sum(counts) + 11)
+            where = {"row": row, "col": col}
+        y, after = scan(arrays, leaf, counts, at=at, begins=begins, **where)
+        want, want_rows = scan(arrays, rows, counts, **where)
+        written = {at[i] for i in live}
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
+    assert np.isfinite(np.asarray(y)).all()
+    after = np.asarray(after)
+    for i in live:
+        np.testing.assert_array_equal(after[at[i]], np.asarray(want_rows[i]))
+    for slot in set(range(SLOTS)) - written:
+        np.testing.assert_array_equal(after[slot], leaf[slot])
 
 
 def test_bfloat16_operands_keep_the_state_and_the_decays_in_float32(form):

@@ -287,44 +287,54 @@ def test_the_gated_delta_scan_compiles_at_the_cells_shapes(chip, budget, n, hk):
     assert memory.alias_size_in_bytes == n * hv * 128 * 128 * 4 and memory.temp_size_in_bytes == 0
 
 
+SSM_LEAF = (9 * 33, 128, 64, 128)  # serve.ssm-chat-burst's ``ssm`` leaf, flat: nine layers of 33 slots
+SSM_ROW = 128 * 64 * 128 * 4       # one slot of it: a row's float32 matrices of one layer, 4 MB
+
+
 @pytest.mark.parametrize("budget,n", [(512, 32), (1024, 32)], ids=lambda v: str(v))
 def test_the_ssd_scan_compiles_at_the_cells_shapes(chip, budget, n):
     """ISSUE 52: the chunked-scan kernel at Granite 4.0-H's 128 heads of 64 x 128
     (B and C shared by all of them), for a compacted pass of ``budget`` tokens
-    over ``n`` sequences laid on chunk edges: one Mosaic kernel, the carried
-    matrices (134 MB at 32 rows) aliased in and out and not held twice."""
+    over ``n`` sequences laid on chunk edges: one Mosaic kernel.  ISSUE 53: the
+    carried matrices BY REFERENCE: the cell's whole flat leaf (1.25 GB) aliased in
+    and out, a chunk's slot from the prefetched table, and not one row of it (4
+    MB) held beside it: this program's temporaries are x laid out anew (an entry
+    parameter's 64-wide rows in whole lane tiles: twice its bytes, and no part of
+    a step program, where x is made in the kernel's layout) and 0.4 MB of scalars."""
     from deepspeed_tpu.ops.linear_attention import ssd
 
-    heads, p, ns = 128, 64, 128
+    heads, p, ns = SSM_LEAF[1:]
     chunks = ssd.scan_chunks(n, 0, budget)
     t = chunks * ssd.CHUNK
     scalars = (chip((heads, chunks, ssd.CHUNK), jnp.float32), ) * 3
-    avals = (chip((4, chunks), jnp.int32), chip((heads, t, p), jnp.bfloat16),
+    avals = (chip((5, chunks), jnp.int32), chip((heads, t, p), jnp.bfloat16),
              chip((t, ns), jnp.bfloat16), chip((t, ns), jnp.bfloat16), scalars,
-             chip((n, heads, p, ns), jnp.float32))
+             chip(SSM_LEAF, jnp.float32))
     compiled = jax.jit(lambda *a: ssd._walk_pallas(*a, interpret=False),
                        donate_argnums=(5, )).lower(*avals).compile()
     assert kernel_calls(compiled.as_text()) == {"ssd_scan": 1}
     memory = compiled.memory_analysis()
-    state = n * heads * p * ns * 4
-    assert memory.alias_size_in_bytes == state and memory.temp_size_in_bytes < state
+    assert memory.alias_size_in_bytes == SSM_LEAF[0] * SSM_ROW
+    assert memory.temp_size_in_bytes - 2 * heads * t * p * 2 < SSM_ROW
 
 
 def test_the_ssd_update_compiles_at_the_cells_shapes(chip):
     """ISSUE 52: the one-token update at a decode step of 32 rows: one Mosaic
-    kernel over (row, 32 heads), the rows' matrices aliased in and out, nothing
-    else held."""
+    kernel over (row, 32 heads).  ISSUE 53: the rows' matrices BY REFERENCE: the
+    whole flat leaf aliased in and out, a row's slot from the prefetched ``at``,
+    under a row's 4 MB held beside it (the decays along the state's lanes: 2 MB)."""
     from deepspeed_tpu.ops.linear_attention import ssd
 
-    n, heads, p, ns = 32, 128, 64, 128
-    avals = (chip((n, heads, p), jnp.bfloat16), chip((n, heads), jnp.float32),
-             chip((n, ns), jnp.bfloat16), chip((n, ns), jnp.bfloat16),
-             chip((n, heads, p, ns), jnp.float32))
+    n, (heads, p, ns) = 32, SSM_LEAF[1:]
+    avals = (chip((n, ), jnp.int32), chip((n, ), jnp.int32), chip((n, heads, p), jnp.bfloat16),
+             chip((n, heads), jnp.float32), chip((n, ns), jnp.bfloat16),
+             chip((n, ns), jnp.bfloat16), chip(SSM_LEAF, jnp.float32))
     compiled = jax.jit(lambda *a: ssd._update_pallas(*a, interpret=False),
-                       donate_argnums=(4, )).lower(*avals).compile()
+                       donate_argnums=(6, )).lower(*avals).compile()
     assert kernel_calls(compiled.as_text()) == {"ssd_update": 1}
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes == n * heads * p * ns * 4 and memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes == SSM_LEAF[0] * SSM_ROW
+    assert memory.temp_size_in_bytes < SSM_ROW
 
 
 @pytest.mark.parametrize("n,t,s", [(4, 1024, 1024), (2, 512, 1024), (8, 1, None)],

@@ -57,25 +57,38 @@ def test_compacted_ragged_forward_compiles_and_holds_no_tensor_more_than_padded(
     assert held[256] - held[None] < 256 * KV * DH * 2
 
 
-def pool_shaped_results(compiled_text, pool_shape):
-    """``(opcode, shape)`` of every instruction of an optimised HLO text with a
-    result shaped like the pool: whole layers of it (one, or all L) with the
-    head dimension last, however the dimensions before are folded
-    (``[L,NB,KV,bs,Dh]``, ``[NB,KV,bs,Dh]``, ``[L*NB*KV*bs,Dh]``).  Left out:
-    the instructions that only hand a buffer on."""
-    layer = int(np.prod(pool_shape[1:]))
-    found = []
+def results(compiled_text):
+    """``(opcode, result types, [dims of each result])`` of every instruction of
+    an optimised HLO text but those that only hand a buffer on."""
     for line in compiled_text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
         if m is None or m.group(2) in ("parameter", "get-tuple-element", "tuple", "bitcast",
                                        "while", "conditional", "call"):
             continue
-        for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1)):
-            dims = [int(d) for d in dims.split(",")]
-            if dims[-1] == pool_shape[-1] and int(np.prod(dims)) % layer == 0:
-                found.append((m.group(2), m.group(1)))
-                break
-    return found
+        yield m.group(2), m.group(1), [[int(d) for d in dims.split(",")]
+                                       for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))]
+
+
+def pool_shaped_results(compiled_text, pool_shape):
+    """``(opcode, shape)`` of every instruction of an optimised HLO text with a
+    result shaped like the pool: whole layers of it (one, or all L) with the
+    head dimension last, however the dimensions before are folded
+    (``[L,NB,KV,bs,Dh]``, ``[NB,KV,bs,Dh]``, ``[L*NB*KV*bs,Dh]``)."""
+    layer = int(np.prod(pool_shape[1:]))
+    return [(opcode, types) for opcode, types, shapes in results(compiled_text)
+            if any(dims[-1] == pool_shape[-1] and int(np.prod(dims)) % layer == 0 for dims in shapes)]
+
+
+def burst_of(fwd):
+    """``fwd`` inside a two-step scan that carries the cache and picks greedily, as a burst runs it."""
+    def burst(params, kv, tokens, n_tokens, start_pos, tables):
+        def body(carry, _):
+            kv, tok, start = carry
+            logits, kv = fwd(params, kv, tok, n_tokens, start, tables)
+            return (kv, jnp.argmax(logits, axis=-1).astype(jnp.int32), start + 1), tok
+        (kv, _, _), toks = jax.lax.scan(body, (kv, tokens, start_pos), None, length=2)
+        return toks, kv
+    return burst
 
 
 def olmoe_shapes(chip, layers):
@@ -145,6 +158,22 @@ def glm_moe_dsa_shapes(chip, layers):
             on_chip(jax.eval_shape(lambda: glm_moe_dsa.init_paged_cache(cfg, 1024, 128))))
 
 
+def granite_shapes(chip, layers):
+    """Granite 4.0-H-Small as ``serve.ssm-chat-burst`` holds it (36 of 72 experts,
+    half the vocabulary; ``layers`` = 10: one period, mamba x 5, attention, mamba
+    x 4, three scans): a pool of the one attention layer ``[1, 1024, 8, 128, 128]``
+    and the Mamba-2 layers' state, 32 slots and a trash slot: the shift ``[9, 33,
+    3, 8448]`` and the float32 matrices ``[9, 33, 128, 64, 128]`` (1.25 GB)."""
+    from deepspeed_tpu.models import granite_moe_hybrid
+    cfg = granite_moe_hybrid.GraniteMoeHybridConfig(vocab_size=50176, num_layers=layers,
+                                                    num_local_experts=36)
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, jnp.bfloat16), tree)
+    kv = jax.eval_shape(lambda: granite_moe_hybrid.init_paged_cache(cfg, 1024, 128))
+    return (granite_moe_hybrid, cfg,
+            on_chip(jax.eval_shape(lambda: granite_moe_hybrid.init_params(cfg, jax.random.PRNGKey(0)))),
+            jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype), kv))
+
+
 def mistral_module_and_shapes(chip, layers):
     from deepspeed_tpu.models import mistral
     return (mistral, ) + mistral_shapes(chip, layers)
@@ -212,17 +241,9 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
         return module.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
                                     block_size=128, live_token_bound=bound)
 
-    def burst(params, kv, tokens, n_tokens, start_pos, tables):
-        def body(carry, _):
-            kv, tok, start = carry
-            logits, kv = fwd(params, kv, tok, n_tokens, start, tables)
-            return (kv, jnp.argmax(logits, axis=-1).astype(jnp.int32), start + 1), tok
-        (kv, _, _), toks = jax.lax.scan(body, (kv, tokens, start_pos), None, length=2)
-        return toks, kv
-
     state = kv.get("state") if isinstance(kv, dict) else None  # a row's slot: one more column
     ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, 8 + (state is not None)))]
-    compiled = jax.jit(burst if in_a_burst else fwd,
+    compiled = jax.jit(burst_of(fwd) if in_a_burst else fwd,
                        donate_argnums=(1, )).lower(params, kv, *ints).compile()
     text = compiled.as_text()
     calls = kernel_calls(text)
@@ -236,3 +257,39 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
         assert calls.get("gdn_scan", 0) == (3 if t > 1 else 0), calls
     pool_bytes = sum(int(np.prod(leaf.shape)) * 2 for leaf in leaves)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.parametrize("n,t,bound,kernel,in_a_burst", [
+    (32, 1, 512, "ssd_update", False), (32, 256, 512, "ssd_scan", False),
+    (32, 1, None, "ssd_update", True)], ids=["decode", "compacted", "burst"])
+def test_a_state_leaf_by_reference_is_moved_by_its_kernels_alone(chip, n, t, bound, kernel,
+                                                                 in_a_burst):
+    """ISSUE 53: Granite's ``ssm`` leaf goes to ``mix`` by reference.  In the
+    cell's decode step (32 rows), its compacted chunk pass (512 slots) and a
+    burst's body inside its loop no operation produces the rows' matrices
+    ``f32[32,128,64,128]`` (the slot read, the select for "begins" and the 134
+    MB they cost a layer are gone) and none
+    but the Mosaic kernel, once a Mamba-2 scan body, produces the leaf's shape
+    (no write back over the flat leaf ``f32[297,128,64,128]``, however folded);
+    the leaf is aliased in and out and the program's temporaries are a fraction
+    of it."""
+    module, cfg, params, kv = granite_shapes(chip, layers=10)
+    ssm = kv["state"]["ssm"]
+
+    def fwd(params, kv, tokens, n_tokens, start_pos, tables):
+        return module.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
+                                    block_size=128, live_token_bound=bound, last_rows=True)
+
+    ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, 20 + 1))]
+    compiled = jax.jit(burst_of(fwd) if in_a_burst else fwd,
+                       donate_argnums=(1, )).lower(params, kv, *ints).compile()
+    text = compiled.as_text()
+    calls = kernel_calls(text)
+    assert calls[kernel] == 2 and calls["paged_attention"] == calls["kv_write"] == 1, calls
+    rows = list((n, ) + ssm.shape[2:])
+    assert [opcode for opcode, _, shapes in results(text) if rows in shapes] == []
+    whole = pool_shaped_results(text, (1, ) + ssm.shape)
+    assert [r[0] for r in whole] == ["custom-call"] * 2, whole
+    memory = compiled.memory_analysis()
+    leaf_bytes = int(np.prod(ssm.shape)) * 4
+    assert memory.alias_size_in_bytes >= leaf_bytes and memory.temp_size_in_bytes < leaf_bytes // 4
