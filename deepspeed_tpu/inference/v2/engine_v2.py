@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec
 
 from ...compat import shard_map
 from ...models.transformer import STATE, TALLY, flat_slots, paged_step_slots
-from ...monitor import compile_events
+from ...monitor import compile_events, program_scopes
 from ...monitor.perf import PHASES, CompileLedger, StepPhaseProfiler
 from ...monitor.tracing import RequestTracer
 from ...parallel.mesh import TENSOR_AXIS, MeshTopology
@@ -489,9 +489,22 @@ class InferenceEngineV2:
             except Exception:
                 # AOT lowering can fail where plain jit works (backend
                 # quirks); serving must degrade to the lazy wrapper, not die
-                self._fwd_cache[key] = fwd = self._build_fwd_jit(n, t, b)
+                fwd = self._build_fwd_jit(n, t, b)
+                self._fwd_cache[key] = self._until_first_call(key, fwd.__name__, fwd)
                 self.ledger.record("fwd", key, name=fwd.__name__)
         return self._fwd_cache[key]
+
+    def _until_first_call(self, key, name: str, fn):
+        """A lazily jitted program for ``_fwd_cache[key]``: its first call hands
+        the ledger a way to compile it again at that call's shapes (for
+        ``program_scopes()``) and puts the bare program in the slot, so no later
+        call passes through here."""
+        def seen(fn, args):
+            program = compile_events.compile_later(fn, args)
+            if program is not None:
+                self._fwd_cache[key] = fn
+                self.ledger.built(name, program)
+        return program_scopes.FirstCall(fn, seen)
 
     def _aot_compile_fwd(self, n: int, t: int, b: int, *,
                          prewarmed: bool = True) -> None:
@@ -527,7 +540,7 @@ class InferenceEngineV2:
             jax.tree_util.tree_map(abstract, self.kv),
             ints((n, t)), ints((n, )), ints((n, )), ints((n, self._table_columns(b)))).compile()
         self.ledger.record("fwd", key, wall_s=time.perf_counter() - t0,  # dslint: disable=raw-clock-in-serving  # same stopwatch as t0 above — host compile duration, never the engine clock
-                           prewarmed=prewarmed, name=fwd.__name__)
+                           prewarmed=prewarmed, name=fwd.__name__, program=self._fwd_cache[key])
 
     def _selected_spans(self, spans):
         """Each launched row's ``(start_pos, n_tokens)`` for the ``dsa_*`` counters:
@@ -558,7 +571,7 @@ class InferenceEngineV2:
                 fn = jax.jit(cow_copy, donate_argnums=(0, ), out_shardings=kv_sh)
             else:
                 fn = jax.jit(cow_copy, donate_argnums=(0, ))
-            self._fwd_cache["cow_copy"] = fn
+            self._fwd_cache["cow_copy"] = fn = self._until_first_call("cow_copy", "cow_copy", fn)
             self.ledger.record("cow_copy", "cow_copy")
         self.counters.dispatches += 1
         self.counters.uploads += 1
@@ -996,6 +1009,21 @@ class InferenceEngineV2:
         snap["compile_ledger"] = self.ledger.snapshot()
         return snap
 
+    def program_scopes(self, name: Optional[str] = None) -> Dict[str, Dict[str, tuple]]:
+        """Which scope each operation of this engine's compiled programs
+        belongs to: ``{program: {instruction: (scope, ...)}}`` for every program
+        ``health()["perf"]["compile_ledger"]`` counts (``name``: that one),
+        outermost scope first, the names those of
+        ``monitor.program_scopes.SCOPES``.  A device trace of this server names
+        an operation by its instruction (``%fusion.735``) inside a program
+        event ``jit_<program>``: laid over it, the table says what the busy
+        time was spent on (``chipbench/reduce/scopes.py`` does that).  It reads
+        the optimized text of the executables the engine holds and compiles a
+        lazily jitted program again at the shapes of its first call (a hit of
+        JAX's caches), once a program: seconds, so an operator's call and never
+        the serve loop's; ``health()`` does not include it."""
+        return self.ledger.program_scopes(name)
+
     def _compiled_step_pick(self, n: int, greedy: bool):
         key = ("pick", n, greedy, self.config.temperature, self.config.top_k,
                self.config.top_p)
@@ -1007,23 +1035,27 @@ class InferenceEngineV2:
             def pick(logits, rng):
                 # the step's forward returned each row's last live logits alone
                 # (_build_fwd_jit asks for last_rows): [n, 1, V], nothing to gather
-                row = logits[:, 0]
-                if greedy:
-                    return jnp.argmax(row, axis=-1).astype(jnp.int32), rng
-                return _sample(row, rng, temperature=temperature, top_k=top_k, top_p=top_p)
+                with jax.named_scope("pick"):
+                    row = logits[:, 0]
+                    if greedy:
+                        return jnp.argmax(row, axis=-1).astype(jnp.int32), rng
+                    return _sample(row, rng, temperature=temperature, top_k=top_k, top_p=top_p)
 
             pick.__name__ = f"pick_n{n}" + ("" if greedy else "_sampled")
-            self._fwd_cache[key] = jax.jit(pick)
+            self._fwd_cache[key] = self._until_first_call(key, pick.__name__, jax.jit(pick))
             self.ledger.record("pick", key, name=pick.__name__)
         return self._fwd_cache[key]
 
     # ------------------------------------------------------------ decode burst
-    def _compiled_burst(self, n: int, k: int, sample_cfg=None, eos: int = -1):
+    def _compiled_burst(self, n: int, k: int, b: int, sample_cfg=None, eos: int = -1):
         """``sample_cfg``: None => greedy; (temperature, top_k, top_p) =>
         on-device sampling with the rng carried through the scan.  ``eos`` >= 0
         makes decode eos-aware: a finished row freezes (re-emits its token) and
-        its done flag streams out alongside the tokens."""
-        key = ("burst", n, k, sample_cfg, eos)
+        its done flag streams out alongside the tokens.  ``b``, the table's
+        width, is a shape of the program and so part of its key and name, as a
+        forward bucket's is: one name, one executable (``program_scopes()`` and
+        the device trace tell programs apart by name)."""
+        key = ("burst", n, k, b, sample_cfg, eos)
         if key not in self._fwd_cache:
             from ..engine import _sample
             model, cfg, bs = self.model, self.model_config, self.block_size
@@ -1081,7 +1113,8 @@ class InferenceEngineV2:
                     # scan is the ENGINE rng, advanced by _sample exactly as the
                     # stepwise pick advances it — burst and per-step decode
                     # sample identical tokens for the same seed
-                    nxt, rng = pick(logits[:, 0], rng)
+                    with jax.named_scope("pick"):
+                        nxt, rng = pick(logits[:, 0], rng)
                     # finished rows freeze: re-emit the last token (the pool
                     # keeps absorbing writes into pre-allocated slots; the host
                     # truncates at the first done flag)
@@ -1100,9 +1133,9 @@ class InferenceEngineV2:
                 burst = self._shard_mapped(
                     burst, (self._kv_specs, PartitionSpec(), PartitionSpec()))
             # sampled and eos-aware bursts are other programs: other names
-            burst.__name__ = (f"burst_n{n}_k{k}" + ("_sampled" if sampling else "")
+            burst.__name__ = (f"burst_n{n}_k{k}_b{b}" + ("_sampled" if sampling else "")
                               + (f"_eos{eos}" if eos >= 0 else ""))
-            self._fwd_cache[key] = jax.jit(burst, donate_argnums=(1, ))  # dslint: disable=donation-after-use  # call-site contract: decode_burst() reassigns self.kv from the result in the same statement
+            self._fwd_cache[key] = self._until_first_call(key, burst.__name__, jax.jit(burst, donate_argnums=(1, )))  # dslint: disable=donation-after-use  # call-site contract: decode_burst() reassigns self.kv from the result in the same statement
             self.ledger.record("burst", key, name=burst.__name__)
         return self._fwd_cache[key]
 
@@ -1128,7 +1161,7 @@ class InferenceEngineV2:
         sample_cfg = None if greedy else (self.config.temperature, self.config.top_k,
                                           self.config.top_p)
         eos = -1 if eos_token_id is None else int(eos_token_id)
-        burst = self._compiled_burst(n, k, sample_cfg=sample_cfg, eos=eos)
+        burst = self._compiled_burst(n, k, b, sample_cfg=sample_cfg, eos=eos)
         done0 = jnp.zeros((n, ), jnp.bool_)
         self.counters.dispatches += 1
         self.counters.uploads += 3
@@ -1303,8 +1336,8 @@ class InferenceEngineV2:
             except Exception:
                 # same degrade as _compiled_fwd: lazy jit when AOT lowering
                 # fails — serving must not die on a backend quirk
-                self._fwd_cache[key] = verify = self._build_spec_verify_jit(
-                    n, k, b, sample_cfg)
+                verify = self._build_spec_verify_jit(n, k, b, sample_cfg)
+                self._fwd_cache[key] = self._until_first_call(key, verify.__name__, verify)
                 self.ledger.record("spec_verify", key, name=verify.__name__)
         return self._fwd_cache[key]
 
@@ -1338,7 +1371,8 @@ class InferenceEngineV2:
             ints((n, )), ints((n, k)), ints((n, )), ints((n, b)),
             rng_aval).compile()
         self.ledger.record("spec_verify", key, wall_s=time.perf_counter() - t0,  # dslint: disable=raw-clock-in-serving  # same stopwatch as t0 above — host compile duration, never the engine clock
-                           prewarmed=prewarmed, name=verify.__name__)
+                           prewarmed=prewarmed, name=verify.__name__,
+                           program=self._fwd_cache[key])
 
     def decode_spec(self, k: int, greedy: bool = True,
                     eos_token_id: Optional[int] = None
@@ -2433,7 +2467,9 @@ class InferenceEngineV2:
             "fault_tolerance": self._fault_tolerance_snapshot(),
             # serving performance observatory (ISSUE 16): per-phase wall-time
             # attribution and compile provenance — the ledger reports even
-            # with the phase profiler off
+            # with the phase profiler off.  Which scope each operation of the
+            # ledger's programs belongs to is program_scopes(): it reads
+            # executables, so it is a call of its own and no part of this
             "perf": self._perf_snapshot(),
             # the recent engine-event history (always on, bounded ring)
             "flight_recorder": self.tracer.recorder.tail(32),

@@ -44,6 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ...monitor.compile_events import compile_later
+
 # host-side placeholder for a sampled-but-not-yet-fetched token.  Negative so
 # it can never collide with a real vocab id; it only ever appears as the LAST
 # entry of a live sequence's token list between dispatch and materialize.
@@ -528,17 +530,19 @@ class DeviceBatchState:
             changed.extend([changed[-1]] * (m_pad - m))
             packed = np.stack(changed)
             sig = (key, m_pad)
+            args = (s.tokens, s.n_tokens, s.start_pos, s.tables, self._device(packed))
             if sig not in self._scatter_shapes:
                 self._scatter_shapes.add(sig)
                 if self._ledger is not None:
-                    self._ledger.record("scatter", sig, name="_scatter_impl")
+                    # one name, an executable a shape: program_scopes() merges their tables
+                    self._ledger.record("scatter", sig, name="_scatter_impl",
+                                        program=compile_later(self._scatter, args))
                 else:
                     self.counters.compiles += 1
             self.counters.uploads += 1
             self.counters.upload_ints += int(packed.size)
             self.counters.dispatches += 1
-            s.tokens, s.n_tokens, s.start_pos, s.tables = self._scatter(
-                s.tokens, s.n_tokens, s.start_pos, s.tables, self._device(packed))
+            s.tokens, s.n_tokens, s.start_pos, s.tables = self._scatter(*args)
         return s
 
     def feed(self, key: Tuple[int, int, int], toks_prev,
@@ -553,16 +557,18 @@ class DeviceBatchState:
         arr[:len(pairs)] = pairs
         arr[len(pairs):] = pairs[-1]  # duplicate writes carry identical values
         sig = (key, int(toks_prev.shape[0]), m_pad)
+        args = (s.tokens, toks_prev, self._device(arr))
         if sig not in self._feed_shapes:
             self._feed_shapes.add(sig)
             if self._ledger is not None:
-                self._ledger.record("feed", sig, name="_feed_impl")
+                self._ledger.record("feed", sig, name="_feed_impl",
+                                    program=compile_later(self._feed, args))
             else:
                 self.counters.compiles += 1
         self.counters.uploads += 1
         self.counters.upload_ints += int(arr.size)
         self.counters.dispatches += 1
-        s.tokens = self._feed(s.tokens, toks_prev, self._device(arr))
+        s.tokens = self._feed(*args)
 
     def forget(self) -> None:
         """Drop every slot (tests / bucket-policy changes)."""

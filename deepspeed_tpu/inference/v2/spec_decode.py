@@ -55,6 +55,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...monitor.compile_events import compile_later
+from ...monitor.program_scopes import FirstCall
+
 
 def spec_k_ladder(k_max: int) -> Tuple[int, ...]:
     """The static draft-length ladder: 1 (the degrade-to-burst floor) then
@@ -273,6 +276,14 @@ class ModelDrafter:
             fn = self._fns[key]
             if self._ledger is not None:
                 self._ledger.record("draft", key, name=draft.__name__)
+
+                def seen(fn, args, name=draft.__name__):
+                    # the first call's shapes, for program_scopes(); then the bare program
+                    program = compile_later(fn, args)
+                    if program is not None:
+                        self._fns[key] = fn
+                        self._ledger.built(name, program)
+                self._fns[key] = fn = FirstCall(fn, seen)
         return fn
 
     # ---------------------------------------------------------------- propose

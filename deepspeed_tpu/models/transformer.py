@@ -310,15 +310,17 @@ def attention_block(params, x, *, n_heads, n_kv_heads, cos, sin, causal=True,
 # ----------------------------------------------------------------- mlp
 def swiglu_mlp(params, x):
     """Llama-style gated MLP: down(silu(gate(x)) * up(x))."""
-    gate = jax.nn.silu(x @ params["w_gate"].astype(x.dtype))
-    up = x @ params["w_up"].astype(x.dtype)
-    return (gate * up) @ params["w_down"].astype(x.dtype)
+    with jax.named_scope("dense_ffn"):
+        gate = jax.nn.silu(x @ params["w_gate"].astype(x.dtype))
+        up = x @ params["w_up"].astype(x.dtype)
+        return (gate * up) @ params["w_down"].astype(x.dtype)
 
 
 def gelu_mlp(params, x):
     """GPT2/BERT-style MLP: fc2(gelu(fc1(x)))."""
-    h = jax.nn.gelu((x @ params["w_fc1"].astype(x.dtype)) + params["b_fc1"].astype(x.dtype), approximate=True)
-    return (h @ params["w_fc2"].astype(x.dtype)) + params["b_fc2"].astype(x.dtype)
+    with jax.named_scope("dense_ffn"):
+        h = jax.nn.gelu((x @ params["w_fc1"].astype(x.dtype)) + params["b_fc1"].astype(x.dtype), approximate=True)
+        return (h @ params["w_fc2"].astype(x.dtype)) + params["b_fc2"].astype(x.dtype)
 
 
 # ------------------------------------------------------- model-family shared
@@ -762,41 +764,52 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
             return jnp.zeros((n, t) + a.shape[2:], a.dtype).at[drop_row, col[0]].set(
                 a[0], mode="drop")
 
-    x = embed(tokens, safe_pos)
+    # The named scopes of this function and of its helpers (``embed``,
+    # ``attn_qkv``, ``kv_write``, ``attn_kernel``, ``layer_finish``, ``mixer_layer``,
+    # ``head``; a family's own inside them) are metadata on the operations and change
+    # nothing that is compiled: ``monitor/program_scopes.py`` reads them off the
+    # executable, so that a device trace's busy time can be told apart by them.
+    with jax.named_scope("embed"):
+        x = embed(tokens, safe_pos)
     # which tiles of the pool this pass writes, the same in every layer (None
     # off the TPU: the scatter's one index per (token, head) writes instead)
     flat_pools = [leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in pool_leaves]
-    plan = write_plan(flat_pools, n_tokens, start_pos, block_tables, t=t, slots=slots)
+    with jax.named_scope("kv_write"):
+        plan = write_plan(flat_pools, n_tokens, start_pos, block_tables, t=t, slots=slots)
 
     def attention_layer(x, pools, lp, l, handed=None):
-        q, *rows, kept = qkv(lp, x, safe_pos)
+        with jax.named_scope("attn_qkv"):
+            q, *rows, kept = qkv(lp, x, safe_pos)
         # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h]
         first = l * num_blocks  # the layer's first row of the flat stack
-        pools = kv_write(pools, rows, first, blk, off, plan)
+        with jax.named_scope("kv_write"):
+            pools = kv_write(pools, rows, first, blk, off, plan)
         # the kernel takes the flat stack as it would one layer's pool (a Pallas
         # operand is materialised, so kpool[l] would be a copy): the table is offset
         facts = dict(block_size=block_size, softmax_scale=softmax_scale, window=window,
                      alibi_slopes=alibi_slopes, value_dim=value_dim)
         attended = pools
-        if selection is not None:
-            # the index-key leaf is scored, not attended: the kernel's pools are the others
-            q_i, w = selection.indexer(lp, kept)
-            if slots is not None:  # the flat tokens as they lie
-                q_i, w = q_i[0], w[0]
-            facts["selection"] = select_keys(
-                q_i, w, pools[index_leaf], block_tables + first, start_pos, n_tokens,
-                topk=selection.topk, chunk=None if slots is None else t)
-            attended = [pool for i, pool in enumerate(pools) if i != index_leaf]
-        kpool, vpool = attended if value_dim is None else (attended[0], None)
-        if slots is None:
-            attn = paged_attention(q, kpool, vpool, block_tables + first, lengths, start_pos,
-                                   n_tokens, **facts)
-        else:  # q as it lies on the flat axis: the kernel finds a sequence's rows by an offset
-            attn = paged_attention_flat(q[0], kpool, vpool, block_tables + first, lengths,
-                                        start_pos, n_tokens, chunk=t, **facts)[None]
-        if not hand_on:
-            return finish(lp, x, kept, attn, live), pools
-        x, handed = finish(lp, x, kept, attn, live, handed)
+        with jax.named_scope("attn_kernel"):
+            if selection is not None:
+                # the index-key leaf is scored, not attended: the kernel's pools are the others
+                q_i, w = selection.indexer(lp, kept)
+                if slots is not None:  # the flat tokens as they lie
+                    q_i, w = q_i[0], w[0]
+                facts["selection"] = select_keys(
+                    q_i, w, pools[index_leaf], block_tables + first, start_pos, n_tokens,
+                    topk=selection.topk, chunk=None if slots is None else t)
+                attended = [pool for i, pool in enumerate(pools) if i != index_leaf]
+            kpool, vpool = attended if value_dim is None else (attended[0], None)
+            if slots is None:
+                attn = paged_attention(q, kpool, vpool, block_tables + first, lengths, start_pos,
+                                       n_tokens, **facts)
+            else:  # q as it lies on the flat axis: the kernel finds a sequence's rows by an offset
+                attn = paged_attention_flat(q[0], kpool, vpool, block_tables + first, lengths,
+                                            start_pos, n_tokens, chunk=t, **facts)[None]
+        with jax.named_scope("layer_finish"):
+            if not hand_on:
+                return finish(lp, x, kept, attn, live), pools
+            x, handed = finish(lp, x, kept, attn, live, handed)
         return x, pools, handed
 
     def mixer_layer(x, flat_states, lp, l):
@@ -809,7 +822,8 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
             kept = [StateRef(leaf, at, start_pos == 0) if ref else
                     jnp.where((start_pos > 0).reshape((-1, ) + (1, ) * (leaf.ndim - 1)), leaf[at], 0)
                     for leaf, ref in zip(flat_states, by_ref)]
-        x, carried = mix(lp, x, taps, live, jax.tree_util.tree_unflatten(state_tree, kept), places)
+        with jax.named_scope("mixer_layer"):  # the family's own (``ssm_mixer``, its FFN) inside it
+            x, carried = mix(lp, x, taps, live, jax.tree_util.tree_unflatten(state_tree, kept), places)
         with jax.named_scope("seq_state"):
             return x, [new if ref else leaf.at[at].set(new.astype(leaf.dtype))
                        for leaf, new, ref in zip(flat_states, state_tree.flatten_up_to(carried), by_ref)]
@@ -859,15 +873,16 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
         cache[STATE] = jax.tree_util.tree_unflatten(state_tree, [
             flat.reshape(leaf.shape) for flat, leaf in zip(pools[len(flat_pools):], state_leaves)])
     by_product = (left_over, ) if hand_on else ()
-    if not last_rows:
-        return (to_padded(head(x)), cache) + by_product
-    if slots is not None:  # the rows lie one after another: row n ends where the first n + 1 counts do
-        last = x[0, jnp.clip(jnp.cumsum(n_tokens) - 1, 0, slots - 1)][:, None]
-    elif t > 1:
-        last = jnp.take_along_axis(x, jnp.maximum(n_tokens - 1, 0)[:, None, None], axis=1)
-    else:  # a decode step: the one slot a row has
-        last = x
-    return (head(last), cache) + by_product
+    with jax.named_scope("head"):
+        if not last_rows:
+            return (to_padded(head(x)), cache) + by_product
+        if slots is not None:  # the rows lie one after another: row n ends where the first n + 1 counts do
+            last = x[0, jnp.clip(jnp.cumsum(n_tokens) - 1, 0, slots - 1)][:, None]
+        elif t > 1:
+            last = jnp.take_along_axis(x, jnp.maximum(n_tokens - 1, 0)[:, None, None], axis=1)
+        else:  # a decode step: the one slot a row has
+            last = x
+        return (head(last), cache) + by_product
 
 
 def paged_step_slots(module, config, kv_cache, q_dtype, tp: int = 1):

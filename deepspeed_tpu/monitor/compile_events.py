@@ -45,6 +45,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+import jax
 from jax import monitoring
 
 TRACE, LOWER, LOAD = "trace", "lower", "load"
@@ -283,6 +284,27 @@ def install() -> Account:
             monitoring.register_event_listener(ACCOUNT.on_event)
             _installed = True
     return ACCOUNT
+
+
+def compile_later(fn: Callable, args) -> Optional[Callable]:
+    """For ``monitor/program_scopes.py``: a thunk that lowers and compiles the
+    jitted ``fn`` again at the shapes, dtypes and placements of ``args`` (read
+    here, so a donated argument may go), which hits JAX's caches where the
+    program ran.  It holds ``fn`` and shapes, never an array.  None where
+    ``args`` are tracers: ``fn`` is being traced into another program (the FLOPs
+    profiler lowers the train step so), which is no dispatch of it."""
+    leaves = jax.tree_util.tree_leaves(args)
+    if any(isinstance(x, jax.core.Tracer) for x in leaves):
+        return None
+
+    def abstract(x):
+        if not hasattr(x, "shape"):
+            return x
+        placed = x.sharding if isinstance(x, jax.Array) and x.committed else None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=placed)
+
+    avals = jax.tree_util.tree_map(abstract, tuple(args))
+    return lambda: fn.lower(*avals).compile()
 
 
 def engine_init(init: Callable) -> Callable:

@@ -27,7 +27,10 @@ the device trace by program name — ``chipbench/``.)
   counter values are unchanged from the pre-ledger ``+= 1`` sites.  Each
   record also carries the program's ``name``: the ``__name__`` the program
   was jitted under, so the device trace's program line (``jit_<name>``), the
-  ledger event and a ``warm_recompile`` flight-recorder line agree.
+  ledger event and a ``warm_recompile`` flight-recorder line agree.  A seam
+  also hands over the executable (or a way to compile it again), which
+  ``monitor/program_scopes.py`` keeps by that name: which scope each operation
+  of a compiled program belongs to, read when asked and never on a step.
 
 Zero-device-sync contract (same as heartbeat/metrics/exposition/ops_server,
 enforced by the dslint whole-file scan): nothing here imports jax or numpy,
@@ -40,6 +43,7 @@ off adds zero clock reads, so FakeClock call counts (and therefore tokens and
 import collections
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from . import program_scopes
 from .tracing import StreamingHistogram
 
 # serve-loop phases, in rough per-iteration order; ``other`` absorbs the
@@ -215,11 +219,15 @@ class CompileLedger:
         return key if isinstance(key, str) else repr(key)
 
     def record(self, site: str, key: Any, *, wall_s: float = 0.0,
-               prewarmed: bool = False, name: Optional[str] = None) -> str:
+               prewarmed: bool = False, name: Optional[str] = None,
+               program: Any = None) -> str:
         """Record one compile at ``site`` for bucket ``key``; returns class.
         ``name`` is the program's jit name (the trace module is
-        ``jit_<name>``); ``key`` alone decides ``warm``."""
+        ``jit_<name>``); ``key`` alone decides ``warm``.  ``program``: see
+        :meth:`built`; a lazily jitted program hands it in at its first call."""
         name = site if name is None else name
+        if program is not None:
+            self.built(name, program)
         k = (site, self._key_str(key))
         seen = self._seen.get(k, 0)
         self._seen[k] = seen + 1
@@ -245,6 +253,21 @@ class CompileLedger:
         if self._counters is not None:
             self._counters.compiles += 1
         return cls
+
+    def built(self, name: str, program: Any) -> None:
+        """The executable behind a recorded name, for ``monitor/program_scopes.py``
+        (which scope each of its operations belongs to): a held ``Compiled``,
+        or a thunk that compiles the program again at the shapes it is
+        dispatched at.  A dictionary store: nothing is read or compiled until
+        :meth:`program_scopes` is asked.  This ledger is the registry's owner,
+        so the programs leave the registry with the engine that holds it."""
+        program_scopes.register(self, name, program)
+
+    def program_scopes(self, name: Optional[str] = None) -> Dict[str, Dict[str, Tuple[str, ...]]]:
+        """``{program: {instruction: scopes}}`` of this ledger's programs
+        (``name``: that one alone): reads and parses their optimized text, once
+        a program.  Never on a serve iteration."""
+        return program_scopes.tables(None if name is None else [name], owner=self)
 
     @property
     def warm_total(self) -> int:

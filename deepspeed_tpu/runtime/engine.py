@@ -27,7 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..compat import shard_map
 from ..monitor.monitor import MonitorMaster
-from ..monitor import compile_events
+from ..monitor import compile_events, program_scopes
 from ..monitor.telemetry import TelemetryCollector
 from ..parallel.mesh import MeshTopology, set_topology
 from ..utils.logging import log_dist, logger
@@ -724,8 +724,24 @@ class Engine:
     @property
     def train_step_fn(self):
         if self._compiled_step is None:
-            self._compiled_step = self._build_train_step()
+            def seen(fn, args):
+                # the first step's shapes, for program_scopes(); then the bare program
+                program = compile_events.compile_later(fn, args)
+                if program is not None:  # None: traced into another program, no step
+                    self._compiled_step = fn
+                    program_scopes.register(self, "train_step", program)
+            self._compiled_step = program_scopes.FirstCall(self._build_train_step(), seen)
         return self._compiled_step
+
+    def program_scopes(self, name: Optional[str] = None):
+        """Which scope (``forward_backward``, ``grad_norm_clip``, ``optimizer``,
+        and the model's own inside the first) each operation of the compiled
+        train step belongs to: ``{"train_step": {instruction: (scope, ...)}}``,
+        to lay over a device trace's ``jit_train_step`` events
+        (``monitor/program_scopes.py``; the serving engine's twin).  Compiles
+        the step again at its first call's shapes (a hit of JAX's caches) and
+        parses its text, once: an operator's call, never a step's."""
+        return program_scopes.tables(None if name is None else [name], owner=self)
 
     # ------------------------------------------------------------ public API
     def _shard_batch(self, batch):

@@ -197,7 +197,9 @@ class HostSyncInHotPath(Rule):
     # it consumes only the engine's injectable clock and host ints the
     # engine already owns — a device fetch here would charge every serve
     # iteration a hidden sync, so the whole file is scanned
-    PERF_PATH_FRAGMENT = "monitor/perf.py"
+    # (monitor/program_scopes.py, the ledger's table of what it compiled, keeps
+    # the same contract: it reads text the engines hand it and never a device)
+    PERF_PATH_FRAGMENT = ("monitor/perf.py", "monitor/program_scopes.py")
     # the fleet router (ISSUE 17) holds the same whole-file promise, stricter
     # than the per-function v2 scan that would otherwise apply: routing and
     # failover decisions read health dicts and journal files only — a device

@@ -91,7 +91,7 @@ def test_slot_counters_equal_the_count_by_hand(path):
     elif path == "decode_burst":
         out = eng.decode_burst(4)
         assert sorted(len(v) for v in out.values()) == [4, 4, 4]
-        assert _names(eng, "burst") == {"burst_n4_k4"}
+        assert _names(eng, "burst") == {f"burst_n4_k4_b{b}"}  # the table's width is a shape: in the name
         # 4 forward passes over [4, 1]; positions up to 8, 9 and 7 need 1, 2
         # and 1 blocks, and every pass walks the [4, 4] table
         assert _slots(eng) == (16 + 4 * 4, 9 + 12, 16 + 4 * 16, 3 + 4 * (1 + 2 + 1))
@@ -164,8 +164,8 @@ def test_every_ledger_name_is_the_modules_and_spells_its_bucket(serve):
         elif e["site"] == "pick":
             assert e["name"] == f"pick_n{key[1]}" + ("" if key[2] else "_sampled")
         elif e["site"] == "burst":
-            _, n, k, sample_cfg, eos = key
-            assert e["name"] == (f"burst_n{n}_k{k}" + ("_sampled" if sample_cfg else "")
+            _, n, k, b, sample_cfg, eos = key
+            assert e["name"] == (f"burst_n{n}_k{k}_b{b}" + ("_sampled" if sample_cfg else "")
                                  + (f"_eos{eos}" if eos >= 0 else ""))
         else:
             assert e["name"].startswith("spec_verify_")
