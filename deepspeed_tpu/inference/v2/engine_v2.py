@@ -543,9 +543,9 @@ class InferenceEngineV2:
                            prewarmed=prewarmed, name=fwd.__name__, program=self._fwd_cache[key])
 
     def _selected_spans(self, spans):
-        """Each launched row's ``(start_pos, n_tokens)`` for the ``dsa_*`` counters:
-        a list only where the family attends a selection (nothing is built for any other)."""
-        return list(spans) if self.counters.selected is not None else None
+        """Each launched row's ``(start_pos, n_tokens)`` for the ``dsa_*`` and ``scan_*``
+        counters: a list only where the family's counters read it (nothing is built for any other)."""
+        return list(spans) if self.counters.reads_spans else None
 
     def _cow_copy_block(self, src: int, dst: int) -> None:
         """Copy-on-write block duplication (ISSUE 13): copy one KV block's

@@ -124,6 +124,20 @@ class ServeCounters:
     a step of one token a row walks none), in every such layer
     ``scan_positions``  the token positions of those chunks
     ``scan_live_positions``  of those, the positions that held a live token
+    A family whose rows of ONE token leave the walk for its update kernel
+    (ISSUE 55; ``state_scan`` says so with a fourth entry, the trips a compacted
+    walk takes: Granite's Mamba-2) counts what its scan was GIVEN, from each
+    launched row's ``n_tokens`` (``spans``): ``scan_live_positions`` the tokens
+    of the rows of more than one token, ``scan_chunks`` / ``scan_positions`` the
+    layout of a compacted walk (``ceil(S / chunk) + window`` chunks a trip,
+    whatever the bucket's rows) times its trips; so ``live_tokens -
+    scan_live_positions / layers`` is the rows the update kernel served, those of
+    chunk passes among them.  Such a family alone counts and reports
+    (``WALK_FIELDS``):
+    ``scan_overflow_windows``  trips its compacted walks ran beyond their first:
+    a pass that held more rows of several tokens than a window lays out, in
+    every such layer (how many rows a launch walks the host knows: no tally on
+    the device, no fetch)
 
     A family that attends a learned selection of the cache (ISSUE 45; the model
     module states ``selected_keys`` = (top-k, attention layers); zero, and absent
@@ -171,20 +185,29 @@ class ServeCounters:
                        "dsa_attended_keys")
     # the same for a family that tallies its picks on the device (``tallied``)
     TALLIED_FIELDS = ("moe_identity_picks", "moe_held_picks", "moe_overflow_windows")
+    # the same for a family whose scan walks windows of its rows of several tokens (``walk_trips``)
+    WALK_FIELDS = ("scan_overflow_windows", )
 
     def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None,
                  kernel_slots: Callable[[int], int] = lambda t: 1,
                  attn_slots: Callable[[int, int], int] = lambda n, flat: flat,
                  scan: Optional[tuple] = None, selected: Optional[tuple] = None,
                  tallied: Optional[tuple] = None):
-        for f in self.FIELDS + self.SELECTED_FIELDS + self.TALLIED_FIELDS:
+        for f in self.FIELDS + self.SELECTED_FIELDS + self.TALLIED_FIELDS + self.WALK_FIELDS:
             setattr(self, f, 0)
         self.moe_picks, self.moe_rows, self.kernel_slots = moe_picks, moe_rows, kernel_slots
         self.attn_slots = attn_slots
-        self.scan = scan  # (chunks(n, t, flat), positions a chunk, layers that scan)
+        self.scan = scan[:3] if scan else None  # (chunks(n, t, flat), positions a chunk, layers that scan)
+        # a fourth entry, trips(walked): the scan walks windows of the rows of more than one token alone
+        self.walk_trips = scan[3] if scan and len(scan) > 3 else None
         self.selected = selected  # (top-k, attention layers, the pool's block size)
         self.tallied = tallied  # the fields the device's running tallies are, in their order
         self._tallies_seen = (0, ) * len(tallied or ())
+
+    @property
+    def reads_spans(self) -> bool:
+        """Whether :meth:`count_slots` reads ``spans``: nothing is built for it otherwise."""
+        return self.selected is not None or self.walk_trips is not None
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
                     live_blocks: int, passes: int = 1,
@@ -199,8 +222,9 @@ class ServeCounters:
         every slot (a speculative verify) and not each row's last live token
         alone (a step, a burst).  ``spans``: each live row's ``(start_pos,
         n_tokens)`` of the first pass (a later pass of a burst begins one token
-        further), read only where the family attends a selection.  Host integers
-        only: no clock read, no device sync."""
+        further), read only where the family attends a selection or its scan
+        passes one-token rows by (:attr:`reads_spans`).  Host integers only: no
+        clock read, no device sync."""
         slots = n * t if flat is None else flat
         self.token_slots += slots * passes
         self.head_rows += (slots if every_position else n) * passes
@@ -211,11 +235,17 @@ class ServeCounters:
             self.moe_routed_rows += live_tokens * self.moe_picks
         if self.scan is not None:
             chunks_of, width, layers = self.scan
-            chunks = chunks_of(n, t, flat) * passes
+            if self.walk_trips is not None:
+                walked = [count for _, count in spans or () if count > 1]
+                chunks, scanned = chunks_of(n, t, flat, len(walked)) * passes, sum(walked)
+                if flat is not None:
+                    self.scan_overflow_windows += max(self.walk_trips(len(walked)) - 1, 0) * layers
+            else:
+                chunks, scanned = chunks_of(n, t, flat) * passes, live_tokens
             if chunks:
                 self.scan_chunks += chunks
                 self.scan_positions += chunks * width
-                self.scan_live_positions += live_tokens * layers
+                self.scan_live_positions += scanned * layers
         self.table_slots += n * b * passes
         self.kernel_steps += n * -(-b // self.kernel_slots(t)) * passes
         self.live_blocks += live_blocks * passes
@@ -256,7 +286,8 @@ class ServeCounters:
 
     def _reported(self) -> Tuple[str, ...]:
         return (self.FIELDS + (self.SELECTED_FIELDS if self.selected is not None else ())
-                + (self.TALLIED_FIELDS if self.tallied is not None else ()))
+                + (self.TALLIED_FIELDS if self.tallied is not None else ())
+                + (self.WALK_FIELDS if self.walk_trips is not None else ()))
 
     def snapshot(self) -> Dict[str, int]:
         return {f: int(getattr(self, f)) for f in self._reported()}

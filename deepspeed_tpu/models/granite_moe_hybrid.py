@@ -15,8 +15,10 @@ the embedding) divided by ``logits_scaling``.
   over ``xBC`` = ``[x | B | C]``; ``dt = softplus(dt + dt_bias)``, ``A =
   -exp(A_log)``; then the state-space duality recurrence over the sequence,
   whose memory is ONE MATRIX ``[P, Ns]`` A HEAD with B and C shared by every
-  head (``ops/linear_attention/ssd.py``: a chunked scan for a step's chunk, a
-  one-token update for a decode row and a burst's step); the output times
+  head (``ops/linear_attention/ssd.py``: a one-token update for a decode row and
+  a burst's step; for a pass of chunks ``ssd_chunks``, which hands that same
+  update the pass's rows of one token and walks the rest in a chunked scan whose
+  layout is sized by the pass's tokens); the output times
   ``silu(z)`` INSIDE one RMS norm over all ``I`` columns, through ``W_out``.
   What a sequence remembers a layer, whatever its length: that matrix of every
   head in float32 (4 MB at 128 heads of 64 x 128) and the last ``taps - 1``
@@ -245,13 +247,18 @@ def state_bytes_per_seq(config: GraniteMoeHybridConfig, value_bytes: int = 2) ->
 
 
 def state_scan(config: GraniteMoeHybridConfig):
-    """``(chunks(n, t, flat), positions a chunk, layers)`` for the serving
-    counters: the chunks the Mamba-2 layers' scans walk in one forward pass over
-    a ``[n, t]`` bucket (``flat``: its compacted slots), how many positions a
-    chunk holds, and how many layers scan."""
-    from ..ops.linear_attention.ssd import CHUNK, scan_chunks
+    """``(chunks(n, t, flat, walked), positions a chunk, layers, trips(walked))``
+    for the serving counters: the chunks of the layouts the Mamba-2 layers' scans
+    are given in one forward pass over a ``[n, t]`` bucket (``flat``: its
+    compacted slots; ``walked``: its rows of more than one token), how many
+    positions a chunk holds, how many layers scan, and the trips a compacted
+    walk of ``walked`` rows takes.  The fourth entry says that a row of one token
+    leaves the walk for the update kernel: the scan's live positions are the
+    tokens of the rows it walked."""
+    from ..ops.linear_attention.ssd import CHUNK, scan_chunks, walk_trips
     layers = config.layer_types.count("mamba")
-    return (lambda n, t, flat=None: scan_chunks(n, t, flat) * layers), CHUNK, layers
+    return ((lambda n, t, flat, walked: scan_chunks(n, t, flat, walked) * layers), CHUNK, layers,
+            walk_trips)
 
 
 def moe_picks_per_token(config: GraniteMoeHybridConfig) -> int:
@@ -274,7 +281,7 @@ def forward_paged(config: GraniteMoeHybridConfig, params, tokens, n_tokens, star
     contract): the Mamba-2 layers through ``mix`` and their sequences' carried
     leaves, the attention layers over the pool, the expert FFN."""
     from ..moe.serving import sparse_moe_ffn
-    from ..ops.linear_attention import ssd_scan, ssd_update
+    from ..ops.linear_attention import ssd_chunks, ssd_update
     if tp_axis is not None:
         raise NotImplementedError("granite_moe_hybrid: tensor-parallel serving is not implemented")
     D, H, KV, dh = config.hidden_size, config.num_heads, config.num_kv_heads, config.head_dim
@@ -314,16 +321,14 @@ def forward_paged(config: GraniteMoeHybridConfig, params, tokens, n_tokens, star
             b, c = xbc[..., inner:inner + ns], xbc[..., inner + ns:]
             dt = jax.nn.softplus(dt + m["dt_bias"].astype(jnp.float32))
             a = -jnp.exp(m["A_log"].astype(jnp.float32))
-            # carried["ssm"] is a ``StateRef`` (leaf, at, begins): the kernels' last state arguments
+            ref = carried["ssm"]  # a ``StateRef``: the kernels' state arguments, and the trash slot
             if places.row is None and x.shape[1] == 1:  # a decode row, a burst's step
                 with jax.named_scope("ssm_update"), jax.named_scope("ssm_state"):
-                    y, state = ssd_update(xs[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], m["D"],
-                                          *carried["ssm"])
+                    y, state = ssd_update(xs[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], m["D"], ref.leaf,
+                                          ref.at, ref.begins)
                 y = y[:, None]
-            else:
-                with jax.named_scope("ssm_scan"):
-                    y, state = ssd_scan(xs, dt, a, b, c, m["D"], *carried["ssm"], places.n_tokens,
-                                        places.row, places.col)
+            else:  # a pass of chunks: the scan for the rows of several tokens, the update for the rest
+                y, state = ssd_chunks(xs, dt, a, b, c, m["D"], *ref, *places)
             # GraniteMoeHybridRMSNormGated: the gate INSIDE the norm, one group over all columns
             y = y.reshape(lead + (inner, )).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
             y = rms_norm(y, m["norm"], eps).astype(dtype)

@@ -471,10 +471,12 @@ class StateRef(NamedTuple):
     ``by_reference``): ``leaf`` the carried leaf whole and flat ``[Ls x slots,
     ...]``, ``at`` ``[N]`` the rows' slots of this layer in it (a dead row's:
     the layer's trash slot), ``begins`` ``[N]`` bool, the rows whose sequence
-    begins (what their slot holds counts as zero)."""
+    begins (what their slot holds counts as zero), ``trash`` that trash slot
+    (for a row that one of the family's kernels is to pass by)."""
     leaf: jax.Array
     at: jax.Array
     begins: jax.Array
+    trash: jax.Array
 
 
 def repeating_runs(kinds):
@@ -646,7 +648,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     selected or scattered here: ``carried`` holds in its place a
     :class:`StateRef` (the carried leaf whole and flat ``[Ls x slots, ...]``,
     the rows' slots ``at`` ``[N]`` in it, a dead row's the trash slot of the
-    layer, and ``begins`` ``[N]``, ``start_pos == 0``), and what ``mix``
+    layer, ``begins`` ``[N]``, ``start_pos == 0``, and that trash slot), and what ``mix``
     returns in its place is the NEW FLAT LEAF, which goes back into the layer
     scan's carry as it is: the rows' slots updated (a beginning row's from
     zeros), every other slot as it was (Granite's ``ssm``: 4 MB a row a layer,
@@ -819,7 +821,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                              f"{'mix' if mix is None else 'kv_cache[STATE]'}")
         at = l * state_slots + seq_slot
         with jax.named_scope("seq_state"):  # a leaf by reference: whole, with where its rows lie
-            kept = [StateRef(leaf, at, start_pos == 0) if ref else
+            kept = [StateRef(leaf, at, start_pos == 0, (l + 1) * state_slots - 1) if ref else
                     jnp.where((start_pos > 0).reshape((-1, ) + (1, ) * (leaf.ndim - 1)), leaf[at], 0)
                     for leaf, ref in zip(flat_states, by_ref)]
         with jax.named_scope("mixer_layer"):  # the family's own (``ssm_mixer``, its FFN) inside it

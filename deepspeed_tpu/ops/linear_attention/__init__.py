@@ -2,4 +2,4 @@
 and their one-token updates (``CHUNK`` and ``scan_chunks`` here are the gated delta rule's;
 ``ssd.py`` has its own)."""
 from .gated_delta import CHUNK, gated_delta_scan, gated_delta_step, scan_chunks
-from .ssd import ssd_scan, ssd_update
+from .ssd import ssd_chunks, ssd_scan, ssd_update

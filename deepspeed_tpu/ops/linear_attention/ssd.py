@@ -53,19 +53,31 @@ products' operands are in the dtype ``x`` comes in (bfloat16 on the chip: the
 state is float32 in memory and rounded where a product reads it, as the gated
 delta rule's), the accumulations float32.
 
-**Sequences on one axis**: as ``gated_delta.py`` (whose layout this imports):
-every sequence of a step, padded ``[N, T]`` or compacted ``[1, S]``, is laid
-out to begin on a chunk's edge; the first chunk of a sequence loads its carried
-matrices from its slot (zeros where it begins), the last stores them there; a
-row with no token walks no chunk and its slot is never written; dead positions
-hold ``dt = 0`` and change nothing.  On the TPU the walk is the Pallas kernel
-``ssd_scan``: grid (``SCAN_HEADS`` heads, chunk), x and y ``[heads, C, P]``, B
-and C ``[C, N]``, the running sums, steps and ``D`` of the heads both as rows
-``[3 heads, C]`` and as columns ``[C, 3 heads]`` (the mask needs ``l_i - l_j``:
-a column minus a row), the chunk table (its ``SEQ`` row the sequences' slots, a
-fifth row their flags) scalar-prefetched, ``S`` in VMEM scratch between a
-sequence's chunks.  Off the TPU the same chunk mathematics, every head at
-once, under ``lax.scan``.
+**Sequences on one axis**: every walked sequence of a step, padded ``[N, T]`` or
+compacted ``[1, S]``, is laid out to begin on a chunk's edge; the first chunk of
+a sequence loads its carried matrices from its slot (zeros where it begins),
+the last stores them there; a row with no token walks no chunk and its slot is
+never written; dead positions hold ``dt = 0`` and change nothing.  The padded
+layout is ``gated_delta.py``'s (a row's chunks in place).  The compacted one is
+this module's (:func:`_lay_window`) and is sized BY THE TOKENS OF THE PASS, not by
+the rows of its bucket: ``ceil(S / CHUNK) + WINDOW`` chunks hold any ``WINDOW``
+rows' tokens, and a pass that walks more rows runs the walk again over the rows
+left (:func:`ssd_scan`).  On the TPU the walk is the Pallas kernel ``ssd_scan``:
+grid (``SCAN_HEADS`` heads, chunk), x and y ``[heads, C, P]``, B and C ``[C,
+N]``, the running sums, steps and ``D`` of the heads both as rows ``[3 heads,
+C]`` and as columns ``[C, 3 heads]`` (the mask needs ``l_i - l_j``: a column
+minus a row), the chunk table (its ``SEQ`` row the sequences' slots, a fifth row
+their flags, a sixth the chunk whose blocks a step names: what is left of the
+static grid past the live chunks names the last live one's, moves nothing and
+computes nothing) scalar-prefetched, ``S`` in VMEM scratch between a sequence's
+chunks.  Off the TPU the same chunk mathematics, every head at once, under
+``lax.scan``.
+
+**A pass of chunks** (:func:`ssd_chunks`): what a family calls for a step whose
+rows hold any number of tokens.  A row of ONE token (a decode row riding beside
+the prompts' pieces, a prompt's last piece) is the update kernel's, a row of
+more the scan's: a one-token row costs its matrices' read and write and no
+chunk of the walk.
 """
 
 import functools
@@ -80,17 +92,29 @@ from .. import _pallas
 from . import gated_delta
 from .gated_delta import FIRST, LAST, LIVE, SEQ, lay_on_chunk_edges
 
-CHUNK = 64         # positions of one chunk of the scan (PERF.md, PR 52: the sweep 64 / 128 / 256)
+CHUNK = 64         # positions of one chunk of the scan (PERF.md, PRs 52 and 55: the sweeps 64 / 128 / 256)
+WINDOW = 4         # rows one trip of a compacted walk lays out (PERF.md, PR 55: 4 against 8)
 SCAN_HEADS = 8     # heads one grid step of ``ssd_scan`` takes
 UPDATE_HEADS = 32  # heads one grid step of ``ssd_update`` takes: 32 x [64, 128] float32 = 1 MiB
 PAD = 16           # rows a one-row operand is padded to: a whole sublane tile of bfloat16
-BEGINS = 4         # the scan kernel's row of the chunk table, after ``gated_delta``'s four
+BEGINS, BLOCK = 4, 5  # the scan kernel's rows of the chunk table, after ``gated_delta``'s four
 
 
-def scan_chunks(n: int, t: int, flat=None) -> int:
-    """``gated_delta.scan_chunks`` at this scan's ``CHUNK``: the chunks walked for
-    a ``[n, t]`` bucket (``flat``: the flat slots it is compacted onto)."""
-    return gated_delta.scan_chunks(n, t, flat, CHUNK)
+def walk_trips(walked):
+    """Trips a compacted walk of ``walked`` rows takes: ``WINDOW`` rows a trip,
+    none where no row is walked (a Python integer or a traced one)."""
+    return -(-walked // WINDOW)
+
+
+def scan_chunks(n: int, t: int, flat=None, walked=None) -> int:
+    """The chunks of the layout the scan is given for a ``[n, t]`` bucket: none
+    for a step of one token a row (the one-token update), a row's ``ceil(t /
+    CHUNK)`` padded; compacted onto ``flat`` slots ``ceil(flat / CHUNK) +
+    WINDOW`` a trip, whatever ``n``, and :func:`walk_trips` trips for ``walked``
+    rows (None: one trip)."""
+    if flat is not None:
+        return (-(-flat // CHUNK) + WINDOW) * (1 if walked is None else walk_trips(walked))
+    return gated_delta.scan_chunks(n, t, None, CHUNK)
 
 
 def _heads_a_step(heads: int, most: int) -> int:
@@ -121,17 +145,24 @@ def _by_value(leaf, at, begins, step, live=None):
 
 
 # ------------------------------------------------------------------- one token
-def ssd_update(x, dt, A, B, C, D, leaf, at, begins):
+def ssd_update(x, dt, A, B, C, D, leaf, at, begins, passed=None):
     """One token a row.  x ``[N, H, P]``, dt ``[N, H]`` float32 (after its
     softplus), A, D ``[H]``, B, C ``[N, Ns]``; leaf ``[slots, H, P, Ns]``
     float32, the carried state whole, at ``[N]`` the rows' slots (distinct, but
     for the dead rows' one trash slot), begins ``[N]`` bool -> (y ``[N, H, P]``
-    float32, leaf): every row's slot updated, no other touched."""
+    float32, leaf): every row's slot updated, no other touched.  ``passed``
+    ``[N]`` bool (None: no row): rows that hold no token of this kernel's and
+    name the trash slot (:func:`ssd_chunks`); y is nothing at them, and on the TPU
+    every grid step of such a row names ONE block of the trash slot, so a run of
+    them moves it once (a bucket's plain dead rows move it a row each)."""
     dt = dt.astype(jnp.float32)
     decay = jnp.exp(dt * A.astype(jnp.float32))  # [N, H]
     dtx = dt[..., None] * x.astype(jnp.float32)
     if _pallas.use_pallas():
-        y, leaf = _update_pallas(at.astype(jnp.int32), begins.astype(jnp.int32), dtx.astype(x.dtype),
+        flags = begins.astype(jnp.int32)
+        if passed is not None:
+            flags = flags + 2 * passed.astype(jnp.int32)
+        y, leaf = _update_pallas(at.astype(jnp.int32), flags, dtx.astype(x.dtype),
                                  decay, B.astype(x.dtype), C.astype(x.dtype), leaf,
                                  interpret=_pallas.INTERPRET)
     else:
@@ -140,18 +171,19 @@ def ssd_update(x, dt, A, B, C, D, leaf, at, begins):
                     + dtx[..., None] * B.astype(jnp.float32)[:, None, None, :])
             return jnp.sum(rows * C.astype(jnp.float32)[:, None, None, :], axis=-1), rows
 
-        y, leaf = _by_value(leaf, at, begins, step)
+        y, leaf = _by_value(leaf, at, begins, step,
+                            live=None if passed is None else jnp.logical_not(passed))
     return y + D.astype(jnp.float32)[None, :, None] * x.astype(jnp.float32), leaf
 
 
-def _update_body(at_ref, begins_ref, dtx_ref, decay_ref, b_ref, c_ref, state_ref, y_ref, out_ref):
+def _update_body(at_ref, flags_ref, dtx_ref, decay_ref, b_ref, c_ref, state_ref, y_ref, out_ref):
     heads, p, ns = state_ref.shape[1:]
     dtype = dtx_ref.dtype
     first = jax.lax.broadcasted_iota(jnp.int32, (PAD, ns), 0) == 0
     # (dt x) B^T of every head of the step at once: [PAD, heads P]^T [PAD, Ns], one live row
     outer = _dot(jnp.broadcast_to(dtx_ref[0], (PAD, heads * p)),
                  jnp.where(first, b_ref[0].astype(jnp.float32), 0.0), TN, dtype)
-    begins = begins_ref[pl.program_id(0)] > 0
+    begins = flags_ref[pl.program_id(0)] > 0  # a row passed by writes, like one that begins, what it read not
 
     @pl.when(jnp.logical_not(begins))
     def _continues():
@@ -169,18 +201,19 @@ def _update_body(at_ref, begins_ref, dtx_ref, decay_ref, b_ref, c_ref, state_ref
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", ), inline=True)
-def _update_pallas(at, begins, dtx, decay, b, c, leaf, *, interpret):
+def _update_pallas(at, flags, dtx, decay, b, c, leaf, *, interpret):
+    """``flags`` ``[N]``: 1 a row whose sequence begins, 2 or 3 a row passed by."""
     n, (heads, p, ns) = at.shape[0], leaf.shape[1:]
     step = _heads_a_step(heads, UPDATE_HEADS)
-    a_row = lambda r, g, at, begins: (r, 0, 0)
-    of_heads = lambda r, g, at, begins: (r, 0, g)
-    in_slot = lambda r, g, at, begins: (at[r], g, 0, 0)
+    a_row = lambda r, g, at, flags: (r, 0, 0)
+    of_heads = lambda r, g, at, flags: (r, 0, g)
+    in_slot = lambda r, g, at, flags: (at[r], jnp.where(flags[r] > 1, 0, g), 0, 0)
     y, leaf = pl.pallas_call(
         _update_body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(n, heads // step),
             in_specs=[pl.BlockSpec((1, 1, step * p), of_heads),
-                      pl.BlockSpec((1, step, ns), lambda r, g, at, begins: (r, g, 0)),
+                      pl.BlockSpec((1, step, ns), lambda r, g, at, flags: (r, g, 0)),
                       pl.BlockSpec((1, 1, ns), a_row), pl.BlockSpec((1, 1, ns), a_row),
                       pl.BlockSpec((1, step, p, ns), in_slot)],
             out_specs=[pl.BlockSpec((1, 1, step * p), of_heads),
@@ -191,7 +224,7 @@ def _update_pallas(at, begins, dtx, decay, b, c, leaf, *, interpret):
         compiler_params=CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="ssd_update",
-    )(at, begins, dtx.reshape(n, 1, heads * p), jnp.broadcast_to(decay[..., None], (n, heads, ns)),
+    )(at, flags, dtx.reshape(n, 1, heads * p), jnp.broadcast_to(decay[..., None], (n, heads, ns)),
       b[:, None], c[:, None], leaf)
     return y.reshape(n, heads, p), leaf
 
@@ -217,34 +250,151 @@ def _causal(c: int):
             >= jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
 
 
+# ------------------------------------------------------------- a pass of chunks
+def ssd_chunks(x, dt, A, B, C, D, leaf, at, begins, trash, n_tokens, row=None, col=None):
+    """A step whose rows hold any number of tokens (a prompt's pieces, and beside
+    them decode rows and a prompt's last piece of one token): :func:`ssd_scan`'s
+    arguments and ``trash``, a slot of ``leaf`` that no row names.  The rows of
+    ONE token are :func:`ssd_update`'s, each from its first slot of the axes
+    (begun or continued, as a decode step's rows); the scan walks the rows that
+    hold more and nothing else, so a one-token row costs the read and the write
+    of its matrices and no chunk.  Which row is which is ``n_tokens == 1``, data;
+    either kernel passes the other's rows by on the trash slot (the update is
+    told which, ``passed``: a run of them moves one block of it once), so the two
+    touch disjoint slots of the one leaf.  Returns (y ``[b, s, H, P]`` in x's
+    dtype, leaf)."""
+    single = n_tokens == 1
+    if row is None:
+        first = lambda v: v[:, 0]
+    else:  # the compacted axis: a row begins where the rows before it end
+        start = jnp.minimum(jnp.cumsum(n_tokens) - n_tokens, x.shape[1] - 1)
+        first = lambda v: v[0, start]
+    with jax.named_scope("ssm_update"), jax.named_scope("ssm_state"):
+        y_single, leaf = ssd_update(first(x), first(dt), A, first(B), first(C), D, leaf,
+                                    jnp.where(single, at, trash), begins, jnp.logical_not(single))
+    with jax.named_scope("ssm_scan"):
+        y, leaf = ssd_scan(x, dt, A, B, C, D, leaf, jnp.where(single, trash, at), begins, n_tokens,
+                           row, col, walked=n_tokens > 1)
+        y_single = y_single.astype(y.dtype)
+        if row is None:
+            return y.at[:, 0].set(jnp.where(single[:, None, None], y_single, y[:, 0])), leaf
+        # a row of another count lands nowhere
+        return y.at[0, jnp.where(single, start, x.shape[1])].set(y_single, mode="drop"), leaf
+
+
 # ----------------------------------------------------------------- the chunked scan
-def ssd_scan(x, dt, A, B, C, D, leaf, at, begins, n_tokens, row=None, col=None):
+def ssd_scan(x, dt, A, B, C, D, leaf, at, begins, n_tokens, row=None, col=None, walked=None):
     """The chunked scan over a step's tokens.  x ``[b, s, H, P]``, dt ``[b, s,
     H]`` float32 (after its softplus), A, D ``[H]``, B, C ``[b, s, Ns]``, ``[b,
     s]`` = ``[N, T]`` (``row`` None) or the compacted ``[1, S]`` (``row``,
     ``col`` ``[1, S]``); leaf ``[slots, H, P, Ns]`` float32, the carried state
     whole, at ``[N]`` the rows' slots, begins ``[N]`` bool (a sequence that
-    begins walks from zeros whatever its slot holds); n_tokens ``[N]``.
+    begins walks from zeros whatever its slot holds); n_tokens ``[N]``;
+    ``walked`` ``[N]`` bool, the rows this scan walks (None: every row that
+    holds a token).  A row not walked keeps its tokens' places on the axes and
+    nothing else: it takes no chunk, y is zero at its tokens, and its ``at``
+    names a slot no walked row names (the trash slot).
     Returns (y ``[b, s, H, P]`` in x's dtype, leaf).  A row with no token
-    writes no slot."""
+    writes no slot.
+
+    The padded layout is a row's ``ceil(T / CHUNK)`` chunks in place.  The
+    compacted one is sized by the tokens of the pass and not by its rows:
+    ``ceil(S / CHUNK) + WINDOW`` chunks hold ANY ``WINDOW`` rows' tokens, each
+    row on a chunk's edge (:func:`_lay_window`), and a pass that walks more rows
+    than that runs the walk again over the rows left (a ``while_loop`` whose
+    body is traced once, :func:`walk_trips` trips: none where no row is walked;
+    nothing has a capacity, nothing is dropped)."""
     heads = x.shape[2]
-    table, laid, back, chunks = lay_on_chunk_edges(n_tokens, x.shape[:2], row, col, CHUNK)
     dt = dt.astype(jnp.float32)
-    xa = laid(x)  # [H, chunks C, P]
-    ba, ca = laid(B[:, :, None])[0], laid(C[:, :, None])[0]  # [chunks C, Ns]
-    dta = laid(dt[..., None])[..., 0].reshape(heads, chunks, CHUNK)  # 0 where no token sits
-    la = jnp.cumsum(dta * A.astype(jnp.float32)[:, None, None], axis=-1)
-    d = jnp.broadcast_to(D.astype(jnp.float32)[:, None, None], la.shape)
-    if _pallas.use_pallas():
-        # a chunk's slot in its sequence's place, and whether the sequence begins
-        seq = table[SEQ]
-        table = jnp.concatenate([at.astype(jnp.int32)[seq][None], table[SEQ + 1:],
-                                 begins.astype(jnp.int32)[seq][None]])
-        y, leaf = _walk_pallas(table, xa, ba, ca, (la, dta, d), leaf, interpret=_pallas.INTERPRET)
-    else:
-        walk = lambda rows: _walk_scan(table, xa, ba, ca, (la, dta, d), rows)
-        y, leaf = _by_value(leaf, at, begins, walk, live=n_tokens > 0)
-    return back(jnp.moveaxis(y, 0, 1)), leaf
+    counts = n_tokens if walked is None else jnp.where(walked, n_tokens, 0)
+
+    def walk(leaf, table, laid, chunks, at, begins, live):
+        """One layout walked: ``at``, ``begins``, ``live`` of the sequences the table's ``SEQ``
+        row names (``live``: the sequence holds a token)."""
+        xa = laid(x)  # [H, chunks C, P]
+        ba, ca = laid(B[:, :, None])[0], laid(C[:, :, None])[0]  # [chunks C, Ns]
+        dta = laid(dt[..., None])[..., 0].reshape(heads, chunks, CHUNK)  # 0 where no token sits
+        la = jnp.cumsum(dta * A.astype(jnp.float32)[:, None, None], axis=-1)
+        d = jnp.broadcast_to(D.astype(jnp.float32)[:, None, None], la.shape)
+        if not _pallas.use_pallas():
+            step = lambda rows: _walk_scan(table, xa, ba, ca, (la, dta, d), rows)
+            return _by_value(leaf, at, begins, step, live=live)
+        # a chunk's slot in its sequence's place, whether the sequence begins, and the chunk
+        # whose blocks its grid step names: its own, or for an empty chunk the last live one's
+        # (as ``_chunk_table`` names that one's sequence: nothing is fetched, nothing stored)
+        seq, c = table[SEQ], jnp.arange(chunks, dtype=jnp.int32)
+        alive = table[LIVE] > 0
+        before = jax.lax.cummax(jnp.where(alive, c, -1))
+        table = jnp.concatenate([
+            at.astype(jnp.int32)[seq][None], table[SEQ + 1:], begins.astype(jnp.int32)[seq][None],
+            jnp.where(before >= 0, before, jnp.argmax(alive).astype(jnp.int32))[None]])
+        return _walk_pallas(table, xa, ba, ca, (la, dta, d), leaf, interpret=_pallas.INTERPRET)
+
+    if row is None:
+        table, laid, back, chunks = lay_on_chunk_edges(counts, x.shape[:2], None, None, CHUNK)
+        y, leaf = walk(leaf, table, laid, chunks, at, begins, counts > 0)
+        # the chunks no step computed hold whatever was there: a row's dead positions read zero
+        held = jnp.arange(x.shape[1])[None, :] < counts[:, None]
+        return jnp.where(held[:, :, None, None], back(jnp.moveaxis(y, 0, 1)), 0), leaf
+    walked = counts > 0
+    chunks = scan_chunks(0, 0, x.shape[1])
+
+    def trip(carry):
+        w, y, leaf = carry
+        table, laid, back, rows, held = _lay_window(n_tokens, walked, w, chunks, row, col)
+        o, leaf = walk(leaf, table, laid, chunks, at[rows], begins[rows], held)
+        return w + 1, back(jnp.moveaxis(o, 0, 1), y), leaf
+
+    trips = walk_trips(jnp.sum(walked, dtype=jnp.int32))
+    _, y, leaf = jax.lax.while_loop(lambda carry: carry[0] < trips, trip,
+                                    (jnp.int32(0), jnp.zeros_like(x), leaf))
+    return y, leaf
+
+
+def _lay_window(n_tokens, walked, trip, chunks: int, row, col):
+    """Trip ``trip`` of a compacted walk: the walked rows from the ``trip x
+    WINDOW``-th on, each laid to begin on a chunk's edge of a layout of
+    ``chunks`` chunks (``ceil(S / CHUNK) + WINDOW``: what ``WINDOW`` rows of at
+    most ``S`` tokens in all can take).  ``n_tokens`` ``[N]`` every row's tokens
+    (a row not walked still takes its places on the flat axis), ``walked``
+    ``[N]``, ``row`` / ``col`` ``[1, S]``.  Returns ``(table, laid, back, rows,
+    held)``: the chunk table ``[4, chunks]`` whose ``SEQ`` row names a PLACE OF
+    THE WINDOW, ``laid`` as ``lay_on_chunk_edges``', ``back(o [chunks * CHUNK,
+    H, d], y [1, S, H, d]) -> y`` with the window's rows' tokens taken from ``o``
+    and every other slot as it was, ``rows`` ``[WINDOW]`` the row of the bucket
+    each place holds and ``held`` ``[WINDOW]`` whether it holds one (a place
+    past the walked rows does not: no token, no chunk)."""
+    place = jnp.cumsum(walked) - 1 - trip * WINDOW  # a walked row's place in this window
+    w = jnp.arange(WINDOW, dtype=jnp.int32)
+    holds = walked[None, :] & (place[None, :] == w[:, None])  # [WINDOW, N]
+    rows, held = jnp.argmax(holds, axis=1), jnp.any(holds, axis=1)
+    count = jnp.where(held, n_tokens[rows], 0)
+    start = (jnp.cumsum(n_tokens) - n_tokens)[rows]  # the row's first flat slot
+    per_seq = -(-count // CHUNK)
+    ends = jnp.cumsum(per_seq)
+    first_chunk = ends - per_seq
+    c = jnp.arange(chunks, dtype=jnp.int32)
+    seq = jnp.minimum(jnp.sum((c[:, None] >= ends[None, :]).astype(jnp.int32), axis=1), WINDOW - 1)
+    nth = c - first_chunk[seq]
+    table = gated_delta._chunk_table(seq, nth, jnp.clip(count[seq] - nth * CHUNK, 0, CHUNK), per_seq)
+    p = jnp.arange(chunks * CHUNK, dtype=jnp.int32)
+    live = (p % CHUNK) < table[LIVE][p // CHUNK]
+    source = jnp.where(live, start[seq[p // CHUNK]] + nth[p // CHUNK] * CHUNK + p % CHUNK, 0)
+
+    def laid(a):  # [1, S, H, d] -> [H, chunks * C, d], zero where no token sits
+        a = a[0][source]
+        mask = live.reshape((-1, ) + (1, ) * (a.ndim - 1))
+        return jnp.moveaxis(jnp.where(mask, a, jnp.zeros((), a.dtype)), 1, 0)
+
+    mine = place[row[0]]  # a flat slot's row's place: in this window from 0 to WINDOW - 1
+    here = walked[row[0]] & (mine >= 0) & (mine < WINDOW)
+    spot = first_chunk[jnp.clip(mine, 0, WINDOW - 1)] * CHUNK + col[0]  # where a flat slot's token went
+
+    def back(o, y):
+        taken = o[jnp.clip(spot, 0, chunks * CHUNK - 1)]
+        return jnp.where(here.reshape((-1, ) + (1, ) * (taken.ndim - 1)), taken, y[0])[None]
+
+    return table, laid, back, rows, held
 
 
 def _walk_scan(table, x, b, c, scalars, state):
@@ -301,10 +451,6 @@ def _scan_body(table_ref, x_ref, b_ref, c_ref, rows_ref, cols_ref, state_ref, y_
             y_ref[h] = y.astype(y_ref.dtype)
             s_ref[h] = s1
 
-    @pl.when(table_ref[LIVE, k] == 0)
-    def _empty():
-        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
-
     @pl.when(table_ref[LAST, k] > 0)
     def _store():
         out_state_ref[0] = s_ref[...]
@@ -313,8 +459,10 @@ def _scan_body(table_ref, x_ref, b_ref, c_ref, rows_ref, cols_ref, state_ref, y_
 # jitted for its trace cache: every chunk program of a cell traces the kernel once
 @functools.partial(jax.jit, static_argnames=("interpret", ), inline=True)
 def _walk_pallas(table, x, b, c, scalars, leaf, *, interpret):
-    """``table`` ``[5, chunks]``: ``SEQ`` holds each chunk's SLOT of ``leaf``, ``BEGINS`` whether
-    its sequence begins."""
+    """``table`` ``[6, chunks]``: ``SEQ`` holds each chunk's SLOT of ``leaf``, ``BEGINS`` whether
+    its sequence begins, ``BLOCK`` the chunk whose blocks of x, B, C, the scalars and y its grid
+    step names: an empty chunk's is the last live one's, so it moves nothing and computes
+    nothing, and the positions of y that no live chunk covers are never written."""
     heads, p = x.shape[0], x.shape[-1]
     chunks, ns = table.shape[1], b.shape[-1]
     size = scalars[0].shape[-1]  # a chunk's positions
@@ -322,9 +470,9 @@ def _walk_pallas(table, x, b, c, scalars, leaf, *, interpret):
     # a step's l, dt and D side by side: [groups, chunks, 3 heads, C], and transposed
     rows = jnp.concatenate([a.reshape(heads // step, step, chunks, size) for a in scalars], axis=1)
     rows = jnp.moveaxis(rows, 1, 2)
-    of_heads = lambda g, k, table: (g, k, 0)
-    of_chunk = lambda g, k, table: (k, 0)
-    of_both = lambda g, k, table: (g, k, 0, 0)
+    of_heads = lambda g, k, table: (g, table[BLOCK, k], 0)
+    of_chunk = lambda g, k, table: (table[BLOCK, k], 0)
+    of_both = lambda g, k, table: (g, table[BLOCK, k], 0, 0)
     in_slot = lambda g, k, table: (table[SEQ, k], g, 0, 0)
     return pl.pallas_call(
         _scan_body,

@@ -24,7 +24,7 @@ from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.models import granite_moe_hybrid as family
 from deepspeed_tpu.models.transformer import STATE
 from deepspeed_tpu.moe.serving import sparse_moe_ffn
-from deepspeed_tpu.ops.linear_attention.ssd import CHUNK
+from deepspeed_tpu.ops.linear_attention.ssd import CHUNK, WINDOW, scan_chunks, walk_trips
 
 HELD = 4  # of 8 experts: one chip's share of two
 SIZES = {"attention_bias": False, "attention_multiplier": 0.0625, "embedding_multiplier": 12,
@@ -76,8 +76,8 @@ def close(got, wanted):
     np.testing.assert_allclose(got, wanted, atol=REL_TOL * np.abs(wanted).max(), rtol=0)
 
 
-def fresh_cache(dtype=jnp.float32):
-    return family.init_paged_cache(CFG, NB, BS, dtype=dtype, state_slots=SLOTS)
+def fresh_cache(dtype=jnp.float32, slots=SLOTS):
+    return family.init_paged_cache(CFG, NB, BS, dtype=dtype, state_slots=slots)
 
 
 FORWARD = jax.jit(functools.partial(family.forward_paged, CFG),
@@ -102,7 +102,7 @@ def step(params, cache, rows, t, bound=None, forward=FORWARD):
     n = 1 << (len(rows) - 1).bit_length()
     tokens, counts = np.zeros((n, t), np.int32), np.zeros(n, np.int32)
     starts, tables = np.zeros(n, np.int32), np.full((n, MAXB + 1), NB - 1, np.int32)
-    tables[:, -1] = SLOTS  # the trash slot
+    tables[:, -1] = cache[STATE]["ssm"].shape[1] - 1  # the trash slot
     for i, (toks, start, blocks, slot) in enumerate(rows):
         tokens[i, :len(toks)], counts[i], starts[i] = toks, len(toks), start
         tables[i, :len(blocks)], tables[i, -1] = blocks, slot
@@ -183,6 +183,39 @@ def test_a_compacted_mixed_step_gives_each_sequence_what_it_gets_alone(params):
     for leaf in ("conv", "ssm"):  # the slot no row named is untouched
         np.testing.assert_array_equal(np.asarray(after[STATE][leaf][:, 2]),
                                       np.asarray(cache[STATE][leaf][:, 2]))
+
+
+def test_a_pass_that_walks_more_rows_than_a_window_is_the_padded_pass_and_the_reference(params,
+                                                                                       forward):
+    """ISSUE 55: five prompt pieces (2, 30, 64, 65 and 9 tokens, each continuing
+    its sequence), a decode row and a prompt of one token that begins, in one
+    compacted pass of 176 slots: the two one-token rows go to the update kernel,
+    the five others are walked ``WINDOW`` a trip (two trips), and each row reads
+    what the padded pass gives it (the oracle: every row's chunks in place, no
+    window) and what the reference gives; both leave the same state in the rows'
+    slots."""
+    heads, pieces = (10, 8, 6, 5, 7, 8, 0), (2, 30, 64, 65, 9, 1, 1)
+    assert walk_trips(sum(p > 1 for p in pieces)) == 2 and sum(pieces) <= 176
+    seqs, at = [], 0
+    for i, (head, piece) in enumerate(zip(heads, pieces)):
+        blocks = -(-(head + piece) // BS)
+        seqs.append((ids_of(40 + i, head + piece), list(range(at, at + blocks)), 6 - i))
+        at += blocks
+    cache = fresh_cache(slots=8)
+    _, cache = step(params, cache, [(ids[:head], 0, blocks, slot)
+                                    for (ids, blocks, slot), head in zip(seqs, heads) if head], t=16,
+                    forward=forward)
+    rows = [(ids[head:], head, blocks, slot) for (ids, blocks, slot), head in zip(seqs, heads)]
+    mixed, after = step(params, cache, rows, t=256, bound=176, forward=forward)  # [8, 256] > 176
+    padded, oracle = step(params, cache, rows, t=256, forward=forward)
+    for i, (ids, _, slot) in enumerate(seqs):
+        close(mixed[i], padded[i])
+        close(mixed[i], want(params, ids, [len(ids) - 1])[0])
+        for leaf in ("conv", "ssm"):
+            close(np.asarray(after[STATE][leaf][:, slot]), np.asarray(oracle[STATE][leaf][:, slot]))
+    for leaf in ("conv", "ssm"):  # the slot no row named is untouched
+        np.testing.assert_array_equal(np.asarray(after[STATE][leaf][:, 7]),
+                                      np.asarray(cache[STATE][leaf][:, 7]))
 
 
 def test_rows_find_their_own_slots_in_whatever_order_the_slots_lie(params, forward):
@@ -356,13 +389,74 @@ def test_generate_through_chunks_and_the_fused_burst_is_the_references_greedy(pa
                      "state_bytes_per_seq": family.state_bytes_per_seq(CFG),
                      "state_slots_zeroed": before[1]["state_slots_zeroed"] + 6,
                      "prefix_declined_stateful": 0}
-    # the scan's counters: a pass that walks chunks counts its live tokens (a mixed pass's decode
-    # rows among them) in each of the nine Mamba-2 layers; a decode step or a burst walks none
+    # the scan's counters: a pass that walks chunks counts the tokens of its rows of more than one
+    # (ISSUE 55: a decode row beside them, a prompt's last piece of one token, are the update
+    # kernel's) in each of the nine Mamba-2 layers; a decode step or a burst walks none
     assert c["scan_positions"] == c["scan_chunks"] * CHUNK
     assert 0 < c["scan_live_positions"] <= c["scan_positions"]
     assert c["scan_live_positions"] % 9 == 0
-    assert sum(map(len, prompts)) <= c["scan_live_positions"] // 9 < c["live_tokens"]
+    # every prompt token but the pieces of one token the budget's cuts left (the wave below counts
+    # them launch by launch)
+    assert sum(map(len, prompts)) - len(prompts) <= c["scan_live_positions"] // 9 \
+        <= sum(map(len, prompts)) < c["live_tokens"]
     assert c["moe_routed_rows"] == c["live_tokens"] * 4 * 10
+    assert c["scan_overflow_windows"] == 0  # four slots: no pass holds more rows than a window
+    assert set(c) == set(eng.counters.FIELDS) | {"scan_overflow_windows"}
+
+
+def test_a_wave_of_decode_rows_beside_chunks_counts_what_its_scans_were_given(params):
+    """ISSUE 55, through the engine: six prompts admitted at once under a budget
+    of 32 over eight slots, so the first pass holds six prompt pieces (two trips
+    of the window) and the short prompts decode beside the long one's chunks.  The
+    tokens are the reference's greedy continuation; the three scan counters are
+    what each launch's rows say (the tokens of the rows of more than one token;
+    ``ceil(S / CHUNK) + WINDOW`` chunks a trip a layer for a compacted pass, a
+    row's chunks in place for a padded one) and the trips beyond a walk's first
+    are counted with them, on the host, at no fetch; and a family whose
+    one-token rows stay in its walk (Qwen3-Next's ``state_scan``) counts the same
+    launches as the parent did: every live token, ``ceil(S / 64) + n`` chunks."""
+    from deepspeed_tpu.inference.v2.fastpath import ServeCounters
+    from deepspeed_tpu.models import qwen3_next
+    eng = engine(params, seqs=8)
+    other = ServeCounters(scan=qwen3_next.state_scan(qwen3_next.Qwen3NextConfig.tiny()))
+    their_layers = other.scan[2]
+    assert not other.reads_spans and eng.counters.reads_spans
+    launches, count = [], eng.counters.count_slots
+
+    def recorded(n, t, b, live, blocks, **kw):
+        launches.append((n, t, kw.get("flat"), kw.get("passes", 1), live, kw.get("spans")))
+        other.count_slots(n, t, b, live, blocks, **{**kw, "spans": None})
+        count(n, t, b, live, blocks, **kw)
+    eng.counters.count_slots = recorded
+    prompts = [ids_of(60 + i, n) for i, n in enumerate((5, 6, 4, 7, 5, 40))]
+    got = eng.generate(prompts, max_new_tokens=4)
+    for p, g in list(zip(prompts, got))[::5]:
+        assert list(g) == greedy(params, p, 4)
+    chunks = positions = scanned = trips_beyond = theirs = their_live = 0
+    beside = False  # a pass in which a one-token row rode beside a walked one
+    for n, t, flat, passes, live, spans in launches:
+        walked = [count for _, count in spans if count > 1]
+        if t == 1:
+            continue
+        if flat is None:
+            here = n * -(-t // CHUNK)
+            theirs += here
+        else:
+            here = walk_trips(len(walked)) * (-(-flat // CHUNK) + WINDOW)
+            assert here == scan_chunks(n, t, flat, len(walked))
+            trips_beyond += max(walk_trips(len(walked)) - 1, 0)
+            theirs += -(-flat // 64) + n
+        chunks, scanned, their_live = chunks + here, scanned + sum(walked), their_live + live
+        beside |= bool(walked) and len(walked) < len(spans)
+    c = eng.counters.snapshot()
+    assert beside and trips_beyond >= 1
+    assert (c["scan_chunks"], c["scan_positions"], c["scan_live_positions"]) == \
+        (9 * chunks, 9 * chunks * CHUNK, 9 * scanned)
+    assert c["scan_overflow_windows"] == 9 * trips_beyond
+    assert (other.scan_chunks, other.scan_live_positions) == (their_layers * theirs,
+                                                              their_layers * their_live)
+    assert scanned < their_live  # the rows of one token are no part of Granite's walk
+    assert "scan_overflow_windows" not in other.snapshot() and other.scan_overflow_windows == 0
 
 
 def test_the_fast_path_and_the_padded_oracle_serve_the_same_tokens(params, served):

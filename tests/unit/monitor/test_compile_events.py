@@ -34,8 +34,11 @@ def named(name, body=lambda x: jnp.sin(x) * 2 + 1):
     return fn
 
 
-def stages_of(account, name, since=0):
-    return [row.stage for row in account.rows()[since:] if row.program == name]
+def stages_of(account, name):
+    """The stages recorded under ``name``: a test's names are its own, so nothing earlier carries
+    one (an index into ``rows()`` would not do: a thread keeps its newest ``MAX_ROWS`` rows, and in
+    a worker that has built hundreds of programs before this file the list no longer grows)."""
+    return [row.stage for row in account.rows() if row.program == name]
 
 
 # ------------------------------------------------------------- JAX's events
@@ -43,12 +46,11 @@ def stages_of(account, name, since=0):
 def test_a_named_program_gives_one_row_a_stage_under_its_one_name(account, how):
     name = f"fwd_n4_t1_b2_{how}"
     jitted, x = jax.jit(named(name)), jnp.ones(8)
-    before = len(account.rows())
     if how == "call":
         jitted(x)
     else:
         jitted.lower(jax.ShapeDtypeStruct((8, ), jnp.float32)).compile()
-    assert stages_of(account, name, before) == [TRACE, LOWER, LOAD]
+    assert stages_of(account, name) == [TRACE, LOWER, LOAD]
     program = account.by_program()[name]
     assert (program["traces"], program["lowers"], program["loads"]) == (1, 1, 1)
     assert program["trace_s"] > 0 and program["lower_s"] > 0 and program["load_s"] > 0
@@ -59,7 +61,7 @@ def test_a_named_program_gives_one_row_a_stage_under_its_one_name(account, how):
         arrivals = account.totals()["events"]
         jitted(x)
         assert account.totals()["events"] == arrivals
-        assert stages_of(account, name, before) == [TRACE, LOWER, LOAD]
+        assert stages_of(account, name) == [TRACE, LOWER, LOAD]
 
 
 @pytest.mark.parametrize("spelling", ["fwd_n32_t1_b20", "jit(fwd_n32_t1_b20)", "jit_fwd_n32_t1_b20"])

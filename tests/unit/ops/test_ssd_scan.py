@@ -7,7 +7,10 @@ are not multiples of the chunk, with and without a carried state, decays near 0
 and near 1, a scan continued from its state, and the one-token update; and the
 state BY REFERENCE (ISSUE 53): the rows' slots of a leaf of more slots than
 rows, in any order, a sequence that begins over whatever its slot holds, dead
-rows on one trash slot, and every slot no live row names left bit for bit."""
+rows on one trash slot, and every slot no live row names left bit for bit; and
+a pass of chunks (ISSUE 55, ``ssd_chunks``): the rows of one token served by the
+update kernel, the rest walked in windows of ``WINDOW`` rows whose layout is
+sized by the pass's tokens, in as many trips as the rows need."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -16,7 +19,8 @@ import pytest
 from chipbench.references.granite_moe_hybrid import selective_scan
 from deepspeed_tpu.ops import _pallas
 from deepspeed_tpu.ops.linear_attention import ssd
-from deepspeed_tpu.ops.linear_attention.ssd import CHUNK, scan_chunks, ssd_scan, ssd_update
+from deepspeed_tpu.ops.linear_attention.ssd import (CHUNK, WINDOW, scan_chunks, ssd_chunks, ssd_scan,
+                                                    ssd_update, walk_trips)
 
 H, P, NS = 16, 8, 16  # 16 heads: two grid steps of the scan kernel's eight
 TOL = 1e-5  # float32 throughout, of the largest value (``near``): a chunk's products against 64 steps
@@ -196,6 +200,61 @@ def test_the_state_is_read_and_written_in_the_rows_slots_alone(form, kernel, cas
         np.testing.assert_array_equal(after[slot], leaf[slot])
 
 
+MIXED = {  # one bucket's counts: rows of one token (begun and continued) beside rows that are walked
+    "every-length-in-two-trips": (0, 1, 1, 2, 63, 64, 65, 300),
+    "one-trip": (1, 70, 1, 5, 0, 1),
+    "three-trips": (2, 3, 1, 5, 0, 70, 2, 9, 4, 1, 66, 130),
+    "no-row-walked": (1, 0, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(MIXED))
+@pytest.mark.parametrize("layout", ["padded", "flat"])
+def test_a_pass_of_chunks_is_the_recurrence_whatever_its_rows_hold(form, layout, case):
+    """ISSUE 55: one pass whose rows hold 0, 1 (a sequence that begins over NaNs,
+    one that continues from its slot), 2, 63, 64, 65 and 300 tokens, in slots
+    that are neither the rows nor in order: the one-token rows through the update
+    kernel and the rest through the scan, ``WINDOW`` rows a trip (five walked
+    rows: two trips; ten: three; none: no trip), give the recurrence token by
+    token for every row, each row's slot holds its new matrices, and every slot
+    no live row names (the trash slot apart: the kernels pass rows by on it) is
+    bit for bit what it was."""
+    counts = MIXED[case]
+    n, walked = len(counts), sum(c > 1 for c in counts)
+    assert walk_trips(walked) == {"every-length-in-two-trips": 2, "one-trip": 1, "three-trips": 3,
+                                  "no-row-walked": 0}[case]
+    rng = np.random.default_rng(n)
+    slots = n + 3  # two slots no row names, and the trash slot last
+    at = rng.permutation(slots - 1)[:n]
+    begins = rng.random(n) < 0.4
+    begins[1:3] = (True, False)  # of two one-token rows, one begins and one continues
+    at[[i for i, c in enumerate(counts) if c == 0]] = slots - 1  # a dead row: the trash slot
+    leaf = rng.normal(size=(slots, H, P, NS)).astype(np.float32)
+    leaf[at[begins]] = np.nan
+    leaf[slots - 1] = 3.0
+    seqs = [draw(rng, max(c, 1)) for c in counts]
+    args = (jnp.asarray(A), jnp.asarray(D), jnp.asarray(leaf), jnp.asarray(at, jnp.int32),
+            jnp.asarray(begins), jnp.int32(slots - 1), jnp.asarray(counts, jnp.int32))
+    chunks = lambda x, dt, b, c, *where: ssd_chunks(x, dt, args[0], b, c, *args[1:], *where)
+    if layout == "padded":
+        y, after = chunks(*padded(seqs, counts, max(max(counts), 2)))
+        mine = lambda i, c: np.asarray(y[i, :c])
+    else:
+        arrays, row, col = flat(seqs, counts, sum(counts) + 11)
+        y, after = chunks(*arrays, row, col)
+        starts = np.cumsum((0, ) + counts)
+        mine = lambda i, c: np.asarray(y[0, starts[i]:starts[i] + c])
+    assert np.isfinite(np.asarray(y)).all()
+    after = np.asarray(after)
+    for i, c in enumerate(counts):
+        if c:
+            want, want_last = token_by_token([a[:c] for a in seqs[i]], None if begins[i] else leaf[at[i]])
+            near(mine(i, c), want)
+            near(after[at[i]], want_last)
+    for slot in set(range(slots - 1)) - {s for s, c in zip(at, counts) if c}:
+        np.testing.assert_array_equal(after[slot], leaf[slot])
+
+
 def test_bfloat16_operands_keep_the_state_and_the_decays_in_float32(form):
     """The chip's form: x, B and C bfloat16, dt and the state float32.  The new
     state is float32 and within bfloat16's rounding of the float32 scan's."""
@@ -216,4 +275,6 @@ def test_the_chunks_a_bucket_walks():
     assert CHUNK == 64 and ssd.SCAN_HEADS == 8 and ssd.UPDATE_HEADS == 32
     assert scan_chunks(32, 1) == 0  # a decode step: the one-token update
     assert scan_chunks(4, 256) == 16 and scan_chunks(4, 65) == 8
-    assert scan_chunks(32, 256, 512) == 8 + 32  # compacted: every sequence on a chunk's edge
+    # compacted: sized by the pass's tokens, WINDOW rows on chunk edges a trip, whatever the bucket's rows
+    assert scan_chunks(32, 256, 512) == scan_chunks(4, 256, 512) == 8 + WINDOW == 12
+    assert [scan_chunks(32, 256, 512, walked) for walked in (0, 1, 4, 5, 9)] == [0, 12, 12, 24, 36]

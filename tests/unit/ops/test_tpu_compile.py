@@ -295,7 +295,9 @@ SSM_ROW = 128 * 64 * 128 * 4       # one slot of it: a row's float32 matrices of
 def test_the_ssd_scan_compiles_at_the_cells_shapes(chip, budget, n):
     """ISSUE 52: the chunked-scan kernel at Granite 4.0-H's 128 heads of 64 x 128
     (B and C shared by all of them), for a compacted pass of ``budget`` tokens
-    over ``n`` sequences laid on chunk edges: one Mosaic kernel.  ISSUE 53: the
+    over ``n`` sequences, a window of them laid on chunk edges (ISSUE 55: ``ceil(budget
+    / CHUNK) + WINDOW`` chunks whatever ``n``; an empty chunk's step names the last
+    live one's blocks by the table's sixth row): one Mosaic kernel.  ISSUE 53: the
     carried matrices BY REFERENCE: the cell's whole flat leaf (1.25 GB) aliased in
     and out, a chunk's slot from the prefetched table, and not one row of it (4
     MB) held beside it: this program's temporaries are x laid out anew (an entry
@@ -305,9 +307,10 @@ def test_the_ssd_scan_compiles_at_the_cells_shapes(chip, budget, n):
 
     heads, p, ns = SSM_LEAF[1:]
     chunks = ssd.scan_chunks(n, 0, budget)
+    assert chunks == budget // ssd.CHUNK + ssd.WINDOW
     t = chunks * ssd.CHUNK
     scalars = (chip((heads, chunks, ssd.CHUNK), jnp.float32), ) * 3
-    avals = (chip((5, chunks), jnp.int32), chip((heads, t, p), jnp.bfloat16),
+    avals = (chip((6, chunks), jnp.int32), chip((heads, t, p), jnp.bfloat16),
              chip((t, ns), jnp.bfloat16), chip((t, ns), jnp.bfloat16), scalars,
              chip(SSM_LEAF, jnp.float32))
     compiled = jax.jit(lambda *a: ssd._walk_pallas(*a, interpret=False),

@@ -259,20 +259,26 @@ def test_the_pool_is_carried_and_written_in_place(chip, form):
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
-@pytest.mark.parametrize("n,t,bound,kernel,in_a_burst", [
-    (32, 1, 512, "ssd_update", False), (32, 256, 512, "ssd_scan", False),
-    (32, 1, None, "ssd_update", True)], ids=["decode", "compacted", "burst"])
-def test_a_state_leaf_by_reference_is_moved_by_its_kernels_alone(chip, n, t, bound, kernel,
+@pytest.mark.parametrize("n,t,bound,kernels,in_a_burst", [
+    (32, 1, 512, ("ssd_update", ), False), (32, 256, 512, ("ssd_update", "ssd_scan"), False),
+    (32, 1, None, ("ssd_update", ), True)], ids=["decode", "compacted", "burst"])
+def test_a_state_leaf_by_reference_is_moved_by_its_kernels_alone(chip, n, t, bound, kernels,
                                                                  in_a_burst):
     """ISSUE 53: Granite's ``ssm`` leaf goes to ``mix`` by reference.  In the
     cell's decode step (32 rows), its compacted chunk pass (512 slots) and a
     burst's body inside its loop no operation produces the rows' matrices
     ``f32[32,128,64,128]`` (the slot read, the select for "begins" and the 134
     MB they cost a layer are gone) and none
-    but the Mosaic kernel, once a Mamba-2 scan body, produces the leaf's shape
+    but the Mosaic kernels, each once a Mamba-2 scan body, produces the leaf's shape
     (no write back over the flat leaf ``f32[297,128,64,128]``, however folded);
     the leaf is aliased in and out and the program's temporaries are a fraction
-    of it."""
+    of it.  ISSUE 55: a chunk pass holds BOTH kernels a Mamba-2 layer (the update
+    for its rows of one token, the scan, inside a loop of trips, for the rest:
+    the leaf goes through that loop's carry uncopied) and its scan's layout is
+    sized by the pass's tokens: ``ceil(512 / CHUNK) + WINDOW`` chunks, and
+    nothing in the program has a dimension of the parent's ``ceil(512 / CHUNK)
+    + n`` chunks or of their positions."""
+    from deepspeed_tpu.ops.linear_attention import ssd
     module, cfg, params, kv = granite_shapes(chip, layers=10)
     ssm = kv["state"]["ssm"]
 
@@ -285,11 +291,46 @@ def test_a_state_leaf_by_reference_is_moved_by_its_kernels_alone(chip, n, t, bou
                        donate_argnums=(1, )).lower(params, kv, *ints).compile()
     text = compiled.as_text()
     calls = kernel_calls(text)
-    assert calls[kernel] == 2 and calls["paged_attention"] == calls["kv_write"] == 1, calls
+    assert {k: v for k, v in calls.items() if k.startswith("ssd_")} == dict.fromkeys(kernels, 2), calls
+    assert calls["paged_attention"] == calls["kv_write"] == 1, calls
     rows = list((n, ) + ssm.shape[2:])
     assert [opcode for opcode, _, shapes in results(text) if rows in shapes] == []
     whole = pool_shaped_results(text, (1, ) + ssm.shape)
-    assert [r[0] for r in whole] == ["custom-call"] * 2, whole
+    assert [r[0] for r in whole] == ["custom-call"] * 2 * len(kernels), whole
     memory = compiled.memory_analysis()
     leaf_bytes = int(np.prod(ssm.shape)) * 4
     assert memory.alias_size_in_bytes >= leaf_bytes and memory.temp_size_in_bytes < leaf_bytes // 4
+    if "ssd_scan" in kernels:
+        chunks, by_rows = ssd.scan_chunks(n, t, bound), -(-bound // ssd.CHUNK) + n
+        assert chunks == -(-bound // ssd.CHUNK) + ssd.WINDOW < by_rows
+        laid = {dim for _, _, shapes in results(text) for dims in shapes for dim in dims}
+        assert chunks * ssd.CHUNK in laid and not laid & {by_rows, by_rows * ssd.CHUNK}, laid
+
+
+# the lowered text of two families that share ``paged_forward``'s mixer contract (and
+# Qwen3-Next ``gated_delta.lay_on_chunk_edges``) with Granite, hashed at the parent of ISSUE 55
+PROGRAMS_BEFORE = {"qwen3_next_compacted": "083892485b503c49", "qwen3_next_padded": "637be00be201268f",
+                   "lfm2_compacted": "6dd10a4bfc4f41c2"}
+
+
+@pytest.mark.parametrize("case", sorted(PROGRAMS_BEFORE))
+def test_a_family_whose_state_goes_by_value_lowers_to_the_program_it_was(case):
+    """ISSUE 55 changes Granite's scan and what a ``StateRef`` carries, and nothing a
+    family whose leaves go by value traces: Qwen3-Next's chunk programs (the gated
+    delta scan keeps a chunk a row: ``ceil(S / 64) + n``) and LFM2's lower to the
+    text they lowered to before it."""
+    import hashlib
+    from deepspeed_tpu.models import lfm2, qwen3_next
+    module, cfg, bound = {
+        "qwen3_next_compacted": (qwen3_next, qwen3_next.Qwen3NextConfig.tiny(
+            experts=16, local_experts=4), 32),
+        "qwen3_next_padded": (qwen3_next, qwen3_next.Qwen3NextConfig.tiny(
+            experts=16, local_experts=4), None),
+        "lfm2_compacted": (lfm2, lfm2.Lfm2Config.tiny(), 32)}[case]
+    params = jax.eval_shape(lambda: module.init_params(cfg, jax.random.PRNGKey(0)))
+    kv = jax.eval_shape(lambda: module.init_paged_cache(cfg, 16, 8, dtype=jnp.float32, state_slots=5))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    text = jax.jit(lambda p, kv, tok, nt, sp, tab: module.forward_paged(
+        cfg, p, tok, nt, sp, tab, kv, block_size=8, live_token_bound=bound, last_rows=True)).lower(
+            params, kv, ints(4, 16), ints(4), ints(4), ints(4, 5)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PROGRAMS_BEFORE[case]
