@@ -299,6 +299,8 @@ class InferenceEngineV2:
             counted.update(selected=(*model_module.selected_keys(model_config), block_size))
         if hasattr(model_module, "pick_tallies"):  # picks whose kind only the device knows
             counted.update(tallied=model_module.pick_tallies(model_config))
+        if hasattr(model_module, "attention_windows"):  # layers that differ in their window count blocks
+            counted.update(windowed=(model_module.attention_windows(model_config), block_size))
         self.counters = ServeCounters(**counted)
         # serving performance observatory (ISSUE 16): the compile ledger is
         # always on (no clock reads, no device work) and is the single source
@@ -543,8 +545,8 @@ class InferenceEngineV2:
                            prewarmed=prewarmed, name=fwd.__name__, program=self._fwd_cache[key])
 
     def _selected_spans(self, spans):
-        """Each launched row's ``(start_pos, n_tokens)`` for the ``dsa_*`` and ``scan_*``
-        counters: a list only where the family's counters read it (nothing is built for any other)."""
+        """Each launched row's ``(start_pos, n_tokens)`` for the ``dsa_*``, ``scan_*`` and
+        ``kv_blocks_behind_window`` counters: a list only where the family's counters read it (nothing is built for any other)."""
         return list(spans) if self.counters.reads_spans else None
 
     def _cow_copy_block(self, src: int, dst: int) -> None:

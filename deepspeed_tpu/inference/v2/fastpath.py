@@ -152,6 +152,19 @@ class ServeCounters:
     rows with: every step of ``kernel_slots(t)`` blocks up to the sequence's
     length, selected or not (``ops/attention/paged.py`` masks what was not)
 
+    A family whose attention layers differ in their window (ISSUE 56; the model
+    module states ``attention_windows`` = one window a layer, None for a layer
+    that attends its whole past; absent from a snapshot for every other:
+    ``WINDOWED_FIELDS``), counted from each launched row's ``start_pos``
+    (``spans``) in whole blocks of the pool:
+    ``kv_blocks_behind_window``  blocks a live sequence holds WHOLLY BEHIND the
+    window of the step's first query token, and so of every later one, times the
+    windowed layers: what an allocator that frees by layer kind would hold no
+    longer (``live_blocks`` x the layers is what the one table a sequence holds).
+    It says what the one table keeps, not what the kernel fetched: that the
+    walk leaves these blocks alone the kernel's tests hold (poisoned blocks) and
+    the chip's trace shows (the kernel's time against its roofline)
+
     A family whose router may pick experts that compute nothing (ISSUE 49; the
     model module states ``pick_tallies``; absent from a snapshot for every
     other: ``TALLIED_FIELDS``).  Which kind a pick is only the device knows: the
@@ -187,13 +200,16 @@ class ServeCounters:
     TALLIED_FIELDS = ("moe_identity_picks", "moe_held_picks", "moe_overflow_windows")
     # the same for a family whose scan walks windows of its rows of several tokens (``walk_trips``)
     WALK_FIELDS = ("scan_overflow_windows", )
+    # the same for a family whose attention layers differ in their window (``windowed``)
+    WINDOWED_FIELDS = ("kv_blocks_behind_window", )
 
     def __init__(self, moe_picks: int = 0, moe_rows: Optional[Callable[[int], int]] = None,
                  kernel_slots: Callable[[int], int] = lambda t: 1,
                  attn_slots: Callable[[int, int], int] = lambda n, flat: flat,
                  scan: Optional[tuple] = None, selected: Optional[tuple] = None,
-                 tallied: Optional[tuple] = None):
-        for f in self.FIELDS + self.SELECTED_FIELDS + self.TALLIED_FIELDS + self.WALK_FIELDS:
+                 tallied: Optional[tuple] = None, windowed: Optional[tuple] = None):
+        for f in (self.FIELDS + self.SELECTED_FIELDS + self.TALLIED_FIELDS + self.WALK_FIELDS
+                  + self.WINDOWED_FIELDS):
             setattr(self, f, 0)
         self.moe_picks, self.moe_rows, self.kernel_slots = moe_picks, moe_rows, kernel_slots
         self.attn_slots = attn_slots
@@ -203,11 +219,13 @@ class ServeCounters:
         self.selected = selected  # (top-k, attention layers, the pool's block size)
         self.tallied = tallied  # the fields the device's running tallies are, in their order
         self._tallies_seen = (0, ) * len(tallied or ())
+        self.windowed = windowed  # (one window a layer, None for a full one; the pool's block size)
 
     @property
     def reads_spans(self) -> bool:
         """Whether :meth:`count_slots` reads ``spans``: nothing is built for it otherwise."""
-        return self.selected is not None or self.walk_trips is not None
+        return (self.selected is not None or self.walk_trips is not None
+                or self.windowed is not None)
 
     def count_slots(self, n: int, t: int, b: int, live_tokens: int,
                     live_blocks: int, passes: int = 1,
@@ -252,6 +270,18 @@ class ServeCounters:
         self.compact_passes += passes if flat is not None else 0
         if self.selected is not None and spans:
             self._count_selected(t, b, spans, passes)
+        if self.windowed is not None and spans:
+            self._count_windowed(spans, passes)
+
+    def _count_windowed(self, spans, passes: int) -> None:
+        from ...ops.attention.paged import walk_first_block
+        windows, bs = self.windowed
+        for window in set(windows) - {None}:
+            layers = windows.count(window)
+            for first, count in spans:  # a burst's row moves ``count`` tokens a pass
+                self.kv_blocks_behind_window += layers * sum(
+                    walk_first_block(start, window, bs)
+                    for start in range(first, first + passes * count, count))
 
     def _count_selected(self, t: int, b: int, spans, passes: int) -> None:
         from ...ops.attention.dsa import INDEX_BLOCKS, token_tile
@@ -287,7 +317,8 @@ class ServeCounters:
     def _reported(self) -> Tuple[str, ...]:
         return (self.FIELDS + (self.SELECTED_FIELDS if self.selected is not None else ())
                 + (self.TALLIED_FIELDS if self.tallied is not None else ())
-                + (self.WALK_FIELDS if self.walk_trips is not None else ()))
+                + (self.WALK_FIELDS if self.walk_trips is not None else ())
+                + (self.WINDOWED_FIELDS if self.windowed is not None else ()))
 
     def snapshot(self) -> Dict[str, int]:
         return {f: int(getattr(self, f)) for f in self._reported()}

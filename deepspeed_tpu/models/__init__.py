@@ -1,5 +1,6 @@
-from . import (bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, granite_moe_hybrid, lfm2, llama,
+from . import (afmoe, bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, granite_moe_hybrid, lfm2, llama,
                longcat_flash, mistral, mixtral, olmoe, opt, phi, qwen, transformer)
+from .afmoe import AfmoeConfig
 from .bert import BertConfig
 from .bloom import BloomConfig
 from .deepseek_v2 import DeepseekV2Config

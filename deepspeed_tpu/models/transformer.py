@@ -14,6 +14,7 @@ Attention routes through ``attention_fn`` so Ulysses sequence parallelism
 DistributedAttention wrapping "any local attention" (deepspeed/sequence/layer.py:60).
 """
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable, NamedTuple, Optional
@@ -568,7 +569,14 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
       biases; ``live`` is the ``[b, s]`` mask of slots that hold a token;
     - ``head(x) -> logits``: final norm, tied or untied head, bias, TP gather;
 
-    and the facts the kernel needs: ``window`` (Mistral's sliding window),
+    and the facts the kernel needs: ``window`` (Mistral's sliding window: one
+    value for every layer; or, for a family whose layers differ in it, a LIST
+    shaped like ``layers``: a stack's one value, a period's tuple of one value a
+    layer of the period, None for a layer that attends its whole past, so a
+    layer's window is its place in the period and static in its trace; the
+    kernel's walk begins at the first block a windowed layer's queries can see,
+    ``ops/attention/paged.py``; the layer's kernel and projections then lie under
+    the scope ``attn_window`` or ``attn_full``),
     ``alibi_slopes`` ([H] local heads, BLOOM), and for a family whose scores
     are not ``q . k / sqrt(Dh)`` over a pool of K and a pool of V its
     ``softmax_scale`` (None: one over the root of q's width) and
@@ -779,8 +787,17 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     with jax.named_scope("kv_write"):
         plan = write_plan(flat_pools, n_tokens, start_pos, block_tables, t=t, slots=slots)
 
-    def attention_layer(x, pools, lp, l, handed=None):
-        with jax.named_scope("attn_qkv"):
+    stacks = layers if isinstance(layers, list) else [layers]
+    by_layer = isinstance(window, list)  # the family told one a layer, shaped like ``layers``
+    windows = window if by_layer else [window] * len(stacks)
+
+    def kind_scope(window):  # the layer's kind, where layers differ in it
+        if not by_layer:
+            return contextlib.nullcontext()
+        return jax.named_scope("attn_full") if window is None else jax.named_scope("attn_window")
+
+    def attention_layer(x, pools, lp, l, window, handed=None):
+        with jax.named_scope("attn_qkv"), kind_scope(window):
             q, *rows, kept = qkv(lp, x, safe_pos)
         # this step's rows, in place: pool[l*NB + blk, h, off] = k[n, t, h]
         first = l * num_blocks  # the layer's first row of the flat stack
@@ -791,7 +808,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
         facts = dict(block_size=block_size, softmax_scale=softmax_scale, window=window,
                      alibi_slopes=alibi_slopes, value_dim=value_dim)
         attended = pools
-        with jax.named_scope("attn_kernel"):
+        with jax.named_scope("attn_kernel"), kind_scope(window):
             if selection is not None:
                 # the index-key leaf is scored, not attended: the kernel's pools are the others
                 q_i, w = selection.indexer(lp, kept)
@@ -838,8 +855,9 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     carry = (x, *flat_pools, *(leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in state_leaves))
     done = {False: 0, True: 0}  # attention / mixer layers behind the stack being scanned
     left_over = []  # with ``hand_on``: what each stack's periods handed to no layer
-    for stack in layers if isinstance(layers, list) else [layers]:
+    for stack, here in zip(stacks, windows):
         period = stack if isinstance(stack, tuple) else (stack, )
+        here = here if isinstance(here, tuple) else (here, ) * len(period)  # a layer's own, by its place
         mixes = [STATE_MIXER in lp for lp in period]  # by what a layer's parameters hold
         depth = jax.tree_util.tree_leaves(period[0])[0].shape[0]
         # each kind's index of a period's first layer of that kind: the pool's and the state's row
@@ -849,7 +867,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                                    dtype=jnp.int32)
                   for kind in set(mixes)}
 
-        def body(carry, inp, mixes=mixes):
+        def body(carry, inp, mixes=mixes, here=here):
             (x, *pools), (lps, first) = carry, inp
             handed = None  # a period begins with nothing handed, and what it ends with leaves the scan
             for j, (lp, is_mix) in enumerate(zip(lps, mixes)):
@@ -859,9 +877,10 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                     x, pools[len(flat_pools):] = mixer_layer(x, pools[len(flat_pools):], lp, l)
                 elif hand_on:
                     x, pools[:len(flat_pools)], handed = attention_layer(
-                        x, pools[:len(flat_pools)], lp, l, handed)
+                        x, pools[:len(flat_pools)], lp, l, here[j], handed)
                 else:
-                    x, pools[:len(flat_pools)] = attention_layer(x, pools[:len(flat_pools)], lp, l)
+                    x, pools[:len(flat_pools)] = attention_layer(x, pools[:len(flat_pools)], lp, l,
+                                                                 here[j])
             return (x, *pools), handed
 
         carry, left = jax.lax.scan(body, carry, (period, firsts))

@@ -14,7 +14,7 @@ who captures a profile of a running server asks the engine for the same table
 
 - :data:`SCOPES` is the ONE list of scope names, as ``monitor.perf.PHASES`` is
   the one list of phases: a ``jax.named_scope`` anywhere under
-  ``deepspeed_tpu/`` takes a name of it (a source scan in
+  ``deepspeed_tpu/`` takes a name of it, or of :data:`KINDS` (a source scan in
   ``tests/unit/monitor/test_program_scopes.py`` holds that).
 - :func:`scope_table` is a pure function over the text of an optimized module
   (``jax.stages.Compiled.as_text()``).
@@ -64,7 +64,12 @@ SCOPES = (
     # the train step (runtime/engine.py)
     "forward_backward", "grad_norm_clip", "optimizer",
 )
-_SCOPE_SET = frozenset(SCOPES)
+# A layer's KIND, where a family's attention layers differ in their window (``paged_forward``
+# opens one inside ``attn_qkv`` and ``attn_kernel``): kept on an instruction's path like a scope, and
+# no part of a step of its own, so in no group: the operations stay their enclosing scope's
+# (the benchmark's groups are made of :data:`SCOPES`, name for name).
+KINDS = ("attn_window", "attn_full")
+_SCOPE_SET = frozenset(SCOPES + KINDS)
 
 _OP_NAME = 'op_name="'
 _HEADER = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
@@ -78,7 +83,7 @@ _NOT_A_SCOPE = ("jit", "pjit")  # jit(head) is a function of that name, not a sc
 
 
 class Path(tuple):
-    """The names of :data:`SCOPES` on an instruction's path, outermost first.
+    """The names of :data:`SCOPES` and :data:`KINDS` on an instruction's path, outermost first.
     ``mixed``: for a fusion, the innermost scopes of the instructions fused into
     it that are not its own.  ``inherited``: the path is not the instruction's
     own but that of the only instructions that read it.  ``ambiguous``: several

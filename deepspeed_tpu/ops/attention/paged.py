@@ -128,6 +128,17 @@ thing.  A grid step that does no arithmetic (past a sequence's last live
 block, or of a row of the bucket that holds no token) asks for the window the
 live step before it took, so the pipeline fetches nothing for it.
 
+**A window's walk begins at the window** (ISSUE 56).  With ``window`` given, no
+query token of the step sees a key older than ``start_pos - window + 1``, so the
+blocks wholly behind that position are not walked: the plan's last row names the
+table slot the walk begins at (:func:`walk_first_block`), ``BLOCKS`` counts the
+live blocks from there, a step's copies take ``tables[n, first + j]`` and its key
+positions begin at ``first * bs``.  Nothing else of the body knows: the steps, the
+fetch one step ahead, the softmax state and the output's leaving are a shorter
+sequence's.  The window's other edge, and its near edge inside the first block,
+stay the mask's.  Under a ``selection`` (whose tiles lie by the table's own
+steps) the walk begins at the first slot as before.
+
 Off-TPU falls back to the dense gather + masked sdpa (identical math; tests
 compare the two).
 """
@@ -320,30 +331,50 @@ def _last_live_step(b, blocks, slots: int):
 BLOCKS, ROW0, SPLITS = 0, 1, 2
 
 
-def _fetch_plan(lengths, n_tokens, row0, bs: int, maxb: int, group: int, rows: int, splits: int):
-    """[2 or 3, N + 1] int32 (``lengths``, ``n_tokens`` and ``row0`` come as
-    int32): what the kernel's fetch asks of a sequence, worked out once a call
-    and not once a grid step.  Row ``BLOCKS``: the table slots that name a live
-    block of a sequence that holds a token (0 for a row of the bucket that holds
-    none: its blocks are never fetched).  Row ``ROW0``: where the sequence's q
-    rows begin on the kernel's row axis, and its output's, in sublane tiles of
-    SMALL_ROWS (the compiler must see that a window begins on a whole one).
-    Only where a KV head's rows are cut into ``splits`` grid steps, row
-    ``SPLITS``: the splits that hold a token.  Column N is the sequence past the
-    last: no blocks, no fetch."""
+def walk_first_block(start_pos: int, window: Optional[int], bs: int) -> int:
+    """The first table slot the walk of a sequence takes in a step whose first
+    query token sits at ``start_pos``: the block of the oldest key that token
+    sees through ``window`` (every later query sees no older one); 0 without a
+    window.  Host integers: ``_fetch_plan`` states the same for the kernel
+    (its tests hold the two together), and ``ServeCounters`` counts with this
+    the blocks a sequence's one table keeps behind a window."""
+    return 0 if window is None else max(start_pos - (window - 1), 0) // bs
+
+
+def _fetch_plan(lengths, n_tokens, row0, bs: int, maxb: int, group: int, rows: int, splits: int,
+                start_pos=None, window: Optional[int] = None):
+    """[2 to 4, N + 1] int32 (``lengths``, ``n_tokens``, ``row0`` and
+    ``start_pos`` come as int32): what the kernel's fetch asks of a sequence,
+    worked out once a call and not once a grid step.  Row ``BLOCKS``: the table
+    slots the walk takes, those that name a live block of a sequence that holds
+    a token (0 for a row of the bucket that holds none: its blocks are never
+    fetched).  Row ``ROW0``: where the sequence's q rows begin on the kernel's
+    row axis, and its output's, in sublane tiles of SMALL_ROWS (the compiler
+    must see that a window begins on a whole one).  Only where a KV head's rows
+    are cut into ``splits`` grid steps, row ``SPLITS``: the splits that hold a
+    token.  Only with a ``window``, the LAST row: the table slot the walk begins
+    at (:func:`walk_first_block`: the blocks wholly behind the window of the
+    step's first query token, which no query of the step can see, are not
+    walked), and ``BLOCKS`` counts from there.  Column N is the sequence past
+    the last: no blocks, no fetch."""
     i32 = np.int32  # numpy scalars are literals of the trace: no equation, no jnp wrapper
     blocks = lax.min(lax.div(lax.add(lengths, i32(bs - 1)), i32(bs)), i32(maxb))
+    if window is not None:
+        first = lax.div(lax.max(lax.sub(start_pos, i32(window - 1)), i32(0)), i32(bs))
+        blocks = lax.sub(blocks, first)
     plan = [lax.select(lax.gt(n_tokens, i32(0)), blocks, lax.full_like(blocks, 0)), row0]
     if splits > 1:
         plan.append(lax.min(lax.div(lax.add(lax.mul(n_tokens, i32(group)), i32(rows - 1)), i32(rows)),
                             i32(splits)))
+    if window is not None:
+        plan.append(first)
     plan = lax.concatenate([lax.expand_dims(row, (0, )) for row in plan], 0)
     return lax.pad(plan, i32(0), ((0, 0, 0), (0, 1, 0)))
 
 
 def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
                   scale, block_size, group, kvg, tile, slots, head_steps, splits, window, alibi,
-                  value_dim, selected=False):
+                  value_dim, selected=False, begins=None):
     # Every program traces and lowers this body once, and a cell meets 38-70
     # programs: scalars and equal shapes go through ``lax`` (a jnp operator
     # costs five times as much to trace), a ``pl.when`` costs 2-3 ms (so what
@@ -384,7 +415,8 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
         def one(j, _):
             at = pl.ds(pl.multiple_of(lax.mul(lax.sub(j, first), block_size), block_size),
                        block_size)
-            blk = tables_ref[i, j]
+            # a windowed walk counts its slots from the first block a query of the step sees
+            blk = tables_ref[i, j if begins is None else lax.add(j, plan_ref[begins, i])]
             for p, (hbm, tiles) in enumerate(pools):
                 act(pltpu.make_async_copy(hbm.at[blk, heads], tiles.at[half, :, at, :],
                                           sems.at[half, p]))
@@ -423,6 +455,8 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
         tok = lax.div(row, group)
         qp = lax.add(tok, start)  # absolute query positions
         kpos = lax.add(lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2), lax.mul(b, keys))
+        if begins is not None:  # the walk's step 0 holds the table slot it began at
+            kpos = lax.add(kpos, lax.mul(plan_ref[begins, n], block_size))
         if alibi:
             # ALiBi key-only form: slope_h * absolute key index (softmax-
             # equivalent to the relative-distance form per query row —
@@ -750,11 +784,15 @@ def _walk(qr, row0, kpool, vpool, tables, lengths, start_pos, n_tokens, *, group
     kvg, rows, splits, tile, slots = shape
     dv = dh if value_dim is None else value_dim
     alibi = alibi_slopes is not None
-    check_block_table_fits(n, maxb, n_vectors=(4 if alibi else 3) + 3)  # and the plan's rows
+    # a window's walk begins at the first block a query of the step sees; under a selection
+    # (whose tiles lie by the table's own steps) every live block is walked as before
+    skips = window is not None and selection is None
+    check_block_table_fits(n, maxb, n_vectors=(4 if alibi else 3) + 3 + skips)  # and the plan's rows
     kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs, group=group,
                                kvg=kvg, tile=tile, slots=slots, head_steps=kvh // kvg,
                                splits=splits, window=window, alibi=alibi, value_dim=value_dim,
-                               **({} if selection is None else {"selected": True}))
+                               **({} if selection is None else {"selected": True}),
+                               **({"begins": 2 + (splits > 1)} if skips else {}))
     pools = (kpool, vpool) if value_dim is None else (kpool, )
     def q_window(ni, g, r, b, tables, lengths, start, ntok, plan, *_):
         """Where the step's q rows begin on the flat axis (an element, not a
@@ -806,7 +844,8 @@ def _walk(qr, row0, kpool, vpool, tables, lengths, start_pos, n_tokens, *, group
     tables, lengths, start_pos, n_tokens = (lax.convert_element_type(x, jnp.int32)
                                             for x in (tables, lengths, start_pos, n_tokens))
     scalars = [tables, lengths, start_pos, n_tokens,
-               _fetch_plan(lengths, n_tokens, row0, bs, maxb, group, rows, splits)]
+               _fetch_plan(lengths, n_tokens, row0, bs, maxb, group, rows, splits,
+                           *((start_pos, window) if skips else ()))]
     if alibi:
         scalars.append(jnp.asarray(alibi_slopes, jnp.float32))
     # rows no window writes come back zero: the output begins as zeros, aliased in
