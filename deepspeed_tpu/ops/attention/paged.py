@@ -88,6 +88,29 @@ SMALL_ROWS for a decode row riding in a chunk's bucket) and the other rows are
 zeros.  A sequence's first step starts the softmax state instead of reading it,
 so nothing is initialised apart.
 
+**A row tile works only on the steps it sees** (ISSUE 57).  A tile's rows are a
+few consecutive tokens, and those see a contiguous band of the walk's steps:
+from the step that holds the oldest key the tile's FIRST token sees through the
+window (the walk's first step without one) to the step that holds its LAST
+token's own position.  Outside the band the mask would throw everything away
+(``p`` is 0, ``corr`` is 1: the state stays what it is), so the product, the
+mask, the two ``exp`` passes and the read and write of the accumulator are not
+done: the tile loop of a live step runs over ``[lo, hi)`` of :func:`tile_band`,
+the tiles below ``lo`` lying wholly before the step's first key (a chunk's
+causal edge: a token does not multiply the keys of the tokens behind it in its
+own chunk, by whole steps) and those from ``hi`` wholly past the window behind
+the step's last key (a window's near edge: a chunk of T tokens is no longer ONE
+window of T + window keys a token).  A tile that skipped the walk's first steps
+has no state to read at its own first one, so a tile's state starts at
+:func:`tile_first_step`, not at step 0; after its band a tile's state is
+complete and waits for the sequence's last live step, where every live tile is
+divided and leaves as before.  The FETCH did not move: a step's blocks serve all
+the tiles of the step, some tile of the sequence sees every live step, and the
+copy one step ahead knows nothing of tiles.  The two paths that never enter the
+tile loop (a window of at most SMALL_ROWS rows, a decode row in a chunk's bucket)
+are what they were, and a latent cache's decode step (one token of 128 rows, one
+tile) has the band of every live step.
+
 **The tile is chosen from the static shapes** (``step_tile``: T, H, KV, Dh,
 bs and the two dtypes against one VMEM budget, ``VMEM_BUDGET_BYTES``): all KV
 heads a step wherever q, out, the accumulators and the double-buffered K/V
@@ -135,9 +158,11 @@ table slot the walk begins at (:func:`walk_first_block`), ``BLOCKS`` counts the
 live blocks from there, a step's copies take ``tables[n, first + j]`` and its key
 positions begin at ``first * bs``.  Nothing else of the body knows: the steps, the
 fetch one step ahead, the softmax state and the output's leaving are a shorter
-sequence's.  The window's other edge, and its near edge inside the first block,
-stay the mask's.  Under a ``selection`` (whose tiles lie by the table's own
-steps) the walk begins at the first slot as before.
+sequence's.  The window's near edge is the row tiles' (above: a tile leaves out
+the steps wholly past its own tokens' windows); inside a step both edges, and the
+far edge inside the walk's first block, stay the mask's.  Under a ``selection``
+(whose tiles lie by the table's own steps) the walk begins at the first slot as
+before, and the band counts its steps from there.
 
 Off-TPU falls back to the dense gather + masked sdpa (identical math; tests
 compare the two).
@@ -372,6 +397,43 @@ def _fetch_plan(lengths, n_tokens, row0, bs: int, maxb: int, group: int, rows: i
     return lax.pad(plan, i32(0), ((0, 0, 0), (0, 1, 0)))
 
 
+def tile_band(k0, first_row, live, start, *, tile: int, group: int, keys: int, window: Optional[int]):
+    """``(lo, hi)``: the row tiles ``[lo, hi)`` of a live grid step that see at least
+    one of the step's keys ``[k0, k0 + keys)``.  The step's rows are ``first_row +
+    [0, live)`` of a sequence whose query tokens sit at positions ``start, start + 1,
+    ...`` (row = token x ``group`` + head of the group).  Tile ``i`` (the step's rows
+    ``i x tile ...``) sees a key iff the step's first key is no later than the tile's
+    LAST token (causal) and, with a ``window``, the step's last key is newer than what
+    the tile's FIRST token has left behind; both are monotone in ``i``, so the tiles
+    are one range.  Exact where the live context ends at the last query token
+    (``lengths = start_pos + n_tokens``, as every caller has it: a live step then
+    begins inside the context and the last step's keys past it are no token's to see);
+    with a longer or shorter context never a tile too few, and none before its own
+    first step.  Scalars through ``lax`` (the kernel calls this inside its traced
+    body; plain integers do as well): integer division only, truncating, each
+    quotient guarded where its dividend can be negative."""
+    tiles = lax.div(lax.add(live, tile - 1), tile)
+    # the first of the step's rows whose token sits at or past the step's first key
+    ahead = lax.sub(k0, start)
+    lo = lax.max(lax.div(lax.sub(lax.mul(ahead, group), first_row), tile), 0)
+    if window is None:
+        return lo, tiles
+    # how many of the step's rows have tokens that still see the step's last key
+    near = lax.sub(lax.mul(lax.add(ahead, keys - 1 + window), group), first_row)
+    return lo, lax.min(tiles, lax.div(lax.add(near, tile - 1), tile))
+
+
+def tile_first_step(row, start, base, *, group: int, keys: int, window: Optional[int]):
+    """The step of the walk at which the band of the row tile that begins at ``row``
+    of its sequence begins: the step that holds the oldest key the tile's first token
+    sees through ``window`` (the walk's keys begin at ``base``); 0 without a window.
+    A tile's softmax state starts there (``begun`` in ``attend``)."""
+    if window is None:
+        return 0
+    oldest = lax.sub(lax.add(lax.div(row, group), start), window - 1)
+    return lax.max(lax.div(lax.sub(oldest, base), keys), 0)
+
+
 def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
                   scale, block_size, group, kvg, tile, slots, head_steps, splits, window, alibi,
                   value_dim, selected=False, begins=None):
@@ -397,6 +459,11 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
     keys = slots * block_size
     length, start, ntok = lengths_ref[n], start_ref[n], ntok_ref[n]
     first_row = lax.mul(r, rows)
+    k0 = lax.mul(b, keys)  # the step's first key
+    base = 0
+    if begins is not None:  # the walk's step 0 holds the table slot it began at
+        base = lax.mul(plan_ref[begins, n], block_size)
+        k0 = lax.add(k0, base)
     # row = token * group + (q head within the KV head's group): the rows that
     # hold a token are a prefix, so leaving the others out is a loop bound
     live = lax.max(lax.min(lax.sub(lax.mul(ntok, group), first_row), rows), 0)
@@ -440,11 +507,12 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
     if splits > 1:
         step_live = lax.bitwise_and(step_live, lax.lt(r, plan_ref[SPLITS, n]))
 
-    def attend(r0, size):
+    def attend(r0, size, first=0):
         """Rows [r0, r0 + size) of every local KV head against the step's
         blocks: one batched product over the heads and over all the step's
         keys, [kvg, size, Dh] x [kvg, slots * bs, Dh], and one softmax update
-        (the first step's starts the state: nothing is read of what VMEM held)."""
+        (the rows' first step, ``first``, starts the state: nothing is read of
+        what VMEM held)."""
         at = pl.ds(r0, size)
         k = k_buf[half]  # [kvg, slots * bs, Dh], the pool's dtype
         v = v_buf[half] if value_dim is None else lax.slice_in_dim(k, 0, value_dim, axis=2)
@@ -454,9 +522,7 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
         row = lax.add(lax.broadcasted_iota(jnp.int32, (1, size, 1), 1), lax.add(first_row, r0))
         tok = lax.div(row, group)
         qp = lax.add(tok, start)  # absolute query positions
-        kpos = lax.add(lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2), lax.mul(b, keys))
-        if begins is not None:  # the walk's step 0 holds the table slot it began at
-            kpos = lax.add(kpos, lax.mul(plan_ref[begins, n], block_size))
+        kpos = lax.add(lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2), k0)
         if alibi:
             # ALiBi key-only form: slope_h * absolute key index (softmax-
             # equivalent to the relative-distance form per query row —
@@ -493,7 +559,7 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
         s = lax.select(mask, s, lax.full_like(s, NEG_INF))
 
         column = (kvg, size, 1)  # a number a row
-        begun = lax.broadcast(lax.gt(b, 0), column)
+        begun = lax.broadcast(lax.gt(b, first), column)
         m_prev = lax.select(begun, m_sc[:, at, 0:1], lax.full(column, NEG_INF, jnp.float32))
         m_new = lax.max(m_prev, lax.expand_dims(lax.reduce_max(s, (2, )), (2, )))
         p = lax.select(mask, lax.exp(lax.sub(s, m_new)), lax.full_like(s, 0.0))
@@ -550,9 +616,10 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
         turn[0] = other
         turn[1] = lax.convert_element_type(
             lax.bitwise_or(stay, lax.gt(plan_ref[BLOCKS, n1], 0)), jnp.int32)
-        # every row tile that holds a token, all local KV heads at once: tiles of
-        # ``tile``, or the one of SMALL_ROWS where no more rows are live (a decode
-        # row in a chunk's bucket does a decode row's work)
+        # every row tile that holds a token and sees a key of the step
+        # (``tile_band``), all local KV heads at once: tiles of ``tile``, or the
+        # one of SMALL_ROWS where no more rows are live (a decode row in a
+        # chunk's bucket does a decode row's work)
         if rows <= SMALL_ROWS:
             attend(0, rows)
         else:
@@ -560,8 +627,13 @@ def _paged_kernel(tables_ref, lengths_ref, start_ref, ntok_ref, plan_ref, *rest,
 
             @pl.when(lax.gt(live, SMALL_ROWS))
             def _tiles():
-                lax.fori_loop(0, pl.cdiv(live, tile),
-                              lambda i, _: attend(pl.multiple_of(lax.mul(i, tile), tile), tile), None)
+                def work(i, _):
+                    r0 = pl.multiple_of(lax.mul(i, tile), tile)
+                    attend(r0, tile, tile_first_step(lax.add(first_row, r0), start, base, group=group,
+                                                     keys=keys, window=window))
+
+                lax.fori_loop(*tile_band(k0, first_row, live, start, tile=tile, group=group, keys=keys,
+                                         window=window), work, None)
 
         # A window's output leaves by a copy of the kernel's own, at the window's
         # last live step: its live rows alone.  The one tile of a decode row is
