@@ -4,7 +4,8 @@ Reference ``build_hf_engine`` (inference/v2/engine_factory.py:66): resolves the
 model's policy by HF ``model_type`` and assembles InferenceEngineV2.  Supported:
 llama, mistral (sliding window), mixtral (MoE), olmoe (64-expert top-8 MoE with
 QK-norm; serving only, training not supported), afmoe (windowed and full attention
-layers mixed, a sigmoid-routed MoE; serving only), opt, falcon, phi, qwen2, gptj.
+layers mixed, a sigmoid-routed MoE; serving only), bailing_hybrid (Kimi Delta Attention
+and latent attention layers mixed; serving only), opt, falcon, phi, qwen2, gptj.
 (BLOOM serves through the v1 engine — ALiBi needs the biased dense attention,
 models/bloom.py.)
 """
@@ -16,9 +17,10 @@ from .engine_v2 import InferenceEngineV2
 
 
 def _registry():
-    from ...models import afmoe, falcon, gptj, llama, mistral, mixtral, olmoe, opt, phi, qwen
+    from ...models import afmoe, bailing_hybrid, falcon, gptj, llama, mistral, mixtral, olmoe, opt, phi, qwen
     return {
         "afmoe": (afmoe, afmoe.config_from_hf),
+        "bailing_hybrid": (bailing_hybrid, bailing_hybrid.config_from_hf),
         "llama": (llama, llama.config_from_hf),
         "mistral": (mistral, mistral.config_from_hf),
         "mixtral": (mixtral, None),  # config built field-by-field below

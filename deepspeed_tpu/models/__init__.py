@@ -1,6 +1,7 @@
-from . import (afmoe, bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, granite_moe_hybrid, lfm2, llama,
+from . import (afmoe, bailing_hybrid, bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, granite_moe_hybrid, lfm2, llama,
                longcat_flash, mistral, mixtral, olmoe, opt, phi, qwen, transformer)
 from .afmoe import AfmoeConfig
+from .bailing_hybrid import BailingHybridConfig
 from .bert import BertConfig
 from .bloom import BloomConfig
 from .deepseek_v2 import DeepseekV2Config

@@ -181,8 +181,11 @@ def mla_qkv(config, a, h, safe_pos, inv_freq, table_scale: float, width: int):
     H, rank, rope = config.num_heads, config.kv_lora_rank, config.qk_rope_head_dim
     nope, dv = config.qk_nope_head_dim, config.v_head_dim
     dtype = h.dtype
-    c_q = rms_norm(h @ a["wq_a"].astype(dtype), a["q_norm"], config.rms_eps)
-    q = (c_q @ a["wq_b"].astype(dtype)).reshape(h.shape[:2] + (H, nope + rope))
+    if "wq_a" in a:
+        c_q = rms_norm(h @ a["wq_a"].astype(dtype), a["q_norm"], config.rms_eps)
+        q = (c_q @ a["wq_b"].astype(dtype)).reshape(h.shape[:2] + (H, nope + rope))
+    else:  # a full-rank query (``q_lora_rank`` null: bailing_hybrid): ``c_q`` is None
+        c_q, q = None, (h @ a["wq"].astype(dtype)).reshape(h.shape[:2] + (H, nope + rope))
     kv = h @ a["wkv_a"].astype(dtype)
     c_kv = rms_norm(kv[..., :rank], a["kv_norm"], config.rms_eps)
     q_pe = rotate_pairs(q[..., nope:], safe_pos, inv_freq, table_scale)
@@ -196,16 +199,21 @@ def mla_qkv(config, a, h, safe_pos, inv_freq, table_scale: float, width: int):
     return to_lanes(jnp.concatenate([q_lat, q_pe], axis=-1)), latent, c_q
 
 
-def mla_out(config, a, attn):
+def mla_out(config, a, attn, gate=None):
     """What a layer's attention adds to the residual stream: ``attn`` ``[b, s,
     H, kv_lora_rank]``, the kernel's weighted sums of latents, out of the latent
-    through ``W_kvb[v]`` and through ``W_o``."""
+    through ``W_kvb[v]`` and through ``W_o``; ``gate`` ``[b, s, H]`` float32
+    (bailing_hybrid's head-wise output gate; None: none) scales each head's
+    output by its sigmoid between the two."""
     H, rank = config.num_heads, config.kv_lora_rank
     nope, dv = config.qk_nope_head_dim, config.v_head_dim
     dtype = attn.dtype
     with jax.named_scope("mla_absorb"):
         w_v = a["wkv_b"].astype(dtype).reshape(rank, H, nope + dv)[..., nope:]
         heads = jnp.einsum("bshc,chd->bshd", attn, w_v)
+    if gate is not None:
+        with jax.named_scope("attn_gate"):
+            heads = (heads.astype(jnp.float32) * jax.nn.sigmoid(gate)[..., None]).astype(dtype)
     return heads.reshape(attn.shape[:2] + (H * dv, )) @ a["wo"].astype(dtype)
 
 

@@ -616,7 +616,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
 
     **Layers without attention** (``STATE_MIXER`` among ``lp``'s keys: LFM2's
     gated short convolutions, Qwen3-Next's Gated DeltaNet, Granite 4.0-H's
-    Mamba-2).  Such a layer
+    Mamba-2, Ling-3.0's Kimi Delta Attention).  Such a layer
     touches neither the pool nor the write plan nor the kernel: ``mix(lp, x,
     taps, live, carried, places) -> (x, carried)`` is the whole layer, and what
     it remembers of a sequence's past is a fixed state a SEQUENCE, not rows a
@@ -660,10 +660,19 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     returns in its place is the NEW FLAT LEAF, which goes back into the layer
     scan's carry as it is: the rows' slots updated (a beginning row's from
     zeros), every other slot as it was (Granite's ``ssm``: 4 MB a row a layer,
-    read and written once, inside ``ssd_update`` / ``ssd_scan``).
+    read and written once, inside ``ssd_update`` / ``ssd_scan``; Ling-3.0's
+    ``recurrent``: 2 MB, inside ``kda_update`` / ``kda_scan``).
 
     The pool's row is counted over the attention layers alone, the state's over
-    the rest.
+    the rest, in whatever order the two kinds lie within a period or across
+    stacks: a pool ``[L_attention, ...]`` of ANY leaves (K and V; or ONE latent
+    leaf with ``value_dim``, as Ling-3.0's ``bailing_hybrid``: one layer in six
+    attends a latent, five keep a matrix by reference and a shift by value) and
+    state leaves ``[L_mixer, slots + 1, ...]`` lie side by side in one
+    ``kv_cache`` and one scan's carry; ``softmax_scale``, ``value_dim`` and
+    ``window`` are the attention layers' alone and a mixer never sees them
+    (``tests/unit/inference/test_latent_pool_beside_state.py`` holds this with a
+    toy family of its own).
 
     **A hand-on inside a period** (``hand_on``: LongCat-Flash's shortcut expert
     layer, computed from the first sublayer's normed stream and added at the end

@@ -258,22 +258,37 @@ def route(wg, x, top_k: int, renormalise: bool, n_group: int = 1, topk_group: in
     multiplies the picked weights (``routed_scaling_factor``: 16 there, where
     nothing is renormalised).  One group and factor 1 (every other family)
     trace to the plain softmax top-k.
+
+    ``n_group`` > 1 WITH a ``bias`` is DeepSeek-V3's choice (``noaux_tc``;
+    Ling-3.0's ``bailing_hybrid``): groups and picks are chosen by the BIASED
+    score, a group scores the sum of its two best, the top-k is taken among the
+    experts of the ``topk_group`` best groups (an expert of another group can
+    not be picked whatever the bias: it is masked with -inf, not with 0), and
+    the weights are the picked experts' scores without the bias.
     x [S, D] -> (weights [S, k] float32, experts [S, k] int32)."""
     with jax.named_scope("moe_route"):
         logits = jnp.dot(x, wg.astype(x.dtype), preferred_element_type=jnp.float32)
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"route: scoring {scoring!r} is not implemented (softmax, sigmoid)")
         probs = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
-        if n_group > 1:
-            by_group = probs.reshape(probs.shape[0], n_group, -1)
-            _, best = jax.lax.top_k(jnp.max(by_group, axis=-1), topk_group)
+        if n_group > 1 and bias is not None:  # DeepSeek-V3's: groups and picks by the biased score
+            by_group = (probs + bias.astype(jnp.float32)).reshape(probs.shape[0], n_group, -1)
+            _, best = jax.lax.top_k(jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1), topk_group)
             kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
-            probs = jnp.where(kept[:, :, None], by_group, 0.0).reshape(probs.shape)
-        if bias is None:
-            top_p, top_idx = jax.lax.top_k(probs, top_k)
-        else:
-            _, top_idx = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+            _, top_idx = jax.lax.top_k(
+                jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(probs.shape), top_k)
             top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
+        else:
+            if n_group > 1:
+                by_group = probs.reshape(probs.shape[0], n_group, -1)
+                _, best = jax.lax.top_k(jnp.max(by_group, axis=-1), topk_group)
+                kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+                probs = jnp.where(kept[:, :, None], by_group, 0.0).reshape(probs.shape)
+            if bias is None:
+                top_p, top_idx = jax.lax.top_k(probs, top_k)
+            else:
+                _, top_idx = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+                top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
         if renormalise:
             total = jnp.sum(top_p, axis=-1, keepdims=True)
             top_p = top_p / (total + norm_eps if norm_eps else total)

@@ -14,7 +14,7 @@ who captures a profile of a running server asks the engine for the same table
 
 - :data:`SCOPES` is the ONE list of scope names, as ``monitor.perf.PHASES`` is
   the one list of phases: a ``jax.named_scope`` anywhere under
-  ``deepspeed_tpu/`` takes a name of it, or of :data:`KINDS` (a source scan in
+  ``deepspeed_tpu/`` takes a name of it, of :data:`KINDS` or of :data:`INNER` (a source scan in
   ``tests/unit/monitor/test_program_scopes.py`` holds that).
 - :func:`scope_table` is a pure function over the text of an optimized module
   (``jax.stages.Compiled.as_text()``).
@@ -69,7 +69,11 @@ SCOPES = (
 # no part of a step of its own, so in no group: the operations stay their enclosing scope's
 # (the benchmark's groups are made of :data:`SCOPES`, name for name).
 KINDS = ("attn_window", "attn_full")
-_SCOPE_SET = frozenset(SCOPES + KINDS)
+# A family's own names INSIDE a driver's scope (Kimi Delta Attention's, inside ``mixer_layer``):
+# kept on the path like a kind, so that a reader can tell the gate, the scan and the update apart;
+# the operation's group is its enclosing scope's (``mixer_layer``: the mixers').
+INNER = ("kda_mixer", "kda_gate", "kda_scan", "kda_update", "kda_state")
+_SCOPE_SET = frozenset(SCOPES + KINDS + INNER)
 
 _OP_NAME = 'op_name="'
 _HEADER = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
@@ -83,7 +87,7 @@ _NOT_A_SCOPE = ("jit", "pjit")  # jit(head) is a function of that name, not a sc
 
 
 class Path(tuple):
-    """The names of :data:`SCOPES` and :data:`KINDS` on an instruction's path, outermost first.
+    """The names of :data:`SCOPES`, :data:`KINDS` and :data:`INNER` on an instruction's path, outermost first.
     ``mixed``: for a fusion, the innermost scopes of the instructions fused into
     it that are not its own.  ``inherited``: the path is not the instruction's
     own but that of the only instructions that read it.  ``ambiguous``: several

@@ -319,16 +319,8 @@ def ssd_scan(x, dt, A, B, C, D, leaf, at, begins, n_tokens, row=None, col=None, 
         if not _pallas.use_pallas():
             step = lambda rows: _walk_scan(table, xa, ba, ca, (la, dta, d), rows)
             return _by_value(leaf, at, begins, step, live=live)
-        # a chunk's slot in its sequence's place, whether the sequence begins, and the chunk
-        # whose blocks its grid step names: its own, or for an empty chunk the last live one's
-        # (as ``_chunk_table`` names that one's sequence: nothing is fetched, nothing stored)
-        seq, c = table[SEQ], jnp.arange(chunks, dtype=jnp.int32)
-        alive = table[LIVE] > 0
-        before = jax.lax.cummax(jnp.where(alive, c, -1))
-        table = jnp.concatenate([
-            at.astype(jnp.int32)[seq][None], table[SEQ + 1:], begins.astype(jnp.int32)[seq][None],
-            jnp.where(before >= 0, before, jnp.argmax(alive).astype(jnp.int32))[None]])
-        return _walk_pallas(table, xa, ba, ca, (la, dta, d), leaf, interpret=_pallas.INTERPRET)
+        return _walk_pallas(_slot_table(table, at, begins), xa, ba, ca, (la, dta, d), leaf,
+                            interpret=_pallas.INTERPRET)
 
     if row is None:
         table, laid, back, chunks = lay_on_chunk_edges(counts, x.shape[:2], None, None, CHUNK)
@@ -349,6 +341,19 @@ def ssd_scan(x, dt, A, B, C, D, leaf, at, begins, n_tokens, row=None, col=None, 
     _, y, leaf = jax.lax.while_loop(lambda carry: carry[0] < trips, trip,
                                     (jnp.int32(0), jnp.zeros_like(x), leaf))
     return y, leaf
+
+
+def _slot_table(table, at, begins):
+    """The chunk table a by-reference scan kernel prefetches, ``[6, chunks]``: a chunk's SLOT in
+    its sequence's place, ``FIRST``, ``LAST``, ``LIVE``, whether the sequence begins, and the
+    chunk whose blocks its grid step names: its own, or for an empty chunk the last live one's
+    (as ``_chunk_table`` names that one's sequence: nothing is fetched, nothing stored)."""
+    seq, c = table[SEQ], jnp.arange(table.shape[1], dtype=jnp.int32)
+    alive = table[LIVE] > 0
+    before = jax.lax.cummax(jnp.where(alive, c, -1))
+    return jnp.concatenate([
+        at.astype(jnp.int32)[seq][None], table[SEQ + 1:], begins.astype(jnp.int32)[seq][None],
+        jnp.where(before >= 0, before, jnp.argmax(alive).astype(jnp.int32))[None]])
 
 
 def _lay_window(n_tokens, walked, trip, chunks: int, row, col):

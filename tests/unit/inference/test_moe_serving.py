@@ -310,3 +310,86 @@ def test_no_identity_experts_is_none_and_zero_of_them_is_a_tally_of_nothing():
     assert np.asarray(tally).tolist() == [0, 24 * 2]  # every pick is on a held expert
     text = jax.jit(lambda m, a: sparse_moe_ffn(m, a, 2, True)).lower(moe, x).as_text()
     assert "moe_identity" not in text
+
+
+# ---------------------------------------- DeepSeek-V3's grouped sigmoid choice (ISSUE 58)
+def parents_route(wg, x, top_k, renormalise, n_group=1, topk_group=1, scaling=1.0,
+                  scoring="softmax", bias=None, norm_eps=0.0):
+    """``route`` as it stood before the grouped sigmoid choice: DeepSeek-V2's groups by their
+    best softmax score, GLM-5's sigmoid with a bias over one group."""
+    logits = jnp.dot(x, wg.astype(x.dtype), preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
+    if n_group > 1:
+        by_group = probs.reshape(probs.shape[0], n_group, -1)
+        _, best = jax.lax.top_k(jnp.max(by_group, axis=-1), topk_group)
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+        probs = jnp.where(kept[:, :, None], by_group, 0.0).reshape(probs.shape)
+    if bias is None:
+        top_p, top_idx = jax.lax.top_k(probs, top_k)
+    else:
+        _, top_idx = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
+    if renormalise:
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p = top_p / (total + norm_eps if norm_eps else total)
+    if scaling != 1.0:
+        top_p = top_p * scaling
+    return top_p, top_idx.astype(jnp.int32)
+
+
+@pytest.mark.parametrize("family,args,kwargs", [
+    ("deepseek_v2", (6, False, 8, 3, 16.0), {}),
+    ("glm_5", (8, True, 1, 1, 2.5), {"scoring": "sigmoid", "biased": True, "norm_eps": 1e-20}),
+    ("lfm2", (4, True), {"scoring": "sigmoid", "biased": True})])
+def test_the_choices_that_were_there_route_bit_for_bit_as_before(family, args, kwargs):
+    moe, x = drawn_moe(64, seed=5)
+    kwargs = dict(kwargs)
+    if kwargs.pop("biased", False):
+        kwargs["bias"] = 0.1 * jax.random.normal(jax.random.PRNGKey(2), (64, ))
+    for dtype in (jnp.float32, jnp.bfloat16):
+        got = jax.jit(lambda w, a: route(w, a, *args, **kwargs))(moe["gate"]["wg"], x.astype(dtype))
+        want = jax.jit(lambda w, a: parents_route(w, a, *args, **kwargs))(moe["gate"]["wg"],
+                                                                          x.astype(dtype))
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype and np.array_equal(np.asarray(a), np.asarray(b))
+    text = lambda f: jax.jit(f).lower(moe["gate"]["wg"], x).as_text()
+    assert text(lambda w, a: route(w, a, *args, **kwargs)).replace("route", "") == \
+        text(lambda w, a: parents_route(w, a, *args, **kwargs)).replace("parents_route", "").replace(
+            "route", "")
+
+
+@pytest.mark.parametrize("n_group,topk_group,top_k,width", [(8, 4, 8, 64), (4, 2, 4, 8), (2, 1, 3, 16)])
+def test_grouped_sigmoid_route_is_a_plain_loops(n_group, topk_group, top_k, width):
+    """DeepSeek-V3's ``noaux_tc`` by brute force, a token at a time: the groups by the sum of
+    their two best BIASED scores, the picks by the biased score among the kept groups' experts
+    alone, the weights the picked scores without the bias over their sum, times the factor.
+    The bias changes which groups are kept for some token, and the limit binds for some."""
+    experts = width * n_group
+    moe, x = drawn_moe(experts, seed=3)
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(6), (experts, ))
+    weights, picks = route(moe["gate"]["wg"], x, top_k, True, n_group, topk_group, 2.5,
+                           scoring="sigmoid", bias=bias)
+    scores = np.asarray(jax.nn.sigmoid(x @ moe["gate"]["wg"]), np.float64)
+    choice = scores + np.asarray(bias, np.float64)
+    groups_moved = limit_binds = 0
+    for s in range(x.shape[0]):
+        two_best = lambda v: np.sort(v.reshape(n_group, width), axis=1)[:, -2:].sum(axis=1)
+        best = np.argsort(-two_best(choice[s]))[:topk_group]
+        allowed = [e for e in range(experts) if e // width in best]
+        want = sorted(allowed, key=lambda e: -choice[s, e])[:top_k]
+        assert list(np.asarray(picks[s])) == want
+        np.testing.assert_allclose(np.asarray(weights[s]), 2.5 * scores[s, want] / scores[s, want].sum(),
+                                   rtol=1e-5)
+        groups_moved += set(best) != set(np.argsort(-two_best(scores[s]))[:topk_group])
+        limit_binds += set(want) != set(np.argsort(-choice[s])[:top_k])
+    assert groups_moved and limit_binds  # or the test shows nothing
+    assert picks.dtype == jnp.int32 and weights.dtype == jnp.float32
+
+
+def test_an_expert_of_a_group_left_out_is_not_picked_whatever_its_bias():
+    """The mask is -inf and not zero: with every kept expert's biased score under zero an
+    expert of a group that was left out (choice 0 under a zero fill) would be picked."""
+    moe, x = drawn_moe(16, seed=4)
+    bias = jnp.full((16, ), -2.0).at[:8].add(0.5)  # groups 0 and 1 of four are kept, all choices < 0
+    _, picks = route(moe["gate"]["wg"], x, 4, True, 4, 2, 1.0, scoring="sigmoid", bias=bias)
+    assert (np.asarray(picks) < 8).all()

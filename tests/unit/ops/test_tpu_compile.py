@@ -417,3 +417,49 @@ def test_quantize_int8_compiles(chip):
     from deepspeed_tpu.ops.quantizer.quantize import quantize_int8
     calls = compile_and_count(lambda x: quantize_int8(x)[:2], chip((4096, 14336), jnp.bfloat16))
     assert sum(calls.values()) == 1
+
+
+KDA_LEAF = (10 * 17, 32, 128, 128)  # serve.kda-mixed-lengths's ``recurrent`` leaf, flat: ten layers of 17 slots
+KDA_ROW = 32 * 128 * 128 * 4        # one slot of it: a row's float32 matrices of one layer, 2 MB
+
+
+@pytest.mark.parametrize("budget", [1024, 2048])
+def test_the_kda_scan_compiles_at_the_cells_shapes(chip, budget):
+    """ISSUE 58: the chunked-scan kernel at Ling-3.0's 32 heads of 128 x 128 with a decay a
+    channel (``gamma`` ``[heads, C, 128]`` float32 a chunk, the factors of A and B a block of
+    16 rows at a time), for a compacted pass of ``budget`` tokens, a window of its rows laid
+    on chunk edges: one Mosaic kernel, the carried matrices BY REFERENCE: the cell's whole
+    flat leaf (0.36 GB) aliased in and out, a chunk's slot from the prefetched table, and
+    not one row of it (2 MB) held beside it."""
+    from deepspeed_tpu.ops.linear_attention import kda, ssd
+
+    heads, dk, dv = KDA_LEAF[1:]
+    chunks = ssd.scan_chunks(16, 0, budget)
+    assert chunks == budget // kda.CHUNK + ssd.WINDOW
+    t = chunks * kda.CHUNK
+    avals = (chip((6, chunks), jnp.int32), chip((heads, t, dk), jnp.bfloat16),
+             chip((heads, t, dk), jnp.bfloat16), chip((heads, t, dv), jnp.bfloat16),
+             chip((heads, t, dk), jnp.float32), chip((heads, chunks, kda.CHUNK), jnp.float32),
+             chip(KDA_LEAF, jnp.float32))
+    compiled = jax.jit(lambda *a: kda._walk_pallas(*a, interpret=False),
+                       donate_argnums=(6, )).lower(*avals).compile()
+    assert kernel_calls(compiled.as_text()) == {"kda_scan": 1}
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == KDA_LEAF[0] * KDA_ROW
+    assert memory.temp_size_in_bytes < KDA_ROW
+
+
+def test_the_kda_update_compiles_at_the_cells_shapes(chip):
+    """ISSUE 58: the one-token update at a decode step of 16 rows: one Mosaic kernel over
+    (row, 8 heads), the rows' matrices BY REFERENCE: the whole flat leaf aliased in and out,
+    a row's slot from the prefetched ``at``, under a row's 2 MB held beside it."""
+    from deepspeed_tpu.ops.linear_attention import kda
+
+    n, (heads, dk, dv) = 16, KDA_LEAF[1:]
+    avals = (chip((n, ), jnp.int32), chip((n, ), jnp.int32), chip((n, heads, 4, dk), jnp.float32),
+             chip((n, heads, 1, dv), jnp.float32), chip(KDA_LEAF, jnp.float32))
+    compiled = jax.jit(lambda *a: kda._update_pallas(*a, interpret=False),
+                       donate_argnums=(4, )).lower(*avals).compile()
+    assert kernel_calls(compiled.as_text()) == {"kda_update": 1}
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == KDA_LEAF[0] * KDA_ROW and memory.temp_size_in_bytes < KDA_ROW

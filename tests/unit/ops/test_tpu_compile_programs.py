@@ -174,6 +174,20 @@ def granite_shapes(chip, layers):
             jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype), kv))
 
 
+def ling_shapes(chip, layers):
+    """Ling-3.0-flash as ``serve.kda-mixed-lengths`` holds it (64 of 512 experts, an
+    eighth of the vocabulary; ``layers`` = 12: two dense KDA layers, then KDA x 3, MLA, KDA
+    x 5, MLA, five scans): the latent pool of the two MLA layers ``[2, 1024, 1, 128,
+    640]`` and the KDA layers' state, 16 slots and a trash slot: the shift ``[10, 17,
+    3, 12288]`` and the float32 matrices ``[10, 17, 32, 128, 128]`` (0.36 GB)."""
+    from deepspeed_tpu.models import bailing_hybrid
+    cfg = bailing_hybrid.BailingHybridConfig(vocab_size=19648, num_layers=layers, num_local_experts=64)
+    params = jax.eval_shape(lambda: bailing_hybrid.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+    kv = jax.eval_shape(lambda: bailing_hybrid.init_paged_cache(cfg, 1024, 128, state_slots=16))
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype), tree)
+    return bailing_hybrid, cfg, on_chip(params), on_chip(kv)
+
+
 def mistral_module_and_shapes(chip, layers):
     from deepspeed_tpu.models import mistral
     return (mistral, ) + mistral_shapes(chip, layers)
@@ -334,3 +348,43 @@ def test_a_family_whose_state_goes_by_value_lowers_to_the_program_it_was(case):
         cfg, p, tok, nt, sp, tab, kv, block_size=8, live_token_bound=bound, last_rows=True)).lower(
             params, kv, ints(4, 16), ints(4), ints(4), ints(4, 5)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PROGRAMS_BEFORE[case]
+
+
+@pytest.mark.parametrize("n,t,bound,kernels,in_a_burst", [
+    (16, 1024, 1024, ("kda_update", "kda_scan"), False), (16, 1, None, ("kda_update", ), True)],
+    ids=["compacted", "burst"])
+def test_a_latent_pool_and_a_state_by_reference_are_each_held_once(chip, n, t, bound, kernels,
+                                                                  in_a_burst):
+    """ISSUE 58: Ling-3.0's two caches in one program at the cell's size.  In its compacted
+    chunk pass (1,024 slots) and a decode step as a burst's body inside its loop: the LATENT POOL is written by the writer kernel alone, once an MLA scan body, and
+    attended by the paged kernel once (no pool-shaped result else: no copy, slice or update
+    of it); the RECURRENT leaf goes by reference: no operation produces the rows' matrices
+    ``f32[16,32,128,128]`` and none but the Mosaic kernels, each once a KDA scan body (three
+    of the five scans hold KDA layers), produces the leaf's shape; both are aliased in and
+    out, and the program's temporaries are under either (a burst's 0.31 GB are three
+    weights laid out anew once a burst, outside its loop: ``W_f`` of the two KDA stacks,
+    whose product leaves in float32, and the head)."""
+    module, cfg, params, kv = ling_shapes(chip, layers=12)
+    recurrent, latent = kv["state"]["recurrent"], kv["latent"]
+
+    def fwd(params, kv, tokens, n_tokens, start_pos, tables):
+        return module.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
+                                    block_size=128, live_token_bound=bound, last_rows=True)
+
+    ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, 32 + 1))]
+    compiled = jax.jit(burst_of(fwd) if in_a_burst else fwd,
+                       donate_argnums=(1, )).lower(params, kv, *ints).compile()
+    text = compiled.as_text()
+    calls = kernel_calls(text)
+    assert {k: v for k, v in calls.items() if k.startswith("kda_")} == dict.fromkeys(kernels, 3), calls
+    assert calls["paged_attention"] == calls["kv_write"] == 2, calls  # the two MLA layers: a scan each
+    assert [r[0] for r in pool_shaped_results(text, latent.shape)] == ["custom-call"] * 2
+    rows = list((n, ) + recurrent.shape[2:])
+    assert [opcode for opcode, _, shapes in results(text) if rows in shapes] == []
+    whole = pool_shaped_results(text, (1, ) + recurrent.shape)
+    assert [r[0] for r in whole] == ["custom-call"] * 3 * len(kernels), whole
+    memory = compiled.memory_analysis()
+    leaf_bytes, pool_bytes = int(np.prod(recurrent.shape)) * 4, int(np.prod(latent.shape)) * 2
+    assert memory.alias_size_in_bytes >= leaf_bytes + pool_bytes
+    assert memory.temp_size_in_bytes < min(leaf_bytes, pool_bytes)
+
