@@ -26,7 +26,8 @@ every ``rms`` a plain gain; an untied head; no biases.
   128 x 128) and the last ``taps - 1`` rows of ``[q | k | v]`` before the
   filter.  Both are leaves of ``kv_cache[STATE]``, one slot a live sequence,
   beside the pool; ``transformer.paged_forward`` (which states the contract)
-  hands ``mix`` the shift's rows BY VALUE and the matrices BY REFERENCE
+  hands ``mix`` the shift's rows BY VALUE, with the filter over them local to a
+  sequence (``filtered``: no shifted copy of the columns), and the matrices BY REFERENCE
   (``STATE_BY_REFERENCE``: ``kda_step`` and ``kda_scan`` index the rows' slots
   of the carried leaf themselves).  Nothing here computes a slot.
 - **MLA** (``q_lora_rank`` null: a full-rank query): DeepSeek-V2's absorbed
@@ -388,15 +389,15 @@ def forward_paged(config: BailingHybridConfig, params, tokens, n_tokens, start_p
     def head_gate(u, w):  # one gate a head, float32: its sigmoid scales the head's output
         return jnp.dot(u, w.astype(dtype), preferred_element_type=jnp.float32)
 
-    def mix(lp, x, taps, live, carried, places):
+    def mix(lp, x, filtered, live, carried, places):
         m = lp[STATE_MIXER]
         u = rms_norm(x, lp["op_norm"], eps)
         lead = x.shape[:2]
         with jax.named_scope("kda_mixer"):
             mixed = u @ m["w_qkv"].astype(dtype)
             with jax.named_scope("kda_state"):
-                earlier, last = taps(mixed, carried["conv"])
-            conv = jax.nn.silu(transformer.causal_filter(mixed, earlier, m["filter"]))
+                conv, last = filtered(mixed, carried["conv"], m["filter"])
+            conv = jax.nn.silu(conv)
             q, k, v = (conv[..., i * H * dh:(i + 1) * H * dh].reshape(lead + (H, dh)) for i in range(3))
             q, k, v = (l2norm(q) * dh ** -0.5).astype(dtype), l2norm(k).astype(dtype), v.astype(dtype)
             with jax.named_scope("kda_gate"):

@@ -25,7 +25,8 @@ the embedding) divided by ``logits_scaling``.
   rows of ``xBC`` before the filter.  Both are leaves of ``kv_cache[STATE]``,
   one slot a live sequence, beside the paged pool; ``transformer.paged_forward``
   (which states the contract) hands ``mix`` the shift's rows BY VALUE, with the
-  shift local to a sequence and where the sequences lie, and writes back what
+  filter over them local to a sequence (``filtered``: no shifted copy of the
+  columns) and where the sequences lie, and writes back what
   ``mix`` returns for them; the matrices go BY REFERENCE (``STATE_BY_REFERENCE``:
   ``ssd_update`` and ``ssd_scan`` index the rows' slots of the carried leaf
   themselves, so 4 MB a row a layer is read where it lies once and written there
@@ -305,7 +306,7 @@ def forward_paged(config: GraniteMoeHybridConfig, params, tokens, n_tokens, star
         return (params["embed"][tokens].astype(jnp.float32)
                 * config.embedding_multiplier).astype(dtype)
 
-    def mix(lp, x, taps, live, carried, places):
+    def mix(lp, x, filtered, live, carried, places):
         m = lp[STATE_MIXER]
         u = rms_norm(x, lp["op_norm"], eps)
         lead = x.shape[:2]
@@ -314,9 +315,8 @@ def forward_paged(config: GraniteMoeHybridConfig, params, tokens, n_tokens, star
             z, xbc = projected[..., :inner], projected[..., inner:inner + conv_dim]
             dt = projected[..., inner + conv_dim:].astype(jnp.float32)
             with jax.named_scope("ssm_state"):
-                earlier, last = taps(xbc, carried["conv"])
-            xbc = jax.nn.silu(transformer.causal_filter(xbc, earlier, m["filter"],
-                                                        m["conv_bias"])).astype(dtype)
+                xbc, last = filtered(xbc, carried["conv"], m["filter"], m["conv_bias"])
+            xbc = jax.nn.silu(xbc).astype(dtype)
             xs = xbc[..., :inner].reshape(lead + (hm, p))
             b, c = xbc[..., inner:inner + ns], xbc[..., inner + ns:]
             dt = jax.nn.softplus(dt + m["dt_bias"].astype(jnp.float32))

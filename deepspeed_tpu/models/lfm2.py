@@ -11,7 +11,8 @@ not attention.  One block is ``h = x + Op(rms(x))``, ``y = h + FFN(rms(h))``:
   last ``conv_L_cache - 1`` values of ``z``: a FIXED state a sequence, whatever
   its length.  It lives beside the paged pool (``kv_cache[STATE]``, one leaf,
   one slot a live sequence); ``transformer.paged_forward`` states the contract
-  of such layers (``mix``, ``taps``, the carried leaves) and nothing here knows
+  of such layers (``mix``, ``filtered``: the filter local to a sequence, its
+  products and sum in float32; the carried leaves) and nothing here knows
   where a step's tokens lie.
 - **Attention** (``full_attention``): GQA with an RMSNorm over each head of q
   and of k before rotate-half rotary, no window, over the paged pool.  Heads
@@ -267,18 +268,14 @@ def forward_paged(config: Lfm2Config, params, tokens, n_tokens, start_pos, block
     def embed(tokens, safe_pos):
         return params["embed"][tokens].astype(dtype)
 
-    def mix(lp, x, taps, live, kept, places):
+    def mix(lp, x, filtered, live, kept, places):
         m = lp[STATE_MIXER]
         u = rms_norm(x, lp["op_norm"], config.norm_eps)
         with jax.named_scope("conv_mixer"):
             b, c, xs = jnp.split(u @ m["w_in"].astype(dtype), 3, axis=-1)
             z = b * xs
-            w = m["filter"].astype(dtype)  # [taps, D]: the last weighs z_t itself
-            conv = w[-1] * z
-            before, last = taps(z, kept)  # the one leaf of the state is this shift's
-            for tap, earlier in zip(w[:-1], before):
-                conv = conv + tap * earlier
-            x = x + (c * conv) @ m["w_out"].astype(dtype)
+            conv, last = filtered(z, kept, m["filter"])  # float32; the state's one leaf is this shift's
+            x = x + (c * conv).astype(dtype) @ m["w_out"].astype(dtype)
         return block_ffn(lp, x, live), last
 
     def qkv(lp, x, safe_pos):

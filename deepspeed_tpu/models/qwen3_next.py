@@ -18,7 +18,8 @@ four.  One block is ``h = x + Mixer(rms(x))``, ``y = h + MoE(rms(h))``, every
   ``taps - 1`` rows of ``[q | k | v]`` before the filter.  Both are leaves of
   ``kv_cache[STATE]``, one slot a live sequence, beside the paged pool;
   ``transformer.paged_forward`` (which states the contract) hands ``mix`` the
-  rows' carried leaves, the shift local to a sequence and where the sequences
+  rows' carried leaves, the filter over the shift local to a sequence
+  (``filtered``: no shifted copy of the columns) and where the sequences
   lie, and writes back what ``mix`` returns.  Nothing here knows of slots.
 - **Gated attention**: ``W_q`` gives a head its query and, beside it, a gate as
   wide; an RMSNorm over each head of q and of k, rotate-half rotary over the
@@ -289,7 +290,7 @@ def forward_paged(config: Qwen3NextConfig, params, tokens, n_tokens, start_pos, 
     def embed(tokens, safe_pos):
         return params["embed"][tokens].astype(dtype)
 
-    def mix(lp, x, taps, live, carried, places):
+    def mix(lp, x, filtered, live, carried, places):
         m = lp[STATE_MIXER]
         u = norm(x, lp["op_norm"])
         lead = x.shape[:2]
@@ -299,8 +300,8 @@ def forward_paged(config: Qwen3NextConfig, params, tokens, n_tokens, start_pos, 
             b, a = jnp.split(jnp.dot(u, m["w_ba"].astype(dtype),
                                      preferred_element_type=jnp.float32), 2, axis=-1)
             with jax.named_scope("gdn_state"):
-                earlier, last = taps(mixed, carried["conv"])
-            conv = jax.nn.silu(transformer.causal_filter(mixed, earlier, m["filter"]))
+                conv, last = filtered(mixed, carried["conv"], m["filter"])
+            conv = jax.nn.silu(conv)
             q = (l2norm(conv[..., :key_dim].reshape(lead + (hk, dk))) * dk ** -0.5).astype(dtype)
             k = l2norm(conv[..., key_dim:2 * key_dim].reshape(lead + (hk, dk))).astype(dtype)
             v = conv[..., 2 * key_dim:].reshape(lead + (hv, dv)).astype(dtype)

@@ -511,18 +511,6 @@ def layers_of_one_expert_stack(runs, segments):
             for (start, period, repeats), segment in zip(runs, segments)]
 
 
-def causal_filter(z, earlier, w, bias=None):
-    """A depth-wise causal filter over a mixer's columns, float32: ``w``
-    ``[taps, columns]`` whose LAST row weighs the token itself, ``earlier`` the
-    ``taps - 1`` shifted copies of ``z`` that :func:`paged_forward`'s ``taps``
-    gave (oldest first)."""
-    w = w.astype(jnp.float32)
-    out = w[-1] * z.astype(jnp.float32)
-    for tap, before in zip(w[:-1], earlier):
-        out = out + tap * before.astype(jnp.float32)
-    return out if bias is None else out + bias.astype(jnp.float32)
-
-
 def tp_psum(tp_axis: Optional[str]):
     """What a family's ``finish`` does with a row-parallel partial: the psum
     over ``tp_axis`` inside shard_map, nothing on one chip."""
@@ -618,7 +606,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     gated short convolutions, Qwen3-Next's Gated DeltaNet, Granite 4.0-H's
     Mamba-2, Ling-3.0's Kimi Delta Attention).  Such a layer
     touches neither the pool nor the write plan nor the kernel: ``mix(lp, x,
-    taps, live, carried, places) -> (x, carried)`` is the whole layer, and what
+    filtered, live, carried, places) -> (x, carried)`` is the whole layer, and what
     it remembers of a sequence's past is a fixed state a SEQUENCE, not rows a
     token: ``kv_cache[STATE]``, a TREE OF LEAVES ``[Ls, slots + 1, ...]`` (one
     array, or a dict of arrays of any trailing shapes and dtypes: a shift's
@@ -633,13 +621,18 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     to the rows' slots, a dead row's to the trash slot.  What only this function
     can give, since it alone knows where a step's tokens lie, comes beside it:
 
-    - ``taps(z, kept) -> ([z_{t-k}, ..., z_{t-1}], last)`` for a leaf that is a
-      shift: for ``z`` ``[b, s, D]`` in either layout and ``kept`` ``[N, k, D]``
-      (the carried leaf) the ``k`` earlier values of every token's own
-      sequence, from the chunk itself where the chunk has them and from
-      ``kept`` where it does not (a chunk's first ``k`` tokens), and ``last``
-      ``[N, k, D]``, the leaf's new value: the chunk's last ``k`` values (a
-      chunk of one token shifts it; :func:`sequence_taps`);
+    - ``filtered(z, kept, w, bias=None) -> (out, last)`` for a leaf that is a
+      shift, the short causal filter that reads it: for ``z`` ``[b, s, D]`` in
+      either layout, ``kept`` ``[N, k, D]`` (the carried leaf) and ``w`` ``[k +
+      1, D]`` (its last row weighs the token itself) ``out`` like ``z`` in
+      float32, every token filtered over its own sequence's ``k`` earlier
+      values, from the chunk itself where the chunk has them and from ``kept``
+      where it does not (a row's first ``k`` tokens), and ``last`` ``[N, k,
+      D]``, the leaf's new value: the chunk's last ``k`` values (a chunk of one
+      token shifts it).  No shifted copy of ``z`` is made: compacted, the pass
+      reads ``z`` once and ``kept`` touches ``N x k`` slots
+      (:func:`sequence_filter`); the activation, norms and casts around the
+      filter are the family's;
     - ``places`` (:class:`SeqPlaces`) for a leaf that is a recurrence over the
       step's tokens: ``n_tokens`` and, compacted, whose token each flat slot
       holds, so that the family's scan can walk each sequence from its own
@@ -649,8 +642,8 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
 
     That is a leaf BY VALUE: a read of the rows' slots, a select and a scatter
     around ``mix``, each a pass over the rows, which suits a few rows of filter
-    taps that ``taps`` must gather and shift anyway.  A leaf whose kernels index
-    the slots themselves goes BY REFERENCE: ``by_reference`` is the family's
+    taps (``filtered`` reads them for a row's first ``k`` tokens alone).  A leaf
+    whose kernels index the slots themselves goes BY REFERENCE: ``by_reference`` is the family's
     statement, in its code, of which leaves those are (a tree of bools shaped
     like ``kv_cache[STATE]``; None: none).  For such a leaf nothing is read,
     selected or scattered here: ``carried`` holds in its place a
@@ -851,7 +844,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                     jnp.where((start_pos > 0).reshape((-1, ) + (1, ) * (leaf.ndim - 1)), leaf[at], 0)
                     for leaf, ref in zip(flat_states, by_ref)]
         with jax.named_scope("mixer_layer"):  # the family's own (``ssm_mixer``, its FFN) inside it
-            x, carried = mix(lp, x, taps, live, jax.tree_util.tree_unflatten(state_tree, kept), places)
+            x, carried = mix(lp, x, filtered, live, jax.tree_util.tree_unflatten(state_tree, kept), places)
         with jax.named_scope("seq_state"):
             return x, [new if ref else leaf.at[at].set(new.astype(leaf.dtype))
                        for leaf, new, ref in zip(flat_states, state_tree.flatten_up_to(carried), by_ref)]
@@ -860,7 +853,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     # a new [L, ...] array that cannot alias a donated argument still being
     # read, which cost a slice, an update and a copy of the whole pool a pass.
     places = SeqPlaces(n_tokens, row, col)
-    taps = lambda z, kept: sequence_taps(z, kept, *places)
+    filtered = lambda z, kept, w, bias=None: sequence_filter(z, kept, w, bias, *places)
     carry = (x, *flat_pools, *(leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in state_leaves))
     done = {False: 0, True: 0}  # attention / mixer layers behind the stack being scanned
     left_over = []  # with ``hand_on``: what each stack's periods handed to no layer
@@ -942,36 +935,52 @@ def paged_step_slots(module, config, kv_cache, q_dtype, tp: int = 1):
     return slots, lambda n, flat: flat_token_slots(n, flat, heads // local_kvh)
 
 
-def sequence_taps(z, kept, n_tokens, row, col):
-    """The shift of :func:`paged_forward`'s ``taps``.  ``z`` ``[b, s, D]`` in the
-    padded layout ``[N, T]`` (``row`` None) or the compacted ``[1, S]`` (``row``
-    / ``col`` ``[1, S]``: whose token a flat slot holds); ``kept`` ``[N, k, D]``
-    the rows' remembered values, oldest first.  Returns ``([z_{t-k}, ...,
-    z_{t-1}], last)``: each shifted copy like ``z``, and ``last`` ``[N, k, D]``
-    the rows' new remembered values: the last ``k`` of what was kept followed
-    by the chunk's live tokens (a row with no token keeps what it had)."""
+def sequence_filter(z, kept, w, bias, n_tokens, row, col):
+    """The depth-wise causal filter of :func:`paged_forward`'s ``filtered``, local
+    to a sequence.  ``z`` ``[b, s, D]`` in the padded layout ``[N, T]`` (``row``
+    None) or the compacted ``[1, S]`` (``row`` / ``col`` ``[1, S]``: whose token a
+    flat slot holds); ``kept`` ``[N, k, D]`` the rows' remembered values, oldest
+    first; ``w`` ``[k + 1, D]`` whose LAST row weighs the token itself.  Returns
+    ``(out, last)``: ``out`` like ``z`` in float32, ``sum_i w[i] z_{t - k + i}``
+    (the token itself first, then the earlier ones oldest first, then ``bias``,
+    each product and sum in float32), and ``last`` ``[N, k, D]`` the rows' new
+    remembered values: the last ``k`` of what was kept followed by the chunk's
+    live tokens (a row with no token keeps what it had).
+
+    Compacted, the rows lie one after another on the flat axis, so token ``t -
+    back`` of a row is flat slot ``j - back`` wherever the row has that many
+    tokens before it: ONE pass over ``z``, its earlier taps slices of the chunk
+    itself.  ``kept`` matters to a row's first ``k`` tokens alone: those ``N x k``
+    slots are filtered apart, from ``kept`` and the row's first tokens, and set
+    in place (what the pass read across a row's edge there is written over)."""
     k = kept.shape[1]
-    kept = kept.astype(z.dtype)
+    kept, w = kept.astype(z.dtype), w.astype(jnp.float32)
+
+    def over(whole, t):  # ``whole`` [.., k + t, D]: position p of it is token p - k
+        out = w[-1] * whole[..., k:, :].astype(jnp.float32)
+        for i in range(k):
+            out = out + w[i] * whole[..., i:i + t, :].astype(jnp.float32)
+        return out if bias is None else out + bias.astype(jnp.float32)
+
     if row is None:
-        whole = jnp.concatenate([kept, z], axis=1)  # [N, k + T, D]: position p of it is token p - k
-        earlier = [whole[:, i:i + z.shape[1]] for i in range(k)]
+        whole = jnp.concatenate([kept, z], axis=1)  # [N, k + T, D]
         pick = n_tokens[:, None] + jnp.arange(k)[None, :]  # the k before token n_tokens
-        return earlier, jnp.take_along_axis(whole, pick[:, :, None], axis=1)
-    flat, row, col = z[0], row[0], col[0]
-    earlier = []
-    for back in range(k, 0, -1):
-        # token col - back of the row's chunk: an earlier flat slot, or (col - back < 0) the
-        # kept value that many before the chunk's first token
-        shifted = jnp.pad(flat, ((back, 0), (0, 0)))[:flat.shape[0]]
-        from_kept = kept[row, jnp.clip(k + col - back, 0, k - 1)]
-        earlier.append(jnp.where((col >= back)[:, None], shifted, from_kept)[None])
+        return over(whole, z.shape[1]), jnp.take_along_axis(whole, pick[:, :, None], axis=1)
+    flat = z[0]
+    s = flat.shape[0]
+    out = over(jnp.pad(flat, ((k, 0), (0, 0))), s)
     ends = jnp.cumsum(n_tokens)
+    j = jnp.arange(k)[None, :]
+    at = (ends - n_tokens)[:, None] + j  # [N, k]: the rows' first k slots
+    first = over(jnp.concatenate([kept, flat[jnp.clip(at, 0, s - 1)]], axis=1), k)
+    # out of bounds where the row has no such token: it lands nowhere
+    out = out.at[jnp.where(j < n_tokens[:, None], at, s)].set(first, mode="drop")
     last = []
     for back in range(k, 0, -1):  # the value `back` before the row's next token
-        in_chunk = flat[jnp.clip(ends - back, 0, flat.shape[0] - 1)]
+        in_chunk = flat[jnp.clip(ends - back, 0, s - 1)]
         from_kept = kept[jnp.arange(kept.shape[0]), jnp.clip(k + n_tokens - back, 0, k - 1)]
         last.append(jnp.where((n_tokens >= back)[:, None], in_chunk, from_kept))
-    return earlier, jnp.stack(last, axis=1)
+    return out[None], jnp.stack(last, axis=1)
 
 
 # ----------------------------------------------------------------- losses

@@ -45,11 +45,12 @@ def fresh_cache():
 
 
 def forward(params, tokens, n_tokens, start_pos, tables, cache, *, bound):
-    def mix(lp, x, taps, live, carried, places):
+    def mix(lp, x, filtered, live, carried, places):
         ref = carried["sum"]
         assert isinstance(ref, StateRef) and not isinstance(carried["shift"], StateRef)
         assert ref.leaf.shape == (DEPTH * (SLOTS + 1), D)  # the leaf whole and flat
-        (before, ), last = taps(x, carried["shift"])
+        # a filter of two taps that weighs the token before at one and the token itself at nought
+        before, last = filtered(x, carried["shift"], jnp.stack([jnp.ones(D), jnp.zeros(D)]))
         n = places.n_tokens.shape[0]
         # the toy's "kernel": the rows' slots read, the recurrence a row at a time, written back
         s0 = jnp.where(ref.begins[:, None], 0.0, ref.leaf[ref.at])
@@ -176,7 +177,7 @@ def test_without_by_reference_the_same_leaf_comes_by_value(params):
     value (zeros where a sequence begins) and no ``StateRef``."""
     seen = {}
 
-    def mix(lp, x, taps, live, carried, places):
+    def mix(lp, x, filtered, live, carried, places):
         seen.update(carried)
         return x, carried
 

@@ -19,7 +19,7 @@ from chipbench.references import lfm2 as ref
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.ragged_manager import PrefixCache, RaggedStateManager
 from deepspeed_tpu.models import lfm2
-from deepspeed_tpu.models.transformer import STATE, sequence_taps
+from deepspeed_tpu.models.transformer import STATE, sequence_filter
 from deepspeed_tpu.moe.serving import route
 
 TYPES = ["conv", "full_attention", "conv", "conv", "conv", "full_attention", "conv", "conv", "conv"]
@@ -133,31 +133,42 @@ def test_a_compacted_mixed_step_gives_each_sequence_what_it_gets_alone(params):
     np.testing.assert_array_equal(np.asarray(after[STATE][:, 2]), np.asarray(cache[STATE][:, 2]))
 
 
+@pytest.mark.parametrize("with_bias", [False, True], ids=["no_bias", "bias"])
+@pytest.mark.parametrize("k", [1, 2, 3])
 @pytest.mark.parametrize("layout", ["padded", "compacted"])
-def test_the_shift_is_local_to_a_sequence_in_both_layouts(layout):
-    rng = np.random.default_rng(0)
-    counts, k, d, t = np.array([3, 0, 1, 5]), 2, 4, 8
-    kept = rng.normal(size=(4, k, d)).astype(np.float32)
-    chunk = rng.normal(size=(4, t, d)).astype(np.float32)
+def test_the_shift_is_local_to_a_sequence_in_both_layouts(layout, k, with_bias):
+    """``paged_forward``'s ``filtered`` against a filter over each sequence alone: a
+    row of no token, of fewer tokens than ``k`` (at 2 and 3 taps), of exactly ``k``,
+    of more, and a dead tail; ``out`` and ``last`` to the bit in float32."""
+    rng = np.random.default_rng(k)
+    counts, d, t = np.array([k, 0, 1, 5, 2, 0]), 4, 8
+    n = len(counts)
+    kept = rng.normal(size=(n, k, d)).astype(np.float32)
+    chunk = rng.normal(size=(n, t, d)).astype(np.float32)
+    w = rng.normal(size=(k + 1, d)).astype(np.float32)
+    bias = rng.normal(size=(d, )).astype(np.float32) if with_bias else None
     if layout == "padded":
-        earlier, last = sequence_taps(jnp.asarray(chunk), jnp.asarray(kept), jnp.asarray(counts),
-                                      None, None)
+        z, places = chunk, (None, None)
         at = lambda a, r, c: np.asarray(a)[r, c]
     else:
-        row = np.repeat(np.arange(4), counts)
+        row = np.repeat(np.arange(n), counts)
         col = np.concatenate([np.arange(c) for c in counts])
         pad = 16 - len(row)
-        flat = np.concatenate([chunk[row, col], np.zeros((pad, d), np.float32)])[None]
-        row, col = np.concatenate([row, np.zeros(pad, int)]), np.concatenate([col, np.zeros(pad, int)])
-        earlier, last = sequence_taps(jnp.asarray(flat), jnp.asarray(kept), jnp.asarray(counts),
-                                      jnp.asarray(row)[None], jnp.asarray(col)[None])
+        # a dead slot is what ``flat_chunk_indices`` makes it: token [0, 0], row and column nought
+        z = np.concatenate([chunk[row, col], np.tile(chunk[0, 0], (pad, 1))])[None]
+        places = tuple(jnp.asarray(np.concatenate([a, np.zeros(pad, int)]))[None] for a in (row, col))
         starts = np.cumsum(counts) - counts
         at = lambda a, r, c: np.asarray(a)[0, starts[r] + c]
-    for r, n in enumerate(counts):
-        whole = np.concatenate([kept[r], chunk[r, :n]])  # what the sequence has seen, in order
-        for c in range(n):
-            for i in range(k):  # earlier[i] is the value k - i before the token
-                np.testing.assert_array_equal(at(earlier[i], r, c), whole[c + i])
+    out, last = sequence_filter(jnp.asarray(z), jnp.asarray(kept), jnp.asarray(w),
+                                None if bias is None else jnp.asarray(bias), jnp.asarray(counts), *places)
+    assert out.dtype == jnp.float32 and out.shape == z.shape and last.shape == kept.shape
+    for r, c in enumerate(counts):
+        whole = np.concatenate([kept[r], chunk[r, :c]])  # what the sequence has seen, in order
+        for j in range(c):
+            want = w[-1] * whole[k + j]  # the token itself, then the earlier ones oldest first
+            for i in range(k):
+                want = want + w[i] * whole[j + i]
+            np.testing.assert_array_equal(at(out, r, j), want if bias is None else want + bias)
         np.testing.assert_array_equal(np.asarray(last)[r], whole[-k:])
 
 

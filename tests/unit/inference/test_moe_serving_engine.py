@@ -170,13 +170,15 @@ def test_deepseek_v2_through_the_engine_counts_every_pick_and_compacts(monkeypat
 # period, identity experts and a tally leaf are traced for the family that has them
 # and for no other.  The two shares were re-pinned in ISSUE 51, which compacts a
 # share's held picks (the four others, whose leaves hold every routed expert or
-# none, lowered to what they were).  Whoever changes ``paged_forward`` or ``sparse_moe_ffn`` on
+# none, lowered to what they were); LFM2's in ISSUE 59, which hands a mixer its filter and no
+# shifted copies (the five others, whose families have no shift, lowered to what they were).
+# Whoever changes ``paged_forward`` or ``sparse_moe_ffn`` on
 # purpose re-pins these from the new tree and says in PERF.md that every cell's
 # programs, and with them ``setup_s``, are compiled anew.
 PROGRAMS_BEFORE = {"olmoe_decode": "be129bc1388a5808", "olmoe_compacted": "1157e790add8daad",
                    "deepseek_v2_share_compacted": "d59248c8f6384b9a",
                    "glm_moe_dsa_share_padded": "70fa7bbde87e1038",
-                   "lfm2_period_compacted": "6dd10a4bfc4f41c2", "llama_decode": "c6d24d39c1fabc19"}
+                   "lfm2_period_compacted": "b0c7b817dc68f0ed", "llama_decode": "c6d24d39c1fabc19"}
 
 
 @pytest.mark.parametrize("case", sorted(PROGRAMS_BEFORE))
@@ -192,10 +194,37 @@ def test_a_family_without_a_hand_on_or_identity_experts_lowers_to_the_program_it
             local_experts=1), {}, 2, 16, 4, None),
         "lfm2_period_compacted": (lfm2, lfm2.Lfm2Config.tiny(), {"state_slots": 5}, 4, 16, 5, 32),
         "llama_decode": (llama, llama.LlamaConfig.tiny(), {}, 4, 1, 4, 32)}[case]
+    text = lowered_step(module, cfg, cache_kw, n, t, b, bound)[0]
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PROGRAMS_BEFORE[case]
+
+
+def lowered_step(module, cfg, cache_kw, n, t, b, bound):
+    """``(text, cache)``: a family's step program over ``[n, t]`` as it lowers, from shapes alone."""
     params = jax.eval_shape(lambda: module.init_params(cfg, jax.random.PRNGKey(0)))
     kv = jax.eval_shape(lambda: module.init_paged_cache(cfg, 16, 8, dtype=jnp.float32, **cache_kw))
     ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    text = jax.jit(lambda p, kv, tok, nt, sp, tab: module.forward_paged(
+    return jax.jit(lambda p, kv, tok, nt, sp, tab: module.forward_paged(
         cfg, p, tok, nt, sp, tab, kv, block_size=8, live_token_bound=bound, last_rows=True)).lower(
-            params, kv, ints(n, t), ints(n), ints(n), ints(n, b)).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PROGRAMS_BEFORE[case]
+            params, kv, ints(n, t), ints(n), ints(n), ints(n, b)).as_text(), kv
+
+
+@pytest.mark.parametrize("family, config, sizes", [
+    ("bailing_hybrid", "BailingHybridConfig", {}),
+    ("qwen3_next", "Qwen3NextConfig", {"linear_dim": 16}),  # 128 columns: not the stream's 64
+    ("granite_moe_hybrid", "GraniteMoeHybridConfig", {})], ids=["bailing_hybrid", "qwen3_next", "granite_moe_hybrid"])
+def test_a_compacted_pass_makes_no_shifted_copy_of_a_mixers_columns(family, config, sizes):
+    """ISSUE 59: the short filter reads the flat chunk itself; no gather from the rows' kept
+    values and no pad a tap over the pass's ``[S, D]`` columns is left in the program."""
+    import importlib
+    import re
+    from deepspeed_tpu.models.transformer import STATE, flat_slots
+    module = importlib.import_module(f"deepspeed_tpu.models.{family}")
+    cfg = getattr(module, config).tiny(**sizes)
+    text, kv = lowered_step(module, cfg, {"state_slots": 5}, 4, 16, 5, 32)
+    s, (k, d) = flat_slots(4, 16, 32), kv[STATE]["conv"].shape[-2:]
+    assert s == 32 and d != cfg.hidden_size  # compacted, and the filter's columns told from the stream's
+    results = [line.rsplit("->", 1)[1] for line in text.splitlines()
+               if re.search(r"stablehlo\.(gather|pad)\b", line)]
+    assert results and not [r for r in results if re.search(rf"tensor<(1x)?{s}x{d}x", r)], results
+    # the filter's own pad is there: the chunk with ``k`` rows in front, sliced a tap
+    assert any(f"tensor<{s + k}x{d}x" in r for r in results)
