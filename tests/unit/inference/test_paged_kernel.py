@@ -6,6 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.unit.ops.compiled import compiled
+
 from .test_inference_v2 import _paged_case
 
 
@@ -29,8 +31,8 @@ def _assert_kernel_is_the_fallback(case, block_size, window, slopes, atol):
     q, kpool, vpool, tables, lengths, start_pos, n_tokens = case
     ref = _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
                           1.0 / np.sqrt(q.shape[-1]), window, slopes)
-    got = paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
-                          block_size=block_size, window=window, alibi_slopes=slopes)
+    got = compiled(paged_attention, block_size=block_size, window=window, alibi_slopes=slopes)(
+        q, kpool, vpool, tables, lengths, start_pos, n_tokens)
     assert got.shape == q.shape and got.dtype == q.dtype
     valid = np.asarray(jnp.arange(q.shape[1])[None, :] < n_tokens[:, None])
     got, ref = (np.asarray(a.astype(jnp.float32)) for a in (got, ref))

@@ -6,6 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.unit.ops.compiled import compiled
+
 
 def _latent_paged_case(H, T, dk, dtype, seed=0):
     """``_ragged_paged_case`` over a latent pool: one KV head whose key is
@@ -39,8 +41,8 @@ def test_paged_attention_with_a_value_that_is_a_prefix_of_the_key(
         assert kvg == 1 and splits > 1 and splits * rows == T * H  # equal parts: q is not padded
     ref = paged._dense_fallback(q, pool, None, tables, lengths, start_pos, n_tokens, 0.21, None,
                                 None, dv)
-    got = paged.paged_attention(q, pool, None, tables, lengths, start_pos, n_tokens,
-                                block_size=16, softmax_scale=0.21, value_dim=dv)
+    attend = compiled(paged.paged_attention, block_size=16, softmax_scale=0.21, value_dim=dv)  # one program
+    got = attend(q, pool, None, tables, lengths, start_pos, n_tokens)
     assert got.shape == q.shape[:3] + (dv, ) and got.dtype == q.dtype
     valid = np.asarray(jnp.arange(T)[None, :] < n_tokens[:, None])
     got, ref = (np.asarray(a.astype(jnp.float32)) for a in (got, ref))
@@ -48,15 +50,11 @@ def test_paged_attention_with_a_value_that_is_a_prefix_of_the_key(
     assert (got[~valid] == 0.0).all()
     # the columns past dv are key and never value: with them negated the scores change,
     # with q's share of them zeroed as well nothing does
-    other = paged.paged_attention(q, pool.at[..., dv:].multiply(-1.0), None, tables, lengths,
-                                  start_pos, n_tokens, block_size=16, softmax_scale=0.21,
-                                  value_dim=dv)
+    other = attend(q, pool.at[..., dv:].multiply(-1.0), None, tables, lengths, start_pos, n_tokens)
     assert not np.allclose(np.asarray(other.astype(jnp.float32))[valid], ref[valid], atol=1e-2)
-    same = paged.paged_attention(q.at[..., dv:].set(0.0), pool.at[..., dv:].multiply(-1.0), None,
-                                 tables, lengths, start_pos, n_tokens, block_size=16,
-                                 softmax_scale=0.21, value_dim=dv)
-    blind = paged.paged_attention(q.at[..., dv:].set(0.0), pool, None, tables, lengths, start_pos,
-                                  n_tokens, block_size=16, softmax_scale=0.21, value_dim=dv)
+    same = attend(q.at[..., dv:].set(0.0), pool.at[..., dv:].multiply(-1.0), None, tables, lengths, start_pos,
+                  n_tokens)
+    blind = attend(q.at[..., dv:].set(0.0), pool, None, tables, lengths, start_pos, n_tokens)
     assert np.array_equal(np.asarray(same.astype(jnp.float32)), np.asarray(blind.astype(jnp.float32)))
 
 

@@ -14,6 +14,8 @@ from deepspeed_tpu.inference.v2.fastpath import ServeCounters
 from deepspeed_tpu.ops import _pallas
 from deepspeed_tpu.ops.attention import dsa, paged
 
+from .compiled import compiled
+
 
 def top_k_by_sorting(scores, valid, k):
     want = np.zeros(scores.shape, bool)
@@ -90,16 +92,16 @@ def test_the_index_score_kernel_is_the_plain_sum(interpreted_kernels, monkeypatc
     layouts against the gathered table; a token's visible columns are compared."""
     bs, maxb = 8, max(-(-(s + c) // 8) for s, c in zip(starts, counts)) + 2
     pool, tables, q, w, start, count, seen = paged_case(1, n, t, 4, 16, bs, maxb, starts, counts)
-    got = dsa.index_scores(q, w, pool, tables, start, count)
+    got = compiled(dsa.index_scores)(q, w, pool, tables, start, count)
     (qf, wf, seenf), live, at = flat_of(count, t, q, w, seen)
-    gotf = dsa.index_scores(qf, wf, pool, tables, start, count, chunk=t)
+    gotf = compiled(dsa.index_scores, chunk=t)(qf, wf, pool, tables, start, count)
     monkeypatch.setattr(_pallas, "INTERPRET", False)
     want = dsa.index_scores(q, w, pool, tables, start, count)
     assert got.shape == want.shape == (n, t, maxb * bs)
     np.testing.assert_allclose(np.where(seen, got, 0), np.where(seen, want, 0), atol=2e-5)
     seenf = np.asarray(seenf) & live[:, None]
     np.testing.assert_allclose(np.where(seenf, gotf, 0), np.where(seenf, want[at], 0), atol=2e-5)
-    sel = dsa.select_keys(qf, wf, pool, tables, start, count, topk=4, chunk=t)
+    sel = compiled(dsa.select_keys, topk=4, chunk=t)(qf, wf, pool, tables, start, count)
     pos = np.asarray(start)[at[0]] + np.asarray(at[1])
     assert (np.asarray(sel).sum(-1) == np.where(live, np.minimum(pos + 1, 4), 0)).all()
 

@@ -11,6 +11,7 @@ import pytest
 from deepspeed_tpu.ops import _pallas
 from deepspeed_tpu.ops.attention import paged
 
+from .compiled import compiled
 from .test_dsa import flat_of
 from .test_paged_slots_chooser import every_equation
 
@@ -58,9 +59,9 @@ def test_the_paged_kernel_attends_the_selection_alone(interpreted_kernels, monke
             1, t * heads // splits, paged.ROW_TILE, q.shape[-1], 16, 4, 4, facts["value_dim"]))
     assert paged.step_tile(t, heads, 1, q.shape[-1], 16, q.dtype, q.dtype, facts["value_dim"])[2] == splits
     count = args[-1]
-    got = paged.paged_attention(q, *args, selection=chosen, **facts)
+    got = compiled(paged.paged_attention, selection=chosen, **facts)(q, *args)
     (qf, chosenf), live, at = flat_of(count, t, q, chosen)
-    gotf = paged.paged_attention_flat(qf, *args, chunk=t, selection=chosenf, **facts)
+    gotf = compiled(paged.paged_attention_flat, chunk=t, selection=chosenf, **facts)(qf, *args)
     monkeypatch.setattr(_pallas, "INTERPRET", False)
     want = paged.paged_attention(q, *args, selection=chosen, **facts)
     every = paged.paged_attention(q, *args, **facts)

@@ -16,6 +16,7 @@ from jax import lax
 
 from deepspeed_tpu.ops.attention import paged
 
+from .compiled import compiled
 from .test_dsa import flat_of as selected_flat_of
 from .test_dsa_selection import selected_case
 from .test_paged_slots import BS, drawn_case
@@ -202,9 +203,9 @@ def kernel_and_parent(monkeypatch, name, layout):
 
     def call():
         if layout == "padded":
-            return np.asarray(paged.paged_attention(*drawn, **facts))
+            return np.asarray(compiled(paged.paged_attention, **facts)(*drawn))
         flat, _ = flat_of(drawn, spare=5)
-        return np.asarray(paged.paged_attention_flat(flat, *drawn[1:], chunk=t, **facts))
+        return np.asarray(compiled(paged.paged_attention_flat, chunk=t, **facts)(flat, *drawn[1:]))
 
     got = call()
     parents_loop(monkeypatch)
@@ -254,15 +255,15 @@ def test_the_band_under_a_selection_walks_from_the_tables_first_slot(monkeypatch
                                  facts["value_dim"], selection=chosen)
     (qf, chosenf), live, at = selected_flat_of(args[-1], t, q, chosen)
     monkeypatch.setattr(_pallas, "INTERPRET", True)
-    got = paged.paged_attention(q, *args, selection=chosen, **facts)
-    gotf = paged.paged_attention_flat(qf, *args, chunk=t, selection=chosenf, **facts)
+    got = compiled(paged.paged_attention, selection=chosen, **facts)(q, *args)
+    gotf = compiled(paged.paged_attention_flat, chunk=t, selection=chosenf, **facts)(qf, *args)
     valid = np.asarray(jnp.arange(t)[None, :] < args[-1][:, None])
     np.testing.assert_allclose(np.asarray(got)[valid], np.asarray(want)[valid], atol=2e-6)
     np.testing.assert_allclose(np.asarray(gotf)[live], np.asarray(want[at])[live], atol=2e-6)
     parents_loop(monkeypatch)
-    np.testing.assert_array_equal(got, paged.paged_attention(q, *args, selection=chosen, **facts))
-    np.testing.assert_array_equal(gotf, paged.paged_attention_flat(qf, *args, chunk=t, selection=chosenf,
-                                                                  **facts))
+    np.testing.assert_array_equal(got, compiled(paged.paged_attention, selection=chosen, **facts)(q, *args))
+    np.testing.assert_array_equal(gotf, compiled(paged.paged_attention_flat, chunk=t, selection=chosenf,
+                                                 **facts)(qf, *args))
 
 
 # --------------------------------------------- (c) where a tile's state begins
@@ -287,9 +288,10 @@ def test_a_tiles_state_begins_at_its_own_first_step(monkeypatch, layout):
 
     def call():
         if layout == "padded":
-            return np.asarray(paged.paged_attention(*case, block_size=BS, window=window))
+            return np.asarray(compiled(paged.paged_attention, block_size=BS, window=window)(*case))
         flat, (row, col) = flat_of(case)
-        got = np.asarray(paged.paged_attention_flat(flat, *case[1:], chunk=t, block_size=BS, window=window))
+        got = np.asarray(compiled(paged.paged_attention_flat, chunk=t, block_size=BS, window=window)(
+            flat, *case[1:]))
         return _onto(got, row, col, want.shape)
 
     np.testing.assert_allclose(call(), want, atol=2e-5)

@@ -6,11 +6,15 @@ chooser, the counters and the kernel's traced size are
 is one worker's from start to end, and every case here is an interpreted kernel
 of its own shape)."""
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from deepspeed_tpu.ops.attention import paged
+
+from .compiled import compiled
 
 BS = 16  # keys a block; a step of four slots holds 64
 
@@ -29,14 +33,14 @@ def drawn_case(rows, t, hq, kvh, maxb, dk=32, dv=None, dtype=jnp.float32, seed=0
     return q, kpool, vpool, tables, lengths, lengths - n_tokens, n_tokens
 
 
-def assert_kernel_is_the_fallback(case, window=None, slopes=None, dv=None, scale=None, atol=2e-5):
+def assert_kernel_is_the_fallback(case, window=None, slopes=None, dv=None, scale=None, atol=2e-5,
+                                  call=compiled):
     q, kpool, vpool, tables, lengths, start_pos, n_tokens = case
     scale = scale or 1.0 / np.sqrt(q.shape[-1])
     ref = paged._dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale,
                                 window, slopes, dv)
-    got = paged.paged_attention(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
-                                block_size=BS, window=window, alibi_slopes=slopes,
-                                softmax_scale=scale, value_dim=dv)
+    got = call(paged.paged_attention, block_size=BS, window=window, alibi_slopes=slopes,
+               softmax_scale=float(scale), value_dim=dv)(q, kpool, vpool, tables, lengths, start_pos, n_tokens)
     assert got.shape == q.shape[:3] + (dv or q.shape[-1], ) and got.dtype == q.dtype
     valid = np.asarray(jnp.arange(q.shape[1])[None, :] < n_tokens[:, None])
     got, ref = (np.asarray(a.astype(jnp.float32)) for a in (got, ref))
@@ -118,5 +122,6 @@ def test_every_copy_is_waited_for_before_its_block_is_read(monkeypatch, hq, kvh,
     from deepspeed_tpu.ops import _pallas
     monkeypatch.setattr(_pallas, "INTERPRET", pltpu.InterpretParams(
         dma_execution_mode="on_wait", detect_races=True))
-    assert_kernel_is_the_fallback(drawn_case(ends(maxb, t), t, hq, kvh, maxb))
+    # eagerly: the interpreter that models DMA runs op by op
+    assert_kernel_is_the_fallback(drawn_case(ends(maxb, t), t, hq, kvh, maxb), call=functools.partial)
     assert not interpreter.races.races_found

@@ -10,6 +10,7 @@ import pytest
 
 from deepspeed_tpu.ops.attention import paged
 
+from .compiled import compiled
 from .test_paged_slots import BS, drawn_case
 
 
@@ -66,10 +67,10 @@ def flat_is_the_padded_bucket(monkeypatch, case, how, path):
     facts = dict(block_size=BS, window=how.get("window"), alibi_slopes=slopes,
                  softmax_scale=how.get("scale"), value_dim=how.get("dv"))
     drawn = drawn_case(**case)
-    padded = paged.paged_attention(*drawn, **facts)
+    padded = compiled(paged.paged_attention, **facts)(*drawn)
     for spare in (0, 9):  # the flat axis full to its last slot, and with dead slots behind
         flat, (row, col) = flat_of(drawn, spare)
-        got = paged.paged_attention_flat(flat, *drawn[1:], chunk=case["t"], **facts)
+        got = compiled(paged.paged_attention_flat, chunk=case["t"], **facts)(flat, *drawn[1:])
         assert got.shape == flat.shape[:2] + (how.get("dv") or flat.shape[-1], ) and got.dtype == flat.dtype
         got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, padded))
         np.testing.assert_array_equal(got[:len(row)], want[row, col])
