@@ -49,12 +49,39 @@ two factors, ``(k_i exp(gamma_i - rho))`` and ``(k_j exp(rho - gamma_j))`` with
 the first factor is at most 1, the second at most 1 for every EARLIER block's
 keys and at most ``exp((SUB - 1) x 5) = 3.7e32`` for the block's own, which
 float32 and bfloat16 hold (their largest: 3.4e38); the keys of LATER blocks are
-masked before the exponential (their factor would pass float32, and the causal
-mask takes them anyway).  That is what the bound of -5 is for and why a block is
-16 rows: ``exp(rho - gamma_j)`` over a whole chunk of 64 would reach
-``exp(315)``.  Accumulations are float32; the products' operands are in the
-dtype q, k and v come in (bfloat16 on the chip), but for the inverse's chain
-(three passes, as Gated DeltaNet's).
+not in the product at all (a static slice: their factor would pass float32, and
+the causal mask takes them anyway; zeros stand in their columns).  That is what
+the bound of -5 is for and why a block is 16 rows: ``exp(rho - gamma_j)`` over a
+whole chunk of 64 would reach ``exp(315)``.  Accumulations are float32; the
+products' operands are in the dtype q, k and v come in (bfloat16 on the chip),
+but for the inverse's chain (three passes, as Gated DeltaNet's).
+
+**A pair of heads together** (``_chunk``, as ``gated_delta.py``'s "The value
+heads of a key head together").  A chunk of the algebra takes ``p`` heads at
+once (``_pair``: ``gated_delta.heads_a_step``'s rule over a grid step's heads,
+2 where they are even, 1 where odd), their chunks one under another as ``p C``
+= 128 rows, the MXU's.  KDA's heads have their own keys, so less is shared than
+in Gated DeltaNet.  *A head's own:* its four row blocks of ``A`` and ``B``
+(``[2 SUB, dk] x [p C, dk]^T``, the factors above exactly) and what meets its
+state: ``W S_0``, ``(Q e^gamma) S_0``, ``K^T V'``.  *Once a pair:* ``I + N`` as
+ONE block-diagonal ``[p C, p C]`` with a head's ``[C, C]`` a block, inverted by
+the same ten products, joined up to ``C`` and no further; ``W``, ``U`` and
+``tril(B) V'`` one product each with the heads' right-hand sides stacked.
+**The zeros between the blocks are PLACED, never computed**: a row block's
+right factor is the head's keys up to the block's last with zero ROWS above and
+below (the later blocks' and the partner's), so the product's other columns
+are exact zeros and stay zeros through every product.  One head's rows against
+its partner's column factor is never taken: that factor is not bounded by the
+block rule, and ``inf x 0`` reads NaN (Gated DeltaNet got its zeros from
+``exp(-1e30)``; here they come from the layout).  Passes of the MXU a pair a
+chunk: 8 + 30 + 3 + 6 = 47 (the chain's ten products in three each), against 2
+x (4 + 30 + 6) = 80 a head at a time, and the chain's ladder of eight stages is
+walked once a pair.  The pairs of a grid step go through the algebra in
+LOCK-STEP (``_chunks``: one ``vmap``, so each stage of every pair's ladder
+stands beside the others' in the program and the MXU is fed by one while
+another's result drains; a Python loop over them walks the ladders one after
+another, PERF.md PR 61).  The result is the one-head algebra's to the bit: the
+stacked products sum the same terms and exact zeros.
 
 **Sequences on one axis** and **a pass of chunks** (:func:`kda_chunks`) are
 ``ssd.py``'s, shared and not copied: the padded layout is
@@ -65,10 +92,11 @@ the chunk table ``gated_delta._chunk_table``'s with the scan kernel's three rows
 more (the chunk's slot, whether its sequence begins, the chunk whose blocks an
 empty grid step names); a row of ONE token is the update's, a row of more the
 scan's, each passing the other's rows by on the trash slot.  On the TPU the walk
-is the Pallas kernel ``kda_scan``: grid (``SCAN_HEADS`` heads, chunk), q, k, v
-``[heads, C, d]``, ``gamma`` ``[heads, C, dk]`` float32, ``beta`` a row ``[heads,
-C]``, ``S`` in VMEM scratch between a sequence's chunks.  Off the TPU the same
-chunk mathematics, every head at once, under ``lax.scan``.
+is the Pallas kernel ``kda_scan``: grid (``SCAN_HEADS`` = 8 heads = four pairs a
+step, chunk), q, k, v ``[heads, C, d]``, ``gamma`` ``[heads, C, dk]`` float32,
+``beta`` a row ``[heads, C]``, ``S`` in VMEM scratch between a sequence's
+chunks.  Off the TPU the same chunk mathematics, the same pairs, every pair at
+once, under ``lax.scan``.
 """
 
 import functools
@@ -80,13 +108,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...compat import CompilerParams
 from .. import _pallas
-from .gated_delta import FIRST, LAST, LIVE, SEQ, _dot_f32, _unit_lower_inverse, lay_on_chunk_edges
+from .gated_delta import (FIRST, LAST, LIVE, SEQ, _dot_f32, _unit_lower_inverse, heads_a_step,
+                          lay_on_chunk_edges)
 from .ssd import (BEGINS, BLOCK, CHUNK, NN, NT, TN, _by_value, _dot, _heads_a_step, _lay_window,
                   _slot_table, scan_chunks, walk_trips)
 
 SUB = 16            # rows of a block of A and B: one reference row ``rho`` a block
 LOWER_BOUND = -5.0  # the least ``g`` a token a channel: (SUB - 1) x 5 = 75 < 88, float32's exponent
-SCAN_HEADS = 4      # heads one grid step of ``kda_scan`` takes
+SCAN_HEADS = 8      # heads one grid step of ``kda_scan`` takes: four pairs in lock-step (PERF.md, PR 61)
 UPDATE_HEADS = 8    # heads one grid step of ``kda_update`` takes: 8 x [128, 128] float32 = 512 KiB
 assert CHUNK % SUB == 0 and -(SUB - 1) * LOWER_BOUND < 87.0
 
@@ -187,40 +216,75 @@ def _update_pallas(at, flags, keys, bv, leaf, *, interpret):
 
 # ------------------------------------------------------------ one chunk's algebra
 def _chunk(q, k, v, gamma, beta, s0):
-    """One chunk of one head.  q, k ``[C, dk]``, v ``[C, dv]`` (their dtype is the
-    products' operand dtype), gamma ``[C, dk]`` float32 (the chunk's running sum
-    of g), beta ``[1, C]`` float32, s0 ``[dk, dv]`` float32.  Returns (o ``[C,
-    dv]`` float32, s1)."""
-    c, dk = q.shape
-    dtype = q.dtype
-    r = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    beta_c = _column(beta, r == col)
-    q32, k32 = q.astype(jnp.float32), k.astype(jnp.float32)
-    place = jax.lax.broadcasted_iota(jnp.int32, (c, dk), 0)
+    """One chunk of ``p`` heads (:func:`_pair`: 2 or 1), their chunks one under another as
+    ``p C`` rows.  q, k ``[p, C, dk]``, v ``[p, C, dv]`` (their dtype is the products'
+    operand dtype), gamma ``[p, C, dk]`` float32 (the chunk's running sum of g), beta ``[p,
+    C]`` float32, s0 ``[p, dk, dv]`` float32.  A head's row blocks of ``A`` and ``B`` are its
+    own products, laid on the diagonal of ``[p C, p C]`` beside PLACED zeros; ``I + N`` is
+    inverted as one, ``W``, ``U`` and the chunk's own part of ``O`` are one product each;
+    what meets the state is a product a head.  Returns (o ``[p, C, dv]`` float32, s1)."""
+    heads, c, dk = q.shape
+    dtype, size = q.dtype, heads * c
+    r = jax.lax.broadcasted_iota(jnp.int32, (size, size), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (size, size), 1)
+    stacked = lambda parts: parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    of = lambda a, h: a[h * c:(h + 1) * c]  # head h's rows
+    eye, eye_k = _eye(c), _eye(dk)
+    beta_c = stacked([_column(beta[h:h + 1], eye) for h in range(heads)])
+    q32, k32 = (a.reshape(size, dk).astype(jnp.float32) for a in (q, k))
+    gamma = gamma.reshape(size, dk)
+    zeros = lambda n: [jnp.zeros((n, dk), dtype)] if n else []
     a_rows, b_rows = [], []
-    for lo in range(0, c, SUB):
-        rho = gamma[lo:lo + 1]  # the block's first row: every factor below is at most exp(75)
-        ahead = jnp.exp(gamma[lo:lo + SUB] - rho)  # <= 1
-        # the keys up to the block's last: <= 1 before the block, up to exp((SUB - 1) x 5) inside
-        # it; the later blocks' are the causal mask's, and their factor would pass float32
-        behind = k32 * jnp.exp(jnp.where(place < lo + SUB, rho - gamma, -1e30))
-        both = _dot(jnp.concatenate([k32[lo:lo + SUB] * ahead, q32[lo:lo + SUB] * ahead], axis=0),
-                    behind, NT, dtype)  # [2 SUB, C]
-        a_rows.append(both[:SUB])
-        b_rows.append(both[SUB:])
+    for top in range(0, size, c):  # a head: its first row
+        for lo in range(top, top + c, SUB):
+            rho = gamma[lo:lo + 1]  # the block's first row: every factor below is at most exp(75)
+            ahead = jnp.exp(gamma[lo:lo + SUB] - rho)  # <= 1
+            # the head's keys up to the block's last: <= 1 before the block, up to exp((SUB - 1)
+            # x 5) inside it.  The later blocks' (their factor would pass float32, and the causal
+            # mask takes them anyway) and the other head's are never multiplied: zeros stand there
+            upto = slice(top, lo + SUB)
+            behind = (k32[upto] * jnp.exp(rho - gamma[upto])).astype(dtype)
+            behind = jnp.concatenate(zeros(top) + [behind] + zeros(size - lo - SUB), axis=0)
+            both = _dot(jnp.concatenate([k32[lo:lo + SUB] * ahead, q32[lo:lo + SUB] * ahead], axis=0),
+                        behind, NT, dtype)  # [2 SUB, p C]
+            a_rows.append(both[:SUB])
+            b_rows.append(both[SUB:])
     n = jnp.where(r > col, beta_c * jnp.concatenate(a_rows, axis=0), 0.0)
     inv = _unit_lower_inverse(n, r, col, _dot_f32 if dtype != jnp.float32 else functools.partial(
         jnp.dot, preferred_element_type=jnp.float32), top=c)
     e = jnp.exp(gamma)
     w = _dot(inv, beta_c * e * k32, NN, dtype)
-    u = _dot(inv, beta_c * v.astype(jnp.float32), NN, dtype)
-    v_new = u - _dot(w, s0, NN, dtype)
+    u = _dot(inv, beta_c * v.reshape(size, -1).astype(jnp.float32), NN, dtype)
+    v_new = stacked([of(u, h) - _dot(of(w, h), s0[h], NN, dtype) for h in range(heads)])
     within = jnp.where(r >= col, jnp.concatenate(b_rows, axis=0), 0.0)
-    o = _dot(e * q32, s0, NN, dtype) + _dot(within, v_new, NN, dtype)
-    last = gamma[c - 1:c]  # the chunk's whole decay, a row over the channels
-    s1 = _column(jnp.exp(last), _eye(dk)) * s0 + _dot(jnp.exp(last - gamma) * k32, v_new, TN, dtype)
-    return o, s1
+    inner = _dot(within, v_new, NN, dtype)
+    eq = e * q32
+    o, s1 = [], []
+    for h in range(heads):
+        o.append(_dot(of(eq, h), s0[h], NN, dtype) + of(inner, h))
+        last = gamma[(h + 1) * c - 1:(h + 1) * c]  # the chunk's whole decay, a row over the channels
+        s1.append(_column(jnp.exp(last), eye_k) * s0[h]
+                  + _dot(jnp.exp(last - of(gamma, h)) * of(k32, h), of(v_new, h), TN, dtype))
+    return jnp.stack(o), jnp.stack(s1)
+
+
+def _pair(heads: int) -> int:
+    """The heads one chunk of the algebra takes together: ``gated_delta.heads_a_step``'s rule
+    over the heads of a grid step (2 where they are even and ``2 x CHUNK <= 128``, else 1)."""
+    return heads_a_step(_heads_a_step(heads, SCAN_HEADS))
+
+
+def _chunks(q, k, v, gamma, beta, s0):
+    """:func:`_chunk` over heads (the first axis of every argument), :func:`_pair` of them at
+    a time and the pairs in LOCK-STEP: one ``vmap``, so stage by stage the pairs' inverse chains
+    stand side by side in the program, and one pair's product enters the MXU while another's
+    leaves it (a Python loop over the pairs walks the ladders one after another: PERF.md,
+    PRs 46 and 61)."""
+    pair = _pair(q.shape[0])
+    paired = lambda a: a.reshape((a.shape[0] // pair, pair) + a.shape[1:])
+    whole = lambda a: a.reshape((-1, ) + a.shape[2:])
+    o, s1 = jax.vmap(_chunk)(*map(paired, (q, k, v, gamma, beta, s0)))
+    return whole(o), whole(s1)
 
 
 # ------------------------------------------------------------- a pass of chunks
@@ -306,14 +370,13 @@ def _walk_scan(table, q, k, v, gamma, beta, state):
     ``[H, chunks C, d]``, beta ``[H, chunks, C]``, state ``[N, H, dk, dv]``."""
     heads, dk, dv = q.shape[0], q.shape[-1], v.shape[-1]
     chunks = table.shape[1]
-    per_head = jax.vmap(_chunk)
     by_chunk = lambda a: jnp.moveaxis(a.reshape(heads, chunks, CHUNK, -1), 1, 0)
 
     def one(carry, inp):
         s, states = carry
         (seq, first, last, live), qc, kc, vc, gc, bc = inp
         s = jnp.where(first > 0, states[seq], s)
-        o, s1 = per_head(qc, kc, vc, gc, bc, s)
+        o, s1 = _chunks(qc, kc, vc, gc, bc, s)  # the kernel's pairs
         s = jnp.where(live > 0, s1, s)
         states = states.at[jnp.where(last > 0, seq, states.shape[0])].set(s, mode="drop")
         return (s, states), jnp.where(live > 0, o, 0.0).astype(v.dtype)
@@ -321,14 +384,13 @@ def _walk_scan(table, q, k, v, gamma, beta, state):
     (_, state), o = jax.lax.scan(
         one, (jnp.zeros((heads, dk, dv), jnp.float32), state),
         (table.T, by_chunk(q), by_chunk(k), by_chunk(v), by_chunk(gamma),
-         jnp.moveaxis(beta, 1, 0)[:, :, None, :]))
+         jnp.moveaxis(beta, 1, 0)))
     return jnp.moveaxis(o, 0, 1).reshape(heads, chunks * CHUNK, dv), state
 
 
 def _scan_body(table_ref, q_ref, k_ref, v_ref, gamma_ref, beta_ref, state_ref, o_ref, out_state_ref,
                s_ref):
     c = pl.program_id(1)
-    heads = q_ref.shape[0]
 
     @pl.when((table_ref[FIRST, c] > 0) & (table_ref[BEGINS, c] == 0))
     def _load():
@@ -340,11 +402,9 @@ def _scan_body(table_ref, q_ref, k_ref, v_ref, gamma_ref, beta_ref, state_ref, o
 
     @pl.when(table_ref[LIVE, c] > 0)
     def _compute():
-        beta = beta_ref[0, 0]  # [heads, C]
-        for h in range(heads):
-            o, s1 = _chunk(q_ref[h], k_ref[h], v_ref[h], gamma_ref[h], beta[h:h + 1], s_ref[h])
-            o_ref[h] = o.astype(o_ref.dtype)
-            s_ref[h] = s1
+        o, s1 = _chunks(q_ref[...], k_ref[...], v_ref[...], gamma_ref[...], beta_ref[0, 0], s_ref[...])
+        o_ref[...] = o.astype(o_ref.dtype)
+        s_ref[...] = s1
 
     @pl.when(table_ref[LAST, c] > 0)
     def _store():

@@ -28,6 +28,30 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
+def pytest_configure(config):
+    """Under xdist's ``--dist loadfile`` the files go to the workers in the order they were
+    collected, not xdist's default of the most cases first.  By cases, a file of few and long
+    ones comes last (``tests/chipbench/test_reference_longcat_flash.py``: 9 cases, 220-345 s,
+    begun after 1,170 s of a run of 1,540), and one worker ends minutes after the other
+    five.  Collected, ``tests/chipbench`` comes first (``test_harness.py``, the longest file,
+    at second 0) and the run ends on ``tests/unit/test_*.py``, files of seconds.  The option
+    is xdist's own ``--no-loadscope-reorder``; without xdist there is nothing to set."""
+    if getattr(config.option, "loadscopereorder", False):
+        config.option.loadscopereorder = False
+
+
+LONG_FIRST = ("tests/chipbench/", "tests/unit/ops/", "tests/unit/inference/")
+
+
+def pytest_collection_modifyitems(items):
+    """The directories of long files first (a stable sort: a file's cases stay together and
+    in their order), so that the last files a worker is handed are ``tests/unit/test_*.py``'s
+    and not ``tests/unit/ops/test_tpu_compile*.py`` (200 s each, begun at second 1,140 of
+    1,380 in collected order)."""
+    items.sort(key=lambda item: next((i for i, d in enumerate(LONG_FIRST) if item.nodeid.startswith(d)),
+                                     len(LONG_FIRST)))
+
+
 @pytest.fixture(autouse=True)
 def _reset_global_topology():
     yield

@@ -10,6 +10,7 @@ import pytest
 from deepspeed_tpu.inference.v2 import (BlockedAllocator, InferenceEngineV2, RaggedStateManager,
                                         SplitFuseScheduler)
 from deepspeed_tpu.models import llama
+from tests.unit.ops.compiled import compiled, dense_fallback
 
 
 def test_blocked_allocator_roundtrip():
@@ -110,7 +111,7 @@ def test_paged_attention_reads_a_layer_of_the_flat_stack_through_offset_tables(
     layer scan's index is) give the kernel and the fallback the bits of layer
     l handed alone, at the last layer (an offset lost would read layer 0) and
     at a middle one."""
-    from deepspeed_tpu.ops.attention.paged import _dense_fallback, paged_attention
+    from deepspeed_tpu.ops.attention.paged import paged_attention
     L, NB = 3, 16
     q, kstack, vstack, tables, *rest = _paged_case(H, KV, T, layers=L)
     lengths, start_pos, n_tokens = rest
@@ -118,19 +119,20 @@ def test_paged_attention_reads_a_layer_of_the_flat_stack_through_offset_tables(
     kw = dict(block_size=8, window=window)
     valid = np.asarray(jnp.arange(T)[None, :] < n_tokens[:, None])
     offset = jax.jit(lambda l: paged_attention(q, kflat, vflat, tables + l * NB, *rest, **kw))
-    first = np.asarray(paged_attention(q, kstack[0], vstack[0], tables, *rest, **kw))[valid]
+    one_layer = compiled(paged_attention, **kw)  # one program for the three layers handed alone
+    first = np.asarray(one_layer(q, kstack[0], vstack[0], tables, *rest))[valid]
     for l in (L - 1, 1):
-        alone = np.asarray(paged_attention(q, kstack[l], vstack[l], tables, *rest, **kw))[valid]
+        alone = np.asarray(one_layer(q, kstack[l], vstack[l], tables, *rest))[valid]
         np.testing.assert_array_equal(np.asarray(offset(jnp.int32(l)))[valid], alone)
         assert not np.array_equal(alone, first)
-    ref = _dense_fallback(q, kstack[L - 1], vstack[L - 1], tables, lengths, start_pos, n_tokens,
-                          1.0 / np.sqrt(q.shape[-1]), window)
+    ref = dense_fallback(q, kstack[L - 1], vstack[L - 1], tables, lengths, start_pos, n_tokens,
+                         1.0 / np.sqrt(q.shape[-1]), window)
     np.testing.assert_allclose(np.asarray(offset(jnp.int32(L - 1)))[valid],
                                np.asarray(ref)[valid], atol=2e-5)
     # off the TPU the same call is the fallback's own indexing: the same bits
     from deepspeed_tpu.ops import _pallas
     monkeypatch.setattr(_pallas, "INTERPRET", False)
-    fell_back = paged_attention(q, kflat, vflat, tables + (L - 1) * NB, *rest, **kw)
+    fell_back = compiled(paged_attention, **kw)(q, kflat, vflat, tables + (L - 1) * NB, *rest)
     np.testing.assert_array_equal(np.asarray(fell_back)[valid], np.asarray(ref)[valid])
 
 

@@ -44,11 +44,11 @@ def written_both_ways(n_tokens, start_pos, t, bound, *, kvh=8, width=128, dtype=
                                                             slots))
     rows = [jnp.asarray(rng.standard_normal(blk.shape + (kvh, width)), dtype) for _ in pools]
     first = jnp.int32(layer * nb)
-    plan = kvw.write_plan(pools, n_tokens, start_pos, tables, t=t, slots=slots)
+    plan = jax.jit(lambda *a: kvw.write_plan(*a, t=t, slots=slots))(pools, n_tokens, start_pos, tables)
     assert plan is not None and plan.table.shape == (5, kvw.work_bound(n, t, slots,
                                                                        kvw.tile_rows(pools)))
     got = jax.jit(lambda pools, rows: kvw.kv_write(pools, rows, first, blk, off, plan))(pools, rows)
-    want = kvw.kv_write(pools, rows, first, blk, off, None)
+    want = jax.jit(lambda pools, rows: kvw.kv_write(pools, rows, first, blk, off, None))(pools, rows)
     return got, want, pools, [l * nb + nb - 1 for l in range(W_LAYERS)], plan
 
 

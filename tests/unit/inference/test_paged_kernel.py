@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.unit.ops.compiled import compiled
+from tests.unit.ops.compiled import compiled, dense_fallback
 
 from .test_inference_v2 import _paged_case
 
@@ -27,10 +27,10 @@ def _ragged_paged_case(H, KV, T, dtype, seed=0):
 
 
 def _assert_kernel_is_the_fallback(case, block_size, window, slopes, atol):
-    from deepspeed_tpu.ops.attention.paged import _dense_fallback, paged_attention
+    from deepspeed_tpu.ops.attention.paged import paged_attention
     q, kpool, vpool, tables, lengths, start_pos, n_tokens = case
-    ref = _dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
-                          1.0 / np.sqrt(q.shape[-1]), window, slopes)
+    ref = dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
+                         1.0 / np.sqrt(q.shape[-1]), window, slopes)
     got = compiled(paged_attention, block_size=block_size, window=window, alibi_slopes=slopes)(
         q, kpool, vpool, tables, lengths, start_pos, n_tokens)
     assert got.shape == q.shape and got.dtype == q.dtype

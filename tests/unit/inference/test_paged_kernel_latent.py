@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.unit.ops.compiled import compiled
+from tests.unit.ops.compiled import compiled, dense_fallback
 
 
 def _latent_paged_case(H, T, dk, dtype, seed=0):
@@ -39,8 +39,7 @@ def test_paged_attention_with_a_value_that_is_a_prefix_of_the_key(
         monkeypatch.setattr(paged, "VMEM_BUDGET_BYTES", 2 << 20)
         kvg, rows, splits, tile, _ = paged.step_tile(T, H, 1, dk, 16, q.dtype, pool.dtype, dv)
         assert kvg == 1 and splits > 1 and splits * rows == T * H  # equal parts: q is not padded
-    ref = paged._dense_fallback(q, pool, None, tables, lengths, start_pos, n_tokens, 0.21, None,
-                                None, dv)
+    ref = dense_fallback(q, pool, None, tables, lengths, start_pos, n_tokens, 0.21, None, None, dv)
     attend = compiled(paged.paged_attention, block_size=16, softmax_scale=0.21, value_dim=dv)  # one program
     got = attend(q, pool, None, tables, lengths, start_pos, n_tokens)
     assert got.shape == q.shape[:3] + (dv, ) and got.dtype == q.dtype

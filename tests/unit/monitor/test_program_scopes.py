@@ -293,7 +293,11 @@ def _tiny_engine(conf=None):
 @pytest.fixture(scope="module")
 def served():
     """A tiny engine that served a wave (forwards, a pick, a burst, the fast
-    path's scatter), its tables and its tokens."""
+    path's scatter), its tables and its tokens.  The process's registry begins empty:
+    ``tables(names)`` with no owner merges ANYONE's programs of a name, the engine that died
+    last included, and an earlier file of this worker (``test_compile_events.py``) leaves a
+    ``fwd_*`` of the same bucket's name behind."""
+    program_scopes.REGISTRY.clear()
     engine = _tiny_engine()
     tokens = engine.generate([[1, 2, 3], [4, 5, 6, 7], [8, 9]], max_new_tokens=8)
     return engine, engine.program_scopes(), tokens

@@ -63,8 +63,8 @@ def test_the_paged_kernel_attends_the_selection_alone(interpreted_kernels, monke
     (qf, chosenf), live, at = flat_of(count, t, q, chosen)
     gotf = compiled(paged.paged_attention_flat, chunk=t, selection=chosenf, **facts)(qf, *args)
     monkeypatch.setattr(_pallas, "INTERPRET", False)
-    want = paged.paged_attention(q, *args, selection=chosen, **facts)
-    every = paged.paged_attention(q, *args, **facts)
+    want = compiled(paged.paged_attention, selection=chosen, **facts)(q, *args)  # the fallback, one program
+    every = compiled(paged.paged_attention, **facts)(q, *args)
     np.testing.assert_allclose(got, want, atol=2e-6)
     np.testing.assert_allclose(gotf[live], want[at][live], atol=2e-6)
     assert float(jnp.max(jnp.abs(want - every))) > 0.1  # the selection is not every key

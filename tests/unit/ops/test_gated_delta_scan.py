@@ -7,6 +7,8 @@ chunk, with and without a carried state, decays near 0 and near 1, and the
 one-token update; each at one, two and four value heads a key head (the kernel
 takes one or two of a key head's value heads a grid step: ``heads_a_step``)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -49,10 +51,13 @@ def draw(rng, s, decay=(1e-4, 3.0)):
     return q, k, v, g, beta
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _rule(rep, q, k, v, g, beta, state):  # one program a length, not an op at a time
+    return delta_rule(jnp.repeat(q, rep, 1), jnp.repeat(k, rep, 1), v, jnp.exp(g), beta, state)
+
+
 def token_by_token(seq, state):
-    q, k, v, g, beta = seq
-    o, last = delta_rule(jnp.repeat(q, HV // HK, 1), jnp.repeat(k, HV // HK, 1), v, jnp.exp(g), beta,
-                         state)
+    o, last = _rule(HV // HK, *seq, state)
     return np.asarray(o), np.asarray(last)
 
 

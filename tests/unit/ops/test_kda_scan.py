@@ -8,8 +8,11 @@ over several passes, the state BY REFERENCE (slots in any order, a sequence that
 begins over whatever its slot holds, every slot no live row names left bit for
 bit), a pass of chunks whose one-token rows go to the update, and what float32
 must survive at the published size: decays AT THE BOUND of -5 in every channel,
-keys that resemble one another, SiLU's one orthant, at heads of 128."""
+keys that resemble one another, SiLU's one orthant, at heads of 128; and the PAIR of
+heads a chunk of the algebra takes on the MXU's 128 rows: no head reaches its partner,
+and an odd count of heads still runs one head a chunk."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -20,7 +23,9 @@ from deepspeed_tpu.ops.linear_attention import kda
 from deepspeed_tpu.ops.linear_attention.kda import LOWER_BOUND, kda_chunks, kda_scan, kda_step
 from deepspeed_tpu.ops.linear_attention.ssd import CHUNK, WINDOW
 
-H, DK, DV = 8, 16, 16  # 8 heads: two grid steps of the scan kernel's four
+from .compiled import entry
+
+H, DK, DV = 8, 16, 16  # 8 heads: one grid step of the scan kernel, four pairs in lock-step (16, two steps: the pair's test)
 TOL = 2e-5  # float32 throughout, of the largest value: a chunk's products and its inverse against 64 steps
 
 
@@ -59,9 +64,13 @@ def draw(rng, s, h=H, dk=DK, dv=DV, g=None):
     return tuple(np.asarray(a, np.float32) for a in (q, k, v, np.broadcast_to(g, (s, h, dk)), beta))
 
 
+@jax.jit
+def _rule(q, k, v, g, beta, state):  # one program a length, not an op at a time
+    return delta_rule(q, k, v, jnp.exp(g), beta, state)
+
+
 def token_by_token(seq, state):
-    q, k, v, g, beta = (jnp.asarray(a) for a in seq)
-    o, last = delta_rule(q, k, v, jnp.exp(g), beta, None if state is None else jnp.asarray(state))
+    o, last = _rule(*(jnp.asarray(a) for a in seq), None if state is None else jnp.asarray(state))
     return np.asarray(o), np.asarray(last)
 
 
@@ -89,7 +98,7 @@ def scan(arrays, state, counts, row=None, col=None, at=None, begins=None, trash=
     at = jnp.arange(n, dtype=jnp.int32) if at is None else jnp.asarray(at, jnp.int32)
     begins = jnp.zeros(n, bool) if begins is None else jnp.asarray(begins)
     fn, more = (kda_scan, ()) if trash is None else (kda_chunks, (jnp.int32(trash), ))
-    return fn(*arrays, jnp.asarray(state), at, begins, *more, jnp.asarray(counts, jnp.int32), row, col)
+    return entry(fn)(*arrays, jnp.asarray(state), at, begins, *more, jnp.asarray(counts, jnp.int32), row, col)
 
 
 @pytest.mark.parametrize("counts,carried", [((130, 0, 1, 64, 77), True), ((64, 5), False)],
@@ -184,7 +193,7 @@ def test_the_one_token_update_is_the_recurrence(form):
     at = np.array([7, 4, 8, 0, 8, 8])  # rows 2, 4 dead and row 5 passed by, all on the trash slot 8
     begins = np.array([False, True, False, False, False, False])
     passed = np.array([False, False, False, False, False, True])
-    o, new = kda_step(*(jnp.asarray(a) for a in (q, k, v, g, beta)), jnp.asarray(leaf),
+    o, new = entry(kda_step)(*(jnp.asarray(a) for a in (q, k, v, g, beta)), jnp.asarray(leaf),
                       jnp.asarray(at, jnp.int32), jnp.asarray(begins), jnp.asarray(passed))
     for row in (0, 1, 3):
         want, want_last = token_by_token([a[row:row + 1] for a in (q, k, v, g, beta)],
@@ -239,9 +248,11 @@ def test_what_float32_must_survive_at_small_heads(kernel, case):
 def test_heads_of_128_with_decays_at_the_bound_and_keys_that_resemble(dtype, tol, monkeypatch):
     """The published head size, the kernel interpreted, in float32 and with the chip's
     bfloat16 operands (the inverse's three-pass chain): keys alike in one orthant, every
-    channel at the bound in half the heads and near no decay in the others.  An inverse by
-    squarings over the whole chunk, or a factor taken over more than a block of 16, reads
-    NaN or inf here."""
+    channel at the bound in half the heads and near no decay in the others, by the head's
+    PARITY: so inside every pair that a chunk of the algebra stacks, one head is at the
+    bound and its partner is not.  An inverse by squarings over the whole chunk, a factor
+    taken over more than a block of 16, or a product of one head's rows with its partner's
+    column factor reads NaN or inf here."""
     monkeypatch.setattr(_pallas, "INTERPRET", True)
     rng = np.random.default_rng(9)
     s, h, d = 144, 4, 128
@@ -250,7 +261,7 @@ def test_heads_of_128_with_decays_at_the_bound_and_keys_that_resemble(dtype, tol
     seq = (q, k, v, g, beta)
     cast = lambda a, i: jnp.asarray(a, dtype if i < 3 else "float32")
     arrays = [cast(a[None], i) for i, a in enumerate(seq)]
-    o, last = kda_scan(*arrays, jnp.zeros((1, h, d, d), jnp.float32), jnp.zeros(1, jnp.int32),
+    o, last = entry(kda_scan)(*arrays, jnp.zeros((1, h, d, d), jnp.float32), jnp.zeros(1, jnp.int32),
                        jnp.ones(1, bool), jnp.asarray([s], jnp.int32))
     rounded = [np.asarray(cast(a, i), np.float32) for i, a in enumerate(seq)]
     want, want_last = token_by_token(rounded, None)
@@ -258,7 +269,52 @@ def test_heads_of_128_with_decays_at_the_bound_and_keys_that_resemble(dtype, tol
     near(last[0], want_last, tol)
 
 
+def test_an_odd_count_of_heads_runs_one_head_a_chunk(form):
+    """Three heads are no pairs (``gated_delta.heads_a_step``'s rule over a grid step's heads):
+    the algebra of one head's ``[64, 64]`` is still run, over two chunks and a carried state."""
+    heads = 3
+    assert (kda._pair(heads), kda._pair(H), kda._pair(16)) == (1, 2, 2)
+    rng = np.random.default_rng(13)
+    seq = draw(rng, 70, h=heads)
+    state = rng.normal(size=(1, heads, DK, DV)).astype(np.float32)
+    o, last = scan(padded([seq], (70, ), 70), state, (70, ))
+    want, want_last = token_by_token(seq, state[0])
+    near(o[0], want)
+    near(last[0], want_last)
+
+
+def test_no_head_leaks_into_its_pair(form):
+    """A pair in the SECOND grid step of sixteen heads (heads 8 and 9, one under another in
+    one chunk of the algebra): the first holds every channel at the bound with keys alike in
+    one orthant, the second holds ``g = 0``.  Each reads the recurrence, and reads the same
+    when its partner's q, k and v are replaced by others (the values a thousand times as
+    large): what stands between the heads' blocks is placed zeros, never a product (one
+    head's rows against its partner's column factor is not bounded by the block rule: ``inf
+    x 0``)."""
+    rng = np.random.default_rng(17)
+    s, heads, first, second = 70, 16, 8, 9
+    assert heads == 2 * kda.SCAN_HEADS and kda._pair(heads) == 2
+    q, k, v, g, beta = (a.copy() for a in draw(rng, s, h=heads))
+    hard = adversarial(rng, s, 1, DK, g=LOWER_BOUND, keys="alike_positive")
+    for a, b in zip((q, k, v, g, beta), hard):
+        a[:, first] = b[:, 0]
+    g[:, second] = 0.0
+    state = rng.normal(size=(1, heads, DK, DV)).astype(np.float32)
+    run = lambda *seq: scan(padded([seq], (s, ), s), state, (s, ))
+    o, last = run(q, k, v, g, beta)
+    want, want_last = token_by_token((q, k, v, g, beta), state[0])
+    for mine, partner in ((first, second), (second, first)):
+        near(o[0, :, mine], want[:, mine], 1e-4)
+        near(last[0, mine], want_last[mine], 1e-4)
+        other = [a.copy() for a in (q, k, v)]
+        for a, b, scale in zip(other, draw(rng, s, h=1), (1.0, 1.0, 1e3)):  # unit keys, values of 1e3
+            a[:, partner] = scale * b[:, 0]
+        o_other, last_other = run(*other, g, beta)
+        near(o_other[0, :, mine], o[0, :, mine])
+        near(last_other[0, mine], last[0, mine])
+
+
 def test_the_layouts_are_ssds_and_the_chunk_is_64():
-    assert (CHUNK, kda.SUB, LOWER_BOUND) == (64, 16, -5.0)
+    assert (CHUNK, kda.SUB, LOWER_BOUND, kda.SCAN_HEADS) == (64, 16, -5.0, 8)
     assert kda.lay_on_chunk_edges.__module__.endswith("gated_delta")
     assert kda._lay_window.__module__.endswith("ssd")

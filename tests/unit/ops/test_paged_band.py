@@ -16,7 +16,7 @@ from jax import lax
 
 from deepspeed_tpu.ops.attention import paged
 
-from .compiled import compiled
+from .compiled import compiled, dense_fallback
 from .test_dsa import flat_of as selected_flat_of
 from .test_dsa_selection import selected_case
 from .test_paged_slots import BS, drawn_case
@@ -89,13 +89,20 @@ def test_the_band_is_the_tiles_that_see_a_key_of_the_step(step, window, group):
     band = jax.jit(jax.vmap(lambda *a: paged.tile_band(*a, tile=tile, group=group, keys=keys,
                                                        window=window)))
     lo, hi = (np.asarray(x) for x in band(*columns))
+    # ``tile_first_step`` of every tile asked about below, as one program (a call a tile is a
+    # dozen ops dispatched one by one, some 10,000 tiles a case)
+    asked = sorted({(c[1] + i * tile, c[3], c[6]) for c, lo_, hi_ in zip(steps, lo, hi) for i in range(lo_, hi_)}
+                   | {(first_row + i * tile, start, base) for first_row, start, base, tiles, _ in firsts
+                      for i in range(tiles)})
+    begins = jax.jit(jax.vmap(lambda *a: jnp.asarray(paged.tile_first_step(
+        *a, group=group, keys=keys, window=window), jnp.int32)))
+    first_step = dict(zip(asked, np.asarray(begins(*(jnp.asarray(col, jnp.int32) for col in zip(*asked)))).tolist()))
     skipped = exact = 0
     for (k0, first_row, _, start, ntok, length, base, b, tiles, sees), lo_, hi_ in zip(steps, lo, hi):
         assert lo_ >= 0 and hi_ <= tiles
         got = list(range(lo_, hi_))
         if window is not None:  # no tile is visited before the step its state begins at
-            assert all(b >= int(paged.tile_first_step(first_row + i * tile, start, base, group=group,
-                                                      keys=keys, window=window)) for i in got)
+            assert all(b >= first_step[first_row + i * tile, start, base] for i in got)
         if length == start + ntok and k0 < length:  # a live step of a call as the engine makes it
             assert got == sees, (step, window, group, (k0, first_row, start, ntok, length), got, sees)
             skipped += tiles - len(sees)
@@ -106,9 +113,8 @@ def test_the_band_is_the_tiles_that_see_a_key_of_the_step(step, window, group):
     for first_row, start, base, tiles, first in firsts:
         for i in range(tiles):
             if first[i] >= 0:  # a tile that sees nothing at all never starts
-                got = int(paged.tile_first_step(first_row + i * tile, start, base, group=group,
-                                                keys=keys, window=window))
-                assert got == first[i], (step, window, group, first_row, start, base, i)
+                assert first_step[first_row + i * tile, start, base] == first[i], \
+                    (step, window, group, first_row, start, base, i)
 
 
 def test_a_latent_decode_rows_one_tile_sees_every_live_step():
@@ -197,8 +203,8 @@ def kernel_and_parent(monkeypatch, name, layout):
                  value_dim=how.get("dv"))
     q, kpool, vpool, tables, lengths, start_pos, n_tokens = drawn
     scale = how.get("scale") or 1.0 / np.sqrt(q.shape[-1])
-    want = np.asarray(paged._dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
-                                            scale, how.get("window"), None, how.get("dv")))
+    want = np.asarray(dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
+                                     scale, how.get("window"), None, how.get("dv")))
     monkeypatch.setattr(_pallas, "INTERPRET", True)
 
     def call():
@@ -251,8 +257,8 @@ def test_the_band_under_a_selection_walks_from_the_tables_first_slot(monkeypatch
     visited, live_pairs = worked(args, dict(window=window, selected=True), t, heads, shape[3],
                                  shape[4] * 16)
     assert 0 < visited < live_pairs
-    want = paged._dense_fallback(q, *args, facts["softmax_scale"], window, None,
-                                 facts["value_dim"], selection=chosen)
+    want = dense_fallback(q, *args, facts["softmax_scale"], window, None, facts["value_dim"],
+                          selection=chosen)
     (qf, chosenf), live, at = selected_flat_of(args[-1], t, q, chosen)
     monkeypatch.setattr(_pallas, "INTERPRET", True)
     got = compiled(paged.paged_attention, selection=chosen, **facts)(q, *args)
@@ -283,7 +289,7 @@ def test_a_tiles_state_begins_at_its_own_first_step(monkeypatch, layout):
     late = [int(paged.tile_first_step(r0, 200, base, group=2, keys=64, window=window))
             for r0 in range(0, 256, 32)]
     assert late[0] == 0 and late[-1] > 0, late  # the second sequence's last tile begins past step 0
-    want = np.asarray(paged._dense_fallback(*case, 1.0 / np.sqrt(q.shape[-1]), window))
+    want = np.asarray(dense_fallback(*case, 1.0 / np.sqrt(q.shape[-1]), window))
     monkeypatch.setattr(_pallas, "INTERPRET", True)
 
     def call():

@@ -14,7 +14,7 @@ import pytest
 
 from deepspeed_tpu.ops.attention import paged
 
-from .compiled import compiled
+from .compiled import compiled, dense_fallback
 
 BS = 16  # keys a block; a step of four slots holds 64
 
@@ -37,8 +37,7 @@ def assert_kernel_is_the_fallback(case, window=None, slopes=None, dv=None, scale
                                   call=compiled):
     q, kpool, vpool, tables, lengths, start_pos, n_tokens = case
     scale = scale or 1.0 / np.sqrt(q.shape[-1])
-    ref = paged._dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale,
-                                window, slopes, dv)
+    ref = dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale, window, slopes, dv)
     got = call(paged.paged_attention, block_size=BS, window=window, alibi_slopes=slopes,
                softmax_scale=float(scale), value_dim=dv)(q, kpool, vpool, tables, lengths, start_pos, n_tokens)
     assert got.shape == q.shape[:3] + (dv or q.shape[-1], ) and got.dtype == q.dtype

@@ -10,7 +10,7 @@ import pytest
 
 from deepspeed_tpu.ops.attention import paged
 
-from .compiled import compiled
+from .compiled import compiled, dense_fallback
 from .test_paged_slots import BS, drawn_case
 
 
@@ -90,6 +90,6 @@ def test_the_flat_forms_copies_are_waited_for_and_in_the_grids_order(monkeypatch
     drawn = drawn_case([(40, 1), (90, 30), (0, 0), (17, 1), (33, 2)], 32, 4, 2, 6)
     flat, (row, col) = flat_of(drawn, 3)
     got = paged.paged_attention_flat(flat, *drawn[1:], chunk=32, block_size=BS)
-    ref = paged._dense_fallback(*drawn, 1.0 / np.sqrt(32), None)
+    ref = dense_fallback(*drawn, 1.0 / np.sqrt(32), None)
     np.testing.assert_allclose(np.asarray(got)[:len(row)], np.asarray(ref)[row, col], atol=2e-5)
     assert not interpreter.races.races_found

@@ -11,6 +11,7 @@ import pytest
 
 from deepspeed_tpu.ops.attention import paged
 
+from .compiled import compiled, dense_fallback
 from .test_paged_slots import BS, drawn_case
 from .test_paged_slots_flat import flat_of
 
@@ -43,26 +44,25 @@ def test_a_windowed_walk_is_the_masked_reference_and_fetches_nothing_behind_it(m
     case = drawn_case(ROWS[layout], t, 4, 2, 8)
     q, kpool, vpool, tables, lengths, start_pos, n_tokens = case
     scale = 1.0 / np.sqrt(q.shape[-1])
-    want = np.asarray(paged._dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens,
-                                            scale, window))
+    want = np.asarray(dense_fallback(q, kpool, vpool, tables, lengths, start_pos, n_tokens, scale, window))
     dirty, behind = poisoned(case, window)
     assert behind > 0
     monkeypatch.setattr(_pallas, "INTERPRET", True)
     valid = np.asarray(jnp.arange(t)[None, :] < n_tokens[:, None])
     if layout == "flat":
         flat, (row, col) = flat_of(dirty, spare=3)
-        got = np.asarray(paged.paged_attention_flat(flat, *dirty[1:], chunk=t, block_size=BS,
-                                                    window=window))
+        got = np.asarray(compiled(paged.paged_attention_flat, chunk=t, block_size=BS, window=window)(
+            flat, *dirty[1:]))
         np.testing.assert_allclose(got[:len(row)], want[row, col], atol=2e-5)
     else:
-        got = np.asarray(paged.paged_attention(*dirty, block_size=BS, window=window))
+        got = np.asarray(compiled(paged.paged_attention, block_size=BS, window=window)(*dirty))
         np.testing.assert_allclose(got[valid], want[valid], atol=2e-5)
     assert np.isfinite(got).all()
     # the same walk from the table's first slot meets the poison: the test can tell
     monkeypatch.setattr(paged, "_fetch_plan", lambda *a: _whole_walk(*a))
     if layout == "decode":
-        assert not np.isfinite(np.asarray(paged.paged_attention(*dirty, block_size=BS,
-                                                                window=window))).all()
+        assert not np.isfinite(np.asarray(
+            compiled(paged.paged_attention, block_size=BS, window=window)(*dirty))).all()
 
 
 _PLAN = paged._fetch_plan

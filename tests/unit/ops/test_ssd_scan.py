@@ -12,6 +12,7 @@ a pass of chunks (ISSUE 55, ``ssd_chunks``): the rows of one token served by the
 update kernel, the rest walked in windows of ``WINDOW`` rows whose layout is
 sized by the pass's tokens, in as many trips as the rows need."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -21,6 +22,8 @@ from deepspeed_tpu.ops import _pallas
 from deepspeed_tpu.ops.linear_attention import ssd
 from deepspeed_tpu.ops.linear_attention.ssd import (CHUNK, WINDOW, scan_chunks, ssd_chunks, ssd_scan,
                                                     ssd_update, walk_trips)
+
+from .compiled import entry
 
 H, P, NS = 16, 8, 16  # 16 heads: two grid steps of the scan kernel's eight
 TOL = 1e-5  # float32 throughout, of the largest value (``near``): a chunk's products against 64 steps
@@ -51,10 +54,13 @@ def draw(rng, s):
     return x, dt, b, c
 
 
+_rule = jax.jit(selective_scan)  # one program a length, not an op at a time
+
+
 def token_by_token(seq, state):
     x, dt, b, c = (jnp.asarray(a) for a in seq)
-    y, last = selective_scan(x, dt, jnp.asarray(A), b, c, jnp.asarray(D),
-                             None if state is None else jnp.asarray(state))
+    y, last = _rule(x, dt, jnp.asarray(A), b, c, jnp.asarray(D),
+                    None if state is None else jnp.asarray(state))
     return np.asarray(y), np.asarray(last)
 
 
@@ -83,7 +89,7 @@ def in_order(n):
 def scan(arrays, state, counts, row=None, col=None, at=None, begins=None):
     x, dt, b, c = arrays
     own = in_order(len(counts))
-    return ssd_scan(x, dt, jnp.asarray(A), b, c, jnp.asarray(D), jnp.asarray(state),
+    return entry(ssd_scan)(x, dt, jnp.asarray(A), b, c, jnp.asarray(D), jnp.asarray(state),
                     own[0] if at is None else jnp.asarray(at, jnp.int32),
                     own[1] if begins is None else jnp.asarray(begins),
                     jnp.asarray(counts, jnp.int32), row, col)
@@ -135,8 +141,8 @@ def test_the_one_token_update_is_a_scan_of_one(form):
     seqs = [draw(rng, 1) for _ in range(n)]
     state = rng.normal(size=(n, H, P, NS)).astype(np.float32)
     x, dt, b, c = padded(seqs, (1, ) * n, 1)
-    y, last = ssd_update(x[:, 0], dt[:, 0], jnp.asarray(A), b[:, 0], c[:, 0], jnp.asarray(D),
-                         jnp.asarray(state), *in_order(n))
+    y, last = entry(ssd_update)(x[:, 0], dt[:, 0], jnp.asarray(A), b[:, 0], c[:, 0], jnp.asarray(D),
+                                jnp.asarray(state), *in_order(n))
     scanned, scanned_last = scan((x, dt, b, c), state, (1, ) * n)
     near(np.asarray(y), np.asarray(scanned[:, 0]))
     near(np.asarray(last), np.asarray(scanned_last))
@@ -176,7 +182,7 @@ def test_the_state_is_read_and_written_in_the_rows_slots_alone(form, kernel, cas
     if kernel == "update":
         seqs = [draw(rng, 1) for _ in counts]
         x, dt, b, c = (a[:, 0] for a in padded(seqs, (1, ) * len(counts), 1))
-        update = lambda state, at, begins: ssd_update(
+        update = lambda state, at, begins: entry(ssd_update)(
             x, dt, jnp.asarray(A), b, c, jnp.asarray(D), jnp.asarray(state), at, begins)
         y, after = update(leaf, jnp.asarray(at, jnp.int32), jnp.asarray(begins))
         want, want_rows = update(rows, *in_order(len(counts)))
@@ -235,7 +241,7 @@ def test_a_pass_of_chunks_is_the_recurrence_whatever_its_rows_hold(form, layout,
     seqs = [draw(rng, max(c, 1)) for c in counts]
     args = (jnp.asarray(A), jnp.asarray(D), jnp.asarray(leaf), jnp.asarray(at, jnp.int32),
             jnp.asarray(begins), jnp.int32(slots - 1), jnp.asarray(counts, jnp.int32))
-    chunks = lambda x, dt, b, c, *where: ssd_chunks(x, dt, args[0], b, c, *args[1:], *where)
+    chunks = lambda x, dt, b, c, *where: entry(ssd_chunks)(x, dt, args[0], b, c, *args[1:], *where)
     if layout == "padded":
         y, after = chunks(*padded(seqs, counts, max(max(counts), 2)))
         mine = lambda i, c: np.asarray(y[i, :c])
