@@ -5,7 +5,8 @@ model's policy by HF ``model_type`` and assembles InferenceEngineV2.  Supported:
 llama, mistral (sliding window), mixtral (MoE), olmoe (64-expert top-8 MoE with
 QK-norm; serving only, training not supported), afmoe (windowed and full attention
 layers mixed, a sigmoid-routed MoE; serving only), bailing_hybrid (Kimi Delta Attention
-and latent attention layers mixed; serving only), opt, falcon, phi, qwen2, gptj.
+and latent attention layers mixed; serving only), nemotron_h (layers that are a Mamba-2
+mixer, ungated experts or NoPE attention alone; serving only), opt, falcon, phi, qwen2, gptj.
 (BLOOM serves through the v1 engine — ALiBi needs the biased dense attention,
 models/bloom.py.)
 """
@@ -17,13 +18,15 @@ from .engine_v2 import InferenceEngineV2
 
 
 def _registry():
-    from ...models import afmoe, bailing_hybrid, falcon, gptj, llama, mistral, mixtral, olmoe, opt, phi, qwen
+    from ...models import (afmoe, bailing_hybrid, falcon, gptj, llama, mistral, mixtral, nemotron_h, olmoe, opt,
+                           phi, qwen)
     return {
         "afmoe": (afmoe, afmoe.config_from_hf),
         "bailing_hybrid": (bailing_hybrid, bailing_hybrid.config_from_hf),
         "llama": (llama, llama.config_from_hf),
         "mistral": (mistral, mistral.config_from_hf),
         "mixtral": (mixtral, None),  # config built field-by-field below
+        "nemotron_h": (nemotron_h, nemotron_h.config_from_hf),
         "olmoe": (olmoe, olmoe.config_from_hf),
         "opt": (opt, opt.config_from_hf),
         "falcon": (falcon, falcon.config_from_hf),

@@ -182,6 +182,9 @@ class ServeCounters:
     pass that held more picks than a window's rows (ISSUE 51); each ran the
     layer's ``moe_expert_rows`` rows once more, so ``moe_held_picks <=
     moe_expert_rows + rows a window x moe_overflow_windows``
+    ``moe_experts_hit``  held experts that a layer-pass's live picks named, summed
+    over layers and passes (ISSUE 62; a family that tallies it): the expert
+    matrices the grouped matmuls must have read, whatever the routing's skew
     """
 
     FIELDS = ("host_syncs", "dispatches", "uploads", "upload_ints", "compiles",
@@ -197,7 +200,7 @@ class ServeCounters:
     SELECTED_FIELDS = ("dsa_causal_keys", "dsa_selected_keys", "dsa_scored_keys",
                        "dsa_attended_keys")
     # the same for a family that tallies its picks on the device (``tallied``)
-    TALLIED_FIELDS = ("moe_identity_picks", "moe_held_picks", "moe_overflow_windows")
+    TALLIED_FIELDS = ("moe_identity_picks", "moe_held_picks", "moe_overflow_windows", "moe_experts_hit")
     # the same for a family whose scan walks windows of its rows of several tokens (``walk_trips``)
     WALK_FIELDS = ("scan_overflow_windows", )
     # the same for a family whose attention layers differ in their window (``windowed``)

@@ -1,5 +1,5 @@
 from . import (afmoe, bailing_hybrid, bert, bloom, deepseek_v2, falcon, glm_moe_dsa, gpt2, gptj, granite_moe_hybrid, lfm2, llama,
-               longcat_flash, mistral, mixtral, olmoe, opt, phi, qwen, transformer)
+               longcat_flash, mistral, mixtral, nemotron_h, olmoe, opt, phi, qwen, transformer)
 from .afmoe import AfmoeConfig
 from .bailing_hybrid import BailingHybridConfig
 from .bert import BertConfig
@@ -15,6 +15,7 @@ from .llama import LlamaConfig
 from .longcat_flash import LongcatFlashConfig
 from .mistral import MistralConfig
 from .mixtral import MixtralConfig
+from .nemotron_h import NemotronHConfig
 from .olmoe import OlmoeConfig
 from .opt import OPTConfig
 from .phi import PhiConfig

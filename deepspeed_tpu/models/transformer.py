@@ -439,6 +439,7 @@ def flat_chunk_indices(n_tokens, start_pos, block_tables, num_blocks: int,
 
 STATE = "state"  # the key of a family's cache tree that holds its fixed per-sequence state
 STATE_MIXER = "mixer"  # a layer whose parameters hold this key has no attention: ``mix`` runs it
+PART_ALONE = "alone"  # a layer whose parameters hold this key touches neither cache: ``alone`` runs it
 # the key of a family's cache tree that holds running tallies its forward adds to on the
 # device (int32 ``[k]``): no pool leaf (the family takes it out before ``paged_forward``,
 # the engine moves no block of it) and read by the host once a wave
@@ -523,7 +524,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
                   window: Optional[int] = None, alibi_slopes=None,
                   softmax_scale: Optional[float] = None, value_dim: Optional[int] = None,
                   mix: Optional[Callable] = None, selection: Optional[Selection] = None,
-                  hand_on: bool = False, by_reference=None):
+                  hand_on: bool = False, by_reference=None, alone: Optional[Callable] = None):
     """The one ragged chunked forward over the paged KV pool (FastGen
     model-forward analog, inference/v2/model_implementations + blocked flash):
     every family's ``forward_paged`` is its own arithmetic as four callables
@@ -657,7 +658,7 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     ``recurrent``: 2 MB, inside ``kda_update`` / ``kda_scan``).
 
     The pool's row is counted over the attention layers alone, the state's over
-    the rest, in whatever order the two kinds lie within a period or across
+    the mixers (a layer that touches neither, below, counts in neither), in whatever order the two kinds lie within a period or across
     stacks: a pool ``[L_attention, ...]`` of ANY leaves (K and V; or ONE latent
     leaf with ``value_dim``, as Ling-3.0's ``bailing_hybrid``: one layer in six
     attends a latent, five keep a matrix by reference and a shift by value) and
@@ -666,6 +667,22 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     ``window`` are the attention layers' alone and a mixer never sees them
     (``tests/unit/inference/test_latent_pool_beside_state.py`` holds this with a
     toy family of its own).
+
+    **Layers that touch neither cache** (``PART_ALONE`` among ``lp``'s keys:
+    Nemotron-H's expert layers, where a layer is ONE part alone, a mixer OR a
+    feed-forward part, and not a mixer then an FFN).  ``alone(lp, x, live) -> x``
+    is the whole layer (its norm, its part, its residual, under the family's own
+    scopes).  It remembers nothing of a sequence: it takes NO STATE ROW AND NO
+    POOL ROW, reads no slot, writes no block, and neither kind's row count moves
+    past it, so a stack of 6 mixers, 6 such layers and 2 attention layers carries
+    state leaves ``[6, slots + 1, ...]`` and a pool ``[2, NB, ...]`` (run through
+    ``mix`` it would hold a state row a layer that nothing reads).  It lies in a
+    period beside the other two kinds in any order (``M E M E M * E``), padded,
+    compacted and in a burst's body alike, or makes a stack of its own
+    (``tests/unit/inference/test_part_alone_layer.py`` holds this with a toy
+    family of its own).  Under ``hand_on`` it is ``alone(lp, x, live, handed) ->
+    (x, handed)`` and stands in the period's chain like an attention layer's
+    ``finish``.
 
     **A hand-on inside a period** (``hand_on``: LongCat-Flash's shortcut expert
     layer, computed from the first sublayer's normed stream and added at the end
@@ -679,7 +696,8 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     hands on is handed to no layer: it leaves the scan, stacked a period
     ``[depth, ...]``, and this function returns ``(logits, cache, [that, a
     stack of ``layers``])``: a family's per-period by-product (its tallies),
-    or None where it has none.  A mixer layer is passed over.  Without
+    or None where it has none.  A mixer layer is passed over; a layer alone
+    (above) takes and hands on like a ``finish``.  Without
     ``hand_on`` (every other family) ``finish`` keeps its five arguments and
     one result, and nothing of this is traced.
 
@@ -855,24 +873,33 @@ def paged_forward(layers, tokens, n_tokens, start_pos, block_tables, kv_cache, *
     places = SeqPlaces(n_tokens, row, col)
     filtered = lambda z, kept, w, bias=None: sequence_filter(z, kept, w, bias, *places)
     carry = (x, *flat_pools, *(leaf.reshape((-1, ) + leaf.shape[2:]) for leaf in state_leaves))
-    done = {False: 0, True: 0}  # attention / mixer layers behind the stack being scanned
+    done = {False: 0, True: 0}  # attention / mixer layers behind the stack being scanned (None: neither)
     left_over = []  # with ``hand_on``: what each stack's periods handed to no layer
     for stack, here in zip(stacks, windows):
         period = stack if isinstance(stack, tuple) else (stack, )
         here = here if isinstance(here, tuple) else (here, ) * len(period)  # a layer's own, by its place
-        mixes = [STATE_MIXER in lp for lp in period]  # by what a layer's parameters hold
+        # by what a layer's parameters hold: a mixer, attention, or (None) a part alone
+        mixes = [None if PART_ALONE in lp else STATE_MIXER in lp for lp in period]
         depth = jax.tree_util.tree_leaves(period[0])[0].shape[0]
         # each kind's index of a period's first layer of that kind: the pool's and the state's row
         # (no step where it is 1: a given step compiles every older family's program anew)
         firsts = {kind: jnp.arange(done[kind], done[kind] + depth * mixes.count(kind),
                                    *([mixes.count(kind)] if mixes.count(kind) > 1 else []),
                                    dtype=jnp.int32)
-                  for kind in set(mixes)}
+                  for kind in set(mixes) - {None}}
 
         def body(carry, inp, mixes=mixes, here=here):
             (x, *pools), (lps, first) = carry, inp
             handed = None  # a period begins with nothing handed, and what it ends with leaves the scan
             for j, (lp, is_mix) in enumerate(zip(lps, mixes)):
+                if is_mix is None:  # no row of either cache: nothing but the stream goes in or out
+                    if alone is None:
+                        raise ValueError(f"a layer holds {PART_ALONE!r} and the family gave no alone")
+                    if hand_on:
+                        x, handed = alone(lp, x, live, handed)
+                    else:
+                        x = alone(lp, x, live)
+                    continue
                 behind = mixes[:j].count(is_mix)  # layers of its kind before it in the period
                 l = first[is_mix] + behind if behind else first[is_mix]
                 if is_mix:

@@ -29,6 +29,19 @@ again (a ``while_loop`` whose body is traced once).  The rows' outputs reach
 their tokens through :func:`combine_rows`, which builds nothing ``[slots,
 top_k, D]``.
 
+**An expert is gated or not** (:func:`expert_ffn` and the shared expert, told by what the leaves
+hold).  With a ``w_gate`` leaf an expert is SwiGLU, ``W_down (silu(W_gate x) *
+W_up x)``, three grouped matmuls; without one it is UNGATED, ``W_down relu(W_up
+x)^2`` (Nemotron-H's ``mlp_hidden_act: relu2``), two grouped matmuls with the
+squared rectifier between them: for the routed experts all held, for a share's
+compacted window of held picks, and for the shared expert alike.  No argument
+and no engine knob says which: a parameter tree cannot hold the wrong form.
+An ungated expert's ``w_up`` is ``[F, D]``, as its ``w_down`` is (a checkpoint's
+``up_proj.weight``), and is multiplied transposed: at Nemotron-H's width of 1,856
+(14.5 lane tiles) the chip lays an array ``[.., D, 1856]`` out with ``D`` minor,
+and a kernel that takes it row-major has the WHOLE expert stack copied for it, 3.8
+GB a step program (compile, PR 62); ``[.., 1856, D]`` lies as the kernel reads it.
+
 The grouped matmul is the Pallas ``gmm`` of ``jax.experimental.pallas.ops.tpu.
 megablox`` on TPU and ``jax.lax.ragged_dot``, the same mathematics in XLA,
 elsewhere.  On the v5e at OLMoE's ``[rows, 64 groups, 2048 x 1024]`` the three
@@ -39,7 +52,7 @@ where reading the 64 experts' weights once is 0.98 ms (PERF.md, PR 27).
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +65,9 @@ from ..ops import _pallas
 ROW_TILE = 128          # rows a gmm program step multiplies: the MXU's height
 HEADROOM = 1.25         # a share's window of held picks over what uniform routing sends it
 K_TILE, N_TILE = 2048, 1024  # an expert's whole 2048 x 1024 matrix in one step
+K_TILE_T, N_TILE_T = 1024, 2048  # a transposed matrix's: its ``[N, K]`` block, N whole (:func:`_even_tile`)
 COMBINE_WIDTH = 2048    # columns a step of the combine kernel holds: 1.5 MB of blocks
+TALLIES = ("identity", "held", "experts_hit")  # what :func:`sparse_moe_ffn` can count beside its sum
 
 
 def expert_rows(slots: int, top_k: int, held: int = 1, routed: int = 1) -> int:
@@ -83,17 +98,28 @@ def window_trips(held_picks, rows: int):
     return -(-held_picks // rows)
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
+def _even_tile(dim: int, most: int) -> int:
+    """The whole dimension where it is at most ``most``, else its largest divisor in whole
+    lane tiles under ``most`` (2,688 under 1,024: 896, three even steps), else ``most``."""
+    if dim <= most:
+        return dim
+    return next((t for t in range(most - most % 128, 0, -128) if dim % t == 0), most)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, transposed: bool = False):
     """``lhs[rows of group g] @ rhs[g]``: lhs [M, K] sorted by group, rhs
     [G, K, N], group_sizes [G] -> [M, N].  Rows past the last group come back
-    as whatever the kernel left there: the caller must not use them."""
+    as whatever the kernel left there: the caller must not use them.
+    ``transposed``: rhs is ``[G, N, K]`` and the product ``lhs @ rhs[g]^T`` (an
+    ungated expert's ``w_up``); its tiles divide the matrix evenly."""
     if not _pallas.use_pallas():
-        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+        return jax.lax.ragged_dot(lhs, jnp.swapaxes(rhs, 1, 2) if transposed else rhs, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
-    (m, k), n = lhs.shape, rhs.shape[-1]
-    tiling = (min(m, ROW_TILE), min(k, K_TILE), min(n, N_TILE))
+    (m, k), n = lhs.shape, rhs.shape[1 if transposed else -1]
+    tiling = ((min(m, ROW_TILE), _even_tile(k, K_TILE_T), _even_tile(n, N_TILE_T)) if transposed
+              else (min(m, ROW_TILE), min(k, K_TILE), min(n, N_TILE)))
     # positionally: the custom-vjp wrapper takes its static arguments by place
-    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None, False, _pallas.INTERPRET)
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None, transposed, _pallas.INTERPRET)
 
 
 def combine_rows(ys, token, weight, slots: int):
@@ -182,6 +208,20 @@ def _combine_tiles(ys, token, weight, *, slots, interpret):
     return out[:slots]
 
 
+def expert_ffn(xs, stacked, group_sizes):
+    """The experts' FFN over rows ``xs`` sorted by group: ``stacked`` holds ``w_up``,
+    ``w_down`` and, for gated experts, ``w_gate``, each ``[groups, ...]``.  Gated
+    (``w_gate``, ``w_up`` ``[.., D, F]``): ``down(silu(gate) * up)``, three grouped
+    matmuls.  Ungated (no ``w_gate`` leaf; ``w_up`` ``[.., F, D]``, multiplied
+    transposed): ``down(relu(up)^2)``, two."""
+    if "w_gate" in stacked:
+        gate = grouped_matmul(xs, stacked["w_gate"], group_sizes)
+        up = grouped_matmul(xs, stacked["w_up"], group_sizes)
+        return grouped_matmul(jax.nn.silu(gate) * up, stacked["w_down"], group_sizes)
+    up = grouped_matmul(xs, stacked["w_up"], group_sizes, transposed=True)
+    return grouped_matmul(jnp.square(jax.nn.relu(up)), stacked["w_down"], group_sizes)
+
+
 def _held_picks_ffn(stacked, x, weights, experts, held, layer, num_experts: int, rows: int):
     """The held picks' part of a share's expert FFN, ``rows`` of them a trip.
 
@@ -200,7 +240,7 @@ def _held_picks_ffn(stacked, x, weights, experts, held, layer, num_experts: int,
     prompt or a popular expert sends more picks here; a later trip's rows name
     only the experts the earlier ones did not finish, so no expert's weights
     are read twice but the one a window's edge cuts.  -> out [S, D] float32."""
-    (slots, top_k), groups = experts.shape, stacked["w_gate"].shape[0]
+    (slots, top_k), groups = experts.shape, stacked["w_up"].shape[0]
     picks = slots * top_k
     held = held.reshape(picks)
     listed = jax.lax.sort((jnp.where(held, experts.reshape(picks), num_experts),
@@ -220,10 +260,7 @@ def _held_picks_ffn(stacked, x, weights, experts, held, layer, num_experts: int,
                         dtype=jnp.int32)
         group_sizes = jax.lax.dynamic_update_slice(jnp.zeros((groups,), jnp.int32), sizes,
                                                    (layer * num_experts,))
-        xs = x[token]
-        gate = grouped_matmul(xs, stacked["w_gate"], group_sizes)
-        up = grouped_matmul(xs, stacked["w_up"], group_sizes)
-        ys = grouped_matmul(jax.nn.silu(gate) * up, stacked["w_down"], group_sizes)
+        ys = expert_ffn(x[token], stacked, group_sizes)
         # rows past the last group are whatever the kernel left: zeroed; then all in token
         # order (the dead ones last), each with its weight, for the combine
         token, order, weight = jax.lax.sort(
@@ -301,11 +338,13 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
                    live: Optional[jax.Array] = None, layer: Optional[jax.Array] = None,
                    n_group: int = 1, topk_group: int = 1, scaling: float = 1.0,
                    scoring: str = "softmax", norm_eps: float = 0.0,
-                   identity_experts: Optional[int] = None):
-    """x [S, D] -> [S, D]: SwiGLU experts under top-k routing.
+                   identity_experts: Optional[int] = None, tally: Optional[Tuple[str, ...]] = None):
+    """x [S, D] -> [S, D]: experts under top-k routing.
 
     ``moe_params``: ``{"gate": {"wg": [D, E]}, "experts": {"w_gate": [E, D, F],
-    "w_up": [E, D, F], "w_down": [E, F, D]}}``.  ``live`` [S] bool marks the
+    "w_up": [E, D, F], "w_down": [E, F, D]}}``; experts (and a shared expert)
+    whose leaves hold no ``w_gate`` are ungated and their ``w_up`` is ``[E, F, D]``
+    (:func:`expert_ffn`).  ``live`` [S] bool marks the
     slots that hold a token; a dead slot gets zeros.  Under tensor parallelism
     the experts are sharded on F and the result is a partial sum: the caller
     psums it, as it does a dense row-parallel FFN's.
@@ -345,11 +384,23 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     ``tally`` int32 ``[2]``, the live slots' picks on identity experts and on
     held experts (which kind a pick is only the device knows; a family sums
     them over its layers for ``ServeCounters.moe_identity_picks`` /
-    ``moe_held_picks``).  With ``identity_experts`` None (every other family)
-    nothing of this is traced and the result is ``out`` alone.
+    ``moe_held_picks``).  With neither ``identity_experts`` nor ``tally`` (every
+    family but the two named here) nothing of this is traced and the result is
+    ``out`` alone.
 
-    ``moe_params["shared"]`` (``{"w_gate": [D, Fs], "w_up", "w_down"}``), where
-    a family has it, is the expert every token takes: a dense SwiGLU added to
+    **The tally** (``tally``: names out of :data:`TALLIES`; left out, ``("identity",
+    "held")`` with identity experts and nothing without).  The call returns ``(out,
+    counts)``, ``counts`` int32 ``[len(tally)]`` in the order named, over the live
+    slots: ``identity`` and ``held`` as above; ``experts_hit`` the held experts that
+    some pick names, each once: the expert matrices the grouped matmuls of this call
+    must read, whatever the routing's skew (``ServeCounters.moe_experts_hit``).  A
+    share that has no identity experts and counts its picks passes ``tally`` alone
+    (Nemotron-H: ``("held", "experts_hit")``).  The counts are part of the step
+    program whether or not a run is traced: two sums, and for ``experts_hit`` one
+    comparison of ``[slots, top_k]`` picks with the held experts' numbers.
+
+    ``moe_params["shared"]`` (``{"w_gate": [D, Fs], "w_up", "w_down"}``; ungated
+    without ``w_gate``), where a family has it, is the expert every token takes: a dense FFN added to
     the routed part.  ``n_group``, ``topk_group``, ``scaling``, ``scoring`` and
     ``norm_eps`` are :func:`route`'s, and so is ``moe_params["gate"]["bias"]``
     (``[E]``), the selection bias of a family that stores one.
@@ -358,7 +409,7 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     ex = moe_params["experts"]
     if layer is None:
         ex, layer = jax.tree_util.tree_map(lambda w: w[None], ex), 0
-    num_layers, num_experts = ex["w_gate"].shape[:2]
+    num_layers, num_experts = ex["w_up"].shape[:2]
     groups = num_layers * num_experts
     stacked = {name: w.reshape((groups,) + w.shape[2:]).astype(x.dtype) for name, w in ex.items()}
     slots = x.shape[0]
@@ -385,10 +436,7 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
             flat = jnp.full((rows,), groups, jnp.int32).at[:picks].set(group.reshape(picks))
             order = jnp.argsort(flat)  # stable: rows sorted by expert, dead rows last
             group_sizes = jnp.zeros((groups,), jnp.int32).at[flat].add(1, mode="drop")
-            xs = x[jnp.minimum(order // top_k, slots - 1)]
-            gate = grouped_matmul(xs, stacked["w_gate"], group_sizes)
-            up = grouped_matmul(xs, stacked["w_up"], group_sizes)
-            ys = grouped_matmul(jax.nn.silu(gate) * up, stacked["w_down"], group_sizes)
+            ys = expert_ffn(x[jnp.minimum(order // top_k, slots - 1)], stacked, group_sizes)
             # rows past the last group are no expert's: whatever sits there is dropped
             ys = jnp.where((flat[order] < groups)[:, None], ys, 0)
             picked = ys[jnp.argsort(order)[:picks]].reshape(slots, top_k, -1)  # back in token order
@@ -396,7 +444,10 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
     if "shared" in moe_params:
         shared = {name: w.astype(x.dtype) for name, w in moe_params["shared"].items()}
         with jax.named_scope("moe_shared_expert"):
-            hidden = jax.nn.silu(x @ shared["w_gate"]) * (x @ shared["w_up"])
+            if "w_gate" in shared:
+                hidden = jax.nn.silu(x @ shared["w_gate"]) * (x @ shared["w_up"])
+            else:  # ungated, as the routed experts beside it: ``w_up`` ``[F, D]``
+                hidden = jnp.square(jax.nn.relu(x @ shared["w_up"].T))
             added = (hidden @ shared["w_down"]).astype(jnp.float32)
             if "shared_gate" in moe_params:
                 with jax.named_scope("moe_shared_gate"):
@@ -404,15 +455,21 @@ def sparse_moe_ffn(moe_params, x, top_k: int, renormalise: bool,
                         x, moe_params["shared_gate"].astype(x.dtype),
                         preferred_element_type=jnp.float32))
             out = out + added
-    if identity_experts is not None:
-        real = moe_params["gate"]["wg"].shape[-1] - identity_experts
+    if tally is None and identity_experts is not None:
+        tally = ("identity", "held")
+    if tally:
         with jax.named_scope("moe_identity"):
             alive = jnp.ones((slots, 1), bool) if live is None else live[:, None]
-            identity = (experts >= real) & alive
-            # an identity expert returns its input: its picks' weights times x, on this chip
-            out = out + jnp.sum(jnp.where(identity, weights, 0.0), axis=-1,
-                                keepdims=True) * x.astype(jnp.float32)
-            tally = jnp.stack([jnp.sum(identity, dtype=jnp.int32),
-                               jnp.sum((experts < num_experts) & alive, dtype=jnp.int32)])
-        return out.astype(x.dtype), tally
+            counts = {}
+            if identity_experts is not None:
+                identity = (experts >= moe_params["gate"]["wg"].shape[-1] - identity_experts) & alive
+                # an identity expert returns its input: its picks' weights times x, on this chip
+                out = out + jnp.sum(jnp.where(identity, weights, 0.0), axis=-1,
+                                    keepdims=True) * x.astype(jnp.float32)
+                counts["identity"] = jnp.sum(identity, dtype=jnp.int32)
+            counts["held"] = jnp.sum((experts < num_experts) & alive, dtype=jnp.int32)
+            if "experts_hit" in tally:
+                named = jnp.where(alive, experts, routed)[:, :, None] == jnp.arange(num_experts)
+                counts["experts_hit"] = jnp.sum(jnp.any(named, axis=(0, 1)), dtype=jnp.int32)
+        return out.astype(x.dtype), jnp.stack([counts[name] for name in tally])
     return out.astype(x.dtype)

@@ -6,10 +6,15 @@ the state's) and
 
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T;    y_t = S_t C_t + D x_t
 
-with ``x_t`` ``[P]`` the head's input, ``B_t``, ``C_t`` ``[N]`` SHARED BY EVERY
-HEAD (one group), ``dt_t > 0`` a step a head a token, ``A < 0`` and ``D`` a
-scalar a head.  No delta term: nothing is solved for, and the chunked form is
-matrix products alone.  Two forms:
+with ``x_t`` ``[P]`` the head's input, ``B_t``, ``C_t`` ``[N]`` A GROUP OF HEADS
+(``G`` groups of ``H / G`` consecutive heads: head ``h`` reads group ``h // (H /
+G)``; Granite 4.0-H has one group, every head's; Nemotron-H eight), ``dt_t > 0``
+a step a head a token, ``A < 0`` and ``D`` a scalar a head.  No delta term:
+nothing is solved for, and the chunked form is matrix products alone.  Every
+function here takes ``B`` and ``C`` as ``[.., G, N]`` or, for one group, as
+``[.., N]``; a grid step of either kernel takes whole groups or a part of one
+(:func:`_heads_a_step`), and with one group the programs are what they were.
+Two forms:
 
 **The state by reference**: both forms take the carried state as the WHOLE
 flat leaf ``[slots, H, P, N]`` float32 (every such layer's slots on one axis,
@@ -37,7 +42,11 @@ the decay comes as a row broadcast along the state's lanes, the outer
 product ``(dt x) B^T`` of all the step's heads as ONE product with a padded
 contraction of ``PAD`` rows (a column vector costs a whole lane tile an element
 in memory, a row nothing), and ``y = S C`` as one product ``C S^T`` whose
-result lies along lanes.
+result lies along lanes.  A step that spans several groups (Nemotron-H: 32
+heads, four groups) keeps both as one product: row ``j`` of the contraction
+holds group ``j``'s ``B`` against ``dt x`` of that group's heads alone (zero in
+every other head's columns), and row ``j`` of ``C S^T`` is read at group ``j``'s
+heads.
 
 **A chunked scan** (:func:`ssd_scan`): the tokens of a step in chunks of
 ``CHUNK``.  With ``l_i`` the chunk's running sum of ``dt A`` (so ``exp(l_i -
@@ -46,7 +55,7 @@ l_j)`` is what token j's write has decayed to at token i), for one head
     Y   = (tril(exp(l_i - l_j)) * (C B^T) * dt_j) X  +  exp(l) * (C S_0^T)  +  D X
     S_1 = exp(l_C) S_0 + (exp(l_C - l) * dt * X)^T B
 
-``C B^T`` ``[C, C]`` once a chunk for all heads; a head's own work is the mask
+``C B^T`` ``[C, C]`` once a chunk a group; a head's own work is the mask
 and three products.  A sequence's chunks are walked in order with ``S``
 carried.  The decays are float32 everywhere and every exponent is <= 0; the
 products' operands are in the dtype ``x`` comes in (bfloat16 on the chip: the
@@ -63,8 +72,8 @@ this module's (:func:`_lay_window`) and is sized BY THE TOKENS OF THE PASS, not 
 the rows of its bucket: ``ceil(S / CHUNK) + WINDOW`` chunks hold any ``WINDOW``
 rows' tokens, and a pass that walks more rows runs the walk again over the rows
 left (:func:`ssd_scan`).  On the TPU the walk is the Pallas kernel ``ssd_scan``:
-grid (``SCAN_HEADS`` heads, chunk), x and y ``[heads, C, P]``, B and C ``[C,
-N]``, the running sums, steps and ``D`` of the heads both as rows ``[3 heads,
+grid (``SCAN_HEADS`` heads, chunk), x and y ``[heads, C, P]``, B and C of the
+step's groups ``[groups, C, N]``, the running sums, steps and ``D`` of the heads both as rows ``[3 heads,
 C]`` and as columns ``[C, 3 heads]`` (the mask needs ``l_i - l_j``: a column
 minus a row), the chunk table (its ``SEQ`` row the sequences' slots, a fifth row
 their flags, a sixth the chunk whose blocks a step names: what is left of the
@@ -117,9 +126,22 @@ def scan_chunks(n: int, t: int, flat=None, walked=None) -> int:
     return gated_delta.scan_chunks(n, t, None, CHUNK)
 
 
-def _heads_a_step(heads: int, most: int) -> int:
-    """The largest divisor of ``heads`` that is at most ``most``."""
-    return max(h for h in range(1, most + 1) if heads % h == 0)
+def _heads_a_step(heads: int, most: int, groups: int = 1) -> int:
+    """Heads one grid step takes, at most ``most``: the largest divisor of a
+    group's heads, or where a group has fewer than ``most`` the heads of as many
+    whole groups as fit (at most ``PAD``: a group is a row of the update's
+    contraction).  A step so never cuts a group it does not lie inside."""
+    if heads % groups:
+        raise ValueError(f"ssd: {heads} heads do not divide into {groups} B/C groups")
+    a_group = heads // groups
+    if a_group >= most:
+        return max(h for h in range(1, most + 1) if a_group % h == 0)
+    return a_group * max(g for g in range(1, min(most // a_group, PAD) + 1) if groups % g == 0)
+
+
+def _grouped(v, x):
+    """``B`` or ``C`` with its group axis: ``[.., N]`` (one group) -> ``[.., 1, N]``."""
+    return v[..., None, :] if v.ndim == x.ndim - 1 else v
 
 
 def _dot(a, b, dims, dtype):
@@ -147,7 +169,8 @@ def _by_value(leaf, at, begins, step, live=None):
 # ------------------------------------------------------------------- one token
 def ssd_update(x, dt, A, B, C, D, leaf, at, begins, passed=None):
     """One token a row.  x ``[N, H, P]``, dt ``[N, H]`` float32 (after its
-    softplus), A, D ``[H]``, B, C ``[N, Ns]``; leaf ``[slots, H, P, Ns]``
+    softplus), A, D ``[H]``, B, C ``[N, G, Ns]`` (or ``[N, Ns]``: one group);
+    leaf ``[slots, H, P, Ns]``
     float32, the carried state whole, at ``[N]`` the rows' slots (distinct, but
     for the dead rows' one trash slot), begins ``[N]`` bool -> (y ``[N, H, P]``
     float32, leaf): every row's slot updated, no other touched.  ``passed``
@@ -155,6 +178,7 @@ def ssd_update(x, dt, A, B, C, D, leaf, at, begins, passed=None):
     name the trash slot (:func:`ssd_chunks`); y is nothing at them, and on the TPU
     every grid step of such a row names ONE block of the trash slot, so a run of
     them moves it once (a bucket's plain dead rows move it a row each)."""
+    B, C = _grouped(B, x), _grouped(C, x)
     dt = dt.astype(jnp.float32)
     decay = jnp.exp(dt * A.astype(jnp.float32))  # [N, H]
     dtx = dt[..., None] * x.astype(jnp.float32)
@@ -166,23 +190,35 @@ def ssd_update(x, dt, A, B, C, D, leaf, at, begins, passed=None):
                                  decay, B.astype(x.dtype), C.astype(x.dtype), leaf,
                                  interpret=_pallas.INTERPRET)
     else:
+        of_head = lambda v: jnp.repeat(v.astype(jnp.float32), x.shape[1] // v.shape[1],
+                                       axis=1)[:, :, None, :]  # [N, G, Ns] -> [N, H, 1, Ns]
+
         def step(rows):
-            rows = (rows * decay[..., None, None]
-                    + dtx[..., None] * B.astype(jnp.float32)[:, None, None, :])
-            return jnp.sum(rows * C.astype(jnp.float32)[:, None, None, :], axis=-1), rows
+            rows = rows * decay[..., None, None] + dtx[..., None] * of_head(B)
+            return jnp.sum(rows * of_head(C), axis=-1), rows
 
         y, leaf = _by_value(leaf, at, begins, step,
                             live=None if passed is None else jnp.logical_not(passed))
     return y + D.astype(jnp.float32)[None, :, None] * x.astype(jnp.float32), leaf
 
 
-def _update_body(at_ref, flags_ref, dtx_ref, decay_ref, b_ref, c_ref, state_ref, y_ref, out_ref):
+def _update_body(groups, at_ref, flags_ref, dtx_ref, decay_ref, b_ref, c_ref, state_ref, y_ref,
+                 out_ref):
+    """``groups``: the B/C groups the step's heads span.  One: ``b_ref`` / ``c_ref`` hold the one
+    row.  More: ``PAD`` rows, row ``j`` group ``j``'s (zeros past the last group)."""
     heads, p, ns = state_ref.shape[1:]
     dtype = dtx_ref.dtype
-    first = jax.lax.broadcasted_iota(jnp.int32, (PAD, ns), 0) == 0
-    # (dt x) B^T of every head of the step at once: [PAD, heads P]^T [PAD, Ns], one live row
-    outer = _dot(jnp.broadcast_to(dtx_ref[0], (PAD, heads * p)),
-                 jnp.where(first, b_ref[0].astype(jnp.float32), 0.0), TN, dtype)
+    dtx, b, c = jnp.broadcast_to(dtx_ref[0], (PAD, heads * p)), b_ref[0, 0], c_ref[0, 0]
+    if groups == 1:
+        first = jax.lax.broadcasted_iota(jnp.int32, (PAD, ns), 0) == 0
+        b, c = jnp.where(first, b.astype(jnp.float32), 0.0), jnp.broadcast_to(c, (PAD, ns))
+    else:  # row j of the contraction: group j's B against dt x of group j's heads alone
+        column = jax.lax.broadcasted_iota(jnp.int32, (PAD, heads * p), 1)
+        begin = jax.lax.broadcasted_iota(jnp.int32, (PAD, heads * p), 0) * (heads // groups * p)
+        mine = (column >= begin) & (column < begin + heads // groups * p)
+        dtx = jnp.where(mine, dtx.astype(jnp.float32), 0.0)  # in float32: the mask is laid out as int32's
+    # (dt x) B^T of every head of the step at once: [PAD, heads P]^T [PAD, Ns], one live row a group
+    outer = _dot(dtx, b, TN, dtype)
     begins = flags_ref[pl.program_id(0)] > 0  # a row passed by writes, like one that begins, what it read not
 
     @pl.when(jnp.logical_not(begins))
@@ -195,26 +231,36 @@ def _update_body(at_ref, flags_ref, dtx_ref, decay_ref, b_ref, c_ref, state_ref,
         for h in range(heads):
             out_ref[0, h] = outer[h * p:(h + 1) * p]
 
-    # y = S C as C S^T: the result lies along lanes, one row of PAD alike
-    y = _dot(jnp.broadcast_to(c_ref[0], (PAD, ns)), out_ref[0].reshape(heads * p, ns), NT, dtype)
-    y_ref[0] = y[0:1]
+    # y = S C as C S^T: the result lies along lanes, one row of PAD alike (with groups: row j is
+    # group j's C over every head, read at group j's heads)
+    y = _dot(c, out_ref[0].reshape(heads * p, ns), NT, dtype)
+    y_ref[0] = y[0:1] if groups == 1 else jnp.sum(jnp.where(mine, y, 0.0), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", ), inline=True)
 def _update_pallas(at, flags, dtx, decay, b, c, leaf, *, interpret):
-    """``flags`` ``[N]``: 1 a row whose sequence begins, 2 or 3 a row passed by."""
-    n, (heads, p, ns) = at.shape[0], leaf.shape[1:]
-    step = _heads_a_step(heads, UPDATE_HEADS)
-    a_row = lambda r, g, at, flags: (r, 0, 0)
+    """``flags`` ``[N]``: 1 a row whose sequence begins, 2 or 3 a row passed by; b, c ``[N, G, Ns]``."""
+    n, (heads, p, ns), groups = at.shape[0], leaf.shape[1:], b.shape[1]
+    step = _heads_a_step(heads, UPDATE_HEADS, groups)
+    a_group = heads // groups
+    spans = max(step // a_group, 1)  # the groups a step's heads span
+    if spans == 1:  # a step inside one group: its one row
+        of_group = lambda r, g, at, flags: (r, g * step // a_group, 0, 0)
+        b, c = b[:, :, None], c[:, :, None]
+    else:  # a step's groups as the first rows of PAD
+        of_group = lambda r, g, at, flags: (r, g, 0, 0)
+        b, c = (jnp.pad(v.reshape(n, groups // spans, spans, ns), ((0, 0), (0, 0), (0, PAD - spans), (0, 0)))
+                for v in (b, c))
     of_heads = lambda r, g, at, flags: (r, 0, g)
     in_slot = lambda r, g, at, flags: (at[r], jnp.where(flags[r] > 1, 0, g), 0, 0)
     y, leaf = pl.pallas_call(
-        _update_body,
+        functools.partial(_update_body, spans),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(n, heads // step),
             in_specs=[pl.BlockSpec((1, 1, step * p), of_heads),
                       pl.BlockSpec((1, step, ns), lambda r, g, at, flags: (r, g, 0)),
-                      pl.BlockSpec((1, 1, ns), a_row), pl.BlockSpec((1, 1, ns), a_row),
+                      pl.BlockSpec((1, 1) + b.shape[2:], of_group),
+                      pl.BlockSpec((1, 1) + c.shape[2:], of_group),
                       pl.BlockSpec((1, step, p, ns), in_slot)],
             out_specs=[pl.BlockSpec((1, 1, step * p), of_heads),
                        pl.BlockSpec((1, step, p, ns), in_slot)]),
@@ -225,7 +271,7 @@ def _update_pallas(at, flags, dtx, decay, b, c, leaf, *, interpret):
         interpret=interpret,
         name="ssd_update",
     )(at, flags, dtx.reshape(n, 1, heads * p), jnp.broadcast_to(decay[..., None], (n, heads, ns)),
-      b[:, None], c[:, None], leaf)
+      b, c, leaf)
     return y.reshape(n, heads, p), leaf
 
 
@@ -285,7 +331,7 @@ def ssd_chunks(x, dt, A, B, C, D, leaf, at, begins, trash, n_tokens, row=None, c
 # ----------------------------------------------------------------- the chunked scan
 def ssd_scan(x, dt, A, B, C, D, leaf, at, begins, n_tokens, row=None, col=None, walked=None):
     """The chunked scan over a step's tokens.  x ``[b, s, H, P]``, dt ``[b, s,
-    H]`` float32 (after its softplus), A, D ``[H]``, B, C ``[b, s, Ns]``, ``[b,
+    H]`` float32 (after its softplus), A, D ``[H]``, B, C ``[b, s, G, Ns]`` (or ``[b, s, Ns]``: one group), ``[b,
     s]`` = ``[N, T]`` (``row`` None) or the compacted ``[1, S]`` (``row``,
     ``col`` ``[1, S]``); leaf ``[slots, H, P, Ns]`` float32, the carried state
     whole, at ``[N]`` the rows' slots, begins ``[N]`` bool (a sequence that
@@ -305,6 +351,7 @@ def ssd_scan(x, dt, A, B, C, D, leaf, at, begins, n_tokens, row=None, col=None, 
     body is traced once, :func:`walk_trips` trips: none where no row is walked;
     nothing has a capacity, nothing is dropped)."""
     heads = x.shape[2]
+    B, C = _grouped(B, x), _grouped(C, x)
     dt = dt.astype(jnp.float32)
     counts = n_tokens if walked is None else jnp.where(walked, n_tokens, 0)
 
@@ -312,7 +359,7 @@ def ssd_scan(x, dt, A, B, C, D, leaf, at, begins, n_tokens, row=None, col=None, 
         """One layout walked: ``at``, ``begins``, ``live`` of the sequences the table's ``SEQ``
         row names (``live``: the sequence holds a token)."""
         xa = laid(x)  # [H, chunks C, P]
-        ba, ca = laid(B[:, :, None])[0], laid(C[:, :, None])[0]  # [chunks C, Ns]
+        ba, ca = laid(B), laid(C)  # [G, chunks C, Ns]
         dta = laid(dt[..., None])[..., 0].reshape(heads, chunks, CHUNK)  # 0 where no token sits
         la = jnp.cumsum(dta * A.astype(jnp.float32)[:, None, None], axis=-1)
         d = jnp.broadcast_to(D.astype(jnp.float32)[:, None, None], la.shape)
@@ -406,17 +453,21 @@ def _walk_scan(table, x, b, c, scalars, state):
     """The walk in XLA: a ``lax.scan`` over the chunks, every head at once.
     ``scalars``: l, dt and D, each ``[H, chunks, C]``."""
     heads, p = x.shape[0], x.shape[-1]
-    chunks, ns = table.shape[1], b.shape[-1]
+    chunks, (groups, _, ns) = table.shape[1], b.shape
     causal = _causal(CHUNK)
+    # C B^T once a group; a group's heads against it
     per_head = jax.vmap(_head, in_axes=(0, None, None, None, 0, 0, 0, 0, 0, 0, None))
+    per_group = jax.vmap(per_head, in_axes=(0, ) * 10 + (None, ))
+    of_group = lambda a: a.reshape((groups, heads // groups) + a.shape[1:])
 
     def one(carry, inp):
         s, states = carry
         (seq, first, last, live), xc, bc, cc, (lc, tc, dc) = inp
         s = jnp.where(first > 0, states[seq], s)
-        scores = _dot(cc, bc, NT, x.dtype)
-        y, s1 = per_head(xc, scores, bc, cc, lc[:, None, :], lc[:, :, None], tc[:, None, :],
-                         tc[:, :, None], dc[:, :, None], s, causal)
+        scores = jax.vmap(lambda cg, bg: _dot(cg, bg, NT, x.dtype))(cc, bc)
+        y, s1 = per_group(of_group(xc), scores, bc, cc, *map(of_group, (
+            lc[:, None, :], lc[:, :, None], tc[:, None, :], tc[:, :, None], dc[:, :, None], s)), causal)
+        y, s1 = y.reshape((heads, ) + y.shape[2:]), s1.reshape(s.shape)
         s = jnp.where(live > 0, s1, s)
         states = states.at[jnp.where(last > 0, seq, states.shape[0])].set(s, mode="drop")
         return (s, states), jnp.where(live > 0, y, 0.0).astype(x.dtype)
@@ -424,7 +475,8 @@ def _walk_scan(table, x, b, c, scalars, state):
     (_, state), y = jax.lax.scan(
         one, (jnp.zeros((heads, p, ns), jnp.float32), state),
         (table.T, jnp.moveaxis(x.reshape(heads, chunks, CHUNK, p), 1, 0),
-         b.reshape(chunks, CHUNK, ns), c.reshape(chunks, CHUNK, ns),
+         jnp.moveaxis(b.reshape(groups, chunks, CHUNK, ns), 1, 0),
+         jnp.moveaxis(c.reshape(groups, chunks, CHUNK, ns), 1, 0),
          tuple(jnp.moveaxis(a, 1, 0) for a in scalars)))
     return jnp.moveaxis(y, 0, 1).reshape(heads, chunks * CHUNK, p), state
 
@@ -444,17 +496,19 @@ def _scan_body(table_ref, x_ref, b_ref, c_ref, rows_ref, cols_ref, state_ref, y_
 
     @pl.when(table_ref[LIVE, k] > 0)
     def _compute():
-        b, c = b_ref[...], c_ref[...]
-        scores = _dot(c, b, NT, b.dtype)
-        causal = _causal(b.shape[0])
+        causal = _causal(b_ref.shape[1])
         rows, cols = rows_ref[0, 0], cols_ref[0, 0]  # [3 heads, C], [C, 3 heads]: l, dt, D
         row = lambda at: rows[at:at + 1]
         column = lambda at: cols[:, at:at + 1]
-        for h in range(heads):
-            y, s1 = _head(x_ref[h], scores, b, c, row(h), column(h), row(heads + h),
-                          column(heads + h), column(2 * heads + h), s_ref[h], causal)
-            y_ref[h] = y.astype(y_ref.dtype)
-            s_ref[h] = s1
+        groups = b_ref.shape[0]  # the step's groups: C B^T once each, then its heads
+        for g in range(groups):
+            b, c = b_ref[g], c_ref[g]
+            scores = _dot(c, b, NT, b.dtype)
+            for h in range(g * heads // groups, (g + 1) * heads // groups):
+                y, s1 = _head(x_ref[h], scores, b, c, row(h), column(h), row(heads + h),
+                              column(heads + h), column(2 * heads + h), s_ref[h], causal)
+                y_ref[h] = y.astype(y_ref.dtype)
+                s_ref[h] = s1
 
     @pl.when(table_ref[LAST, k] > 0)
     def _store():
@@ -464,19 +518,22 @@ def _scan_body(table_ref, x_ref, b_ref, c_ref, rows_ref, cols_ref, state_ref, y_
 # jitted for its trace cache: every chunk program of a cell traces the kernel once
 @functools.partial(jax.jit, static_argnames=("interpret", ), inline=True)
 def _walk_pallas(table, x, b, c, scalars, leaf, *, interpret):
-    """``table`` ``[6, chunks]``: ``SEQ`` holds each chunk's SLOT of ``leaf``, ``BEGINS`` whether
+    """x ``[H, chunks C, P]``, b, c ``[G, chunks C, Ns]``.
+    ``table`` ``[6, chunks]``: ``SEQ`` holds each chunk's SLOT of ``leaf``, ``BEGINS`` whether
     its sequence begins, ``BLOCK`` the chunk whose blocks of x, B, C, the scalars and y its grid
     step names: an empty chunk's is the last live one's, so it moves nothing and computes
     nothing, and the positions of y that no live chunk covers are never written."""
     heads, p = x.shape[0], x.shape[-1]
-    chunks, ns = table.shape[1], b.shape[-1]
+    chunks, (groups, _, ns) = table.shape[1], b.shape
     size = scalars[0].shape[-1]  # a chunk's positions
-    step = _heads_a_step(heads, SCAN_HEADS)
+    step = _heads_a_step(heads, SCAN_HEADS, groups)
+    a_group = heads // groups
+    spans = max(step // a_group, 1)  # the groups a step's heads span: its blocks of B and C
     # a step's l, dt and D side by side: [groups, chunks, 3 heads, C], and transposed
     rows = jnp.concatenate([a.reshape(heads // step, step, chunks, size) for a in scalars], axis=1)
     rows = jnp.moveaxis(rows, 1, 2)
     of_heads = lambda g, k, table: (g, table[BLOCK, k], 0)
-    of_chunk = lambda g, k, table: (table[BLOCK, k], 0)
+    of_chunk = lambda g, k, table: (g * step // (a_group * spans), table[BLOCK, k], 0)
     of_both = lambda g, k, table: (g, table[BLOCK, k], 0, 0)
     in_slot = lambda g, k, table: (table[SEQ, k], g, 0, 0)
     return pl.pallas_call(
@@ -484,7 +541,8 @@ def _walk_pallas(table, x, b, c, scalars, leaf, *, interpret):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(heads // step, chunks),
             in_specs=[pl.BlockSpec((step, size, p), of_heads),
-                      pl.BlockSpec((size, ns), of_chunk), pl.BlockSpec((size, ns), of_chunk),
+                      pl.BlockSpec((spans, size, ns), of_chunk),
+                      pl.BlockSpec((spans, size, ns), of_chunk),
                       pl.BlockSpec((1, 1, 3 * step, size), of_both),
                       pl.BlockSpec((1, 1, size, 3 * step), of_both),
                       pl.BlockSpec((1, step, p, ns), in_slot)],

@@ -13,7 +13,7 @@ from tests.unit.inference.family_contract import Family, ServingContract, Statef
 
 HERE = pathlib.Path(__file__).parent
 FAMILIES = ["test_lfm2_state", "test_qwen3_next_state", "test_glm_moe_dsa", "test_longcat_flash",
-            "test_granite_moe_hybrid", "test_afmoe", "test_bailing_hybrid"]
+            "test_granite_moe_hybrid", "test_afmoe", "test_bailing_hybrid", "test_nemotron_h"]
 
 
 @pytest.mark.parametrize("name", FAMILIES)

@@ -393,3 +393,90 @@ def test_an_expert_of_a_group_left_out_is_not_picked_whatever_its_bias():
     bias = jnp.full((16, ), -2.0).at[:8].add(0.5)  # groups 0 and 1 of four are kept, all choices < 0
     _, picks = route(moe["gate"]["wg"], x, 4, True, 4, 2, 1.0, scoring="sigmoid", bias=bias)
     assert (np.asarray(picks) < 8).all()
+
+
+# ------------------------------------------- an ungated expert: relu(up)^2, two matrices (ISSUE 62)
+def ungated_moe(routed, held, seed=0, slots=41):
+    """A router with a selection bias over ``routed`` outputs, ``held`` ungated experts (no
+    ``w_gate`` leaf; ``w_up`` ``[E, F, D]`` like ``w_down``) and a shared expert of the same form."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    up = lambda key, *lead: jax.random.normal(key, (*lead, F, D)) * D ** -0.5
+    down = lambda key, *lead: jax.random.normal(key, (*lead, F, D)) * F ** -0.5
+    return {"gate": {"wg": jax.random.normal(ks[0], (D, routed)) * D ** -0.5,
+                     "bias": jax.random.normal(ks[1], (routed, )) * 0.1},
+            "experts": {"w_up": up(ks[2], held), "w_down": down(ks[3], held)},
+            "shared": {"w_up": up(ks[4]), "w_down": down(ks[5])}}, jax.random.normal(ks[6], (slots, D))
+
+
+def ungated_loop(moe, x, top_k, live, shared=True):
+    """Token by token, pick by pick: ``W_down relu(W_up x)^2`` for a pick on an expert held here,
+    nothing for a pick held elsewhere, the shared expert once a token."""
+    moe, x = jax.tree_util.tree_map(lambda a: np.asarray(a, np.float64), (moe, np.asarray(x)))
+    weights, picks = route(jnp.asarray(moe["gate"]["wg"], jnp.float32), jnp.asarray(x, jnp.float32), top_k,
+                           True, scaling=2.5, scoring="sigmoid", bias=jnp.asarray(moe["gate"]["bias"]),
+                           norm_eps=1e-20)
+    ffn = lambda w, row: np.square(np.maximum(w["w_up"] @ row, 0.0)) @ w["w_down"]
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        if not live[t]:
+            continue
+        for w, e in zip(np.asarray(weights[t]), np.asarray(picks[t])):
+            if e < moe["experts"]["w_up"].shape[0]:
+                out[t] += w * ffn({k: v[e] for k, v in moe["experts"].items()}, x[t])
+        if shared:
+            out[t] += ffn(moe["shared"], x[t])
+    return out
+
+
+@pytest.mark.parametrize("interpreted", [False, True], ids=["xla", "interpreted-kernels"])
+@pytest.mark.parametrize("routed,held,shared", [(8, 8, True), (16, 4, True), (16, 8, False)],
+                         ids=["all-held", "a-share", "a-share-no-shared-expert"])
+def test_an_ungated_expert_is_the_brute_force_loop(routed, held, shared, interpreted, monkeypatch):
+    """No ``w_gate`` leaf: two grouped matmuls and ``relu(.)^2`` between them, for every
+    expert held (no loop traced), for a share's compacted window, and for the shared expert;
+    the tally counts the live slots' picks on held experts."""
+    from deepspeed_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, "INTERPRET", interpreted)
+    moe, x = ungated_moe(routed, held, seed=routed + held)
+    if not shared:
+        del moe["shared"]
+    live = np.arange(x.shape[0]) % 5 != 3
+    run = jax.jit(lambda m, a, alive: sparse_moe_ffn(m, a, 3, True, alive, scaling=2.5, scoring="sigmoid",
+                                                     norm_eps=1e-20, tally=("held", )))
+    with jax.default_matmul_precision("highest"):
+        got, tally = run(moe, x, jnp.asarray(live))
+    want = ungated_loop(moe, x, 3, live, shared)
+    np.testing.assert_allclose(np.asarray(got)[live], want[live], atol=3e-5 * np.abs(want).max())
+    # a dead slot's routed part is zero; the shared expert runs over every slot, read or not
+    assert np.abs(want[live]).max() > 0.1 and (shared or not np.asarray(got)[~live].any())
+    _, picks = route(moe["gate"]["wg"], x, 3, True, scoring="sigmoid", bias=moe["gate"]["bias"])
+    assert np.asarray(tally).tolist() == [int(((np.asarray(picks) < held) & live[:, None]).sum())]
+    if not interpreted:  # (an interpreted kernel is a loop itself)
+        text = run.lower(moe, x, jnp.asarray(live)).as_text()
+        assert ("while" in text) == (held < routed)  # all held: no window, no loop
+
+
+def test_a_gated_tree_is_gated_and_an_ungated_tree_is_not_whatever_else_is_passed():
+    """The form is the tree's: with a ``w_gate`` leaf beside the same ``w_up`` and ``w_down`` the
+    layer is SwiGLU over ``[D, F]`` matrices (three products), and differs."""
+    moe, x = ungated_moe(8, 8, seed=3)
+    gated = {**moe, "experts": {"w_gate": jnp.swapaxes(moe["experts"]["w_up"], 1, 2),
+                                "w_up": jnp.swapaxes(moe["experts"]["w_up"], 1, 2),
+                                "w_down": moe["experts"]["w_down"]}}
+    del gated["shared"], moe["shared"]
+    a, b = (sparse_moe_ffn(m, x, 3, True, scoring="sigmoid") for m in (moe, gated))
+    up = np.einsum("td,efd->tef", np.asarray(x), np.asarray(moe["experts"]["w_up"]))
+    assert np.abs(np.asarray(a) - np.asarray(b)).max() > 0.05 and (up < 0).any()
+
+
+def test_the_tally_of_experts_named_counts_each_held_expert_once_whatever_names_it():
+    moe, x = ungated_moe(16, 8, seed=2)
+    live = jnp.asarray(np.arange(x.shape[0]) % 3 != 0)
+    _, tally = sparse_moe_ffn(moe, x, 3, True, live, scoring="sigmoid", tally=("held", "experts_hit"))
+    _, picks = route(moe["gate"]["wg"], x, 3, True, scoring="sigmoid", bias=moe["gate"]["bias"])
+    named = {int(e) for row, alive in zip(np.asarray(picks), np.asarray(live)) if alive for e in row if e < 8}
+    assert np.asarray(tally).tolist()[1] == len(named) and 0 < len(named) <= 8
+    # the counts come in the order named, and with identity experts and no names the pair it was
+    assert np.asarray(sparse_moe_ffn(moe, x, 3, True, live, scoring="sigmoid",
+                                     tally=("experts_hit", "held"))[1]).tolist() == np.asarray(tally).tolist()[::-1]
+    assert np.asarray(sparse_moe_ffn(moe, x, 3, True, live, scoring="sigmoid", identity_experts=0)[1]).shape == (2, )

@@ -15,6 +15,8 @@ persistent compilation cache off around it (a described compile is written to
 the cache but cannot be read back without a chip).
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -288,11 +290,14 @@ def test_the_gated_delta_scan_compiles_at_the_cells_shapes(chip, budget, n, hk):
 
 
 SSM_LEAF = (9 * 33, 128, 64, 128)  # serve.ssm-chat-burst's ``ssm`` leaf, flat: nine layers of 33 slots
-SSM_ROW = 128 * 64 * 128 * 4       # one slot of it: a row's float32 matrices of one layer, 4 MB
+# serve.nemotron-decode-wide's: six M layers of 65 slots, 64 heads in EIGHT B/C groups (ISSUE 62)
+SSMG_LEAF, SSMG_GROUPS = (6 * 65, 64, 64, 128), 8
+SSM_CELLS = {"granite": (SSM_LEAF, 1), "nemotron": (SSMG_LEAF, SSMG_GROUPS)}
 
 
-@pytest.mark.parametrize("budget,n", [(512, 32), (1024, 32)], ids=lambda v: str(v))
-def test_the_ssd_scan_compiles_at_the_cells_shapes(chip, budget, n):
+@pytest.mark.parametrize("budget,n,cell", [(512, 32, "granite"), (1024, 32, "granite"), (1024, 64, "nemotron"),
+                                           (2048, 64, "nemotron")], ids=lambda v: str(v))
+def test_the_ssd_scan_compiles_at_the_cells_shapes(chip, budget, n, cell):
     """ISSUE 52: the chunked-scan kernel at Granite 4.0-H's 128 heads of 64 x 128
     (B and C shared by all of them), for a compacted pass of ``budget`` tokens
     over ``n`` sequences, a window of them laid on chunk edges (ISSUE 55: ``ceil(budget
@@ -302,42 +307,52 @@ def test_the_ssd_scan_compiles_at_the_cells_shapes(chip, budget, n):
     and out, a chunk's slot from the prefetched table, and not one row of it (4
     MB) held beside it: this program's temporaries are x laid out anew (an entry
     parameter's 64-wide rows in whole lane tiles: twice its bytes, and no part of
-    a step program, where x is made in the kernel's layout) and 0.4 MB of scalars."""
+    a step program, where x is made in the kernel's layout) and 0.4 MB of scalars.
+    ISSUE 62: B and C a GROUP of heads, ``[G, t, Ns]``: at Nemotron-H's 64 heads in 8
+    groups a grid step's 8 heads are exactly one group, whose block it names.  Each cell is
+    held to ITS OWN row (Granite's 4 MB as before, Nemotron's 2 MB): B and C with their group
+    axis (5-9 MB here) reach the kernel as they come, no copy of them among the temporaries."""
     from deepspeed_tpu.ops.linear_attention import ssd
 
-    heads, p, ns = SSM_LEAF[1:]
+    leaf, groups = SSM_CELLS[cell]
+    (heads, p, ns), row = leaf[1:], math.prod(leaf[1:]) * 4  # a slot: a row's float32 matrices of one layer
     chunks = ssd.scan_chunks(n, 0, budget)
     assert chunks == budget // ssd.CHUNK + ssd.WINDOW
     t = chunks * ssd.CHUNK
     scalars = (chip((heads, chunks, ssd.CHUNK), jnp.float32), ) * 3
     avals = (chip((6, chunks), jnp.int32), chip((heads, t, p), jnp.bfloat16),
-             chip((t, ns), jnp.bfloat16), chip((t, ns), jnp.bfloat16), scalars,
-             chip(SSM_LEAF, jnp.float32))
+             chip((groups, t, ns), jnp.bfloat16), chip((groups, t, ns), jnp.bfloat16), scalars,
+             chip(leaf, jnp.float32))
     compiled = jax.jit(lambda *a: ssd._walk_pallas(*a, interpret=False),
                        donate_argnums=(5, )).lower(*avals).compile()
     assert kernel_calls(compiled.as_text()) == {"ssd_scan": 1}
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes == SSM_LEAF[0] * SSM_ROW
-    assert memory.temp_size_in_bytes - 2 * heads * t * p * 2 < SSM_ROW
+    assert memory.alias_size_in_bytes == leaf[0] * row
+    assert memory.temp_size_in_bytes - 2 * heads * t * p * 2 < row
 
 
-def test_the_ssd_update_compiles_at_the_cells_shapes(chip):
+@pytest.mark.parametrize("n,cell", [(32, "granite"), (64, "nemotron"), (128, "nemotron")], ids=lambda v: str(v))
+def test_the_ssd_update_compiles_at_the_cells_shapes(chip, n, cell):
     """ISSUE 52: the one-token update at a decode step of 32 rows: one Mosaic
     kernel over (row, 32 heads).  ISSUE 53: the rows' matrices BY REFERENCE: the
     whole flat leaf aliased in and out, a row's slot from the prefetched ``at``,
-    under a row's 4 MB held beside it (the decays along the state's lanes: 2 MB)."""
+    under a row's 4 MB held beside it (the decays along the state's lanes: 2 MB).
+    ISSUE 62: at Nemotron-H's 64 rows (and 128) of 64 heads in 8 groups a grid step's 32
+    heads span FOUR groups: their B and C as the first rows of a padded contraction; the
+    temporaries under ITS row of 2 MB, at 64 rows and at 128 alike."""
     from deepspeed_tpu.ops.linear_attention import ssd
 
-    n, (heads, p, ns) = 32, SSM_LEAF[1:]
+    leaf, groups = SSM_CELLS[cell]
+    (heads, p, ns), row = leaf[1:], math.prod(leaf[1:]) * 4
     avals = (chip((n, ), jnp.int32), chip((n, ), jnp.int32), chip((n, heads, p), jnp.bfloat16),
-             chip((n, heads), jnp.float32), chip((n, ns), jnp.bfloat16),
-             chip((n, ns), jnp.bfloat16), chip(SSM_LEAF, jnp.float32))
+             chip((n, heads), jnp.float32), chip((n, groups, ns), jnp.bfloat16),
+             chip((n, groups, ns), jnp.bfloat16), chip(leaf, jnp.float32))
     compiled = jax.jit(lambda *a: ssd._update_pallas(*a, interpret=False),
                        donate_argnums=(6, )).lower(*avals).compile()
     assert kernel_calls(compiled.as_text()) == {"ssd_update": 1}
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes == SSM_LEAF[0] * SSM_ROW
-    assert memory.temp_size_in_bytes < SSM_ROW
+    assert memory.alias_size_in_bytes == leaf[0] * row
+    assert memory.temp_size_in_bytes < row
 
 
 @pytest.mark.parametrize("n,t,s", [(4, 1024, 1024), (2, 512, 1024), (8, 1, None)],
@@ -399,6 +414,35 @@ def test_a_shares_expert_ffn_compiles_at_the_cells_shapes(chip, slots, top_k, wi
     assert kernel_calls(compiled.as_text()) == {"gmm": 3, "moe_combine": 1}
     every_pick = slots * top_k * width * 2  # the bf16 rows the parent gathered a pass
     assert compiled.memory_analysis().temp_size_in_bytes < max(every_pick // 2, 4 << 20)
+
+
+@pytest.mark.parametrize("slots,rows", [(64, 256), (1024, 3840)], ids=["decode", "chunk"])
+def test_a_share_of_ungated_experts_compiles_at_the_cells_shapes(chip, slots, rows):
+    """ISSUE 62: one chip's share of an ``E`` layer of ``serve.nemotron-decode-wide`` (64 of
+    128 experts held, top 6, 2688 x 1856: neither a multiple of the grouped matmul's tiles): the
+    leaves hold no ``w_gate``, so the window's FFN is TWO ``gmm`` calls with the squared
+    rectifier between them (``w_up`` ``[F, D]`` multiplied transposed: laid ``[D, 1856]`` the chip
+    keeps ``D`` minor and the whole 3.8 GB stack is copied for the kernel a program), the shared
+    expert of the same form beside it, and the call returns the tallies the family asks for
+    (held picks, held experts named); no temporary as large as every pick's row."""
+    from deepspeed_tpu.moe.serving import expert_rows, sparse_moe_ffn
+    width, expert_width, held, routed, layers, top_k = 2688, 1856, 64, 128, 6, 6
+    assert expert_rows(slots, top_k, held, routed) == rows
+    moe = {"gate": {"wg": chip((width, routed), jnp.bfloat16), "bias": chip((routed, ), jnp.bfloat16)},
+           "experts": {"w_up": chip((layers, held, expert_width, width), jnp.bfloat16),
+                       "w_down": chip((layers, held, expert_width, width), jnp.bfloat16)},
+           "shared": {"w_up": chip((2 * expert_width, width), jnp.bfloat16),
+                      "w_down": chip((2 * expert_width, width), jnp.bfloat16)}}
+
+    def layer(moe, x, live, at):
+        return sparse_moe_ffn(moe, x, top_k, True, live, layer=at, scaling=2.5, scoring="sigmoid",
+                              norm_eps=1e-20, tally=("held", "experts_hit"))
+
+    compiled = jax.jit(layer).lower(moe, chip((slots, width), jnp.bfloat16), chip((slots, ), jnp.bool_),
+                                    chip((), jnp.int32)).compile()
+    assert kernel_calls(compiled.as_text()) == {"gmm": 2, "moe_combine": 1}
+    every_pick = slots * top_k * width * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < max(every_pick, 4 << 20)
 
 
 def test_fused_adamw_flat_compiles(chip):

@@ -188,6 +188,20 @@ def ling_shapes(chip, layers):
     return bailing_hybrid, cfg, on_chip(params), on_chip(kv)
 
 
+def nemotron_shapes(chip, layers):
+    """Nemotron-3-Nano as ``serve.nemotron-decode-wide`` holds it (64 of 128 experts, half
+    the vocabulary; ``layers`` = 14: ``MEMEM*E`` twice, one scan of two periods): a pool
+    of the two ``*`` layers ``[2, 1024, 2, 128, 128]``, the six ``M`` layers' state, 64
+    slots and a trash slot (the shift ``[6, 65, 3, 6144]`` and the float32 matrices ``[6,
+    65, 64, 64, 128]``, 0.82 GB) and the pick tallies ``[3]``: an ``E`` layer has a row in none."""
+    from deepspeed_tpu.models import nemotron_h
+    cfg = nemotron_h.NemotronHConfig(vocab_size=65536, num_layers=layers, held_experts=64)
+    params = jax.eval_shape(lambda: nemotron_h.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+    kv = jax.eval_shape(lambda: nemotron_h.init_paged_cache(cfg, 1024, 128, state_slots=64))
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype), tree)
+    return nemotron_h, cfg, on_chip(params), on_chip(kv)
+
+
 def mistral_module_and_shapes(chip, layers):
     from deepspeed_tpu.models import mistral
     return (mistral, ) + mistral_shapes(chip, layers)
@@ -319,6 +333,54 @@ def test_a_state_leaf_by_reference_is_moved_by_its_kernels_alone(chip, n, t, bou
         assert chunks == -(-bound // ssd.CHUNK) + ssd.WINDOW < by_rows
         laid = {dim for _, _, shapes in results(text) for dims in shapes for dim in dims}
         assert chunks * ssd.CHUNK in laid and not laid & {by_rows, by_rows * ssd.CHUNK}, laid
+
+
+@pytest.mark.parametrize("n,t,bound,kernels,in_a_burst", [
+    (64, 1, 1024, ("ssd_update", ), False), (64, 256, 1024, ("ssd_update", "ssd_scan"), False),
+    (64, 1, None, ("ssd_update", ), True)], ids=["decode", "compacted", "burst"])
+def test_a_layer_that_is_one_part_alone_holds_no_row_and_64_slots_are_moved_by_the_kernels_alone(
+        chip, n, t, bound, kernels, in_a_burst):
+    """ISSUE 62: Nemotron-3-Nano's step programs at the cell's size (9.17 GB of
+    weights, 64 state slots).  The cache's leaves count their own kind of layer
+    (``[6, 65, ...]`` state rows, ``[2, 1024, ...]`` pool rows for 14 layers: the six
+    ``E`` layers hold a row in neither); in the decode step, the compacted chunk pass
+    and a burst's body no operation produces the rows' matrices ``f32[64,64,64,128]``
+    and none but the Mosaic kernels, each once an ``M`` layer of the scan's body,
+    produces the flat leaf's shape; no operation copies the expert stack (``w_up``
+    lies ``[.., 1856, 2688]`` and goes to ``gmm`` transposed); leaf and pool are
+    aliased in and out and the program fits the chip beside them."""
+    module, cfg, params, kv = nemotron_shapes(chip, layers=14)
+    ssm, conv = kv["state"]["ssm"], kv["state"]["conv"]
+    assert ssm.shape == (6, 65, 64, 64, 128) and conv.shape == (6, 65, 3, 6144)
+    assert kv["k"].shape == kv["v"].shape == (2, 1024, 2, 128, 128) and kv["tally"].shape == (3, )
+
+    def fwd(params, kv, tokens, n_tokens, start_pos, tables):
+        return module.forward_paged(cfg, params, tokens, n_tokens, start_pos, tables, kv,
+                                    block_size=128, live_token_bound=bound, last_rows=True)
+
+    ints = [chip(shape, jnp.int32) for shape in ((n, t), (n, ), (n, ), (n, 8 + 1))]
+    compiled = jax.jit(burst_of(fwd) if in_a_burst else fwd,
+                       donate_argnums=(1, )).lower(params, kv, *ints).compile()
+    text = compiled.as_text()
+    calls = kernel_calls(text)
+    # the period's body holds three M layers, one * layer and three E layers of two gmm each
+    assert {k: v for k, v in calls.items() if k.startswith("ssd_")} == dict.fromkeys(kernels, 3), calls
+    assert calls["paged_attention"] == calls["kv_write"] == 1 and calls["gmm"] == 6, calls
+    rows = list((n, ) + ssm.shape[2:])
+    assert [opcode for opcode, _, shapes in results(text) if rows in shapes] == []
+    whole = pool_shaped_results(text, (1, ) + ssm.shape)
+    assert [r[0] for r in whole] == ["custom-call"] * 3 * len(kernels), whole
+    stack = list(params["experts"]["w_up"].shape)
+    assert [opcode for opcode, _, shapes in results(text) if stack in shapes
+            or [stack[0] * stack[1]] + stack[2:] in shapes] == [], "the expert stack is copied"
+    memory = compiled.memory_analysis()
+    leaf_bytes = int(np.prod(ssm.shape)) * 4
+    # a burst's program lays the M layers' W_in [2, 2688, 10304] out anew once a call (10,304 = 80.5
+    # lane tiles: 110 MB a position of the period, 0.33 GB); a step program takes them as they lie
+    assert memory.alias_size_in_bytes >= leaf_bytes
+    assert memory.temp_size_in_bytes < (leaf_bytes * 3 // 4 if in_a_burst else leaf_bytes // 8)
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert held < 12.5e9, held  # weights 9.17 GB, state 0.83, pool 0.27 and the pass's temporaries
 
 
 # the lowered text of two families that share ``paged_forward``'s mixer contract (and
